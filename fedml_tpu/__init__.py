@@ -28,32 +28,4 @@ Layer map (mirrors SURVEY.md §1):
 
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.6 ships shard_map under experimental; the codebase (and its
-    # tests) import the stable ``jax.shard_map`` spelling everywhere, so
-    # alias it once here — every module imports this package first. The
-    # experimental version spells today's check_vma kwarg check_rep, so the
-    # alias must translate or every check_vma=False call site TypeErrors.
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @_functools.wraps(_shard_map)
-    def _shard_map_compat(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "pcast"):
-    # jax < 0.7 has no varying/replicated cast op: its shard_map tracks
-    # replication itself, so marking a value "varying" is the identity there
-    def _pcast(x, axis_name=None, to=None):  # noqa: ARG001 - newer-jax sig
-        return x
-
-    _jax.lax.pcast = _pcast
-
 from fedml_tpu.core import aggregation, partition, pytree  # noqa: F401

@@ -11,6 +11,7 @@ Dirichlet machinery (fedml_tpu.core.partition).
 
 from __future__ import annotations
 
+import logging
 import os
 import pickle
 
@@ -20,6 +21,8 @@ from fedml_tpu.data import FedDataset, register_dataset
 from fedml_tpu.data.batching import pad_and_stack_clients, pad_eval_pool
 from fedml_tpu.data.synthetic import make_synthetic_classification
 from fedml_tpu.core.partition import partition as partition_fn
+
+log = logging.getLogger(__name__)
 
 _CIFAR_MEAN = np.array([0.4914, 0.4822, 0.4465], np.float32)
 _CIFAR_STD = np.array([0.2470, 0.2435, 0.2616], np.float32)
@@ -148,6 +151,10 @@ def _build(
     data_dir: str = "./data", mean=_CIFAR_MEAN, std=_CIFAR_STD,
 ) -> FedDataset:
     if loaded is None:
+        log.warning(
+            "%s: no archive under %s — training on the SYNTHETIC seeded "
+            "32x32x3 stand-in (160 records per client), not the real images",
+            name, data_dir)
         return make_synthetic_classification(
             f"{name}(synthetic)", (32, 32, 3), classes, client_num_in_total,
             records_per_client=160, partition_method=partition_method,

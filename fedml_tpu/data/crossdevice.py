@@ -25,6 +25,7 @@ materialization idea with the stacked-cohort contract:
 
 from __future__ import annotations
 
+import logging
 import threading
 from typing import Callable, Optional, Sequence
 
@@ -32,6 +33,8 @@ import numpy as np
 
 from fedml_tpu.data import FedDataset, register_dataset
 from fedml_tpu.data.batching import pad_eval_pool
+
+log = logging.getLogger(__name__)
 
 
 class VirtualArray:
@@ -243,6 +246,10 @@ def load_stackoverflow_lr_full(
     O(client_num) counts + O(cohort) per round."""
     from fedml_tpu.data.stackoverflow import TAG_DIM, WORD_DIM
 
+    log.warning(
+        "stackoverflow_lr_full: SYNTHETIC cross-device stand-in — %d logical "
+        "clients of seeded %d-dim bag-of-words records, not the real h5",
+        client_num_in_total, WORD_DIM)
     return make_synthetic_crossdevice(
         "stackoverflow_lr_full", WORD_DIM, TAG_DIM, client_num_in_total,
         batch_size=batch_size, mean_records=20.0, max_records=64,

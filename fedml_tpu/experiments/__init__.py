@@ -33,11 +33,17 @@ ALGORITHMS = (
 
 
 def _bundle_for(config: FedConfig, ds):
+    import jax.numpy as jnp
+
     from fedml_tpu.models import create_model
 
+    # the module computes in the config's dtype (--dtype bfloat16 builds the
+    # bf16 module, not an f32 module fed bf16 batches); factories without a
+    # dtype knob swallow the keyword
     return create_model(
         config.model, ds.class_num,
         input_shape=ds.train_x.shape[2:] or None,
+        dtype=jnp.bfloat16 if config.dtype == "bfloat16" else jnp.float32,
     )
 
 

@@ -13,6 +13,14 @@ so the exact same per-rank entry deploys across machines — run it by hand
 (reference grpc_ipconfig.csv, grpc_comm_manager.py:59-60). This helper just
 automates the single-host case. See docs/deploy.md for the runbook.
 
+One process per chip: an accelerator belongs to the first process that
+touches JAX, and a second one that wants it hangs or dies. On a single
+host only rank 0 (the server — aggregation and evaluation) inherits this
+environment and may claim the host's chip; every worker rank is started
+with ``JAX_PLATFORMS=cpu``. The launcher itself never initialises a JAX
+backend. A rank started by hand on its own machine keeps that machine's
+device.
+
 All FedConfig flags pass through to every rank — including the wire
 reliability/chaos knobs (--wire_reliable, --chaos_seed, --chaos_drop,
 --chaos_dup, --chaos_delay_ms, --chaos_reorder, --chaos_crash_rank,
@@ -50,6 +58,12 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         result_json = argv[i:i + 2]
         del argv[i:i + 2]
 
+    # one process per chip (module docstring): the server keeps the
+    # inherited environment, the workers are pinned to the CPU backend
+    worker_env = dict(os.environ, JAX_PLATFORMS="cpu")
+    print(f"launch_edge: rank 0 (server) keeps this host's JAX device "
+          f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '<unset>')}); "
+          f"ranks 1..{n - 1} run with JAX_PLATFORMS=cpu", file=sys.stderr)
     procs = []
     try:
         for rank in range(n):
@@ -61,7 +75,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             procs.append(subprocess.Popen(
                 cmd,
                 stdout=None if rank == 0 else subprocess.DEVNULL,
-                env=os.environ.copy(),
+                env=os.environ.copy() if rank == 0 else worker_env,
             ))
         rcs = [p.wait() for p in procs]
     finally:

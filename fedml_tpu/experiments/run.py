@@ -14,6 +14,7 @@ from typing import Optional, Sequence
 
 from fedml_tpu.core.config import add_args, config_from_args
 from fedml_tpu.experiments import ALGORITHMS, run_experiment
+from fedml_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main(argv: Optional[Sequence[str]] = None, default_algorithm: str = "fedavg") -> dict:
@@ -32,6 +33,7 @@ def main(argv: Optional[Sequence[str]] = None, default_algorithm: str = "fedavg"
     result_json = ns.result_json
     del ns.algorithm, ns.result_json
     cfg = config_from_args(ns)
+    enable_compile_cache()
     result = run_experiment(cfg, algorithm)
     if result_json:
         with open(result_json, "w") as f:

@@ -457,12 +457,9 @@ class PulsePlane:
         rec = max(tables.values(),
                   key=lambda r: r["summary"]["gemm_flops_per_invocation"])
         if not self._peak_resolved:
-            try:
-                import jax
+            import jax
 
-                self._peak = _cost.peak_flops(jax.devices()[0])[0]
-            except Exception:  # pragma: no cover - devices always queryable
-                self._peak = None
+            self._peak = _cost.peak_flops(jax.devices()[0])[0]
             self._peak_resolved = True
         rf = _cost.roofline(rec["summary"], round_ms / 1e3, invocations=1,
                             peak=self._peak)

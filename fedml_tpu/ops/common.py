@@ -8,12 +8,8 @@ import jax
 def sds(shape, dtype, like):
     """ShapeDtypeStruct carrying the varying-manual-axes of ``like`` — under
     shard_map (the cross-silo mesh round) pallas outputs must declare how
-    they vary across the mesh; outside shard_map vma is empty and harmless.
-    The try/except shims over JAX versions without the ``vma`` kwarg."""
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
-    except (AttributeError, TypeError):
-        return jax.ShapeDtypeStruct(shape, dtype)
+    they vary across the mesh; outside shard_map vma is empty and harmless."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def interpret() -> bool:

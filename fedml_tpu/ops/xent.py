@@ -21,6 +21,11 @@ import jax.numpy as jnp
 
 from fedml_tpu.ops.attention import _pick_impl
 
+#: bytes the double-buffered logits tile may take. The kernel's block is a
+#: whole padded vocabulary row, so its VMEM grows with V; Mosaic's scoped
+#: limit on a v5e is 16 MiB (64 rows of V=32,768 in f32 is exactly over).
+_VMEM_TILE_BUDGET = 12 * 1024 * 1024
+
 
 def _xla_xent(logits, labels):
     logz = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
@@ -77,6 +82,12 @@ def _pallas_xent(logits, labels, block_n: int, block_v: int, interpret: bool):
         logits = jnp.pad(logits, ((0, 0), (0, v_pad - v)),
                          constant_values=-1e30)
     v = v_pad
+    # large vocabularies: fewer rows per block until the tile pair fits
+    # (halving keeps bn a divisor of n; the floor is one sublane tile)
+    min_rows = 8 if logits.dtype.itemsize >= 4 else 16
+    while (bn % 2 == 0 and bn // 2 >= min_rows
+           and 2 * bn * v * logits.dtype.itemsize > _VMEM_TILE_BUDGET):
+        bn //= 2
 
     out = pl.pallas_call(
         functools.partial(_xent_kernel, block_n=bn, block_v=bv),

@@ -117,7 +117,12 @@ def make_tp_lm_train_step(
     state inherits the shardings via ``tx.init`` on the sharded params) and
     pass batches with the batch axis on 'dp'. Returns
     ``step(variables, opt_state, x, y, mask, rng)``; use
-    ``attn_impl='xla'`` modules so attention stays partitionable.
+    ``attn_impl='xla'`` modules so attention stays partitionable, and the
+    loss below takes the XLA cross-entropy for the same reason: GSPMD
+    cannot partition a Mosaic kernel (on a four-chip v5e host the
+    ``impl='auto'`` loss raised "Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map." — PR 21; a CPU run
+    never sees it because 'auto' is XLA off-TPU).
     """
     from fedml_tpu.ops.xent import masked_cross_entropy
 
@@ -128,7 +133,7 @@ def make_tp_lm_train_step(
             vars_in = dict(variables)
             vars_in["params"] = params
             logits = module.apply(vars_in, x, train=True, rngs={"dropout": rng})
-            per = masked_cross_entropy(logits, y, mask)
+            per = masked_cross_entropy(logits, y, mask, impl="xla")
             cnt = jnp.sum(mask.astype(jnp.float32))
             return jnp.sum(per) / jnp.maximum(cnt, 1.0)
 

@@ -131,16 +131,20 @@ def test_parser_batched_dot():
     assert o["flops"] == pytest.approx(2 * 5 * 7 * 11 * 13)
 
 
-def test_peak_table_matches_bench_mfu_basis():
-    """The committed BENCH artifacts pin mfu_basis to ('v5 lite', 197e12);
-    the shared table must keep resolving the same entry."""
+def test_peak_table_is_keyed_by_exact_device_kind():
+    """The v5e's own device_kind resolves to its published 197 TFLOP/s; a
+    TPU kind nobody has run raises (never a neighbour's peak, never a
+    silent null MFU); off-TPU there is no peak and no MFU."""
 
     class Dev:
+        platform = "tpu"
         device_kind = "TPU v5 lite"
 
-    peak, entry = cost.peak_flops(Dev())
-    assert (peak, entry) == (197e12, "v5 lite")
-    assert cost.peak_flops(object())[0] is None   # CPU: no peak, no MFU
+    assert cost.peak_flops(Dev()) == (197e12, "TPU v5 lite")
+    Dev.device_kind = "TPU v5p"        # substring of nothing: exact keys only
+    with pytest.raises(cost.UnknownDeviceKind, match="TPU v5p"):
+        cost.peak_flops(Dev())
+    assert cost.peak_flops(jax.devices()[0]) == (None, None)   # CPU
 
 
 def test_summarize_flop_weighted_ceiling():

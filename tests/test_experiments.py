@@ -194,7 +194,6 @@ def test_bench_tiny_smoke(monkeypatch, capsys):
 
     monkeypatch.setenv("BENCH_SCALE", "tiny")
     monkeypatch.setenv("BENCH_MODEL", "lr")
-    monkeypatch.setenv("BENCH_NO_CACHE", "1")
     bench.main()
     line = capsys.readouterr().out.strip().splitlines()[-1]
     out = json.loads(line)
@@ -210,3 +209,25 @@ def test_bench_tiny_smoke(monkeypatch, capsys):
     prog = next(iter(roof["programs"].values()))
     assert prog["gemm_gflops_per_invocation"] > 0
     assert prog["out_lane_ceiling"] is not None
+
+
+def test_bundle_for_builds_the_module_in_the_config_dtype():
+    """--dtype bfloat16 through the CLI builds the bf16 module (the one
+    bench.py builds), not an f32 module fed bf16 batches."""
+    import types
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.core.config import FedConfig
+    from fedml_tpu.experiments import _bundle_for
+
+    ds = types.SimpleNamespace(class_num=10,
+                               train_x=np.zeros((1, 1, 32, 32, 3)))
+    bf16 = _bundle_for(FedConfig(dtype="bfloat16", model="resnet56"), ds)
+    assert bf16.module.dtype == jnp.bfloat16
+    f32 = _bundle_for(FedConfig(model="resnet56"), ds)
+    assert f32.module.dtype == jnp.float32
+    # a factory with no dtype knob swallows the keyword
+    assert _bundle_for(FedConfig(dtype="bfloat16", model="lr"), ds)
+

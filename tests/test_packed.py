@@ -61,19 +61,6 @@ def test_plan_covers_every_client_exactly_once():
     assert int(plan.emit.sum()) == len(counts)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing on jax 0.4.37 CPU (since PR 3): the divergence is "
-           "a few-ULP drift on the 'lr' DENSE dot path — this model has no "
-           "convs, so the old 'conv lowering' attribution was wrong. "
-           "Measured (ISSUE 9 revisit): kernel/bias differ by <=57 ULP "
-           "after E=2 epochs of momentum-0.9 steps, client-dependent "
-           "(ci=11 is bit-exact) — the lane program's IN-scan dynamic "
-           "batch gathers vs local_train's pre-scan gather+reshape give "
-           "XLA CPU different fusion/fma choices for the same step math, "
-           "and momentum amplifies the per-step ULP noise. Not resolved by "
-           "the fedpack joint lowering (docs/mfu_experiments.md H8): 'lr' "
-           "has no packed variant, so it keeps this vmap path.")
 def test_packed_single_lane_replays_local_train_bit_exact():
     """One lane, one client: acc_vars must equal count * local_train's
     result EXACTLY — the packed scan replays the canonical program."""

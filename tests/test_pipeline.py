@@ -102,11 +102,6 @@ def test_pipeline_two_steps_converge():
     assert float(l1) < float(l0)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing on jax 0.4.37 (since PR 3, verified per-file at "
-           "3c2579b): shard_map autodiff spec issue in the sp paths "
-           "(see CHANGES.md PR 2 note)")
 @pytest.mark.parametrize("sp_mode", ["ring", "ulysses"])
 def test_3d_dp_pp_sp_matches_single_device(sp_mode):
     """DP x PP x SP in one program: pipeline stages with sequence-parallel

@@ -55,11 +55,6 @@ def test_protocol_matches_fused():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing on jax 0.4.37 CPU mesh (since PR 3, verified "
-           "per-file at 3c2579b): sharded-vs-fused loss drifts to ~9e-4, "
-           "over the 1e-5 tolerance, from psum reduction order")
 def test_sharded_matches_fused():
     ds = _ds()
     P_parties = 2

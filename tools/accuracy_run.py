@@ -56,10 +56,9 @@ def main(argv):
     import jax
     import jax.numpy as jnp
 
-    if not os.environ.get("BENCH_NO_CACHE"):
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from fedml_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from fedml_tpu.algorithms.centralized import CentralizedTrainer
     from fedml_tpu.algorithms.fedavg import FedAvgAPI

@@ -2,8 +2,8 @@
 packed cross-silo mesh path, at two silo counts. (_bench_crosssilo warms
 two full passes — see docs/mfu_experiments.md H7 pitfall #2.)
 
-Each cell is a whole _bench_crosssilo run (the tunnel measurement
-protocol); the fixed per-round overhead is the weak-scaling intercept
+Each cell is a whole _bench_crosssilo run (warm-up passes, then one
+timed pass); the fixed per-round overhead is the weak-scaling intercept
 (docs/perf.md: T(c) = a + b*c, a ~ 27.5 ms at r4), so the super-step's
 win should be ~a*(H-1)/H per round, largest in relative terms at small c.
 
@@ -21,12 +21,9 @@ def main(argv):
     h = int(argv[0]) if argv else 5
     clients = [int(c) for c in argv[1:]] or [8, 32]
 
-    import jax
+    from fedml_tpu.utils.compile_cache import enable_compile_cache
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(os.path.dirname(__file__), "..",
-                                   ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    enable_compile_cache()
 
     from bench import _bench_crosssilo
 
