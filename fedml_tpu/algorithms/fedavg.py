@@ -33,6 +33,10 @@ from fedml_tpu.core.rng import round_key, sample_clients, seed_everything, serve
 from fedml_tpu.core.tasks import get_task
 from fedml_tpu.data import FedDataset
 from fedml_tpu.models import ModelBundle, create_model
+from fedml_tpu.obs.tracer import (SCOPE_AGGREGATE, SCOPE_PROLOGUE,
+                                  SCOPE_SERVER, SPAN_ENQUEUE, SPAN_H2D,
+                                  SPAN_MATERIALIZE, SPAN_PLAN, SPAN_ROUND,
+                                  SPAN_WAIT_INPUTS, span)
 from fedml_tpu.parallel.local import (
     LocalResult,
     finalize_metrics,
@@ -320,27 +324,32 @@ class FedAvgAPI:
         return jax.tree.map(lambda a: a.reshape((n,) + a.shape[2:]), res)
 
     def _round_body(self, variables, server_state, cx, cy, cm, counts, rng):
-        res = self._cohort_train(
-            variables, cx, cy, cm, counts, jax.random.split(rng, cx.shape[0])
-        )
+        with jax.named_scope(SCOPE_PROLOGUE):
+            keys = jax.random.split(rng, cx.shape[0])
+        res = self._cohort_train(variables, cx, cy, cm, counts, keys)
         return self._finish_round(variables, server_state, res, counts, rng)
 
     def _finish_round(self, variables, server_state, res, counts, rng):
         """Aggregate the cohort's local results + elastic-round guard +
         weighted train loss (shared by the single- and multi-group round
         programs)."""
-        new_vars, new_state = self.aggregate(
-            variables, res.variables, counts, res, server_key(rng), server_state
-        )
+        # aggregate() is the algorithm's own (a weighted mean, or a server
+        # optimizer on top of one): all of it is the aggregation layer here
+        with jax.named_scope(SCOPE_AGGREGATE):
+            new_vars, new_state = self.aggregate(
+                variables, res.variables, counts, res, server_key(rng), server_state
+            )
         # elastic rounds: failed clients enter with count 0 and drop out of
         # the weighted mean; an all-failed round is a full no-op — weights
         # AND server state (FedOpt moments etc.) roll back, else the server
         # optimizer would absorb the garbage zero-aggregate pseudo-gradient
-        total = jnp.sum(counts)
-        keep = total > 0
-        new_vars = jax.tree.map(lambda n, o: jnp.where(keep, n, o), new_vars, variables)
-        new_state = jax.tree.map(lambda n, o: jnp.where(keep, n, o), new_state, server_state)
-        train_loss = jnp.sum(res.train_loss * counts) / jnp.maximum(total, 1e-12)
+        with jax.named_scope(SCOPE_SERVER):
+            total = jnp.sum(counts)
+            keep = total > 0
+            new_vars = jax.tree.map(lambda n, o: jnp.where(keep, n, o), new_vars, variables)
+            new_state = jax.tree.map(lambda n, o: jnp.where(keep, n, o), new_state, server_state)
+        with jax.named_scope(SCOPE_AGGREGATE):
+            train_loss = jnp.sum(res.train_loss * counts) / jnp.maximum(total, 1e-12)
         if self._lens_armed:
             # fedlens lane (obs/lens.py): output-only reductions over the
             # stacked cohort result the program already holds — nothing
@@ -374,12 +383,13 @@ class FedAvgAPI:
 
         @jax.jit
         def round_step(variables, server_state, tx, ty, tm, tcounts, idx, live, rng):
-            cx = jnp.take(tx, idx, axis=0)
-            cy = jnp.take(ty, idx, axis=0)
-            cm = jnp.take(tm, idx, axis=0)
-            if bucket is not None:
-                cx, cy, cm = cx[:, :bucket], cy[:, :bucket], cm[:, :bucket]
-            counts = jnp.take(tcounts, idx, axis=0) * live
+            with jax.named_scope(SCOPE_PROLOGUE):
+                cx = jnp.take(tx, idx, axis=0)
+                cy = jnp.take(ty, idx, axis=0)
+                cm = jnp.take(tm, idx, axis=0)
+                if bucket is not None:
+                    cx, cy, cm = cx[:, :bucket], cy[:, :bucket], cm[:, :bucket]
+                counts = jnp.take(tcounts, idx, axis=0) * live
             return body(variables, server_state, cx, cy, cm, counts, rng)
 
         return round_step
@@ -449,18 +459,22 @@ class FedAvgAPI:
 
         @jax.jit
         def round_step(variables, server_state, tx, ty, tm, tcounts, idx, live, pos, rng):
-            keys = jax.random.split(rng, cohort)[pos]
+            with jax.named_scope(SCOPE_PROLOGUE):
+                keys = jax.random.split(rng, cohort)[pos]
             parts = []
             for start, size, bucket in zip(starts, sizes, buckets):
                 sl = slice(start, start + size)
-                idx_g = idx[sl]
-                cx = jnp.take(tx, idx_g, axis=0)[:, :bucket]
-                cy = jnp.take(ty, idx_g, axis=0)[:, :bucket]
-                cm = jnp.take(tm, idx_g, axis=0)[:, :bucket]
-                cnt_g = jnp.take(tcounts, idx_g, axis=0) * live[sl]
-                parts.append(cohort_train(variables, cx, cy, cm, cnt_g, keys[sl]))
-            res = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-            counts = jnp.take(tcounts, idx, axis=0) * live
+                with jax.named_scope(SCOPE_PROLOGUE):
+                    idx_g = idx[sl]
+                    cx = jnp.take(tx, idx_g, axis=0)[:, :bucket]
+                    cy = jnp.take(ty, idx_g, axis=0)[:, :bucket]
+                    cm = jnp.take(tm, idx_g, axis=0)[:, :bucket]
+                    cnt_g = jnp.take(tcounts, idx_g, axis=0) * live[sl]
+                    keys_g = keys[sl]
+                parts.append(cohort_train(variables, cx, cy, cm, cnt_g, keys_g))
+            with jax.named_scope(SCOPE_AGGREGATE):
+                res = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
+                counts = jnp.take(tcounts, idx, axis=0) * live
             return finish(variables, server_state, res, counts, rng)
 
         return round_step
@@ -618,22 +632,24 @@ class FedAvgAPI:
             out = packed(
                 variables, tx, ty, tm, rows, weights, rng, plan_arrays)
             acc, acc_w, acc_loss, _tau, extras = out[:5]
-            denom = jnp.maximum(acc_w, 1e-12)
-            agg = jax.tree.map(
-                lambda a, v: (a / denom).astype(v.dtype), acc, variables)
+            with jax.named_scope(SCOPE_AGGREGATE):
+                denom = jnp.maximum(acc_w, 1e-12)
+                agg = jax.tree.map(
+                    lambda a, v: (a / denom).astype(v.dtype), acc, variables)
             # the one shared post-aggregation tail (crosssilo.py): server
             # hook on the aggregate with the round's server key, elastic
             # all-failed rollback of weights AND server state
             new_vars, new_state = apply_server_and_rollback(
                 variables, agg, extras if has_extras else None, acc_w,
                 server_state, rng, server_update)
-            if lens_on:
-                from fedml_tpu.obs.lens import packed_lens
+            with jax.named_scope(SCOPE_AGGREGATE):
+                if lens_on:
+                    from fedml_tpu.obs.lens import packed_lens
 
-                upd, lf, ll, mw = out[5]
-                return (new_vars, new_state, acc_loss / denom,
-                        packed_lens(upd, lf, ll, mw))
-            return new_vars, new_state, acc_loss / denom
+                    upd, lf, ll, mw = out[5]
+                    return (new_vars, new_state, acc_loss / denom,
+                            packed_lens(upd, lf, ll, mw))
+                return new_vars, new_state, acc_loss / denom
 
         # fedcost packing hint (obs/cost.attribute_program): the joint
         # form's block-diag dots stream n_lanes x the useful FLOPs; the
@@ -658,29 +674,33 @@ class FedAvgAPI:
         program, never a vmap fallback."""
         if not self._packing_supported():
             return None
-        plan = self._packed_plan(sampled)
-        if plan is None:
-            return None
+        with span(SPAN_PLAN, round=round_idx):
+            plan = self._packed_plan(sampled)
+            if plan is None:
+                return None
+            counts = np.asarray(self.dataset.train_counts, np.float32)[sampled]
+            weights = (counts if live is None
+                       else counts * np.asarray(live, np.float32))
+            active = self._client_active
+            if active is None:
+                from fedml_tpu.parallel.packed import plan_arrays_tuple
+
+                plan_arrays = plan_arrays_tuple(plan)
+            else:
+                from fedml_tpu.parallel.packed import mask_plan_arrays
+
+                plan_arrays = mask_plan_arrays(
+                    plan,
+                    np.asarray(active, np.float32)[sampled][plan.member_pos])
         key = plan.shape_key
         step = self._lru_step(self._packed_steps, key,
                               lambda: self.build_round_step_packed(key),
                               "packed_step")
-        counts = np.asarray(self.dataset.train_counts, np.float32)[sampled]
-        weights = counts if live is None else counts * np.asarray(live, np.float32)
-        active = self._client_active
-        if active is None:
-            from fedml_tpu.parallel.packed import plan_arrays_tuple
-
-            plan_arrays = plan_arrays_tuple(plan)
-        else:
-            from fedml_tpu.parallel.packed import mask_plan_arrays
-
-            plan_arrays = mask_plan_arrays(
-                plan, np.asarray(active, np.float32)[sampled][plan.member_pos])
         tx, ty, tm, _tc = self._dev_train
-        out = step(self.variables, self.server_state, tx, ty, tm,
-                   jnp.asarray(sampled, jnp.int32), jnp.asarray(weights),
-                   rk, tuple(jnp.asarray(a) for a in plan_arrays))
+        with span(SPAN_ENQUEUE, round=round_idx):
+            out = step(self.variables, self.server_state, tx, ty, tm,
+                       jnp.asarray(sampled, jnp.int32), jnp.asarray(weights),
+                       rk, tuple(jnp.asarray(a) for a in plan_arrays))
         if len(out) == 4:
             # packed_lens flattens [n_lanes, k_max] in member_pos order;
             # padding slots (member_valid 0) and dead/exited members
@@ -847,32 +867,20 @@ class FedAvgAPI:
         (fanned out over the cohort's clients on ``pool``), then ship
         host->device — all while the in-flight round computes. Returns the
         device-resident payload plus stage timings (round_stats)."""
-        from fedml_tpu.obs import tracer_if_sampled
-
-        # the prefetch spans belong to the round they build for, so they
-        # follow that round's head-sampling verdict (same pure function)
-        tr = tracer_if_sampled(0, round_idx)
+        # the prefetch spans carry the round they build FOR (and follow
+        # its head-sampling verdict in the tracer's ring). They live on the
+        # prefetcher's background threads — in the timeline they sit beside
+        # (not under) the consuming round, which is exactly the overlap the
+        # pipeline exists to create
         t0 = time.perf_counter()
-        if tr is None:
+        with span(SPAN_MATERIALIZE, round=round_idx):
             cx, cy, cm, counts = self._host_round_inputs(
                 round_idx, pool, n_chunks=getattr(pool, "_max_workers", 0))
-            t1 = time.perf_counter()
+        t1 = time.perf_counter()
+        with span(SPAN_H2D, round=round_idx):
             payload = (jax.device_put(cx), jax.device_put(cy),
                        jax.device_put(cm), jax.device_put(counts))
             jax.block_until_ready(payload)
-        else:
-            # these spans live on the prefetcher's background threads — in
-            # the timeline they sit beside (not under) the consuming round,
-            # which is exactly the overlap the pipeline exists to create
-            with tr.span("materialize", cat="prefetch",
-                         args={"round": round_idx}):
-                cx, cy, cm, counts = self._host_round_inputs(
-                    round_idx, pool, n_chunks=getattr(pool, "_max_workers", 0))
-            t1 = time.perf_counter()
-            with tr.span("h2d", cat="prefetch", args={"round": round_idx}):
-                payload = (jax.device_put(cx), jax.device_put(cy),
-                           jax.device_put(cm), jax.device_put(counts))
-                jax.block_until_ready(payload)
         t2 = time.perf_counter()
         return payload, {"materialize_ms": (t1 - t0) * 1e3,
                          "h2d_ms": (t2 - t1) * 1e3}
@@ -1025,28 +1033,16 @@ class FedAvgAPI:
         chunks_per_round + chunk — the CohortPrefetcher speculates over
         this monotone sequence exactly as it does over rounds, so its
         in-flight memory is depth x ONE CHUNK, never a whole cohort."""
-        from fedml_tpu.obs import tracer_if_sampled
-
         C = self._stream_chunks_per_round
         r, ci = divmod(gidx, C)
-        tr = tracer_if_sampled(0, r)
         t0 = time.perf_counter()
-        if tr is None:
+        with span(SPAN_MATERIALIZE, round=r, chunk=ci):
             payload_np, meta = self._stream_chunk_inputs(
                 r, ci, pool, n_chunks=getattr(pool, "_max_workers", 0))
-            t1 = time.perf_counter()
+        t1 = time.perf_counter()
+        with span(SPAN_H2D, round=r, chunk=ci):
             payload = tuple(jax.device_put(a) for a in payload_np)
             jax.block_until_ready(payload)
-        else:
-            with tr.span("materialize", cat="prefetch",
-                         args={"round": r, "chunk": ci}):
-                payload_np, meta = self._stream_chunk_inputs(
-                    r, ci, pool, n_chunks=getattr(pool, "_max_workers", 0))
-            t1 = time.perf_counter()
-            with tr.span("h2d", cat="prefetch",
-                         args={"round": r, "chunk": ci}):
-                payload = tuple(jax.device_put(a) for a in payload_np)
-                jax.block_until_ready(payload)
         t2 = time.perf_counter()
         return (payload, meta), {"materialize_ms": (t1 - t0) * 1e3,
                                  "h2d_ms": (t2 - t1) * 1e3}
@@ -1083,17 +1079,19 @@ class FedAvgAPI:
 
         def chunk_step(variables, acc, acc_w, acc_loss, cx, cy, cm, counts,
                        w_norm, rng):
-            keys = jax.random.split(rng, cohort)[start:start + size]
+            with jax.named_scope(SCOPE_PROLOGUE):
+                keys = jax.random.split(rng, cohort)[start:start + size]
             res = cohort_train(variables, cx, cy, cm, counts, keys)
 
             def wadd(a, x):
                 wb = w_norm.reshape((-1,) + (1,) * (x.ndim - 1))
                 return a + jnp.sum(x.astype(jnp.float32) * wb, axis=0)
 
-            acc = jax.tree.map(wadd, acc, res.variables)
-            w = counts.astype(jnp.float32)
-            return (acc, acc_w + jnp.sum(w),
-                    acc_loss + jnp.sum(res.train_loss * w))
+            with jax.named_scope(SCOPE_AGGREGATE):
+                acc = jax.tree.map(wadd, acc, res.variables)
+                w = counts.astype(jnp.float32)
+                return (acc, acc_w + jnp.sum(w),
+                        acc_loss + jnp.sum(res.train_loss * w))
 
         if not self.config.donate:
             return jax.jit(chunk_step)
@@ -1130,10 +1128,11 @@ class FedAvgAPI:
                        rng, plan_arrays):
             a, w, l, _tau, _extras = packed(
                 variables, cx, cy, cm, rows, counts, rng, plan_arrays)
-            acc = jax.tree.map(
-                lambda s, p: s + p.astype(jnp.float32), acc, a)
-            return acc, acc_w + w.astype(jnp.float32), \
-                acc_loss + l.astype(jnp.float32)
+            with jax.named_scope(SCOPE_AGGREGATE):
+                acc = jax.tree.map(
+                    lambda s, p: s + p.astype(jnp.float32), acc, a)
+                return acc, acc_w + w.astype(jnp.float32), \
+                    acc_loss + l.astype(jnp.float32)
 
         if not self.config.donate:
             return jax.jit(chunk_step)
@@ -1148,21 +1147,23 @@ class FedAvgAPI:
         if self._stream_finish_fn is None:
             @jax.jit
             def finish_vmap(variables, acc, acc_w, acc_loss):
-                keep = acc_w > 0
-                new_vars = jax.tree.map(
-                    lambda a, v: jnp.where(keep, a.astype(v.dtype), v),
-                    acc, variables)
-                return new_vars, acc_loss / jnp.maximum(acc_w, 1e-12)
+                with jax.named_scope(SCOPE_AGGREGATE):
+                    keep = acc_w > 0
+                    new_vars = jax.tree.map(
+                        lambda a, v: jnp.where(keep, a.astype(v.dtype), v),
+                        acc, variables)
+                    return new_vars, acc_loss / jnp.maximum(acc_w, 1e-12)
 
             @jax.jit
             def finish_packed(variables, acc, acc_w, acc_loss):
-                denom = jnp.maximum(acc_w, 1e-12)
-                keep = acc_w > 0
-                new_vars = jax.tree.map(
-                    lambda a, v: jnp.where(keep, (a / denom).astype(v.dtype),
-                                           v),
-                    acc, variables)
-                return new_vars, acc_loss / denom
+                with jax.named_scope(SCOPE_AGGREGATE):
+                    denom = jnp.maximum(acc_w, 1e-12)
+                    keep = acc_w > 0
+                    new_vars = jax.tree.map(
+                        lambda a, v: jnp.where(
+                            keep, (a / denom).astype(v.dtype), v),
+                        acc, variables)
+                    return new_vars, acc_loss / denom
 
             self._stream_finish_fn = (finish_vmap, finish_packed)
         return self._stream_finish_fn[1 if packed else 0]
@@ -1174,9 +1175,10 @@ class FedAvgAPI:
         server memory is ONE f32 model sum regardless of cohort size."""
         c = self.config
         rk = round_key(self.root_key, round_idx)
-        sampled, live, bucket = self._round_plan(round_idx, record=True)
-        self._stash_plan(round_idx, sampled, live)
-        spec = self._stream_chunk_spec(len(sampled))
+        with span(SPAN_PLAN, round=round_idx):
+            sampled, live, bucket = self._round_plan(round_idx, record=True)
+            self._stash_plan(round_idx, sampled, live)
+            spec = self._stream_chunk_spec(len(sampled))
         C = len(spec)
         cohort_n = len(sampled)
         packed = self._stream_packed_active()
@@ -1188,13 +1190,15 @@ class FedAvgAPI:
         mat_ms = h2d_ms = wait_ms = compute_ms = 0.0
         for ci, (start, size) in enumerate(spec):
             if pf is not None:
-                (payload, meta), stages, w_ms = pf.pop(round_idx * C + ci)
+                with span(SPAN_WAIT_INPUTS, round=round_idx, chunk=ci):
+                    (payload, meta), stages, w_ms = pf.pop(round_idx * C + ci)
                 mat_ms += stages["materialize_ms"]
                 h2d_ms += stages["h2d_ms"]
                 wait_ms += w_ms
             else:
                 t0 = time.perf_counter()
-                payload, meta = self._stream_chunk_inputs(round_idx, ci)
+                with span(SPAN_MATERIALIZE, round=round_idx, chunk=ci):
+                    payload, meta = self._stream_chunk_inputs(round_idx, ci)
                 dt = (time.perf_counter() - t0) * 1e3
                 mat_ms += dt
                 wait_ms += dt    # serial: the host stage is fully exposed
@@ -1204,22 +1208,24 @@ class FedAvgAPI:
                 from fedml_tpu.parallel.packed import (plan_arrays_tuple,
                                                        plan_packing)
 
-                raw = self._counts_view(np.float64)[
-                    sampled[start:start + size]]
-                plan = plan_packing(
-                    raw, c.batch_size, c.epochs, c.pack_lanes,
-                    t_quantum=max(1, c.bucket_quantum_batches // 4))
+                with span(SPAN_PLAN, round=round_idx, chunk=ci):
+                    raw = self._counts_view(np.float64)[
+                        sampled[start:start + size]]
+                    plan = plan_packing(
+                        raw, c.batch_size, c.epochs, c.pack_lanes,
+                        t_quantum=max(1, c.bucket_quantum_batches // 4))
                 key = ("p", cohort_n, start, size, plan.shape_key)
                 step = self._lru_step(
                     self._stream_steps, key,
                     lambda: self.build_round_step_stream_packed(
                         cohort_n, start, size, plan.shape_key),
                     "stream_step")
-                acc, acc_w, acc_loss = step(
-                    self.variables, acc, acc_w, acc_loss, cx, cy, cm,
-                    jnp.asarray(counts), rk,
-                    tuple(jnp.asarray(a)
-                          for a in plan_arrays_tuple(plan)))
+                with span(SPAN_ENQUEUE, round=round_idx, chunk=ci):
+                    acc, acc_w, acc_loss = step(
+                        self.variables, acc, acc_w, acc_loss, cx, cy, cm,
+                        jnp.asarray(counts), rk,
+                        tuple(jnp.asarray(a)
+                              for a in plan_arrays_tuple(plan)))
             else:
                 key = ("v", cohort_n, start, size, meta[3])
                 step = self._lru_step(
@@ -1227,9 +1233,10 @@ class FedAvgAPI:
                     lambda: self.build_round_step_stream_chunk(
                         cohort_n, start, size),
                     "stream_step")
-                acc, acc_w, acc_loss = step(
-                    self.variables, acc, acc_w, acc_loss, cx, cy, cm,
-                    jnp.asarray(counts), jnp.asarray(w_norm), rk)
+                with span(SPAN_ENQUEUE, round=round_idx, chunk=ci):
+                    acc, acc_w, acc_loss = step(
+                        self.variables, acc, acc_w, acc_loss, cx, cy, cm,
+                        jnp.asarray(counts), jnp.asarray(w_norm), rk)
             compute_ms += (time.perf_counter() - t0) * 1e3
         self.variables, train_loss = self._stream_finish(packed)(
             self.variables, acc, acc_w, acc_loss)
@@ -1266,12 +1273,13 @@ class FedAvgAPI:
         the span measures DISPATCH (+ trace/compile on a program's first
         call) — the tracer never forces a device sync."""
         from fedml_tpu.obs import tracer_if_sampled
+        from fedml_tpu.obs.tracer import NOOP_SPAN
 
         tr = tracer_if_sampled(0, round_idx)
-        if tr is None:
-            return step(*args)
-        with tr.span("mesh_step", cat="device",
-                     args={"round": round_idx, "path": path}):
+        ring = NOOP_SPAN if tr is None else tr.span(
+            "mesh_step", cat="device",
+            args={"round": round_idx, "path": path})
+        with span(SPAN_ENQUEUE, round=round_idx, path=path), ring:
             return step(*args)
 
     def close(self) -> None:
@@ -1296,8 +1304,10 @@ class FedAvgAPI:
 
         THE traced wrapper: every paradigm's round logic lives in
         ``_run_round_inner`` (subclasses override THAT, never this — the
-        fedlint ``trace-coverage`` rule enforces it), so one span per round
-        plus the round-boundary device-memory sample cover the whole zoo.
+        fedlint ``trace-coverage`` rule enforces it), so one ``fedml/round``
+        span per round (always a profiler annotation; a ring record too
+        under ``--trace_dir``) plus the round-boundary device-memory sample
+        cover the whole zoo.
         The fedpulse plane rides the same wrapper: with ``--pulse_path``
         set, every round feeds the per-client profiler and appends one
         snapshot to the pulse stream — both gates are one global read when
@@ -1308,22 +1318,14 @@ class FedAvgAPI:
         from fedml_tpu.obs import (pulse_if_enabled, sample_device_memory,
                                    tracer_if_sampled)
 
-        tr = tracer_if_sampled(0, round_idx)
         pulse = pulse_if_enabled()
-        sched = self._cohort_sched
-        if tr is None and pulse is None:
-            out = self._run_round_inner(round_idx)
-            if sched.wants_notify:
-                sched.notify_round_done(round_idx)
-            return out
         t0 = time.perf_counter()
-        if tr is None:
+        with span(SPAN_ROUND, round=round_idx):
             out = self._run_round_inner(round_idx)
-        else:
-            with tr.span("round", cat="round", args={"round": round_idx}):
-                out = self._run_round_inner(round_idx)
-            if getattr(self.config, "trace_device_sampler", True):
-                sample_device_memory(tr, round_idx)
+        tr = tracer_if_sampled(0, round_idx)
+        if tr is not None and getattr(self.config, "trace_device_sampler",
+                                      True):
+            sample_device_memory(tr, round_idx)
         if pulse is not None:
             # with async_rounds `out` is an un-synced device scalar and the
             # wall measured dispatch; the plane never float()s it (that
@@ -1332,6 +1334,7 @@ class FedAvgAPI:
                                out, (time.perf_counter() - t0) * 1e3)
         # fedsched boundary: snapshot the profiler AFTER this round's pulse
         # feed, so the plan for round r + SCHED_LAG sees it
+        sched = self._cohort_sched
         if sched.wants_notify:
             sched.notify_round_done(round_idx)
         return out
@@ -1446,29 +1449,32 @@ class FedAvgAPI:
     def _run_round_inner(self, round_idx: int) -> "float | jax.Array":
         rk = round_key(self.root_key, round_idx)
         if self._dev_train is not None:
-            sampled, live, bucket = self._round_plan(round_idx, record=True)
-            self._stash_plan(round_idx, sampled, live)
-            live_np = (np.ones((len(sampled),), np.float32) if live is None
-                       else np.asarray(live, np.float32))
+            with span(SPAN_PLAN, round=round_idx):
+                sampled, live, bucket = self._round_plan(round_idx, record=True)
+                self._stash_plan(round_idx, sampled, live)
+                live_np = (np.ones((len(sampled),), np.float32) if live is None
+                           else np.asarray(live, np.float32))
             if self.config.pack_lanes > 0:
                 out = self._run_packed_round(sampled, live, rk, round_idx)
                 if out is not None:
                     self.variables, self.server_state, train_loss = out
                     return (train_loss if self.config.async_rounds
                             else float(train_loss))
-            plan = self._round_groups(sampled, live)
+            with span(SPAN_PLAN, round=round_idx):
+                plan = self._round_groups(sampled, live)
             if plan is not None:
                 perm, groups = plan
                 step = self._lru_step(
                     self._group_steps, groups,
                     lambda: self.build_round_step_gather_groups(groups),
                     "group_step")
-                out = step(
-                    self.variables, self.server_state, *self._dev_train,
-                    jnp.asarray(sampled[perm], jnp.int32),
-                    jnp.asarray(live_np[perm]),
-                    jnp.asarray(perm, jnp.int32), rk
-                )
+                with span(SPAN_ENQUEUE, round=round_idx):
+                    out = step(
+                        self.variables, self.server_state, *self._dev_train,
+                        jnp.asarray(sampled[perm], jnp.int32),
+                        jnp.asarray(live_np[perm]),
+                        jnp.asarray(perm, jnp.int32), rk
+                    )
                 self.variables, self.server_state, train_loss = \
                     self._lens_absorb(round_idx, out,
                                       np.asarray(sampled, np.int64)[perm],
@@ -1481,10 +1487,11 @@ class FedAvgAPI:
                     self._gather_steps, bucket,
                     lambda: self.build_round_step_gather(bucket),
                     "gather_step")
-            out = step(
-                self.variables, self.server_state, *self._dev_train,
-                jnp.asarray(sampled, jnp.int32), jnp.asarray(live_np), rk
-            )
+            with span(SPAN_ENQUEUE, round=round_idx):
+                out = step(
+                    self.variables, self.server_state, *self._dev_train,
+                    jnp.asarray(sampled, jnp.int32), jnp.asarray(live_np), rk
+                )
             self.variables, self.server_state, train_loss = \
                 self._lens_absorb(round_idx, out, sampled, live_np > 0)
         else:
@@ -1505,23 +1512,28 @@ class FedAvgAPI:
                     round_idx,
                     min(self.config.client_num_per_round,
                         self.dataset.num_clients), record=True)
-                (cx, cy, cm, counts), stages, wait_ms = pf.pop(round_idx)
+                with span(SPAN_WAIT_INPUTS, round=round_idx):
+                    (cx, cy, cm, counts), stages, wait_ms = pf.pop(round_idx)
                 step = self._host_pipeline_step()
             else:
                 t0 = time.perf_counter()
-                sampled, live, bucket = self._round_plan(round_idx, record=True)
-                self._stash_plan(round_idx, sampled, live)
-                cx, cy, cm, counts = self._host_round_inputs(
-                    round_idx, plan=(sampled, live, bucket))
+                with span(SPAN_PLAN, round=round_idx):
+                    sampled, live, bucket = self._round_plan(
+                        round_idx, record=True)
+                    self._stash_plan(round_idx, sampled, live)
+                with span(SPAN_MATERIALIZE, round=round_idx):
+                    cx, cy, cm, counts = self._host_round_inputs(
+                        round_idx, plan=(sampled, live, bucket))
                 mat_ms = (time.perf_counter() - t0) * 1e3
                 # serial: the host stages are fully exposed (wait == work)
                 stages, wait_ms = {"materialize_ms": mat_ms, "h2d_ms": 0.0}, mat_ms
                 step = self._round_step
             t0 = time.perf_counter()
-            out = step(
-                self.variables, self.server_state, cx, cy, cm,
-                jnp.asarray(counts, jnp.float32), rk
-            )
+            with span(SPAN_ENQUEUE, round=round_idx):
+                out = step(
+                    self.variables, self.server_state, cx, cy, cm,
+                    jnp.asarray(counts, jnp.float32), rk
+                )
             if len(out) == 4:
                 # host-path cohort order is the stashed plan's sampled
                 # order; the prefetcher stashes its plans too, so the id

@@ -12,7 +12,12 @@ assumes (arXiv:2303.01778):
   stage rows) is a :class:`CounterGroup` attached to it, so the existing
   public APIs become *views* over one store instead of four disjoint dicts.
 - :mod:`fedml_tpu.obs.tracer` — per-rank span tracer: monotonic
-  durations, ring-buffered events, allocation-free when disabled. Trace
+  durations, ring-buffered events, allocation-free when disabled. Its
+  ``span(name, **ids)`` is the round path's one span primitive: always a
+  ``jax.profiler.TraceAnnotation`` (so any profiler session sees the
+  program's ``fedml/...`` spans), and a ring record too under
+  ``--trace_dir``; the module also holds the table of span and device-scope
+  (``jax.named_scope``) names. Trace
   context piggybacks on ``comm/message.py`` envelopes so send spans stitch
   to recv spans across ranks and transports by message id.
 - :mod:`fedml_tpu.obs.export` — Perfetto/Chrome ``trace_event`` JSON and
@@ -102,6 +107,7 @@ from fedml_tpu.obs.tracer import (
     get_tracer,
     reset,
     set_process_index,
+    span,
     span_sampled,
     trace_filename,
     tracer_if_enabled,
@@ -144,6 +150,7 @@ __all__ = [
     "reset",
     "sample_device_memory",
     "set_process_index",
+    "span",
     "span_sampled",
     "timed_build",
     "trace_filename",

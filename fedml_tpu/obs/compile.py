@@ -31,7 +31,8 @@ import time
 from typing import Callable, Optional
 
 from fedml_tpu.obs.registry import CounterGroup, default_registry
-from fedml_tpu.obs.tracer import tracer_if_enabled
+from fedml_tpu.obs.tracer import (NOOP_SPAN, SPAN_BUILD, span,
+                                  tracer_if_enabled)
 
 _KEYS = ("hits", "misses", "build_ms", "first_call_ms")
 #: module-global strong ref: the registry only holds weakrefs, and compile
@@ -65,12 +66,14 @@ def timed_build(name: str, shape_key, builder: Callable) -> Callable:
     g = compile_counters()
     tr = tracer_if_enabled(0)
     t0 = time.perf_counter()
-    if tr is None:
+    # fedml/round/build: the profiler-clock span of a new program, around
+    # its construction here and around its first call below (where jax
+    # traces and XLA compiles); the ring's compile spans and the counters
+    # stay as they were
+    ring = NOOP_SPAN if tr is None else tr.span(
+        f"{name}:build", cat="compile", args={"shape_key": repr(shape_key)})
+    with span(SPAN_BUILD, program=name), ring:
         fn = builder()
-    else:
-        with tr.span(f"{name}:build", cat="compile",
-                     args={"shape_key": repr(shape_key)}):
-            fn = builder()
     # counters bump only once the builder has RETURNED a program: a raising
     # builder propagates with no partial misses/build_ms entry (the caller's
     # LRU never stores the step, so a retry is a fresh build, counted once)
@@ -85,12 +88,11 @@ def timed_build(name: str, shape_key, builder: Callable) -> Callable:
             return fn(*args)
         tr = tracer_if_enabled(0)
         t0 = time.perf_counter()
-        if tr is None:
+        ring = NOOP_SPAN if tr is None else tr.span(
+            f"{name}:first_call", cat="compile",
+            args={"shape_key": repr(shape_key)})
+        with span(SPAN_BUILD, program=name), ring:
             out = fn(*args)
-        else:
-            with tr.span(f"{name}:first_call", cat="compile",
-                         args={"shape_key": repr(shape_key)}):
-                out = fn(*args)
         # only a SUCCESSFUL first call records first_call_ms: a raise
         # propagates, the flag stays set, and the next invocation is timed
         # as the first (the compile genuinely happens on whichever call

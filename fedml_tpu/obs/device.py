@@ -3,7 +3,9 @@
 The ROADMAP gap this closes: device-side visibility used to require a
 separate ``--profile_dir`` run through the jax profiler. This sampler
 instead snapshots ``jax.local_devices()`` ``memory_stats()`` (bytes_in_use
-and the peak watermark) at ROUND BOUNDARIES and emits them as ``device``-
+and the peak watermark, and where the backend gives them ``bytes_reserved``
+/ ``peak_bytes_reserved``: the running program's scratch, which no
+``*_in_use`` figure holds) at ROUND BOUNDARIES and emits them as ``device``-
 category counter events, which the Perfetto export renders as a dedicated
 "devices" counter lane next to the span timeline.
 
@@ -53,6 +55,11 @@ def sample_device_memory(tr, round_idx: Optional[int] = None) -> dict:
         peak = ms.get("peak_bytes_in_use")
         if peak is not None:
             vals[f"d{d.id}/peak_bytes"] = int(peak)
+        # the running program's scratch is in no *_in_use figure: the TPU
+        # runtime keeps it in a region of its own (PERF.md, Memory)
+        for key in ("bytes_reserved", "peak_bytes_reserved"):
+            if ms.get(key) is not None:
+                vals[f"d{d.id}/{key}"] = int(ms[key])
     if not vals:
         rss = _host_rss_bytes()
         if rss is not None:

@@ -33,6 +33,15 @@ call sites gate through :func:`tracer_if_sampled`; sampled-out rounds skip
 span emission entirely while counters, pulse snapshots and sketch lanes
 still see every round — percentiles stay exact while spans stay bounded.
 
+The round path's ONE span primitive is :func:`span`: a
+``jax.profiler.TraceAnnotation`` (the profiler's runtime drops it unless a
+profiler session is running, so any ``jax.profiler.trace`` — the
+benchmark's traced run, an operator's ``--profile_dir`` — sees the
+program's spans with no switch in the program) that, with ``--trace_dir``
+set, also records into this tracer's ring. The names of every host span
+and of every device scope (``jax.named_scope`` inside the round programs)
+are the constants below: one table, read by ``benchmarks/trace/scopes.py``.
+
 Overhead contract (pinned by tests/test_trace.py):
 
 - disabled (the default): ``tracer_if_enabled(rank)`` is a module-global
@@ -54,6 +63,43 @@ import time
 import uuid
 from collections import deque
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
+
+# -- the names' table --------------------------------------------------------
+# Device scopes: ``jax.named_scope`` inside the jitted round programs. They
+# are metadata of the HLO (no op is added, no program split) and arrive in
+# the profiler trace as components of each op's ``tf_op`` path. Dots inside
+# a name, since ``/`` separates the path.
+#: stack cast, reshapes, key splits, member gathers, replay tables, opt init
+SCOPE_PROLOGUE = "fedml.prologue"
+#: the local-training ``lax.scan`` itself: the ``while``'s own time and
+#: whatever its body runs outside the five step scopes below
+SCOPE_STEP = "fedml.step"
+#: resets of variables / optimizer state / loss at a client's first step
+SCOPE_STEP_RESET = "fedml.step.reset"
+#: the batch's order slice and the ``take`` of its records
+SCOPE_STEP_GATHER = "fedml.step.gather"
+#: forward and backward (``jvp`` / ``transpose(jvp)`` tell them apart)
+SCOPE_STEP_TRAIN = "fedml.step.train"
+#: the client optimizer: ``tx.update`` + ``apply_updates``
+SCOPE_STEP_OPT = "fedml.step.opt"
+#: dead-step freeze, loss / weight accumulation, the emit into the sums
+SCOPE_STEP_EMIT = "fedml.step.emit"
+#: sums over lanes / clients (the ``psum`` on a mesh), division, cast back
+SCOPE_AGGREGATE = "fedml.aggregate"
+#: server update hook and the all-failed rollback
+SCOPE_SERVER = "fedml.server"
+
+# Host spans (:func:`span`), on the profiler's clock.
+SPAN_ROUND = "fedml/round"
+SPAN_PLAN = "fedml/round/plan"
+SPAN_BUILD = "fedml/round/build"
+SPAN_ENQUEUE = "fedml/round/enqueue"
+SPAN_WAIT_INPUTS = "fedml/round/wait_inputs"
+SPAN_MATERIALIZE = "fedml/prefetch/materialize"
+SPAN_H2D = "fedml/prefetch/h2d"
+
 
 def _now_us() -> int:
     # wall-clock µs for CROSS-PROCESS alignment of the per-rank files;
@@ -82,10 +128,10 @@ NOOP_SPAN = _NoopSpan()
 
 class _Span:
     __slots__ = ("_tr", "name", "cat", "args", "span_id", "parent_id",
-                 "_ts_us", "_t0", "_jax_ann")
+                 "_ts_us", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, args: Optional[dict],
-                 parent_id: Optional[int]):
+                 parent_id: Optional[int], ann=None):
         self._tr = tr
         self.name = name
         self.cat = cat
@@ -94,7 +140,8 @@ class _Span:
         self.parent_id = parent_id
         self._ts_us = 0
         self._t0 = 0.0
-        self._jax_ann = None
+        #: the profiler annotation :func:`span` opens with this ring record
+        self._ann = ann
 
     def set(self, key, value) -> None:
         if self.args is None:
@@ -107,17 +154,16 @@ class _Span:
         if self.parent_id is None and stack:
             self.parent_id = stack[-1]
         stack.append(self.span_id)
-        if tr._jax_bridge is not None:
-            self._jax_ann = tr._jax_bridge(f"{self.cat}/{self.name}")
-            self._jax_ann.__enter__()
+        if self._ann is not None:
+            self._ann.__enter__()
         self._ts_us = _now_us()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur_us = int((time.perf_counter() - self._t0) * 1e6)
-        if self._jax_ann is not None:
-            self._jax_ann.__exit__(*exc)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tr = self._tr
         stack = tr._stack()
         if stack and stack[-1] == self.span_id:
@@ -147,7 +193,6 @@ class Tracer:
         #: and closes at aggregate, in different handlers
         self._open: dict = {}
         self._open_lock = threading.Lock()
-        self._jax_bridge = None
         #: fedflight full-rate retrospective ring (obs/flight.py): when the
         #: flight recorder is armed, every event ALSO lands here — the head
         #: sampler keeps gating what streams, the recorder keeps everything
@@ -331,7 +376,6 @@ class _FlightShadowTracer(Tracer):
         super().__init__(rank=parent.rank, buffer_events=1,
                          trace_id=parent.trace_id, process=parent.process)
         self._parent = parent
-        self._jax_bridge = parent._jax_bridge
 
     def _next_id(self) -> int:
         return self._parent._next_id()
@@ -364,7 +408,6 @@ _TRACE_DIR: Optional[str] = None
 _BUFFER = 65536
 _TRACERS: dict[int, Tracer] = {}
 _TRACE_ID: Optional[str] = None
-_JAX_BRIDGE = False
 #: head-based span sampling: keep fraction + the seed the pure verdict
 #: hashes (defaults = keep everything, the pre-fedsketch behavior)
 _SAMPLE_RATE = 1.0
@@ -455,13 +498,13 @@ def _process_index() -> int:
 
 
 def configure(trace_dir: Optional[str], buffer_events: int = 65536,
-              jax_bridge: bool = False, trace_id: Optional[str] = None,
+              trace_id: Optional[str] = None,
               sample_rate: float = 1.0, sample_seed: int = 0) -> None:
     """Enable tracing into ``trace_dir`` (None disables). Existing
     per-rank tracers are kept so an in-flight run reconfiguring is safe.
     ``sample_rate``/``sample_seed`` drive :func:`span_sampled`'s
     deterministic head-based round sampling (1.0 = keep every round)."""
-    global _ENABLED, _TRACE_DIR, _BUFFER, _TRACE_ID, _JAX_BRIDGE
+    global _ENABLED, _TRACE_DIR, _BUFFER, _TRACE_ID
     global _SAMPLE_RATE, _SAMPLE_SEED
     if not 0.0 <= sample_rate <= 1.0:
         raise ValueError(
@@ -470,7 +513,6 @@ def configure(trace_dir: Optional[str], buffer_events: int = 65536,
         _TRACE_DIR = trace_dir
         _ENABLED = bool(trace_dir)
         _BUFFER = max(int(buffer_events), 1)
-        _JAX_BRIDGE = bool(jax_bridge)
         _TRACE_ID = trace_id or uuid.uuid4().hex[:16]
         _SAMPLE_RATE = float(sample_rate)
         _SAMPLE_SEED = int(sample_seed)
@@ -508,7 +550,6 @@ def configure_from(config) -> bool:
         return False
     configure(trace_dir,
               buffer_events=getattr(config, "trace_buffer_events", 65536),
-              jax_bridge=bool(getattr(config, "profile_dir", None)),
               # the run seed doubles as the trace seed: re-running the same
               # config samples the same rounds (BlazeFL-grade replays)
               sample_rate=getattr(config, "trace_sample_rate", 1.0),
@@ -534,13 +575,6 @@ def get_tracer(rank: int = 0) -> Tracer:
                                          process=_process_index())
             if _FLIGHT_RING_FACTORY is not None:
                 tr._flight_ring = _FLIGHT_RING_FACTORY(tr.rank, tr.process)
-            if _JAX_BRIDGE:
-                try:
-                    import jax
-
-                    tr._jax_bridge = jax.profiler.TraceAnnotation
-                except Exception:  # pragma: no cover - jax always present here
-                    tr._jax_bridge = None
         return tr
 
 
@@ -579,6 +613,29 @@ def tracer_if_sampled(rank: int = 0, round_idx: int = 0) -> Optional[Tracer]:
             shadow = tr._flight_shadow = _FlightShadowTracer(tr)
         return shadow
     return get_tracer(rank)
+
+
+def span(name: str, **ids):
+    """THE round-path span: a context manager around one layer boundary of
+    the round driver or the host data path (names: the ``SPAN_*`` table).
+
+    With the tracer off (the default) it is a ``TraceAnnotation(name,
+    **ids)`` and nothing else. With ``--trace_dir`` set the same span also
+    lands in rank 0's ring (name, start, end, parent, ``ids`` as args),
+    following the head-sampling verdict of ``ids["round"]``; the ring keeps
+    its ``cat`` / ``name`` split: ``fedml/round`` is (round, round),
+    ``fedml/prefetch/h2d`` is (prefetch, h2d). ``ids`` carries
+    ``round=<index>`` wherever the call site knows it, so the spans of one
+    round share an identifier, also across threads."""
+    ann = TraceAnnotation(name, **ids)
+    if not _ENABLED:
+        return ann
+    tr = tracer_if_sampled(0, ids.get("round", 0))
+    if tr is None:
+        return ann
+    parts = name.split("/")
+    parts = parts[1:] or parts
+    return _Span(tr, parts[-1], parts[0], ids or None, None, ann)
 
 
 def trace_filename(rank: int, process: int = 0) -> str:

@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from fedml_tpu.core.rng import server_key
+from fedml_tpu.obs.tracer import SCOPE_AGGREGATE, SCOPE_SERVER
 from fedml_tpu.parallel.local import LocalResult
 
 
@@ -119,20 +120,21 @@ def _make_mesh_finish(axis, client_transform, reduce_extras, server_update,
     local training consumed; ``variables0`` the replicated original)."""
 
     def finish(variables0, variables, server_state, res: LocalResult, counts, rng):
-        stacked = res.variables
-        if client_transform is not None:
-            stacked = client_transform(variables, stacked)
-        w = counts.astype(jnp.float32)
-        total = jax.lax.psum(jnp.sum(w), axis)
-        denom = jnp.maximum(total, 1e-12)
-        agg = weighted_psum_tree_mean(stacked, w, axis, denom)
-        extras = None
-        if reduce_extras is not None:
-            extras = jax.tree.map(
-                lambda x: jax.lax.psum(x, axis),
-                reduce_extras(variables, res, w),
-            )
-        loss = jax.lax.psum(jnp.sum(res.train_loss * w), axis) / denom
+        with jax.named_scope(SCOPE_AGGREGATE):
+            stacked = res.variables
+            if client_transform is not None:
+                stacked = client_transform(variables, stacked)
+            w = counts.astype(jnp.float32)
+            total = jax.lax.psum(jnp.sum(w), axis)
+            denom = jnp.maximum(total, 1e-12)
+            agg = weighted_psum_tree_mean(stacked, w, axis, denom)
+            extras = None
+            if reduce_extras is not None:
+                extras = jax.tree.map(
+                    lambda x: jax.lax.psum(x, axis),
+                    reduce_extras(variables, res, w),
+                )
+            loss = jax.lax.psum(jnp.sum(res.train_loss * w), axis) / denom
         new_vars, new_state = apply_server_and_rollback(
             variables0, agg, extras, total, server_state, rng, server_update)
         if lens:
@@ -180,15 +182,16 @@ def apply_server_and_rollback(variables0, agg, extras, total, server_state,
     full no-op — weights AND server state roll back (matching the
     simulation paradigm's _finish_round guard), else the server optimizer
     would absorb the garbage zero-aggregate pseudo-gradient."""
-    if server_update is not None:
-        new_vars, new_state = server_update(
-            variables0, agg, extras, total, server_state, server_key(rng)
-        )
-    else:
-        new_vars, new_state = agg, server_state
-    keep = total > 0
-    new_vars = jax.tree.map(lambda n, o: jnp.where(keep, n, o), new_vars, variables0)
-    new_state = jax.tree.map(lambda n, o: jnp.where(keep, n, o), new_state, server_state)
+    with jax.named_scope(SCOPE_SERVER):
+        if server_update is not None:
+            new_vars, new_state = server_update(
+                variables0, agg, extras, total, server_state, server_key(rng)
+            )
+        else:
+            new_vars, new_state = agg, server_state
+        keep = total > 0
+        new_vars = jax.tree.map(lambda n, o: jnp.where(keep, n, o), new_vars, variables0)
+        new_state = jax.tree.map(lambda n, o: jnp.where(keep, n, o), new_state, server_state)
     return new_vars, new_state
 
 

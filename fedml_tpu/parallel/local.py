@@ -28,6 +28,9 @@ import optax
 from fedml_tpu.core.pytree import Pytree, tree_dot, tree_sub
 from fedml_tpu.core.tasks import Task
 from fedml_tpu.models import ModelBundle
+from fedml_tpu.obs.tracer import (SCOPE_PROLOGUE, SCOPE_STEP,
+                                  SCOPE_STEP_EMIT, SCOPE_STEP_GATHER,
+                                  SCOPE_STEP_OPT, SCOPE_STEP_TRAIN)
 
 # Salt folded into each epoch key to derive the per-step batch keys. The
 # packed schedule (parallel/packed.py) replays each client's trajectory
@@ -111,29 +114,31 @@ def make_batch_sgd_step(
     """
 
     def batch_step(variables, opt_state, params0, bx, by, bm, bkey):
-        if compute_dtype is not None and jnp.issubdtype(bx.dtype, jnp.floating):
-            bx = bx.astype(compute_dtype)
+        with jax.named_scope(SCOPE_STEP_TRAIN):
+            if compute_dtype is not None and jnp.issubdtype(bx.dtype, jnp.floating):
+                bx = bx.astype(compute_dtype)
 
-        def loss_fn(p):
-            vars_in = dict(variables)
-            vars_in["params"] = p
-            logits, new_vars = bundle.apply_train(vars_in, bx, bkey)
-            l = task.loss(logits, by, bm)
-            if prox_mu:
-                d = tree_sub(p, params0)
-                l = l + 0.5 * prox_mu * tree_dot(d, d)
-            return l, new_vars
+            def loss_fn(p):
+                vars_in = dict(variables)
+                vars_in["params"] = p
+                logits, new_vars = bundle.apply_train(vars_in, bx, bkey)
+                l = task.loss(logits, by, bm)
+                if prox_mu:
+                    d = tree_sub(p, params0)
+                    l = l + 0.5 * prox_mu * tree_dot(d, d)
+                return l, new_vars
 
-        (l, new_vars), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            variables["params"]
-        )
-        if grad_clip:
-            gnorm = optax.global_norm(grads)
-            scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-12))
-            grads = jax.tree.map(lambda g: g * scale, grads)
-        updates, new_opt_state = tx.update(grads, opt_state, variables["params"])
-        out_vars = dict(new_vars)
-        out_vars["params"] = optax.apply_updates(variables["params"], updates)
+            (l, new_vars), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                variables["params"]
+            )
+            if grad_clip:
+                gnorm = optax.global_norm(grads)
+                scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-12))
+                grads = jax.tree.map(lambda g: g * scale, grads)
+        with jax.named_scope(SCOPE_STEP_OPT):
+            updates, new_opt_state = tx.update(grads, opt_state, variables["params"])
+            out_vars = dict(new_vars)
+            out_vars["params"] = optax.apply_updates(variables["params"], updates)
         return out_vars, new_opt_state, l
 
     return batch_step
@@ -179,28 +184,31 @@ def make_local_train_fn(
     def local_train(variables: dict, x, y, mask, count, rng) -> LocalResult:
         n_pad = x.shape[0]
         steps = n_pad // batch_size
-        params0 = variables["params"]
-        opt_state = tx.init(variables["params"])
-        # effective steps/epoch for this client's real data (traced scalar)
-        steps_real = jnp.ceil(count.astype(jnp.float32) / batch_size).astype(jnp.int32)
+        with jax.named_scope(SCOPE_PROLOGUE):
+            params0 = variables["params"]
+            opt_state = tx.init(variables["params"])
+            # effective steps/epoch for this client's real data (traced scalar)
+            steps_real = jnp.ceil(count.astype(jnp.float32) / batch_size).astype(jnp.int32)
 
-        if compute_dtype is not None and jnp.issubdtype(x.dtype, jnp.floating):
-            x_cast = x.astype(compute_dtype)
-        else:
-            x_cast = x
+            if compute_dtype is not None and jnp.issubdtype(x.dtype, jnp.floating):
+                x_cast = x.astype(compute_dtype)
+            else:
+                x_cast = x
 
         def epoch_fn(carry, ekey):
             variables, opt_state = carry
-            perm = jax.random.permutation(ekey, n_pad)
-            # stable-sort shuffled indices so real records come first: batches
-            # 0..steps_real-1 are the reference's real minibatches, later
-            # batches are pure padding and their steps get masked out.
-            order = perm[jnp.argsort(-mask[perm], stable=True)]
-            xs = x_cast[order].reshape((steps, batch_size) + x.shape[1:])
-            ys = y[order].reshape((steps, batch_size) + y.shape[1:])
-            ms = mask[order].reshape((steps, batch_size))
-            bkeys = jax.random.split(
-                jax.random.fold_in(ekey, EPOCH_KEY_SALT), steps)
+            with jax.named_scope(SCOPE_STEP_GATHER):
+                perm = jax.random.permutation(ekey, n_pad)
+                # stable-sort shuffled indices so real records come first:
+                # batches 0..steps_real-1 are the reference's real
+                # minibatches, later batches are pure padding and their
+                # steps get masked out.
+                order = perm[jnp.argsort(-mask[perm], stable=True)]
+                xs = x_cast[order].reshape((steps, batch_size) + x.shape[1:])
+                ys = y[order].reshape((steps, batch_size) + y.shape[1:])
+                ms = mask[order].reshape((steps, batch_size))
+                bkeys = jax.random.split(
+                    jax.random.fold_in(ekey, EPOCH_KEY_SALT), steps)
 
             def step_fn(carry, batch):
                 variables, opt_state = carry
@@ -218,23 +226,28 @@ def make_local_train_fn(
                         new, old,
                     )
 
-                new_opt_state = freeze_if_dead(new_opt_state, opt_state)
-                out_vars = dict(freeze_if_dead(new_vars, variables))
-                return (out_vars, new_opt_state), l * live
+                with jax.named_scope(SCOPE_STEP_EMIT):
+                    new_opt_state = freeze_if_dead(new_opt_state, opt_state)
+                    out_vars = dict(freeze_if_dead(new_vars, variables))
+                    return (out_vars, new_opt_state), l * live
 
             (variables, opt_state), losses = jax.lax.scan(
                 step_fn, (variables, opt_state),
                 (xs, ys, ms, bkeys, jnp.arange(steps)),
                 unroll=max(int(scan_unroll), 1),
             )
-            mean_loss = jnp.sum(losses) / jnp.maximum(steps_real.astype(jnp.float32), 1.0)
+            with jax.named_scope(SCOPE_STEP_EMIT):
+                mean_loss = jnp.sum(losses) / jnp.maximum(steps_real.astype(jnp.float32), 1.0)
             return (variables, opt_state), mean_loss
 
-        ekeys = jax.random.split(rng, epochs)
-        (variables, opt_state), ep_losses = jax.lax.scan(
-            epoch_fn, (variables, opt_state), ekeys
-        )
-        tau = (epochs * steps_real).astype(jnp.float32)
+        with jax.named_scope(SCOPE_PROLOGUE):
+            ekeys = jax.random.split(rng, epochs)
+        with jax.named_scope(SCOPE_STEP):
+            (variables, opt_state), ep_losses = jax.lax.scan(
+                epoch_fn, (variables, opt_state), ekeys
+            )
+        with jax.named_scope(SCOPE_STEP_EMIT):
+            tau = (epochs * steps_real).astype(jnp.float32)
         return LocalResult(variables, ep_losses[-1], tau, ep_losses[0])
 
     return local_train

@@ -34,6 +34,10 @@ import optax
 
 from fedml_tpu.core.tasks import Task
 from fedml_tpu.models import ModelBundle
+from fedml_tpu.obs.tracer import (SCOPE_AGGREGATE, SCOPE_PROLOGUE, SCOPE_STEP,
+                                  SCOPE_STEP_EMIT, SCOPE_STEP_GATHER,
+                                  SCOPE_STEP_OPT, SCOPE_STEP_RESET,
+                                  SCOPE_STEP_TRAIN)
 from fedml_tpu.parallel.local import (EPOCH_KEY_SALT as _EPOCH_KEY_SALT,
                                       make_batch_sgd_step, make_optimizer)
 
@@ -197,132 +201,141 @@ def make_lane_train(
         """One lane. x_flat/y_flat/m_flat: [C*n_pad, ...] flattened stacks
         (shared, unbatched); mask_rows [C, n_pad]; member_* are this lane's
         [k_max] arrays; per-step metadata [T]."""
-        params0 = variables0["params"]
-        opt_state0 = tx_opt.init(params0)
+        with jax.named_scope(SCOPE_PROLOGUE):
+            params0 = variables0["params"]
+            opt_state0 = tx_opt.init(params0)
 
-        # Exact replay of make_local_train_fn's per-epoch order and batch
-        # keys, per member (shared definition — see _member_replay_tables)
-        member_tables = _member_replay_tables(mask_rows, epochs, n_pad,
-                                              steps_full)
-        orders, bkeys = jax.vmap(member_tables)(member_keys, member_row)
+            # Exact replay of make_local_train_fn's per-epoch order and
+            # batch keys, per member (shared definition — see
+            # _member_replay_tables)
+            member_tables = _member_replay_tables(mask_rows, epochs, n_pad,
+                                                  steps_full)
+            orders, bkeys = jax.vmap(member_tables)(member_keys, member_row)
 
         def step_fn(carry, xs):
             (variables, opt_state, loss_acc, acc_vars, acc_w, acc_loss,
              acc_tau, acc_extras) = carry[:8]
             k, e, s, rs, em, lv = xs
-            variables = jax.tree.map(
-                lambda v, z: jnp.where(rs > 0, z, v), variables, variables0)
-            opt_state = jax.tree.map(
-                lambda v, z: jnp.where(rs > 0, z, v), opt_state, opt_state0)
-            loss_acc = jnp.where(rs > 0, 0.0, loss_acc)
-            if lens:
-                upd_stack, l_first, l_last, floss_acc = carry[8]
-                floss_acc = jnp.where(rs > 0, 0.0, floss_acc)
+            with jax.named_scope(SCOPE_STEP_RESET):
+                variables = jax.tree.map(
+                    lambda v, z: jnp.where(rs > 0, z, v), variables, variables0)
+                opt_state = jax.tree.map(
+                    lambda v, z: jnp.where(rs > 0, z, v), opt_state, opt_state0)
+                loss_acc = jnp.where(rs > 0, 0.0, loss_acc)
+                if lens:
+                    upd_stack, l_first, l_last, floss_acc = carry[8]
+                    floss_acc = jnp.where(rs > 0, 0.0, floss_acc)
 
-            row = member_row[k]
-            oseg = jax.lax.dynamic_slice(
-                orders, (k, e, s * bs), (1, 1, bs)).reshape(bs)
-            flat = row * n_pad + oseg
-            bx = jnp.take(x_flat, flat, axis=0)
-            by = jnp.take(y_flat, flat, axis=0)
-            bm = jnp.take(m_flat, flat, axis=0)
-            bkey = bkeys[k, e, s]
+            with jax.named_scope(SCOPE_STEP_GATHER):
+                row = member_row[k]
+                oseg = jax.lax.dynamic_slice(
+                    orders, (k, e, s * bs), (1, 1, bs)).reshape(bs)
+                flat = row * n_pad + oseg
+                bx = jnp.take(x_flat, flat, axis=0)
+                by = jnp.take(y_flat, flat, axis=0)
+                bm = jnp.take(m_flat, flat, axis=0)
+                bkey = bkeys[k, e, s]
 
+            # batch_step scopes itself (parallel/local.py): step.train with
+            # step.opt inside
             new_vars, new_opt, l = batch_step(
                 variables, opt_state, params0, bx, by, bm, bkey)
 
-            def freeze_if_dead(new, old):
-                return jax.tree.map(
-                    lambda n, o: lv * n + (1.0 - lv) * o
-                    if jnp.issubdtype(n.dtype, jnp.floating)
-                    else jnp.where(lv > 0, n, o),
-                    new, old,
-                )
+            with jax.named_scope(SCOPE_STEP_EMIT):
+                def freeze_if_dead(new, old):
+                    return jax.tree.map(
+                        lambda n, o: lv * n + (1.0 - lv) * o
+                        if jnp.issubdtype(n.dtype, jnp.floating)
+                        else jnp.where(lv > 0, n, o),
+                        new, old,
+                    )
 
-            new_opt = freeze_if_dead(new_opt, opt_state)
-            out_vars = dict(freeze_if_dead(new_vars, variables))
+                new_opt = freeze_if_dead(new_opt, opt_state)
+                out_vars = dict(freeze_if_dead(new_vars, variables))
 
-            lastep = (e == epochs - 1).astype(jnp.float32)
-            loss_acc = loss_acc + l * lv * lastep
+                lastep = (e == epochs - 1).astype(jnp.float32)
+                loss_acc = loss_acc + l * lv * lastep
 
-            w = member_w[k] * em
-            sr = jnp.maximum(steps_real[k].astype(jnp.float32), 1.0)
-            if lens:
-                # fedlens member scatter (obs/lens.py): each member emits
-                # exactly once, so .add at its slot is a masked set, and
-                # off-emit steps (em = 0) contribute exactly nothing — the
-                # same linear-in-w contract the accumulators above rely on.
-                # RAW update (pre-client_transform): a robust clip must not
-                # hide the attacker from the lens.
-                floss_acc = floss_acc + l * lv * (e == 0).astype(jnp.float32)
-                upd_stack = jax.tree.map(
-                    lambda b, v, p: b.at[k].add(
-                        em * (v.astype(jnp.float32) - p.astype(jnp.float32))),
-                    upd_stack, out_vars["params"], params0)
-                l_first = l_first.at[k].add(em * floss_acc / sr)
-                l_last = l_last.at[k].add(em * loss_acc / sr)
-            acc_out = out_vars
-            if client_transform is not None:
-                # hook contract is stacked-clients; singleton axis at emit
-                acc_out = jax.tree.map(
-                    lambda v: v[0],
-                    client_transform(
-                        variables0,
-                        jax.tree.map(lambda v: v[None], out_vars)))
-            acc_vars = jax.tree.map(lambda a, v: a + w * v, acc_vars, acc_out)
-            acc_w = acc_w + w
-            acc_loss = acc_loss + w * loss_acc / sr
-            acc_tau = acc_tau + w * epochs * sr
-            if reduce_extras is not None:
-                res1 = LocalResult(
-                    jax.tree.map(lambda v: v[None], out_vars),
-                    (loss_acc / sr)[None], (epochs * sr)[None])
-                # the hook returns WEIGHTED partial sums; w = 0 off-emit,
-                # so non-emit steps contribute exactly nothing. The hook
-                # (like client_transform above) COMPUTES every step even
-                # though only emit steps land — that is O(params) of
-                # elementwise work per step against the step's O(batch x
-                # model) training FLOPs, <0.1% for conv models; buffering
-                # emitted trees and hooking once per member would trade it
-                # for a k_max-sized model buffer per lane and more HBM
-                # traffic than it saves.
-                ex = reduce_extras(variables0, res1, w[None])
-                acc_extras = jax.tree.map(lambda a, b: a + b, acc_extras, ex)
-            out = (out_vars, new_opt, loss_acc, acc_vars, acc_w, acc_loss,
-                   acc_tau, acc_extras)
-            if lens:
-                out = out + ((upd_stack, l_first, l_last, floss_acc),)
+                w = member_w[k] * em
+                sr = jnp.maximum(steps_real[k].astype(jnp.float32), 1.0)
+                if lens:
+                    # fedlens member scatter (obs/lens.py): each member emits
+                    # exactly once, so .add at its slot is a masked set, and
+                    # off-emit steps (em = 0) contribute exactly nothing — the
+                    # same linear-in-w contract the accumulators above rely on.
+                    # RAW update (pre-client_transform): a robust clip must not
+                    # hide the attacker from the lens.
+                    floss_acc = floss_acc + l * lv * (e == 0).astype(jnp.float32)
+                    upd_stack = jax.tree.map(
+                        lambda b, v, p: b.at[k].add(
+                            em * (v.astype(jnp.float32) - p.astype(jnp.float32))),
+                        upd_stack, out_vars["params"], params0)
+                    l_first = l_first.at[k].add(em * floss_acc / sr)
+                    l_last = l_last.at[k].add(em * loss_acc / sr)
+                acc_out = out_vars
+                if client_transform is not None:
+                    # hook contract is stacked-clients; singleton axis at emit
+                    acc_out = jax.tree.map(
+                        lambda v: v[0],
+                        client_transform(
+                            variables0,
+                            jax.tree.map(lambda v: v[None], out_vars)))
+                acc_vars = jax.tree.map(lambda a, v: a + w * v, acc_vars, acc_out)
+                acc_w = acc_w + w
+                acc_loss = acc_loss + w * loss_acc / sr
+                acc_tau = acc_tau + w * epochs * sr
+                if reduce_extras is not None:
+                    res1 = LocalResult(
+                        jax.tree.map(lambda v: v[None], out_vars),
+                        (loss_acc / sr)[None], (epochs * sr)[None])
+                    # the hook returns WEIGHTED partial sums; w = 0 off-emit,
+                    # so non-emit steps contribute exactly nothing. The hook
+                    # (like client_transform above) COMPUTES every step even
+                    # though only emit steps land — that is O(params) of
+                    # elementwise work per step against the step's O(batch x
+                    # model) training FLOPs, <0.1% for conv models; buffering
+                    # emitted trees and hooking once per member would trade it
+                    # for a k_max-sized model buffer per lane and more HBM
+                    # traffic than it saves.
+                    ex = reduce_extras(variables0, res1, w[None])
+                    acc_extras = jax.tree.map(lambda a, b: a + b, acc_extras, ex)
+                out = (out_vars, new_opt, loss_acc, acc_vars, acc_w, acc_loss,
+                       acc_tau, acc_extras)
+                if lens:
+                    out = out + ((upd_stack, l_first, l_last, floss_acc),)
             return out, None
 
         # zeros DERIVED from inputs, not constants: under shard_map the
         # inputs are device-varying, and a constant-zero carry init would
         # type-clash with the varying carry the scan body produces
-        z = jnp.sum(member_w) * 0.0
-        acc0 = jax.tree.map(lambda v: v.astype(jnp.float32) * 0.0, variables0)
-        if reduce_extras is not None:
-            ex0 = reduce_extras(
-                variables0,
-                LocalResult(jax.tree.map(lambda v: (v * 0.0)[None], variables0),
-                            z[None], z[None]),
-                z[None])
-            acc_extras0 = jax.tree.map(lambda e: e * 0.0, ex0)
-        else:
-            acc_extras0 = {}
-        carry0 = (variables0, opt_state0, z, acc0, z, z, z, acc_extras0)
-        if lens:
-            # zeros derived from inputs (shard_map type consistency): the
-            # per-member update stack [k_max, *param] plus first/last mean
-            # losses [k_max]; same memory class as the vmap fallback's
-            # stacked per-client variables
-            zk = member_w * 0.0
-            upd0 = jax.tree.map(
-                lambda p: zk.reshape(zk.shape + (1,) * p.ndim)
-                * p.astype(jnp.float32)[None], params0)
-            carry0 = carry0 + ((upd0, zk, zk, z),)
-        final, _ = jax.lax.scan(
-            step_fn, carry0, (slot, epoch_a, sie, reset, emit, live),
-            unroll=max(int(scan_unroll), 1),
-        )
+        with jax.named_scope(SCOPE_PROLOGUE):
+            z = jnp.sum(member_w) * 0.0
+            acc0 = jax.tree.map(lambda v: v.astype(jnp.float32) * 0.0, variables0)
+            if reduce_extras is not None:
+                ex0 = reduce_extras(
+                    variables0,
+                    LocalResult(jax.tree.map(lambda v: (v * 0.0)[None], variables0),
+                                z[None], z[None]),
+                    z[None])
+                acc_extras0 = jax.tree.map(lambda e: e * 0.0, ex0)
+            else:
+                acc_extras0 = {}
+            carry0 = (variables0, opt_state0, z, acc0, z, z, z, acc_extras0)
+            if lens:
+                # zeros derived from inputs (shard_map type consistency): the
+                # per-member update stack [k_max, *param] plus first/last mean
+                # losses [k_max]; same memory class as the vmap fallback's
+                # stacked per-client variables
+                zk = member_w * 0.0
+                upd0 = jax.tree.map(
+                    lambda p: zk.reshape(zk.shape + (1,) * p.ndim)
+                    * p.astype(jnp.float32)[None], params0)
+                carry0 = carry0 + ((upd0, zk, zk, z),)
+        with jax.named_scope(SCOPE_STEP):
+            final, _ = jax.lax.scan(
+                step_fn, carry0, (slot, epoch_a, sie, reset, emit, live),
+                unroll=max(int(scan_unroll), 1),
+            )
         (_, _, _, acc_vars, acc_w, acc_loss, acc_tau, acc_extras) = final[:8]
         if lens:
             return (acc_vars, acc_w, acc_loss, acc_tau, acc_extras,
@@ -543,180 +556,188 @@ def make_packed_lanes_train(
     def lanes_train(variables0, x_flat, y_flat, m_flat, mask_rows,
                     member_row, member_keys, member_w, steps_real,
                     slot, epoch_a, sie, reset, emit, live):
-        L = slot.shape[0]
-        stack0 = stack_variables(variables0, L)
-        sparams0 = stack0["params"]
-        # per-LANE optimizer state: vmap(init) gives every optax leaf a
-        # leading [L] axis (adam's scalar count becomes [L]), so the
-        # reset/freeze masks below address adaptive state per lane
-        opt_state0 = jax.vmap(tx_opt.init)(sparams0)
+        with jax.named_scope(SCOPE_PROLOGUE):
+            L = slot.shape[0]
+            stack0 = stack_variables(variables0, L)
+            sparams0 = stack0["params"]
+            # per-LANE optimizer state: vmap(init) gives every optax leaf a
+            # leading [L] axis (adam's scalar count becomes [L]), so the
+            # reset/freeze masks below address adaptive state per lane
+            opt_state0 = jax.vmap(tx_opt.init)(sparams0)
 
-        # Exact replay of make_local_train_fn's per-epoch order and batch
-        # keys, per (lane, member) — the SAME shared definition the vmap
-        # form uses (_member_replay_tables), so the two lowerings cannot
-        # drift on the replay contract
-        member_tables = _member_replay_tables(mask_rows, epochs, n_pad,
-                                              steps_full)
-        orders, bkeys = jax.vmap(jax.vmap(member_tables))(
-            member_keys, member_row)     # [L,k_max,E,n_pad], [L,k_max,E,S]
+            # Exact replay of make_local_train_fn's per-epoch order and batch
+            # keys, per (lane, member) — the SAME shared definition the vmap
+            # form uses (_member_replay_tables), so the two lowerings cannot
+            # drift on the replay contract
+            member_tables = _member_replay_tables(mask_rows, epochs, n_pad,
+                                                  steps_full)
+            orders, bkeys = jax.vmap(jax.vmap(member_tables))(
+                member_keys, member_row)     # [L,k_max,E,n_pad], [L,k_max,E,S]
 
         def batch_step_packed(svars, sopt, bx, by, bm, bkey_l):
             """One joint minibatch step: per-lane losses summed so the grad
             of the stacked params IS the per-lane grads (the block weight's
             off-diagonal zeros are structural — ops/packed_conv)."""
 
-            def loss_fn(sp):
-                vars_in = dict(svars)
-                vars_in["params"] = sp
-                # the FULL [L] key vector: explicit-dropout packed twins
-                # draw lane l's mask from bkey_l[l] — the very key the
-                # vmap form's lane l consumes (non-dropout twins ignore it)
-                logits, new_vars = pb.apply_train(vars_in, bx, bkey_l)
-                per_lane = jax.vmap(task.loss)(logits, by, bm)      # [L]
-                if prox_mu:
-                    # per-LANE prox term, folded into per_lane so the
-                    # REPORTED loss matches the vmap form (whose batch_step
-                    # returns loss WITH prox); summing per-lane terms gives
-                    # the same total the grads need (== tree_dot(d, d))
-                    from fedml_tpu.core.pytree import tree_sub
-                    d = tree_sub(sp, sparams0)
-                    prox_l = sum(
-                        jnp.sum(jnp.square(g), axis=tuple(range(1, g.ndim)))
-                        for g in jax.tree.leaves(d))                # [L]
-                    per_lane = per_lane + 0.5 * prox_mu * prox_l
-                return jnp.sum(per_lane), (new_vars, per_lane)
+            with jax.named_scope(SCOPE_STEP_TRAIN):
+                def loss_fn(sp):
+                    vars_in = dict(svars)
+                    vars_in["params"] = sp
+                    # the FULL [L] key vector: explicit-dropout packed twins
+                    # draw lane l's mask from bkey_l[l] — the very key the
+                    # vmap form's lane l consumes (non-dropout twins ignore it)
+                    logits, new_vars = pb.apply_train(vars_in, bx, bkey_l)
+                    per_lane = jax.vmap(task.loss)(logits, by, bm)      # [L]
+                    if prox_mu:
+                        # per-LANE prox term, folded into per_lane so the
+                        # REPORTED loss matches the vmap form (whose batch_step
+                        # returns loss WITH prox); summing per-lane terms gives
+                        # the same total the grads need (== tree_dot(d, d))
+                        from fedml_tpu.core.pytree import tree_sub
+                        d = tree_sub(sp, sparams0)
+                        prox_l = sum(
+                            jnp.sum(jnp.square(g), axis=tuple(range(1, g.ndim)))
+                            for g in jax.tree.leaves(d))                # [L]
+                        per_lane = per_lane + 0.5 * prox_mu * prox_l
+                    return jnp.sum(per_lane), (new_vars, per_lane)
 
-            (_, (new_vars, per_lane)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(svars["params"])
-            if grad_clip:
-                # per-LANE clip (lane == one client's step), the joint form
-                # of the vmap path's per-lane optax.global_norm
-                sq = [jnp.sum(jnp.square(g), axis=tuple(range(1, g.ndim)))
-                      for g in jax.tree.leaves(grads)]
-                gnorm = jnp.sqrt(sum(sq))                            # [L]
-                scale = jnp.minimum(
-                    1.0, grad_clip / jnp.maximum(gnorm, 1e-12))
-                grads = jax.tree.map(
-                    lambda g: g * bcast(scale, g).astype(g.dtype), grads)
+                (_, (new_vars, per_lane)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(svars["params"])
+                if grad_clip:
+                    # per-LANE clip (lane == one client's step), the joint form
+                    # of the vmap path's per-lane optax.global_norm
+                    sq = [jnp.sum(jnp.square(g), axis=tuple(range(1, g.ndim)))
+                          for g in jax.tree.leaves(grads)]
+                    gnorm = jnp.sqrt(sum(sq))                            # [L]
+                    scale = jnp.minimum(
+                        1.0, grad_clip / jnp.maximum(gnorm, 1e-12))
+                    grads = jax.tree.map(
+                        lambda g: g * bcast(scale, g).astype(g.dtype), grads)
             # per-lane update mirrors the per-lane init: adaptive moments,
             # step counts and accumulators advance lane-by-lane exactly as
             # the vmap form's per-lane tx.update does
-            updates, new_opt = jax.vmap(tx_opt.update)(
-                grads, sopt, svars["params"])
-            out_vars = dict(new_vars)
-            out_vars["params"] = optax.apply_updates(
-                svars["params"], updates)
+            with jax.named_scope(SCOPE_STEP_OPT):
+                updates, new_opt = jax.vmap(tx_opt.update)(
+                    grads, sopt, svars["params"])
+                out_vars = dict(new_vars)
+                out_vars["params"] = optax.apply_updates(
+                    svars["params"], updates)
             return out_vars, new_opt, per_lane
 
         def step_fn(carry, xs):
             (svars, sopt, loss_acc, acc_vars, acc_w, acc_loss, acc_tau,
              acc_extras) = carry[:8]
             k, e, s, rs, em, lv = xs                    # each [L]
-            svars = jax.tree.map(
-                lambda v, z: jnp.where(bcast(rs, v) > 0, z, v), svars, stack0)
-            sopt = jax.tree.map(
-                lambda v, z: jnp.where(bcast(rs, v) > 0, z, v),
-                sopt, opt_state0)
-            loss_acc = jnp.where(rs > 0, 0.0, loss_acc)
-            if lens:
-                upd_stack, l_first, l_last, floss_acc = carry[8]
-                floss_acc = jnp.where(rs > 0, 0.0, floss_acc)
+            with jax.named_scope(SCOPE_STEP_RESET):
+                svars = jax.tree.map(
+                    lambda v, z: jnp.where(bcast(rs, v) > 0, z, v), svars, stack0)
+                sopt = jax.tree.map(
+                    lambda v, z: jnp.where(bcast(rs, v) > 0, z, v),
+                    sopt, opt_state0)
+                loss_acc = jnp.where(rs > 0, 0.0, loss_acc)
+                if lens:
+                    upd_stack, l_first, l_last, floss_acc = carry[8]
+                    floss_acc = jnp.where(rs > 0, 0.0, floss_acc)
 
-            rows = jnp.take_along_axis(member_row, k[:, None], axis=1)[:, 0]
-            oseg = jax.vmap(
-                lambda o, kk, ee, ss: jax.lax.dynamic_slice(
-                    o, (kk, ee, ss * bs), (1, 1, bs)).reshape(bs)
-            )(orders, k, e, s)                          # [L, bs]
-            flat = rows[:, None] * n_pad + oseg
-            bx = jnp.take(x_flat, flat, axis=0)
-            by = jnp.take(y_flat, flat, axis=0)
-            bm = jnp.take(m_flat, flat, axis=0)
-            bkey_l = jax.vmap(
-                lambda bk, kk, ee, ss: bk[kk, ee, ss])(bkeys, k, e, s)
+            with jax.named_scope(SCOPE_STEP_GATHER):
+                rows = jnp.take_along_axis(member_row, k[:, None], axis=1)[:, 0]
+                oseg = jax.vmap(
+                    lambda o, kk, ee, ss: jax.lax.dynamic_slice(
+                        o, (kk, ee, ss * bs), (1, 1, bs)).reshape(bs)
+                )(orders, k, e, s)                          # [L, bs]
+                flat = rows[:, None] * n_pad + oseg
+                bx = jnp.take(x_flat, flat, axis=0)
+                by = jnp.take(y_flat, flat, axis=0)
+                bm = jnp.take(m_flat, flat, axis=0)
+                bkey_l = jax.vmap(
+                    lambda bk, kk, ee, ss: bk[kk, ee, ss])(bkeys, k, e, s)
 
             new_vars, new_opt, per_lane = batch_step_packed(
                 svars, sopt, bx, by, bm, bkey_l)
 
-            def freeze_if_dead(new, old):
-                return jax.tree.map(
-                    lambda n, o: bcast(lv, n) * n + (1.0 - bcast(lv, n)) * o
-                    if jnp.issubdtype(n.dtype, jnp.floating)
-                    else jnp.where(bcast(lv, n) > 0, n, o),
-                    new, old,
-                )
+            with jax.named_scope(SCOPE_STEP_EMIT):
+                def freeze_if_dead(new, old):
+                    return jax.tree.map(
+                        lambda n, o: bcast(lv, n) * n + (1.0 - bcast(lv, n)) * o
+                        if jnp.issubdtype(n.dtype, jnp.floating)
+                        else jnp.where(bcast(lv, n) > 0, n, o),
+                        new, old,
+                    )
 
-            new_opt = freeze_if_dead(new_opt, sopt)
-            out_vars = dict(freeze_if_dead(new_vars, svars))
+                new_opt = freeze_if_dead(new_opt, sopt)
+                out_vars = dict(freeze_if_dead(new_vars, svars))
 
-            lastep = (e == epochs - 1).astype(jnp.float32)
-            loss_acc = loss_acc + per_lane * lv * lastep
+                lastep = (e == epochs - 1).astype(jnp.float32)
+                loss_acc = loss_acc + per_lane * lv * lastep
 
-            w = jnp.take_along_axis(member_w, k[:, None], axis=1)[:, 0] * em
-            sr = jnp.maximum(jnp.take_along_axis(
-                steps_real, k[:, None], axis=1)[:, 0].astype(jnp.float32),
-                1.0)
-            if lens:
-                # fedlens member scatter, joint form: lane l's member k[l]
-                # slot takes the masked set (each member emits once); same
-                # RAW-update/linear-in-emit contract as the vmap lane form
-                floss_acc = (floss_acc
-                             + per_lane * lv * (e == 0).astype(jnp.float32))
-                lidx = jnp.arange(k.shape[0])
-                upd_stack = jax.tree.map(
-                    lambda b, v, p: b.at[lidx, k].add(
-                        bcast(em, v)
-                        * (v.astype(jnp.float32) - p.astype(jnp.float32))),
-                    upd_stack, out_vars["params"], sparams0)
-                l_first = l_first.at[lidx, k].add(em * floss_acc / sr)
-                l_last = l_last.at[lidx, k].add(em * loss_acc / sr)
-            acc_out = out_vars
-            if client_transform is not None:
-                # the hook contract is stacked-clients; the joint form IS
-                # stacked — one call covers every lane
-                acc_out = client_transform(variables0, out_vars)
-            acc_vars = jax.tree.map(
-                lambda a, v: a + bcast(w, v) * v, acc_vars, acc_out)
-            acc_w = acc_w + w
-            acc_loss = acc_loss + w * loss_acc / sr
-            acc_tau = acc_tau + w * epochs * sr
-            if reduce_extras is not None:
-                # w = 0 off-emit, so non-emit lanes contribute exactly
-                # nothing (the same linear-in-w contract the vmap form
-                # relies on); the hook's return is already the lane sum
-                res = LocalResult(out_vars, loss_acc / sr, epochs * sr)
-                ex = reduce_extras(variables0, res, w)
-                acc_extras = jax.tree.map(
-                    lambda a, b: a + b, acc_extras, ex)
-            out = (out_vars, new_opt, loss_acc, acc_vars, acc_w, acc_loss,
-                   acc_tau, acc_extras)
-            if lens:
-                out = out + ((upd_stack, l_first, l_last, floss_acc),)
+                w = jnp.take_along_axis(member_w, k[:, None], axis=1)[:, 0] * em
+                sr = jnp.maximum(jnp.take_along_axis(
+                    steps_real, k[:, None], axis=1)[:, 0].astype(jnp.float32),
+                    1.0)
+                if lens:
+                    # fedlens member scatter, joint form: lane l's member k[l]
+                    # slot takes the masked set (each member emits once); same
+                    # RAW-update/linear-in-emit contract as the vmap lane form
+                    floss_acc = (floss_acc
+                                 + per_lane * lv * (e == 0).astype(jnp.float32))
+                    lidx = jnp.arange(k.shape[0])
+                    upd_stack = jax.tree.map(
+                        lambda b, v, p: b.at[lidx, k].add(
+                            bcast(em, v)
+                            * (v.astype(jnp.float32) - p.astype(jnp.float32))),
+                        upd_stack, out_vars["params"], sparams0)
+                    l_first = l_first.at[lidx, k].add(em * floss_acc / sr)
+                    l_last = l_last.at[lidx, k].add(em * loss_acc / sr)
+                acc_out = out_vars
+                if client_transform is not None:
+                    # the hook contract is stacked-clients; the joint form IS
+                    # stacked — one call covers every lane
+                    acc_out = client_transform(variables0, out_vars)
+                acc_vars = jax.tree.map(
+                    lambda a, v: a + bcast(w, v) * v, acc_vars, acc_out)
+                acc_w = acc_w + w
+                acc_loss = acc_loss + w * loss_acc / sr
+                acc_tau = acc_tau + w * epochs * sr
+                if reduce_extras is not None:
+                    # w = 0 off-emit, so non-emit lanes contribute exactly
+                    # nothing (the same linear-in-w contract the vmap form
+                    # relies on); the hook's return is already the lane sum
+                    res = LocalResult(out_vars, loss_acc / sr, epochs * sr)
+                    ex = reduce_extras(variables0, res, w)
+                    acc_extras = jax.tree.map(
+                        lambda a, b: a + b, acc_extras, ex)
+                out = (out_vars, new_opt, loss_acc, acc_vars, acc_w, acc_loss,
+                       acc_tau, acc_extras)
+                if lens:
+                    out = out + ((upd_stack, l_first, l_last, floss_acc),)
             return out, None
 
         # zeros DERIVED from inputs (shard_map type consistency, as in the
         # vmap form)
-        zl = jnp.sum(member_w, axis=1) * 0.0            # [L]
-        acc0 = jax.tree.map(lambda v: v.astype(jnp.float32) * 0.0, stack0)
-        if reduce_extras is not None:
-            ex0 = reduce_extras(
-                variables0,
-                LocalResult(jax.tree.map(lambda v: v * 0.0, stack0),
-                            zl, zl), zl)
-            acc_extras0 = jax.tree.map(lambda e: e * 0.0, ex0)
-        else:
-            acc_extras0 = {}
-        carry0 = (stack0, opt_state0, zl, acc0, zl, zl, zl, acc_extras0)
-        if lens:
-            zk2 = member_w * 0.0                        # [L, k_max]
-            upd0 = jax.tree.map(
-                lambda p: zk2.reshape(zk2.shape + (1,) * (p.ndim - 1))
-                * p.astype(jnp.float32)[:, None], sparams0)
-            carry0 = carry0 + ((upd0, zk2, zk2, zl),)
-        final, _ = jax.lax.scan(
-            step_fn, carry0,
-            (slot.T, epoch_a.T, sie.T, reset.T, emit.T, live.T),
-            unroll=max(int(scan_unroll), 1),
-        )
+        with jax.named_scope(SCOPE_PROLOGUE):
+            zl = jnp.sum(member_w, axis=1) * 0.0            # [L]
+            acc0 = jax.tree.map(lambda v: v.astype(jnp.float32) * 0.0, stack0)
+            if reduce_extras is not None:
+                ex0 = reduce_extras(
+                    variables0,
+                    LocalResult(jax.tree.map(lambda v: v * 0.0, stack0),
+                                zl, zl), zl)
+                acc_extras0 = jax.tree.map(lambda e: e * 0.0, ex0)
+            else:
+                acc_extras0 = {}
+            carry0 = (stack0, opt_state0, zl, acc0, zl, zl, zl, acc_extras0)
+            if lens:
+                zk2 = member_w * 0.0                        # [L, k_max]
+                upd0 = jax.tree.map(
+                    lambda p: zk2.reshape(zk2.shape + (1,) * (p.ndim - 1))
+                    * p.astype(jnp.float32)[:, None], sparams0)
+                carry0 = carry0 + ((upd0, zk2, zk2, zl),)
+        with jax.named_scope(SCOPE_STEP):
+            final, _ = jax.lax.scan(
+                step_fn, carry0,
+                (slot.T, epoch_a.T, sie.T, reset.T, emit.T, live.T),
+                unroll=max(int(scan_unroll), 1),
+            )
         (_, _, _, acc_vars, acc_w, acc_loss, acc_tau, acc_extras) = final[:8]
         # singleton lane axis on the extras: the hook summed lanes already,
         # and the caller's sum(axis=0) must reduce THIS axis, not a real one
@@ -773,21 +794,23 @@ def make_packed_cohort_train(
         exactly as in the unpacked paths: split(rng, cohort)[position])."""
         (slot, epoch_a, sie, reset, emit, live,
          member_pos, member_valid, steps_real) = plan_arrays
-        if compute_dtype is not None and jnp.issubdtype(tx.dtype, jnp.floating):
-            tx = tx.astype(compute_dtype)
-        C = tx.shape[0]
-        x_flat = tx.reshape((C * n_pad,) + tx.shape[2:])
-        y_flat = ty.reshape((C * n_pad,) + ty.shape[2:])
-        m_flat = tm.reshape((C * n_pad,))
-        if key_slice is None:
-            keys_full = jax.random.split(rng, sampled_rows.shape[0])
-        else:
-            total, start = key_slice
-            keys_full = jax.random.split(rng, total)[
-                start:start + sampled_rows.shape[0]]
-        member_row = sampled_rows[member_pos]      # [n_lanes, k_max]
-        member_keys = keys_full[member_pos]
-        member_w = weights_pos[member_pos] * member_valid
+        with jax.named_scope(SCOPE_PROLOGUE):
+            if (compute_dtype is not None
+                    and jnp.issubdtype(tx.dtype, jnp.floating)):
+                tx = tx.astype(compute_dtype)
+            C = tx.shape[0]
+            x_flat = tx.reshape((C * n_pad,) + tx.shape[2:])
+            y_flat = ty.reshape((C * n_pad,) + ty.shape[2:])
+            m_flat = tm.reshape((C * n_pad,))
+            if key_slice is None:
+                keys_full = jax.random.split(rng, sampled_rows.shape[0])
+            else:
+                total, start = key_slice
+                keys_full = jax.random.split(rng, total)[
+                    start:start + sampled_rows.shape[0]]
+            member_row = sampled_rows[member_pos]      # [n_lanes, k_max]
+            member_keys = keys_full[member_pos]
+            member_w = weights_pos[member_pos] * member_valid
 
         lanes = lanes_fn(variables, x_flat, y_flat, m_flat, tm,
                          member_row, member_keys, member_w, steps_real,
@@ -800,9 +823,10 @@ def make_packed_cohort_train(
         # extras: [L] stacked (vmap form) or singleton-axis (joint form) —
         # sum(axis=0) reduces either to the cohort partial sums the
         # server_update hook consumes
-        out = (jax.tree.map(lambda a: jnp.sum(a, axis=0), acc_vars),
-               jnp.sum(acc_w), jnp.sum(acc_loss), jnp.sum(acc_tau),
-               jax.tree.map(lambda e: jnp.sum(e, axis=0), extras))
+        with jax.named_scope(SCOPE_AGGREGATE):
+            out = (jax.tree.map(lambda a: jnp.sum(a, axis=0), acc_vars),
+                   jnp.sum(acc_w), jnp.sum(acc_loss), jnp.sum(acc_tau),
+                   jax.tree.map(lambda e: jnp.sum(e, axis=0), extras))
         if lens_out is not None:
             # per-member stacks stay UNsummed ([L, k_max, ...], member_pos
             # order) + the matching member weights for the alignment basis
@@ -995,35 +1019,40 @@ def make_crosssilo_packed_round(
         (slot, epoch_a, sie, reset, emit, live,
          member_pos, member_valid, steps_real) = plan_arrays
         variables0 = variables
-        variables = jax.tree.map(
-            lambda x: jax.lax.pcast(x, axis_name=axis, to="varying"), variables
-        )
-        L = tx.shape[0]
-        x_flat = tx.reshape((L * n_pad,) + tx.shape[2:])
-        y_flat = ty.reshape((L * n_pad,) + ty.shape[2:])
-        m_flat = tm.reshape((L * n_pad,))
-        member_keys = keys[member_pos]
-        member_w = weights[member_pos] * member_valid
+        with jax.named_scope(SCOPE_PROLOGUE):
+            variables = jax.tree.map(
+                lambda x: jax.lax.pcast(x, axis_name=axis, to="varying"),
+                variables)
+            L = tx.shape[0]
+            x_flat = tx.reshape((L * n_pad,) + tx.shape[2:])
+            y_flat = ty.reshape((L * n_pad,) + ty.shape[2:])
+            m_flat = tm.reshape((L * n_pad,))
+            member_keys = keys[member_pos]
+            member_w = weights[member_pos] * member_valid
 
         acc_vars, acc_w, acc_loss, _tau, acc_extras = lanes_fn(
             variables, x_flat, y_flat, m_flat, tm,
             member_pos, member_keys, member_w, steps_real,
             slot, epoch_a, sie, reset, emit, live)
 
-        acc_vars = jax.tree.map(
-            lambda a: jax.lax.psum(jnp.sum(a, axis=0), axis), acc_vars)
-        total = jax.lax.psum(jnp.sum(acc_w), axis)
-        loss_sum = jax.lax.psum(jnp.sum(acc_loss), axis)
-        denom = jnp.maximum(total, 1e-12)
-        agg = jax.tree.map(
-            lambda a, v: (a / denom).astype(v.dtype), acc_vars, variables0)
-        extras = None
-        if reduce_extras is not None:
-            extras = jax.tree.map(
-                lambda e: jax.lax.psum(jnp.sum(e, axis=0), axis), acc_extras)
+        # the psum tail: here fedml.aggregate holds the collectives
+        with jax.named_scope(SCOPE_AGGREGATE):
+            acc_vars = jax.tree.map(
+                lambda a: jax.lax.psum(jnp.sum(a, axis=0), axis), acc_vars)
+            total = jax.lax.psum(jnp.sum(acc_w), axis)
+            loss_sum = jax.lax.psum(jnp.sum(acc_loss), axis)
+            denom = jnp.maximum(total, 1e-12)
+            agg = jax.tree.map(
+                lambda a, v: (a / denom).astype(v.dtype), acc_vars, variables0)
+            extras = None
+            if reduce_extras is not None:
+                extras = jax.tree.map(
+                    lambda e: jax.lax.psum(jnp.sum(e, axis=0), axis),
+                    acc_extras)
         new_vars, new_state = apply_server_and_rollback(
             variables0, agg, extras, total, server_state, rng, server_update)
-        return new_vars, new_state, loss_sum / denom
+        with jax.named_scope(SCOPE_AGGREGATE):
+            return new_vars, new_state, loss_sum / denom
 
     p_plan = tuple(P(axis) for _ in range(9))
     mapped = shard_map(
@@ -1040,9 +1069,11 @@ def make_crosssilo_packed_round(
         every client keeps the per-round key of its ORIGINAL index (same
         rule as the grouped mesh schedule), so the packing changes only the
         padding, never which randomness a client consumes."""
-        if compute_dtype is not None and jnp.issubdtype(tx.dtype, jnp.floating):
-            tx = tx.astype(compute_dtype)
-        keys = jax.random.split(rng, weights.shape[0])[perm]
+        with jax.named_scope(SCOPE_PROLOGUE):
+            if (compute_dtype is not None
+                    and jnp.issubdtype(tx.dtype, jnp.floating)):
+                tx = tx.astype(compute_dtype)
+            keys = jax.random.split(rng, weights.shape[0])[perm]
         return mapped(variables, server_state, tx, ty, tm, weights, keys,
                       plan_arrays, rng)
 

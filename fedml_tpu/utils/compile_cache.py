@@ -43,6 +43,15 @@ def enable_compile_cache() -> str:
                       MIN_COMPILE_SECS)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                       MIN_ENTRY_BYTES)
+    # An executable read back from the cache carries the metadata of the
+    # process that compiled it: op names (the program's jax.named_scope
+    # layer names, which the benchmark's trace reduction reads) and source
+    # lines. JAX leaves metadata out of the key by default, so a checkout
+    # whose scopes or lines differ would be handed another's names (seen
+    # on the chip, PR 24: the parent commit's traced run showed this
+    # commit's scopes out of a shared cache). Metadata is part of what is
+    # read, so it is part of the key.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return jax.config.jax_compilation_cache_dir
 
 

@@ -17,6 +17,7 @@ import importlib.util
 import json
 import os
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -24,6 +25,7 @@ import pytest
 import jax
 
 from fedml_tpu import obs
+from fedml_tpu.obs import tracer
 from fedml_tpu.core.config import FedConfig
 from fedml_tpu.data import load_dataset
 from fedml_tpu.data.synthetic import make_synthetic_classification
@@ -60,6 +62,34 @@ def _edge_cfg(**kw):
 
 def _edge_ds():
     return load_dataset("synthetic_1_1", num_clients=4, batch_size=10, seed=3)
+
+
+def _free_base_port(n: int) -> int:
+    """A base port with ``n`` consecutive free ports, checked now. Fixed
+    ports collide under xdist, and so do ports the OS hands out: they lie
+    in the ephemeral range (32768 up), where the next ``bind(0)`` of another
+    worker (an MQTT broker's, an outgoing connection's) lands right beside
+    them. So: below that range, from a start that differs by process, each
+    run of ports verified by binding it."""
+    import random
+    import socket
+
+    rng = random.Random(os.getpid() * 7919 + time.monotonic_ns())
+    for _ in range(256):
+        base = rng.randrange(20000, 32000 - n)
+        socks = []
+        try:
+            for port in range(base, base + n):
+                sk = socket.socket()
+                socks.append(sk)
+                sk.bind(("127.0.0.1", port))
+            return base
+        except OSError:
+            continue
+        finally:
+            for sk in socks:
+                sk.close()
+    raise RuntimeError(f"no {n} consecutive free ports found")
 
 
 # -- bit-identity: tracing must not touch the math -------------------------
@@ -136,10 +166,11 @@ def test_cross_rank_stitch_grpc_4_ranks(tmp_path):
     from fedml_tpu.comm.grpc_backend import GRPCCommManager
 
     d = str(tmp_path / "tr")
+    port = _free_base_port(4)
     run_fedavg_edge(
         _edge_ds(), _edge_cfg(trace_dir=d), worker_num=3,
         comm_factory=lambda r: GRPCCommManager(
-            rank=r, size=4, base_port=56880, host="127.0.0.1"))
+            rank=r, size=4, base_port=port, host="127.0.0.1"))
     assert sorted(os.listdir(d)) == [f"trace-rank{r}.jsonl" for r in range(4)]
     _assert_stitched(d, n_ranks=4, n_rounds=2)
 
@@ -793,8 +824,8 @@ def test_sampled_tracing_grpc_edge_bit_identical(tmp_path):
             comm_factory=lambda r: GRPCCommManager(
                 rank=r, size=4, base_port=port, host="127.0.0.1"))
 
-    on = run(str(tmp_path / "s"), 0.5, 56970)
-    off = run(None, 1.0, 56974)
+    on = run(str(tmp_path / "s"), 0.5, _free_base_port(4))
+    off = run(None, 1.0, _free_base_port(4))
     assert [h["loss"] for h in on.test_history] \
         == [h["loss"] for h in off.test_history]
     for a, b in zip(jax.tree.leaves(on.get_global_model_params()),
@@ -944,3 +975,158 @@ def test_superstep_block_follows_head_sampling_verdict(tmp_path):
     tr_dropped = obs.tracer_if_sampled(0, 0)  # ...and drops round 0
     assert span_sampled(1, seed=1) and not span_sampled(0, seed=1)
     assert tr_kept is not None and tr_dropped is None
+
+
+# -- the round path's one span primitive, and the scopes (ISSUE 24) ---------
+
+def test_span_with_the_tracer_off_is_a_trace_annotation_and_nothing_else():
+    assert not obs.tracing_enabled()
+    sp = obs.span(tracer.SPAN_PLAN, round=3)
+    assert type(sp) is jax.profiler.TraceAnnotation
+    with sp:
+        pass
+    assert obs.get_tracer(0).drain() == []       # the shared disabled tracer
+
+
+def test_span_with_trace_dir_is_in_the_ring_with_round_id_and_parent(tmp_path):
+    obs.configure(str(tmp_path / "t"))
+    with obs.span(tracer.SPAN_ROUND, round=5):
+        with obs.span(tracer.SPAN_PLAN, round=5):
+            pass
+        with obs.span(tracer.SPAN_H2D, round=6, chunk=1):
+            pass
+    ev = {(e["cat"], e["name"]): e for e in obs.get_tracer(0).drain()}
+    # the ring keeps its cat / name split: trace_report keys on (round, round)
+    assert set(ev) == {("round", "round"), ("round", "plan"),
+                       ("prefetch", "h2d")}
+    rnd = ev["round", "round"]
+    assert rnd["args"] == {"round": 5} and rnd["ph"] == "X" and "psid" not in rnd
+    assert ev["round", "plan"]["psid"] == rnd["sid"]
+    assert ev["prefetch", "h2d"]["args"] == {"round": 6, "chunk": 1}
+    assert ev["prefetch", "h2d"]["psid"] == rnd["sid"]
+    # sampled out: the annotation alone, no ring record
+    obs.configure(str(tmp_path / "t"), sample_rate=0.5, sample_seed=1)
+    assert not tracer.span_sampled(0)
+    assert type(obs.span(tracer.SPAN_ROUND, round=0)) is \
+        jax.profiler.TraceAnnotation
+    assert not hasattr(tracer, "_JAX_BRIDGE")
+    import inspect
+    assert "jax_bridge" not in inspect.signature(obs.configure).parameters
+
+
+def _packed_api(**kw):
+    from fedml_tpu.algorithms.fedavg import FedAvgAPI
+
+    ds = make_synthetic_classification(
+        "tr", (6,), 3, 4, records_per_client=8, partition_method="homo",
+        batch_size=4, seed=0)
+    cfg = FedConfig(**{**dict(
+        model="lr", client_num_in_total=4, client_num_per_round=4,
+        comm_round=2, batch_size=4, lr=0.1, frequency_of_the_test=1,
+        device_data="on", pack_lanes=2, async_rounds=True), **kw})
+    return FedAvgAPI(ds, cfg)
+
+
+def test_profiler_trace_holds_the_round_spans_nested_and_changes_no_bit(tmp_path):
+    """Any jax.profiler session sees the program's spans with no switch in
+    the program, and a profiled run computes what an unprofiled one does."""
+    from jax.profiler import ProfileData
+
+    api = _packed_api()
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        for r in range(2):
+            jax.block_until_ready(api.run_round(r))
+    plain = _packed_api()
+    for r in range(2):
+        jax.block_until_ready(plain.run_round(r))
+    for a, b in zip(jax.tree.leaves(api.variables),
+                    jax.tree.leaves(plain.variables)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    import glob
+    (path,) = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                        recursive=True)
+    spans = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+              dict(ev.stats))
+             for pl in ProfileData.from_file(path).planes
+             for ln in pl.lines for ev in ln.events
+             if ev.name.startswith("fedml/")]
+    for r in range(2):
+        mine = {n: (s, e) for s, e, n, st in sorted(spans)
+                if st.get("round") == r and n != tracer.SPAN_PLAN}
+        plans = [(s, e) for s, e, n, st in spans
+                 if st.get("round") == r and n == tracer.SPAN_PLAN]
+        rs, re_ = mine[tracer.SPAN_ROUND]
+        es, ee = mine[tracer.SPAN_ENQUEUE]
+        assert plans and all(rs <= s and e <= es for s, e in plans)
+        assert rs <= es and ee <= re_
+    # the new program's build is a span of its own, inside round 0's enqueue
+    builds = [st for _s, _e, n, st in spans if n == tracer.SPAN_BUILD]
+    assert builds and all(st == {"program": "packed_step"} for st in builds)
+    assert len([1 for *_x, n, _st in spans if n != tracer.SPAN_BUILD]) <= 2 * 6
+
+
+@pytest.mark.parametrize("kw,missing", [
+    (dict(), set()),                                        # packed lanes
+    (dict(pack_lanes=0), {tracer.SCOPE_STEP_RESET}),        # the gather step
+])
+def test_lowered_round_program_names_every_scope(kw, missing):
+    import jax.numpy as jnp
+
+    api = _packed_api(**kw)
+    sampled, live, bucket = api._round_plan(0)
+    rk = jax.random.PRNGKey(0)
+    if api.config.pack_lanes > 0:
+        from fedml_tpu.parallel.packed import plan_arrays_tuple
+
+        plan = api._packed_plan(sampled)
+        step = api.build_round_step_packed(plan.shape_key)
+        tx, ty, tm, _ = api._dev_train
+        counts = np.asarray(api.dataset.train_counts, np.float32)[sampled]
+        args = (api.variables, api.server_state, tx, ty, tm,
+                jnp.asarray(sampled, jnp.int32), jnp.asarray(counts), rk,
+                tuple(jnp.asarray(a) for a in plan_arrays_tuple(plan)))
+    else:
+        step = api.build_round_step_gather(bucket)
+        args = (api.variables, api.server_state, *api._dev_train,
+                jnp.asarray(sampled, jnp.int32),
+                jnp.ones((len(sampled),), jnp.float32), rk)
+    text = step.lower(*args).as_text(debug_info=True)
+    table = {v for k, v in vars(tracer).items() if k.startswith("SCOPE_")}
+    assert len(table) == 9
+    import re
+    found = set(re.findall(r"fedml\.[a-z_.]+", text))
+    assert found == table - missing
+    # a scope is metadata: the program's text without locations has none
+    assert "fedml." not in step.lower(*args).as_text()
+
+
+def test_device_memory_sample_reports_the_running_programs_scratch(
+        monkeypatch, tmp_path):
+    """peak_bytes_in_use leaves out the running program's scratch
+    (bytes_reserved: 4,160 of the flagship cell's 4,836 MB, PR 23)."""
+    from fedml_tpu.obs import sample_device_memory
+
+    class Dev:
+        id = 0
+
+        def __init__(self, stats):
+            self._stats = stats
+
+        def memory_stats(self):
+            return self._stats
+
+    full = {"bytes_in_use": 10, "peak_bytes_in_use": 20,
+            "bytes_reserved": 30, "peak_bytes_reserved": 40}
+    obs.configure(str(tmp_path / "t"))
+    tr = obs.get_tracer(0)
+    monkeypatch.setattr(jax, "local_devices", lambda: [Dev(full)])
+    assert sample_device_memory(tr, 1) == {
+        "d0/bytes_in_use": 10, "d0/peak_bytes": 20,
+        "d0/bytes_reserved": 30, "d0/peak_bytes_reserved": 40}
+    monkeypatch.setattr(jax, "local_devices",
+                        lambda: [Dev({"bytes_in_use": 7})])
+    assert sample_device_memory(tr, 2) == {"d0/bytes_in_use": 7}
+    (ev,) = [e for e in tr.drain() if e["args"].get("round") == 1]
+    assert ev["name"] == "device_mem" and ev["args"]["values"][
+        "d0/peak_bytes_reserved"] == 40
