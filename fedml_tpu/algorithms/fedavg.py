@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from fedml_tpu.core.config import FedConfig
-from fedml_tpu.core.pytree import tree_weighted_mean
+from fedml_tpu.core.pytree import map_chunks, tree_weighted_mean
 from fedml_tpu.core.rng import round_key, sample_clients, seed_everything, server_key
 from fedml_tpu.core.tasks import get_task
 from fedml_tpu.data import FedDataset
@@ -314,14 +314,8 @@ class FedAvgAPI:
                 self._warned_cohort_width = True  # fedlint: disable=traced-purity
             return vt(variables, cx, cy, cm, counts, keys)
 
-        def rs(a):
-            return a.reshape((n // w, w) + a.shape[1:])
-
-        res = jax.lax.map(
-            lambda args: vt(variables, *args),
-            (rs(cx), rs(cy), rs(cm), rs(counts), rs(keys)),
-        )
-        return jax.tree.map(lambda a: a.reshape((n,) + a.shape[2:]), res)
+        return map_chunks(lambda *chunk: vt(variables, *chunk),
+                          (cx, cy, cm, counts, keys), w)
 
     def _round_body(self, variables, server_state, cx, cy, cm, counts, rng):
         with jax.named_scope(SCOPE_PROLOGUE):
@@ -557,6 +551,21 @@ class FedAvgAPI:
     def _packing_supported(self) -> bool:
         return self._packing_hooks() is not None
 
+    def _lane_ids(self, n_lanes: int, pconv) -> dict:
+        """What a packed program's build span and the compile counters say
+        of its lanes (obs/compile.timed_build reads ``.lane_ids``): how
+        many one device runs, and how many of them advance together — all
+        of them in the joint form, parallel/packed.lane_vmap_width's
+        choice in the per-lane form."""
+        from fedml_tpu.parallel.packed import (lane_vmap_width,
+                                               packed_conv_active)
+
+        joint = packed_conv_active(self.bundle, pconv,
+                                   self.config.client_optimizer)
+        return {"lanes": int(n_lanes),
+                "lane_width": int(n_lanes) if joint
+                else lane_vmap_width(self.variables, int(n_lanes))}
+
     def packed_status(self) -> dict:
         """Introspection for the packed-coverage contract (the tier-1
         matrix test pins it): ``{"scheduled": <packed schedule applies>,
@@ -663,6 +672,7 @@ class FedAvgAPI:
             # the LoweringPlan itself: attribute_program self-checks the
             # realized static ceiling against it and emits program_plan
             round_step.cost_hints["plan"] = pconv
+        round_step.lane_ids = self._lane_ids(shape_key[0], pconv)
         return round_step
 
     def _run_packed_round(self, sampled, live, rk, round_idx=0):
@@ -1134,9 +1144,11 @@ class FedAvgAPI:
                 return acc, acc_w + w.astype(jnp.float32), \
                     acc_loss + l.astype(jnp.float32)
 
-        if not self.config.donate:
-            return jax.jit(chunk_step)
-        return _donation_quiet(jax.jit(chunk_step, donate_argnums=(1, 4, 5, 6)))
+        step = (_donation_quiet(jax.jit(chunk_step,
+                                        donate_argnums=(1, 4, 5, 6)))
+                if self.config.donate else jax.jit(chunk_step))
+        step.lane_ids = self._lane_ids(shape_key[0], pconv)
+        return step
 
     def _stream_finish(self, packed: bool):
         """Round-close for the streaming fold: elastic all-failed rollback
@@ -1853,6 +1865,7 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
                 "packing_factor": int(plan.n_lanes // D)}
             if active and not isinstance(pconv, str):
                 rf.cost_hints["plan"] = pconv
+            rf.lane_ids = self._lane_ids(plan.n_lanes // D, pconv)
             return rf
 
         round_fn = timed_build(

@@ -85,6 +85,18 @@ def tree_index(tree: Pytree, i) -> Pytree:
     return jax.tree.map(lambda x: x[i], tree)
 
 
+def map_chunks(fn: Callable, args: Sequence[jax.Array], width: int) -> Pytree:
+    """``fn(*args)`` ``width`` rows of the leading axis at a time, the
+    chunks one after another in one ``lax.map`` (one loop body, not n/width
+    copies of it); the results keep the leading axis. ``fn`` is batched
+    over that axis (a ``vmap``), and ``width`` divides its length."""
+    n = args[0].shape[0]
+    out = jax.lax.map(
+        lambda chunk: fn(*chunk),
+        tuple(a.reshape((n // width, width) + a.shape[1:]) for a in args))
+    return jax.tree.map(lambda a: a.reshape((n,) + a.shape[2:]), out)
+
+
 def tree_weighted_mean(stacked: Pytree, weights: jax.Array) -> Pytree:
     """Weighted average along the leading (client) axis of every leaf.
 

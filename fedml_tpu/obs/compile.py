@@ -81,6 +81,14 @@ def timed_build(name: str, shape_key, builder: Callable) -> Callable:
     g[f"misses.{name}"] = g.get(f"misses.{name}", 0) + 1
     g["build_ms"] = g.get("build_ms", 0.0) + (time.perf_counter() - t0) * 1e3
 
+    # a packed round program says how it runs its lanes (`.lane_ids`:
+    # lanes, lane_width — parallel/packed.lane_vmap_width): the last value
+    # per program name is kept here, and the span of the first call, where
+    # that choice is traced and compiled, carries it
+    ids = getattr(fn, "lane_ids", None) or {}
+    for k, v in ids.items():
+        g[f"{k}.{name}"] = v
+
     first = [True]
 
     def step(*args):
@@ -91,7 +99,7 @@ def timed_build(name: str, shape_key, builder: Callable) -> Callable:
         ring = NOOP_SPAN if tr is None else tr.span(
             f"{name}:first_call", cat="compile",
             args={"shape_key": repr(shape_key)})
-        with span(SPAN_BUILD, program=name), ring:
+        with span(SPAN_BUILD, program=name, **ids), ring:
             out = fn(*args)
         # only a SUCCESSFUL first call records first_call_ms: a raise
         # propagates, the flag stays set, and the next invocation is timed
@@ -115,7 +123,7 @@ def timed_build(name: str, shape_key, builder: Callable) -> Callable:
     # the packed mesh round carries its un-jitted body as `.raw` (the
     # super-step scans it) and fedpack programs carry fedcost packing
     # hints as `.cost_hints`; keep such sidecar attributes reachable
-    for attr in ("raw", "cost_hints"):
+    for attr in ("raw", "cost_hints", "lane_ids"):
         val = getattr(fn, attr, None)
         if val is not None:
             setattr(step, attr, val)

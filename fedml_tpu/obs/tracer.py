@@ -74,7 +74,8 @@ from jax.profiler import TraceAnnotation
 #: stack cast, reshapes, key splits, member gathers, replay tables, opt init
 SCOPE_PROLOGUE = "fedml.prologue"
 #: the local-training ``lax.scan`` itself: the ``while``'s own time and
-#: whatever its body runs outside the five step scopes below
+#: whatever its body runs outside the five step scopes below; also the
+#: ``lax.map`` over lane chunks around it (parallel/packed.make_lanes_train)
 SCOPE_STEP = "fedml.step"
 #: resets of variables / optimizer state / loss at a client's first step
 SCOPE_STEP_RESET = "fedml.step.reset"

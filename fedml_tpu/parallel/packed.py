@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from fedml_tpu.core.pytree import map_chunks
 from fedml_tpu.core.tasks import Task
 from fedml_tpu.models import ModelBundle
 from fedml_tpu.obs.tracer import (SCOPE_AGGREGATE, SCOPE_PROLOGUE, SCOPE_STEP,
@@ -463,6 +464,32 @@ def packed_conv_active(bundle: ModelBundle, packed_conv: str,
     return packed_fallback_reason(bundle, packed_conv, optimizer) is None
 
 
+#: Lanes vmapped together. The plan's lane count says how many lanes a
+#: round HAS; how many advance in one grouped convolution is the chip's
+#: choice: on the v5e the per-lane form executes 31,215 img/s at a vmap
+#: width of 2, 30,530 at 4, 26,183 at 8, 26,555 at 1
+#: (docs/mfu_experiments.md H5, H11)
+LANE_VMAP_WIDTH = 2
+#: a convolution kernel with fewer output channels leaves MXU columns idle
+_MXU_COLUMNS = 128
+
+
+def lane_vmap_width(variables, n_lanes: int) -> int:
+    """How many of ``n_lanes`` lanes :func:`make_lanes_train`'s per-lane
+    form vmaps together: :data:`LANE_VMAP_WIDTH` when the model has
+    convolution kernels narrower than the MXU (a 4-D parameter leaf with
+    fewer than 128 output channels) and the lanes split evenly into more
+    than one such chunk, else all of them. Dense-only models (lr, the
+    RNNs) gain from the wider batched matmul and keep the full vmap.
+    ``variables``: the model's variables, arrays or shapes."""
+    w = LANE_VMAP_WIDTH
+    if n_lanes <= w or n_lanes % w:
+        return n_lanes
+    narrow = any(len(p.shape) == 4 and p.shape[-1] < _MXU_COLUMNS
+                 for p in jax.tree.leaves(variables["params"]))
+    return w if narrow else n_lanes
+
+
 def make_lanes_train(
     bundle: ModelBundle,
     task: Task,
@@ -473,17 +500,35 @@ def make_lanes_train(
 ) -> Callable:
     """The all-lanes program both packed round builders share: by default
     ``vmap`` of :func:`make_lane_train` over the lane axis (XLA lowers the
-    batched-kernel convs to a grouped conv, docs/mfu_experiments.md H4);
-    with ``packed_conv`` on and a capable model, the fedpack JOINT form
-    (:func:`make_packed_lanes_train`) whose convs are ONE block-diagonal/
-    grouped contraction across lanes (ops/packed_conv.py). Same signature
-    and stacked-accumulator return either way."""
+    batched-kernel convs to a grouped conv, docs/mfu_experiments.md H4),
+    :func:`lane_vmap_width` lanes at a time, the chunks one after another
+    in one ``lax.map``; with ``packed_conv`` on and a capable model, the
+    fedpack JOINT form (:func:`make_packed_lanes_train`) whose convs are
+    ONE block-diagonal/grouped contraction across lanes
+    (ops/packed_conv.py). Same signature and stacked-accumulator return
+    either way."""
     pb = _packed_model_bundle(bundle, packed_conv,
                               lane_kwargs.get("optimizer", "sgd"))
-    if pb is None:
-        lane_train = make_lane_train(bundle, task, n_pad, **lane_kwargs)
-        return jax.vmap(lane_train, in_axes=(None,) * 5 + (0,) * 10)
-    return make_packed_lanes_train(bundle, pb, task, n_pad, **lane_kwargs)
+    if pb is not None:
+        return make_packed_lanes_train(bundle, pb, task, n_pad, **lane_kwargs)
+    lane_train = make_lane_train(bundle, task, n_pad, **lane_kwargs)
+    vmapped = jax.vmap(lane_train, in_axes=(None,) * 5 + (0,) * 10)
+
+    def lanes_train(variables0, x_flat, y_flat, m_flat, mask_rows, *per_lane):
+        L = per_lane[-1].shape[0]
+        w = lane_vmap_width(variables0, L)
+        if w == L:
+            return vmapped(variables0, x_flat, y_flat, m_flat, mask_rows,
+                           *per_lane)
+        # same lanes, same steps, same order within a lane: only how many
+        # lanes one convolution groups changes. The shared arguments are
+        # closed over (loop constants), each chunk runs its own T-step scan
+        with jax.named_scope(SCOPE_STEP):
+            return map_chunks(
+                lambda *chunk: vmapped(variables0, x_flat, y_flat, m_flat,
+                                       mask_rows, *chunk), per_lane, w)
+
+    return lanes_train
 
 
 def make_packed_lanes_train(

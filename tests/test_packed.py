@@ -21,7 +21,8 @@ from fedml_tpu.core.tasks import get_task
 from fedml_tpu.data.synthetic import make_synthetic_classification
 from fedml_tpu.models import create_model
 from fedml_tpu.parallel.local import make_local_train_fn
-from fedml_tpu.parallel.packed import make_packed_cohort_train, plan_packing
+from fedml_tpu.parallel.packed import (make_packed_cohort_train,
+                                       plan_arrays_tuple, plan_packing)
 
 
 def _ds(C=12, records=160, seed=9, bs=8):
@@ -297,3 +298,89 @@ def test_superstep_eval_aligned_to_block_ends():
                                    rtol=1e-5)
         np.testing.assert_allclose(ss["Test/Loss"][i], plain["Test/Loss"][j],
                                    rtol=1e-5)
+
+
+# -- the lane vmap width (parallel/packed.lane_vmap_width) --------------------
+
+def _lanes_case(model, n_lanes, hooks, lens):
+    """A jitted packed cohort program at ``n_lanes`` with its arguments: 10
+    LDA clients of 8x8 images (or 6 features for the dense model), batch 4."""
+    shape = (6,) if model == "lr" else (8, 8, 3)
+    ds = make_synthetic_classification(
+        "pack-w", shape, 4, 10, records_per_client=12,
+        partition_method="hetero", partition_alpha=0.5, batch_size=4, seed=3)
+    bundle = create_model(model, ds.class_num, input_shape=shape)
+    task = get_task(ds.task, ds.class_num)
+    counts = np.asarray(ds.train_counts, np.float64)
+    plan = plan_packing(counts, 4, 1, n_lanes=n_lanes)
+    assert plan.n_lanes == n_lanes and plan.k_max > 1
+    kw = dict(optimizer="sgd", lr=0.05, momentum=0.9, epochs=1, batch_size=4,
+              lens=lens)
+    if hooks:
+        def client_transform(gvars, stacked):
+            out = dict(stacked)
+            out["params"] = jax.tree.map(
+                lambda g, v: v + 0.5 * (g[None] - v), gvars["params"],
+                stacked["params"])
+            return out
+
+        def reduce_extras(gvars, res, w):
+            return {"tau": jnp.sum(w * res.tau),
+                    "sq": jnp.sum(w * res.train_loss ** 2)}
+
+        kw.update(client_transform=client_transform,
+                  reduce_extras=reduce_extras)
+    args = (bundle.init(jax.random.PRNGKey(0)), jnp.asarray(ds.train_x),
+            jnp.asarray(ds.train_y), jnp.asarray(ds.train_mask),
+            jnp.arange(10, dtype=jnp.int32), jnp.asarray(counts, jnp.float32),
+            jax.random.PRNGKey(5),
+            tuple(jnp.asarray(a) for a in plan_arrays_tuple(plan)))
+
+    def build():
+        return jax.jit(make_packed_cohort_train(
+            bundle, task, int(ds.train_x.shape[1]), plan.shape_key, **kw))
+
+    return build, args
+
+
+@pytest.mark.parametrize("model,n_lanes,hooks,lens,chunked", [
+    ("resnet20", 8, True, False, True),
+    ("resnet20", 4, True, True, True),
+    ("resnet20", 8, False, True, True),
+    ("resnet20", 2, False, False, False),    # the flagship's own point
+    ("resnet20", 3, False, False, False),    # lanes that do not split evenly
+    ("lr", 8, True, False, False),           # dense only: the wide matmul
+], ids=["resnet20-8-hooks", "resnet20-4-hooks-lens", "resnet20-8-lens",
+        "resnet20-2", "resnet20-3", "lr-8-hooks"])
+def test_lanes_run_lane_vmap_width_at_a_time(monkeypatch, model, n_lanes,
+                                             hooks, lens, chunked):
+    """Narrow-conv models with more than LANE_VMAP_WIDTH lanes run them in
+    chunks of that width, one ``lax.map`` around the lane scan, and compute
+    what the single vmap computes (accumulators, weights, loss, tau, extras,
+    the lens stacks) to float32 rounding: the grouped convolutions have 2
+    groups, not L. Everything else lowers to the single vmap's program, to
+    the byte."""
+    from fedml_tpu.parallel import packed
+
+    build, args = _lanes_case(model, n_lanes, hooks, lens)
+    assert packed.lane_vmap_width(args[0], n_lanes) == (
+        packed.LANE_VMAP_WIDTH if chunked else n_lanes)
+    step = build()
+    text = step.lower(*args).as_text()
+    monkeypatch.setattr(packed, "LANE_VMAP_WIDTH", 1 << 30)   # one vmap
+    assert packed.lane_vmap_width(args[0], n_lanes) == n_lanes
+    whole = build()
+    whole_text = whole.lower(*args).as_text()
+    if not chunked:
+        assert text == whole_text
+        return
+    assert (text.count("stablehlo.while")
+            == whole_text.count("stablehlo.while") + 1)
+    got, want = step(*args), whole(*args)
+    assert len(got) == (6 if lens else 5)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, rtol=0,
+                                   atol=2e-6 * max(np.abs(b).max(), 1e-6))

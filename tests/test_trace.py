@@ -1027,6 +1027,24 @@ def _packed_api(**kw):
     return FedAvgAPI(ds, cfg)
 
 
+def _packed_step_and_args(api):
+    """Round 0's packed round program of ``api``, unbuilt by any cache, and
+    the arguments ``_run_packed_round`` would call it with."""
+    import jax.numpy as jnp
+
+    from fedml_tpu.parallel.packed import plan_arrays_tuple
+
+    sampled, _live, _bucket = api._round_plan(0)
+    plan = api._packed_plan(sampled)
+    step = api.build_round_step_packed(plan.shape_key)
+    tx, ty, tm, _ = api._dev_train
+    counts = np.asarray(api.dataset.train_counts, np.float32)[sampled]
+    return step, (api.variables, api.server_state, tx, ty, tm,
+                  jnp.asarray(sampled, jnp.int32), jnp.asarray(counts),
+                  jax.random.PRNGKey(0),
+                  tuple(jnp.asarray(a) for a in plan_arrays_tuple(plan)))
+
+
 def test_profiler_trace_holds_the_round_spans_nested_and_changes_no_bit(tmp_path):
     """Any jax.profiler session sees the program's spans with no switch in
     the program, and a profiled run computes what an unprofiled one does."""
@@ -1061,8 +1079,10 @@ def test_profiler_trace_holds_the_round_spans_nested_and_changes_no_bit(tmp_path
         assert plans and all(rs <= s and e <= es for s, e in plans)
         assert rs <= es and ee <= re_
     # the new program's build is a span of its own, inside round 0's enqueue
-    builds = [st for _s, _e, n, st in spans if n == tracer.SPAN_BUILD]
-    assert builds and all(st == {"program": "packed_step"} for st in builds)
+    # (construction, then the first call, which says how the lanes run)
+    builds = [st for _s, _e, n, st in sorted(spans) if n == tracer.SPAN_BUILD]
+    assert builds == [{"program": "packed_step"},
+                      {"program": "packed_step", "lanes": 2, "lane_width": 2}]
     assert len([1 for *_x, n, _st in spans if n != tracer.SPAN_BUILD]) <= 2 * 6
 
 
@@ -1077,15 +1097,7 @@ def test_lowered_round_program_names_every_scope(kw, missing):
     sampled, live, bucket = api._round_plan(0)
     rk = jax.random.PRNGKey(0)
     if api.config.pack_lanes > 0:
-        from fedml_tpu.parallel.packed import plan_arrays_tuple
-
-        plan = api._packed_plan(sampled)
-        step = api.build_round_step_packed(plan.shape_key)
-        tx, ty, tm, _ = api._dev_train
-        counts = np.asarray(api.dataset.train_counts, np.float32)[sampled]
-        args = (api.variables, api.server_state, tx, ty, tm,
-                jnp.asarray(sampled, jnp.int32), jnp.asarray(counts), rk,
-                tuple(jnp.asarray(a) for a in plan_arrays_tuple(plan)))
+        step, args = _packed_step_and_args(api)
     else:
         step = api.build_round_step_gather(bucket)
         args = (api.variables, api.server_state, *api._dev_train,
@@ -1099,6 +1111,54 @@ def test_lowered_round_program_names_every_scope(kw, missing):
     assert found == table - missing
     # a scope is metadata: the program's text without locations has none
     assert "fedml." not in step.lower(*args).as_text()
+
+
+def test_build_span_and_counters_carry_the_lane_width(tmp_path):
+    """A conv model at 4 lanes runs them 2 at a time (parallel/packed.
+    lane_vmap_width): the first call's fedml/round/build span and the
+    compile counters say so, and the chunk loop is a ``while`` of the
+    fedml.step scope, around the lane scan's own."""
+    import glob
+    import re
+
+    from jax.profiler import ProfileData
+
+    from fedml_tpu.algorithms.fedavg import FedAvgAPI
+
+    ds = make_synthetic_classification(
+        "tr-conv", (8, 8, 3), 3, 4, records_per_client=8,
+        partition_method="homo", batch_size=4, seed=0)
+    api = FedAvgAPI(ds, FedConfig(
+        model="resnet20", client_num_in_total=4, client_num_per_round=4,
+        comm_round=2, batch_size=4, lr=0.1, frequency_of_the_test=1,
+        device_data="on", pack_lanes=4, async_rounds=True))
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        jax.block_until_ready(api.run_round(0))
+    (path,) = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                        recursive=True)
+    builds = [dict(ev.stats) for pl in ProfileData.from_file(path).planes
+              for ln in pl.lines for ev in ln.events
+              if ev.name == tracer.SPAN_BUILD]
+    ids = {"program": "packed_step", "lanes": 4, "lane_width": 2}
+    assert ids in builds and {"program": "packed_step"} in builds
+    g = obs.compile_counters()
+    assert g["lanes.packed_step"] == 4 and g["lane_width.packed_step"] == 2
+
+    step, args = _packed_step_and_args(api)
+    assert step.lane_ids == {"lanes": 4, "lane_width": 2}
+    text = step.lower(*args).as_text(debug_info=True)
+    # every loop's name stack up to its first "while": the chunk loop
+    # directly under fedml.step, the lane scan (named from inside the chunk
+    # loop's body) under vmap(fedml.step)
+    heads = {n[:n.index("/while")] for n in re.findall(r'loc\("([^"]*)"', text)
+             if "/while" in n}
+    assert heads == {"jit(round_step)/" + tracer.SCOPE_STEP,
+                     f"vmap({tracer.SCOPE_STEP})"}
+
+    # the flagship's own point, 2 lanes: the width is the lane count
+    lr = _packed_api()
+    jax.block_until_ready(lr.run_round(0))
+    assert g["lanes.packed_step"] == 2 and g["lane_width.packed_step"] == 2
 
 
 def test_device_memory_sample_reports_the_running_programs_scratch(
