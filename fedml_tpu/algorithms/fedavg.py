@@ -32,7 +32,7 @@ from fedml_tpu.core.pytree import map_chunks, tree_weighted_mean
 from fedml_tpu.core.rng import round_key, sample_clients, seed_everything, server_key
 from fedml_tpu.core.tasks import get_task
 from fedml_tpu.data import FedDataset
-from fedml_tpu.models import ModelBundle, create_model
+from fedml_tpu.models import COUNTERS, ModelBundle, create_model
 from fedml_tpu.obs.tracer import (SCOPE_AGGREGATE, SCOPE_PROLOGUE,
                                   SCOPE_SERVER, SPAN_ENQUEUE, SPAN_H2D,
                                   SPAN_MATERIALIZE, SPAN_PLAN, SPAN_ROUND,
@@ -627,11 +627,22 @@ class FedAvgAPI:
                                     int(shape_key[0]),
                                     optimizer=c.client_optimizer)
         lens_on = self._lens_armed
+        reduce_extras = hooks.get("reduce_extras")
+        # a model's own counts (models.COUNTERS) are SUMS over the clients'
+        # steps: what each client added rides the scan's extras, and the
+        # new global's counts are the old ones plus that sum, not the
+        # weighted mean every other leaf is
+        counted = COUNTERS in self.variables and not has_extras
+        if counted:
+            def reduce_extras(variables0, res, w):
+                return jax.tree.map(lambda v, z: jnp.sum(
+                    (w > 0).reshape((-1,) + (1,) * z.ndim) * (v - z), axis=0),
+                    res.variables[COUNTERS], variables0[COUNTERS])
         packed = make_packed_cohort_train(
             self.bundle, self.task, n_pad, shape_key,
             packed_conv=pconv,
             client_transform=hooks.get("client_transform"),
-            reduce_extras=hooks.get("reduce_extras"),
+            reduce_extras=reduce_extras,
             lens=lens_on,
             **self._local_train_kwargs())
 
@@ -652,6 +663,9 @@ class FedAvgAPI:
                 variables, agg, extras if has_extras else None, acc_w,
                 server_state, rng, server_update)
             with jax.named_scope(SCOPE_AGGREGATE):
+                if counted:
+                    new_vars = {**new_vars, COUNTERS: jax.tree.map(
+                        jnp.add, variables[COUNTERS], extras)}
                 if lens_on:
                     from fedml_tpu.obs.lens import packed_lens
 
@@ -1296,8 +1310,16 @@ class FedAvgAPI:
 
     def close(self) -> None:
         """Drain and tear down background machinery (the host round
-        pipeline). Idempotent; the API stays usable — the next host-path
-        round lazily rebuilds the prefetcher."""
+        pipeline) and publish the model's own counters (``bundle.counters``
+        of the current variables: a sync on a few small arrays). Idempotent;
+        the API stays usable — the next host-path round lazily rebuilds the
+        prefetcher."""
+        if self.bundle.counters is not None:
+            from fedml_tpu.obs import model_counters
+
+            g = model_counters()
+            for k, v in self.bundle.counters(self.variables).items():
+                g[k] = v
         pf = self._prefetcher
         self._prefetcher = None
         if pf is not None:

@@ -25,13 +25,23 @@ def register_model(name: str):
     return deco
 
 
+#: the flax collection of a model's own counts (rows an expert computed,
+#: steps): ``apply_train`` updates it like ``batch_stats``, and the packed
+#: simulation round (``FedAvgAPI.build_round_step_packed``) SUMS what the
+#: round's clients added, where every other leaf of the state is their
+#: weighted mean. Any other round form, and an algorithm whose hooks bring
+#: extras of their own, averages it with the rest.
+COUNTERS = "counters"
+
+
 @dataclass
 class ModelBundle:
     """A model as pure functions over variable pytrees.
 
     ``variables`` is the full flax collection dict {'params': ..., maybe
-    'batch_stats': ...}. ``apply_train`` returns (logits, new_variables) with
-    mutable collections updated; ``apply_eval`` is deterministic.
+    'batch_stats': ..., maybe 'counters': ...}. ``apply_train`` returns
+    (logits, new_variables) with mutable collections updated; ``apply_eval``
+    is deterministic.
     """
 
     name: str
@@ -55,10 +65,25 @@ class ModelBundle:
     #: None = this model family has no packed conv lowering; the packed
     #: schedule keeps its per-lane vmap.
     packed_variant: Optional[Callable[[str], "ModelBundle"]] = None
+    #: the single-example shape ``init`` traces with, where parameter shapes
+    #: do not depend on it (a sequence model's length) and a forward pass at
+    #: ``input_shape`` would be minutes of op-by-op work; such an init is
+    #: also jitted, one program instead of one per op
+    init_shape: Optional[tuple] = None
+    #: ``counters(variables) -> {name: number}``: host numbers read from the
+    #: model's :data:`COUNTERS` collection (the sparse layers' rows per
+    #: expert), published by the round driver's ``close()`` into the
+    #: ``model`` counter group
+    counters: Optional[Callable[[dict], dict]] = None
 
     def init(self, rng: jax.Array, batch_size: int = 2) -> dict:
-        x = jnp.zeros((batch_size,) + tuple(self.input_shape), self.input_dtype)
-        return self.module.init({"params": rng}, x, train=False)
+        shape = self.init_shape or self.input_shape
+        x = jnp.zeros((batch_size,) + tuple(shape), self.input_dtype)
+
+        def init(r):
+            return self.module.init({"params": r}, x, train=False)
+
+        return (jax.jit(init) if self.init_shape is not None else init)(rng)
 
     def apply_train(self, variables: dict, x: jax.Array, rng: jax.Array):
         rngs, kwargs = {}, {}
@@ -66,9 +91,11 @@ class ModelBundle:
             kwargs["dropout_rng"] = rng     # raw key(s); module derives masks
         elif self.uses_dropout:
             rngs = {"dropout": rng}
-        if self.has_batch_stats:
+        mutable = (["batch_stats"] if self.has_batch_stats else []) + (
+            [COUNTERS] if COUNTERS in variables else [])
+        if mutable:
             logits, updated = self.module.apply(
-                variables, x, train=True, mutable=["batch_stats"], rngs=rngs,
+                variables, x, train=True, mutable=mutable, rngs=rngs,
                 **kwargs
             )
             new_vars = dict(variables)
@@ -86,7 +113,7 @@ def create_model(model_name: str, output_dim: int, input_shape: Optional[Sequenc
     (main_fedavg.py:232-267: lr, cnn, resnet18_gn, rnn, resnet56, mobilenet,
     ...)."""
     # Import lazily so optional model families don't slow cold start.
-    from fedml_tpu.models import cnn, linear, mobilenet, resnet, resnet_gn, rnn, segmentation, transformer, vgg  # noqa: F401
+    from fedml_tpu.models import cnn, linear, mobilenet, moe, resnet, resnet_gn, rnn, segmentation, transformer, vgg  # noqa: F401
     try:
         from fedml_tpu.models import efficientnet  # noqa: F401
     except ImportError:
@@ -100,7 +127,7 @@ def create_model(model_name: str, output_dim: int, input_shape: Optional[Sequenc
 
 
 def known_models() -> list[str]:
-    from fedml_tpu.models import cnn, linear, mobilenet, resnet, resnet_gn, rnn, segmentation, transformer, vgg  # noqa: F401
+    from fedml_tpu.models import cnn, linear, mobilenet, moe, resnet, resnet_gn, rnn, segmentation, transformer, vgg  # noqa: F401
     try:
         from fedml_tpu.models import efficientnet  # noqa: F401
     except ImportError:

@@ -1,5 +1,16 @@
 """Mixture-of-experts transformer blocks with expert parallelism.
 
+Two layers live here. The SPARSE one (:class:`SharedRoutedMoe`,
+:class:`LatentMoeLM`, below the dense one) is what today's open MoE language
+models run: sigmoid scores, selection with a correction bias, shared
+experts, and a layer that is told which experts it holds, routes over all of
+them and computes only the rows its own experts were chosen for (a grouped
+matmul over rows sorted by expert, no capacity, no dropped token). The DENSE
+one (:class:`MoeMlp`) is the older GSPMD baseline, kept for the expert-axis
+sharding tests: every expert computes every token.
+
+--- the dense-dispatch baseline ---
+
 The reference has no MoE (and no LLM-era parallelism at all, SURVEY.md
 §2.6); this exists so the framework's parallelism surface covers the EP
 axis alongside dp/tp/sp/clients/group.
@@ -22,7 +33,16 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.models.transformer import SelfAttention
+import dataclasses
+from typing import Optional
+
+from fedml_tpu.models import COUNTERS, ModelBundle, register_model
+from fedml_tpu.models.transformer import (LatentAttention, Linear, RMSNorm,
+                                          SelfAttention, SwiGLU, _normal)
+from fedml_tpu.obs.tracer import (SCOPE_LM_DENSE, SCOPE_LM_EXPERTS,
+                                  SCOPE_LM_ROUTE)
+from fedml_tpu.ops.grouped_matmul import (embed_rows, fan_out_rows,
+                                          grouped_matmul, permute_rows)
 
 
 def top_k_probs(router_logits: jax.Array, top_k: int) -> jax.Array:
@@ -117,3 +137,245 @@ class MoeTransformerLM(nn.Module):
                          self.dtype, name=f"block{i}")(h, train)
         h = nn.LayerNorm(dtype=self.dtype)(h)
         return nn.Dense(self.vocab_size, dtype=jnp.float32, name="lm_head")(h)
+
+
+# ---------------------------------------------------------------------------
+# The sparse layer and the LM built on it (DeepSeek-V3's block, as
+# kakaocorp/kanana-2-30b-a3b's config.json fixes it)
+# ---------------------------------------------------------------------------
+
+def route(scores: jax.Array, bias: jax.Array, top_k: int, scaling: float):
+    """``scores [N, E]`` (sigmoid, float32) -> ``(idx [N, k], weights [N,
+    k])``: the ``k`` experts with the largest ``score + bias``, weighted by
+    their own scores over the chosen scores' sum, times ``scaling``.
+    Gradients flow through the scores, not through the selection or the
+    bias. (No gather: a gather's transpose is a scatter, which a TPU
+    serialises; the one-hot product's transpose is a product.)"""
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), top_k)
+    hot = jax.nn.one_hot(idx, scores.shape[-1], dtype=scores.dtype)
+    chosen = jnp.einsum("nke,ne->nk", hot, scores)
+    return idx, chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
+
+
+class SharedRoutedMoe(nn.Module):
+    """Shared experts on every token + the weighted sum of each token's
+    chosen routed experts, for the experts HELD here.
+
+    The router keeps all ``n_routed`` outputs, its ``top_k`` choices and the
+    weights normalised over all the chosen. The layer holds experts
+    ``held_first .. held_first + held_count - 1`` (all, when ``held_count``
+    is None): it takes the (token, choice) pairs whose expert it holds,
+    sorts them by expert, runs one grouped matmul per projection over the
+    rows and adds the weighted rows back. What the absent experts would have
+    added is left out: in an expert-parallel deployment their chips add it,
+    and nothing here stands in for them or for the exchange.
+
+    The ``counters`` collection (``models.COUNTERS``) carries
+    ``expert_rows`` (rows each held expert has computed, summed over the
+    training steps) and ``steps``: the load statistic that the published
+    bias update reads. The packed simulation round sums them over the
+    round's clients (float32: exact up to 2**24 rows an expert), and
+    ``ModelBundle.counters`` reads them on the host.
+    """
+
+    n_routed: int
+    top_k: int
+    width: int
+    n_shared: int = 0
+    scaling: float = 1.0
+    held_first: int = 0
+    held_count: Optional[int] = None
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        b, t, d = x.shape
+        n, k, dt = b * t, self.top_k, self.dtype
+        held = self.n_routed if self.held_count is None else self.held_count
+        xf = x.reshape(n, d)
+        out = 0.0
+        if self.n_shared:
+            with jax.named_scope(SCOPE_LM_DENSE):
+                out = SwiGLU(self.n_shared * self.width, dt, name="shared")(xf)
+
+        def experts(name, a, c):
+            return self.param(name, _normal(), (held, a, c), jnp.float32)
+
+        w_gate = experts("gate", d, self.width)
+        w_up = experts("up", d, self.width)
+        w_down = experts("down", self.width, d)
+        with jax.named_scope(SCOPE_LM_ROUTE):
+            w_r = self.param("router", _normal(), (d, self.n_routed),
+                             jnp.float32)
+            bias = self.param("e_score_correction_bias", _normal(0.01),
+                              (self.n_routed,), jnp.float32)
+            scores = jax.nn.sigmoid(jnp.dot(
+                xf.astype(jnp.float32), w_r,
+                precision=jax.lax.Precision.HIGHEST))
+            idx, weights = route(scores, bias, k, self.scaling)
+            self.sow("intermediates", "choices", idx)
+            # pairs are laid out choice-major, [k, N]: a [k, N, D] view pads
+            # no axis to the TPU's tiles, which [N, k, D] would (k = 6)
+            local = (idx - self.held_first).T
+            mine = (local >= 0) & (local < held)
+            # pairs sorted by held expert; the pairs of absent experts last
+            key = jnp.where(mine, local, held).reshape(-1)
+            order = jnp.argsort(key, stable=True)
+            inv = jnp.argsort(order)
+            sizes = jnp.sum(jax.nn.one_hot(key, held + 1, dtype=jnp.int32),
+                            axis=0)[:held]
+            live = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
+            # rows past the last group belong to no expert: zero going in
+            # and coming out, so that nothing a kernel leaves there (and no
+            # cotangent of it) reaches a token
+            rows = jnp.where(live, fan_out_rows(xf.astype(dt), order, inv), 0)
+        with jax.named_scope(SCOPE_LM_EXPERTS):
+            g = grouped_matmul(rows, w_gate.astype(dt), sizes)
+            u = grouped_matmul(rows, w_up.astype(dt), sizes)
+            y = grouped_matmul(nn.silu(g) * u, w_down.astype(dt), sizes)
+        with jax.named_scope(SCOPE_LM_ROUTE):
+            y = jnp.where(live, y, 0)
+            back = permute_rows(y, inv, order).reshape(k, n, d)
+            wt = jnp.where(mine, weights.T, 0.0)
+            routed = jnp.einsum("knd,kn->nd", back.astype(jnp.float32), wt)
+        seen = self.variable(COUNTERS, "expert_rows",
+                             lambda: jnp.zeros((held,), jnp.float32))
+        steps = self.variable(COUNTERS, "steps",
+                              lambda: jnp.zeros((), jnp.float32))
+        if train and not self.is_initializing():
+            seen.value = seen.value + sizes.astype(jnp.float32)
+            steps.value = steps.value + 1.0
+        return (out + routed.astype(dt)).reshape(b, t, d)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoeSizes:
+    """Every size of a :class:`LatentMoeLM` but its vocabulary (the
+    registered names' values: :data:`LATENT_MOE_PRESETS`)."""
+
+    dim: int
+    heads: int
+    nope: int
+    rope: int
+    v_dim: int
+    kv_rank: int
+    layers: int
+    first_dense: int
+    dense_width: int
+    n_routed: int
+    top_k: int
+    expert_width: int
+    n_shared: int
+    routed_scaling: float
+    rope_theta: float
+    eps: float
+    held_first: int = 0
+    held_count: Optional[int] = None
+    remat: bool = True
+    dtype: Any = jnp.float32
+
+
+class LatentMoeBlock(nn.Module):
+    """``h += Attn(RMSNorm(h))``; ``h += Mlp(RMSNorm(h))``: a SwiGLU of
+    ``dense_width`` in the leading dense layers, the sparse layer after."""
+
+    sizes: LatentMoeSizes
+    sparse: bool
+
+    @nn.compact
+    def __call__(self, h, train: bool = False):
+        c = self.sizes
+        a = LatentAttention(c.heads, c.nope, c.rope, c.v_dim, c.kv_rank,
+                            c.rope_theta, c.eps, c.dtype, name="attn")(
+            RMSNorm(c.eps, c.dtype, name="attn_norm")(h))
+        h = h + a
+        m = RMSNorm(c.eps, c.dtype, name="mlp_norm")(h)
+        if self.sparse:
+            m = SharedRoutedMoe(c.n_routed, c.top_k, c.expert_width,
+                                c.n_shared, c.routed_scaling, c.held_first,
+                                c.held_count, c.dtype, name="mlp")(m, train)
+        else:
+            with jax.named_scope(SCOPE_LM_DENSE):
+                m = SwiGLU(c.dense_width, c.dtype, name="mlp")(m)
+        return h + m
+
+
+class LatentMoeLM(nn.Module):
+    """Decoder-only LM of latent-attention blocks with sparse experts: an
+    embedding, ``layers`` blocks (the first ``first_dense`` with a dense
+    MLP), a final RMSNorm and an untied head; no learned positions. Each
+    block is rematerialised in the backward pass (``remat``)."""
+
+    vocab_size: int
+    sizes: LatentMoeSizes
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        c = self.sizes
+        table = self.param("embed", _normal(), (self.vocab_size, c.dim),
+                           jnp.float32)
+        h = embed_rows(table, x.astype(jnp.int32)).astype(c.dtype)
+        block = (nn.remat(LatentMoeBlock, static_argnums=(2,)) if c.remat
+                 else LatentMoeBlock)
+        for i in range(c.layers):
+            h = block(c, i >= c.first_dense, name=f"layer_{i}")(h, train)
+        h = RMSNorm(c.eps, c.dtype, name="final_norm")(h)
+        with jax.named_scope(SCOPE_LM_DENSE):
+            return Linear(self.vocab_size, c.dtype, jnp.float32,
+                          name="lm_head")(h)
+
+
+def expert_row_counters(variables: dict) -> dict:
+    """``{"rows.<layer>.<expert>": rows, "steps.<layer>": steps}`` from the
+    ``counters`` the sparse layers keep (host numbers): sums over every
+    training step since the variables were seeded, where the packed
+    simulation round trained them."""
+    out = {}
+    for layer, stats in sorted(variables.get(COUNTERS, {}).items()):
+        mlp = stats.get("mlp", {})
+        if "expert_rows" not in mlp:
+            continue
+        for e, rows in enumerate(jax.device_get(mlp["expert_rows"])):
+            out[f"rows.{layer}.{e}"] = float(rows)
+        out[f"steps.{layer}"] = float(jax.device_get(mlp["steps"]))
+    return out
+
+
+#: the registered names' sizes: what ``create_model(name, vocab)`` builds
+#: when it is passed nothing else. ``kanana2_30b_a3b`` is one chip's share
+#: (8 chips share each layer) of kakaocorp/kanana-2-30b-a3b-instruct-2601,
+#: cut to 5 layers: ``benchmarks/configs/kanana2_30b_a3b.json`` holds the
+#: same numbers in its ``model`` block and a test holds the two equal.
+LATENT_MOE_PRESETS = {
+    "kanana2_30b_a3b": dict(
+        dim=2048, heads=32, nope=128, rope=64, v_dim=128, kv_rank=512,
+        layers=5, first_dense=1, dense_width=6144, n_routed=128, top_k=6,
+        expert_width=768, n_shared=2, routed_scaling=2.448, rope_theta=1e6,
+        eps=1e-6, held_first=0, held_count=16, seq_len=4096),
+    "kanana2_tiny": dict(
+        dim=32, heads=2, nope=16, rope=8, v_dim=16, kv_rank=16, layers=3,
+        first_dense=1, dense_width=96, n_routed=8, top_k=2, expert_width=24,
+        n_shared=2, routed_scaling=2.448, rope_theta=1e6, eps=1e-6,
+        held_first=0, held_count=4, seq_len=16),
+}
+
+
+def _latent_moe_bundle(name: str, output_dim: int, **kw) -> ModelBundle:
+    sizes = {**LATENT_MOE_PRESETS[name], **kw}
+    seq_len = sizes.pop("seq_len")
+    module = LatentMoeLM(vocab_size=output_dim, sizes=LatentMoeSizes(**sizes))
+    return ModelBundle(
+        name=name, module=module, input_shape=(seq_len,),
+        input_dtype=jnp.int32, task="nwp",
+        # parameter shapes do not depend on the sequence length
+        init_shape=(8,), counters=expert_row_counters)
+
+
+@register_model("kanana2_30b_a3b")
+def _kanana2(output_dim: int = 16032, **kw):
+    return _latent_moe_bundle("kanana2_30b_a3b", output_dim or 16032, **kw)
+
+
+@register_model("kanana2_tiny")
+def _kanana2_tiny(output_dim: int = 64, **kw):
+    return _latent_moe_bundle("kanana2_tiny", output_dim or 64, **kw)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from fedml_tpu.models import ModelBundle, register_model
+from fedml_tpu.obs.tracer import SCOPE_LM_ATTN, SCOPE_LM_DENSE
 from fedml_tpu.ops.attention import attention
 
 
@@ -149,3 +150,127 @@ def _transformer(output_dim: int = 90, seq_len: int = 80, **kw):
 @register_model("transformer_nwp")
 def _transformer_nwp(output_dim: int = 10004, seq_len: int = 20, **kw):
     return _bundle("transformer_nwp", output_dim or 10004, seq_len, **kw)
+
+
+# ---------------------------------------------------------------------------
+# The blocks of today's open decoder LMs: RMSNorm pre-norm, SwiGLU, rotary
+# positions, latent attention (DeepSeek-V2/V3's MLA). No biases anywhere.
+# models/moe.py builds the sparse-expert LM out of them.
+# ---------------------------------------------------------------------------
+
+def _normal(std: float = 0.02):
+    return nn.initializers.normal(std)
+
+
+class Linear(nn.Module):
+    """``x @ kernel``: float32 parameter, operands in ``dtype``, float32
+    accumulation, result in ``out_dtype`` (default ``dtype``)."""
+
+    features: int
+    dtype: Any = jnp.float32
+    out_dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", _normal(), (x.shape[-1], self.features),
+                            jnp.float32)
+        y = jnp.dot(x.astype(self.dtype), kernel.astype(self.dtype),
+                    preferred_element_type=jnp.float32)
+        return y.astype(self.out_dtype or self.dtype)
+
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * scale``, statistics in float32."""
+
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        xf = x.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                               + self.eps)
+        return (y * scale).astype(self.dtype)
+
+
+class SwiGLU(nn.Module):
+    """``down(silu(gate(x)) * up(x))``."""
+
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        g = Linear(self.width, self.dtype, name="gate")(x)
+        u = Linear(self.width, self.dtype, name="up")(x)
+        return Linear(x.shape[-1], self.dtype, name="down")(nn.silu(g) * u)
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding over the last axis of ``x [..., T, R]``, INTERLEAVED
+    pairs: channels ``(2i, 2i+1)`` turn by ``pos * theta^(-2i/R)``. (The
+    published ``rope_interleave`` code first moves the even channels to the
+    front half and then turns halves; applied to queries and keys alike that
+    is this rotation under one fixed permutation of the channels, and every
+    ``q . k`` is the same.) Computed in float32, returned in ``x.dtype``."""
+    t, r = x.shape[-2], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+#: queries and keys per block of the attention kernels: on the v5e, forward
+#: and backward at [2, 32, 4096] took 97.0 ms at the op's default of 128,
+#: 25.3 at 512, 20.6 at 1024 (PERF.md, PR 26); shorter sequences clamp it
+_LATENT_ATTN_BLOCK = 1024
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention without a query bottleneck
+    (``q_lora_rank`` null). ``q = W_q x`` as ``heads`` of ``nope + rope``;
+    ``[c, k_r] = W_kva x`` with ``c`` the ``kv_rank``-wide compressed
+    key-value and ``k_r`` ONE rotary key for all heads; ``c <- RMSNorm(c)``;
+    ``[k_nope, v] = W_kvb c`` per head; rotary on ``q_rope`` and ``k_r``;
+    ``k = [k_nope, k_r]``; causal softmax of ``q . k / sqrt(nope + rope)``;
+    the ``heads * v_dim`` output goes through ``W_o``."""
+
+    heads: int
+    nope: int
+    rope: int
+    v_dim: int
+    kv_rank: int
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, dim = x.shape
+        h, dn, dr, dv = self.heads, self.nope, self.rope, self.v_dim
+        with jax.named_scope(SCOPE_LM_DENSE):
+            q = Linear(h * (dn + dr), self.dtype, name="q_proj")(x)
+            ckr = Linear(self.kv_rank + dr, self.dtype, name="kv_a")(x)
+        q = q.reshape(b, t, h, dn + dr).transpose(0, 2, 1, 3)
+        c = RMSNorm(self.eps, self.dtype, name="kv_norm")(
+            ckr[..., :self.kv_rank])
+        with jax.named_scope(SCOPE_LM_DENSE):
+            kv = Linear(h * (dn + dv), self.dtype, name="kv_b")(c)
+        kv = kv.reshape(b, t, h, dn + dv).transpose(0, 2, 1, 3)
+        k_r = rotary(ckr[:, None, :, self.kv_rank:], self.rope_theta)  # [B,1,T,dr]
+        q = jnp.concatenate(
+            [q[..., :dn], rotary(q[..., dn:], self.rope_theta)], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_r, (b, h, t, dr))], axis=-1)
+        with jax.named_scope(SCOPE_LM_ATTN):
+            o = attention(q, k, kv[..., dn:], causal=True,
+                          block_q=_LATENT_ATTN_BLOCK,
+                          block_k=_LATENT_ATTN_BLOCK)
+        o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dv)
+        with jax.named_scope(SCOPE_LM_DENSE):
+            return Linear(dim, self.dtype, name="o_proj")(o)

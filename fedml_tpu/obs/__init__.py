@@ -67,7 +67,8 @@ contract: a traced or pulsed run is bit-identical to a plain run — these
 modules only ever read clocks and counters.
 """
 
-from fedml_tpu.obs.compile import compile_counters, record_cache_hit, timed_build
+from fedml_tpu.obs.compile import (compile_counters, model_counters,
+                                   record_cache_hit, timed_build)
 from fedml_tpu.obs.cost import (
     cost_attribution_enabled,
     cost_tables,
@@ -127,6 +128,7 @@ __all__ = [
     "Sketch",
     "Tracer",
     "compile_counters",
+    "model_counters",
     "configure",
     "configure_from",
     "cost_attribution_enabled",

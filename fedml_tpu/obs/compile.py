@@ -49,6 +49,21 @@ def compile_counters() -> CounterGroup:
     return _GROUP
 
 
+_MODEL_GROUP: Optional[CounterGroup] = None
+
+
+def model_counters() -> CounterGroup:
+    """The process-wide ``model`` counter group: host numbers a model keeps
+    in its ``counters`` collection (``ModelBundle.counters``: the sparse
+    layers' rows per held expert and their steps, summed on the device over
+    every step since the variables were seeded), written by the round
+    driver's ``close()``: each key holds the last value written."""
+    global _MODEL_GROUP
+    if _MODEL_GROUP is None:
+        _MODEL_GROUP = default_registry().group("model", rank=0)
+    return _MODEL_GROUP
+
+
 def record_cache_hit(name: str) -> None:
     """One LRU hit: the compiled program was reused, no build happened.
     Attributed both in aggregate and per program name, so a report can say

@@ -87,6 +87,19 @@ SCOPE_STEP_TRAIN = "fedml.step.train"
 SCOPE_STEP_OPT = "fedml.step.opt"
 #: dead-step freeze, loss / weight accumulation, the emit into the sums
 SCOPE_STEP_EMIT = "fedml.step.emit"
+#: inside ``fedml.step.train``, the parts of a decoder LM's step
+#: (models/transformer.py, models/moe.py, core/tasks.py). What carries none
+#: of them (norms, rotary, residual adds, the embedding) stays step.train's.
+#: attention proper: scores, softmax, values (ops/attention.py's kernels)
+SCOPE_LM_ATTN = "fedml.lm.attn"
+#: router matmul, selection, sort, the rows' fan-out and weighted add-back
+SCOPE_LM_ROUTE = "fedml.lm.route"
+#: the grouped matmuls over the rows of the experts held here
+SCOPE_LM_EXPERTS = "fedml.lm.experts"
+#: every other matmul: attention projections, shared experts, dense MLP, head
+SCOPE_LM_DENSE = "fedml.lm.dense"
+#: next-token cross-entropy over the logits
+SCOPE_LM_LOSS = "fedml.lm.loss"
 #: sums over lanes / clients (the ``psum`` on a mesh), division, cast back
 SCOPE_AGGREGATE = "fedml.aggregate"
 #: server update hook and the all-failed rollback

@@ -7,6 +7,9 @@ implementations:
 - :mod:`fedml_tpu.ops.attention` — blockwise (flash) attention: online
   softmax over K/V blocks, MXU-shaped matmuls, partial (o, m, l) outputs so
   sequence-parallel ring attention can merge chunks across devices.
+- :mod:`fedml_tpu.ops.grouped_matmul` — the sparse-expert layer's grouped
+  matmul over rows sorted by expert (``lax.ragged_dot``) and the row moves
+  around it, gathers in both directions.
 - :mod:`fedml_tpu.ops.xent` — fused masked softmax cross-entropy over large
   vocabularies without materializing log-softmax in HBM.
 

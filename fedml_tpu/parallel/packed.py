@@ -516,6 +516,13 @@ def make_lanes_train(
 
     def lanes_train(variables0, x_flat, y_flat, m_flat, mask_rows, *per_lane):
         L = per_lane[-1].shape[0]
+        if L == 1:
+            # one lane needs no lane axis inside its program; the results
+            # get the axis back
+            return jax.tree.map(
+                lambda a: a[None],
+                lane_train(variables0, x_flat, y_flat, m_flat, mask_rows,
+                           *(a[0] for a in per_lane)))
         w = lane_vmap_width(variables0, L)
         if w == L:
             return vmapped(variables0, x_flat, y_flat, m_flat, mask_rows,

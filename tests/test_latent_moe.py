@@ -1,0 +1,273 @@
+"""The latent-attention sparse-expert LM at a small size on the CPU: the
+program against the plain reference (``benchmarks/references/
+kanana2_30b_a3b.py``), the share against the uncut layer, one federated
+round, and token ids through the resident stack. The ops it is built on:
+``tests/test_lm_ops.py``."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.references import kanana2_30b_a3b as ref
+from fedml_tpu.core.tasks import nwp
+from fedml_tpu.models import create_model
+from fedml_tpu.models.moe import LATENT_MOE_PRESETS, SharedRoutedMoe
+
+VOCAB = 64
+
+
+def tiny_config(**over):
+    sizes = {**LATENT_MOE_PRESETS["kanana2_tiny"], **over}
+    return {"name": "tiny", "model": sizes, "data": {"vocab": VOCAB},
+            "recipe": {"lr": 0.1, "momentum": 0.0}}
+
+
+def batch(seed=1, n=4, t=16):
+    x = jax.random.randint(jax.random.key(seed), (n, t + 1), 0, VOCAB)
+    return x[:, :-1], x[:, 1:], jnp.asarray([1.0] * (n - 1) + [0.0])
+
+
+# --- the model against the reference ---------------------------------------
+
+@pytest.mark.parametrize("held_first,held_count,remat", [
+    (0, 4, True), (0, 2, True), (2, 3, False), (4, 4, True), (0, 8, False)])
+def test_logits_loss_and_gradients_match_the_reference(held_first, held_count,
+                                                       remat):
+    over = dict(held_first=held_first, held_count=held_count)
+    config = tiny_config(**over)
+    v = ref.init(jax.random.key(7), config)
+    b = create_model("kanana2_tiny", VOCAB, input_shape=(16,),
+                     dtype=jnp.float32, remat=remat, **over)
+    assert (jax.tree.map(jnp.shape, b.init(jax.random.key(0)))
+            == jax.tree.map(jnp.shape, v))
+    x, y, m = batch()
+    forward = ref._forward(config, "reference")
+
+    def program(p):
+        logits, new = b.apply_train({**v, "params": p}, x, None)
+        return nwp.loss(logits, y, m), (logits, new["counters"])
+
+    def reference(p):
+        logits, stats, _ = forward(p, v["counters"], x)
+        per = -jnp.take_along_axis(jax.nn.log_softmax(logits), y[..., None],
+                                   -1)[..., 0]
+        w = jnp.broadcast_to(m[:, None], per.shape)
+        return jnp.sum(per * w) / jnp.sum(w), (logits, stats)
+
+    with jax.default_matmul_precision("highest"):
+        (lp, (op, sp)), gp = jax.value_and_grad(program, has_aux=True)(v["params"])
+        (lr, (orf, sr)), gr = jax.value_and_grad(reference, has_aux=True)(v["params"])
+    np.testing.assert_allclose(op, orf, atol=2e-6)
+    np.testing.assert_allclose(lp, lr, rtol=1e-6)
+    for (path, a), c in zip(jax.tree_util.tree_leaves_with_path(gp),
+                            jax.tree.leaves(gr)):
+        np.testing.assert_allclose(a, c, atol=2e-6 * float(jnp.abs(c).max() + 1e-6)
+                                   + 1e-9, err_msg=str(path))
+    # the correction bias selects and gets no gradient; the counters count
+    assert not np.any(np.asarray(gp["layer_1"]["mlp"]["e_score_correction_bias"]))
+    for name in sp:
+        np.testing.assert_array_equal(sp[name]["mlp"]["expert_rows"],
+                                      sr[name]["mlp"]["expert_rows"])
+        assert float(sp[name]["mlp"]["steps"]) == 1.0
+
+
+def test_registered_defaults_are_the_published_widths():
+    k = LATENT_MOE_PRESETS["kanana2_30b_a3b"]
+    assert (k["dim"], k["heads"], k["nope"], k["rope"], k["v_dim"],
+            k["kv_rank"]) == (2048, 32, 128, 64, 128, 512)
+    assert (k["n_routed"], k["top_k"], k["n_shared"], k["expert_width"],
+            k["dense_width"], k["routed_scaling"]) == (128, 6, 2, 768, 6144, 2.448)
+    b = create_model("kanana2_30b_a3b", 16032)
+    shapes = jax.eval_shape(lambda: b.module.init(
+        {"params": jax.random.key(0)}, jnp.zeros((1, 8), jnp.int32)))
+    n = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes["params"]))
+    assert 575e6 < n < 577e6            # the issue's 576 M parameters
+    assert shapes["params"]["layer_1"]["mlp"]["router"].shape == (2048, 128)
+
+
+# --- the share --------------------------------------------------------------
+
+def test_all_shares_add_up_to_the_uncut_layer():
+    """8 shares of 2 experts: the routed parts of all the shares, plus the
+    shared experts counted once, are the uncut reference layer's output."""
+    d, n_routed, width = 32, 16, 24
+    config = tiny_config(n_routed=n_routed, held_first=0, held_count=n_routed,
+                         top_k=3, layers=2)
+    v = ref.init(jax.random.key(3), config)
+    p = v["params"]["layer_1"]["mlp"]
+    x = jax.random.normal(jax.random.key(4), (2, 16, d), jnp.float32)
+
+    def layer(first, count, n_shared):
+        mod = SharedRoutedMoe(n_routed, 3, width, n_shared, 2.448, first, count)
+        params = {k: (a[first:first + count] if k in ("gate", "up", "down")
+                      else a) for k, a in p.items() if n_shared or k != "shared"}
+        stats = {"expert_rows": jnp.zeros((count,)), "steps": jnp.zeros(())}
+        return mod.apply({"params": params, "counters": stats}, x)
+
+    with jax.default_matmul_precision("highest"):
+        whole = layer(0, n_routed, 2)
+        shared_once = whole - layer(0, n_routed, 0)
+        parts = sum(layer(first, 2, 0) for first in range(0, n_routed, 2))
+    np.testing.assert_allclose(parts + shared_once, whole, atol=3e-6)
+    with jax.default_matmul_precision("highest"):
+        uncut, rows, _ = ref._forward(config, "reference").moe(x, p)
+    np.testing.assert_allclose(whole, uncut, atol=3e-6)
+    assert float(rows.sum()) == 2 * 16 * 3
+
+
+@pytest.mark.parametrize("target", [0, 3])
+def test_no_token_is_dropped_when_all_choose_one_expert(target):
+    """A bias that sends every token to the same two experts: every (token,
+    choice) pair of a held expert is computed, however many there are."""
+    d, n = 32, 2 * 16
+    config = tiny_config(top_k=2)
+    p = dict(ref.init(jax.random.key(5), config)["params"]["layer_1"]["mlp"])
+    p["e_score_correction_bias"] = jnp.zeros((8,)).at[
+        jnp.asarray([target, 7])].set(10.0)
+    x = jax.random.normal(jax.random.key(6), (2, 16, d), jnp.float32)
+    mod = SharedRoutedMoe(8, 2, 24, 2, 2.448, 0, 4)
+    stats = {"expert_rows": jnp.zeros((4,)), "steps": jnp.zeros(())}
+    with jax.default_matmul_precision("highest"):
+        out, new = mod.apply({"params": p, "counters": stats}, x, True,
+                             mutable=["counters"])
+        # expert 7 is absent: only the held target's part is in the sum
+        want, _, _ = ref._forward(config, "reference").moe(x, p)
+    rows = np.asarray(new["counters"]["expert_rows"])
+    assert rows[target] == n and rows.sum() == n
+    np.testing.assert_allclose(out, want, atol=3e-6)
+
+
+# --- the federated round ------------------------------------------------------
+
+def _round_spec():
+    import os
+    from benchmarks.harness.spec import Spec
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return Spec(os.path.join(root, "tests", "benchmark", "fixtures",
+                             "BENCHMARK.tiny_lm.json"))
+
+
+@pytest.mark.parametrize("seed,round_idx", [(3, 1), (2**31 + 9, 2)])
+def test_one_fedavg_round_matches_the_reference_rounds(seed, round_idx):
+    from benchmarks.harness import check, protocol
+    from benchmarks.harness.cell import build_api, seed_program
+
+    spec = _round_spec()
+    cell = spec.cell("tiny_kanana2_sim")
+    config = spec.config(cell["config"])
+    gen = spec.module("traffic", config["generator"])
+    rf = spec.module("references", config["reference"])
+    ds, rows = gen.make(config, cell, seed)
+    api = build_api(config, cell, ds)
+    init = seed_program(api, rf, config, seed)
+    loss = float(api.run_round(round_idx))
+    state = jax.device_get(api.variables)
+    api.close()
+    ref_losses, ref_states = check.reference_rounds(
+        rf, config, cell, rows, init, seed, [round_idx])
+    out = check.compare([loss], [state], ref_losses, ref_states, init,
+                        {"loss_rel": 1e-5, "update_norm_gap": 1e-4,
+                         "change_norm_gap": 1e-4, "update_l2": 1e-4,
+                         "lowp_share": 0.01})
+    assert out["ok"], out["numbers"]
+    # the round SUMS its clients' counters, where the reference's rounds
+    # average every leaf: hold it to the reference's clients one by one
+    ids = protocol.sample_cohort(round_idx, int(cell["clients"]),
+                                 int(cell["fed_config"]["client_num_per_round"]),
+                                 int(cell["sampling_seed"]))
+    keys = protocol.client_keys(protocol.run_key(seed), round_idx, len(ids))
+    x, y, m, counts = rows(ids)
+    batch_size = int(config["recipe"]["batch_size"])
+    want = jax.tree.map(np.zeros_like, init["counters"])
+    for j in range(len(ids)):
+        order = protocol.epoch_orders(keys[j], 1, m[j])
+
+        def batched(a):
+            return a[order].reshape((1, -1, batch_size) + a.shape[1:])
+
+        new, _ = rf.local_train(config, init, batched(x[j]), batched(y[j]),
+                                batched(m[j]), -(-int(counts[j]) // batch_size))
+        want = jax.tree.map(lambda w, n: w + np.asarray(n), want,
+                            jax.device_get(new["counters"]))
+    assert len(ids) == 2
+    for name, stats in want.items():
+        assert stats["mlp"]["steps"] > 1
+        np.testing.assert_array_equal(
+            state["counters"][name]["mlp"]["expert_rows"],
+            stats["mlp"]["expert_rows"])
+        assert state["counters"][name]["mlp"]["steps"] == stats["mlp"]["steps"]
+    # and close() published them
+    from fedml_tpu.obs import model_counters
+
+    assert model_counters()["steps.layer_1"] == want["layer_1"]["mlp"]["steps"]
+
+
+@pytest.mark.parametrize("dtype,kind", [
+    ("bfloat16", "ids"), ("float32", "ids"), ("bfloat16", "pixels")])
+def test_token_ids_reach_the_model_unrounded(dtype, kind):
+    """The resident stack casts floating inputs to bf16 when training in
+    bf16 and leaves integer ids alone: an id above 256 does not survive
+    bf16."""
+    from fedml_tpu.algorithms.fedavg import FedAvgAPI
+    from fedml_tpu.core.config import FedConfig
+    from fedml_tpu.data import FedDataset
+
+    rng = np.random.default_rng(0)
+    if kind == "ids":
+        x = rng.integers(257, 16032, (3, 4, 16)).astype(np.int32)
+        x[0, 0, 0] = 16031
+        y, model, task, classes = x.copy(), "kanana2_tiny", "nwp", 16032
+    else:
+        x = rng.standard_normal((3, 4, 8)).astype(np.float32)
+        y, model, task, classes = (rng.integers(0, 4, (3, 4)).astype(np.int32),
+                                   "lr", "classification", 4)
+    ds = FedDataset(train_x=x, train_y=y, train_mask=np.ones((3, 4), np.float32),
+                    train_counts=np.full((3,), 4), test_x=x[0], test_y=y[0],
+                    test_mask=np.ones((4,), np.float32), class_num=classes,
+                    task=task)
+    cfg = FedConfig(model=model, batch_size=2, epochs=1, lr=0.1, dtype=dtype,
+                    client_num_in_total=3, client_num_per_round=2,
+                    pack_lanes=1, device_data="on", comm_round=1)
+    api = FedAvgAPI(ds, cfg, create_model(
+        model, classes, input_shape=x.shape[2:],
+        **({"dtype": jnp.float32} if kind == "ids" else {})))
+    placed = api._dev_train[0]
+    if kind == "ids":
+        assert placed.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(placed), x)
+        assert np.isfinite(float(api.run_round(1)))
+    else:
+        assert placed.dtype == (jnp.bfloat16 if dtype == "bfloat16"
+                                else jnp.float32)
+    api.close()
+
+
+def test_lowered_lm_round_program_names_every_scope():
+    """The LM's round program carries the step's scopes and all five
+    ``fedml.lm.*`` names, as metadata only."""
+    import re
+
+    from benchmarks.harness.cell import build_api
+    from fedml_tpu.obs import tracer
+    from fedml_tpu.parallel.packed import plan_arrays_tuple
+
+    spec = _round_spec()
+    cell = spec.cell("tiny_kanana2_sim")
+    config = spec.config(cell["config"])
+    ds, _rows = spec.module("traffic", config["generator"]).make(config, cell, 1)
+    api = build_api(config, cell, ds)
+    sampled, _live, _bucket = api._round_plan(1)
+    plan = api._packed_plan(sampled)
+    step = api.build_round_step_packed(plan.shape_key)
+    args = (api.variables, api.server_state, *api._dev_train[:3],
+            jnp.asarray(sampled, jnp.int32),
+            jnp.ones((len(sampled),), jnp.float32), jax.random.key(0),
+            tuple(jnp.asarray(a) for a in plan_arrays_tuple(plan)))
+    lowered = step.lower(*args)
+    found = set(re.findall(r"fedml\.[a-z_.]+", lowered.as_text(debug_info=True)))
+    table = {v for k, v in vars(tracer).items() if k.startswith("SCOPE_")}
+    assert found == table
+    assert "fedml." not in lowered.as_text()
+    api.close()

@@ -1,0 +1,82 @@
+"""The LM cell's kernels compile for a described v5e at the published
+widths: no chip, no result, only what the chip's compiler would refuse
+(a tiling, the fast memory a kernel may use, a batched grouped matmul).
+All topology work happens inside the fixtures, in this one file."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_cache():
+    """A compile for a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("block", [512, 1024])
+def test_latent_attention_kernels_compile_at_published_widths(one_chip,
+                                                              no_cache, block):
+    """32 heads, 4,096 positions, 192-wide queries and keys (padded to 256),
+    128-wide values, bf16: forward, dq and dk/dv kernels."""
+    from fedml_tpu.ops.attention import attention
+
+    def step(q, k, v, c):
+        return jax.grad(lambda q, k, v: jnp.sum(attention(
+            q, k, v, impl="pallas", block_q=block, block_k=block
+        ).astype(jnp.float32) * c), argnums=(0, 1, 2))(q, k, v)
+
+    def sd(d):
+        return jax.ShapeDtypeStruct((2, 32, 4096, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(step).lower(sd(192), sd(192), sd(128), sd(128)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    # no [T, T] score tensor: the program's temporaries stay under 1 GB
+    # (one head's float32 scores alone would be 67 MB, all 64 4.3 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_grouped_matmul_compiles_unbatched_at_published_widths(one_chip,
+                                                               no_cache):
+    """49,152 row slots (8,192 tokens x 6 choices), 16 held experts of 2048
+    x 768: forward and both gradients; XLA's own operation count is the
+    row slots' (the static capacity), three passes."""
+    from fedml_tpu.ops.grouped_matmul import grouped_matmul
+
+    rows, d, f, held = 8192 * 6, 2048, 768, 16
+
+    def step(x, w, sizes):
+        return jax.grad(lambda x, w: jnp.sum(grouped_matmul(
+            x, w, sizes).astype(jnp.float32) ** 2), argnums=(0, 1))(x, w)
+
+    compiled = jax.jit(step).lower(
+        jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((held, d, f), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)).compile()
+    flops = compiled.cost_analysis()["flops"]
+    assert flops == pytest.approx(3 * 2 * rows * d * f, rel=0.02)
