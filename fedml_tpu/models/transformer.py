@@ -225,9 +225,12 @@ def rotary(x: jax.Array, theta: float) -> jax.Array:
     return out.reshape(x.shape).astype(x.dtype)
 
 
-#: queries and keys per block of the attention kernels: on the v5e, forward
-#: and backward at [2, 32, 4096] took 97.0 ms at the op's default of 128,
-#: 25.3 at 512, 20.6 at 1024 (PERF.md, PR 26); shorter sequences clamp it
+#: queries and keys per TILE of the attention kernels (what a grid step
+#: fetches): on the v5e, forward and backward at [2, 32, 4096] took 97.0 ms
+#: at the op's default of 128, 25.3 at 512, 20.6 at 1024 (PERF.md, PR 26);
+#: 2048 does not fit the kernels' 16 MB of VMEM; shorter sequences clamp it.
+#: Inside a tile the kernels compute in 256-wide sub-tiles of their own
+#: choosing (``ops/attention.py``; PERF.md, PR 27: 19.46 -> 17.83 ms)
 _LATENT_ATTN_BLOCK = 1024
 
 

@@ -6,6 +6,8 @@ partials == pallas kernel (interpret mode on CPU) == ring attention over an
 are both pinned to the same math the transformer trains with.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -143,6 +145,192 @@ class TestAttention:
         g_pal = jax.grad(loss("pallas", True))((q, k, v))
         for a, b in zip(g_xla, g_pal):
             np.testing.assert_allclose(a, b, atol=1e-4)
+
+
+# the module (the package's attribute of that name is the function)
+att = importlib.import_module("fedml_tpu.ops.attention")
+
+
+@pytest.fixture()
+def sub8(monkeypatch):
+    """Compute sub-tiles of 8 x 8, so that interpret-mode sizes engage them
+    (the chip's are 256 x 256 inside a 1024-wide tile)."""
+    monkeypatch.setattr(att, "_SUB_Q", 8)
+    monkeypatch.setattr(att, "_SUB_K", 8)
+
+
+def _value_and_grads(fn, q, k, v, c):
+    return jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) * c),
+                              argnums=(0, 1, 2))(q, k, v)
+
+
+class TestSubTiles:
+    """Two-level tiling: the BlockSpec tile is 4 x 4 compute sub-tiles and
+    the sequence 4 x 4 tiles, as the LM cell's 4,096 positions in 1024-wide
+    tiles of 256-wide sub-tiles."""
+
+    @pytest.mark.parametrize("causal,d,dv,block_q,block_k", [
+        (True, 24, 16, 32, 32), (False, 24, 16, 32, 32),
+        (True, 136, 16, 32, 32),        # keys padded 136 -> 256
+        (True, 24, 16, 32, 64), (True, 24, 16, 64, 16)])
+    def test_forward_and_gradients_match_xla(self, sub8, causal, d, dv,
+                                             block_q, block_k):
+        ks = jax.random.split(jax.random.key(d + block_k), 4)
+        q, k = (jax.random.normal(ks[i], (2, 2, 128, d)) for i in (0, 1))
+        v, c = (jax.random.normal(ks[i], (2, 2, 128, dv)) for i in (2, 3))
+        got = _value_and_grads(lambda q, k, v: attention(
+            q, k, v, causal=causal, impl="pallas", interpret=True,
+            block_q=block_q, block_k=block_k), q, k, v, c)
+        want = _value_and_grads(lambda q, k, v: attention(
+            q, k, v, causal=causal, impl="xla"), q, k, v, c)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("q_offset,k_offset", [
+        (13, 5), (5, 13), (37, 40), (64, 0), (0, 64), (0, 200)])
+    def test_partial_with_a_ring_steps_offsets(self, sub8, q_offset, k_offset):
+        """Shard starts that are no multiple of the sub-tile, a chunk wholly
+        in the past (no mask anywhere) and one wholly in the future (rows
+        that saw no key: m stays NEG_INF, l and o 0)."""
+        q, k, v = _qkv(b=1, h=2, t=64, d=16, seed=q_offset + k_offset)
+        kw = dict(q_offset=q_offset, k_offset=k_offset, causal=True)
+        got = attention_block_partial(q, k, v, impl="pallas", interpret=True,
+                                      block_q=32, block_k=32, **kw)
+        want = attention_block_partial(q, k, v, impl="xla", **kw)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("tq,tk,bq,bk,sq,sk,q_offset,k_offset", [
+        (4096, 4096, 1024, 1024, 256, 256, 0, 0),
+        (4096, 4096, 1024, 1024, 128, 128, 0, 0),
+        (4096, 4096, 1024, 1024, 512, 256, 0, 0),
+        (128, 128, 32, 32, 8, 8, 0, 0), (128, 128, 32, 64, 8, 16, 0, 0),
+        (64, 64, 32, 32, 8, 8, 13, 5), (64, 64, 32, 32, 8, 8, 5, 13),
+        (64, 96, 32, 32, 16, 8, 37, 40), (64, 64, 32, 32, 8, 8, 0, 200),
+        (64, 64, 16, 16, 16, 16, 3, 0)])
+    @pytest.mark.parametrize("over_queries", [False, True])
+    def test_visited_sub_tiles_are_those_the_mask_leaves(
+            self, tq, tk, bq, bk, sq, sk, q_offset, k_offset, over_queries):
+        """Numpy brute force over the mask against the kernels' own spans
+        (forward and dq by query block, dk/dv by key block): a sub-tile is
+        computed, once, exactly if one of its elements is unmasked; a span
+        without the mask holds no masked element; the engagement number
+        counts the same area."""
+        keep = (q_offset + np.arange(tq)[:, None]
+                >= k_offset + np.arange(tk)[None, :])
+        tiles = keep.reshape(tq // sq, sq, tk // sk, sk)
+        live = set(zip(*np.nonzero(tiles.any(axis=(1, 3)))))
+        seen = []
+        for qb in range(tq // bq):
+            for kb in range(tk // bk):
+                for when, group in att._tile_spans(
+                        q_offset + qb * bq - k_offset - kb * bk,
+                        att._Tiling(True, bq, bk, sq, sk), over_queries):
+                    for rows, keys, masked in group if when else ():
+                        r = slice(qb * bq + rows.start, qb * bq + rows.stop)
+                        c = slice(kb * bk + keys.start, kb * bk + keys.stop)
+                        assert masked or keep[r, c].all()
+                        seen += [(i, j)
+                                 for i in range(r.start // sq, r.stop // sq)
+                                 for j in range(c.start // sk, c.stop // sk)]
+        assert len(seen) == len(set(seen)) and set(seen) == live
+        assert att.executed_score_share(
+            tq, tk, bq, bk, sq, sk, True, q_offset, k_offset
+        ) == len(live) * sq * sk / (tq * tk)
+
+    def test_executed_score_share_of_the_lm_cell(self):
+        share = att.executed_score_share
+        assert share(4096, 4096, 1024, 1024, 256, 256) == 0.53125
+        assert share(4096, 4096, 1024, 1024, 1024, 1024) == 0.625
+        # a sub-tile follows the tile it is given, as the kernels' does
+        assert share(4096, 4096, 128, 128, 256, 256) == share(
+            4096, 4096, 128, 128, 128, 128) == 0.515625
+        assert share(4096, 4096, 1024, 1024, causal=False) == 1.0
+
+    @pytest.mark.parametrize("tq,tk,bq,bk,q_offset", [
+        (128, 128, 32, 32, 0), (128, 128, 32, 64, 0), (128, 128, 64, 16, 0),
+        (64, 128, 32, 32, 0), (128, 64, 32, 32, 0), (64, 64, 32, 32, 40),
+        (64, 64, 32, 32, -200)])
+    def test_a_dead_grid_step_names_the_nearest_live_block(self, tq, tk, bq,
+                                                           bk, q_offset):
+        """So that Pallas sees an unchanged block index and issues no DMA:
+        the last live K/V block in the forward and dq sweeps (dead steps
+        come last), the first live query block in dk/dv's (they come
+        first); block 0 / the last block where the whole sweep is dead."""
+        nq, nk = tq // bq, tk // bk
+        live = np.array([[q_offset + qb * bq + bq - 1 >= kb * bk
+                          for kb in range(nk)] for qb in range(nq)])
+        for qb in range(nq):
+            for kb in range(nk):
+                want_k = kb if live[qb, kb] else max(
+                    [j for j in range(nk) if live[qb, j]], default=0)
+                assert int(att._last_live_key_block(
+                    kb, q_offset + qb * bq, bq, bk, nk)) == want_k
+                want_q = qb if live[qb, kb] else min(
+                    [i for i in range(nq) if live[i, kb]], default=nq - 1)
+                assert int(att._first_live_query_block(
+                    qb, q_offset - kb * bk, bq, bk, nq)) == want_q
+
+    @staticmethod
+    def _kernel_primitives(fn, *args):
+        """Primitive names inside every pallas_call of ``fn``'s jaxpr."""
+        names = []
+
+        def walk(jaxpr, inside):
+            for eqn in jaxpr.eqns:
+                if inside:
+                    names.append(eqn.primitive.name)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub, inside or eqn.primitive.name == "pallas_call")
+
+        walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+        return names
+
+    @pytest.mark.parametrize("tile,sub,spans", [
+        (16, None, 2), (64, None, 2), (128, None, 2), (32, 8, 21)])
+    def test_a_tile_within_the_sub_tile_is_one_sub_tile(self, monkeypatch,
+                                                        tile, sub, spans):
+        """Every caller at the default tile of 128 and every smaller tile:
+        the sub-tile chosen is the tile, and each kernel holds the tile
+        twice, without the mask and with it (the parent's kernel), and no
+        loop. Four sub-tiles a tile: the whole tile, the diagonal tile's 4
+        spans as one group, and 4 x 4 spans each under its condition."""
+        if sub:
+            monkeypatch.setattr(att, "_SUB_Q", sub)
+            monkeypatch.setattr(att, "_SUB_K", sub)
+        else:
+            assert att._fit_block(att._SUB_Q, tile) == tile
+            assert att._fit_block(att._SUB_K, tile) == tile
+        q, k, v = _qkv(b=1, h=1, t=2 * tile, d=16)
+
+        def grads(q, k, v):
+            return jax.grad(lambda *a: jnp.sum(attention(
+                *a, impl="pallas", interpret=True, block_q=tile,
+                block_k=tile)), argnums=(0, 1, 2))(q, k, v)
+
+        names = self._kernel_primitives(grads, q, k, v)
+        assert names.count("dot_general") == spans * (2 + 4 + 3)
+        assert not {"while", "scan"} & set(names)
+
+
+    def test_a_kernel_is_traced_once_for_all_its_call_sites(self, monkeypatch):
+        """Each kernel call is an inner jit, so a model with one call a layer
+        and pass traces forward, dk/dv and dq once (set-up time: the LM
+        cell's round program holds 20 calls of many spans each)."""
+        traced = []
+        real = att._tile_spans
+        monkeypatch.setattr(att, "_tile_spans", lambda d, tiling, over=False: (
+            traced.append(over), real(d, tiling, over))[1])
+        q, k, v = _qkv(b=1, h=3, t=48, d=40)      # shapes no other test has
+
+        def three_layers(q, k, v):
+            for _ in range(3):
+                q = attention(q, k, v, impl="pallas", interpret=True,
+                              block_q=16, block_k=16)
+            return jnp.sum(q)
+
+        jax.make_jaxpr(jax.grad(three_layers, argnums=(0, 1, 2)))(q, k, v)
+        assert sorted(traced) == [False, False, True]
 
 
 class TestXent:

@@ -1,0 +1,203 @@
+#!/usr/bin/env python3
+"""attn_sweep: ``ops/attention.py``'s three kernels alone on the chip, at the
+LM cell's shapes, over DMA tiles and compute sub-tiles.
+
+The benchmark reads the kernels through a whole round (``attn_ms``, by
+scope, with XLA's passes around them). This tool times each kernel by
+itself: forward (``_pallas_block_partial``), dk/dv and dq (the two calls of
+``_pallas_flash_bwd``, each jitted alone so that XLA drops the other), at
+``[2, 32, 4096]`` with 192-wide keys and 128-wide values in bf16, causal.
+One row per ``tile:sub_q:sub_k``: wall-clock ms a call over ``--iters``
+calls, the device time of the heaviest operation in a traced call (the
+kernel without XLA's passes around it), the share of the score area the row
+executes, its MXU share on that executed work, the largest difference from
+the first row's results, and ``attention`` forward and gradients against
+the XLA path at float32 ``highest`` on two heads. The sub-tile is the
+module's constant, set here for a row; nothing else selects it.
+
+    python tools/attn_sweep.py 1024:1024:1024 1024:256:256 1024:128:128
+
+Fails at once without a TPU. Writes ``chiprun_out/attn_sweep.json``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import glob
+import importlib
+import json
+import os
+import sys
+import tempfile
+import time
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _ROOT)
+
+#: matmul passes over the executed score area, (at the key width, at the
+#: value width): forward s | pv; dk/dv s, dk | dp, dv; dq s, dq | dp
+PASSES = {"fwd": (1, 1), "dkv": (2, 2), "dq": (2, 1)}
+
+
+@functools.cache
+def _peak_flops() -> float:
+    """The chip's published bf16 peak (``benchmarks/peaks.json``; a device
+    that is not in the table is an error)."""
+    import jax
+
+    with open(os.path.join(_ROOT, "benchmarks", "peaks.json")) as f:
+        return json.load(f)[jax.devices()[0].device_kind]["flops_per_s"]["bfloat16"]
+
+
+def _device_ms(fn, args, calls: int = 3) -> dict:
+    """Device time by operation name over ``calls`` traced calls, ms a call."""
+    import jax
+
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for _ in range(calls):
+                out = fn(*args)
+            jax.block_until_ready(out)
+        path, = glob.glob(os.path.join(d, "plugins/profile/*/*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(path)
+    ops = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/device:TPU:0"):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for ev in line.events:
+                ops[ev.name] = ops.get(ev.name, 0.0) + ev.duration_ns / 1e6 / calls
+    return ops
+
+
+def measure(shape, d, dv, tile, sub_q, sub_k, iters, interpret=False,
+            trace=True):
+    """-> (row, results): one configuration's three kernels."""
+    import jax
+    import jax.numpy as jnp
+
+    att = importlib.import_module("fedml_tpu.ops.attention")
+    att._SUB_Q, att._SUB_K = sub_q, sub_k
+    b, h, t = shape
+    keys = jax.random.split(jax.random.key(27), 4)
+    q, k = (jax.random.normal(keys[i], (b, h, t, d), jnp.bfloat16) for i in (0, 1))
+    v, do = (jax.random.normal(keys[i], (b, h, t, dv), jnp.bfloat16) for i in (2, 3))
+    scale = d ** -0.5
+    q, k = att._pad_qk(q, k)
+
+    def fwd(q, k, v):
+        o, m, l = att._pallas_block_partial(q, k, v, 0, 0, True, scale, tile,
+                                            tile, interpret)
+        return (o / l[..., None]).astype(q.dtype), m + jnp.log(l)
+
+    out, lse = jax.jit(fwd)(q, k, v)
+
+    def bwd(q, k, v, out, lse, do):
+        return att._pallas_flash_bwd(q, k, v, out, lse, do, True, scale, tile,
+                                     tile, interpret)
+
+    fns = {
+        "fwd": (jax.jit(lambda q, k, v: att._pallas_block_partial(
+            q, k, v, 0, 0, True, scale, tile, tile, interpret)), (q, k, v)),
+        "dkv": (jax.jit(lambda *a: bwd(*a)[1:]), (q, k, v, out, lse, do)),
+        "dq": (jax.jit(lambda *a: bwd(*a)[0]), (q, k, v, out, lse, do)),
+    }
+    share = att.executed_score_share(t, t, tile, tile, sub_q, sub_k)
+    row = {"tile": tile, "sub_q": sub_q, "sub_k": sub_k,
+           "executed_score_share": share}
+    results = {}
+    for name, (fn, args) in fns.items():
+        results[name] = jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            r = fn(*args)
+        jax.block_until_ready(r)
+        row[f"{name}_ms"] = (time.perf_counter() - t0) * 1e3 / iters
+        if trace:
+            ops = _device_ms(fn, args)
+            top = max(ops, key=ops.get)
+            row[f"{name}_kernel_ms"] = ops[top]
+            row[f"{name}_other_ms"] = sum(ops.values()) - ops[top]
+            # executed work at the PADDED key width, which the MXU passes
+            wide, narrow = PASSES[name]
+            flops = 2 * b * h * t * t * share * (wide * q.shape[-1] + narrow * dv)
+            row[f"{name}_mxu_pct"] = 100 * flops / _peak_flops() / (ops[top] / 1e3)
+    row["err_to_xla"] = _err_to_xla(att, (q[:1, :2, :, :d], k[:1, :2, :, :d],
+                                          v[:1, :2], do[:1, :2]), tile)
+    return row, results
+
+
+def _err_to_xla(att, qkvc, tile) -> dict:
+    """Forward and the three gradients of ``attention`` on the kernels
+    against the XLA path in float32 at ``highest``."""
+    import jax
+    import jax.numpy as jnp
+
+    def run(impl, args):
+        q, k, v, c = args
+
+        def loss(q, k, v):
+            o = att.attention(q, k, v, causal=True, impl=impl, block_q=tile,
+                              block_k=tile)
+            return jnp.sum(o.astype(jnp.float32) * c.astype(jnp.float32)), o
+
+        (_, o), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(
+            q, k, v)
+        return o, grads
+
+    got = jax.jit(lambda *a: run("pallas", a))(*qkvc)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda *a: run("xla", a))(
+            *(x.astype(jnp.float32) for x in qkvc))
+    return {"fwd": _gap(got[0], want[0]), "bwd": _gap(got[1], want[1])}
+
+
+def _gap(a, b) -> float:
+    import jax
+    import numpy as np
+
+    gaps = []
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        x, y = np.asarray(x, np.float32), np.asarray(y, np.float32)
+        gaps.append(float(np.max(np.abs(x - y)) / np.max(np.abs(y))))
+    return max(gaps)
+
+
+def main(argv=None) -> int:
+    import jax
+
+    ap = argparse.ArgumentParser()
+    ap.add_argument("rows", nargs="*", default=[
+        "1024:1024:1024", "1024:128:128", "1024:256:256", "1024:512:512"],
+        help="tile:sub_q:sub_k; the first row is what the others' results "
+             "are compared with")
+    ap.add_argument("--iters", type=int, default=20)
+    args = ap.parse_args(argv)
+    if jax.default_backend() != "tpu":
+        print("attn_sweep: no TPU", file=sys.stderr)
+        return 1
+    rows, first = [], None
+    for spec in args.rows:
+        tile, sub_q, sub_k = (int(x) for x in spec.split(":"))
+        try:
+            row, results = measure((2, 32, 4096), 192, 128, tile, sub_q,
+                                   sub_k, args.iters)
+        except Exception as e:  # noqa: BLE001  the compiler refused the row
+            print(json.dumps({"row": spec, "failed": str(e)[-600:]}), flush=True)
+            continue
+        first = first or results
+        row["gap_to_first_row"] = {n: _gap(results[n], first[n]) for n in results}
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/attn_sweep.json", "w") as f:
+        json.dump({"device": jax.devices()[0].device_kind, "rows": rows}, f,
+                  indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
