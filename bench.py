@@ -116,15 +116,9 @@ def _bench_crosssilo(tiny: bool, model: str, rounds: int, batch: int,
         comm_round=rounds, batch_size=batch, epochs=EPOCHS, lr=0.1,
         momentum=0.9, dtype="bfloat16", frequency_of_the_test=10_000,
         seed=0, async_rounds=True,
-        # the grouped mesh schedule (strip-dealt clients, per-group scan
-        # lengths) is the measured configuration, like the sim paradigm's
-        bucket_groups=int(os.environ.get("BENCH_BUCKET_GROUPS", "6")),
         # packed mesh schedule: 2 lanes/device measured best at 32 silos
-        # (docs/mfu_experiments.md H5); 0 restores the grouped schedule
+        # (docs/mfu_experiments.md H5); 0 runs the resident-sharded vmap
         pack_lanes=int(os.environ.get("BENCH_PACK_LANES_CS", "2")),
-        # super-step: fold H rounds into one scanned program (H7 lever;
-        # H=rounds makes the measured pass exactly one program)
-        rounds_per_step=int(os.environ.get("BENCH_CS_SUPERSTEP", "1")),
         # force residency even on the CPU smoke path so tiny mode exercises
         # the same resident-sharded branch the TPU run measures
         device_data="on",
@@ -136,8 +130,7 @@ def _bench_crosssilo(tiny: bool, model: str, rounds: int, batch: int,
     api = CrossSiloFedAvgAPI(ds, cfg, bundle, mesh=client_mesh(1))
     # warm TWICE: the first pass's outputs carry fresh shardings, so the
     # second pass triggers one more trace/compile specialization — it must
-    # land in the warm-up, not the measured pass (bit hard with the
-    # super-step, whose single block call per pass hides it otherwise)
+    # land in the warm-up, not the measured pass
     for _pass in range(2):
         for r in range(1, rounds + 1):
             last = api.run_round(r)
@@ -154,10 +147,9 @@ def _bench_crosssilo(tiny: bool, model: str, rounds: int, batch: int,
         padded += pa * EPOCHS
     return {
         "paradigm": "crosssilo shard_map psum, full participation, "
-                    "resident-sharded, grouped scan schedule",
+                    "resident-sharded",
         "algorithm": algo,
         "clients": clients,
-        "grouped_schedule": api._group_plan is not None,
         "packed_schedule": api._packed_mesh is not None,
         "images_per_sec": round(real / dt, 1),
         "padded_images_per_sec": round(padded / dt, 1),
@@ -792,7 +784,7 @@ def main():
     import jax.numpy as jnp
 
     # Persistent compilation cache: the bench compiles one XLA program per
-    # distinct round plan (cohort bucket/group tuple); caching makes repeat
+    # distinct round plan (cohort bucket or lane shape); caching makes repeat
     # bench invocations skip straight to the measured pass.
     from fedml_tpu.utils.compile_cache import enable_compile_cache
 
@@ -805,7 +797,7 @@ def main():
     from fedml_tpu.obs import cost as fedcost
 
     # fedcost roofline attribution: every round program the bench builds
-    # (sim packed/grouped steps, the mesh packed round, the super-step fn)
+    # (the sim packed and gather steps, the mesh packed round)
     # is lowered once more at build time and its per-op GEMM/lane-fill
     # table recorded — pure tracing during the WARMUP pass, so the timed
     # pass is untouched. BENCH_NO_ROOFLINE=1 opts out.
@@ -853,10 +845,9 @@ def main():
         client_num_per_round=cohort, comm_round=rounds,
         batch_size=batch, epochs=EPOCHS, lr=0.1, momentum=0.9,
         dtype="bfloat16", frequency_of_the_test=10_000, seed=0,
-        bucket_groups=int(os.environ.get("BENCH_BUCKET_GROUPS", "6")),
         # packed schedule (parallel/packed.py): 2 lanes measured best for
         # the cohort-8 sim round — round-4 campaign, docs/mfu_experiments.md
-        # H5 (0 restores the grouped/bucketed schedule)
+        # H5 (0 restores the bucketed gather schedule)
         pack_lanes=int(os.environ.get("BENCH_PACK_LANES", "2")),
         scan_unroll=int(os.environ.get("BENCH_UNROLL", "1")),
         cohort_vmap_width=int(os.environ.get("BENCH_COHORT_WIDTH", "0")),
@@ -1049,7 +1040,7 @@ def main():
                 "top_ops": s["top_ops"][:5],
             }
         # the flagship program = the FLOP-dominant record of the flagship
-        # pass (model-agnostic: packed, grouped, gather or host round)
+        # pass (model-agnostic: packed, gather or host round)
         flag_rec = max(
             flagship_tables.values(),
             key=lambda r: r["summary"]["gemm_flops_per_invocation"],

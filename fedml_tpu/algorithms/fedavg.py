@@ -21,7 +21,7 @@ import time
 import warnings
 from collections import deque
 from functools import partial
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -68,27 +68,33 @@ def _donation_quiet(jitted):
     return step
 
 
-def _chunk_buckets(sorted_maxes, G: int, q: int, n_pad: int) -> list:
-    """The ONE grouping core both bucket schedulers share (the sim paradigm's
-    _round_groups over sorted client counts, the mesh paradigm's
-    _mesh_group_plan over sorted per-strip maxes): split the ascending
-    max-count sequence into at most ``G`` contiguous chunks, give each chunk
-    the scan length of its largest member rounded up to quantum ``q`` (capped
-    at ``n_pad``), and merge adjacent chunks whose scan lengths round equal.
-    Returns ``[[a, b, scan_len], ...]`` half-open index chunks."""
-    n = len(sorted_maxes)
-    bounds = np.linspace(0, n, G + 1).round().astype(int)
-    merged: list[list] = []
-    for a, b in zip(bounds[:-1], bounds[1:]):
-        if a == b:
-            continue
-        bucket = min(int(np.ceil(max(float(sorted_maxes[b - 1]), 1.0) / q) * q),
-                     n_pad)
-        if merged and merged[-1][2] == bucket:
-            merged[-1][1] = b
-        else:
-            merged.append([a, b, bucket])
-    return merged
+#: The programs that can run a round: the closed set ``RoundPlan.path``
+#: takes. An API object chooses its path once, at construction
+#: (``_choose_path``); ``_run_round_inner`` dispatches on it through the
+#: class's ``_ROUND_RUNNERS`` and ``round_counts`` reports the same record.
+PATH_PACKED = "packed"                # resident stack, packed lanes
+PATH_GATHER = "gather"                # resident stack, vmap over the cohort
+PATH_HOST = "host"                    # cohort shipped from the host, serial
+PATH_HOST_PIPELINE = "host_pipeline"  # ... built ahead by the prefetcher
+PATH_STREAM = "stream"                # host chunks folded as they finish
+PATH_STREAM_PACKED = "stream_packed"  # ... each chunk as packed lanes
+PATH_MESH_PACKED = "mesh_packed"      # sharded over the mesh, packed lanes
+PATH_MESH_SHARDED = "mesh_sharded"    # sharded over the mesh, vmap a device
+_PACKED_PATHS = (PATH_PACKED, PATH_STREAM_PACKED, PATH_MESH_PACKED)
+
+
+class RoundPlan(NamedTuple):
+    """What one round trains on and which program runs it: ``run_round``
+    executes exactly this record and ``round_counts`` reports it."""
+
+    path: str
+    sampled: np.ndarray             # cohort, in the order the program sees it
+    live: Optional[np.ndarray]      # {0,1} per cohort slot; None = all live
+    bucket: Optional[int]           # scan length; None = the full record axis
+    #: the packed paths' lane plan (parallel/packed.plan_packing; one a
+    #: chunk, in a tuple, on ``stream_packed``); None on the vmap paths
+    lanes: object
+    padded_slots: int               # record slots one epoch executes
 
 
 class FedAvgAPI:
@@ -114,8 +120,8 @@ class FedAvgAPI:
         self._eval = make_eval_fn(self.bundle, self.task)
         self.server_state = self.init_server_state()
         # the default (host-cohort) round program rides the same fedscope
-        # compile telemetry + fedcost attribution hook as the packed/
-        # grouped/gather programs — a vanilla run is not a blind spot.
+        # compile telemetry + fedcost attribution hook as the packed and
+        # gather programs — a vanilla run is not a blind spot.
         # Subclass paradigms build a DIFFERENT program from the same
         # __init__, so their records are name-qualified: one process running
         # several API types (bench.py) keeps one attribution per program
@@ -126,10 +132,9 @@ class FedAvgAPI:
             self._program_name("round_step"), ("default",),
             self.build_round_step)
         self._dev_train = self._maybe_place_train_data()
-        self._gather_steps: dict[int, Callable] = {}
-        self._group_steps: dict[tuple, Callable] = {}
+        self._gather_steps: dict[Optional[int], Callable] = {}
         self._packed_steps: dict[tuple, Callable] = {}
-        # recently computed round plans (round_idx -> (sampled, live)) —
+        # recently computed round plans (round_idx -> RoundPlan) —
         # stashed by _run_round_inner AND the prefetcher's background
         # builds so the fedpulse wrapper can reuse the plan the round
         # ALREADY computed instead of re-paying the O(client_num_in_total)
@@ -173,11 +178,23 @@ class FedAvgAPI:
                 "dataset is device-resident, so the whole-cohort round "
                 "program already aggregates in-program; streaming applies "
                 "to the host round path", config.stream_aggregate)
-        if self._dev_train is not None:
-            self._round_step_gather = timed_build(
-                self._program_name("gather_step"), ("full",),
-                self.build_round_step_gather)
         self.history: dict[str, list] = {"round": [], "Test/Acc": [], "Test/Loss": []}
+        self._path = self._choose_path()
+
+    def _choose_path(self) -> str:
+        """Which program runs this API's rounds: the one decision, taken
+        once, here. Whether the algorithm packs (``_packing_hooks``) and
+        whether it streams (``_stream_mode``) are properties of the API
+        object, asked here and not per round, each with its one warning.
+        The one per-round case, a cohort with no record to pack, is
+        ``_round_plan``'s."""
+        c = self.config
+        if self._dev_train is not None:
+            packs = c.pack_lanes > 0 and self._packing_hooks() is not None
+            return PATH_PACKED if packs else PATH_GATHER
+        if self._stream_mode() != "off":
+            return PATH_STREAM_PACKED if c.pack_lanes > 0 else PATH_STREAM
+        return PATH_HOST_PIPELINE if c.host_pipeline_depth > 0 else PATH_HOST
 
     def _maybe_place_train_data(self):
         """Ship the full stacked client dataset to HBM once so rounds gather
@@ -216,20 +233,16 @@ class FedAvgAPI:
             jax.device_put(jnp.asarray(ds.train_counts, jnp.float32)),
         )
 
-    def _eligible_device_train_x(self, shard_factor: int = 1,
-                                 slots_fraction: float = 1.0):
+    def _eligible_device_train_x(self, shard_factor: int = 1):
         """Shared device-residency eligibility + bf16 pre-cast for train_x.
 
         ``shard_factor`` = number of devices the stacked arrays will be
         sharded across (1 = fully replicated/single-device): the 'auto'
-        byte budget applies to the PER-DEVICE footprint. ``slots_fraction``
-        scales the estimate when the caller will truncate the record axis
-        before placement (the grouped mesh schedule keeps only each group's
-        scan length, so its footprint is sum(n_g * len_g) / (C * n_pad) of
-        the full stack). Auto also declines CPU backends — there is no
-        host->device hop to avoid, and a second in-RAM copy of the dataset
-        would be pure cost ('on' still forces it, e.g. for tests). Returns
-        train_x (bf16-cast when training in bf16) or None when ineligible."""
+        byte budget applies to the PER-DEVICE footprint. Auto also declines
+        CPU backends — there is no host->device hop to avoid, and a second
+        in-RAM copy of the dataset would be pure cost ('on' still forces
+        it, e.g. for tests). Returns train_x (bf16-cast when training in
+        bf16) or None when ineligible."""
         c = self.config
         ds = self.dataset
         if getattr(ds, "virtual", False):
@@ -245,7 +258,6 @@ class FedAvgAPI:
         cast_bf16 = c.dtype == "bfloat16" and np.issubdtype(x.dtype, np.floating)
         nbytes = ((x.size * 2 if cast_bf16 else x.nbytes) + ds.train_y.nbytes
                   + ds.train_mask.nbytes + ds.train_counts.nbytes)
-        nbytes *= slots_fraction
         if c.device_data == "auto" and (
             jax.default_backend() == "cpu"
             or nbytes / max(shard_factor, 1) > c.device_data_max_bytes
@@ -306,9 +318,8 @@ class FedAvgAPI:
         if w <= 0 or w >= n or n % w:
             if 0 < w < n and n % w and not getattr(self, "_warned_cohort_width", False):
                 log.warning(
-                    "cohort_vmap_width=%d does not divide a cohort/group of "
-                    "%d clients; falling back to the full vmap schedule for "
-                    "such groups", w, n)
+                    "cohort_vmap_width=%d does not divide a cohort of %d "
+                    "clients; falling back to the full vmap schedule", w, n)
                 # warn-once bookkeeping on a shape-static branch: executes at
                 # trace time only and never feeds a traced value
                 self._warned_cohort_width = True  # fedlint: disable=traced-purity
@@ -325,8 +336,7 @@ class FedAvgAPI:
 
     def _finish_round(self, variables, server_state, res, counts, rng):
         """Aggregate the cohort's local results + elastic-round guard +
-        weighted train loss (shared by the single- and multi-group round
-        programs)."""
+        weighted train loss."""
         # aggregate() is the algorithm's own (a weighted mean, or a server
         # optimizer on top of one): all of it is the aggregation layer here
         with jax.named_scope(SCOPE_AGGREGATE):
@@ -404,75 +414,6 @@ class FedAvgAPI:
         bucket = int(np.ceil(max(maxc, 1.0) / q) * q)
         return None if bucket >= n_pad else bucket
 
-    def _round_groups(self, sampled: np.ndarray, live: Optional[np.ndarray]):
-        """Multi-group schedule (config.bucket_groups > 1): sort the cohort
-        by real count and split it into up to ``bucket_groups`` contiguous
-        groups, each with its own quantum-rounded scan length. A single
-        scan length must cover the cohort's LARGEST client, so small
-        clients burn (max - count) masked padding steps; per-group scan
-        lengths cut that waste while computing the exact same weighted
-        aggregate (group order is irrelevant to a weighted mean).
-
-        Returns None (schedule degenerates to the single-bucket path) or
-        ``(perm, groups)``: ``perm`` sorts cohort positions by count,
-        ``groups`` is a tuple of (size, scan_len) ascending."""
-        c = self.config
-        if c.bucket_groups <= 1 or len(sampled) < 2:
-            return None
-        n_pad = int(self.dataset.train_x.shape[1])
-        q = c.bucket_quantum_batches * c.batch_size
-        if c.bucket_quantum_batches <= 0 or q >= n_pad:
-            return None
-        counts = np.asarray(self.dataset.train_counts, np.float64)[sampled]
-        if live is not None:
-            counts = counts * live
-        perm = np.argsort(counts, kind="stable")
-        chunks = _chunk_buckets(counts[perm], min(c.bucket_groups, len(sampled)),
-                                q, n_pad)
-        groups = [(b - a, bucket) for a, b, bucket in chunks]
-        if len(groups) == 1:
-            # degenerate schedule: one shared scan length — the single-bucket
-            # path computes the identical program (same bucket via
-            # _round_bucket, same per-position keys), so don't compile a
-            # second copy of it here
-            return None
-        return perm, tuple((s, b) for s, b in groups)
-
-    def build_round_step_gather_groups(self, groups: tuple):
-        """Round step over device-resident data with PER-GROUP scan lengths
-        (see _round_groups). ``idx``/``live`` arrive in group (count-sorted)
-        order; ``pos`` maps each slot back to its original sampled position
-        so every client consumes the same per-round RNG key it would under
-        the single-bucket program (key = split(rng, cohort)[position])."""
-        cohort_train = self._cohort_train
-        finish = self._finish_round
-        sizes = [g[0] for g in groups]
-        buckets = [g[1] for g in groups]
-        starts = np.concatenate([[0], np.cumsum(sizes)[:-1]]).astype(int)
-        cohort = int(sum(sizes))
-
-        @jax.jit
-        def round_step(variables, server_state, tx, ty, tm, tcounts, idx, live, pos, rng):
-            with jax.named_scope(SCOPE_PROLOGUE):
-                keys = jax.random.split(rng, cohort)[pos]
-            parts = []
-            for start, size, bucket in zip(starts, sizes, buckets):
-                sl = slice(start, start + size)
-                with jax.named_scope(SCOPE_PROLOGUE):
-                    idx_g = idx[sl]
-                    cx = jnp.take(tx, idx_g, axis=0)[:, :bucket]
-                    cy = jnp.take(ty, idx_g, axis=0)[:, :bucket]
-                    cm = jnp.take(tm, idx_g, axis=0)[:, :bucket]
-                    cnt_g = jnp.take(tcounts, idx_g, axis=0) * live[sl]
-                    keys_g = keys[sl]
-                parts.append(cohort_train(variables, cx, cy, cm, cnt_g, keys_g))
-            with jax.named_scope(SCOPE_AGGREGATE):
-                res = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-                counts = jnp.take(tcounts, idx, axis=0) * live
-            return finish(variables, server_state, res, counts, rng)
-
-        return round_step
-
     def _program_name(self, base: str) -> str:
         """Telemetry/attribution name for a round program built in the
         shared ``__init__``: subclasses build a DIFFERENT program from the
@@ -483,9 +424,9 @@ class FedAvgAPI:
         return f"{base}.{type(self).__name__}"
 
     def _lru_step(self, cache: dict, key, builder, name: str, cap: int = 64):
-        """Shared LRU for compiled round programs (group/packed schedules):
-        bound the cache — with failure injection the per-round plan varies
-        and the key space is large — and make every eviction VISIBLE
+        """Shared LRU for compiled round programs: bound the cache — with
+        failure injection the per-round plan varies and the key space is
+        large — and make every eviction VISIBLE
         (history counter + log), since each one implies a fresh XLA compile
         (seconds to minutes for a flagship program) next time the key recurs;
         a pathological config shows up here instead of as mystery slowness.
@@ -497,7 +438,7 @@ class FedAvgAPI:
         from fedml_tpu.obs import record_cache_hit, timed_build
 
         # class-qualified like the __init__-built programs: a subclass's
-        # packed/group/gather program is a different program and must not
+        # packed/gather program is a different program and must not
         # overwrite the base class's attribution record or merge counters
         name = self._program_name(name)
         step = cache.get(key)
@@ -548,71 +489,91 @@ class FedAvgAPI:
             hooks = {}
         return hooks
 
-    def _packing_supported(self) -> bool:
-        return self._packing_hooks() is not None
-
-    def _lane_ids(self, n_lanes: int, pconv) -> dict:
-        """What a packed program's build span and the compile counters say
-        of its lanes (obs/compile.timed_build reads ``.lane_ids``): how
-        many one device runs, and how many of them advance together — all
-        of them in the joint form, parallel/packed.lane_vmap_width's
-        choice in the per-lane form."""
-        from fedml_tpu.parallel.packed import (lane_vmap_width,
+    def _tag_packed_program(self, step, n_lanes: int, pconv,
+                            cost_hints: bool = True):
+        """What a built packed program says of itself, set in this one
+        place for the sim, streamed-chunk and mesh builders alike.
+        ``.lane_ids`` (obs/compile.timed_build reads it into the build span
+        and the compile counters): how many lanes one device runs, and how
+        many of them advance together — all of them in the joint form,
+        parallel/packed.lane_vmap_width's choice in the per-lane form.
+        ``.cost_hints`` (obs/cost.attribute_program): the joint form's
+        block-diag dots stream n_lanes x the useful FLOPs and the per-lane
+        form's grouped convs fold the same n_lanes clients (H4) — either
+        way the program folds ``n_lanes`` clients per op."""
+        from fedml_tpu.parallel.packed import (impl_label, lane_vmap_width,
                                                packed_conv_active)
 
+        n_lanes = int(n_lanes)
         joint = packed_conv_active(self.bundle, pconv,
                                    self.config.client_optimizer)
-        return {"lanes": int(n_lanes),
-                "lane_width": int(n_lanes) if joint
-                else lane_vmap_width(self.variables, int(n_lanes))}
+        step.lane_ids = {
+            "lanes": n_lanes,
+            "lane_width": n_lanes if joint
+            else lane_vmap_width(self.variables, n_lanes)}
+        if cost_hints:
+            step.cost_hints = {
+                "packed_conv": impl_label(pconv) if joint else "off",
+                "packing_factor": n_lanes}
+            if joint and not isinstance(pconv, str):
+                # the LoweringPlan itself: attribute_program self-checks
+                # the realized static ceiling against it and emits
+                # program_plan
+                step.cost_hints["plan"] = pconv
+        return step
 
     def packed_status(self) -> dict:
         """Introspection for the packed-coverage contract (the tier-1
-        matrix test pins it): ``{"scheduled": <packed schedule applies>,
-        "packed_conv_active": <joint MXU form engages>, "reason": <None or
-        the documented fallback reason>}``. After packed-everywhere the
-        only honest reasons left are the DESIGN.md §15 exception table —
+        matrix test pins it): ``{"scheduled": <the round path runs packed
+        lanes>, "packed_conv_active": <joint MXU form engages>, "reason":
+        <None or the documented fallback reason>}``. After packed-everywhere
+        the only honest reasons left are the DESIGN.md §15 exception table —
         models without a packed twin, flax-rng dropout without an
-        explicit-key twin, flag off, or an algorithm the lane builder
-        cannot mirror."""
+        explicit-key twin, flag off, an algorithm the lane builder cannot
+        mirror, or a round path that runs no lanes."""
         from fedml_tpu.parallel.packed import packed_fallback_reason
 
         c = self.config
-        if c.pack_lanes <= 0:
+        if self._path not in _PACKED_PATHS:
+            if c.pack_lanes <= 0:
+                reason = "pack_lanes=0"
+            elif self._packing_hooks() is None:
+                reason = (f"{type(self).__name__} has no packed-lane "
+                          "algorithm mirror")
+            else:
+                reason = f"the {self._path} round path runs no packed lanes"
             return {"scheduled": False, "packed_conv_active": False,
-                    "reason": "pack_lanes=0"}
-        if not self._packing_supported():
-            return {"scheduled": False, "packed_conv_active": False,
-                    "reason": f"{type(self).__name__} has no packed-lane "
-                              "algorithm mirror"}
+                    "reason": reason}
         reason = packed_fallback_reason(self.bundle, c.packed_conv,
                                         c.client_optimizer)
         return {"scheduled": True, "packed_conv_active": reason is None,
                 "reason": reason}
 
-    def _packed_plan(self, sampled: np.ndarray):
+    def _packed_plan(self, ids: np.ndarray):
+        """The lane plan of a cohort (or streamed chunk) of these clients;
+        None when it holds no record."""
         from fedml_tpu.parallel.packed import plan_packing
 
-        key = tuple(int(s) for s in sampled)
-        memo = getattr(self, "_packed_plan_memo", None)
-        if memo is not None and memo[0] == key:
-            return memo[1]   # run_round + round_counts share one build
         c = self.config
-        counts = np.asarray(self.dataset.train_counts, np.float64)[sampled]
+        counts = self._counts_view(np.float64)[ids]
         # finer quantum than the bucketed schedule: a lane amortizes its
         # rounding tail over several clients, and the tail is pure waste —
         # the quantum only bounds how many distinct XLA programs the
         # varying per-round plans can demand (LRU-capped anyway)
-        plan = plan_packing(counts, c.batch_size, c.epochs, c.pack_lanes,
+        return plan_packing(counts, c.batch_size, c.epochs, c.pack_lanes,
                             t_quantum=max(1, c.bucket_quantum_batches // 4))
-        self._packed_plan_memo = (key, plan)
-        return plan
+
+    def _lane_slots(self, lanes) -> int:
+        """Record slots one EPOCH of a lane plan executes: packed lanes run
+        T batch-steps each over the whole round; report one epoch's share,
+        rounded to nearest (exact at epochs=1, the bench recipe; off by <1
+        batch otherwise)."""
+        c = self.config
+        return round(lanes.executed_slots / max(c.epochs, 1)) * c.batch_size
 
     def build_round_step_packed(self, shape_key: tuple):
         from fedml_tpu.parallel.crosssilo import apply_server_and_rollback
-        from fedml_tpu.parallel.packed import (impl_label,
-                                               make_packed_cohort_train,
-                                               packed_conv_active,
+        from fedml_tpu.parallel.packed import (make_packed_cohort_train,
                                                resolve_packed_conv)
 
         c = self.config
@@ -674,34 +635,17 @@ class FedAvgAPI:
                             packed_lens(upd, lf, ll, mw))
                 return new_vars, new_state, acc_loss / denom
 
-        # fedcost packing hint (obs/cost.attribute_program): the joint
-        # form's block-diag dots stream n_lanes x the useful FLOPs; the
-        # per-lane vmap form's grouped convs fold the same n_lanes clients
-        # (H4) — either way the program folds shape_key[0] clients per op
-        active = packed_conv_active(self.bundle, pconv, c.client_optimizer)
-        round_step.cost_hints = {
-            "packed_conv": impl_label(pconv) if active else "off",
-            "packing_factor": int(shape_key[0])}
-        if active and not isinstance(pconv, str):
-            # the LoweringPlan itself: attribute_program self-checks the
-            # realized static ceiling against it and emits program_plan
-            round_step.cost_hints["plan"] = pconv
-        round_step.lane_ids = self._lane_ids(shape_key[0], pconv)
-        return round_step
+        return self._tag_packed_program(round_step, shape_key[0], pconv)
 
-    def _run_packed_round(self, sampled, live, rk, round_idx=0):
-        """Execute the round under the packed schedule; returns (variables,
-        server_state, loss) or None when packing doesn't apply this round.
-        ``live`` already folds the Silo client-active mask (_round_plan);
-        exited clients additionally get the STRUCTURAL lane freeze — their
-        plan steps masked dead (mask_plan_arrays) in the same compiled
-        program, never a vmap fallback."""
-        if not self._packing_supported():
-            return None
+    def _run_packed_round(self, round_idx: int, plan: RoundPlan):
+        """Execute the round under the packed schedule. ``plan.live``
+        already folds the Silo client-active mask (_round_plan); exited
+        clients additionally get the STRUCTURAL lane freeze — their plan
+        steps masked dead (mask_plan_arrays) in the same compiled program,
+        never a vmap fallback."""
+        sampled, live, lanes = plan.sampled, plan.live, plan.lanes
+        rk = round_key(self.root_key, round_idx)
         with span(SPAN_PLAN, round=round_idx):
-            plan = self._packed_plan(sampled)
-            if plan is None:
-                return None
             counts = np.asarray(self.dataset.train_counts, np.float32)[sampled]
             weights = (counts if live is None
                        else counts * np.asarray(live, np.float32))
@@ -709,14 +653,14 @@ class FedAvgAPI:
             if active is None:
                 from fedml_tpu.parallel.packed import plan_arrays_tuple
 
-                plan_arrays = plan_arrays_tuple(plan)
+                plan_arrays = plan_arrays_tuple(lanes)
             else:
                 from fedml_tpu.parallel.packed import mask_plan_arrays
 
                 plan_arrays = mask_plan_arrays(
-                    plan,
-                    np.asarray(active, np.float32)[sampled][plan.member_pos])
-        key = plan.shape_key
+                    lanes,
+                    np.asarray(active, np.float32)[sampled][lanes.member_pos])
+        key = lanes.shape_key
         step = self._lru_step(self._packed_steps, key,
                               lambda: self.build_round_step_packed(key),
                               "packed_step")
@@ -729,12 +673,31 @@ class FedAvgAPI:
             # packed_lens flattens [n_lanes, k_max] in member_pos order;
             # padding slots (member_valid 0) and dead/exited members
             # (weight 0) are dropped host-side via the valid mask
-            mp = np.asarray(plan.member_pos, np.int64).reshape(-1)
+            mp = np.asarray(lanes.member_pos, np.int64).reshape(-1)
             mv = np.asarray(plan_arrays[7], np.float64).reshape(-1)
             valid = (mv > 0) & (np.asarray(weights, np.float64)[mp] > 0)
             out = self._lens_absorb(round_idx, out,
                                     np.asarray(sampled, np.int64)[mp], valid)
-        return out
+        self.variables, self.server_state, train_loss = out
+        return train_loss
+
+    def _run_gather_round(self, round_idx: int, plan: RoundPlan):
+        """Execute the round as one vmap over the cohort, gathered in HBM
+        from the resident stack, at the plan's scan length."""
+        sampled, bucket = plan.sampled, plan.bucket
+        rk = round_key(self.root_key, round_idx)
+        live_np = (np.ones((len(sampled),), np.float32) if plan.live is None
+                   else np.asarray(plan.live, np.float32))
+        step = self._lru_step(
+            self._gather_steps, bucket,
+            lambda: self.build_round_step_gather(bucket), "gather_step")
+        with span(SPAN_ENQUEUE, round=round_idx):
+            out = step(
+                self.variables, self.server_state, *self._dev_train,
+                jnp.asarray(sampled, jnp.int32), jnp.asarray(live_np), rk)
+        self.variables, self.server_state, train_loss = \
+            self._lens_absorb(round_idx, out, sampled, live_np > 0)
+        return train_loss
 
     def _sample_failures(self, round_idx: int, cohort: int,
                          record: bool = True) -> Optional[np.ndarray]:
@@ -776,8 +739,7 @@ class FedAvgAPI:
         and the packed paths additionally freeze its lane span structurally
         (parallel/packed.mask_plan_arrays) inside the SAME compiled
         program. ``active``: [num_clients] {0,1}-ish, or None to clear.
-        Takes effect from the next round (next superstep BLOCK on the
-        packed-mesh superstep path — the block is one device program)."""
+        Takes effect from the next round."""
         if active is None:
             self._client_active = None
         else:
@@ -785,70 +747,58 @@ class FedAvgAPI:
             self._client_active = None if a.all() else a
         self._client_active_version += 1
 
-    def _round_plan(self, round_idx: int, record: bool = False):
-        """The deterministic per-round plan: (sampled cohort, live mask,
-        scan bucket). run_round executes exactly this plan; round_counts
-        reports it — one source of truth for what a round trains on.
-        The Silo client-active mask folds into ``live`` here, so every
-        host-cohort/gather/grouped/packed schedule honors an exit the same
-        way it honors an injected failure: weight zero."""
-        sampled = self._cohort_sched.sample(round_idx)
+    def _round_live(self, round_idx: int, sampled: np.ndarray,
+                    record: bool) -> Optional[np.ndarray]:
+        """The round's {0,1} weight mask over ``sampled``: injected
+        failures times the Silo client-active mask, so every path honors
+        an exit the same way it honors a failure: weight zero."""
         live = self._sample_failures(round_idx, len(sampled), record=record)
         if self._client_active is not None:
             av = self._client_active[sampled]
             live = av if live is None else live * av
+        return live
+
+    def _round_plan(self, round_idx: int, record: bool = False) -> RoundPlan:
+        """The deterministic per-round plan: the sampled cohort, its live
+        mask, the program that runs it and the slots that program
+        executes. run_round executes exactly this plan; round_counts
+        reports it — one source of truth for what a round trains on."""
+        sampled = self._cohort_sched.sample(round_idx)
+        live = self._round_live(round_idx, sampled, record)
         bucket = self._round_bucket(sampled, live)
-        return sampled, live, bucket
+        path, lanes = self._path, None
+        # every sampled client counts (failure injection only zeroes
+        # weights): at the shared scan length on the vmap paths, as its
+        # lanes' batch steps on the packed ones
+        n_pad = int(self.dataset.train_x.shape[1])
+        padded = (n_pad if bucket is None else bucket) * len(sampled)
+        if path == PATH_PACKED:
+            lanes = self._packed_plan(sampled)
+            if lanes is None:
+                path = PATH_GATHER      # a cohort with no record to pack
+            else:
+                padded = self._lane_slots(lanes)
+        elif path == PATH_STREAM_PACKED:
+            lanes = tuple(self._packed_plan(sampled[start:start + size])
+                          for start, size in
+                          self._stream_chunk_spec(len(sampled)))
+            padded = sum(self._lane_slots(pk) for pk in lanes
+                         if pk is not None)
+        return RoundPlan(path, sampled, live, bucket, lanes, padded)
 
     def round_counts(self, round_idx: int) -> tuple:
         """(real, padded) training examples one epoch of this round
-        processes: real = the live cohort's actual record counts (masked
-        padding excluded; failed clients' work is discarded by aggregation,
-        so it isn't "real" training), padded = the scan slots the device
-        EXECUTES — every sampled client counts (failure injection only
-        zeroes weights), at the shared scan length, or per-group
-        size x scan_len when bucket_groups schedules apply. Used by
-        bench.py so throughput accounting can never drift from run_round."""
-        sampled, live, bucket = self._round_plan(round_idx)
-        counts = np.asarray(self.dataset.train_counts, np.float64)[sampled]
-        if live is not None:
-            counts = counts * live
-        n_pad = int(self.dataset.train_x.shape[1])
-        if (self.config.pack_lanes > 0 and self._dev_train is not None
-                and self._packing_supported()):
-            pk = self._packed_plan(sampled)
-            if pk is not None:
-                # packed lanes execute T batch-steps each over the whole
-                # round; report one epoch's share, rounded to nearest
-                # (exact at epochs=1, the bench recipe; off by <1 batch
-                # otherwise — advisor r4 #3)
-                ep = max(self.config.epochs, 1)
-                padded = round(pk.executed_slots / ep) * self.config.batch_size
-                return int(counts.sum()), int(padded)
-        if (self._dev_train is None and self._stream_mode() != "off"
-                and self._stream_packed_active()):
-            # streamed packed chunks: each chunk executes its own lane
-            # plan's slots — sum them, one epoch's share (as above)
-            from fedml_tpu.parallel.packed import plan_packing
-
-            c = self.config
-            ep = max(c.epochs, 1)
-            raw = np.asarray(self.dataset.train_counts, np.float64)[sampled]
-            padded = 0
-            for start, size in self._stream_chunk_spec(len(sampled)):
-                pk = plan_packing(
-                    raw[start:start + size], c.batch_size, c.epochs,
-                    c.pack_lanes,
-                    t_quantum=max(1, c.bucket_quantum_batches // 4))
-                if pk is not None:
-                    padded += round(pk.executed_slots / ep) * c.batch_size
-            return int(counts.sum()), int(padded)
-        plan = self._round_groups(sampled, live) if self._dev_train is not None else None
-        if plan is not None:
-            padded = sum(s * b for s, b in plan[1])
-        else:
-            padded = (n_pad if bucket is None else bucket) * len(sampled)
-        return int(counts.sum()), int(padded)
+        processes, read off the plan run_round executes: real = the live
+        cohort's actual record counts (masked padding excluded; failed
+        clients' work is discarded by aggregation, so it isn't "real"
+        training), padded = the record slots the device EXECUTES. The
+        benchmark divides by these, so throughput accounting cannot drift
+        from run_round."""
+        plan = self._round_plan(round_idx)
+        counts = self._counts_view(np.float64)[plan.sampled]
+        if plan.live is not None:
+            counts = counts * plan.live
+        return int(counts.sum()), int(plan.padded_slots)
 
     # -- host round pipeline -------------------------------------------------
 
@@ -864,14 +814,14 @@ class FedAvgAPI:
         from fedml_tpu.data.pipeline import materialize_cohort
         from fedml_tpu.utils.dtypes import host_bf16_cast
 
-        if plan is not None:
-            sampled, live, bucket = plan
-        else:
-            # prefetcher path: this build's plan is the one the consuming
-            # round's pulse hook will want — stash it so pulse-on pipelined
-            # runs don't re-pay the sampling draw on the critical path
-            sampled, live, bucket = self._round_plan(round_idx)
-            self._stash_plan(round_idx, sampled, live)
+        if plan is None:
+            # prefetcher path: this build's plan is THE round's plan — the
+            # consuming round (its lens ids, its pulse hook) reads it from
+            # the stash instead of re-paying the sampling draw on the
+            # critical path
+            plan = self._round_plan(round_idx)
+            self._stash_plan(round_idx, plan)
+        sampled, live, bucket = plan.sampled, plan.live, plan.bucket
         cx, cy, cm, counts = materialize_cohort(
             self.dataset, sampled, pool, n_chunks)
         if bucket is not None:
@@ -975,13 +925,6 @@ class FedAvgAPI:
         self._stream_mode_memo = mode
         return mode
 
-    def _stream_packed_active(self) -> bool:
-        """Whether streamed chunks ride the packed-lanes round program
-        (pack_lanes > 0): clients packed back-to-back in scan lanes, so a
-        chunk executes ~ceil(count/bs) real batches per client instead of
-        the shared bucket length."""
-        return self.config.pack_lanes > 0 and self._stream_mode() != "off"
-
     def _counts_view(self, dtype) -> "np.ndarray":
         """Cached float view of the population counts table: the streamed
         chunk path indexes it once per sub-cohort and the pulse feed once
@@ -1016,7 +959,7 @@ class FedAvgAPI:
                 for s in range(0, cohort_n, chunk)]
 
     def _stream_chunk_inputs(self, round_idx: int, ci: int, pool=None,
-                             n_chunks: int = 0):
+                             n_chunks: int = 0, plan=None):
         """Host-side inputs for ONE sub-cohort chunk — pure in
         (seed, round_idx, ci) like _host_round_inputs: materialize just the
         chunk's clients, trim to the ROUND's shared bucket (vmap chunks —
@@ -1024,15 +967,17 @@ class FedAvgAPI:
         replay tables), bf16-cast, zero failed clients' weights, and derive
         the full-round-normalized aggregation weights the deterministic
         fold needs (the total weight is known from the plan, so the fold
-        can use exactly tree_weighted_mean's normalize-first arithmetic)."""
+        can use exactly tree_weighted_mean's normalize-first arithmetic).
+        ``plan`` passes the round's plan where the caller has it (the
+        serial path); a prefetcher build makes its own."""
         from fedml_tpu.data.pipeline import materialize_cohort
         from fedml_tpu.utils.dtypes import host_bf16_cast
 
-        sampled, live, bucket = self._round_plan(round_idx)
-        if ci == 0:
-            self._stash_plan(round_idx, sampled, live)
+        if plan is None:
+            plan = self._round_plan(round_idx)
+        sampled, live, bucket = plan.sampled, plan.live, plan.bucket
         start, size = self._stream_chunk_spec(len(sampled))[ci]
-        packed = self._stream_packed_active()
+        packed = plan.path == PATH_STREAM_PACKED
         cx, cy, cm, counts = materialize_cohort(
             self.dataset, sampled[start:start + size], pool, n_chunks)
         if bucket is not None and not packed:
@@ -1161,8 +1106,8 @@ class FedAvgAPI:
         step = (_donation_quiet(jax.jit(chunk_step,
                                         donate_argnums=(1, 4, 5, 6)))
                 if self.config.donate else jax.jit(chunk_step))
-        step.lane_ids = self._lane_ids(shape_key[0], pconv)
-        return step
+        return self._tag_packed_program(step, shape_key[0], pconv,
+                                        cost_hints=False)
 
     def _stream_finish(self, packed: bool):
         """Round-close for the streaming fold: elastic all-failed rollback
@@ -1194,20 +1139,19 @@ class FedAvgAPI:
             self._stream_finish_fn = (finish_vmap, finish_packed)
         return self._stream_finish_fn[1 if packed else 0]
 
-    def _run_streaming_round(self, round_idx: int):
+    def _run_streaming_round(self, round_idx: int, plan: RoundPlan):
         """Execute one host round as streamed sub-cohort chunks: each chunk
         materializes (prefetched when the pipeline is on), trains, and
         folds into the running accumulator as it finishes on device —
-        server memory is ONE f32 model sum regardless of cohort size."""
+        server memory is ONE f32 model sum regardless of cohort size.
+        Unchunked deterministic mode computes the batch program's
+        arithmetic bit-for-bit."""
         c = self.config
         rk = round_key(self.root_key, round_idx)
-        with span(SPAN_PLAN, round=round_idx):
-            sampled, live, bucket = self._round_plan(round_idx, record=True)
-            self._stash_plan(round_idx, sampled, live)
-            spec = self._stream_chunk_spec(len(sampled))
+        cohort_n = len(plan.sampled)
+        spec = self._stream_chunk_spec(cohort_n)
         C = len(spec)
-        cohort_n = len(sampled)
-        packed = self._stream_packed_active()
+        packed = plan.path == PATH_STREAM_PACKED
         acc = jax.tree.map(lambda v: jnp.zeros(v.shape, jnp.float32),
                            self.variables)
         acc_w = jnp.zeros((), jnp.float32)
@@ -1224,34 +1168,29 @@ class FedAvgAPI:
             else:
                 t0 = time.perf_counter()
                 with span(SPAN_MATERIALIZE, round=round_idx, chunk=ci):
-                    payload, meta = self._stream_chunk_inputs(round_idx, ci)
+                    payload, meta = self._stream_chunk_inputs(
+                        round_idx, ci, plan=plan)
                 dt = (time.perf_counter() - t0) * 1e3
                 mat_ms += dt
                 wait_ms += dt    # serial: the host stage is fully exposed
             cx, cy, cm, counts, w_norm = payload
             t0 = time.perf_counter()
             if packed:
-                from fedml_tpu.parallel.packed import (plan_arrays_tuple,
-                                                       plan_packing)
+                from fedml_tpu.parallel.packed import plan_arrays_tuple
 
-                with span(SPAN_PLAN, round=round_idx, chunk=ci):
-                    raw = self._counts_view(np.float64)[
-                        sampled[start:start + size]]
-                    plan = plan_packing(
-                        raw, c.batch_size, c.epochs, c.pack_lanes,
-                        t_quantum=max(1, c.bucket_quantum_batches // 4))
-                key = ("p", cohort_n, start, size, plan.shape_key)
+                lanes = plan.lanes[ci]
+                key = ("p", cohort_n, start, size, lanes.shape_key)
                 step = self._lru_step(
                     self._stream_steps, key,
                     lambda: self.build_round_step_stream_packed(
-                        cohort_n, start, size, plan.shape_key),
+                        cohort_n, start, size, lanes.shape_key),
                     "stream_step")
                 with span(SPAN_ENQUEUE, round=round_idx, chunk=ci):
                     acc, acc_w, acc_loss = step(
                         self.variables, acc, acc_w, acc_loss, cx, cy, cm,
                         jnp.asarray(counts), rk,
                         tuple(jnp.asarray(a)
-                              for a in plan_arrays_tuple(plan)))
+                              for a in plan_arrays_tuple(lanes)))
             else:
                 key = ("v", cohort_n, start, size, meta[3])
                 step = self._lru_step(
@@ -1380,12 +1319,13 @@ class FedAvgAPI:
         independent, the determinism mode tools/xdev_ab.py --policy pins."""
         self._cohort_sched.set_static_profile(source)
 
-    def _stash_plan(self, round_idx: int, sampled, live) -> None:
-        """Record a computed round plan for :meth:`_pulse_cohort` (single
-        dict store under the GIL — the prefetcher's background builds and
-        the main thread may both write, always to distinct round keys)."""
+    def _stash_plan(self, round_idx: int, plan: RoundPlan) -> None:
+        """Record a computed round plan for its later readers (the host
+        round's lens ids, :meth:`_pulse_cohort`); a single dict store under
+        the GIL — the prefetcher's background builds and the main thread
+        may both write, always to distinct round keys."""
         stash = self._plan_stash
-        stash[int(round_idx)] = (sampled, live)
+        stash[int(round_idx)] = plan
         while len(stash) > 16:   # bound: pipeline depth + slack
             stash.pop(next(iter(stash)))
 
@@ -1398,14 +1338,11 @@ class FedAvgAPI:
         train a different population than the sampled cohort (the
         decentralized gossip family trains EVERY node) override this —
         otherwise the pulse stream would profile a phantom cohort."""
-        plan = self._plan_stash.pop(int(round_idx), None)
-        if plan is not None:
-            sampled, live = plan
-        else:
-            sampled, live, _bucket = self._round_plan(round_idx)
-        ids = np.asarray(sampled, np.int64)
-        if live is not None:
-            ids = ids[np.asarray(live) > 0]
+        plan = (self._plan_stash.pop(int(round_idx), None)
+                or self._round_plan(round_idx))
+        ids = np.asarray(plan.sampled, np.int64)
+        if plan.live is not None:
+            ids = ids[np.asarray(plan.live) > 0]
         return ids
 
     # -- fedlens (obs/lens.py) ----------------------------------------------
@@ -1481,125 +1418,96 @@ class FedAvgAPI:
         return c / total if total > 0 else None
 
     def _run_round_inner(self, round_idx: int) -> "float | jax.Array":
-        rk = round_key(self.root_key, round_idx)
-        if self._dev_train is not None:
+        plan = None
+        if self._path != PATH_HOST_PIPELINE:
             with span(SPAN_PLAN, round=round_idx):
-                sampled, live, bucket = self._round_plan(round_idx, record=True)
-                self._stash_plan(round_idx, sampled, live)
-                live_np = (np.ones((len(sampled),), np.float32) if live is None
-                           else np.asarray(live, np.float32))
-            if self.config.pack_lanes > 0:
-                out = self._run_packed_round(sampled, live, rk, round_idx)
-                if out is not None:
-                    self.variables, self.server_state, train_loss = out
-                    return (train_loss if self.config.async_rounds
-                            else float(train_loss))
-            with span(SPAN_PLAN, round=round_idx):
-                plan = self._round_groups(sampled, live)
-            if plan is not None:
-                perm, groups = plan
-                step = self._lru_step(
-                    self._group_steps, groups,
-                    lambda: self.build_round_step_gather_groups(groups),
-                    "group_step")
-                with span(SPAN_ENQUEUE, round=round_idx):
-                    out = step(
-                        self.variables, self.server_state, *self._dev_train,
-                        jnp.asarray(sampled[perm], jnp.int32),
-                        jnp.asarray(live_np[perm]),
-                        jnp.asarray(perm, jnp.int32), rk
-                    )
-                self.variables, self.server_state, train_loss = \
-                    self._lens_absorb(round_idx, out,
-                                      np.asarray(sampled, np.int64)[perm],
-                                      live_np[perm] > 0)
-                return train_loss if self.config.async_rounds else float(train_loss)
-            if bucket is None:
-                step = self._round_step_gather
-            else:
-                step = self._lru_step(
-                    self._gather_steps, bucket,
-                    lambda: self.build_round_step_gather(bucket),
-                    "gather_step")
-            with span(SPAN_ENQUEUE, round=round_idx):
-                out = step(
-                    self.variables, self.server_state, *self._dev_train,
-                    jnp.asarray(sampled, jnp.int32), jnp.asarray(live_np), rk
-                )
-            self.variables, self.server_state, train_loss = \
-                self._lens_absorb(round_idx, out, sampled, live_np > 0)
-        else:
-            if self._stream_mode() != "off":
-                # fedsched streaming path: sub-cohort chunks fold into the
-                # running accumulator as they finish (O(1) server memory
-                # in cohort size); unchunked deterministic mode computes
-                # the batch program's arithmetic bit-for-bit
-                return self._run_streaming_round(round_idx)
-            pf = self._host_prefetcher()
-            if pf is not None:
-                # pipelined: the background build computes the full plan
-                # itself, so only the record=True side effects (failure
-                # history + log) run here — NOT the O(client_num_in_total)
-                # sampling draw, which would sit on the critical path this
-                # pipeline exists to clear
-                self._sample_failures(
-                    round_idx,
-                    min(self.config.client_num_per_round,
-                        self.dataset.num_clients), record=True)
-                with span(SPAN_WAIT_INPUTS, round=round_idx):
-                    (cx, cy, cm, counts), stages, wait_ms = pf.pop(round_idx)
-                step = self._host_pipeline_step()
-            else:
-                t0 = time.perf_counter()
-                with span(SPAN_PLAN, round=round_idx):
-                    sampled, live, bucket = self._round_plan(
-                        round_idx, record=True)
-                    self._stash_plan(round_idx, sampled, live)
-                with span(SPAN_MATERIALIZE, round=round_idx):
-                    cx, cy, cm, counts = self._host_round_inputs(
-                        round_idx, plan=(sampled, live, bucket))
-                mat_ms = (time.perf_counter() - t0) * 1e3
-                # serial: the host stages are fully exposed (wait == work)
-                stages, wait_ms = {"materialize_ms": mat_ms, "h2d_ms": 0.0}, mat_ms
-                step = self._round_step
-            t0 = time.perf_counter()
-            with span(SPAN_ENQUEUE, round=round_idx):
-                out = step(
-                    self.variables, self.server_state, cx, cy, cm,
-                    jnp.asarray(counts, jnp.float32), rk
-                )
-            if len(out) == 4:
-                # host-path cohort order is the stashed plan's sampled
-                # order; the prefetcher stashes its plans too, so the id
-                # mapping survives pipelining (absent plan = lens skipped)
-                plan_s = self._plan_stash.get(int(round_idx))
-                if plan_s is not None:
-                    s_ids, s_live = plan_s
-                    out = self._lens_absorb(
-                        round_idx, out, s_ids,
-                        None if s_live is None else np.asarray(s_live) > 0)
-                else:
-                    out = out[:3]
-            self.variables, self.server_state, train_loss = out
-            if not self.config.async_rounds:
-                train_loss = float(train_loss)
-            row = dict(stages, wait_ms=wait_ms, round=round_idx,
-                       compute_ms=(time.perf_counter() - t0) * 1e3)
-            self._stage_rows.append(row)
-            from fedml_tpu.obs import default_registry, tracer_if_sampled
-
-            # the registry's stage-row record mirrors _stage_rows (the
-            # round_stats view) so registry readers (MetricsLogger,
-            # tests) see the same numbers the summary reports; the trace
-            # analyzer gets its copy via the host_stages counter below
-            default_registry().append_row("stage", row)
-            tr = tracer_if_sampled(0, round_idx)
-            if tr is not None:
-                tr.counter("host_stages", {
-                    k: row[k] for k in
-                    ("materialize_ms", "h2d_ms", "compute_ms", "wait_ms")},
-                    args={"round": round_idx})
+                plan = self._round_plan(round_idx, record=True)
+                self._stash_plan(round_idx, plan)
+        runner = self._ROUND_RUNNERS[self._path if plan is None
+                                     else plan.path]
+        train_loss = runner(self, round_idx, plan)
         return train_loss if self.config.async_rounds else float(train_loss)
+
+    def _run_host_round(self, round_idx: int, plan: RoundPlan):
+        """The serial host path: materialize the plan's cohort, ship it,
+        run the round program; the host stages are fully exposed."""
+        t0 = time.perf_counter()
+        with span(SPAN_MATERIALIZE, round=round_idx):
+            inputs = self._host_round_inputs(round_idx, plan=plan)
+        mat_ms = (time.perf_counter() - t0) * 1e3
+        return self._host_step(
+            round_idx, plan, self._round_step, inputs,
+            {"materialize_ms": mat_ms, "h2d_ms": 0.0}, wait_ms=mat_ms)
+
+    def _run_pipelined_round(self, round_idx: int, plan):
+        """The pipelined host path (``plan`` is None here): the
+        prefetcher's background build made this round's plan (and stashed
+        it) and its device-resident inputs, so only the record=True side
+        effects (failure history + log) run here — NOT the
+        O(client_num_in_total) sampling draw, which would sit on the
+        critical path this pipeline exists to clear."""
+        self._sample_failures(
+            round_idx,
+            min(self.config.client_num_per_round, self.dataset.num_clients),
+            record=True)
+        with span(SPAN_WAIT_INPUTS, round=round_idx):
+            inputs, stages, wait_ms = self._host_prefetcher().pop(round_idx)
+        return self._host_step(
+            round_idx, self._plan_stash.get(int(round_idx)),
+            self._host_pipeline_step(), inputs, stages, wait_ms)
+
+    def _host_step(self, round_idx: int, plan: Optional[RoundPlan], step,
+                   inputs, stages: dict, wait_ms: float):
+        """The host paths' shared tail: run ``step`` on the shipped cohort
+        and record the round's stage row."""
+        cx, cy, cm, counts = inputs
+        rk = round_key(self.root_key, round_idx)
+        t0 = time.perf_counter()
+        with span(SPAN_ENQUEUE, round=round_idx):
+            out = step(
+                self.variables, self.server_state, cx, cy, cm,
+                jnp.asarray(counts, jnp.float32), rk
+            )
+        if len(out) == 4:
+            # host-path cohort order is the plan's sampled order; the
+            # prefetcher stashes its plans too, so the id mapping survives
+            # pipelining (absent plan = lens skipped)
+            if plan is not None:
+                out = self._lens_absorb(
+                    round_idx, out, plan.sampled,
+                    None if plan.live is None else np.asarray(plan.live) > 0)
+            else:
+                out = out[:3]
+        self.variables, self.server_state, train_loss = out
+        if not self.config.async_rounds:
+            train_loss = float(train_loss)
+        row = dict(stages, wait_ms=wait_ms, round=round_idx,
+                   compute_ms=(time.perf_counter() - t0) * 1e3)
+        self._stage_rows.append(row)
+        from fedml_tpu.obs import default_registry, tracer_if_sampled
+
+        # the registry's stage-row record mirrors _stage_rows (the
+        # round_stats view) so registry readers (MetricsLogger,
+        # tests) see the same numbers the summary reports; the trace
+        # analyzer gets its copy via the host_stages counter below
+        default_registry().append_row("stage", row)
+        tr = tracer_if_sampled(0, round_idx)
+        if tr is not None:
+            tr.counter("host_stages", {
+                k: row[k] for k in
+                ("materialize_ms", "h2d_ms", "compute_ms", "wait_ms")},
+                args={"round": round_idx})
+        return train_loss
+
+    #: path -> the method that runs a round on it (``_run_round_inner``)
+    _ROUND_RUNNERS = {
+        PATH_PACKED: _run_packed_round,
+        PATH_GATHER: _run_gather_round,
+        PATH_HOST: _run_host_round,
+        PATH_HOST_PIPELINE: _run_pipelined_round,
+        PATH_STREAM: _run_streaming_round,
+        PATH_STREAM_PACKED: _run_streaming_round,
+    }
 
     def save(self, path: str, round_idx: int = 0, orbax: bool = False) -> None:
         """Checkpoint variables + server state (+ resume round). The
@@ -1725,9 +1633,7 @@ class FedAvgAPI:
 
     def _eval_at(self, r: int) -> bool:
         """Whether to run the periodic eval after round ``r`` (self.variables
-        holds the post-round-r model at that point). Subclasses whose
-        run_round advances state in blocks (super-step) override this to
-        align evals to block ends."""
+        holds the post-round-r model at that point)."""
         c = self.config
         return r % c.frequency_of_the_test == 0 or r == c.comm_round - 1
 
@@ -1788,40 +1694,53 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
         if config.cohort_vmap_width > 0:
             # the mesh round programs vmap each device's client block inside
             # shard_map; the chunked schedule applies to the simulation
-            # paradigm only (and measured FLAT there — mfu_experiments H4)
+            # paradigm only (where H5 / H11 put its optimum at 2, the width
+            # the packed lanes now run at by themselves)
             log.warning(
                 "cohort_vmap_width=%d ignored: the cross-silo mesh round "
                 "always vmaps the per-device client block",
                 config.cohort_vmap_width)
-        self._dev_sharded = self._dev_groups = self._group_plan = None
-        self._packed_mesh = None
+        # the mesh's own paths are resident, full-participation and static:
+        # one plan for every round (only ``live`` varies), made here. With
+        # neither, the round runs the host path the base class chose.
+        self._packed_mesh = self._dev_sharded = self._static_plan = None
+        everyone = np.arange(dataset.num_clients)
         if config.pack_lanes > 0:
             self._packed_mesh = self._mesh_packed_setup(cohort)
-        if self._packed_mesh is None:
-            plan = self._mesh_group_plan(cohort)
-            if plan is not None:
-                self._dev_groups = self._place_grouped(plan)
-                if self._dev_groups is not None:
-                    self._group_plan = plan
-                    self._grouped_step = self.build_round_step_grouped(len(plan))
-            if self._dev_groups is None:
-                self._dev_sharded = self._maybe_place_sharded(cohort)
+        if self._packed_mesh is not None:
+            lanes = self._packed_mesh["plan"]
+            self._static_plan = RoundPlan(
+                PATH_MESH_PACKED, everyone, None, None, lanes,
+                self._lane_slots(lanes))
+        else:
+            self._dev_sharded = self._maybe_place_sharded(cohort)
+            if self._dev_sharded is not None:
+                self._static_plan = RoundPlan(
+                    PATH_MESH_SHARDED, everyone, None, None, None,
+                    int(dataset.train_x.shape[1]) * dataset.num_clients)
+        if self._static_plan is not None:
+            self._path = self._static_plan.path
 
     def _resident_train_x(self):
-        if self._packed_mesh is not None:
+        if self._path == PATH_MESH_PACKED:
             return self._packed_mesh["data"][0]
-        if self._dev_groups is not None:
-            return self._dev_groups[0][0][0]
-        return None if self._dev_sharded is None else self._dev_sharded[0]
+        if self._path == PATH_MESH_SHARDED:
+            return self._dev_sharded[0]
+        return None
+
+    def _round_plan(self, round_idx: int, record: bool = False) -> RoundPlan:
+        if self._static_plan is None:
+            return super()._round_plan(round_idx, record)
+        plan = self._static_plan
+        return plan._replace(
+            live=self._round_live(round_idx, plan.sampled, record))
 
     def _mesh_packed_setup(self, cohort: int):
         """Resident placement + program for the packed mesh schedule
         (parallel/packed.py): per-device lanes, one psum tail. Returns None
-        when packing doesn't apply (falls back to grouped/sharded)."""
+        when packing doesn't apply (the resident-sharded path is next)."""
         from fedml_tpu.parallel.packed import (
-            impl_label,
             make_crosssilo_packed_round,
-            packed_conv_active,
             plan_packing_mesh,
             resolve_packed_conv,
         )
@@ -1878,17 +1797,8 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
                 self.bundle, self.task, n_pad, self.mesh,
                 packed_conv=pconv, **hooks,
                 **self._local_train_kwargs())
-            # fedcost packing hint: the per-DEVICE contraction folds
-            # lanes_dev clients (obs/cost.attribute_program)
-            active = packed_conv_active(self.bundle, pconv,
-                                        c.client_optimizer)
-            rf.cost_hints = {
-                "packed_conv": impl_label(pconv) if active else "off",
-                "packing_factor": int(plan.n_lanes // D)}
-            if active and not isinstance(pconv, str):
-                rf.cost_hints["plan"] = pconv
-            rf.lane_ids = self._lane_ids(plan.n_lanes // D, pconv)
-            return rf
+            # the per-DEVICE contraction folds lanes_dev clients
+            return self._tag_packed_program(rf, plan.n_lanes // D, pconv)
 
         round_fn = timed_build(
             "mesh_packed_round",
@@ -1927,227 +1837,6 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
              np.asarray(ds.train_counts, np.float32)),
         )
 
-    def _mesh_group_plan(self, cohort: int):
-        """Static grouped schedule for the resident-sharded full-participation
-        path — the mesh form of ``_round_groups``. Count-sorted clients are
-        dealt to devices in STRIPS (strip s = clients [sD, (s+1)D), one per
-        device), so strip scan lengths are global constants and the SPMD
-        program is identical on every device; consecutive strips are chunked
-        into at most ``bucket_groups`` groups whose scan length is the chunk's
-        quantum-rounded max count. Returns None (schedule off / nothing to
-        trim) or a tuple of (idx_g, scan_len_g): ``idx_g`` lists the group's
-        client indices DEVICE-MAJOR (shard d of the stacked group axis =
-        that device's strip slots)."""
-        c = self.config
-        ds = self.dataset
-        if c.device_data == "off" or cohort != ds.num_clients:
-            return None
-        D = self.mesh.shape["clients"]
-        L = ds.num_clients // D           # clients per device
-        if c.bucket_groups <= 1 or L < 2:
-            return None
-        n_pad = int(ds.train_x.shape[1])
-        q = c.bucket_quantum_batches * c.batch_size
-        if c.bucket_quantum_batches <= 0 or q >= n_pad:
-            return None
-        counts = np.asarray(ds.train_counts, np.float64)
-        strips = np.argsort(counts, kind="stable").reshape(L, D)
-        strip_max = counts[strips].max(axis=1)      # nondecreasing
-        merged = _chunk_buckets(strip_max, min(c.bucket_groups, L), q, n_pad)
-        if len(merged) == 1 and merged[0][2] >= n_pad:
-            return None                             # nothing to trim
-        return tuple((strips[a:b].T.reshape(-1), bucket) for a, b, bucket in merged)
-
-    def _place_grouped(self, plan):
-        """Resident placement for the grouped schedule: per group, the
-        stacked client arrays are gathered in plan order, TRUNCATED to the
-        group's scan length on host (saving the HBM the padding tail would
-        occupy), and sharded over the mesh. Returns (groups, counts) tuples
-        or None when the dataset is ineligible for residency."""
-        ds = self.dataset
-        n_slots = ds.num_clients * int(ds.train_x.shape[1])
-        kept = sum(len(idx_g) * bucket for idx_g, bucket in plan)
-        x = self._eligible_device_train_x(
-            shard_factor=self.mesh.shape["clients"],
-            slots_fraction=kept / max(n_slots, 1))
-        if x is None:
-            return None
-        from fedml_tpu.parallel.mesh import shard_client_batch
-
-        groups, counts = [], []
-        for idx_g, bucket in plan:
-            # single-step fancy index: produce ONLY the truncated copy
-            # (x[idx_g][:, :bucket] would materialize full padded rows first)
-            gx = x[idx_g, :bucket]
-            gy = np.asarray(ds.train_y)[idx_g, :bucket]
-            gm = np.asarray(ds.train_mask)[idx_g, :bucket]
-            placed = shard_client_batch(self.mesh, (
-                gx, gy, gm, np.asarray(ds.train_counts, np.float32)[idx_g]))
-            groups.append(placed[:3])
-            counts.append(placed[3])
-        return tuple(groups), tuple(counts)
-
-    def build_round_step_grouped(self, n_groups: int):
-        from fedml_tpu.parallel.crosssilo import make_crosssilo_round_grouped
-        from fedml_tpu.parallel.mesh import client_sharded, global_put, replicated
-
-        round_fn = make_crosssilo_round_grouped(
-            self._local_train, self.mesh, n_groups,
-            **self._crosssilo_hooks_checked())
-        rep, sh = replicated(self.mesh), client_sharded(self.mesh)
-
-        def round_step(variables, server_state, groups, counts, rng):
-            # every client keeps the per-round key of its ORIGINAL index, so
-            # the grouped schedule changes only the padding steps a client
-            # burns, never which randomness it consumes
-            keys_full = jax.random.split(rng, self.dataset.num_clients)
-            if jax.process_count() == 1:   # device-side gather (hot path)
-                keys = tuple(jax.device_put(keys_full[idx_g], sh)
-                             for idx_g, _ in self._group_plan)
-            else:                          # global_put handles typed keys
-                keys = tuple(global_put(keys_full[idx_g], sh)
-                             for idx_g, _ in self._group_plan)
-            variables = global_put(variables, rep)
-            server_state = global_put(server_state, rep)
-            return round_fn(variables, server_state, groups, counts, keys,
-                            global_put(rng, rep))
-
-        return round_step
-
-    def _superstep_h(self) -> int:
-        """Effective super-step length: disabled (1) when checkpointing
-        would land MID-block — inside a block self.variables holds the
-        block-end state, so a mid-block checkpoint would double-apply
-        rounds on resume (review r5). Periodic evals no longer disable the
-        super-step: _eval_at aligns them to block ends with true round
-        labels (ADVICE r5 medium — the old block-START guard reported the
-        post-block model under the start round's label, shifting
-        convergence curves by h-1 rounds)."""
-        h = self.config.rounds_per_step
-        if h <= 1:
-            return 1
-        c = self.config
-        if getattr(c, "checkpoint_dir", None) or getattr(c, "resume_from", None):
-            if not getattr(self, "_warned_ss", False):
-                log.warning("rounds_per_step=%d ignored: checkpointing "
-                            "needs per-round state", h)
-                self._warned_ss = True
-            return 1
-        return h
-
-    def _eval_at(self, r: int) -> bool:
-        """Super-step blocks advance self.variables to the BLOCK-END state
-        on the block's first round, so evals only run at block ends — at
-        which point self.variables is exactly the post-round-r model — and
-        a block end evals iff its block contains a round the plain-path
-        schedule would have evaluated (or it is the final round)."""
-        h = self._superstep_h()
-        if h <= 1 or self._packed_mesh is None:
-            return super()._eval_at(r)
-        c = self.config
-        if c.failure_prob:
-            # failure injection forces run_round onto the per-round path
-            # (live mask every round), so variables ARE post-round-r state
-            # at every r — keep the plain eval schedule
-            return super()._eval_at(r)
-        if r == c.comm_round - 1:
-            return True
-        base = getattr(self, "_ss_base", 0)
-        if (r - base + 1) % h != 0:
-            return False               # mid-block: variables are from the future
-        start = r - h + 1
-        return any(k % c.frequency_of_the_test == 0 for k in range(start, r + 1))
-
-    def _packed_superstep_fn(self, h: int):
-        """One jitted program running ``h`` packed rounds as a lax.scan over
-        round keys — the fixed per-round cost (dispatch, program prologue,
-        aggregation tail serialization) is paid once per h rounds instead of
-        every round (the weak-scaling intercept lever, docs/perf.md)."""
-        pm = self._packed_mesh
-        # scan the RAW round body: scanning the jitted wrapper drags the
-        # loop-invariant resident data into the while carry (per-iteration
-        # full-tensor copies — measured 14-28x slower on the chip)
-        inner = pm["round_fn"].raw
-
-        @jax.jit
-        def super_fn(variables, server_state, tx, ty, tm, w_dev, perm, rks,
-                     plan_arrays):
-            def body(carry, rk):
-                v, s = carry
-                v, s, loss = inner(v, s, tx, ty, tm, w_dev, perm, rk,
-                                   plan_arrays)
-                return (v, s), loss
-
-            # unroll=h: the rolled while-form measured ~4x slower per
-            # iteration than the standalone round (CPU and TPU both)
-            # despite identical per-iteration cost-model flops — unrolling
-            # keeps the one-dispatch amortization without while mechanics
-            (v, s), losses = jax.lax.scan(body, (variables, server_state),
-                                          rks, unroll=h)
-            return v, s, losses
-
-        hints = getattr(pm["round_fn"], "cost_hints", None)
-        if hints is not None:
-            super_fn.cost_hints = hints  # fedpack: same packed GEMMs x h
-        return super_fn
-
-    def _run_superstep(self, start: int, blk: int, w):
-        """Compute one super-step block and cache its per-round losses.
-
-        Trace semantics (DESIGN.md §12): the block is ONE device program, so
-        it emits ONE ``superstep`` span annotated with its covered round
-        range, plus ``blk`` amortized ``mesh_round`` child spans (each
-        dur/blk, evenly placed) so per-round views of the timeline still
-        decompose — amortized attribution, flagged as such, because the scan
-        gives the tracer no real per-round boundary to observe. Under
-        ``--trace_sample_rate`` the sampling unit is the whole BLOCK, keyed
-        by its starting round (the block is one program — per-round gating
-        inside it would tear the amortized children from their parent): a
-        sampled-out block emits nothing, so span volume stays bounded on
-        the superstep path too."""
-        from fedml_tpu.obs import timed_build, tracer_if_sampled
-        from fedml_tpu.parallel.mesh import shard_client_batch
-
-        pm = self._packed_mesh
-        fns = getattr(self, "_ss_fns", None)
-        if fns is None:
-            fns = self._ss_fns = {}
-        if blk not in fns:
-            fns[blk] = timed_build("superstep_fn", (blk,),
-                                   lambda: self._packed_superstep_fn(blk))
-        rks = jnp.stack([round_key(self.root_key, start + i)
-                         for i in range(blk)])
-        (w_dev,) = shard_client_batch(self.mesh, (w,))
-        # client-active exits ride the superstep too: masked w (caller) +
-        # masked plan arrays, picked up at each block START — a mid-block
-        # mask change takes effect at the next block boundary (the block
-        # is one device program; see set_client_active)
-        step_args = (self.variables, self.server_state, *pm["data"], w_dev,
-                     jnp.asarray(pm["perm"], jnp.int32), rks,
-                     self._mesh_plan_arrays())
-        tr = tracer_if_sampled(0, start)
-        if tr is None:
-            out = fns[blk](*step_args)
-        else:
-            ts0 = time.time_ns() // 1_000
-            t0 = time.perf_counter()
-            with tr.span("superstep", cat="device",
-                         args={"round_start": start,
-                               "round_end": start + blk - 1, "h": blk,
-                               "path": "packed_mesh"}) as sp:
-                out = fns[blk](*step_args)
-            slice_us = max(int((time.perf_counter() - t0) * 1e6) // blk, 1)
-            for i in range(blk):
-                tr.emit_complete(
-                    "mesh_round", cat="device",
-                    ts_us=ts0 + i * slice_us, dur_us=slice_us,
-                    parent_id=sp.span_id,
-                    args={"round": start + i, "amortized": True,
-                          "path": "packed_mesh",
-                          "superstep": [start, start + blk - 1]})
-        self.variables, self.server_state, losses = out
-        return losses
-
     def _mesh_plan_arrays(self):
         """The packed-mesh plan arrays, with the Silo client-active mask
         applied as a STRUCTURAL lane freeze (mask_plan_arrays) when set —
@@ -2171,113 +1860,46 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
         self._masked_mesh_plan = (self._client_active_version, placed)
         return placed
 
-    def _run_round_inner(self, round_idx: int) -> float:
-        if self._packed_mesh is not None:
-            from fedml_tpu.parallel.mesh import shard_client_batch
+    def _run_mesh_packed_round(self, round_idx: int, plan: RoundPlan):
+        from fedml_tpu.parallel.mesh import shard_client_batch
 
-            pm = self._packed_mesh
-            live = self._sample_failures(round_idx, self.dataset.num_clients)
-            w = pm["counts_perm"]
-            if self._client_active is not None:
-                # weight-zero exits everywhere; the packed program also gets
-                # the structural lane freeze via _mesh_plan_arrays
-                w = w * np.asarray(self._client_active, np.float32)[pm["perm"]]
-            h = self._superstep_h()
-            if h > 1 and live is None:
-                # super-step block: round_idx falls in block
-                # [start, start+h); compute the whole block once, hand out
-                # the cached per-round device losses. A block's FIRST round
-                # always recomputes, so re-running the same rounds (the
-                # bench's warm+timed passes) re-executes like the plain path.
-                if not hasattr(self, "_ss_base"):
-                    self._ss_base = round_idx
-                start = ((round_idx - self._ss_base) // h) * h + self._ss_base
-                # the tail block is clamped so the scan NEVER trains rounds
-                # past the federation's total (review r5: comm_round % h)
-                done_before = start - self._ss_base
-                blk = min(h, self.config.comm_round - done_before)
-                cached = getattr(self, "_ss_cache", None)
-                if cached is None or cached[0] != start or round_idx == start:
-                    losses = self._run_superstep(start, blk, w)
-                    self._ss_cache = cached = (start, losses)
-                train_loss = cached[1][round_idx - start]
-                return (train_loss if self.config.async_rounds
-                        else float(train_loss))
-            if live is not None:
-                w = w * np.asarray(live, np.float32)[pm["perm"]]
-            rk = round_key(self.root_key, round_idx)
-            (w_dev,) = shard_client_batch(self.mesh, (w,))
-            self.variables, self.server_state, train_loss = \
-                self._traced_device_step(
-                    "packed_mesh", round_idx, pm["round_fn"],
-                    self.variables, self.server_state, *pm["data"], w_dev,
-                    jnp.asarray(pm["perm"], jnp.int32), rk,
-                    self._mesh_plan_arrays())
-            return train_loss if self.config.async_rounds else float(train_loss)
-        if self._dev_groups is not None:
-            groups, counts_res = self._dev_groups
-            live = self._sample_failures(round_idx, self.dataset.num_clients)
-            if self._client_active is not None:
-                live = (self._client_active if live is None
-                        else live * self._client_active)
-            if live is not None:
-                counts = tuple(
-                    c * jnp.asarray(live[idx_g], jnp.float32)
-                    for c, (idx_g, _) in zip(counts_res, self._group_plan))
-            else:
-                counts = counts_res
-            rk = round_key(self.root_key, round_idx)
-            self.variables, self.server_state, train_loss = \
-                self._traced_device_step(
-                    "grouped", round_idx, self._grouped_step,
-                    self.variables, self.server_state, groups, counts, rk)
-            return train_loss if self.config.async_rounds else float(train_loss)
-        if self._dev_sharded is None:
-            return super()._run_round_inner(round_idx)
+        pm = self._packed_mesh
+        w = pm["counts_perm"]
+        if plan.live is not None:
+            # weight-zero failures and exits; an exit also gets the
+            # structural lane freeze via _mesh_plan_arrays
+            w = w * np.asarray(plan.live, np.float32)[pm["perm"]]
+        rk = round_key(self.root_key, round_idx)
+        (w_dev,) = shard_client_batch(self.mesh, (w,))
+        self.variables, self.server_state, train_loss = \
+            self._traced_device_step(
+                "packed_mesh", round_idx, pm["round_fn"],
+                self.variables, self.server_state, *pm["data"], w_dev,
+                jnp.asarray(pm["perm"], jnp.int32), rk,
+                self._mesh_plan_arrays())
+        return train_loss
+
+    def _run_mesh_sharded_round(self, round_idx: int, plan: RoundPlan):
         cx, cy, cm, counts = self._dev_sharded
-        live = self._sample_failures(round_idx, self.dataset.num_clients)
-        if self._client_active is not None:
-            live = (self._client_active if live is None
-                    else live * self._client_active)
-        if live is not None:
-            counts = counts * jnp.asarray(live, jnp.float32)
+        if plan.live is not None:
+            counts = counts * jnp.asarray(plan.live, jnp.float32)
         rk = round_key(self.root_key, round_idx)
         out = self._traced_device_step(
             "sharded", round_idx, self._round_step,
             self.variables, self.server_state, cx, cy, cm, counts, rk)
         # fedlens (plain mesh): full participation in dataset order, so the
-        # logical ids are simply arange; failure/exit masks drop zero-weight
+        # logical ids are the plan's; failure/exit masks drop zero-weight
         # clients from the stash host-side
         self.variables, self.server_state, train_loss = self._lens_absorb(
-            round_idx, out,
-            np.arange(self.dataset.num_clients, dtype=np.int64),
-            None if live is None else np.asarray(live) > 0)
-        return train_loss if self.config.async_rounds else float(train_loss)
+            round_idx, out, plan.sampled,
+            None if plan.live is None else np.asarray(plan.live) > 0)
+        return train_loss
 
-    def round_counts(self, round_idx: int) -> tuple:
-        """Resident full-participation paths execute their own static
-        schedule (no per-round bucketing), so report exactly that: every
-        client's real records, and per-group size x scan_len (grouped) or
-        cohort x n_pad (plain) executed slots."""
-        if (self._packed_mesh is None and self._dev_groups is None
-                and self._dev_sharded is None):
-            return super().round_counts(round_idx)
-        counts = np.asarray(self.dataset.train_counts, np.float64)
-        live = self._sample_failures(round_idx, self.dataset.num_clients,
-                                     record=False)
-        if live is not None:
-            counts = counts * live
-        if self._client_active is not None:
-            counts = counts * self._client_active
-        if self._packed_mesh is not None:
-            plan = self._packed_mesh["plan"]
-            padded = (plan.executed_slots * self.config.batch_size
-                      // max(self.config.epochs, 1))
-        elif self._group_plan is not None:
-            padded = sum(len(idx_g) * bucket for idx_g, bucket in self._group_plan)
-        else:
-            padded = int(self.dataset.train_x.shape[1]) * self.dataset.num_clients
-        return int(counts.sum()), int(padded)
+    _ROUND_RUNNERS = {
+        **FedAvgAPI._ROUND_RUNNERS,
+        PATH_MESH_PACKED: _run_mesh_packed_round,
+        PATH_MESH_SHARDED: _run_mesh_sharded_round,
+    }
 
     def _crosssilo_hooks_checked(self) -> dict:
         hooks = self.crosssilo_hooks()

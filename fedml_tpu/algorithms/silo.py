@@ -43,8 +43,7 @@ class SiloRunner:
       becomes a structural no-op in the SAME compiled program
       (FedAvgAPI.set_client_active -> parallel/packed.mask_plan_arrays):
       masked lane freeze/exit, never a vmap fallback or a recompile.
-      Exits take effect from the next round (next superstep block on the
-      packed-mesh superstep path).
+      Exits take effect from the next round.
     """
 
     def __init__(

@@ -126,7 +126,8 @@ class StreamingFedAvgAPI(FedAvgAPI):
         dataset's materialized_rows) is identical to the serial path by
         construction. Payload maps cohort position -> (x, y, mask)."""
         t0 = time.perf_counter()
-        sampled, live, _bucket = self._round_plan(round_idx)
+        plan = self._round_plan(round_idx)
+        sampled, live = plan.sampled, plan.live
         keep = [int(p) for p in (range(len(sampled)) if live is None
                                  else np.flatnonzero(live > 0))]
         ids = [int(sampled[p]) for p in keep]
@@ -240,7 +241,8 @@ class StreamingFedAvgAPI(FedAvgAPI):
 
     def _run_round_inner(self, round_idx: int):
         # traced via the base run_round wrapper (one "round" span per round)
-        sampled, live, _bucket = self._round_plan(round_idx, record=True)
+        plan = self._round_plan(round_idx, record=True)
+        sampled, live = plan.sampled, plan.live
         rk = round_key(self.root_key, round_idx)
         keys = jax.random.split(rk, len(sampled))
         outs, losses, taus = [], [], []

@@ -38,7 +38,7 @@ RULES: Dict[str, str] = {
         "every config/args attribute read must name a defined flag or field"
     ),
     "trace-coverage": (
-        "run_round/run_superstep overrides must route through the fedtrace "
+        "run_round overrides must route through the fedtrace "
         "span wrapper (override _run_round_inner, delegate to super(), or "
         "open the span) so no paradigm drops out of the round timeline"
     ),

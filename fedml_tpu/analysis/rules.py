@@ -369,10 +369,9 @@ def check_config_flag_drift(
 
 # -------------------------------------------------------- trace-coverage
 
-#: the round entry points the fedtrace wrapper owns (fedavg.py run_round
-#: wraps _run_round_inner; run_superstep is the reserved name for a future
-#: block-granular public entry)
-_TRACED_ENTRY_POINTS = {"run_round", "run_superstep"}
+#: the round entry point the fedtrace wrapper owns (fedavg.py run_round
+#: wraps _run_round_inner)
+_TRACED_ENTRY_POINTS = {"run_round"}
 #: calls that prove a method opens the trace gate itself (the head-sampled
 #: gate counts: sampling is the gate's fedsketch form, not a bypass)
 _TRACE_GATES = {"tracer_if_enabled", "tracer_if_sampled", "get_tracer"}
@@ -381,8 +380,8 @@ _SPAN_OPENERS = {"span", "begin_span", "emit_complete"}
 
 
 def _is_super_delegation(node: ast.Call) -> bool:
-    """``super().run_round(...)`` / ``super().run_superstep(...)`` — the
-    override funnels back into the traced base wrapper."""
+    """``super().run_round(...)`` — the override funnels back into the
+    traced base wrapper."""
     f = node.func
     return (isinstance(f, ast.Attribute) and f.attr in _TRACED_ENTRY_POINTS
             and isinstance(f.value, ast.Call)
@@ -391,8 +390,7 @@ def _is_super_delegation(node: ast.Call) -> bool:
 
 
 def check_trace_coverage(pkg: PackageIndex, graph: TracedGraph) -> List[Finding]:
-    """Every ``run_round`` / ``run_superstep`` method must route through the
-    traced span wrapper (fedml_tpu/obs): fedtrace's one-timeline guarantee
+    """Every ``run_round`` method must route through the traced span wrapper (fedml_tpu/obs): fedtrace's one-timeline guarantee
     holds only because the base ``run_round`` is THE wrapper and paradigm
     logic lives in ``_run_round_inner``. An override of the entry point that
     neither opens a span itself nor delegates to ``super()`` silently drops

@@ -179,22 +179,13 @@ class FedConfig:
     # than an unbucketed run — same distribution, different trajectory.
     # Runs are still deterministic per (seed, config).
     bucket_quantum_batches: int = 8
-    # Split the sampled cohort into up to this many count-sorted groups,
-    # each with its own (quantum-rounded) scan length, inside ONE round
-    # program — small clients stop paying the largest client's padding
-    # steps. 1 = single shared scan length (the bucket above). Same
-    # weighted aggregate either way (group order is irrelevant to it);
-    # like bucketing itself, the truncated shuffle stream changes the
-    # trajectory, not the distribution. Device-resident (gather) path only.
-    bucket_groups: int = 1
     # Client-packing schedule (parallel/packed.py): pack the sampled cohort
     # into this many fixed-length scan lanes, clients back-to-back with
-    # optimizer reset at boundaries — padding shrinks from group-max
+    # optimizer reset at boundaries — padding shrinks from cohort-max
     # granularity to one batch per client plus the lane tail. 0 = off.
     # Each client's trajectory replays the canonical unbucketed program
-    # exactly; the aggregate matches up to float summation order. Overrides
-    # bucket_groups on the device-resident simulation path; serves every
-    # algorithm with a plain weighted mean OR a crosssilo_hooks contract
+    # exactly; the aggregate matches up to float summation order. Serves
+    # every algorithm with a plain weighted mean OR a crosssilo_hooks contract
     # (FedOpt/FedNova/FedAGC/robust — server state threads through the
     # packed round); only rewired build_local_train / hookless custom
     # aggregate() fall back, with a warning.
@@ -222,13 +213,6 @@ class FedConfig:
     # match the vmap lowering up to GEMM summation order
     # (tests/test_packed_conv.py, tests/test_packed_everywhere.py).
     packed_conv: str = "off"
-    # Cross-silo super-step: fold H consecutive rounds into ONE jitted
-    # program (lax.scan over round keys) on the packed resident-sharded
-    # mesh path — amortizes the fixed per-round cost (dispatch + program
-    # prologue/epilogue, the weak-scaling intercept of docs/perf.md) over
-    # H rounds. Requires full participation without failure injection;
-    # per-round losses still come back individually. 1 = off.
-    rounds_per_step: int = 1
     # lax.scan unroll factor for the local-SGD minibatch loop: XLA fuses
     # across adjacent steps (amortizing per-step loop/weight-traffic
     # overheads) without changing the math — same updates in the same
@@ -455,8 +439,6 @@ class FedConfig:
             raise ValueError(f"dtype must be float32|bfloat16, got {self.dtype!r}")
         if self.device_data not in ("auto", "on", "off"):
             raise ValueError(f"device_data must be auto|on|off, got {self.device_data!r}")
-        if self.bucket_groups < 1:
-            raise ValueError(f"bucket_groups must be >= 1, got {self.bucket_groups}")
         if self.pack_lanes < 0:
             raise ValueError(f"pack_lanes must be >= 0, got {self.pack_lanes}")
         if self.packed_conv not in ("off", "blockdiag", "grouped", "auto"):
@@ -509,9 +491,6 @@ class FedConfig:
                 "cohort_chunk > 0 needs stream_aggregate: sub-cohort chunks "
                 "only exist to be folded into the streaming accumulator — "
                 "set --stream_aggregate deterministic (or arrival)")
-        if self.rounds_per_step < 1:
-            raise ValueError(
-                f"rounds_per_step must be >= 1, got {self.rounds_per_step}")
         if self.host_pipeline_depth < 0:
             raise ValueError(
                 f"host_pipeline_depth must be >= 0, got {self.host_pipeline_depth}")
@@ -718,11 +697,6 @@ def add_args(parser: Optional[argparse.ArgumentParser] = None) -> argparse.Argum
                    default=defaults.device_data_max_bytes)
     p.add_argument("--bucket_quantum_batches", type=int,
                    default=defaults.bucket_quantum_batches)
-    p.add_argument("--bucket_groups", type=int, default=defaults.bucket_groups)
-    p.add_argument("--rounds_per_step", type=int,
-                   default=defaults.rounds_per_step,
-                   help="fold H cross-silo rounds into one scanned program "
-                        "(docs/mfu_experiments.md H7); 1 = off")
     p.add_argument("--pack_lanes", type=int, default=defaults.pack_lanes,
                    help="pack the cohort into N scan lanes (0 = off)")
     p.add_argument("--packed_conv", type=str, default=defaults.packed_conv,

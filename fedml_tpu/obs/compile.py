@@ -135,10 +135,10 @@ def timed_build(name: str, shape_key, builder: Callable) -> Callable:
             _cost.attribute_program(name, shape_key, fn, args)
         return out
 
-    # the packed mesh round carries its un-jitted body as `.raw` (the
-    # super-step scans it) and fedpack programs carry fedcost packing
-    # hints as `.cost_hints`; keep such sidecar attributes reachable
-    for attr in ("raw", "cost_hints", "lane_ids"):
+    # packed programs carry fedcost packing hints as `.cost_hints` and
+    # their lane geometry as `.lane_ids`; keep such sidecar attributes
+    # reachable
+    for attr in ("cost_hints", "lane_ids"):
         val = getattr(fn, attr, None)
         if val is not None:
             setattr(step, attr, val)

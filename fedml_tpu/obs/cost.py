@@ -645,14 +645,9 @@ def roofline(summary: dict, measured_s: float, invocations: float = 1.0,
 #: mesh-path tag for programs whose rounds carry fedscope ``mesh_step`` /
 #: ``mesh_round`` device spans — lets trace_report match a program's static
 #: cost to its measured device time; sim-paradigm programs have no device
-#: span and are matched against the round span instead. ``superstep_fn``
-#: deliberately gets its own tag that matches NO device rows: one
-#: invocation covers h rounds, so pairing it with single-round mesh_step
-#: spans would overstate achieved-FLOP/s by ~h — its table stays
-#: static-only (the superstep wall is reported separately by trace_report).
+#: span and are matched against the round span instead.
 PROGRAM_PATHS = {
     "mesh_packed_round": "packed_mesh",
-    "superstep_fn": "superstep",
 }
 
 _lock = threading.Lock()

@@ -195,61 +195,6 @@ def apply_server_and_rollback(variables0, agg, extras, total, server_state,
     return new_vars, new_state
 
 
-def make_crosssilo_round_grouped(
-    local_train: Callable,
-    mesh: Mesh,
-    n_groups: int,
-    axis: str = "clients",
-    client_transform: Callable | None = None,
-    reduce_extras: Callable | None = None,
-    server_update: Callable | None = None,
-):
-    """Grouped cross-silo round: the mesh counterpart of the simulation
-    paradigm's ``bucket_groups`` schedule (algorithms/fedavg.py
-    build_round_step_gather_groups). Clients are dealt to devices so that
-    every device's group ``g`` shares ONE static scan length (see
-    CrossSiloFedAvgAPI._mesh_group_plan); the round program then runs one
-    vmapped local-training scan per group — small clients stop burning the
-    biggest client's masked padding steps — and ONE psum tail aggregates all
-    groups together. SPMD-safe by construction: group sizes and scan lengths
-    are trace-time constants identical on every device.
-
-    Returns round_fn(variables, server_state, groups, counts, keys, rng)
-    -> (variables, server_state, loss) where ``groups`` is a tuple over g of
-    (cx, cy, cm) stacked [n_g, len_g, ...] sharded along ``axis`` (len_g is
-    the group's truncated record axis), ``counts``/``keys`` matching tuples
-    of [n_g] arrays, and variables/server_state/rng are replicated.
-    """
-    finish = _make_mesh_finish(axis, client_transform, reduce_extras, server_update)
-
-    def shard_fn(variables, server_state, groups, counts, keys, rng):
-        variables0 = variables
-        variables = jax.tree.map(
-            lambda x: jax.lax.pcast(x, axis_name=axis, to="varying"), variables
-        )
-        parts = [
-            jax.vmap(local_train, in_axes=(None, 0, 0, 0, 0, 0))(
-                variables, cx, cy, cm, cnt, k
-            )
-            for (cx, cy, cm), cnt, k in zip(groups, counts, keys)
-        ]
-        # group order is irrelevant to the weighted mean; concatenate the
-        # per-group cohorts back into one stacked axis for the shared tail
-        res = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-        counts_all = jnp.concatenate(counts, axis=0)
-        return finish(variables0, variables, server_state, res, counts_all, rng)
-
-    g_spec = tuple((P(axis), P(axis), P(axis)) for _ in range(n_groups))
-    v_spec = tuple(P(axis) for _ in range(n_groups))
-    mapped = shard_map(
-        shard_fn,
-        mesh=mesh,
-        in_specs=(P(), P(), g_spec, v_spec, v_spec, P()),
-        out_specs=(P(), P(), P()),
-    )
-    return jax.jit(mapped)
-
-
 def make_hierarchical_round(
     local_train: Callable,
     mesh: Mesh,

@@ -1,10 +1,10 @@
 """Client-packing schedule: many small clients share one scan lane.
 
-The bucketed/grouped schedules (algorithms/fedavg.py `_round_groups`,
-`_mesh_group_plan`) cut padding by giving count-sorted client groups their
-own scan lengths — but every client in a group still pads to the group max,
-which left 15% (sim) / 21% (mesh) of executed slots dead in round 3's
-bench. This module removes the group-max: the cohort is packed into a few
+The bucketed schedule (algorithms/fedavg.py `_round_bucket`) cuts padding
+by trimming the scan to the cohort's largest client — but every client
+still pads to that max (a grouped variant with one scan length per
+count-sorted group, deleted in PR 28, still left 15% (sim) / 21% (mesh) of
+executed slots dead). This module removes the max: the cohort is packed into a few
 fixed-length lanes (LPT balancing), each lane running its clients
 BACK-TO-BACK in one `lax.scan` with optimizer-state reset at client
 boundaries. Padding shrinks to the final partial batch of each client plus
@@ -967,8 +967,8 @@ def plan_packing_mesh(counts: np.ndarray, batch_size: int, epochs: int,
                       t_quantum: int = 1):
     """Mesh packing: deal clients to devices by capacity-constrained LPT
     (biggest client first to the least-loaded device with a free row — see
-    the inline comment for why this beats the `_mesh_group_plan` strip
-    deal here), pack each device's clients into its own lanes, and pad
+    the inline comment for why this beats a count-sorted strip deal
+    here), pack each device's clients into its own lanes, and pad
     every per-device plan to shared shapes (SPMD: one program, all
     devices).
 
@@ -1038,7 +1038,7 @@ def make_crosssilo_packed_round(
     """Mesh form of the packed schedule: each device runs its lanes (vmap of
     the SAME lane program the simulation paradigm uses), and ONE weighted
     psum tail aggregates all lanes' accumulators — the packed counterpart of
-    `make_crosssilo_round_grouped`, with the group-max padding replaced by
+    `make_crosssilo_round`, with the cohort-max padding replaced by
     one-batch-granularity lanes.
 
     The three hooks are the cross-silo contract (make_crosssilo_round):
@@ -1129,10 +1129,4 @@ def make_crosssilo_packed_round(
         return mapped(variables, server_state, tx, ty, tm, weights, keys,
                       plan_arrays, rng)
 
-    jitted = jax.jit(round_fn)
-    # the super-step (fedavg.py _packed_superstep_fn) scans the round body;
-    # scanning the JITTED form would drag the resident data into the while
-    # carry (measured: per-iteration full-tensor copies, 14-28x slower
-    # through the remote device) — it must trace the raw body instead
-    jitted.raw = round_fn
-    return jitted
+    return jax.jit(round_fn)

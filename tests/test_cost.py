@@ -214,8 +214,8 @@ def test_golden_flagship_round_program_table():
                     dtype="bfloat16", frequency_of_the_test=1000, seed=0,
                     pack_lanes=2, device_data="on")
     api = FedAvgAPI(ds, cfg, _flagship_bundle())
-    sampled, _live, _bucket = api._round_plan(1, record=False)
-    plan = api._packed_plan(sampled)
+    round_plan = api._round_plan(1, record=False)
+    sampled, plan = round_plan.sampled, round_plan.lanes
     step = api.build_round_step_packed(plan.shape_key)
     counts = np.asarray(ds.train_counts, np.float32)[sampled]
     plan_arrays = tuple(jnp.asarray(a) for a in (

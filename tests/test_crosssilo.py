@@ -74,111 +74,6 @@ class TestMeshHelpers:
         assert m.devices.shape == (2, 4)
 
 
-class TestCrossSiloGrouped:
-    """Grouped mesh schedule (bucket_groups on the resident-sharded path):
-    count-sorted clients dealt to devices in strips, one static scan length
-    per group — the SPMD form of the simulation paradigm's bucket_groups."""
-
-    def _ragged(self, clients=16, batch=4):
-        return make_synthetic_classification(
-            "xsilo-grouped", (6,), 3, clients, records_per_client=24,
-            partition_method="hetero", partition_alpha=0.3,
-            batch_size=batch, seed=3,
-        )
-
-    def _cfg(self, clients=16, **kw):
-        kw.setdefault("bucket_quantum_batches", 1)
-        kw.setdefault("bucket_groups", 3)
-        kw.setdefault("comm_round", 2)
-        return FedConfig(
-            model="lr", client_num_in_total=clients, client_num_per_round=clients,
-            epochs=1, batch_size=4, lr=0.2, seed=7,
-            frequency_of_the_test=100, device_data="on", **kw,
-        )
-
-    def test_plan_shape(self):
-        ds = self._ragged()
-        api = CrossSiloFedAvgAPI(
-            ds, self._cfg(), create_model("lr", ds.class_num, input_shape=ds.train_x.shape[2:]),
-            mesh=client_mesh(4),
-        )
-        plan = api._group_plan
-        assert plan is not None and api._dev_groups is not None
-        n_pad = int(ds.train_x.shape[1])
-        counts = np.asarray(ds.train_counts)
-        all_idx = np.concatenate([idx for idx, _ in plan])
-        assert sorted(all_idx.tolist()) == list(range(16))
-        for idx_g, bucket in plan:
-            assert len(idx_g) % 4 == 0 and bucket % 4 == 0
-            # the scan length covers every client in the group
-            assert counts[idx_g].max() <= bucket <= n_pad
-        real, padded = api.round_counts(1)
-        assert real == int(counts.sum())
-        assert padded == sum(len(i) * b for i, b in plan) < n_pad * 16
-
-    def test_matches_explicit_reference(self):
-        """One grouped mesh round == per-group vmapped local training on the
-        host + one weighted mean, with each client consuming the per-round
-        key of its original index."""
-        from fedml_tpu.core.pytree import tree_weighted_mean
-        from fedml_tpu.core.rng import round_key
-
-        ds = self._ragged()
-        cfg = self._cfg()
-        api = CrossSiloFedAvgAPI(
-            ds, cfg, create_model("lr", ds.class_num, input_shape=ds.train_x.shape[2:]),
-            mesh=client_mesh(4),
-        )
-        assert api._group_plan is not None
-        vars0 = api.variables
-        rk = round_key(api.root_key, 1)
-        keys_full = jax.random.split(rk, 16)
-        parts, weights = [], []
-        for idx_g, bucket in api._group_plan:
-            cx = jnp.asarray(np.asarray(ds.train_x)[idx_g][:, :bucket])
-            cy = jnp.asarray(np.asarray(ds.train_y)[idx_g][:, :bucket])
-            cm = jnp.asarray(np.asarray(ds.train_mask)[idx_g][:, :bucket])
-            cnt = jnp.asarray(np.asarray(ds.train_counts, np.float32)[idx_g])
-            parts.append(jax.vmap(api._local_train, in_axes=(None, 0, 0, 0, 0, 0))(
-                vars0, cx, cy, cm, cnt, keys_full[idx_g]))
-            weights.append(cnt)
-        stacked = jax.tree.map(lambda *xs: jnp.concatenate(xs, 0),
-                               *[p.variables for p in parts])
-        want = tree_weighted_mean(stacked, jnp.concatenate(weights))
-        api.run_round(1)
-        d = float(tree_global_norm(tree_sub(want["params"], api.variables["params"])))
-        s = float(tree_global_norm(want["params"]))
-        assert d / max(s, 1e-9) < 1e-5, d / s
-
-    def test_grouped_fedopt_hooks(self):
-        """Algorithm hooks (FedOpt's server optimizer) ride the grouped
-        program's shared psum tail; the round must run the grouped schedule
-        and stay finite with server state advancing."""
-        from fedml_tpu.algorithms.fedopt import CrossSiloFedOptAPI
-
-        ds = self._ragged()
-        cfg = self._cfg(server_optimizer="adam", server_lr=0.05)
-        api = CrossSiloFedOptAPI(
-            ds, cfg, create_model("lr", ds.class_num, input_shape=ds.train_x.shape[2:]),
-            mesh=client_mesh(4),
-        )
-        assert api._group_plan is not None
-        hist = api.train()
-        assert np.isfinite(hist["Test/Loss"][-1])
-
-    def test_grouped_failure_injection(self):
-        ds = self._ragged()
-        cfg = self._cfg(failure_prob=0.4, comm_round=4)
-        api = CrossSiloFedAvgAPI(
-            ds, cfg, create_model("lr", ds.class_num, input_shape=ds.train_x.shape[2:]),
-            mesh=client_mesh(4),
-        )
-        assert api._group_plan is not None
-        hist = api.train()
-        assert np.isfinite(hist["Test/Loss"][-1])
-        assert sum(hist.get("failed_clients", [])) > 0
-
-
 class TestHierarchicalMesh:
     """Distributed hierarchical FL on a 2-D ('group','clients') mesh must
     equal the single-device vmap simulator (HierarchicalFedAvgAPI): group

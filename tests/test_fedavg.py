@@ -3,7 +3,6 @@ federated (full participation, full batch, 1 local epoch) == centralized
 (CI-script-fedavg.sh:43-47) — an exact-math property of FedAvg."""
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -148,14 +147,19 @@ class TestAsyncRounds:
 
 
 class TestSampling:
-    def test_partial_participation_deterministic(self):
+    @pytest.mark.parametrize("pack_lanes", [0, 2])
+    def test_partial_participation_deterministic(self, pack_lanes):
+        """Two runs of one config agree to the bit: on the host path, and on
+        the packed lanes of the resident stack."""
         ds = _tiny_dataset()
         cfg = FedConfig(
             model="lr", client_num_in_total=4, client_num_per_round=2,
             comm_round=2, epochs=1, batch_size=8, lr=0.1, seed=3,
+            pack_lanes=pack_lanes, device_data="on" if pack_lanes else "auto",
         )
         a = FedAvgAPI(ds, cfg, create_model("lr", ds.class_num, input_shape=ds.train_x.shape[2:]))
         b = FedAvgAPI(ds, cfg, create_model("lr", ds.class_num, input_shape=ds.train_x.shape[2:]))
+        assert a._path == ("packed" if pack_lanes else "host")
         a.train(); b.train()
         d = float(tree_global_norm(tree_sub(a.variables["params"], b.variables["params"])))
         assert d == 0.0
@@ -265,129 +269,10 @@ class TestCohortBucketing:
         assert api._dev_train is not None
         hist = api.train()
         assert api._gather_steps, "bucketed rounds should compile bucket programs"
-        assert all(b % 2 == 0 and b < ds.train_x.shape[1] for b in api._gather_steps)
+        buckets = [b for b in api._gather_steps if b is not None]  # None: full
+        assert buckets and all(b % 2 == 0 and b < ds.train_x.shape[1]
+                               for b in buckets)
         assert hist["Test/Acc"][-1] > 0.5
-
-
-class TestBucketGroups:
-    """bucket_groups > 1: per-group scan lengths inside one round program.
-    The grouped program must compute exactly the same weighted aggregate as
-    running each group's vmap by hand with the same keys (white-box), cut
-    the padded-step count, and stay deterministic."""
-
-    def _ragged_ds(self, sizes=(4, 6, 10, 28, 30)):
-        rng = np.random.default_rng(5)
-        w_true = rng.normal(0, 1, (6, 3))
-        xs = [rng.normal(0, 1, (n, 6)).astype(np.float32) for n in sizes]
-        ys = [np.argmax(x @ w_true, axis=1).astype(np.int32) for x in xs]
-        from fedml_tpu.data import FedDataset
-        from fedml_tpu.data.batching import pad_and_stack_clients, pad_eval_pool
-
-        tx, ty, tm, tc = pad_and_stack_clients(xs, ys, 2)
-        ex, ey, em = pad_eval_pool(np.concatenate(xs), np.concatenate(ys), 8)
-        return FedDataset(train_x=tx, train_y=ty, train_mask=tm, train_counts=tc,
-                          test_x=ex, test_y=ey, test_mask=em, class_num=3,
-                          name="ragged5")
-
-    def _cfg(self, **kw):
-        kw.setdefault("comm_round", 4)
-        kw.setdefault("device_data", "on")
-        kw.setdefault("bucket_quantum_batches", 1)
-        return FedConfig(model="lr", client_num_in_total=5, client_num_per_round=4,
-                         batch_size=2, lr=0.3, frequency_of_the_test=100, **kw)
-
-    def test_round_groups_schedule(self):
-        ds = self._ragged_ds()
-        api = FedAvgAPI(ds, self._cfg(bucket_groups=2),
-                        create_model("lr", 3, input_shape=(6,)))
-        # counts 4,6,10,28 -> sorted; 2 groups: buckets ceil(6/2)*2=6 and
-        # n_pad-capped 28-rounding = 28
-        perm, groups = api._round_groups(np.array([0, 1, 2, 3]), None)
-        assert [int(x) for x in perm] == [0, 1, 2, 3]
-        assert groups == ((2, 6), (2, 28))
-        # equal-bucket groups merge into one, and any single-group schedule
-        # degenerates to None (the single-bucket path owns it)
-        assert api._round_groups(np.array([0, 0]), None) is None
-        assert api._round_groups(np.array([1, 1, 1, 1]), None) is None
-        # failure-masked big client doesn't inflate its group's bucket
-        perm2, groups2 = api._round_groups(
-            np.array([0, 1, 2, 4]), np.array([1.0, 1.0, 1.0, 0.0]))
-        assert groups2[-1][1] < ds.train_x.shape[1]
-        # bucket_groups=1 -> None (single-bucket path owns it)
-        api1 = FedAvgAPI(ds, self._cfg(bucket_groups=1),
-                         create_model("lr", 3, input_shape=(6,)))
-        assert api1._round_groups(np.array([0, 1, 2, 3]), None) is None
-
-    def test_grouped_step_matches_manual_composition(self):
-        """White-box exactness: the grouped program == per-group vmaps with
-        position-derived keys + the shared finish (same floats modulo
-        concat-order-independent reductions)."""
-        from fedml_tpu.core.rng import round_key, sample_clients
-
-        ds = self._ragged_ds()
-        api = FedAvgAPI(ds, self._cfg(bucket_groups=2),
-                        create_model("lr", 3, input_shape=(6,)))
-        assert api._dev_train is not None
-        sampled, live, _ = api._round_plan(1)
-        perm, groups = api._round_groups(sampled, live)
-        rk = round_key(api.root_key, 1)
-
-        # manual composition on host arrays
-        cohort = len(sampled)
-        keys = jax.random.split(rk, cohort)
-        s_sorted = sampled[perm]
-        tx, ty, tm, tc = api._dev_train
-        start = 0
-        parts = []
-        for size, bucket in groups:
-            sl = perm[start:start + size]
-            idx_g = sampled[sl]
-            cx = np.asarray(ds.train_x)[idx_g][:, :bucket]
-            cy = np.asarray(ds.train_y)[idx_g][:, :bucket]
-            cm = np.asarray(ds.train_mask)[idx_g][:, :bucket]
-            cnt = np.asarray(ds.train_counts, np.float32)[idx_g]
-            parts.append(jax.vmap(api._local_train, in_axes=(None, 0, 0, 0, 0, 0))(
-                api.variables, jnp.asarray(cx), jnp.asarray(cy), jnp.asarray(cm),
-                jnp.asarray(cnt), keys[sl]))
-            start += size
-        res = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-        counts_sorted = jnp.asarray(
-            np.asarray(ds.train_counts, np.float32)[s_sorted])
-        want_vars, _, want_loss = api._finish_round(
-            api.variables, api.server_state, res, counts_sorted, rk)
-
-        # the real grouped program
-        loss = api.run_round(1)
-        np.testing.assert_allclose(loss, float(want_loss), rtol=1e-5)
-        jax.tree_util.tree_map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
-            api.variables, want_vars)
-
-    def test_grouped_padded_counts_shrink_and_converge(self):
-        ds = self._ragged_ds()
-        api1 = FedAvgAPI(ds, self._cfg(bucket_groups=1, comm_round=25),
-                         create_model("lr", 3, input_shape=(6,)))
-        api2 = FedAvgAPI(ds, self._cfg(bucket_groups=2, comm_round=25),
-                         create_model("lr", 3, input_shape=(6,)))
-        padded1 = sum(api1.round_counts(r)[1] for r in range(25))
-        padded2 = sum(api2.round_counts(r)[1] for r in range(25))
-        real1 = sum(api1.round_counts(r)[0] for r in range(25))
-        real2 = sum(api2.round_counts(r)[0] for r in range(25))
-        assert real1 == real2            # same real work either way
-        assert padded2 < padded1         # grouping trims executed padding
-        hist = api2.train()
-        assert api2._group_steps, "grouped rounds should compile group programs"
-        assert hist["Test/Acc"][-1] > 0.5
-
-    def test_grouped_deterministic(self):
-        ds = self._ragged_ds()
-        r1 = FedAvgAPI(ds, self._cfg(bucket_groups=3, comm_round=4),
-                       create_model("lr", 3, input_shape=(6,))).train()
-        r2 = FedAvgAPI(ds, self._cfg(bucket_groups=3, comm_round=4),
-                       create_model("lr", 3, input_shape=(6,))).train()
-        assert r1["Test/Acc"] == r2["Test/Acc"]
-        assert r1["Test/Loss"] == r2["Test/Loss"]
 
 
 @pytest.mark.parametrize("dataset", ["synthetic_1_1", "synthetic_0_0",

@@ -278,9 +278,10 @@ def test_uniform_off_keeps_the_committed_round_plan(ds):
                                           input_shape=(16,)))
     try:
         for r in (1, 2, 9):
-            sampled, _live, _bucket = api._round_plan(r)
+            plan = api._round_plan(r)
             assert np.array_equal(
-                sampled, sample_clients(r, N_CLIENTS, COHORT, seed=0))
+                plan.sampled, sample_clients(r, N_CLIENTS, COHORT, seed=0))
+            assert plan.path == "host"
         assert api._stream_mode() == "off"
     finally:
         api.close()

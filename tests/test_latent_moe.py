@@ -258,8 +258,8 @@ def test_lowered_lm_round_program_names_every_scope():
     config = spec.config(cell["config"])
     ds, _rows = spec.module("traffic", config["generator"]).make(config, cell, 1)
     api = build_api(config, cell, ds)
-    sampled, _live, _bucket = api._round_plan(1)
-    plan = api._packed_plan(sampled)
+    round_plan = api._round_plan(1)
+    sampled, plan = round_plan.sampled, round_plan.lanes
     step = api.build_round_step_packed(plan.shape_key)
     args = (api.variables, api.server_state, *api._dev_train[:3],
             jnp.asarray(sampled, jnp.int32),

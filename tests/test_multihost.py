@@ -39,16 +39,16 @@ hist = api.train()
 print("RESULT " + json.dumps({
     "acc": [float(a) for a in hist["Test/Acc"]],
     "loss": [float(l) for l in hist["Test/Loss"]],
-    "grouped": api._group_plan is not None,
+    "path": api._path,
 }), flush=True)
 """
 
-# 16 clients / 8 devices = 2 per device with ragged (power-law) counts:
-# the grouped resident schedule activates (bucket_groups=2, small quantum)
+# 16 clients / 8 devices = 2 per device with ragged (power-law) counts,
+# full participation, resident: the stack is sharded over both processes
 CFG = dict(model="lr", dataset="synthetic_1_1", client_num_in_total=16,
            client_num_per_round=16, comm_round=3, batch_size=5, lr=0.1,
            epochs=1, frequency_of_the_test=1, seed=2,
-           bucket_groups=2, bucket_quantum_batches=1, device_data="on")
+           bucket_quantum_batches=1, device_data="on")
 
 
 def _free_port():
@@ -91,7 +91,8 @@ def test_two_process_mesh_matches_single_process():
 
     # both processes observe the same replicated result
     assert results[0] == results[1]
-    assert results[0]["grouped"], "rehearsal must exercise the grouped program"
+    assert results[0]["path"] == "mesh_sharded", \
+        "rehearsal must exercise the resident-sharded program"
 
     # and it matches the single-process 8-virtual-device run (conftest env)
     from fedml_tpu.algorithms.fedavg import CrossSiloFedAvgAPI

@@ -233,73 +233,6 @@ def test_crosssilo_packed_elastic_failures():
     np.testing.assert_allclose(h["Test/Loss"], ref["Test/Loss"], rtol=3e-5)
 
 
-def test_superstep_matches_per_round_mesh():
-    """rounds_per_step=H (one scanned program for H rounds) must reproduce
-    the per-round packed mesh path exactly: same round keys, same programs,
-    only the dispatch granularity changes (H7, docs/mfu_experiments.md)."""
-    from fedml_tpu.algorithms.fedavg import CrossSiloFedAvgAPI
-    from fedml_tpu.parallel.mesh import client_mesh
-
-    ds = make_synthetic_classification(
-        "pk-ss", (10,), 4, 4, records_per_client=14, partition_method="homo",
-        batch_size=5, seed=3)
-    bundle = create_model("lr", 4, input_shape=(10,))
-
-    def cfg(**kw):
-        return FedConfig(model="lr", dataset="synthetic",
-                         client_num_in_total=4, client_num_per_round=4,
-                         comm_round=4, batch_size=5, epochs=1, lr=0.2,
-                         seed=7, frequency_of_the_test=10_000,
-                         pack_lanes=2, device_data="on", **kw)
-
-    a = CrossSiloFedAvgAPI(ds, cfg(), bundle, mesh=client_mesh(1))
-    b = CrossSiloFedAvgAPI(ds, cfg(rounds_per_step=2), bundle,
-                           mesh=client_mesh(1))
-    assert a._packed_mesh is not None and b._packed_mesh is not None
-    la = [float(a.run_round(r)) for r in range(1, 5)]
-    lb = [float(b.run_round(r)) for r in range(1, 5)]
-    np.testing.assert_allclose(la, lb, rtol=1e-6)
-    for x, y in zip(jax.tree.leaves(a.variables), jax.tree.leaves(b.variables)):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-6,
-                                   atol=1e-7)
-
-
-def test_superstep_eval_aligned_to_block_ends():
-    """ADVICE r5 medium: the old guard let super-step evals land at block
-    STARTS while self.variables already held the block-END state, so the
-    eval logged at round r reported the model after round r+h-1. Evals now
-    align to block ends with TRUE round labels: the entry labeled round r
-    must equal the plain path's post-round-r eval exactly."""
-    from fedml_tpu.algorithms.fedavg import CrossSiloFedAvgAPI
-    from fedml_tpu.parallel.mesh import client_mesh
-
-    ds = make_synthetic_classification(
-        "pk-ss-eval", (10,), 4, 4, records_per_client=14,
-        partition_method="homo", batch_size=5, seed=3)
-    bundle = create_model("lr", 4, input_shape=(10,))
-
-    def cfg(**kw):
-        return FedConfig(model="lr", dataset="synthetic",
-                         client_num_in_total=4, client_num_per_round=4,
-                         comm_round=4, batch_size=5, epochs=1, lr=0.2,
-                         seed=7, pack_lanes=2, device_data="on", **kw)
-
-    plain = CrossSiloFedAvgAPI(ds, cfg(frequency_of_the_test=1), bundle,
-                               mesh=client_mesh(1)).train()
-    ss = CrossSiloFedAvgAPI(ds, cfg(frequency_of_the_test=2,
-                                    rounds_per_step=2), bundle,
-                            mesh=client_mesh(1)).train()
-    # blocks [0,1] and [2,3]; the plain schedule's rounds 0 and 2 shift to
-    # their block ends, labeled with the round the model actually reflects
-    assert ss["round"] == [1, 3]
-    for i, r in enumerate(ss["round"]):
-        j = plain["round"].index(r)
-        np.testing.assert_allclose(ss["Test/Acc"][i], plain["Test/Acc"][j],
-                                   rtol=1e-5)
-        np.testing.assert_allclose(ss["Test/Loss"][i], plain["Test/Loss"][j],
-                                   rtol=1e-5)
-
-
 # -- the lane vmap width (parallel/packed.lane_vmap_width) --------------------
 
 def _lanes_case(model, n_lanes, hooks, lens):

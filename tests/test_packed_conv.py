@@ -370,8 +370,8 @@ def test_packed_round_program_census_and_lifted_ceiling():
     bundle = create_model("resnet56", 10, dtype=jnp.bfloat16,
                           input_shape=(32, 32, 3))
     api = FedAvgAPI(ds, cfg, bundle)
-    sampled, _live, _bucket = api._round_plan(1, record=False)
-    plan = api._packed_plan(sampled)
+    round_plan = api._round_plan(1, record=False)
+    sampled, plan = round_plan.sampled, round_plan.lanes
     assert plan.n_lanes == 4
     step = api.build_round_step_packed(plan.shape_key)
     hints = getattr(step, "cost_hints", None)

@@ -57,6 +57,7 @@ DOCUMENTED_REASONS = (
     "flax-rng dropout",
     "pack_lanes=0",
     "no packed-lane algorithm mirror",
+    "round path runs no packed lanes",
 )
 
 
@@ -298,8 +299,8 @@ def test_packed_fedopt_round_program_ceiling():
     bundle = create_model("resnet56", 10, dtype=jnp.bfloat16,
                           input_shape=(32, 32, 3))
     api = FedOptAPI(ds, cfg, bundle)
-    sampled, _live, _bucket = api._round_plan(1, record=False)
-    plan = api._packed_plan(sampled)
+    round_plan = api._round_plan(1, record=False)
+    sampled, plan = round_plan.sampled, round_plan.lanes
     assert plan.n_lanes == 4
     step = api.build_round_step_packed(plan.shape_key)
     hints = getattr(step, "cost_hints", None)

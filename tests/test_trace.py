@@ -485,8 +485,7 @@ def _mesh_cfg(trace_dir=None, **kw):
     base = dict(
         model="lr", client_num_in_total=4, client_num_per_round=4,
         comm_round=4, batch_size=4, lr=0.1, frequency_of_the_test=2,
-        seed=0, device_data="on", pack_lanes=2, rounds_per_step=2,
-        trace_dir=trace_dir,
+        seed=0, device_data="on", pack_lanes=2, trace_dir=trace_dir,
     )
     base.update(kw)
     return FedConfig(**base)
@@ -511,9 +510,9 @@ def _mesh_run(trace_dir):
     return hist, api
 
 
-def test_traced_mesh_superstep_run_bit_identical(tmp_path):
+def test_traced_mesh_packed_run_bit_identical(tmp_path):
     """The mesh mirror of the sim/edge bit-identity pins: a traced packed
-    super-step cross-silo run computes exactly the untraced weights."""
+    cross-silo run computes exactly the untraced weights."""
     traced_hist, traced_api = _mesh_run(str(tmp_path / "traces"))
     plain_hist, plain_api = _mesh_run(None)
     assert traced_hist["Test/Acc"] == plain_hist["Test/Acc"]
@@ -528,14 +527,11 @@ def test_traced_mesh_superstep_run_bit_identical(tmp_path):
     rounds = {e["args"]["round"] for e in events
               if e.get("name") == "round" and e.get("ph") == "X"}
     assert rounds == {0, 1, 2, 3}
-    # ...the super-step emitted one device span per block with its range...
-    ss = [e for e in events if e.get("name") == "superstep"]
-    assert [(e["args"]["round_start"], e["args"]["round_end"]) for e in ss] \
-        == [(0, 1), (2, 3)]
-    # ...plus amortized per-round children parented under it
-    mr = [e for e in events if e.get("name") == "mesh_round"]
-    assert {e["args"]["round"] for e in mr} == {0, 1, 2, 3}
-    assert all(e["args"]["amortized"] and e.get("psid") for e in mr)
+    # ...each with its own device span: one program a round, real boundaries
+    ms = [e for e in events if e.get("name") == "mesh_step"]
+    assert sorted(e["args"]["round"] for e in ms) == [0, 1, 2, 3]
+    assert all(e["args"]["path"] == "packed_mesh" and e.get("psid")
+               for e in ms)
     # compile spans attribute the program builds (shape-keyed)
     comp = [e for e in events if e.get("cat") == "compile"]
     assert any(e["name"].endswith(":first_call") for e in comp)
@@ -557,12 +553,10 @@ def test_mesh_report_critical_path_compile_and_device_lane(tmp_path):
         cp = entry["critical_path"]
         assert cp["kind"] == "mesh"
         assert cp["device_ms"] > 0 and cp["path"] == "packed_mesh"
-        assert cp["amortized"] is True
-        assert entry["device"]["superstep"] in ([0, 1], [2, 3])
-    assert [s["rounds"] for s in rep["supersteps"]] == [[0, 1], [2, 3]]
+        assert entry["device"]["path"] == "packed_mesh"
     # compile accounting: registry counters + spans both present
     comp = rep["compile"]
-    assert comp["counters"]["misses"] >= 2       # packed round + superstep fn
+    assert comp["counters"]["misses"] >= 2       # default + packed round
     assert comp["counters"]["first_call_ms"] > 0
     assert any(k.endswith(":first_call") for k in comp["spans"])
     # device lane: sampler ran at every round boundary (CPU falls back to
@@ -583,7 +577,7 @@ def test_mesh_report_critical_path_compile_and_device_lane(tmp_path):
 
 def test_sharded_mesh_rounds_traced(tmp_path):
     """The non-packed (resident-sharded) mesh path emits per-round
-    mesh_step device spans — no amortization, real per-round boundaries."""
+    mesh_step device spans too."""
     from fedml_tpu.algorithms.fedavg import CrossSiloFedAvgAPI
     from fedml_tpu.models import create_model
     from fedml_tpu.parallel.mesh import client_mesh
@@ -593,7 +587,7 @@ def test_sharded_mesh_rounds_traced(tmp_path):
         "mesh-gr", (6,), 3, 4, records_per_client=8,
         partition_method="homo", batch_size=4, seed=0)
     api = CrossSiloFedAvgAPI(
-        ds, _mesh_cfg(d, pack_lanes=0, rounds_per_step=1, comm_round=2),
+        ds, _mesh_cfg(d, pack_lanes=0, comm_round=2),
         create_model("lr", ds.class_num, input_shape=ds.train_x.shape[2:]),
         mesh=client_mesh(2))
     api.train()
@@ -602,7 +596,7 @@ def test_sharded_mesh_rounds_traced(tmp_path):
     assert rep["anomalies"] == []
     for entry in rep["timeline"]:
         assert entry["critical_path"]["kind"] == "mesh"
-        assert entry["critical_path"]["amortized"] is False
+        assert entry["critical_path"]["path"] == "sharded"
 
 
 def test_mesh_gossip_rounds_traced(tmp_path):
@@ -963,20 +957,6 @@ def test_sketch_merge_tolerates_mismatched_and_corrupt_streams(
     assert "p50     10.075" in out.out    # the winner's data, not ~99
 
 
-def test_superstep_block_follows_head_sampling_verdict(tmp_path):
-    """The packed-mesh superstep path emits its superstep + amortized
-    mesh_round spans only for blocks whose STARTING round is sampled —
-    span volume stays bounded under --trace_sample_rate on the one path
-    that bypasses the per-round wrapper's gate."""
-    from fedml_tpu.obs.tracer import span_sampled
-
-    obs.configure(str(tmp_path), sample_rate=0.5, sample_seed=1)
-    tr_kept = obs.tracer_if_sampled(0, 1)    # seed 1 keeps round 1...
-    tr_dropped = obs.tracer_if_sampled(0, 0)  # ...and drops round 0
-    assert span_sampled(1, seed=1) and not span_sampled(0, seed=1)
-    assert tr_kept is not None and tr_dropped is None
-
-
 # -- the round path's one span primitive, and the scopes (ISSUE 24) ---------
 
 def test_span_with_the_tracer_off_is_a_trace_annotation_and_nothing_else():
@@ -1034,8 +1014,8 @@ def _packed_step_and_args(api):
 
     from fedml_tpu.parallel.packed import plan_arrays_tuple
 
-    sampled, _live, _bucket = api._round_plan(0)
-    plan = api._packed_plan(sampled)
+    round_plan = api._round_plan(0)
+    sampled, plan = round_plan.sampled, round_plan.lanes
     step = api.build_round_step_packed(plan.shape_key)
     tx, ty, tm, _ = api._dev_train
     counts = np.asarray(api.dataset.train_counts, np.float32)[sampled]
@@ -1094,7 +1074,8 @@ def test_lowered_round_program_names_every_scope(kw, missing):
     import jax.numpy as jnp
 
     api = _packed_api(**kw)
-    sampled, live, bucket = api._round_plan(0)
+    plan = api._round_plan(0)
+    sampled, bucket = plan.sampled, plan.bucket
     rk = jax.random.PRNGKey(0)
     if api.config.pack_lanes > 0:
         step, args = _packed_step_and_args(api)

@@ -17,8 +17,7 @@ local/grpc/mqtt transports AND the reliable/chaos middleware, retransmits
 collapsed onto their logical message — is one (send span, recv span) pair.
 Mesh (in-mesh cross-silo / gossip) rounds have no wire legs; their
 decomposition comes from the fedscope device spans instead: ``mesh_step``
-per-round device dispatch, ``superstep`` blocks with amortized
-``mesh_round`` children, and ``compile``-category build/first-call spans.
+per-round device dispatch and ``compile``-category build/first-call spans.
 
 Report sections:
 - round timeline: wall-clock per round with per-rank presence,
@@ -147,7 +146,6 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
     #: trace has every host running the same mesh round — summing across
     #: hosts would double-count device time)
     device_rows: dict[int, dict] = {}
-    supersteps: list[dict] = []
     compile_spans: dict[str, dict] = {}   # program name -> {count, ms}
     device_mem: dict[object, dict] = {}   # rank -> series -> high-water
     device_mem_samples = 0
@@ -179,8 +177,7 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
                 m = _args(ev).get("mid")
                 if m:
                     recvs[m] = ev
-            elif ev.get("cat") == "device" and name in ("mesh_step",
-                                                        "mesh_round"):
+            elif ev.get("cat") == "device" and name == "mesh_step":
                 r = _args(ev).get("round")
                 if r is not None:
                     row = device_rows.setdefault(int(r), {}).setdefault(
@@ -189,17 +186,6 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
                     row["spans"] += 1
                     if _args(ev).get("path"):
                         row["path"] = _args(ev)["path"]
-                    if _args(ev).get("amortized"):
-                        row["amortized"] = True
-                        row["superstep"] = _args(ev).get("superstep")
-            elif ev.get("cat") == "device" and name == "superstep":
-                a = _args(ev)
-                supersteps.append({
-                    "rounds": [a.get("round_start"), a.get("round_end")],
-                    "h": a.get("h"),
-                    "wall_ms": round(ev.get("dur", 0) / 1e3, 3),
-                    "rank": rank,
-                })
             elif ev.get("cat") == "compile":
                 row = compile_spans.setdefault(name, {"count": 0, "ms": 0.0})
                 row["count"] += 1
@@ -322,10 +308,7 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
             entry["device"] = {
                 "device_ms": round(dev["device_ms"], 3),
                 "path": dev.get("path"),
-                "amortized": bool(dev.get("amortized")),
                 **({"rank": slow_rk} if len(per_rank_dev) > 1 else {}),
-                **({"superstep": dev["superstep"]}
-                   if dev.get("superstep") else {}),
             }
             if "critical_path" not in entry:
                 # mesh rounds: no wire legs — the critical path IS the
@@ -337,7 +320,6 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
                         max(entry["wall_ms"]
                             - entry["device"]["device_ms"], 0.0), 3),
                     "path": dev.get("path"),
-                    "amortized": bool(dev.get("amortized")),
                 }
         if r in stage_rows:
             row = stage_rows[r]
@@ -375,8 +357,7 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
     if cost_programs:
         # achieved-FLOP/s per program: static GEMM FLOPs per invocation
         # against the MEASURED duration — fedscope device spans for mesh
-        # programs (matched by path; amortized super-step rounds excluded:
-        # their per-round split is synthetic), the round wall for a sim
+        # programs (matched by path), the round wall for a sim
         # program when it is unambiguous (exactly one sim program, no
         # device lanes to confuse it with).
         path_ms: dict[str, list] = {}
@@ -386,7 +367,7 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
             # multi-host trace (same critical-path convention as above)
             per_path: dict[str, float] = {}
             for row in per.values():
-                if row.get("path") and not row.get("amortized"):
+                if row.get("path"):
                     p = row["path"]
                     per_path[p] = max(per_path.get(p, 0.0), row["device_ms"])
             for p, ms in per_path.items():
@@ -438,8 +419,6 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
         rep["plan"] = {
             p: {"plan": a.get("plan"), "self_check": a.get("self_check")}
             for p, a in sorted(plan_programs.items())}
-    if supersteps:
-        rep["supersteps"] = supersteps
     if device_mem:
         rep["device_mem"] = {
             "samples": device_mem_samples,
@@ -583,10 +562,9 @@ def format_report(rep: dict) -> str:
         lines.append(row)
         cp = e.get("critical_path")
         if cp and cp.get("kind") == "mesh":
-            amort = " (amortized)" if cp.get("amortized") else ""
             lines.append(
                 f"        critical: device {cp['device_ms']:.1f} ms"
-                f" [{cp.get('path')}]{amort}"
+                f" [{cp.get('path')}]"
                 f" + host {cp['host_ms']:.1f} ms")
         elif cp:
             lines.append(
@@ -594,14 +572,6 @@ def format_report(rep: dict) -> str:
                 f"{cp['total_ms']:.1f} ms = down {cp['wire_down_ms']:.1f}"
                 f" + train {cp['train_ms']:.1f}"
                 f" + up {cp['wire_up_ms']:.1f}")
-    if rep.get("supersteps"):
-        lines.append("")
-        lines.append("super-steps (one device program per block; per-round "
-                     "attribution above is amortized):")
-        for s in rep["supersteps"]:
-            lines.append(
-                f"  rounds {s['rounds'][0]}..{s['rounds'][1]}  "
-                f"wall {s['wall_ms']:.1f} ms  (h={s['h']}, rank {s['rank']})")
     if rep["straggler_ranking"]:
         lines.append("")
         lines.append("straggler ranking (mean causal-chain ms, worst first):")
