@@ -5,7 +5,8 @@ Two layers live here. The SPARSE one (:class:`SharedRoutedMoe`,
 models run: sigmoid scores, selection with a correction bias, shared
 experts, and a layer that is told which experts it holds, routes over all of
 them and computes only the rows its own experts were chosen for (a grouped
-matmul over rows sorted by expert, no capacity, no dropped token). The DENSE
+matmul over rows sorted by expert, no dropped token: a row capacity chosen
+each step from the router's own count). The DENSE
 one (:class:`MoeMlp`) is the older GSPMD baseline, kept for the expert-axis
 sharding tests: every expert computes every token.
 
@@ -27,6 +28,8 @@ dispatch is a performance specialization of the same parameter layout.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Any
 
 import flax.linen as nn
@@ -157,6 +160,133 @@ def route(scores: jax.Array, bias: jax.Array, top_k: int, scaling: float):
     return idx, chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
 
 
+#: The row capacities a sparse layer chooses from, as shares of its ``n * k``
+#: (token, choice) pairs. A layer that holds ``h`` of ``E`` experts fills
+#: about ``h / E`` of the pairs (the cell: 16 of 128, 11 - 16% by the
+#: program's own counter), and the rows' moves, masks and grouped matmuls all
+#: cost by the capacity they walk, not by the rows that are filled. Alone on
+#: the chip at the cell's shapes (``tools/rows_sweep.py``, 4,543 rows filled;
+#: ``PERF.md`` section 6, PR 29, has the table) a rung's forward + backward
+#: take 9.03 ms at 6,144 slots, 10.48 at 12,288, 12.94 at 24,576 and 20.40
+#: at 49,152: 7.6 ms + 0.26 ms a 1,024 slots, so a finer ladder than
+#: halvings would win under 0.3% of a round.
+ROW_RUNG_SHARES = (1 / 8, 1 / 4, 1 / 2, 1)
+#: rungs are multiples of this many rows (where the pairs allow eight such)
+ROW_RUNG_ALIGN = 1024
+
+
+def row_rungs(pairs: int) -> tuple:
+    """The static row capacities of a layer with ``pairs = n * k`` (token,
+    choice) pairs, ascending, from ``pairs`` alone; the last is ``pairs``
+    itself, which holds any routing."""
+    align = max(1, min(ROW_RUNG_ALIGN, pairs // 8))
+    return tuple(sorted({
+        min(pairs, -(-math.ceil(pairs * share) // align) * align)
+        for share in ROW_RUNG_SHARES}))
+
+
+def _rung_index(rungs: tuple, sizes: jax.Array) -> jax.Array:
+    """Index of the first rung that holds every row of ``sizes``."""
+    total = jnp.sum(sizes)
+    return sum((total > c).astype(jnp.int32) for c in rungs[:-1])
+
+
+@functools.cache
+def _rung(capacity: int):
+    """The held experts' part of a sparse layer over the first ``capacity``
+    sorted row slots: ``(xf [n, d], order [k*n], inv [k*n], sizes [held],
+    mine [k, n], weights [n, k], w_gate, w_up, w_down) -> [n, d]`` float32
+    (the three experts' weights in ``xf``'s dtype). Exact whenever
+    ``sum(sizes) <= capacity``: a slot past the last group goes in and comes
+    out as zeros (so that nothing a kernel leaves there, and no cotangent of
+    it, reaches a token), and a pair whose slot was not kept reads a zero
+    row. One jitted function a capacity, so that every sparse layer of a
+    model traces it once."""
+
+    # the trace counts a capacity's layer-steps by this name
+    rows_name = f"moe_rows_{capacity}"
+
+    def rung(xf, order, inv, sizes, mine, weights, w_gate, w_up, w_down):
+        (k, n), d = mine.shape, xf.shape[-1]
+        with jax.named_scope(rows_name):
+            with jax.named_scope(SCOPE_LM_ROUTE):
+                live = (jnp.arange(capacity) < jnp.sum(sizes))[:, None]
+                rows = jnp.where(
+                    live, fan_out_rows(xf, order[:capacity], inv), 0)
+            with jax.named_scope(SCOPE_LM_EXPERTS):
+                g = grouped_matmul(rows, w_gate, sizes)
+                u = grouped_matmul(rows, w_up, sizes)
+                y = grouped_matmul(nn.silu(g) * u, w_down, sizes)
+            with jax.named_scope(SCOPE_LM_ROUTE):
+                y = jnp.where(live, y, 0)
+                back = permute_rows(y, inv, order[:capacity]).reshape(k, n, d)
+                wt = jnp.where(mine, weights.T, 0.0)
+                return jnp.einsum("knd,kn->nd", back.astype(jnp.float32), wt)
+
+    return jax.jit(rung)
+
+
+@functools.cache
+def _rung_vjp(capacity: int):
+    """``(operands, ct) ->`` the cotangents of ``_rung(capacity)``'s five
+    floating operands, from a forward rebuilt at that capacity; the three
+    weights' in float32, what the parameters they were cast from take."""
+
+    def rung_vjp(operands, ct):
+        xf, order, inv, sizes, mine, weights, *w = operands
+        _, vjp = jax.vjp(
+            lambda xf, weights, *w: _rung(capacity)(
+                xf, order, inv, sizes, mine, weights, *w), xf, weights, *w)
+        dx, dweights, *dw = vjp(ct)
+        with jax.named_scope(SCOPE_LM_EXPERTS):
+            return (dx, dweights, *(g.astype(jnp.float32) for g in dw))
+
+    return jax.jit(rung_vjp)
+
+
+def _cast_experts(w, dtype):
+    """The experts' weights (float32 parameters) in the rows' dtype, OUTSIDE
+    the switch: XLA fuses the cast into whatever made the parameters, where
+    a branch's operand would have to be written out."""
+    with jax.named_scope(SCOPE_LM_EXPERTS):
+        return [a.astype(dtype) for a in w]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def routed_rows(rungs: tuple, xf, order, inv, sizes, mine, weights,
+                *w) -> jax.Array:
+    """``_rung(C)`` at the first ``C`` of ``rungs`` that holds the rows
+    ``sizes`` counts, on a rung's operands (``w``: the three experts'
+    weights, still float32 parameters): a ``lax.switch`` on the router's
+    own count, no row dropped (the last rung is every pair). The backward
+    pass saves the operands alone, switches on the same index and rebuilds
+    that rung's forward: a plain ``lax.switch`` would save the union of
+    every rung's residuals, 1.9 times the full capacity's. Under a ``vmap``
+    (packed lanes) the index is batched and JAX runs every rung and
+    selects: still exact, only slow (and the TPU refuses a batched grouped
+    matmul there anyway)."""
+    return jax.lax.switch(
+        _rung_index(rungs, sizes), [_rung(c) for c in rungs],
+        xf, order, inv, sizes, mine, weights, *_cast_experts(w, xf.dtype))
+
+
+def _routed_fwd(rungs, *operands):
+    return routed_rows(rungs, *operands), operands
+
+
+def _routed_bwd(rungs, operands, ct):
+    xf, order, inv, sizes, mine, weights, *w = operands
+    with jax.named_scope(SCOPE_LM_ROUTE):
+        dx, dweights, *dw = jax.lax.switch(
+            _rung_index(rungs, sizes), [_rung_vjp(c) for c in rungs],
+            (xf, order, inv, sizes, mine, weights,
+             *_cast_experts(w, xf.dtype)), ct)
+    return (dx, None, None, None, None, dweights, *dw)
+
+
+routed_rows.defvjp(_routed_fwd, _routed_bwd)
+
+
 class SharedRoutedMoe(nn.Module):
     """Shared experts on every token + the weighted sum of each token's
     chosen routed experts, for the experts HELD here.
@@ -166,7 +296,11 @@ class SharedRoutedMoe(nn.Module):
     ``held_first .. held_first + held_count - 1`` (all, when ``held_count``
     is None): it takes the (token, choice) pairs whose expert it holds,
     sorts them by expert, runs one grouped matmul per projection over the
-    rows and adds the weighted rows back. What the absent experts would have
+    rows and adds the weighted rows back. No row is dropped: the rows' moves
+    and matmuls walk a static capacity chosen each step from the router's
+    own count (:func:`row_rungs`, :func:`routed_rows`), the smallest that
+    holds every row of a held expert; a layer that holds all its experts
+    always takes the last, every pair. What the absent experts would have
     added is left out: in an expert-parallel deployment their chips add it,
     and nothing here stands in for them or for the exchange.
 
@@ -224,20 +358,8 @@ class SharedRoutedMoe(nn.Module):
             inv = jnp.argsort(order)
             sizes = jnp.sum(jax.nn.one_hot(key, held + 1, dtype=jnp.int32),
                             axis=0)[:held]
-            live = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
-            # rows past the last group belong to no expert: zero going in
-            # and coming out, so that nothing a kernel leaves there (and no
-            # cotangent of it) reaches a token
-            rows = jnp.where(live, fan_out_rows(xf.astype(dt), order, inv), 0)
-        with jax.named_scope(SCOPE_LM_EXPERTS):
-            g = grouped_matmul(rows, w_gate.astype(dt), sizes)
-            u = grouped_matmul(rows, w_up.astype(dt), sizes)
-            y = grouped_matmul(nn.silu(g) * u, w_down.astype(dt), sizes)
-        with jax.named_scope(SCOPE_LM_ROUTE):
-            y = jnp.where(live, y, 0)
-            back = permute_rows(y, inv, order).reshape(k, n, d)
-            wt = jnp.where(mine, weights.T, 0.0)
-            routed = jnp.einsum("knd,kn->nd", back.astype(jnp.float32), wt)
+            routed = routed_rows(row_rungs(n * k), xf.astype(dt), order, inv,
+                                 sizes, mine, weights, w_gate, w_up, w_down)
         seen = self.variable(COUNTERS, "expert_rows",
                              lambda: jnp.zeros((held,), jnp.float32))
         steps = self.variable(COUNTERS, "steps",

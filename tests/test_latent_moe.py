@@ -12,6 +12,7 @@ import pytest
 from benchmarks.references import kanana2_30b_a3b as ref
 from fedml_tpu.core.tasks import nwp
 from fedml_tpu.models import create_model
+from fedml_tpu.models import moe
 from fedml_tpu.models.moe import LATENT_MOE_PRESETS, SharedRoutedMoe
 
 VOCAB = 64
@@ -136,6 +137,143 @@ def test_no_token_is_dropped_when_all_choose_one_expert(target):
     rows = np.asarray(new["counters"]["expert_rows"])
     assert rows[target] == n and rows.sum() == n
     np.testing.assert_allclose(out, want, atol=3e-6)
+
+
+# --- the row capacities -------------------------------------------------------
+
+def test_row_rungs_come_from_the_pair_count_alone():
+    assert moe.row_rungs(8192 * 6) == (6144, 12288, 24576, 49152)
+    assert moe.row_rungs(64) == (8, 16, 32, 64)
+    assert moe.row_rungs(4) == (1, 2, 4)
+    for pairs in (64, 100, 1000, 8192 * 6, 9000 * 7):
+        rungs = moe.row_rungs(pairs)
+        assert rungs[-1] == pairs and 1 <= len(rungs) <= 4
+        assert all(a < b for a, b in zip(rungs, rungs[1:]))
+    assert all(c % 1024 == 0 for c in moe.row_rungs(9000 * 8)[:-1])
+
+
+def _steered_layer(totals, held_count=4, dtype=jnp.float32):
+    """A layer of 32 tokens x 2 choices over 8 experts (rungs 8 / 16 / 32 /
+    64 of its 64 pairs) whose input is solved for so that exactly
+    ``totals[lane]`` pairs choose a held expert (0 .. 3 of 8; all 8 when
+    ``held_count`` is None): -> (module, variables, x [lanes, 2, 16, 32])."""
+    n, k, d, e = 32, 2, 32, 8
+    held = e if held_count is None else held_count
+    config = tiny_config(top_k=k)
+    p = dict(ref.init(jax.random.key(5), config)["params"]["layer_1"]["mlp"])
+    logits = np.full((len(totals), n, e), -4.0)
+    for lane, total in enumerate(totals):
+        for tok in range(n):
+            # this token's pairs of held experts: 2, 1 or 0
+            mine = min(2, max(0, total - 2 * tok)) if held < e else 2
+            chosen = ([tok % held, (tok + 1) % held][:mine]
+                      + [held + tok % 2, held + 2 + tok % 2][:2 - mine])
+            logits[lane, tok, chosen] = 4.0
+    # x @ router = logits exactly: the router's pseudo-inverse places the
+    # logits, and noise in the router's null space fills the other widths
+    router = np.asarray(jax.random.normal(jax.random.key(8), (d, e)), np.float64)
+    pinv = np.linalg.pinv(router)
+    noise = np.asarray(jax.random.normal(jax.random.key(6),
+                                         (len(totals), n, d)), np.float64)
+    xs = jnp.asarray(logits @ pinv + noise @ (np.eye(d) - router @ pinv),
+                     jnp.float32)
+    p["router"] = jnp.asarray(router, jnp.float32)
+    p["e_score_correction_bias"] = jnp.zeros((e,))
+    if held != 4:
+        wide = ref.init(jax.random.key(5), tiny_config(
+            top_k=k, held_count=held))["params"]["layer_1"]["mlp"]
+        p.update({name: wide[name] for name in ("gate", "up", "down")})
+    mod = SharedRoutedMoe(e, k, 24, 2, 2.448, 0, held_count, dtype)
+    stats = {"expert_rows": jnp.zeros((held,)), "steps": jnp.zeros(())}
+    return mod, {"params": p, "counters": stats}, xs.reshape(-1, 2, 16, d)
+
+
+def _layer_value_and_grads(mod, variables, x, c):
+    def loss(params, x):
+        out, new = mod.apply({**variables, "params": params}, x, True,
+                             mutable=["counters"])
+        return (jnp.sum(out.astype(jnp.float32) * c),
+                (out, new["counters"]["expert_rows"]))
+
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            variables["params"], x)
+
+
+def _assert_same(got, want, tol):
+    """Every leaf within ``tol`` of its largest magnitude; the loss, a sum
+    of terms that cancel, within ``tol`` of the terms' magnitudes."""
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        scale = np.abs(b).max() if b.ndim else np.abs(want[0][1][0]).sum()
+        np.testing.assert_allclose(a, b, atol=tol * (scale + 1e-9), rtol=0,
+                                   err_msg=str(path))
+
+
+# totals against the rungs 8 / 16 / 32 / 64: inside each rung, on its edge
+# (total == C) and one past it (total == C + 1), none, and every pair
+RUNG_CASES = [(0, 0), (5, 0), (8, 0), (9, 1), (16, 1), (17, 2), (32, 2),
+              (33, 3), (47, 3), (64, 3)]
+
+
+@pytest.mark.parametrize("total,rung,held_count,dtype", [
+    *[(t, r, 4, "float32") for t, r in RUNG_CASES],
+    (64, 3, None, "float32"),
+    *[(t, r, 4, "bfloat16") for t, r in RUNG_CASES[1::3]]])
+def test_every_rung_is_the_full_capacity_layer(monkeypatch, total, rung,
+                                               held_count, dtype):
+    """Output, loss, and the gradients of every parameter and of the input:
+    the layer at the capacity its count picks against the same layer with
+    the one capacity of every pair."""
+    mod, variables, x = _steered_layer([total], held_count, jnp.dtype(dtype))
+    x = x[0]
+    c = jax.random.normal(jax.random.key(9), x.shape, jnp.float32)
+    rungs = moe.row_rungs(64)
+    assert rungs == (8, 16, 32, 64)
+    got = _layer_value_and_grads(mod, variables, x, c)
+    rows = np.asarray(got[0][1][1])
+    assert rows.sum() == total
+    assert int(moe._rung_index(rungs, jnp.asarray(rows, jnp.int32))) == rung
+    monkeypatch.setattr(moe, "ROW_RUNG_SHARES", (1,))
+    assert moe.row_rungs(64) == (64,)
+    want = _layer_value_and_grads(mod, variables, x, c)
+    np.testing.assert_array_equal(rows, want[0][1][1])
+    # float32: the last bits (one gradient's last bit, 9.5e-7, in the
+    # issue's own check); bf16: one rounding of the dtype
+    _assert_same(got, want, 2e-6 if dtype == "float32" else 8e-3)
+    if total:
+        assert np.abs(np.asarray(got[1][0]["gate"], np.float32)).max() > 0
+
+
+def test_a_rung_too_small_would_lose_rows(monkeypatch):
+    """The comparison above can tell: the layer forced to a capacity under
+    its count differs from the full one."""
+    mod, variables, x = _steered_layer([33])
+    seen = []
+
+    def spy(rungs, *operands):
+        seen.append(operands)
+        return moe._rung(rungs[-1])(*operands)
+
+    monkeypatch.setattr(moe, "routed_rows", spy)
+    mod.apply(variables, x[0])
+    full, small, fits = (moe._rung(c)(*seen[0]) for c in (64, 32, 48))
+    np.testing.assert_allclose(fits, full, atol=1e-6)
+    assert float(jnp.abs(small - full).max()) > 1e-3
+
+
+def test_lanes_under_vmap_take_their_own_rung():
+    """Two lanes whose counts land on different rungs, under ``jax.vmap``
+    (JAX runs every rung and selects): each lane is its own layer."""
+    mod, variables, x = _steered_layer([5, 33])
+    c = jax.random.normal(jax.random.key(9), x.shape, jnp.float32)
+    got = jax.vmap(lambda x, c: _layer_value_and_grads(mod, variables, x, c))(
+        x, c)
+    assert [float(r.sum()) for r in got[0][1][1]] == [5, 33]
+    for lane in range(2):
+        want = _layer_value_and_grads(mod, variables, x[lane], c[lane])
+        _assert_same(jax.tree.map(lambda a: a[lane], got), want, 2e-6)
 
 
 # --- the federated round ------------------------------------------------------
@@ -266,8 +404,32 @@ def test_lowered_lm_round_program_names_every_scope():
             jnp.ones((len(sampled),), jnp.float32), jax.random.key(0),
             tuple(jnp.asarray(a) for a in plan_arrays_tuple(plan)))
     lowered = step.lower(*args)
-    found = set(re.findall(r"fedml\.[a-z_.]+", lowered.as_text(debug_info=True)))
+    named = lowered.as_text(debug_info=True)
+    found = set(re.findall(r"fedml\.[a-z_.]+", named))
     table = {v for k, v in vars(tracer).items() if k.startswith("SCOPE_")}
     assert found == table
-    assert "fedml." not in lowered.as_text()
+    text = lowered.as_text()
+    assert "fedml." not in text
+    # the sparse layers' row capacities: one conditional a layer and pass
+    # (forward, and the backward that rebuilds its rung; the remat replay's
+    # is dead code), a branch a rung, one shared function a rung; inside a
+    # branch the LAST fedml.* name is still the route's or the experts'
+    sizes = config["model"]
+    pairs = (int(config["recipe"]["batch_size"]) * sizes["seq_len"]
+             * sizes["top_k"])
+    rungs = moe.row_rungs(pairs)
+    assert len(rungs) == 4
+    conds = re.findall(r'"stablehlo\.case"\(.*?\n    \}\) :', text, re.S)
+    assert len(conds) == 2 * (sizes["layers"] - sizes["first_dense"])
+    for cond in conds:
+        assert cond.count("func.call @rung") == len(rungs) == cond.count(
+            "stablehlo.return")
+    assert len(set(re.findall(r"func\.call @(rung[a-z_0-9]*)", text))) == 2 * len(rungs)
+    inside = set(re.findall(r'loc\("([^"]*moe_rows_[^"]*)"', named))
+    assert ({int(c) for path in inside
+             for c in re.findall(r"moe_rows_(\d+)", path)} == set(rungs))
+    for path in inside:
+        last = re.findall(r"fedml\.[a-z_.]+", path)
+        assert last and last[-1] in (tracer.SCOPE_LM_ROUTE,
+                                     tracer.SCOPE_LM_EXPERTS), path
     api.close()

@@ -51,22 +51,28 @@ def test_grouped_matmul_matches_the_per_expert_loop(sizes):
         start += n
 
 
-@pytest.mark.parametrize("op", ["permute", "fan_out", "embed"])
-def test_row_moves_have_the_gathers_own_gradient(op):
+@pytest.mark.parametrize("op,kept", [
+    ("permute", 12), ("fan_out", 12), ("embed", 12),
+    ("permute", 5), ("fan_out", 5), ("permute", 1), ("fan_out", 1)])
+def test_row_moves_have_the_gathers_own_gradient(op, kept):
     """Each move's hand-written VJP (gathers only) is the transpose XLA
-    would derive from the plain gather."""
+    would derive from the plain gather; with only the first ``kept`` sorted
+    slots carried (the sparse layer's row capacity), a slot past them is a
+    zero row going out and takes no cotangent coming back."""
     key = jax.random.key(11)
     perm = jax.random.permutation(key, 12)
     inv = jnp.argsort(perm)
     c = jax.random.normal(jax.random.key(12), (12, 5))
     if op == "permute":
-        x = jax.random.normal(key, (12, 5))
-        ours = lambda x: permute_rows(x, perm, inv)
-        plain = lambda x: x[perm]
+        x = jax.random.normal(key, (kept, 5))
+        ours = lambda x: permute_rows(x, perm, inv[:kept])
+        plain = lambda x: jnp.concatenate(
+            [x, jnp.zeros((1, 5))])[jnp.minimum(perm, kept)]
     elif op == "fan_out":
         x = jax.random.normal(key, (4, 5))
-        ours = lambda x: fan_out_rows(x, perm, inv)
-        plain = lambda x: x[perm % 4]
+        c = c[:kept]
+        ours = lambda x: fan_out_rows(x, perm[:kept], inv)
+        plain = lambda x: x[perm[:kept] % 4]
     else:
         x = jax.random.normal(key, (7, 5))
         ids = jax.random.randint(key, (3, 4), 0, 7)

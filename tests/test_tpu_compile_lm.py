@@ -61,14 +61,16 @@ def test_latent_attention_kernels_compile_at_published_widths(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
+@pytest.mark.parametrize("rows", [8192 * 6, 6144])
 def test_grouped_matmul_compiles_unbatched_at_published_widths(one_chip,
-                                                               no_cache):
-    """49,152 row slots (8,192 tokens x 6 choices), 16 held experts of 2048
-    x 768: forward and both gradients; XLA's own operation count is the
-    row slots' (the static capacity), three passes."""
+                                                               no_cache, rows):
+    """49,152 row slots (8,192 tokens x 6 choices: the sparse layer's last
+    row capacity) and 6,144 (its first), 16 held experts of 2048 x 768:
+    forward and both gradients; XLA's own operation count is the row
+    slots' (the static capacity), three passes."""
     from fedml_tpu.ops.grouped_matmul import grouped_matmul
 
-    rows, d, f, held = 8192 * 6, 2048, 768, 16
+    d, f, held = 2048, 768, 16
 
     def step(x, w, sizes):
         return jax.grad(lambda x, w: jnp.sum(grouped_matmul(
@@ -80,3 +82,43 @@ def test_grouped_matmul_compiles_unbatched_at_published_widths(one_chip,
         jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)).compile()
     flops = compiled.cost_analysis()["flops"]
     assert flops == pytest.approx(3 * 2 * rows * d * f, rel=0.02)
+
+
+def test_row_capacities_compile_as_one_conditional_a_pass(one_chip, no_cache):
+    """The held experts' part of a sparse layer at the cell's shapes
+    (``models/moe.routed_rows``): forward and backward each compile to one
+    conditional with a branch a row capacity, and the backward's
+    temporaries stay under the full capacity's rows, ``g``, ``u`` and
+    their cotangents (no union of the branches' residuals)."""
+    from fedml_tpu.models import moe
+
+    n, k, d, f, held = 8192, 6, 2048, 768, 16
+    rungs = moe.row_rungs(n * k)
+    assert rungs == (6144, 12288, 24576, 49152)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    operands = (sd((n, d), jnp.bfloat16), sd((n * k,), jnp.int32),
+                sd((n * k,), jnp.int32), sd((held,), jnp.int32),
+                sd((k, n), jnp.bool_), sd((n, k), jnp.float32),
+                sd((held, d, f), jnp.float32), sd((held, d, f), jnp.float32),
+                sd((held, f, d), jnp.float32))
+
+    def step(*ops):
+        return jax.grad(lambda xf, weights, *w: jnp.sum(moe.routed_rows(
+            rungs, xf, *ops[1:5], weights, *w) ** 2), argnums=(0, 1, 2, 3, 4))(
+                ops[0], *ops[5:])
+
+    compiled = jax.jit(step).lower(*operands).compile()
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 2
+    assert text.count("branch_computations={") == 2
+    for c in rungs:
+        assert f"moe_rows_{c}/" in text
+    # 985 MB here against 808 MB with the full capacity as the only rung
+    # (rows and y 201 MB each in bf16, g, u and their product 75 MB each,
+    # and their cotangents): the bf16 copies of the weights (150 MB) live
+    # as long as the conditional they are operands of. The union of four
+    # rungs' residuals would add another 0.4 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1e9
