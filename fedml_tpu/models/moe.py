@@ -40,8 +40,9 @@ import dataclasses
 from typing import Optional
 
 from fedml_tpu.models import COUNTERS, ModelBundle, register_model
-from fedml_tpu.models.transformer import (LatentAttention, Linear, RMSNorm,
-                                          SelfAttention, SwiGLU, _normal)
+from fedml_tpu.models.transformer import (DeltaAttention, LatentAttention,
+                                          Linear, RMSNorm, SelfAttention,
+                                          SwiGLU, _normal)
 from fedml_tpu.obs.tracer import (SCOPE_LM_DENSE, SCOPE_LM_EXPERTS,
                                   SCOPE_LM_ROUTE)
 from fedml_tpu.ops.grouped_matmul import (embed_rows, fan_out_rows,
@@ -147,14 +148,31 @@ class MoeTransformerLM(nn.Module):
 # kakaocorp/kanana-2-30b-a3b's config.json fixes it)
 # ---------------------------------------------------------------------------
 
-def route(scores: jax.Array, bias: jax.Array, top_k: int, scaling: float):
+def chosen_groups(biased: jax.Array, n_group: int, topk_group: int):
+    """``biased [N, E]`` (``score + bias``) -> ``[N, n_group]`` bool: the
+    ``topk_group`` groups (``E / n_group`` consecutive experts each) whose
+    two largest entries sum highest."""
+    n, e = biased.shape
+    top2, _ = jax.lax.top_k(biased.reshape(n, n_group, e // n_group), 2)
+    _, groups = jax.lax.top_k(jnp.sum(top2, axis=-1), topk_group)
+    return jnp.any(jax.nn.one_hot(groups, n_group, dtype=jnp.bool_), axis=1)
+
+
+def route(scores: jax.Array, bias: jax.Array, top_k: int, scaling: float,
+          groups: Optional[jax.Array] = None):
     """``scores [N, E]`` (sigmoid, float32) -> ``(idx [N, k], weights [N,
     k])``: the ``k`` experts with the largest ``score + bias``, weighted by
-    their own scores over the chosen scores' sum, times ``scaling``.
+    their own scores over the chosen scores' sum, times ``scaling``. With
+    ``groups [N, n_group]`` (:func:`chosen_groups`) the choice is
+    group-limited: only the experts of a token's chosen groups stand for it.
     Gradients flow through the scores, not through the selection or the
     bias. (No gather: a gather's transpose is a scatter, which a TPU
     serialises; the one-hot product's transpose is a product.)"""
-    _, idx = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), top_k)
+    biased = jax.lax.stop_gradient(scores + bias)
+    if groups is not None:
+        size = scores.shape[-1] // groups.shape[-1]
+        biased = jnp.where(jnp.repeat(groups, size, axis=1), biased, -jnp.inf)
+    _, idx = jax.lax.top_k(biased, top_k)
     hot = jax.nn.one_hot(idx, scores.shape[-1], dtype=scores.dtype)
     chosen = jnp.einsum("nke,ne->nk", hot, scores)
     return idx, chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
@@ -304,11 +322,15 @@ class SharedRoutedMoe(nn.Module):
     added is left out: in an expert-parallel deployment their chips add it,
     and nothing here stands in for them or for the exchange.
 
+    ``n_group > 1``: the router's choice is group-limited (:func:`route`).
+
     The ``counters`` collection (``models.COUNTERS``) carries
     ``expert_rows`` (rows each held expert has computed, summed over the
     training steps) and ``steps``: the load statistic that the published
-    bias update reads. The packed simulation round sums them over the
-    round's clients (float32: exact up to 2**24 rows an expert), and
+    bias update reads; under a group-limited router also ``group_tokens``,
+    the tokens among whose chosen groups is one with an expert held here.
+    The packed simulation round sums them over the round's clients
+    (float32: exact up to 2**24 rows an expert), and
     ``ModelBundle.counters`` reads them on the host.
     """
 
@@ -320,6 +342,8 @@ class SharedRoutedMoe(nn.Module):
     held_first: int = 0
     held_count: Optional[int] = None
     dtype: Any = jnp.float32
+    n_group: int = 1
+    topk_group: int = 1
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -346,7 +370,11 @@ class SharedRoutedMoe(nn.Module):
             scores = jax.nn.sigmoid(jnp.dot(
                 xf.astype(jnp.float32), w_r,
                 precision=jax.lax.Precision.HIGHEST))
-            idx, weights = route(scores, bias, k, self.scaling)
+            groups = None
+            if self.n_group > 1:
+                groups = chosen_groups(jax.lax.stop_gradient(scores + bias),
+                                       self.n_group, self.topk_group)
+            idx, weights = route(scores, bias, k, self.scaling, groups)
             self.sow("intermediates", "choices", idx)
             # pairs are laid out choice-major, [k, N]: a [k, N, D] view pads
             # no axis to the TPU's tiles, which [N, k, D] would (k = 6)
@@ -367,6 +395,15 @@ class SharedRoutedMoe(nn.Module):
         if train and not self.is_initializing():
             seen.value = seen.value + sizes.astype(jnp.float32)
             steps.value = steps.value + 1.0
+        if self.n_group > 1:
+            reached = self.variable(COUNTERS, "group_tokens",
+                                    lambda: jnp.zeros((), jnp.float32))
+            if train and not self.is_initializing():
+                size = self.n_routed // self.n_group
+                mine_g = groups[:, self.held_first // size:
+                                (self.held_first + held - 1) // size + 1]
+                reached.value = reached.value + jnp.sum(
+                    jnp.any(mine_g, axis=1).astype(jnp.float32))
         return (out + routed.astype(dt)).reshape(b, t, d)
 
 
@@ -395,27 +432,51 @@ class LatentMoeSizes:
     held_count: Optional[int] = None
     remat: bool = True
     dtype: Any = jnp.float32
+    #: the mixer of each layer, ``"latent"`` or ``"delta"``; empty: all latent
+    mixers: tuple = ()
+    #: group-limited routing (1: none)
+    n_group: int = 1
+    topk_group: int = 1
+    #: the latent mixer's per-head query / key norms; both mixers' output
+    #: norm and head-wise gate
+    qk_norm: bool = False
+    out_gate: bool = False
+    #: the delta mixer: its head size (q, k and v alike), the short
+    #: convolution's positions and the log-decay's lower bound
+    delta_head_dim: int = 128
+    delta_conv: int = 4
+    delta_lower_bound: float = -5.0
 
 
 class LatentMoeBlock(nn.Module):
-    """``h += Attn(RMSNorm(h))``; ``h += Mlp(RMSNorm(h))``: a SwiGLU of
-    ``dense_width`` in the leading dense layers, the sparse layer after."""
+    """``h += Mixer(RMSNorm(h))``; ``h += Mlp(RMSNorm(h))``: the mixer
+    latent attention (``attn``) or the delta rule (``delta``), the MLP a
+    SwiGLU of ``dense_width`` in the leading dense layers, the sparse layer
+    after."""
 
     sizes: LatentMoeSizes
     sparse: bool
+    mixer: str = "latent"
 
     @nn.compact
     def __call__(self, h, train: bool = False):
         c = self.sizes
-        a = LatentAttention(c.heads, c.nope, c.rope, c.v_dim, c.kv_rank,
-                            c.rope_theta, c.eps, c.dtype, name="attn")(
-            RMSNorm(c.eps, c.dtype, name="attn_norm")(h))
+        a = RMSNorm(c.eps, c.dtype, name="attn_norm")(h)
+        if self.mixer == "latent":
+            a = LatentAttention(c.heads, c.nope, c.rope, c.v_dim, c.kv_rank,
+                                c.rope_theta, c.eps, c.dtype, c.qk_norm,
+                                c.out_gate, name="attn")(a)
+        else:
+            a = DeltaAttention(c.heads, c.delta_head_dim, c.delta_conv,
+                               c.delta_lower_bound, c.eps, c.dtype,
+                               name="delta")(a)
         h = h + a
         m = RMSNorm(c.eps, c.dtype, name="mlp_norm")(h)
         if self.sparse:
             m = SharedRoutedMoe(c.n_routed, c.top_k, c.expert_width,
                                 c.n_shared, c.routed_scaling, c.held_first,
-                                c.held_count, c.dtype, name="mlp")(m, train)
+                                c.held_count, c.dtype, c.n_group,
+                                c.topk_group, name="mlp")(m, train)
         else:
             with jax.named_scope(SCOPE_LM_DENSE):
                 m = SwiGLU(c.dense_width, c.dtype, name="mlp")(m)
@@ -423,10 +484,11 @@ class LatentMoeBlock(nn.Module):
 
 
 class LatentMoeLM(nn.Module):
-    """Decoder-only LM of latent-attention blocks with sparse experts: an
-    embedding, ``layers`` blocks (the first ``first_dense`` with a dense
-    MLP), a final RMSNorm and an untied head; no learned positions. Each
-    block is rematerialised in the backward pass (``remat``)."""
+    """Decoder-only LM of blocks with sparse experts: an embedding,
+    ``layers`` blocks (the first ``first_dense`` with a dense MLP; layer
+    ``i``'s mixer is ``mixers[i]``, latent attention where none is named),
+    a final RMSNorm and an untied head; no learned positions. Each block is
+    rematerialised in the backward pass (``remat``)."""
 
     vocab_size: int
     sizes: LatentMoeSizes
@@ -439,8 +501,13 @@ class LatentMoeLM(nn.Module):
         h = embed_rows(table, x.astype(jnp.int32)).astype(c.dtype)
         block = (nn.remat(LatentMoeBlock, static_argnums=(2,)) if c.remat
                  else LatentMoeBlock)
+        mixers = c.mixers or ("latent",) * c.layers
+        if len(mixers) != c.layers or set(mixers) - {"latent", "delta"}:
+            raise ValueError(f"mixers {mixers}: one of 'latent' / 'delta' "
+                             f"for each of the {c.layers} layers")
         for i in range(c.layers):
-            h = block(c, i >= c.first_dense, name=f"layer_{i}")(h, train)
+            h = block(c, i >= c.first_dense, mixers[i],
+                      name=f"layer_{i}")(h, train)
         h = RMSNorm(c.eps, c.dtype, name="final_norm")(h)
         with jax.named_scope(SCOPE_LM_DENSE):
             return Linear(self.vocab_size, c.dtype, jnp.float32,
@@ -448,7 +515,8 @@ class LatentMoeLM(nn.Module):
 
 
 def expert_row_counters(variables: dict) -> dict:
-    """``{"rows.<layer>.<expert>": rows, "steps.<layer>": steps}`` from the
+    """``{"rows.<layer>.<expert>": rows, "steps.<layer>": steps}`` (and
+    ``"group_tokens.<layer>"`` under a group-limited router) from the
     ``counters`` the sparse layers keep (host numbers): sums over every
     training step since the variables were seeded, where the packed
     simulation round trained them."""
@@ -460,6 +528,9 @@ def expert_row_counters(variables: dict) -> dict:
         for e, rows in enumerate(jax.device_get(mlp["expert_rows"])):
             out[f"rows.{layer}.{e}"] = float(rows)
         out[f"steps.{layer}"] = float(jax.device_get(mlp["steps"]))
+        if "group_tokens" in mlp:
+            out[f"group_tokens.{layer}"] = float(
+                jax.device_get(mlp["group_tokens"]))
     return out
 
 
@@ -474,6 +545,28 @@ LATENT_MOE_PRESETS = {
         layers=5, first_dense=1, dense_width=6144, n_routed=128, top_k=6,
         expert_width=768, n_shared=2, routed_scaling=2.448, rope_theta=1e6,
         eps=1e-6, held_first=0, held_count=16, seq_len=4096),
+    # one chip's share (64 chips share each layer) of the text decoder of
+    # inclusionAI/Ling-3.0-flash-VL, cut to 7 layers: the leading dense layer
+    # and one period of 6 (published layers 2 - 7), five delta-rule mixers to
+    # one latent
+    # (``benchmarks/configs/ling3_flash_vl.json``, held equal by a test)
+    "ling3_flash_vl": dict(
+        dim=2560, heads=32, nope=128, rope=64, v_dim=128, kv_rank=512,
+        layers=7, first_dense=1, dense_width=6144, n_routed=512, top_k=8,
+        expert_width=768, n_shared=1, routed_scaling=2.5, rope_theta=6e6,
+        eps=1e-6, held_first=0, held_count=8, seq_len=4096,
+        mixers=["delta", "delta", "delta", "delta", "latent", "delta",
+                "delta"],
+        n_group=8, topk_group=4, qk_norm=True, out_gate=True,
+        delta_head_dim=128, delta_conv=4, delta_lower_bound=-5.0),
+    "ling3_tiny": dict(
+        dim=32, heads=2, nope=16, rope=8, v_dim=16, kv_rank=16, layers=4,
+        first_dense=1, dense_width=96, n_routed=16, top_k=4, expert_width=24,
+        n_shared=1, routed_scaling=2.5, rope_theta=6e6, eps=1e-6,
+        held_first=0, held_count=4, seq_len=32,
+        mixers=["delta", "delta", "latent", "delta"],
+        n_group=4, topk_group=2, qk_norm=True, out_gate=True,
+        delta_head_dim=16, delta_conv=4, delta_lower_bound=-5.0),
     "kanana2_tiny": dict(
         dim=32, heads=2, nope=16, rope=8, v_dim=16, kv_rank=16, layers=3,
         first_dense=1, dense_width=96, n_routed=8, top_k=2, expert_width=24,
@@ -485,6 +578,7 @@ LATENT_MOE_PRESETS = {
 def _latent_moe_bundle(name: str, output_dim: int, **kw) -> ModelBundle:
     sizes = {**LATENT_MOE_PRESETS[name], **kw}
     seq_len = sizes.pop("seq_len")
+    sizes["mixers"] = tuple(sizes.get("mixers", ()))
     module = LatentMoeLM(vocab_size=output_dim, sizes=LatentMoeSizes(**sizes))
     return ModelBundle(
         name=name, module=module, input_shape=(seq_len,),
@@ -496,6 +590,16 @@ def _latent_moe_bundle(name: str, output_dim: int, **kw) -> ModelBundle:
 @register_model("kanana2_30b_a3b")
 def _kanana2(output_dim: int = 16032, **kw):
     return _latent_moe_bundle("kanana2_30b_a3b", output_dim or 16032, **kw)
+
+
+@register_model("ling3_flash_vl")
+def _ling3(output_dim: int = 19648, **kw):
+    return _latent_moe_bundle("ling3_flash_vl", output_dim or 19648, **kw)
+
+
+@register_model("ling3_tiny")
+def _ling3_tiny(output_dim: int = 64, **kw):
+    return _latent_moe_bundle("ling3_tiny", output_dim or 64, **kw)
 
 
 @register_model("kanana2_tiny")

@@ -22,8 +22,10 @@ import jax
 import jax.numpy as jnp
 
 from fedml_tpu.models import ModelBundle, register_model
-from fedml_tpu.obs.tracer import SCOPE_LM_ATTN, SCOPE_LM_DENSE
+from fedml_tpu.obs.tracer import (SCOPE_LM_ATTN, SCOPE_LM_DENSE, SCOPE_LM_KDA,
+                                  SCOPE_LM_KDA_PREP)
 from fedml_tpu.ops.attention import attention
+from fedml_tpu.ops.kda import kda_chunked
 
 
 class SelfAttention(nn.Module):
@@ -241,7 +243,13 @@ class LatentAttention(nn.Module):
     key-value and ``k_r`` ONE rotary key for all heads; ``c <- RMSNorm(c)``;
     ``[k_nope, v] = W_kvb c`` per head; rotary on ``q_rope`` and ``k_r``;
     ``k = [k_nope, k_r]``; causal softmax of ``q . k / sqrt(nope + rope)``;
-    the ``heads * v_dim`` output goes through ``W_o``."""
+    the ``heads * v_dim`` output goes through ``W_o``.
+
+    ``qk_norm``: each head's whole query and key (``nope + rope`` wide) go
+    through an RMSNorm of their own before the rotary part is turned (so the
+    rotary key is turned a head, after its norm). ``out_gate``: each head's
+    output is RMS-normalised and multiplied by one sigmoid gate a head
+    (``W_g x``) before ``W_o``."""
 
     heads: int
     nope: int
@@ -251,6 +259,8 @@ class LatentAttention(nn.Module):
     rope_theta: float = 10000.0
     eps: float = 1e-6
     dtype: Any = jnp.float32
+    qk_norm: bool = False
+    out_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -265,15 +275,130 @@ class LatentAttention(nn.Module):
         with jax.named_scope(SCOPE_LM_DENSE):
             kv = Linear(h * (dn + dv), self.dtype, name="kv_b")(c)
         kv = kv.reshape(b, t, h, dn + dv).transpose(0, 2, 1, 3)
-        k_r = rotary(ckr[:, None, :, self.kv_rank:], self.rope_theta)  # [B,1,T,dr]
-        q = jnp.concatenate(
-            [q[..., :dn], rotary(q[..., dn:], self.rope_theta)], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(k_r, (b, h, t, dr))], axis=-1)
+        if self.qk_norm:
+            def turned(a):
+                return jnp.concatenate(
+                    [a[..., :dn], rotary(a[..., dn:], self.rope_theta)], -1)
+
+            q = turned(RMSNorm(self.eps, self.dtype, name="q_norm")(q))
+            k = turned(RMSNorm(self.eps, self.dtype, name="k_norm")(
+                jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+                    ckr[:, None, :, self.kv_rank:], (b, h, t, dr))], -1)))
+        else:
+            k_r = rotary(ckr[:, None, :, self.kv_rank:], self.rope_theta)  # [B,1,T,dr]
+            q = jnp.concatenate(
+                [q[..., :dn], rotary(q[..., dn:], self.rope_theta)], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_r, (b, h, t, dr))], axis=-1)
         with jax.named_scope(SCOPE_LM_ATTN):
             o = attention(q, k, kv[..., dn:], causal=True,
                           block_q=_LATENT_ATTN_BLOCK,
                           block_k=_LATENT_ATTN_BLOCK)
-        o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dv)
+        o = o.transpose(0, 2, 1, 3)
+        if self.out_gate:
+            o = HeadGate(self.eps, self.dtype, name="out_gate")(o, x)
         with jax.named_scope(SCOPE_LM_DENSE):
-            return Linear(dim, self.dtype, name="o_proj")(o)
+            return Linear(dim, self.dtype, name="o_proj")(
+                o.reshape(b, t, h * dv))
+
+
+class HeadGate(nn.Module):
+    """``o [B, T, H, dv]`` RMS-normalised a head (one scale over ``dv``) and
+    multiplied by one sigmoid gate a head, ``sigmoid(W_g x)`` with ``W_g
+    [D, H]``."""
+
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, o, x):
+        with jax.named_scope(SCOPE_LM_DENSE):
+            gate = Linear(o.shape[-2], self.dtype, jnp.float32, name="proj")(x)
+        o = RMSNorm(self.eps, jnp.float32, name="norm")(o)
+        return (o * jax.nn.sigmoid(gate)[..., None]).astype(self.dtype)
+
+
+def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+    """Depthwise causal convolution along ``T``: ``x [B, T, C]``, ``w [K,
+    C]`` -> ``y_t = sum_i w[i] x_{t-K+1+i}`` (zeros before position 0), in
+    float32."""
+    k, t = w.shape[0], x.shape[1]
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(xp[:, i:i + t] * w[i] for i in range(k))
+
+
+def slow_decay_bias(key: jax.Array, shape, lower_bound: float) -> jax.Array:
+    """``dt_bias`` at which the lower-bounded gate rests on a slow decay:
+    a channel's ``-g`` at a zero pre-activation is drawn log-uniform over
+    ``[0.001, 0.1]`` (``alpha`` 0.999 .. 0.905, the public KDA init's ``dt``
+    range at a rate of 1) and the bias is its logit under the bound."""
+    rate = jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+    share = rate / -lower_bound
+    return jnp.log(share) - jnp.log1p(-share)
+
+
+class DeltaAttention(nn.Module):
+    """A linear-attention mixer by the delta rule with a per-channel decay
+    (Kimi Delta Attention, ``ops/kda.py``). ``q, k, v = SiLU(conv(W x))``,
+    a causal depthwise convolution of ``conv`` positions each; heads of
+    ``head_dim``; ``q`` and ``k`` L2-normalised a head, ``q`` scaled by
+    ``head_dim^-0.5``; the log-decay ``g = lower_bound * sigmoid(exp(A_log)
+    * (W_f x + dt_bias))`` a channel (``A_log`` a head), so ``alpha =
+    exp(g)`` lies in ``[e^lower_bound, 1)``; ``beta = sigmoid(W_b x)`` a
+    head; the scan; then the output's norm and gate (:class:`HeadGate`)
+    and ``W_o``. ``dt_bias`` starts where a channel's decay is slow
+    (:func:`slow_decay_bias`), as the public KDA code's does: a state that
+    is gone after a position or two is no linear attention."""
+
+    heads: int
+    head_dim: int
+    conv: int = 4
+    lower_bound: float = -5.0
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, dim = x.shape
+        h, d = self.heads, self.head_dim
+        with jax.named_scope(SCOPE_LM_DENSE):
+            q, k, v, f = (Linear(h * d, self.dtype, name=n)(x)
+                          for n in ("q_proj", "k_proj", "v_proj", "f_proj"))
+            beta = Linear(h, self.dtype, jnp.float32, name="b_proj")(x)
+        with jax.named_scope(SCOPE_LM_KDA_PREP):
+            def heads(a):
+                return a.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+
+            def conv(a, name):
+                w = self.param(name, _normal(), (self.conv, h * d),
+                               jnp.float32)
+                return heads(nn.silu(causal_conv(a, w)))
+
+            def unit(a):
+                return a * jax.lax.rsqrt(
+                    jnp.sum(a * a, axis=-1, keepdims=True) + self.eps)
+
+            q = unit(conv(q, "q_conv")) * d ** -0.5
+            k = unit(conv(k, "k_conv"))
+            v = conv(v, "v_conv")
+            a_log = self.param("A_log", nn.initializers.zeros, (h,),
+                               jnp.float32)
+            dt_bias = self.param(
+                "dt_bias",
+                lambda key, shape, dtype: slow_decay_bias(
+                    key, shape, self.lower_bound).astype(dtype),
+                (h * d,), jnp.float32)
+            g = self.lower_bound * jax.nn.sigmoid(
+                jnp.exp(a_log)[:, None, None]
+                * heads(f.astype(jnp.float32) + dt_bias))
+            beta = jax.nn.sigmoid(beta).transpose(0, 2, 1)
+        with jax.named_scope(SCOPE_LM_KDA):
+            o = kda_chunked(q.astype(self.dtype), k.astype(self.dtype),
+                            v.astype(self.dtype), g, beta, dtype=self.dtype)
+        with jax.named_scope(SCOPE_LM_KDA_PREP):
+            o = HeadGate(self.eps, self.dtype, name="out_gate")(
+                o.transpose(0, 2, 1, 3), x)
+        with jax.named_scope(SCOPE_LM_DENSE):
+            return Linear(dim, self.dtype, name="o_proj")(
+                o.reshape(b, t, h * d))

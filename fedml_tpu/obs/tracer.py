@@ -92,6 +92,13 @@ SCOPE_STEP_EMIT = "fedml.step.emit"
 #: of them (norms, rotary, residual adds, the embedding) stays step.train's.
 #: attention proper: scores, softmax, values (ops/attention.py's kernels)
 SCOPE_LM_ATTN = "fedml.lm.attn"
+#: the delta rule's chunked scan (ops/kda.py): intra-chunk products, the
+#: triangular solve, the state's recurrence, the output; its backward too
+SCOPE_LM_KDA = "fedml.lm.kda"
+#: what a linear-attention mixer does around the scan: short convolutions,
+#: SiLU, the L2 norms of q and k, the decay and step gates, the output's
+#: norm and gate (its projections are ``fedml.lm.dense``)
+SCOPE_LM_KDA_PREP = "fedml.lm.kda_prep"
 #: router matmul, selection, sort, the rows' fan-out and weighted add-back
 SCOPE_LM_ROUTE = "fedml.lm.route"
 #: the grouped matmuls over the rows of the experts held here

@@ -10,6 +10,9 @@ implementations:
 - :mod:`fedml_tpu.ops.grouped_matmul` — the sparse-expert layer's grouped
   matmul over rows sorted by expert (``lax.ragged_dot``) and the row moves
   around it, gathers in both directions.
+- :mod:`fedml_tpu.ops.kda` — the delta rule with a per-channel decay (a
+  linear-attention layer's recurrence) as a chunked scan, forward and
+  backward; plain ``jax.numpy``, no kernel yet and no ``impl`` switch.
 - :mod:`fedml_tpu.ops.xent` — fused masked softmax cross-entropy over large
   vocabularies without materializing log-softmax in HBM.
 

@@ -278,13 +278,12 @@ def test_lanes_under_vmap_take_their_own_rung():
 
 # --- the federated round ------------------------------------------------------
 
-def _round_spec():
+def _round_spec(fixture: str = "BENCHMARK.tiny_lm.json"):
     import os
     from benchmarks.harness.spec import Spec
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    return Spec(os.path.join(root, "tests", "benchmark", "fixtures",
-                             "BENCHMARK.tiny_lm.json"))
+    return Spec(os.path.join(root, "tests", "benchmark", "fixtures", fixture))
 
 
 @pytest.mark.parametrize("seed,round_idx", [(3, 1), (2**31 + 9, 2)])
@@ -382,17 +381,22 @@ def test_token_ids_reach_the_model_unrounded(dtype, kind):
     api.close()
 
 
-def test_lowered_lm_round_program_names_every_scope():
-    """The LM's round program carries the step's scopes and all five
-    ``fedml.lm.*`` names, as metadata only."""
+@pytest.mark.parametrize("fixture,cell_name", [
+    ("BENCHMARK.tiny_lm.json", "tiny_kanana2_sim"),
+    ("BENCHMARK.tiny_hybrid.json", "tiny_ling3_sim")])
+def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
+    """An LM's round program carries the step's scopes and the
+    ``fedml.lm.*`` names of what it is built of, as metadata only: all of
+    the table for the hybrid decoder, all but the delta rule's two for the
+    latent-attention one."""
     import re
 
     from benchmarks.harness.cell import build_api
     from fedml_tpu.obs import tracer
     from fedml_tpu.parallel.packed import plan_arrays_tuple
 
-    spec = _round_spec()
-    cell = spec.cell("tiny_kanana2_sim")
+    spec = _round_spec(fixture)
+    cell = spec.cell(cell_name)
     config = spec.config(cell["config"])
     ds, _rows = spec.module("traffic", config["generator"]).make(config, cell, 1)
     api = build_api(config, cell, ds)
@@ -407,6 +411,8 @@ def test_lowered_lm_round_program_names_every_scope():
     named = lowered.as_text(debug_info=True)
     found = set(re.findall(r"fedml\.[a-z_.]+", named))
     table = {v for k, v in vars(tracer).items() if k.startswith("SCOPE_")}
+    if "delta" not in config["model"].get("mixers", ()):
+        table -= {tracer.SCOPE_LM_KDA, tracer.SCOPE_LM_KDA_PREP}
     assert found == table
     text = lowered.as_text()
     assert "fedml." not in text
@@ -432,4 +438,200 @@ def test_lowered_lm_round_program_names_every_scope():
         last = re.findall(r"fedml\.[a-z_.]+", path)
         assert last and last[-1] in (tracer.SCOPE_LM_ROUTE,
                                      tracer.SCOPE_LM_EXPERTS), path
+    api.close()
+
+
+# --- the hybrid decoder: delta-rule and latent mixers, group-limited router --
+
+from benchmarks.references import ling3_flash_vl as hyb  # noqa: E402
+
+
+def hybrid_config(**over):
+    sizes = {**LATENT_MOE_PRESETS["ling3_tiny"], **over}
+    return {"name": "tiny_hybrid", "model": sizes, "data": {"vocab": VOCAB},
+            "recipe": {"lr": 0.1, "momentum": 0.0}}
+
+
+@pytest.mark.parametrize("over,remat", [
+    ({}, True), ({"held_first": 4, "held_count": 8}, False),
+    ({"mixers": ["latent", "delta", "delta", "latent"]}, True)])
+def test_hybrid_logits_loss_and_gradients_match_the_reference(over, remat):
+    """The mixers' pattern is data: the same module builds any of them."""
+    config = hybrid_config(**over)
+    v = hyb.init(jax.random.key(7), config)
+    b = create_model("ling3_tiny", VOCAB, input_shape=(32,),
+                     dtype=jnp.float32, remat=remat, **over)
+    assert (jax.tree.map(jnp.shape, b.init(jax.random.key(0)))
+            == jax.tree.map(jnp.shape, v))
+    x, y, m = batch(t=32)
+    forward = hyb._forward(config, "reference")
+
+    def program(p):
+        logits, new = b.apply_train({**v, "params": p}, x, None)
+        return nwp.loss(logits, y, m), (logits, new["counters"])
+
+    def reference(p):
+        logits, stats, _ = forward(p, v["counters"], x)
+        per = -jnp.take_along_axis(jax.nn.log_softmax(logits), y[..., None],
+                                   -1)[..., 0]
+        w = jnp.broadcast_to(m[:, None], per.shape)
+        return jnp.sum(per * w) / jnp.sum(w), (logits, stats)
+
+    with jax.default_matmul_precision("highest"):
+        (lp, (op, sp)), gp = jax.value_and_grad(program, has_aux=True)(v["params"])
+        (lr, (orf, sr)), gr = jax.value_and_grad(reference, has_aux=True)(v["params"])
+    np.testing.assert_allclose(op, orf, atol=5e-6)
+    np.testing.assert_allclose(lp, lr, rtol=1e-6)
+    for (path, a), c in zip(jax.tree_util.tree_leaves_with_path(gp),
+                            jax.tree.leaves(gr)):
+        # the chunked scan sums in another order than the recurrence: the
+        # decay's own gradients are small differences of large terms
+        np.testing.assert_allclose(a, c, atol=5e-4 * float(jnp.abs(c).max() + 1e-6)
+                                   + 1e-9, err_msg=str(path))
+    for name in sp:
+        for leaf in ("expert_rows", "steps", "group_tokens"):
+            np.testing.assert_array_equal(sp[name]["mlp"][leaf],
+                                          sr[name]["mlp"][leaf], err_msg=leaf)
+
+
+def test_mixers_must_name_every_layer():
+    with pytest.raises(ValueError, match="mixers"):
+        create_model("ling3_tiny", VOCAB, mixers=["delta", "latent"]).init(
+            jax.random.key(0))
+
+
+@pytest.mark.parametrize("n_group,topk_group,top_k", [(4, 2, 4), (8, 4, 8),
+                                                      (2, 1, 3)])
+def test_group_limited_selection_against_a_naive_one(n_group, topk_group, top_k):
+    """A group's score is the sum of its two largest ``score + bias``; only
+    the best groups' experts stand; ``top_k`` over those; weights from the
+    UNBIASED scores."""
+    n, e = 64, 32
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.key(1), (n, e)))
+    bias = 0.3 * jax.random.normal(jax.random.key(2), (e,))
+    groups = moe.chosen_groups(scores + bias, n_group, topk_group)
+    idx, w = moe.route(scores, bias, top_k, 2.5, groups)
+    s, b = np.asarray(scores, np.float64), np.asarray(bias, np.float64)
+    size = e // n_group
+    for t in range(n):
+        biased = s[t] + b
+        gscore = [np.sort(biased[g * size:(g + 1) * size])[-2:].sum()
+                  for g in range(n_group)]
+        best = set(np.argsort(gscore)[-topk_group:])
+        assert set(np.flatnonzero(groups[t])) == best
+        stands = [i for i in range(e) if i // size in best]
+        want = sorted(stands, key=lambda i: -biased[i])[:top_k]
+        assert set(np.asarray(idx[t])) == set(want)
+        chosen = s[t, np.asarray(idx[t])]
+        np.testing.assert_allclose(w[t], chosen / chosen.sum() * 2.5, rtol=1e-5)
+    # and the reference's own, written another way, agrees
+    np.testing.assert_array_equal(
+        groups, hyb.chosen_groups(scores + bias, n_group, topk_group))
+
+
+def test_route_without_groups_is_the_ungrouped_router():
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.key(1), (16, 8)))
+    bias = jnp.zeros((8,))
+    every = jnp.ones((16, 2), jnp.bool_)
+    for a, b in zip(moe.route(scores, bias, 2, 1.0),
+                    moe.route(scores, bias, 2, 1.0, every)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_group_routed_shares_add_up_to_the_uncut_layer():
+    """4 shares of 4 of 16 experts in 4 groups (a share holds one whole
+    group, as the cell's 8 held experts lie in group 0): the routed parts of
+    all the shares, plus the shared expert counted once, are the uncut
+    reference layer's output; each share counts the tokens its group stood
+    for, 2 of 4 groups a token."""
+    d, n_routed, width, k = 32, 16, 24, 4
+    config = hybrid_config(held_first=0, held_count=n_routed)
+    v = hyb.init(jax.random.key(3), config)
+    p = v["params"]["layer_1"]["mlp"]
+    x = jax.random.normal(jax.random.key(4), (2, 32, d), jnp.float32)
+
+    def layer(first, count, n_shared):
+        mod = SharedRoutedMoe(n_routed, k, width, n_shared, 2.5, first, count,
+                              jnp.float32, 4, 2)
+        params = {kk: (a[first:first + count] if kk in ("gate", "up", "down")
+                       else a) for kk, a in p.items() if n_shared or kk != "shared"}
+        stats = {"expert_rows": jnp.zeros((count,)), "steps": jnp.zeros(()),
+                 "group_tokens": jnp.zeros(())}
+        out, new = mod.apply({"params": params, "counters": stats}, x, True,
+                             mutable=["counters"])
+        return out, new["counters"]
+
+    with jax.default_matmul_precision("highest"):
+        whole, stats = layer(0, n_routed, 1)
+        shared_once = whole - layer(0, n_routed, 0)[0]
+        shares = [layer(first, 4, 0) for first in range(0, n_routed, 4)]
+        uncut, (rows, reached), _ = hyb._forward(config, "reference").moe(x, p)
+    np.testing.assert_allclose(sum(s[0] for s in shares) + shared_once, whole,
+                               atol=3e-6)
+    np.testing.assert_allclose(whole, uncut, atol=3e-6)
+    assert float(rows.sum()) == 64 * k == float(stats["expert_rows"].sum())
+    assert float(reached) == 64 == float(stats["group_tokens"])
+    assert sum(float(s[1]["group_tokens"]) for s in shares) == 64 * 2
+    # a share's rows come only from tokens its group stood for
+    for out, c in shares:
+        assert float(c["expert_rows"].sum()) <= k * float(c["group_tokens"])
+
+
+def test_hybrid_registered_defaults_are_the_published_widths():
+    k = LATENT_MOE_PRESETS["ling3_flash_vl"]
+    assert (k["dim"], k["heads"], k["delta_head_dim"], k["delta_conv"],
+            k["delta_lower_bound"]) == (2560, 32, 128, 4, -5.0)
+    assert (k["nope"], k["rope"], k["v_dim"], k["kv_rank"]) == (128, 64, 128, 512)
+    assert (k["n_routed"], k["top_k"], k["n_group"], k["topk_group"],
+            k["n_shared"], k["expert_width"], k["dense_width"],
+            k["routed_scaling"]) == (512, 8, 8, 4, 1, 768, 6144, 2.5)
+    assert k["mixers"].count("delta") == 6 and k["mixers"][4] == "latent"
+    b = create_model("ling3_flash_vl", 19648)
+    shapes = jax.eval_shape(b.init, jax.random.key(0))
+    n = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes["params"]))
+    assert n == 822_036_928              # the issue's 822.0 M parameters
+    layer = shapes["params"]["layer_1"]
+    assert layer["mlp"]["router"].shape == (2560, 512)
+    assert layer["mlp"]["gate"].shape == (8, 2560, 768)
+    assert sum(int(np.prod(s.shape))
+               for s in jax.tree.leaves(layer["delta"])) == 52_646_048
+    assert sum(int(np.prod(s.shape)) for s in jax.tree.leaves(
+        shapes["params"]["layer_4"]["attn"])) == 31_966_208
+
+
+def test_hybrid_round_counts_its_groups_and_trains():
+    """One packed round of the tiny hybrid model: the counters are sums over
+    the clients' steps, the loss is finite and the weights move."""
+    from fedml_tpu.algorithms.fedavg import FedAvgAPI
+    from fedml_tpu.core.config import FedConfig
+    from fedml_tpu.data import FedDataset
+
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, VOCAB, (4, 3, 33)).astype(np.int32)
+    ds = FedDataset(train_x=ids[..., :-1], train_y=ids[..., 1:],
+                    train_mask=np.ones((4, 3), np.float32),
+                    train_counts=np.full((4,), 3, np.int64),
+                    test_x=ids[0, :, :-1], test_y=ids[0, :, 1:],
+                    test_mask=np.ones(3, np.float32), class_num=VOCAB,
+                    task="nwp", name="tiny")
+    cfg = FedConfig(model="ling3_tiny", dataset="tiny", batch_size=1, epochs=1,
+                    client_optimizer="sgd", lr=0.1, momentum=0.0,
+                    client_num_in_total=4, client_num_per_round=2,
+                    pack_lanes=1, device_data="on", comm_round=1)
+    api = FedAvgAPI(ds, cfg, create_model("ling3_tiny", VOCAB,
+                                          input_shape=(32,)))
+    before = jax.device_get(api.variables)
+    loss = float(api.run_round(1))
+    after = jax.device_get(api.variables)
+    assert np.isfinite(loss)
+    c = after["counters"]["layer_1"]["mlp"]
+    assert float(c["steps"]) == 6.0                  # 2 clients x 3 sequences
+    assert 0 < float(c["group_tokens"]) <= 6 * 32
+    assert float(c["expert_rows"].sum()) <= 4 * float(c["group_tokens"])
+    moved = jax.tree.map(lambda a, b: float(np.abs(a - b).max()),
+                         after["params"], before["params"])
+    assert moved["layer_0"]["delta"]["f_proj"]["kernel"] > 0
+    assert moved["layer_2"]["attn"]["q_norm"]["scale"] > 0
+    counters = api.bundle.counters(api.variables)
+    assert counters["group_tokens.layer_1"] == float(c["group_tokens"])
     api.close()

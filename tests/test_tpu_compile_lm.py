@@ -122,3 +122,59 @@ def test_row_capacities_compile_as_one_conditional_a_pass(one_chip, no_cache):
     # as long as the conditional they are operands of. The union of four
     # rungs' residuals would add another 0.4 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1e9
+
+
+@pytest.mark.parametrize("chunk", [32, 64])
+def test_delta_rule_scan_compiles_at_published_widths(one_chip, no_cache, chunk):
+    """32 heads of 128, 4,096 positions, one sequence, bf16 operands:
+    forward and backward of the chunked scan (``ops/kda.py``). The
+    temporaries stay bounded: the intra-chunk part is recomputed, the scan
+    keeps a state every few chunks (2 MB each over 32 heads), and no
+    ``[T, T]`` product or per-position state (8.6 GB) is ever formed."""
+    from fedml_tpu.ops.kda import kda_chunked
+
+    def step(q, k, v, g, beta, c):
+        return jax.grad(lambda *a: jnp.sum(kda_chunked(*a, chunk=chunk) * c),
+                        argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    def sd(dtype, *tail):
+        return jax.ShapeDtypeStruct((1, 32, 4096) + tail, dtype,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(step).lower(
+        sd(jnp.bfloat16, 128), sd(jnp.bfloat16, 128), sd(jnp.bfloat16, 128),
+        sd(jnp.float32, 128), sd(jnp.float32), sd(jnp.float32, 128)).compile()
+    text = compiled.as_text()
+    assert " while(" in text                      # the scan over the chunks
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_older_lm_lowers_the_same_with_the_new_options_at_their_defaults():
+    """The options the hybrid decoder added (the mixers' pattern, the
+    group-limited router, the query / key norms, the output gate) leave the
+    latent-attention LM's program as it was: built with every one of them
+    named at its default it lowers to the same text as built without, and
+    its variable tree has no new leaf (CPU fixture; against the parent
+    commit's text the same was checked by sha256, ``PERF.md`` PR 30)."""
+    from fedml_tpu.core.tasks import nwp
+    from fedml_tpu.models import create_model
+
+    def lowered(**kw):
+        b = create_model("kanana2_tiny", 64, input_shape=(16,), **kw)
+        v = b.init(jax.random.key(0))
+
+        def step(v, x, y, m):
+            def loss(p):
+                logits, new = b.apply_train({**v, "params": p}, x, None)
+                return nwp.loss(logits, y, m), new
+            return jax.value_and_grad(loss, has_aux=True)(v["params"])
+
+        x = jnp.zeros((2, 16), jnp.int32)
+        return (jax.jit(step).lower(v, x, x, jnp.ones((2,))).as_text(),
+                jax.tree.map(jnp.shape, v))
+
+    plain, tree = lowered()
+    named, tree2 = lowered(mixers=("latent",) * 3, n_group=1, topk_group=1,
+                           qk_norm=False, out_gate=False)
+    assert plain == named and tree == tree2
+    assert "group_tokens" not in str(tree) and "out_gate" not in str(tree)

@@ -1,0 +1,102 @@
+#!/usr/bin/env python3
+"""Does a cell's round program fit one chip? Compiled for a DESCRIBED v5e,
+no chip needed:
+
+    JAX_PLATFORMS=cpu python tools/round_fit.py --workload ling3_sim_c2
+
+Builds the cell as ``benchmarks/run.py`` does (the configuration's model at
+its published shapes, the cell's data and plan), lowers the packed round
+program of the cell's first round for ``topologies.get_topology_desc("tpu",
+"v5e:2x2")``'s first device and prints ``memory_analysis()``: arguments,
+results, temporaries and program text, and their sum against the chip's
+16 GiB. Nothing runs: no time, no result (``PERF.md``, PR 30). The model's
+parameters are initialised on the host (3.3 GB for 822 M), so this takes a
+few minutes. ``--seq-len`` / ``--batch`` override the configuration's.
+"""
+
+from __future__ import annotations
+
+import argparse
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _ROOT not in sys.path:
+    sys.path.insert(0, _ROOT)
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--workload", required=True)
+    p.add_argument("--seq-len", type=int)
+    p.add_argument("--batch", type=int)
+    args = p.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmarks.harness.cell import build_api
+    from benchmarks.harness.spec import Spec
+    from fedml_tpu.core.rng import round_key
+    from fedml_tpu.parallel.packed import plan_arrays_tuple
+
+    # the program asks jax.default_backend() which attention to take; here
+    # that is the CPU's, and the chip's kernels are what has to fit
+    # (the package exports the function under the module's name)
+    import importlib
+
+    importlib.import_module("fedml_tpu.ops.attention")._pick_impl = (
+        lambda impl: "pallas" if impl == "auto" else impl)
+    jax.config.update("jax_enable_compilation_cache", False)
+
+    spec = Spec()
+    cell = spec.cell(args.workload)
+    config = spec.config(cell["config"])
+    if args.seq_len:
+        config["data"]["seq_len"] = config["model"]["seq_len"] = args.seq_len
+    if args.batch:
+        config["recipe"]["batch_size"] = args.batch
+    dataset, _rows = spec.module("traffic", config["generator"]).make(
+        config, cell, 1)
+    api = build_api(config, cell, dataset)
+    r = int(cell["rounds"]["first"])
+    plan = api._round_plan(r)
+    lanes = plan.lanes
+    step = api.build_round_step_packed(lanes.shape_key)
+    tx, ty, tm, _tc = api._dev_train
+    n = len(plan.sampled)
+    concrete = (api.variables, api.server_state, tx, ty, tm,
+                jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32),
+                round_key(api.root_key, r),
+                tuple(jnp.asarray(a) for a in plan_arrays_tuple(lanes)))
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        concrete)
+    compiled = step.lower(*shapes).compile()
+    m = compiled.memory_analysis()
+    parts = {"arguments": m.argument_size_in_bytes,
+             "results": m.output_size_in_bytes,
+             "aliased": -m.alias_size_in_bytes,
+             "temporaries": m.temp_size_in_bytes,
+             "program text": m.generated_code_size_in_bytes}
+    total = sum(parts.values())
+    params = sum(int(np.prod(a.shape))
+                 for a in jax.tree.leaves(api.variables["params"]))
+    print(f"{args.workload}: {params / 1e6:.1f} M parameters, "
+          f"{config['recipe']['batch_size']} x {config['data']['seq_len']} "
+          f"tokens a step, shape key {lanes.shape_key}")
+    for k, v in parts.items():
+        print(f"  {k:14s} {v / 1e6:10.1f} MB")
+    print(f"  {'sum':14s} {total / 1e6:10.1f} MB = {total / 2**30:.2f} GiB "
+          f"({100 * total / (16 * 2**30):.1f}% of 16 GiB)")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
