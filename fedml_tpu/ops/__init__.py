@@ -11,8 +11,10 @@ implementations:
   matmul over rows sorted by expert (``lax.ragged_dot``) and the row moves
   around it, gathers in both directions.
 - :mod:`fedml_tpu.ops.kda` — the delta rule with a per-channel decay (a
-  linear-attention layer's recurrence) as a chunked scan, forward and
-  backward; plain ``jax.numpy``, no kernel yet and no ``impl`` switch.
+  linear-attention layer's recurrence) in chunks: a kernel pair, forward
+  and backward, that makes a chunk's operands itself and keeps a head's
+  state in VMEM from chunk to chunk; the ``jax.numpy`` scan is the ``xla``
+  path.
 - :mod:`fedml_tpu.ops.xent` — fused masked softmax cross-entropy over large
   vocabularies without materializing log-softmax in HBM.
 

@@ -15,6 +15,8 @@ compiler's message. Rows:
   the kernel under ``vmap`` inside the round's ``lax.scan``;
 - ``ops/xent.py`` at V=10,004 and up a ladder of vocabularies (its block is
   the whole padded row, so VMEM grows with V);
+- ``ops/kda.py``'s kernel pair, forward and backward, at heads of 128 and
+  chunks of 32 and 64 against the ``jax.numpy`` scan, float32 operands;
 - ``ops/batchnorm.py`` and ``ops/conv_lanes.py`` once each — they sit
   behind ``bn_impl``/``conv_impl`` (default ``xla``) and are queued for
   deletion, so a failure there is reported but does not fail the run — and
@@ -131,6 +133,41 @@ def _xent_case(v: int, n: int = 256) -> dict:
             "bwd_err": _rel_err(got[1], ref[1])}
 
 
+def _kda_case(t: int, h: int, chunk: int) -> dict:
+    """The delta rule's kernel pair (forward with the intra-chunk part
+    inside, backward over the chunks in reverse) against the ``jax.numpy``
+    scan, float32 operands on both paths, heads of 128."""
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.ops.kda import kda_chunked
+
+    ks = jax.random.split(jax.random.key(t + chunk), 5)
+
+    def unit(a):
+        return a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+
+    shape = (1, h, t, 128)
+    x = (unit(jax.random.normal(ks[0], shape)) * 128 ** -0.5,
+         unit(jax.random.normal(ks[1], shape)), jax.random.normal(ks[2], shape),
+         -5.0 * jax.nn.sigmoid(jax.random.normal(ks[3], shape)),
+         jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])))
+
+    def run(impl):
+        def loss(*a):
+            o = kda_chunked(*a, chunk=chunk, dtype=jnp.float32, impl=impl)
+            return jnp.sum(jnp.sin(o)), o
+
+        (_, o), grads = jax.value_and_grad(loss, (0, 1, 2, 3, 4),
+                                           has_aux=True)(*x)
+        return o, grads
+
+    ref = jax.jit(lambda: run("xla"))()
+    got = jax.jit(lambda: run("auto"))()
+    return {"fwd_err": _rel_err(got[0], ref[0]),
+            "bwd_err": _rel_err(got[1], ref[1])}
+
+
 def _batchnorm_case() -> dict:
     import jax
     import jax.numpy as jnp
@@ -238,6 +275,9 @@ def main() -> int:
     ] + [
         (f"xent V={v}", v == 10_004, partial(_xent_case, v))
         for v in (10_004, 32_768, 50_304, 131_072)
+    ] + [
+        (f"kda T={t} H={h} chunk={c}", True, partial(_kda_case, t, h, c))
+        for t, h, c in ((512, 4, 32), (1024, 2, 64))
     ] + [
         ("batchnorm (bn_impl=pallas) 64x32x32x16 bf16", False,
          _batchnorm_case),

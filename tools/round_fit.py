@@ -44,13 +44,18 @@ def main(argv=None) -> int:
     from fedml_tpu.core.rng import round_key
     from fedml_tpu.parallel.packed import plan_arrays_tuple
 
-    # the program asks jax.default_backend() which attention to take; here
-    # that is the CPU's, and the chip's kernels are what has to fit
-    # (the package exports the function under the module's name)
+    # the program asks jax.default_backend() which attention and which
+    # delta-rule scan to take; here that is the CPU's, and the chip's
+    # kernels, compiled and not interpreted, are what has to fit (the
+    # package exports the function under the attention module's name)
     import importlib
 
-    importlib.import_module("fedml_tpu.ops.attention")._pick_impl = (
-        lambda impl: "pallas" if impl == "auto" else impl)
+    def on_the_chip(impl):
+        return "pallas" if impl == "auto" else impl
+
+    importlib.import_module("fedml_tpu.ops.attention")._pick_impl = on_the_chip
+    kda = importlib.import_module("fedml_tpu.ops.kda")
+    kda._pick_impl, kda.interpret = on_the_chip, lambda: False
     jax.config.update("jax_enable_compilation_cache", False)
 
     spec = Spec()
