@@ -40,9 +40,10 @@ import dataclasses
 from typing import Optional
 
 from fedml_tpu.models import COUNTERS, ModelBundle, register_model
-from fedml_tpu.models.transformer import (DeltaAttention, LatentAttention,
-                                          Linear, RMSNorm, SelfAttention,
-                                          SwiGLU, _normal)
+from fedml_tpu.models.transformer import (DeltaAttention, GroupedAttention,
+                                          LatentAttention, Linear, RMSNorm,
+                                          SelfAttention, SwiGLU, _normal,
+                                          yarn_frequencies)
 from fedml_tpu.obs.tracer import (SCOPE_LM_DENSE, SCOPE_LM_EXPERTS,
                                   SCOPE_LM_ROUTE)
 from fedml_tpu.ops.grouped_matmul import (embed_rows, fan_out_rows,
@@ -160,15 +161,17 @@ def chosen_groups(biased: jax.Array, n_group: int, topk_group: int):
 
 def route(scores: jax.Array, bias: jax.Array, top_k: int, scaling: float,
           groups: Optional[jax.Array] = None):
-    """``scores [N, E]`` (sigmoid, float32) -> ``(idx [N, k], weights [N,
-    k])``: the ``k`` experts with the largest ``score + bias``, weighted by
+    """``scores [N, E]`` (sigmoid or softmax, float32) -> ``(idx [N, k],
+    weights [N, k])``: the ``k`` experts with the largest ``score + bias``
+    (``bias`` None: the largest scores), weighted by
     their own scores over the chosen scores' sum, times ``scaling``. With
     ``groups [N, n_group]`` (:func:`chosen_groups`) the choice is
     group-limited: only the experts of a token's chosen groups stand for it.
     Gradients flow through the scores, not through the selection or the
     bias. (No gather: a gather's transpose is a scatter, which a TPU
     serialises; the one-hot product's transpose is a product.)"""
-    biased = jax.lax.stop_gradient(scores + bias)
+    biased = jax.lax.stop_gradient(
+        scores if bias is None else scores + bias)
     if groups is not None:
         size = scores.shape[-1] // groups.shape[-1]
         biased = jnp.where(jnp.repeat(groups, size, axis=1), biased, -jnp.inf)
@@ -323,6 +326,9 @@ class SharedRoutedMoe(nn.Module):
     and nothing here stands in for them or for the exchange.
 
     ``n_group > 1``: the router's choice is group-limited (:func:`route`).
+    ``score``: ``"sigmoid"`` scores each expert by itself and chooses by
+    ``score + bias`` (DeepSeek-V3's router); ``"softmax"`` scores over all
+    ``n_routed`` logits and has no bias (the Qwen-MoE lineage's).
 
     The ``counters`` collection (``models.COUNTERS``) carries
     ``expert_rows`` (rows each held expert has computed, summed over the
@@ -344,6 +350,7 @@ class SharedRoutedMoe(nn.Module):
     dtype: Any = jnp.float32
     n_group: int = 1
     topk_group: int = 1
+    score: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -365,11 +372,16 @@ class SharedRoutedMoe(nn.Module):
         with jax.named_scope(SCOPE_LM_ROUTE):
             w_r = self.param("router", _normal(), (d, self.n_routed),
                              jnp.float32)
-            bias = self.param("e_score_correction_bias", _normal(0.01),
-                              (self.n_routed,), jnp.float32)
-            scores = jax.nn.sigmoid(jnp.dot(
-                xf.astype(jnp.float32), w_r,
-                precision=jax.lax.Precision.HIGHEST))
+            logits = jnp.dot(xf.astype(jnp.float32), w_r,
+                             precision=jax.lax.Precision.HIGHEST)
+            if self.score == "softmax":
+                if self.n_group > 1:
+                    raise ValueError("a softmax router has no groups")
+                bias, scores = None, jax.nn.softmax(logits, axis=-1)
+            else:
+                bias = self.param("e_score_correction_bias", _normal(0.01),
+                                  (self.n_routed,), jnp.float32)
+                scores = jax.nn.sigmoid(logits)
             groups = None
             if self.n_group > 1:
                 groups = chosen_groups(jax.lax.stop_gradient(scores + bias),
@@ -432,7 +444,9 @@ class LatentMoeSizes:
     held_count: Optional[int] = None
     remat: bool = True
     dtype: Any = jnp.float32
-    #: the mixer of each layer, ``"latent"`` or ``"delta"``; empty: all latent
+    #: the mixer of each layer: ``"latent"``, ``"delta"``, or grouped-query
+    #: attention over all the keys (``"full"``) or under a sliding window
+    #: (``"window"``); empty: all latent
     mixers: tuple = ()
     #: group-limited routing (1: none)
     n_group: int = 1
@@ -446,6 +460,26 @@ class LatentMoeSizes:
     delta_head_dim: int = 128
     delta_conv: int = 4
     delta_lower_bound: float = -5.0
+    #: the grouped-query mixers: ``heads`` query heads in a ``full`` layer
+    #: and ``window_heads`` in a ``window`` layer, over ``kv_heads``
+    #: key-value heads of ``v_dim`` channels each (queries and keys too);
+    #: a query of a window layer sees ``window`` keys, its own included.
+    #: Full layers turn the first ``rope`` channels of a head, by
+    #: ``rope_theta`` under YaRN where ``yarn_factor`` is set (its original
+    #: positions, two betas and attention factor beside it); window layers
+    #: turn the whole head at ``window_rope_theta``. ``out_gate`` is here
+    #: the head-wise sigmoid gate alone, without a norm
+    kv_heads: int = 0
+    window_heads: int = 0
+    window: int = 0
+    window_rope_theta: float = 10000.0
+    yarn_factor: float = 0.0
+    yarn_original: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
+    #: the router's score function (:class:`SharedRoutedMoe`)
+    score: str = "sigmoid"
 
 
 class LatentMoeBlock(nn.Module):
@@ -466,17 +500,32 @@ class LatentMoeBlock(nn.Module):
             a = LatentAttention(c.heads, c.nope, c.rope, c.v_dim, c.kv_rank,
                                 c.rope_theta, c.eps, c.dtype, c.qk_norm,
                                 c.out_gate, name="attn")(a)
-        else:
+        elif self.mixer == "delta":
             a = DeltaAttention(c.heads, c.delta_head_dim, c.delta_conv,
                                c.delta_lower_bound, c.eps, c.dtype,
                                name="delta")(a)
+        elif self.mixer == "window":
+            a = GroupedAttention(c.window_heads, c.kv_heads, c.v_dim, c.v_dim,
+                                 c.window_rope_theta, window=c.window,
+                                 gate=c.out_gate, dtype=c.dtype,
+                                 name="attn")(a)
+        else:
+            yarn, scale = None, 1.0
+            if c.yarn_factor:
+                scale = c.yarn_attention_factor
+                yarn = tuple(yarn_frequencies(
+                    c.rope, c.rope_theta, c.yarn_factor, c.yarn_original,
+                    c.yarn_beta_fast, c.yarn_beta_slow).tolist())
+            a = GroupedAttention(c.heads, c.kv_heads, c.v_dim, c.rope,
+                                 c.rope_theta, yarn, scale, gate=c.out_gate,
+                                 dtype=c.dtype, name="attn")(a)
         h = h + a
         m = RMSNorm(c.eps, c.dtype, name="mlp_norm")(h)
         if self.sparse:
             m = SharedRoutedMoe(c.n_routed, c.top_k, c.expert_width,
                                 c.n_shared, c.routed_scaling, c.held_first,
                                 c.held_count, c.dtype, c.n_group,
-                                c.topk_group, name="mlp")(m, train)
+                                c.topk_group, c.score, name="mlp")(m, train)
         else:
             with jax.named_scope(SCOPE_LM_DENSE):
                 m = SwiGLU(c.dense_width, c.dtype, name="mlp")(m)
@@ -502,9 +551,11 @@ class LatentMoeLM(nn.Module):
         block = (nn.remat(LatentMoeBlock, static_argnums=(2,)) if c.remat
                  else LatentMoeBlock)
         mixers = c.mixers or ("latent",) * c.layers
-        if len(mixers) != c.layers or set(mixers) - {"latent", "delta"}:
-            raise ValueError(f"mixers {mixers}: one of 'latent' / 'delta' "
-                             f"for each of the {c.layers} layers")
+        if len(mixers) != c.layers or set(mixers) - {"latent", "delta", "full",
+                                                     "window"}:
+            raise ValueError(f"mixers {mixers}: one of 'latent' / 'delta' / "
+                             f"'full' / 'window' for each of the {c.layers} "
+                             "layers")
         for i in range(c.layers):
             h = block(c, i >= c.first_dense, mixers[i],
                       name=f"layer_{i}")(h, train)
@@ -559,6 +610,31 @@ LATENT_MOE_PRESETS = {
                 "delta"],
         n_group=8, topk_group=4, qk_norm=True, out_gate=True,
         delta_head_dim=128, delta_conv=4, delta_lower_bound=-5.0),
+    # one chip's share (8 chips share each layer) of poolside/Laguna-XS.2,
+    # cut to 5 layers: the leading dense layer and one period of 4
+    # (published layers 1 - 4), window attention 3 : 1 full, 64 and 48 query
+    # heads over 8 key-value heads, a softmax router
+    # (``benchmarks/configs/laguna_xs2.json``, held equal by a test)
+    "laguna_xs2": dict(
+        dim=2048, heads=48, nope=64, rope=64, v_dim=128, kv_rank=0,
+        layers=5, first_dense=1, dense_width=8192, n_routed=256, top_k=8,
+        expert_width=512, n_shared=1, routed_scaling=2.5, rope_theta=5e5,
+        eps=1e-6, held_first=0, held_count=32, seq_len=4096,
+        mixers=["full", "window", "window", "window", "full"],
+        out_gate=True, kv_heads=8, window_heads=64, window=512,
+        window_rope_theta=1e4, yarn_factor=64.0, yarn_original=4096,
+        yarn_beta_fast=64.0, yarn_beta_slow=1.0,
+        yarn_attention_factor=1.4158883083359672, score="softmax"),
+    "laguna_tiny": dict(
+        dim=32, heads=6, nope=8, rope=8, v_dim=16, kv_rank=0, layers=3,
+        first_dense=1, dense_width=96, n_routed=16, top_k=4, expert_width=24,
+        n_shared=1, routed_scaling=2.5, rope_theta=5e5, eps=1e-6,
+        held_first=0, held_count=4, seq_len=32,
+        mixers=["full", "window", "full"],
+        out_gate=True, kv_heads=2, window_heads=8, window=8,
+        window_rope_theta=1e4, yarn_factor=4.0, yarn_original=8,
+        yarn_beta_fast=4.0, yarn_beta_slow=1.0,
+        yarn_attention_factor=1.1386294361119891, score="softmax"),
     "ling3_tiny": dict(
         dim=32, heads=2, nope=16, rope=8, v_dim=16, kv_rank=16, layers=4,
         first_dense=1, dense_width=96, n_routed=16, top_k=4, expert_width=24,
@@ -595,6 +671,16 @@ def _kanana2(output_dim: int = 16032, **kw):
 @register_model("ling3_flash_vl")
 def _ling3(output_dim: int = 19648, **kw):
     return _latent_moe_bundle("ling3_flash_vl", output_dim or 19648, **kw)
+
+
+@register_model("laguna_xs2")
+def _laguna(output_dim: int = 12544, **kw):
+    return _latent_moe_bundle("laguna_xs2", output_dim or 12544, **kw)
+
+
+@register_model("laguna_tiny")
+def _laguna_tiny(output_dim: int = 64, **kw):
+    return _latent_moe_bundle("laguna_tiny", output_dim or 64, **kw)
 
 
 @register_model("ling3_tiny")

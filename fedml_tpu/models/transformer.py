@@ -15,14 +15,17 @@ algorithm can train it like any other zoo model.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from fedml_tpu.models import ModelBundle, register_model
-from fedml_tpu.obs.tracer import (SCOPE_LM_ATTN, SCOPE_LM_DENSE, SCOPE_LM_KDA,
+from fedml_tpu.obs.tracer import (SCOPE_LM_ATTN, SCOPE_LM_ATTN_WINDOW,
+                                  SCOPE_LM_DENSE, SCOPE_LM_KDA,
                                   SCOPE_LM_KDA_PREP)
 from fedml_tpu.ops.attention import attention
 from fedml_tpu.ops.kda import kda_chunked
@@ -210,17 +213,48 @@ class SwiGLU(nn.Module):
         return Linear(x.shape[-1], self.dtype, name="down")(nn.silu(g) * u)
 
 
-def rotary(x: jax.Array, theta: float) -> jax.Array:
+def yarn_frequencies(r: int, theta: float, factor: float, original: int,
+                     beta_fast: float, beta_slow: float) -> np.ndarray:
+    """The ``r / 2`` pair frequencies of a rotary width ``r`` under YaRN
+    (arXiv:2309.00071), as ``transformers``' ``_compute_yarn_parameters``
+    computes them: ``f_i = theta^(-2i/r)``; a pair that turns more than
+    ``beta_fast`` times over the ``original`` positions keeps ``f_i``, one
+    that turns less than ``beta_slow`` times takes ``f_i / factor``, and
+    between the two pair indices (floor and ceiling of ``r ln(original / (2
+    pi beta)) / (2 ln theta)``) the two are blended linearly. float64 on the
+    host: the numbers are the model's constants."""
+    f = theta ** (-np.arange(0, r, 2, dtype=np.float64) / r)
+
+    def pair(turns):
+        return r * math.log(original / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), r - 1)
+    ramp = np.clip((np.arange(r // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return f * (1 - ramp) + f / factor * ramp
+
+
+def rotary(x: jax.Array, theta: float, inv_freq=None,
+           scale: float = 1.0) -> jax.Array:
     """Rotary embedding over the last axis of ``x [..., T, R]``, INTERLEAVED
-    pairs: channels ``(2i, 2i+1)`` turn by ``pos * theta^(-2i/R)``. (The
-    published ``rope_interleave`` code first moves the even channels to the
-    front half and then turns halves; applied to queries and keys alike that
-    is this rotation under one fixed permutation of the channels, and every
-    ``q . k`` is the same.) Computed in float32, returned in ``x.dtype``."""
+    pairs: channels ``(2i, 2i+1)`` turn by ``pos * theta^(-2i/R)``, or by
+    ``pos * inv_freq[i]`` where the pairs' frequencies are given
+    (:func:`yarn_frequencies`); cosine and sine times ``scale`` (YaRN's
+    attention factor). (The published ``rope_interleave`` code first moves
+    the even channels to the front half and then turns halves; applied to
+    queries and keys alike that is this rotation under one fixed permutation
+    of the channels, and every ``q . k`` is the same.) Computed in float32,
+    returned in ``x.dtype``."""
     t, r = x.shape[-2], x.shape[-1]
-    inv = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    if inv_freq is None:
+        inv = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
@@ -232,8 +266,10 @@ def rotary(x: jax.Array, theta: float) -> jax.Array:
 #: at the op's default of 128, 25.3 at 512, 20.6 at 1024 (PERF.md, PR 26);
 #: 2048 does not fit the kernels' 16 MB of VMEM; shorter sequences clamp it.
 #: Inside a tile the kernels compute in 256-wide sub-tiles of their own
-#: choosing (``ops/attention.py``; PERF.md, PR 27: 19.46 -> 17.83 ms)
-_LATENT_ATTN_BLOCK = 1024
+#: choosing (``ops/attention.py``; PERF.md, PR 27: 19.46 -> 17.83 ms).
+#: A window layer takes the same tile: under a band of 512 at T 4,096 a
+#: layer-step took 16.83 ms at 1024 and 19.37 at 512 (PERF.md, PR 32)
+_ATTN_BLOCK = 1024
 
 
 class LatentAttention(nn.Module):
@@ -292,8 +328,8 @@ class LatentAttention(nn.Module):
                 [kv[..., :dn], jnp.broadcast_to(k_r, (b, h, t, dr))], axis=-1)
         with jax.named_scope(SCOPE_LM_ATTN):
             o = attention(q, k, kv[..., dn:], causal=True,
-                          block_q=_LATENT_ATTN_BLOCK,
-                          block_k=_LATENT_ATTN_BLOCK)
+                          block_q=_ATTN_BLOCK,
+                          block_k=_ATTN_BLOCK)
         o = o.transpose(0, 2, 1, 3)
         if self.out_gate:
             o = HeadGate(self.eps, self.dtype, name="out_gate")(o, x)
@@ -303,19 +339,74 @@ class LatentAttention(nn.Module):
 
 
 class HeadGate(nn.Module):
-    """``o [B, T, H, dv]`` RMS-normalised a head (one scale over ``dv``) and
-    multiplied by one sigmoid gate a head, ``sigmoid(W_g x)`` with ``W_g
-    [D, H]``."""
+    """``o [B, T, H, dv]`` RMS-normalised a head (one scale over ``dv``;
+    not where ``norm`` is False) and multiplied by one sigmoid gate a head,
+    ``sigmoid(W_g x)`` with ``W_g [D, H]``."""
 
     eps: float = 1e-6
     dtype: Any = jnp.float32
+    norm: bool = True
 
     @nn.compact
     def __call__(self, o, x):
         with jax.named_scope(SCOPE_LM_DENSE):
             gate = Linear(o.shape[-2], self.dtype, jnp.float32, name="proj")(x)
-        o = RMSNorm(self.eps, jnp.float32, name="norm")(o)
+        if self.norm:
+            o = RMSNorm(self.eps, jnp.float32, name="norm")(o)
         return (o * jax.nn.sigmoid(gate)[..., None]).astype(self.dtype)
+
+
+class GroupedAttention(nn.Module):
+    """Grouped-query attention, full or under a sliding window. ``q = W_q
+    x`` as ``heads`` heads of ``head_dim``, ``k`` and ``v`` as ``kv_heads``;
+    query head ``i`` reads key-value head ``i // (heads / kv_heads)``. The
+    first ``rotary_dim`` channels of every head of ``q`` and ``k`` turn
+    (:func:`rotary`: by ``rope_theta``, or by ``inv_freq`` and ``scale``
+    where YaRN gives them), the others pass. Causal softmax of ``q . k /
+    sqrt(head_dim)`` over the ``window`` keys up to the query's own
+    (``None``: all of them). ``gate``: each head's output times ``sigmoid(x
+    W_g)``, one gate a head and no norm, before ``W_o``. No biases."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rotary_dim: int
+    rope_theta: float = 10000.0
+    inv_freq: Optional[tuple] = None
+    rope_scale: float = 1.0
+    window: Optional[int] = None
+    gate: bool = False
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, dim = x.shape
+        h, g, d, r = self.heads, self.kv_heads, self.head_dim, self.rotary_dim
+        with jax.named_scope(SCOPE_LM_DENSE):
+            q = Linear(h * d, self.dtype, name="q_proj")(x)
+            k = Linear(g * d, self.dtype, name="k_proj")(x)
+            v = Linear(g * d, self.dtype, name="v_proj")(x)
+
+        def heads(a, n):
+            return a.reshape(b, t, n, d).transpose(0, 2, 1, 3)
+
+        def turned(a):
+            first = rotary(a[..., :r], self.rope_theta, self.inv_freq,
+                           self.rope_scale)
+            return first if r == d else jnp.concatenate(
+                [first, a[..., r:]], axis=-1)
+
+        q, k, v = turned(heads(q, h)), turned(heads(k, g)), heads(v, g)
+        with jax.named_scope(SCOPE_LM_ATTN if self.window is None
+                             else SCOPE_LM_ATTN_WINDOW):
+            o = attention(q, k, v, causal=True, window=self.window,
+                          block_q=_ATTN_BLOCK, block_k=_ATTN_BLOCK)
+        o = o.transpose(0, 2, 1, 3)
+        if self.gate:
+            o = HeadGate(dtype=self.dtype, norm=False, name="out_gate")(o, x)
+        with jax.named_scope(SCOPE_LM_DENSE):
+            return Linear(dim, self.dtype, name="o_proj")(
+                o.reshape(b, t, h * d))
 
 
 def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:

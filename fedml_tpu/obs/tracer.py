@@ -92,6 +92,9 @@ SCOPE_STEP_EMIT = "fedml.step.emit"
 #: of them (norms, rotary, residual adds, the embedding) stays step.train's.
 #: attention proper: scores, softmax, values (ops/attention.py's kernels)
 SCOPE_LM_ATTN = "fedml.lm.attn"
+#: the same in a layer whose queries see a window of keys: the band's work,
+#: apart from the full layers' (the kernels inside keep their names)
+SCOPE_LM_ATTN_WINDOW = "fedml.lm.attn_window"
 #: the delta rule's chunked scan (ops/kda.py): intra-chunk products, the
 #: triangular solve, the state's recurrence, the output; its backward too
 SCOPE_LM_KDA = "fedml.lm.kda"

@@ -9,12 +9,27 @@ output + running rowmax/rowsum) is exactly what ring attention over an 'sp'
 mesh axis needs to merge chunks arriving over ICI
 (:mod:`fedml_tpu.parallel.sequence`).
 
-Shapes: ``q, k`` are ``[B, H, Tq, D]`` / ``[B, H, Tk, D]``, ``v`` is
-``[B, H, Tk, Dv]`` with a value head size of its own (latent attention has
+Shapes: ``q`` is ``[B, H, Tq, D]``, ``k`` ``[B, G, Tk, D]`` and ``v`` ``[B,
+G, Tk, Dv]`` with a value head size of its own (latent attention has
 192-wide queries and keys and 128-wide values); the output is ``[B, H, Tq,
-Dv]``. Causal masking uses GLOBAL positions ``q_offset + i >= k_offset + j``
-so the same code serves single-device attention (offsets 0) and ring steps
-(offsets are shard starts, traced scalars).
+Dv]``. ``H / G`` consecutive query heads read one key-value head (grouped
+queries; ``G == H`` is every head its own): the kernels' index maps name the
+shared head, nothing is repeated in HBM, and the dk/dv kernel sums a
+key-value head's gradient over its group in VMEM. Causal masking uses GLOBAL
+positions ``q_offset + i >= k_offset + j`` so the same code serves
+single-device attention (offsets 0) and ring steps (offsets are shard
+starts, traced scalars).
+
+``window``: a query sees the ``window`` keys up to and including its own
+(``0 <= i - j < window``), in :func:`attention` alone (the partial form
+refuses one). The band is narrow, so everything about it is static: the few
+offsets at which a tile meets the band, each with its spans
+(:func:`_band_spans`: a sub-tile outside the band on EITHER side is in no
+span, a span masks the edges that cut it), and a sweep that starts at a
+block's first live tile and is as long as the most live tiles any block
+meets (:func:`_band_sweep`: 2 steps a block for a band of 512 in tiles of
+1024, where the causal sweep takes 4), so neither side's dead tiles are
+fetched or computed.
 
 A query / key size over 128 that is not a multiple of the 128 lanes is
 zero-PADDED to the next multiple before the kernels (192 -> 256): the MXU
@@ -44,6 +59,7 @@ chunks).
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -82,6 +98,26 @@ def _xla_block_partial(q, k, v, q_offset, k_offset, causal, sm_scale):
     return o, m, l
 
 
+def _xla_attention(q, k, v, causal, window, sm_scale):
+    """Full attention under a window and / or with fewer key-value heads
+    than query heads, plainly: heads repeated by index, the masked softmax
+    of the whole score matrix, JAX's own backward."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    if causal:
+        behind = jnp.arange(q.shape[2])[:, None] - jnp.arange(k.shape[2])
+        keep = behind >= 0
+        if window is not None:
+            keep &= behind < window
+        s = jnp.where(keep, s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
+    return o.astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Pallas path
 # ---------------------------------------------------------------------------
@@ -110,12 +146,14 @@ class _Tiling(NamedTuple):
     block_k: int
     sub_q: int
     sub_k: int
+    #: keys a query sees, itself included (``0 <= q - k < window``); None: all
+    window: Optional[int] = None
 
 
-def _tiling(causal, tq, tk, block_q, block_k) -> _Tiling:
+def _tiling(causal, tq, tk, block_q, block_k, window=None) -> _Tiling:
     bq, bk = _fit_block(block_q, tq), _fit_block(block_k, tk)
     return _Tiling(causal, bq, bk, _fit_block(_SUB_Q, bq),
-                   _fit_block(_SUB_K, bk))
+                   _fit_block(_SUB_K, bk), window)
 
 
 def _causal_ranges(d, sub_q, sub_k, n, over_queries=False):
@@ -146,6 +184,79 @@ def _causal_ranges(d, sub_q, sub_k, n, over_queries=False):
     return (0, n_plain), (n_plain, blocks(d + sub_q - 1 + sub_k))
 
 
+def _band_ranges(d, sub_q, sub_k, n, window, over_queries=False):
+    """:func:`_causal_ranges` under a window, ``0 <= q - k < window`` kept:
+    the same sweep of ``n`` blocks -> ``(live, plain)``, half-open index
+    ranges. ``live`` blocks hold an unmasked element: the sweep is dead
+    before them (behind the window) and after them (ahead of the queries),
+    and neither side is fetched or computed. ``plain`` blocks lie wholly
+    inside the band (possibly none: ``plain[0] >= plain[1]``); a live block
+    before ``plain[0]`` is cut by the window's far edge and one from
+    ``plain[1]`` on by the diagonal (``over_queries``: the diagonal cuts the
+    blocks before ``plain[0]``, the far edge those from ``plain[1]`` on)."""
+    if over_queries:
+        live, plain = _band_ranges(n * sub_q - sub_k + d, sub_k, sub_q, n,
+                                   window)
+        return (n - live[1], n - live[0]), (n - plain[1], n - plain[0])
+
+    def blocks(x):
+        if isinstance(x, int):
+            return min(max(x, 0), n * sub_k) // sub_k
+        return jax.lax.div(jnp.clip(x, 0, n * sub_k), sub_k)
+
+    return ((blocks(d + 1 - window), blocks(d + sub_q - 1 + sub_k)),
+            (blocks(d + sub_q - 1 - window + sub_k), blocks(d + 1)))
+
+
+#: what a span masks: the diagonal (a key ahead of its query), the window's
+#: far edge (a key ``window`` or more behind it), or both. ``True`` is the
+#: diagonal alone, which is all a call without a window knows.
+_MASK_DIAGONAL, _MASK_EDGE = 1, 2
+
+
+def _band_spans(d: int, tiling: _Tiling, over_queries=False):
+    """The spans ``[(rows, keys, mask), ...]`` of ONE tile under a window,
+    for a static ``d``: each block of ``sub_q`` queries takes its live
+    ``sub_k`` blocks, first to last, in one span (``over_queries``: each
+    block of keys its live blocks of queries), masked by the edges that cut
+    it; a tile wholly inside the band is one span without a mask, a tile
+    outside it has none."""
+    _, block_q, block_k, sub_q, sub_k, window = tiling
+    nsq, nsk = block_q // sub_q, block_k // sub_k
+    out = []
+    for i in range(nsk if over_queries else nsq):
+        if over_queries:
+            keys = slice(i * sub_k, (i + 1) * sub_k)
+            (lo, hi), (diag, edge) = _band_ranges(
+                d - keys.start, sub_q, sub_k, nsq, window, over_queries=True)
+            rows = slice(lo * sub_q, hi * sub_q)
+            mask = (lo < diag) * _MASK_DIAGONAL + (hi > edge) * _MASK_EDGE
+        else:
+            rows = slice(i * sub_q, (i + 1) * sub_q)
+            (lo, hi), (edge, diag) = _band_ranges(
+                d + rows.start, sub_q, sub_k, nsk, window)
+            keys = slice(lo * sub_k, hi * sub_k)
+            mask = (hi > diag) * _MASK_DIAGONAL + (lo < edge) * _MASK_EDGE
+        if lo < hi:
+            out.append((rows, keys, mask))
+    whole = slice(0, block_q), slice(0, block_k)
+    swept = nsk if over_queries else nsq
+    if len(out) == swept and all(
+            mask == 0 and (rows if over_queries else keys)
+            == whole[not over_queries] for rows, keys, mask in out):
+        return [(*whole, 0)]
+    return out
+
+
+def _band_offsets(tiling: _Tiling):
+    """Every ``d`` (first query less first key) at which a tile of a call
+    WITHOUT offsets holds a live element under the window: multiples of the
+    tiles' common divisor, so each is static and so are its spans."""
+    step = math.gcd(tiling.block_q, tiling.block_k)
+    first = -((tiling.block_q - 1) // step) * step
+    return range(first, tiling.window + tiling.block_k - 1, step)
+
+
 def _tile_spans(d, tiling: _Tiling, over_queries=False):
     """What a kernel computes of ONE ``block_q x block_k`` tile whose first
     query lies ``d`` after its first key -> ``[(when, [(rows, keys, masked),
@@ -163,10 +274,16 @@ def _tile_spans(d, tiling: _Tiling, over_queries=False):
     spans of ``d == 0``, known here, win as one group: that is the tile on
     the diagonal of every call without offsets or with offsets a multiple
     of the tile; any other crossed tile takes its spans one by one."""
-    causal, block_q, block_k, sub_q, sub_k = tiling
+    causal, block_q, block_k, sub_q, sub_k, window = tiling
     whole = slice(0, block_q), slice(0, block_k)
     if not causal:
         return [(True, [(*whole, False)])]
+    if window is not None:
+        # a band is narrow: the few offsets at which a tile meets it are
+        # known here, each with static spans under one condition
+        offsets = [d] if isinstance(d, int) else _band_offsets(tiling)
+        return [(d == at, spans) for at in offsets
+                if (spans := _band_spans(at, tiling, over_queries))]
     (_, plain), (_, live) = _causal_ranges(d, block_q, block_k, 1)
     crossed = live - plain == 1
     groups = [(plain == 1, [(*whole, False)])]
@@ -215,17 +332,53 @@ def _first_live_query_block(qb, d, block_q, block_k, nq):
     return jnp.maximum(qb, jnp.minimum(live, nq - 1))
 
 
+def _band_sweep(tiling: _Tiling, n_mine: int, n_swept: int,
+                over_queries=False) -> int:
+    """Grid steps of one sweep under a window (a call without offsets): the
+    most live blocks any of the ``n_mine`` blocks that stay put meets among
+    the ``n_swept`` that stream past. The sweep starts at a block's first
+    live one (:func:`_band_block`), so a band of 512 at T 4,096 in tiles of
+    1024 takes 2 steps a block, not 4."""
+    _, bq, bk, _, _, window = tiling
+    spans = [_band_ranges(-i * bk if over_queries else i * bq, bq, bk,
+                          n_swept, window, over_queries)[0]
+             for i in range(n_mine)]
+    return max(1, max(hi - lo for lo, hi in spans))
+
+
+#: the block of a dead sweep step: 2**20 blocks off (times a tile of 1024
+#: still an int32), far ahead of the queries as a key block and far behind
+#: the window as a query block
+_NO_BLOCK = 1 << 20
+
+
+def _band_block(step, d, tiling: _Tiling, n, over_queries=False):
+    """``(block, index)`` of sweep step ``step`` under a window: the block
+    the step stands for, counted from the sweep's first live one, and the
+    block its index map names: the same, held to the live range, so that a
+    dead step names a block that is in VMEM already. A step past the last
+    live block stands for :data:`_NO_BLOCK`, whose offset no span has (a
+    query block past the sequence's end would lie inside the band)."""
+    _, bq, bk, _, _, window = tiling
+    (lo, hi), _ = _band_ranges(d, bq, bk, n, window, over_queries)
+    block = lo + step
+    return (jnp.where(block < hi, block, _NO_BLOCK),
+            jnp.minimum(block, jnp.maximum(hi - 1, 0)))
+
+
 def executed_score_share(tq: int, tk: int, block_q: int = 128,
                          block_k: int = 128, sub_q: int = _SUB_Q,
                          sub_k: int = _SUB_K, causal: bool = True,
-                         q_offset: int = 0, k_offset: int = 0) -> float:
+                         q_offset: int = 0, k_offset: int = 0,
+                         window: Optional[int] = None) -> float:
     """Share of the ``tq x tk`` score area the kernels compute, summed over
     their own spans (the causal mask itself needs just over a half): 0.625
     at T 4,096 with 1024-wide tiles computed whole, 0.53125 in 256-wide
-    sub-tiles."""
+    sub-tiles; under a window of 512, 0.17578 (the band itself is 0.11720
+    of the area)."""
     bq, bk = _fit_block(block_q, tq), _fit_block(block_k, tk)
     tiling = _Tiling(causal, bq, bk, _fit_block(sub_q, bq),
-                     _fit_block(sub_k, bk))
+                     _fit_block(sub_k, bk), window)
     area = 0
     for qb in range(tq // bq):
         for kb in range(tk // bk):
@@ -247,16 +400,21 @@ def _pad_qk(q, k):
     return jnp.pad(q, pad), jnp.pad(k, pad)
 
 
-def _scores(q, kblk, q_start, k_start, *, masked, sm_scale):
-    """float32 scores of one span, NEG_INF where ``masked`` and the key lies
-    ahead of the query."""
+def _scores(q, kblk, q_start, k_start, *, masked, sm_scale, window=None):
+    """float32 scores of one span, NEG_INF where ``masked`` says so: the key
+    lies ahead of the query (``_MASK_DIAGONAL``, which ``True`` is), or
+    ``window`` or more behind it (``_MASK_EDGE``)."""
     s = jax.lax.dot_general(
         q, kblk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * sm_scale
     if masked:
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
+        keep = qpos >= kpos if masked & _MASK_DIAGONAL else None
+        if masked & _MASK_EDGE:
+            near = qpos - kpos < window
+            keep = near if keep is None else keep & near
+        s = jnp.where(keep, s, NEG_INF)
     return s
 
 
@@ -273,33 +431,37 @@ def _run_spans(update, d, tiling, over_queries=False):
 
 def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref,
                   o_ref, m_ref, l_ref, m_s, l_s, acc_s, *,
-                  sm_scale: float, nk: int, tiling: _Tiling):
-    """Grid point = (batch*heads, q_block, k_block) with the k dimension
+                  sm_scale: float, nk: int, sweep: int, tiling: _Tiling):
+    """Grid point = (batch*heads, q_block, sweep step) with the sweep
     'arbitrary' (sequential): running rowmax/rowsum/accumulator live in
     VMEM scratch across the k sweep, so VMEM holds only one (bq, d) query
     tile and one (bk, d) K/V tile at a time — sequence length is bounded
     by HBM, not by VMEM (the previous full-K/V-resident block spec OOMed
     scoped vmem at T=8192). A tile wholly above the causal diagonal is in
-    no span: scratch carries through unchanged."""
+    no span: scratch carries through unchanged. Step ``i`` of the sweep is
+    key block ``i`` of ``nk``; under a window, the ``i``-th from the query
+    block's first live one (``sweep`` steps hold every live block)."""
     import jax.experimental.pallas as pl
 
     qb = pl.program_id(1)
-    kb = pl.program_id(2)
+    step = kb = pl.program_id(2)
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
     q_start = qoff_ref[0] + qb * tiling.block_q
+    if tiling.window is not None:
+        kb, _ = _band_block(step, q_start - koff_ref[0], tiling, nk)
     k_start = koff_ref[0] + kb * tiling.block_k
 
     def update(rows, keys, masked):
         vblk = v_ref[0, keys, :]
         s = _scores(q_ref[0, rows, :], k_ref[0, keys, :],
                     q_start + rows.start, k_start + keys.start,
-                    masked=masked, sm_scale=sm_scale)
+                    masked=masked, sm_scale=sm_scale, window=tiling.window)
         m_prev = m_s[rows, :1]                                # [rows, 1]
         l_prev = l_s[rows, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -318,7 +480,7 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref,
 
     _run_spans(update, q_start - k_start, tiling)
 
-    @pl.when(kb == nk - 1)
+    @pl.when(step == sweep - 1)
     def _emit():
         o_ref[0] = acc_s[...]
         # m/l are row-broadcast across the 128-lane dim of their outputs
@@ -352,32 +514,38 @@ def _vmem_spec(block, index_map):
 @functools.partial(jax.jit, static_argnames=("tiling", "sm_scale", "interpret"))
 def _flash_fwd(qoff, koff, q, k, v, *, tiling: _Tiling, sm_scale: float,
                interpret: bool):
-    """``q, k [BH, T, D]``, ``v [BH, Tk, Dv]``, offsets ``int32[1]`` ->
-    float32 ``(o [BH, Tq, Dv], m, l [BH, Tq, 128])``."""
+    """``q [BH, T, D]``, ``k [BG, Tk, D]``, ``v [BG, Tk, Dv]`` (``H / G``
+    consecutive query heads read one key-value head), offsets ``int32[1]``
+    -> float32 ``(o [BH, Tq, Dv], m, l [BH, Tq, 128])``. Under a window the
+    offsets are 0 (the ring form refuses one)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     (bh, tq, d), tk, dv = q.shape, k.shape[1], v.shape[2]
     causal, bq, bk = tiling[:3]
-    nk = tk // bk
+    nk, group = tk // bk, bh // k.shape[0]
+    sweep = (nk if tiling.window is None
+             else _band_sweep(tiling, tq // bq, nk))
 
     def q_of(bh, qb, kb, qoff, koff):
         return bh, qb, 0
 
     def k_of(bh, qb, kb, qoff, koff):
-        if causal:
+        if tiling.window is not None:
+            _, kb = _band_block(kb, qoff[0] + qb * bq - koff[0], tiling, nk)
+        elif causal:
             kb = _last_live_key_block(kb, qoff[0] + qb * bq - koff[0],
                                       bq, bk, nk)
-        return bh, kb, 0
+        return (bh // group if group > 1 else bh), kb, 0
 
     return pl.pallas_call(
         functools.partial(_flash_kernel, sm_scale=sm_scale, nk=nk,
-                          tiling=tiling),
+                          sweep=sweep, tiling=tiling),
         # the offsets are prefetched scalars, so that K/V's index map sees
         # them too (ring steps pass traced shard starts)
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(bh, tq // bq, nk),
+            grid=(bh, tq // bq, sweep),
             in_specs=[_vmem_spec((1, bq, d), q_of),
                       _vmem_spec((1, bk, d), k_of),
                       _vmem_spec((1, bk, dv), k_of)],
@@ -399,17 +567,18 @@ def _flash_fwd(qoff, koff, q, k, v, *, tiling: _Tiling, sm_scale: float,
 
 
 def _pallas_block_partial(q, k, v, q_offset, k_offset, causal, sm_scale,
-                          block_q: int, block_k: int, interpret: bool):
+                          block_q: int, block_k: int, interpret: bool,
+                          window=None):
     q, k = _pad_qk(q, k)
     b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[3]
+    g, tk, dv = k.shape[1], k.shape[2], v.shape[3]
     o, m, l = _flash_fwd(
         jnp.asarray(q_offset, jnp.int32).reshape(1),
         jnp.asarray(k_offset, jnp.int32).reshape(1),
-        q.reshape(b * h, tq, d), k.reshape(b * h, tk, d),
-        v.reshape(b * h, tk, dv),
-        tiling=_tiling(causal, tq, tk, block_q, block_k), sm_scale=sm_scale,
-        interpret=interpret)
+        q.reshape(b * h, tq, d), k.reshape(b * g, tk, d),
+        v.reshape(b * g, tk, dv),
+        tiling=_tiling(causal, tq, tk, block_q, block_k, window),
+        sm_scale=sm_scale, interpret=interpret)
     return (o.reshape(b, h, tq, dv),
             m[..., 0].reshape(b, h, tq),
             l[..., 0].reshape(b, h, tq))
@@ -421,9 +590,10 @@ def _pallas_block_partial(q, k, v, q_offset, k_offset, causal, sm_scale,
 # ---------------------------------------------------------------------------
 
 def _bwd_span(q, kblk, vblk, do, lse, delta, q_start, k_start, *,
-              masked, sm_scale):
+              masked, sm_scale, window=None):
     """-> (p, ds) of one span, float32."""
-    s = _scores(q, kblk, q_start, k_start, masked=masked, sm_scale=sm_scale)
+    s = _scores(q, kblk, q_start, k_start, masked=masked, sm_scale=sm_scale,
+                window=window)
     p = jnp.exp(s - lse)                       # masked: exp(-1e30) == 0
     dp = jax.lax.dot_general(
         do, vblk, (((1,), (1,)), ((), ())),
@@ -431,20 +601,36 @@ def _bwd_span(q, kblk, vblk, do, lse, delta, q_start, k_start, *,
     return p, p * (dp - delta) * sm_scale
 
 
+def _dkv_step(step, sweep: int, group: int):
+    """Step ``step`` of the dk/dv kernel's sweep -> ``(head, i)``: which of
+    the key-value head's ``group`` query heads, and the step of that head's
+    own sweep over the query blocks (``sweep`` steps a head, one head after
+    another)."""
+    if group == 1:
+        return 0, step
+    return jax.lax.div(step, sweep), jax.lax.rem(step, sweep)
+
+
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dk_s, dv_s, *, sm_scale, nq, tiling):
-    """Grid (batch*heads, k_block, q_block), the q sweep sequential: one
-    K/V tile stays put while the query tiles stream past it."""
+                      dk_ref, dv_ref, dk_s, dv_s, *, sm_scale, nq, sweep,
+                      group, tiling):
+    """Grid (batch*kv heads, k_block, sweep step), the sweep sequential: one
+    K/V tile stays put while the query tiles of each of its ``group`` query
+    heads stream past it, and their sum is its gradient."""
     import jax.experimental.pallas as pl
 
     kb = pl.program_id(1)
-    qb = pl.program_id(2)
+    step = pl.program_id(2)
 
-    @pl.when(qb == 0)
+    @pl.when(step == 0)
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
+    _, qb = _dkv_step(step, sweep, group)
+    if tiling.window is not None:
+        qb, _ = _band_block(qb, -kb * tiling.block_k, tiling, nq,
+                            over_queries=True)
     q_start, k_start = qb * tiling.block_q, kb * tiling.block_k
 
     def update(rows, keys, masked):
@@ -453,7 +639,8 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           lse_ref[0, rows, :][:, :1],
                           delta_ref[0, rows, :][:, :1],
                           q_start + rows.start, k_start + keys.start,
-                          masked=masked, sm_scale=sm_scale)
+                          masked=masked, sm_scale=sm_scale,
+                          window=tiling.window)
         dv_s[keys, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -463,24 +650,27 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     _run_spans(update, q_start - k_start, tiling, over_queries=True)
 
-    @pl.when(qb == nq - 1)
+    @pl.when(step == group * sweep - 1)
     def _emit():
         dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, dq_s, *, sm_scale, nk, tiling):
-    """Grid (batch*heads, q_block, k_block), the k sweep sequential."""
+                     dq_ref, dq_s, *, sm_scale, nk, sweep, tiling):
+    """Grid (batch*heads, q_block, sweep step), the k sweep sequential (the
+    forward kernel's sweep)."""
     import jax.experimental.pallas as pl
 
     qb = pl.program_id(1)
-    kb = pl.program_id(2)
+    step = kb = pl.program_id(2)
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
 
+    if tiling.window is not None:
+        kb, _ = _band_block(step, qb * tiling.block_q, tiling, nk)
     q_start, k_start = qb * tiling.block_q, kb * tiling.block_k
 
     def update(rows, keys, masked):
@@ -489,14 +679,15 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           do_ref[0, rows, :], lse_ref[0, rows, :][:, :1],
                           delta_ref[0, rows, :][:, :1],
                           q_start + rows.start, k_start + keys.start,
-                          masked=masked, sm_scale=sm_scale)
+                          masked=masked, sm_scale=sm_scale,
+                          window=tiling.window)
         dq_s[rows, :] += jax.lax.dot_general(
             ds.astype(kblk.dtype), kblk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     _run_spans(update, q_start - k_start, tiling)
 
-    @pl.when(kb == nk - 1)
+    @pl.when(step == sweep - 1)
     def _emit():
         dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
 
@@ -511,31 +702,38 @@ def _bwd_in_specs(tiling: _Tiling, d, dv, q_of, k_of):
 @functools.partial(jax.jit, static_argnames=("tiling", "sm_scale", "interpret"))
 def _flash_dkv(q, k, v, do, lse, delta, *, tiling: _Tiling, sm_scale: float,
                interpret: bool):
-    """``[BH, T, .]`` operands, ``lse`` / ``delta`` row-broadcast over 128
-    lanes -> ``(dk, dv)`` in the dtypes of ``k``, ``v``."""
+    """``q, do [BH, T, .]``, ``k, v [BG, Tk, .]``, ``lse`` / ``delta``
+    row-broadcast over 128 lanes -> ``(dk, dv)`` in the shapes and dtypes of
+    ``k``, ``v``: a key-value head's gradient is summed over its ``H / G``
+    query heads inside the kernel."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (bh, tq, d), tk, dv = q.shape, k.shape[1], v.shape[2]
-    causal, bq, bk = tiling[:3]
-    nq = tq // bq
+    (bh, tq, d), (bg, tk, _), dv = q.shape, k.shape, v.shape[2]
+    bq, bk = tiling.block_q, tiling.block_k
+    nq, group = tq // bq, bh // bg
+    sweep = (nq if tiling.window is None
+             else _band_sweep(tiling, tk // bk, nq, over_queries=True))
 
-    def q_of(bh, kb, qb):
-        if causal:
+    def q_of(bg, kb, step):
+        head, qb = _dkv_step(step, sweep, group)
+        if tiling.window is not None:
+            _, qb = _band_block(qb, -kb * bk, tiling, nq, over_queries=True)
+        elif tiling.causal:
             qb = _first_live_query_block(qb, -kb * bk, bq, bk, nq)
-        return bh, qb, 0
+        return (bg * group + head if group > 1 else bg), qb, 0
 
-    def k_of(bh, kb, qb):
-        return bh, kb, 0
+    def k_of(bg, kb, step):
+        return bg, kb, 0
 
     return pl.pallas_call(
         functools.partial(_flash_dkv_kernel, sm_scale=sm_scale, nq=nq,
-                          tiling=tiling),
-        grid=(bh, tk // bk, nq),
+                          sweep=sweep, group=group, tiling=tiling),
+        grid=(bg, tk // bk, group * sweep),
         in_specs=_bwd_in_specs(tiling, d, dv, q_of, k_of),
         out_specs=[_vmem_spec((1, bk, d), k_of), _vmem_spec((1, bk, dv), k_of)],
-        out_shape=[jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, tk, dv), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((bg, tk, d), k.dtype),
+                   jax.ShapeDtypeStruct((bg, tk, dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, dv), jnp.float32)],
         compiler_params=_sweep_last(), interpret=interpret,
@@ -551,20 +749,24 @@ def _flash_dq(q, k, v, do, lse, delta, *, tiling: _Tiling, sm_scale: float,
 
     (bh, tq, d), tk, dv = q.shape, k.shape[1], v.shape[2]
     causal, bq, bk = tiling[:3]
-    nk = tk // bk
+    nk, group = tk // bk, bh // k.shape[0]
+    sweep = (nk if tiling.window is None
+             else _band_sweep(tiling, tq // bq, nk))
 
     def q_of(bh, qb, kb):
         return bh, qb, 0
 
     def k_of(bh, qb, kb):
-        if causal:
+        if tiling.window is not None:
+            _, kb = _band_block(kb, qb * bq, tiling, nk)
+        elif causal:
             kb = _last_live_key_block(kb, qb * bq, bq, bk, nk)
-        return bh, kb, 0
+        return (bh // group if group > 1 else bh), kb, 0
 
     return pl.pallas_call(
         functools.partial(_flash_dq_kernel, sm_scale=sm_scale, nk=nk,
-                          tiling=tiling),
-        grid=(bh, tq // bq, nk),
+                          sweep=sweep, tiling=tiling),
+        grid=(bh, tq // bq, sweep),
         in_specs=_bwd_in_specs(tiling, d, dv, q_of, k_of),
         out_specs=[_vmem_spec((1, bq, d), q_of)],
         out_shape=[jax.ShapeDtypeStruct((bh, tq, d), q.dtype)],
@@ -574,35 +776,38 @@ def _flash_dq(q, k, v, do, lse, delta, *, tiling: _Tiling, sm_scale: float,
 
 
 def _pallas_flash_bwd(q, k, v, out, lse, do, causal, sm_scale,
-                      block_q: int, block_k: int, interpret: bool):
-    """q, k already padded. -> (dq, dk, dv) in the inputs' dtypes."""
+                      block_q: int, block_k: int, interpret: bool,
+                      window=None):
+    """q, k already padded. -> (dq, dk, dv) in the inputs' shapes and
+    dtypes."""
     b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[3]
+    g, tk, dv = k.shape[1], k.shape[2], v.shape[3]
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
 
     def lanes(a):           # [B,H,Tq] -> row-broadcast over the 128 lanes
         return jnp.broadcast_to(a.reshape(b * h, tq, 1), (b * h, tq, 128))
 
-    args = (q.reshape(b * h, tq, d), k.reshape(b * h, tk, d),
-            v.reshape(b * h, tk, dv), do.astype(q.dtype).reshape(b * h, tq, dv),
+    args = (q.reshape(b * h, tq, d), k.reshape(b * g, tk, d),
+            v.reshape(b * g, tk, dv), do.astype(q.dtype).reshape(b * h, tq, dv),
             lanes(lse), lanes(delta))
-    common = dict(tiling=_tiling(causal, tq, tk, block_q, block_k),
+    common = dict(tiling=_tiling(causal, tq, tk, block_q, block_k, window),
                   sm_scale=sm_scale, interpret=interpret)
     dk, dvv = _flash_dkv(*args, **common)
     dq = _flash_dq(*args, **common)
-    return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
-            dvv.reshape(b, h, tk, dv))
+    return (dq.reshape(b, h, tq, d), dk.reshape(b, g, tk, d),
+            dvv.reshape(b, g, tk, dv))
 
 
 @functools.lru_cache(maxsize=None)
 def _flash_with_vjp(causal: bool, sm_scale: float, block_q: int,
-                    block_k: int, interpret: bool):
+                    block_k: int, interpret: bool,
+                    window: Optional[int] = None):
     """Full attention (offsets 0) on the Pallas path, kernels both ways.
     Saved for the backward: q, k, v, the output and the log-sum-exp."""
 
     def run(q, k, v):
         o, m, l = _pallas_block_partial(q, k, v, 0, 0, causal, sm_scale,
-                                        block_q, block_k, interpret)
+                                        block_q, block_k, interpret, window)
         den = jnp.where(l == 0.0, 1.0, l)
         return (o / den[..., None]).astype(q.dtype), m + jnp.log(den)
 
@@ -619,7 +824,8 @@ def _flash_with_vjp(causal: bool, sm_scale: float, block_q: int,
         d = q.shape[-1]
         qp, kp = _pad_qk(q, k)
         dq, dk, dv = _pallas_flash_bwd(qp, kp, v, out, lse, do, causal,
-                                       sm_scale, block_q, block_k, interpret)
+                                       sm_scale, block_q, block_k, interpret,
+                                       window)
         return dq[..., :d], dk[..., :d], dv
 
     f.defvjp(fwd, bwd)
@@ -674,12 +880,18 @@ def attention_block_partial(
     q_offset=0, k_offset=0, causal: bool = True,
     sm_scale: Optional[float] = None, impl: str = "auto",
     block_q: int = 128, block_k: int = 128, interpret: bool = False,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Attention of a Q shard against one K/V chunk -> partial result
     ``(o_unnormalized, rowmax m, rowsum l)``, each fp32. Merge partials from
     several chunks with :func:`merge_partials`, finish with
     :func:`normalize_partial`. Differentiable (custom VJP, recompute-style
-    backward)."""
+    backward). The partial form knows no window and refuses one: a ring
+    step that ignored it would attend to the whole prefix."""
+    if window is not None:
+        raise NotImplementedError(
+            "attention_block_partial has no window: a ring over sequence "
+            "shards would have to skip the shards behind it")
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     impl = _pick_impl(impl)
@@ -710,17 +922,26 @@ def attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = True, sm_scale: Optional[float] = None,
     impl: str = "auto", block_q: int = 128, block_k: int = 128,
-    interpret: bool = False,
+    interpret: bool = False, window: Optional[int] = None,
 ) -> jax.Array:
-    """Full fused attention, ``q, k [B, H, T, D]``, ``v [B, H, T, Dv]`` ->
-    ``[B, H, T, Dv]`` (q.dtype). On the Pallas path forward and backward are
-    kernels (:func:`_flash_with_vjp`); the XLA path is the plain block math
-    and its autodiff."""
+    """Full fused attention, ``q [B, H, T, D]``, ``k [B, G, T, D]``, ``v [B,
+    G, T, Dv]`` -> ``[B, H, T, Dv]`` (q.dtype); ``H / G`` consecutive query
+    heads read one key-value head. ``window``: a query sees the ``window``
+    keys up to and including its own position (causal only). On the Pallas
+    path forward and backward are kernels (:func:`_flash_with_vjp`); the XLA
+    path is the plain block math and its autodiff."""
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
+    if q.shape[1] % k.shape[1] or v.shape[1] != k.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads over {k.shape[1]} key "
+                         f"and {v.shape[1]} value heads")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window is causal and at least 1 wide")
     if _pick_impl(impl) == "pallas":
         return _flash_with_vjp(causal, float(sm_scale), block_q, block_k,
-                               interpret)(q, k, v)
+                               interpret, window)(q, k, v)
+    if window is not None or q.shape[1] != k.shape[1]:
+        return _xla_attention(q, k, v, causal, window, sm_scale)
     o, m, l = attention_block_partial(
         q, k, v, causal=causal, sm_scale=sm_scale, impl=impl,
         block_q=block_q, block_k=block_k, interpret=interpret)

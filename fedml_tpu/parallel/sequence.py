@@ -38,7 +38,7 @@ def ring_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     axis_name: str, axis_size: int, causal: bool = True,
     sm_scale: Optional[float] = None, impl: str = "auto",
-    interpret: bool = False,
+    interpret: bool = False, window: Optional[int] = None,
 ) -> jax.Array:
     """Attention over a sequence sharded along ``axis_name``.
 
@@ -63,7 +63,8 @@ def ring_attention(
         src = (idx - i) % axis_size          # whose shard we hold this step
         part = attention_block_partial(
             q, k_cur, v_cur, q_offset=q_off, k_offset=src * tl,
-            causal=causal, sm_scale=sm_scale, impl=impl, interpret=interpret)
+            causal=causal, sm_scale=sm_scale, impl=impl, interpret=interpret,
+            window=window)      # refused there: the ring knows no window
         return merge_partials(acc, part)
 
     # step 0 on the resident shard, then permute-then-compute for the rest:
@@ -85,7 +86,7 @@ def ulysses_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     axis_name: str, axis_size: int, causal: bool = True,
     sm_scale: Optional[float] = None, impl: str = "auto",
-    interpret: bool = False,
+    interpret: bool = False, window: Optional[int] = None,
 ) -> jax.Array:
     """All-to-all (DeepSpeed-Ulysses style) sequence parallelism.
 
@@ -116,7 +117,7 @@ def ulysses_attention(
 
     qg, kg, vg = scatter_heads(q), scatter_heads(k), scatter_heads(v)
     out = attention(qg, kg, vg, causal=causal, sm_scale=sm_scale,
-                    impl=impl, interpret=interpret)
+                    impl=impl, interpret=interpret, window=window)
     # inverse: sequence scatters back, head groups gather
     return jax.lax.all_to_all(out, axis_name, split_axis=2, concat_axis=1,
                               tiled=True)

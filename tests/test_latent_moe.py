@@ -152,6 +152,27 @@ def test_row_rungs_come_from_the_pair_count_alone():
     assert all(c % 1024 == 0 for c in moe.row_rungs(9000 * 8)[:-1])
 
 
+@pytest.mark.parametrize("pairs, rows, want", [
+    # the window / full cell: 8,192 tokens x 8 choices, 32 of 256 held, so
+    # 8,192 rows expected, which is the first rung to the row: one row more
+    # takes the second (``PERF.md`` PR 32: the cell's rate follows it)
+    (8192 * 8, 8192, 0),
+    (8192 * 8, 8193, 1),
+    (8192 * 8, 16385, 2),
+    (8192 * 8, 8192 * 8, 3),
+    # kanana's 6 choices with 16 of 128 held: 6,144 expected, its edge too
+    (8192 * 6, 6144, 0),
+    (8192 * 6, 6145, 1),
+    # the hybrid's 8 of 512 held: 512 rows in a first rung of 4,096
+    (4096 * 8, 512, 0),
+])
+def test_the_rung_taken_is_the_first_that_holds_the_rows(pairs, rows, want):
+    rungs = moe.row_rungs(pairs)
+    sizes = jnp.asarray([rows - rows // 2, rows // 2], jnp.int32)
+    assert int(moe._rung_index(rungs, sizes)) == want
+    assert rungs[want] >= rows and (want == 0 or rungs[want - 1] < rows)
+
+
 def _steered_layer(totals, held_count=4, dtype=jnp.float32):
     """A layer of 32 tokens x 2 choices over 8 experts (rungs 8 / 16 / 32 /
     64 of its 64 pairs) whose input is solved for so that exactly
@@ -383,12 +404,14 @@ def test_token_ids_reach_the_model_unrounded(dtype, kind):
 
 @pytest.mark.parametrize("fixture,cell_name", [
     ("BENCHMARK.tiny_lm.json", "tiny_kanana2_sim"),
-    ("BENCHMARK.tiny_hybrid.json", "tiny_ling3_sim")])
+    ("BENCHMARK.tiny_hybrid.json", "tiny_ling3_sim"),
+    ("BENCHMARK.tiny_laguna.json", "tiny_laguna_sim")])
 def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
     """An LM's round program carries the step's scopes and the
     ``fedml.lm.*`` names of what it is built of, as metadata only: all of
-    the table for the hybrid decoder, all but the delta rule's two for the
-    latent-attention one."""
+    the table but the window layers' name for the hybrid decoder, that
+    without the delta rule's two for the latent-attention one, and for the
+    window / full decoder all but the delta rule's."""
     import re
 
     from benchmarks.harness.cell import build_api
@@ -411,8 +434,11 @@ def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
     named = lowered.as_text(debug_info=True)
     found = set(re.findall(r"fedml\.[a-z_.]+", named))
     table = {v for k, v in vars(tracer).items() if k.startswith("SCOPE_")}
-    if "delta" not in config["model"].get("mixers", ()):
+    mixers = config["model"].get("mixers", ())
+    if "delta" not in mixers:
         table -= {tracer.SCOPE_LM_KDA, tracer.SCOPE_LM_KDA_PREP}
+    if "window" not in mixers:
+        table -= {tracer.SCOPE_LM_ATTN_WINDOW}
     assert found == table
     text = lowered.as_text()
     assert "fedml." not in text
@@ -634,4 +660,243 @@ def test_hybrid_round_counts_its_groups_and_trains():
     assert moved["layer_2"]["attn"]["q_norm"]["scale"] > 0
     counters = api.bundle.counters(api.variables)
     assert counters["group_tokens.layer_1"] == float(c["group_tokens"])
+    api.close()
+
+
+# --- the window / full decoder: grouped-query mixers, a softmax router ------
+
+from benchmarks.references import laguna_xs2 as lag  # noqa: E402
+
+
+def laguna_config(**over):
+    sizes = {**LATENT_MOE_PRESETS["laguna_tiny"], **over}
+    return {"name": "tiny_laguna", "model": sizes, "data": {"vocab": VOCAB},
+            "recipe": {"lr": 0.1, "momentum": 0.0}}
+
+
+@pytest.mark.parametrize("over,remat", [
+    ({}, True),
+    ({"held_first": 4, "held_count": 8,
+      "mixers": ["window", "full", "window"]}, False)])
+def test_laguna_logits_loss_and_gradients_match_the_reference(over, remat):
+    """6 and 8 query heads over 2 key-value heads, a window of 8 in 32
+    positions, YaRN in the full layers, the head-wise gate, the softmax
+    router; the layers' pattern is data."""
+    config = laguna_config(**over)
+    v = jax.jit(lambda k: lag.init(k, config))(jax.random.key(7))
+    b = create_model("laguna_tiny", VOCAB, input_shape=(32,),
+                     dtype=jnp.float32, remat=remat, **over)
+    assert (jax.tree.map(jnp.shape, b.init(jax.random.key(0)))
+            == jax.tree.map(jnp.shape, v))
+    x, y, m = batch(t=32)
+    forward = lag._forward(config, "reference")
+
+    def program(p):
+        logits, new = b.apply_train({**v, "params": p}, x, None)
+        return nwp.loss(logits, y, m), (logits, new["counters"])
+
+    def reference(p):
+        logits, stats, _ = forward(p, v["counters"], x)
+        per = -jnp.take_along_axis(jax.nn.log_softmax(logits), y[..., None],
+                                   -1)[..., 0]
+        w = jnp.broadcast_to(m[:, None], per.shape)
+        return jnp.sum(per * w) / jnp.sum(w), (logits, stats)
+
+    with jax.default_matmul_precision("highest"):       # jitted: a program
+        # run op by op on the CPU takes ten times as long
+        (lp, (op, sp)), gp = jax.jit(jax.value_and_grad(
+            program, has_aux=True))(v["params"])
+        (lr, (orf, sr)), gr = jax.jit(jax.value_and_grad(
+            reference, has_aux=True))(v["params"])
+    np.testing.assert_allclose(op, orf, atol=5e-6)
+    np.testing.assert_allclose(lp, lr, rtol=1e-6)
+    for (path, a), c in zip(jax.tree_util.tree_leaves_with_path(gp),
+                            jax.tree.leaves(gr)):
+        np.testing.assert_allclose(a, c, atol=5e-6 * float(jnp.abs(c).max() + 1e-6)
+                                   + 1e-9, err_msg=str(path))
+    # a softmax router has no correction bias; the gate has no norm
+    assert "e_score_correction_bias" not in gp["layer_1"]["mlp"]
+    assert set(gp["layer_1"]["attn"]["out_gate"]) == {"proj"}
+    for name in sp:
+        np.testing.assert_array_equal(sp[name]["mlp"]["expert_rows"],
+                                      sr[name]["mlp"]["expert_rows"])
+        assert float(sp[name]["mlp"]["steps"]) == 1.0
+    # what the two controls of its own leave out shows in the logits
+    for variant in ("window_full", "rope_plain"):
+        other = jax.jit(lag._forward(config, variant))(
+            v["params"], v["counters"], x)[0]
+        assert float(jnp.abs(other - orf).max()) > 1e-3, variant
+
+
+def test_yarn_frequencies_against_numbers_written_out_by_hand():
+    """The published keys (theta 500,000, factor 64, original 4,096,
+    beta_fast 64, beta_slow 1, rotary width 64): ``corr(64)`` = 5.05 and
+    ``corr(1)`` = 15.19, so pairs 0 - 5 keep ``theta^(-i/32)``, pairs 16 - 31
+    take a 64th of it, and pair ``i`` between blends by ``(i - 5) / 11``.
+    Three values are what ``transformers`` 4.57 returns for these keys. The
+    program's function and the reference's own agree."""
+    from fedml_tpu.models.transformer import yarn_frequencies
+
+    f = yarn_frequencies(64, 5e5, 64.0, 4096, 64.0, 1.0)
+    plain = 5e5 ** (-np.arange(32) / 32.0)
+    np.testing.assert_allclose(f[:6], plain[:6], rtol=1e-12)
+    np.testing.assert_allclose(f[16:], plain[16:] / 64, rtol=1e-12)
+    ramp = (np.arange(6, 16) - 5) / 11.0
+    np.testing.assert_allclose(
+        f[6:16], plain[6:16] * (1 - ramp) + plain[6:16] / 64 * ramp, rtol=1e-12)
+    np.testing.assert_allclose(
+        [f[6], f[16], f[31]], [0.0777550, 2.2097085e-5, 4.7091532e-8], rtol=1e-6)
+    assert 0.1 * np.log(64) + 1 == pytest.approx(1.4158883083359672)
+    np.testing.assert_allclose(
+        f, lag._yarn_frequencies(64, 5e5, 64.0, 4096, 64.0, 1.0), rtol=1e-12)
+    # no blend: the plain law
+    np.testing.assert_allclose(yarn_frequencies(64, 5e5, 1.0, 4096, 64.0, 1.0),
+                               plain, rtol=1e-12)
+
+
+def test_rotary_with_given_frequencies_and_scale():
+    """``rotary`` turns pair ``i`` by ``pos * inv_freq[i]`` and scales both
+    components; without either it is what it was."""
+    from fedml_tpu.models.transformer import rotary
+
+    x = jax.random.normal(jax.random.key(0), (2, 5, 8))
+    inv = np.array([1.0, 0.5, 0.25, 0.125])
+    got = np.asarray(rotary(x, 10.0, inv, 1.5))
+    pos = np.arange(5)[:, None] * inv
+    a, b = np.asarray(x)[..., 0::2], np.asarray(x)[..., 1::2]
+    want = np.stack([a * np.cos(pos) - b * np.sin(pos),
+                     a * np.sin(pos) + b * np.cos(pos)], -1).reshape(x.shape)
+    np.testing.assert_allclose(got, 1.5 * want, atol=1e-6)
+    np.testing.assert_array_equal(
+        rotary(x, 10.0), rotary(x, 10.0, 10.0 ** (-np.arange(0, 8, 2) / 8)))
+
+
+@pytest.mark.parametrize("top_k,scaling", [(4, 2.5), (8, 1.0), (1, 2.5)])
+def test_softmax_routing_against_a_naive_one(top_k, scaling):
+    """Softmax over all the logits, the ``top_k`` largest, weights
+    normalised over the chosen and scaled; no bias anywhere."""
+    n, e = 64, 32
+    logits = jax.random.normal(jax.random.key(1), (n, e))
+    scores = jax.nn.softmax(logits, axis=-1)
+    idx, w = moe.route(scores, None, top_k, scaling)
+    z = np.asarray(logits, np.float64)
+    for t in range(n):
+        p = np.exp(z[t] - z[t].max())
+        p /= p.sum()
+        want = np.argsort(-p)[:top_k]
+        assert set(np.asarray(idx[t])) == set(want)
+        chosen = p[np.asarray(idx[t])]
+        np.testing.assert_allclose(w[t], chosen / chosen.sum() * scaling,
+                                   rtol=1e-5)
+    # the reference's own router, written another way, agrees
+    config = laguna_config(n_routed=e, top_k=top_k, routed_scaling=scaling)
+    x = jax.random.normal(jax.random.key(2), (n, 32))
+    router = jax.random.normal(jax.random.key(3), (32, e))
+    ridx, rw = lag._forward(config, "reference").choose(x, {"router": router})
+    with jax.default_matmul_precision("highest"):
+        pidx, pw = moe.route(jax.nn.softmax(x @ router, -1), None, top_k,
+                             scaling)
+    np.testing.assert_array_equal(np.sort(ridx, -1), np.sort(pidx, -1))
+    np.testing.assert_allclose(np.sort(rw, -1), np.sort(pw, -1), rtol=1e-5)
+
+
+def test_softmax_routed_shares_add_up_to_the_uncut_layer():
+    """4 shares of 4 of 16 experts: the routed parts of all the shares, plus
+    the shared expert counted once, are the uncut reference layer's output;
+    the router has no bias parameter and no group."""
+    d, n_routed, width, k = 32, 16, 24, 4
+    config = laguna_config(held_first=0, held_count=n_routed)
+    v = jax.jit(lambda k: lag.init(k, config))(jax.random.key(3))
+    p = v["params"]["layer_1"]["mlp"]
+    assert "e_score_correction_bias" not in p
+    x = jax.random.normal(jax.random.key(4), (2, 32, d), jnp.float32)
+
+    def layer(first, count, n_shared):
+        mod = SharedRoutedMoe(n_routed, k, width, n_shared, 2.5, first, count,
+                              jnp.float32, score="softmax")
+        params = {kk: (a[first:first + count] if kk in ("gate", "up", "down")
+                       else a) for kk, a in p.items() if n_shared or kk != "shared"}
+        stats = {"expert_rows": jnp.zeros((count,)), "steps": jnp.zeros(())}
+        out, new = mod.apply({"params": params, "counters": stats}, x, True,
+                             mutable=["counters"])
+        return out, new["counters"]
+
+    with jax.default_matmul_precision("highest"):
+        whole, stats = layer(0, n_routed, 1)
+        shared_once = whole - layer(0, n_routed, 0)[0]
+        shares = [layer(first, 4, 0) for first in range(0, n_routed, 4)]
+        uncut, rows, _ = lag._forward(config, "reference").moe(x, p)
+    np.testing.assert_allclose(sum(s[0] for s in shares) + shared_once, whole,
+                               atol=3e-6)
+    np.testing.assert_allclose(whole, uncut, atol=3e-6)
+    assert float(rows.sum()) == 64 * k == float(stats["expert_rows"].sum())
+    assert sum(float(s[1]["expert_rows"].sum()) for s in shares) == 64 * k
+    with pytest.raises(ValueError, match="groups"):
+        SharedRoutedMoe(n_routed, k, width, 0, 2.5, 0, 4, jnp.float32, 4, 2,
+                        "softmax").init(jax.random.key(0), x)
+
+
+def test_laguna_registered_defaults_are_the_published_widths():
+    k = LATENT_MOE_PRESETS["laguna_xs2"]
+    assert (k["dim"], k["heads"], k["window_heads"], k["kv_heads"], k["v_dim"],
+            k["window"]) == (2048, 48, 64, 8, 128, 512)
+    assert k["nope"] + k["rope"] == k["v_dim"] and k["rope"] == 64
+    assert (k["n_routed"], k["top_k"], k["n_shared"], k["expert_width"],
+            k["dense_width"], k["routed_scaling"], k["score"]) == (
+                256, 8, 1, 512, 8192, 2.5, "softmax")
+    assert k["mixers"] == ["full", "window", "window", "window", "full"]
+    # an eighth of the experts held: the first row capacity's edge
+    assert k["held_count"] / k["n_routed"] == moe.ROW_RUNG_SHARES[0]
+    b = create_model("laguna_xs2", 12544)
+    shapes = jax.eval_shape(b.init, jax.random.key(0))
+
+    def count(tree):
+        return sum(int(np.prod(s.shape)) for s in jax.tree.leaves(tree))
+
+    p = shapes["params"]
+    assert count(p) == 691_623_936        # the issue's 691.6 M parameters
+    assert count(p["layer_0"]["attn"]) == 29_458_432
+    assert count(p["layer_1"]["attn"]) == 37_879_808
+    assert count(p["layer_1"]["mlp"]) == 104_333_312
+    assert p["layer_1"]["mlp"]["router"].shape == (2048, 256)
+    assert p["layer_1"]["mlp"]["gate"].shape == (32, 2048, 512)
+    assert p["layer_1"]["attn"]["out_gate"]["proj"]["kernel"].shape == (2048, 64)
+    assert p["layer_4"]["attn"]["k_proj"]["kernel"].shape == (2048, 1024)
+
+
+def test_laguna_round_counts_its_rows_and_trains():
+    """One packed round of the tiny window / full model: the counters are
+    sums over the clients' steps, the loss is finite and the weights move."""
+    from fedml_tpu.algorithms.fedavg import FedAvgAPI
+    from fedml_tpu.core.config import FedConfig
+    from fedml_tpu.data import FedDataset
+
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, VOCAB, (4, 4, 33)).astype(np.int32)
+    ds = FedDataset(train_x=ids[..., :-1], train_y=ids[..., 1:],
+                    train_mask=np.ones((4, 4), np.float32),
+                    train_counts=np.full((4,), 4, np.int64),
+                    test_x=ids[0, :, :-1], test_y=ids[0, :, 1:],
+                    test_mask=np.ones(4, np.float32), class_num=VOCAB,
+                    task="nwp", name="tiny")
+    cfg = FedConfig(model="laguna_tiny", dataset="tiny", batch_size=2, epochs=1,
+                    client_optimizer="sgd", lr=0.1, momentum=0.0,
+                    client_num_in_total=4, client_num_per_round=2,
+                    pack_lanes=1, device_data="on", comm_round=1)
+    api = FedAvgAPI(ds, cfg, create_model("laguna_tiny", VOCAB,
+                                          input_shape=(32,)))
+    before = jax.device_get(api.variables)
+    loss = float(api.run_round(1))
+    after = jax.device_get(api.variables)
+    assert np.isfinite(loss)
+    c = after["counters"]["layer_1"]["mlp"]
+    assert float(c["steps"]) == 4.0                  # 2 clients x 2 batches
+    assert 0 < float(c["expert_rows"].sum()) <= 4 * 64 * 4
+    moved = jax.tree.map(lambda a, b: float(np.abs(a - b).max()),
+                         after["params"], before["params"])
+    assert moved["layer_0"]["attn"]["out_gate"]["proj"]["kernel"] > 0
+    assert moved["layer_1"]["attn"]["k_proj"]["kernel"] > 0
+    assert moved["layer_2"]["mlp"]["router"] > 0
+    counters = api.bundle.counters(api.variables)
+    assert counters["steps.layer_1"] == 4.0 and "rows.layer_2.3" in counters
     api.close()

@@ -117,3 +117,146 @@ def test_attention_with_value_size_of_its_own(impl, d, dv, t, block):
     assert got[1][0].shape == q.shape and got[1][2].shape == v.shape
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+# --- a window, and query heads that share a key-value head ------------------
+
+def _plain_band_attention(q, k, v, window=None):
+    """Heads repeated by index, the masked softmax of the whole matrix."""
+    group, t = q.shape[1] // k.shape[1], q.shape[2]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    behind = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    keep = behind >= 0 if window is None else (behind >= 0) & (behind < window)
+    return jnp.einsum("bhqk,bhkd->bhqd",
+                      jax.nn.softmax(jnp.where(keep, s, -jnp.inf), -1), v)
+
+
+@pytest.mark.parametrize("impl,heads,kv,t,block,window", [
+    ("xla", 6, 1, 64, 32, 16), ("xla", 8, 2, 64, 32, None),
+    ("pallas", 6, 1, 64, 32, 16),       # a window under the tile, 6 to one
+    ("pallas", 8, 1, 64, 32, 32),       # equal to the tile, 8 to one
+    ("pallas", 4, 2, 128, 32, 48),      # over the tile
+    ("pallas", 2, 2, 64, 16, 5),        # under the sub-tile, equal heads
+    ("pallas", 2, 1, 96, 32, 100),      # wider than the sequence: causal
+    ("pallas", 6, 2, 64, 32, None),     # grouped heads without a window
+    ("pallas", 3, 1, 96, 32, 40), ("pallas", 2, 2, 128, 64, 16)])
+def test_windowed_and_grouped_attention_against_the_plain_softmax(
+        monkeypatch, impl, heads, kv, t, block, window):
+    """Forward and all three gradients (a key-value head's summed over the
+    query heads that read it, inside the dk/dv kernel); the kernels
+    interpreted, in sub-tiles of 8 so that a tile has plain, crossed and
+    dead sub-tiles on both edges of the band."""
+    import importlib
+
+    att = importlib.import_module("fedml_tpu.ops.attention")
+    monkeypatch.setattr(att, "_SUB_Q", 8)
+    monkeypatch.setattr(att, "_SUB_K", 8)
+    ks = jax.random.split(jax.random.key(heads + t + (window or 0)), 4)
+    q = jax.random.normal(ks[0], (2, heads, t, 16))
+    k = jax.random.normal(ks[1], (2, kv, t, 16))
+    v = jax.random.normal(ks[2], (2, kv, t, 8))
+    c = jax.random.normal(ks[3], (2, heads, t, 8))
+
+    def run(fn):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) * c),
+                                  argnums=(0, 1, 2))(q, k, v)
+
+    got = run(lambda q, k, v: attention(
+        q, k, v, impl=impl, block_q=block, block_k=block, window=window,
+        interpret=impl == "pallas"))
+    want = run(lambda q, k, v: _plain_band_attention(q, k, v, window))
+    assert got[1][1].shape == k.shape and got[1][2].shape == v.shape
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("tq,bq,bk,sq,sk,window", [
+    (64, 32, 32, 8, 8, 16), (64, 32, 32, 8, 8, 5), (128, 32, 32, 8, 8, 48),
+    (128, 32, 64, 8, 16, 40), (128, 64, 32, 16, 8, 40), (64, 16, 16, 16, 16, 5),
+    (64, 32, 32, 8, 8, 100), (4096, 1024, 1024, 256, 256, 512),
+    (4096, 512, 512, 256, 256, 512), (4096, 128, 128, 128, 128, 512)])
+@pytest.mark.parametrize("over_queries", [False, True])
+def test_a_windows_sub_tiles_are_those_the_band_leaves(tq, bq, bk, sq, sk,
+                                                       window, over_queries):
+    """Numpy brute force over the band against the kernels' own spans: a
+    sub-tile is computed, once, exactly if one of its elements is inside the
+    band; a span masks every edge that cuts it; the offsets a kernel tests
+    for hold every live tile's; the sweep has a step for every live tile and
+    names only live blocks."""
+    import importlib
+
+    att = importlib.import_module("fedml_tpu.ops.attention")
+    behind = np.arange(tq)[:, None] - np.arange(tq)[None, :]
+    keep = (behind >= 0) & (behind < window)
+    live = set(zip(*np.nonzero(
+        keep.reshape(tq // sq, sq, tq // sk, sk).any(axis=(1, 3)))))
+    tiling = att._Tiling(True, bq, bk, sq, sk, window)
+    nq, nk = tq // bq, tq // bk
+    seen, live_tiles = [], set()
+    for qb in range(nq):
+        for kb in range(nk):
+            d = qb * bq - kb * bk
+            for when, group in att._tile_spans(d, tiling, over_queries):
+                for rows, keys, mask in group if when else ():
+                    live_tiles.add((qb, kb))
+                    assert d in att._band_offsets(tiling)
+                    r = slice(qb * bq + rows.start, qb * bq + rows.stop)
+                    c = slice(kb * bk + keys.start, kb * bk + keys.stop)
+                    assert mask & att._MASK_DIAGONAL or (behind[r, c] >= 0).all()
+                    assert mask & att._MASK_EDGE or (behind[r, c] < window).all()
+                    seen += [(i, j) for i in range(r.start // sq, r.stop // sq)
+                             for j in range(c.start // sk, c.stop // sk)]
+    assert len(seen) == len(set(seen)) and set(seen) == live
+    assert att.executed_score_share(tq, tq, bq, bk, sq, sk, window=window) \
+        == len(live) * sq * sk / tq ** 2
+    # the grid: each block that stays put sweeps its live blocks, first to
+    # last, and a step past them names the last one and stands for no block
+    mine, swept = (nk, nq) if over_queries else (nq, nk)
+    sweep = att._band_sweep(tiling, mine, swept, over_queries)
+    for i in range(mine):
+        tiles = sorted(b if over_queries else a for a, b in
+                       ((kb, qb) for qb, kb in live_tiles)
+                       if (a if over_queries else b) == i)
+        assert tiles == list(range(tiles[0], tiles[-1] + 1))
+        assert len(tiles) <= sweep
+        d = -i * bk if over_queries else i * bq
+        for step in range(sweep):
+            block, index = att._band_block(step, d, tiling, swept, over_queries)
+            if step < len(tiles):
+                assert int(block) == int(index) == tiles[step]
+            else:
+                assert int(block) == att._NO_BLOCK and int(index) == tiles[-1]
+
+
+def test_executed_score_share_with_a_window_by_hand():
+    """T 4,096 under a window of 512 in sub-tiles of 256: the first block of
+    256 queries meets 1 sub-tile, the second 2, the other 14 three each (one
+    cut by the far edge, one whole, one on the diagonal): 45 of 256. The
+    band itself is 1,966,336 pairs, 11.7% of the matrix. Without a window
+    the share is what it was."""
+    from fedml_tpu.ops.attention import executed_score_share as share
+
+    assert share(4096, 4096, 1024, 1024, window=512) == 45 / 256
+    assert share(4096, 4096, 512, 512, window=512) == 45 / 256
+    assert share(4096, 4096, 1024, 1024, 1024, 1024, window=512) == 7 / 16
+    assert 512 * 513 // 2 + (4096 - 512) * 512 == 1_966_336
+    assert share(4096, 4096, 1024, 1024) == 0.53125
+    assert share(4096, 4096, 1024, 1024, window=None) == 0.53125
+    # a window as wide as the sequence is the causal mask
+    assert share(4096, 4096, 1024, 1024, window=4096) == 0.53125
+
+
+def test_the_partial_form_refuses_a_window():
+    """A ring step knows no window: it says so, and never attends to the
+    whole prefix in silence. A window is causal."""
+    from fedml_tpu.ops.attention import attention_block_partial
+
+    q = jnp.zeros((1, 1, 16, 8))
+    with pytest.raises(NotImplementedError, match="window"):
+        attention_block_partial(q, q, q, window=8)
+    with pytest.raises(ValueError, match="causal"):
+        attention(q, q, q, causal=False, window=8)
+    with pytest.raises(ValueError, match="query heads"):
+        attention(jnp.zeros((1, 3, 16, 8)), jnp.zeros((1, 2, 16, 8)),
+                  jnp.zeros((1, 2, 16, 8)))

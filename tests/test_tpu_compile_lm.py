@@ -61,6 +61,41 @@ def test_latent_attention_kernels_compile_at_published_widths(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
+@pytest.mark.parametrize("heads,window,block", [
+    (64, 512, 1024), (64, 512, 512), (48, None, 1024)])
+def test_grouped_and_windowed_kernels_compile_at_published_widths(
+        one_chip, no_cache, heads, window, block):
+    """64 query heads over 8 key-value heads under a window of 512, and 48
+    over 8 without one, 4,096 positions, heads of 128, bf16, two sequences:
+    forward, dq and the dk/dv kernel that sums over its group. Under the
+    window the temporaries hold no score tensor either, and the grid sweeps
+    2 key tiles a query tile where the causal kernels sweep 4."""
+    import importlib
+
+    # the package exports the function under the module's name
+    att = importlib.import_module("fedml_tpu.ops.attention")
+
+    def step(q, k, v, c):
+        return jax.grad(lambda q, k, v: jnp.sum(att.attention(
+            q, k, v, impl="pallas", block_q=block, block_k=block,
+            window=window).astype(jnp.float32) * c), argnums=(0, 1, 2))(q, k, v)
+
+    def sd(h):
+        return jax.ShapeDtypeStruct((2, h, 4096, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(step).lower(sd(heads), sd(8), sd(8), sd(heads)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    _dq, dk, dv = jax.eval_shape(step, sd(heads), sd(8), sd(8), sd(heads))
+    assert dk.shape == dv.shape == (2, 8, 4096, 128)
+    if window:
+        tiling = att._tiling(True, 4096, 4096, block, block, window)
+        n = 4096 // block
+        assert att._band_sweep(tiling, n, n) == 2
+        assert att._band_sweep(tiling, n, n, over_queries=True) == 2
+
+
 @pytest.mark.parametrize("rows", [8192 * 6, 6144])
 def test_grouped_matmul_compiles_unbatched_at_published_widths(one_chip,
                                                                no_cache, rows):
@@ -165,18 +200,31 @@ def test_delta_rule_scan_compiles_at_published_widths(one_chip, no_cache,
         assert temporaries < 1.5e9
 
 
-def test_older_lm_lowers_the_same_with_the_new_options_at_their_defaults():
+@pytest.mark.parametrize("name,named", [
+    ("kanana2_tiny", dict(mixers=("latent",) * 3, n_group=1, topk_group=1,
+                          qk_norm=False, out_gate=False)),
+    ("kanana2_tiny", dict(kv_heads=0, window_heads=0, window=0,
+                          window_rope_theta=1e4, yarn_factor=0.0,
+                          yarn_original=0, yarn_attention_factor=1.0,
+                          score="sigmoid")),
+    ("ling3_tiny", dict(kv_heads=0, window_heads=0, window=0, yarn_factor=0.0,
+                        score="sigmoid"))])
+def test_older_lm_lowers_the_same_with_the_new_options_at_their_defaults(
+        name, named):
     """The options the hybrid decoder added (the mixers' pattern, the
-    group-limited router, the query / key norms, the output gate) leave the
-    latent-attention LM's program as it was: built with every one of them
-    named at its default it lowers to the same text as built without, and
-    its variable tree has no new leaf (CPU fixture; against the parent
-    commit's text the same was checked by sha256, ``PERF.md`` PR 30)."""
+    group-limited router, the query / key norms, the output gate) and those
+    the window / full decoder added (the grouped-query mixers' sizes, the
+    window, YaRN, the router's score function) leave the older LMs'
+    programs as they were: built with every one of them named at its default
+    a model lowers to the same text as built without, and its variable tree
+    has no new leaf (CPU fixtures; against the parent commit's text the
+    three fixtures' round programs were checked by sha256, ``PERF.md`` PR 30
+    and PR 32)."""
     from fedml_tpu.core.tasks import nwp
     from fedml_tpu.models import create_model
 
     def lowered(**kw):
-        b = create_model("kanana2_tiny", 64, input_shape=(16,), **kw)
+        b = create_model(name, 64, input_shape=(16,), **kw)
         v = b.init(jax.random.key(0))
 
         def step(v, x, y, m):
@@ -190,7 +238,7 @@ def test_older_lm_lowers_the_same_with_the_new_options_at_their_defaults():
                 jax.tree.map(jnp.shape, v))
 
     plain, tree = lowered()
-    named, tree2 = lowered(mixers=("latent",) * 3, n_group=1, topk_group=1,
-                           qk_norm=False, out_gate=False)
-    assert plain == named and tree == tree2
-    assert "group_tokens" not in str(tree) and "out_gate" not in str(tree)
+    text, tree2 = lowered(**named)
+    assert plain == text and tree == tree2
+    if name == "kanana2_tiny":
+        assert "group_tokens" not in str(tree) and "out_gate" not in str(tree)

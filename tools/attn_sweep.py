@@ -17,6 +17,14 @@ module's constant, set here for a row; nothing else selects it.
 
     python tools/attn_sweep.py 1024:1024:1024 1024:256:256 1024:128:128
 
+``--heads`` / ``--kv-heads`` / ``--head-dim`` / ``--value-dim`` / ``--window``
+give another cell's shapes (a window layer of 64 query heads over 8
+key-value heads of 128 under a window of 512: ``--heads 64 --kv-heads 8
+--head-dim 128 --window 512``). ``--repeat-kv`` times the other way to serve
+grouped heads: ``k`` and ``v`` repeated ``heads / kv_heads`` times BEFORE
+kernels that then see equal heads, and their gradients summed over the group
+after, both inside the timed call.
+
 Fails at once without a TPU. Writes ``chiprun_out/attn_sweep.json``.
 """
 
@@ -74,7 +82,7 @@ def _device_ms(fn, args, calls: int = 3) -> dict:
 
 
 def measure(shape, d, dv, tile, sub_q, sub_k, iters, interpret=False,
-            trace=True):
+            trace=True, kv_heads=None, window=None, repeat_kv=False):
     """-> (row, results): one configuration's three kernels."""
     import jax
     import jax.numpy as jnp
@@ -82,31 +90,47 @@ def measure(shape, d, dv, tile, sub_q, sub_k, iters, interpret=False,
     att = importlib.import_module("fedml_tpu.ops.attention")
     att._SUB_Q, att._SUB_K = sub_q, sub_k
     b, h, t = shape
+    g = kv_heads or h
     keys = jax.random.split(jax.random.key(27), 4)
-    q, k = (jax.random.normal(keys[i], (b, h, t, d), jnp.bfloat16) for i in (0, 1))
-    v, do = (jax.random.normal(keys[i], (b, h, t, dv), jnp.bfloat16) for i in (2, 3))
+    q, k = (jax.random.normal(keys[i], (b, n, t, d), jnp.bfloat16)
+            for i, n in ((0, h), (1, g)))
+    v, do = (jax.random.normal(keys[i], (b, n, t, dv), jnp.bfloat16)
+             for i, n in ((2, g), (3, h)))
     scale = d ** -0.5
     q, k = att._pad_qk(q, k)
 
+    def spread(a):      # a key-value head for each of its query heads
+        return jnp.repeat(a, h // g, axis=1) if repeat_kv else a
+
+    def gathered(a):    # and their gradients' sum
+        return (a.reshape(b, g, h // g, *a.shape[2:]).sum(2).astype(a.dtype)
+                if repeat_kv else a)
+
+    def partial(q, k, v):
+        return att._pallas_block_partial(q, spread(k), spread(v), 0, 0, True,
+                                         scale, tile, tile, interpret, window)
+
     def fwd(q, k, v):
-        o, m, l = att._pallas_block_partial(q, k, v, 0, 0, True, scale, tile,
-                                            tile, interpret)
+        o, m, l = partial(q, k, v)
         return (o / l[..., None]).astype(q.dtype), m + jnp.log(l)
 
     out, lse = jax.jit(fwd)(q, k, v)
 
     def bwd(q, k, v, out, lse, do):
-        return att._pallas_flash_bwd(q, k, v, out, lse, do, True, scale, tile,
-                                     tile, interpret)
+        dq, dk, dv_ = att._pallas_flash_bwd(
+            q, spread(k), spread(v), out, lse, do, True, scale, tile, tile,
+            interpret, window)
+        return dq, gathered(dk), gathered(dv_)
 
     fns = {
-        "fwd": (jax.jit(lambda q, k, v: att._pallas_block_partial(
-            q, k, v, 0, 0, True, scale, tile, tile, interpret)), (q, k, v)),
+        "fwd": (jax.jit(partial), (q, k, v)),
         "dkv": (jax.jit(lambda *a: bwd(*a)[1:]), (q, k, v, out, lse, do)),
         "dq": (jax.jit(lambda *a: bwd(*a)[0]), (q, k, v, out, lse, do)),
     }
-    share = att.executed_score_share(t, t, tile, tile, sub_q, sub_k)
-    row = {"tile": tile, "sub_q": sub_q, "sub_k": sub_k,
+    share = att.executed_score_share(t, t, tile, tile, sub_q, sub_k,
+                                     window=window)
+    row = {"tile": tile, "sub_q": sub_q, "sub_k": sub_k, "heads": h,
+           "kv_heads": g, "window": window, "repeat_kv": repeat_kv,
            "executed_score_share": share}
     results = {}
     for name, (fn, args) in fns.items():
@@ -125,12 +149,14 @@ def measure(shape, d, dv, tile, sub_q, sub_k, iters, interpret=False,
             wide, narrow = PASSES[name]
             flops = 2 * b * h * t * t * share * (wide * q.shape[-1] + narrow * dv)
             row[f"{name}_mxu_pct"] = 100 * flops / _peak_flops() / (ops[top] / 1e3)
-    row["err_to_xla"] = _err_to_xla(att, (q[:1, :2, :, :d], k[:1, :2, :, :d],
-                                          v[:1, :2], do[:1, :2]), tile)
+    one = h // g                # the query heads of the first key-value head
+    row["err_to_xla"] = _err_to_xla(
+        att, (q[:1, :2 * one, :, :d], k[:1, :2, :, :d], v[:1, :2],
+              do[:1, :2 * one]), tile, window)
     return row, results
 
 
-def _err_to_xla(att, qkvc, tile) -> dict:
+def _err_to_xla(att, qkvc, tile, window=None) -> dict:
     """Forward and the three gradients of ``attention`` on the kernels
     against the XLA path in float32 at ``highest``."""
     import jax
@@ -141,7 +167,7 @@ def _err_to_xla(att, qkvc, tile) -> dict:
 
         def loss(q, k, v):
             o = att.attention(q, k, v, causal=True, impl=impl, block_q=tile,
-                              block_k=tile)
+                              block_k=tile, window=window)
             return jnp.sum(o.astype(jnp.float32) * c.astype(jnp.float32)), o
 
         (_, o), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(
@@ -175,6 +201,16 @@ def main(argv=None) -> int:
         help="tile:sub_q:sub_k; the first row is what the others' results "
              "are compared with")
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--batch", type=int, default=2)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--kv-heads", type=int, help="default: --heads")
+    ap.add_argument("--seq-len", type=int, default=4096)
+    ap.add_argument("--head-dim", type=int, default=192,
+                    help="queries and keys")
+    ap.add_argument("--value-dim", type=int, default=128)
+    ap.add_argument("--window", type=int)
+    ap.add_argument("--repeat-kv", action="store_true")
+    ap.add_argument("--out", default="chiprun_out/attn_sweep.json")
     args = ap.parse_args(argv)
     if jax.default_backend() != "tpu":
         print("attn_sweep: no TPU", file=sys.stderr)
@@ -183,8 +219,11 @@ def main(argv=None) -> int:
     for spec in args.rows:
         tile, sub_q, sub_k = (int(x) for x in spec.split(":"))
         try:
-            row, results = measure((2, 32, 4096), 192, 128, tile, sub_q,
-                                   sub_k, args.iters)
+            row, results = measure(
+                (args.batch, args.heads, args.seq_len), args.head_dim,
+                args.value_dim, tile, sub_q, sub_k, args.iters,
+                kv_heads=args.kv_heads, window=args.window,
+                repeat_kv=args.repeat_kv)
         except Exception as e:  # noqa: BLE001  the compiler refused the row
             print(json.dumps({"row": spec, "failed": str(e)[-600:]}), flush=True)
             continue
@@ -192,8 +231,8 @@ def main(argv=None) -> int:
         row["gap_to_first_row"] = {n: _gap(results[n], first[n]) for n in results}
         rows.append(row)
         print(json.dumps(row), flush=True)
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/attn_sweep.json", "w") as f:
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "w") as f:
         json.dump({"device": jax.devices()[0].device_kind, "rows": rows}, f,
                   indent=1)
     return 0
