@@ -14,7 +14,7 @@ G, Tk, Dv]`` with a value head size of its own (latent attention has
 192-wide queries and keys and 128-wide values); the output is ``[B, H, Tq,
 Dv]``. ``H / G`` consecutive query heads read one key-value head (grouped
 queries; ``G == H`` is every head its own): the kernels' index maps name the
-shared head, nothing is repeated in HBM, and the dk/dv kernel sums a
+shared head, nothing is repeated in HBM, and the backward kernel sums a
 key-value head's gradient over its group in VMEM. Causal masking uses GLOBAL
 positions ``q_offset + i >= k_offset + j`` so the same code serves
 single-device attention (offsets 0) and ring steps (offsets are shard
@@ -50,10 +50,13 @@ T 4,096 where whole 1024-tiles execute 62.5%). A tile no larger than the
 sub-tile (every caller at the default 128) is one sub-tile.
 
 :func:`attention` on the Pallas path is the fused kernel forward AND
-backward (a dq kernel and a dk/dv kernel that rebuild each score tile from
-the saved log-sum-exp): no ``[Tq, Tk]`` tensor reaches HBM in either pass.
-The partial form keeps its recompute-by-XLA backward (ring steps are short
-chunks).
+backward: ONE backward call rebuilds each live score tile once from the
+saved log-sum-exp, and dq, dk and dv all leave it (a key tile stays put, dk
+and dv leave with it, a key-value head's whole dq waits in VMEM for the
+other key tiles: :func:`_bwd_vmem`; a sequence too long for that takes a
+dk/dv and a dq kernel, each rebuilding the tile): no ``[Tq, Tk]`` tensor
+reaches HBM in either pass. The partial form keeps its recompute-by-XLA
+backward (ring steps are short chunks).
 """
 
 from __future__ import annotations
@@ -257,7 +260,7 @@ def _band_offsets(tiling: _Tiling):
     return range(first, tiling.window + tiling.block_k - 1, step)
 
 
-def _tile_spans(d, tiling: _Tiling, over_queries=False):
+def _tile_spans(d, tiling: _Tiling, over_queries=False, no_offsets=False):
     """What a kernel computes of ONE ``block_q x block_k`` tile whose first
     query lies ``d`` after its first key -> ``[(when, [(rows, keys, masked),
     ...])]``: groups of spans (static slices of the tile), each group under
@@ -265,15 +268,21 @@ def _tile_spans(d, tiling: _Tiling, over_queries=False):
     program. A tile wholly under the diagonal is one span without the mask.
     In a tile the diagonal crosses, each block of ``sub_q`` queries takes
     the keys up to its last live ``sub_k`` block in one span (forward and
-    dq, which accumulate by query), or with ``over_queries`` each block of
-    ``sub_k`` keys the queries from its first live ``sub_q`` block on
-    (dk/dv, which accumulate by key): a sub-tile wholly above the diagonal
-    is in no span. On the chip (PERF.md, PR 27) a loop over sub-tiles with
-    traced bounds ran 1.1 to 2.6 times SLOWER than the tile computed whole,
-    and each span under a condition of its own won a third of what the
-    spans of ``d == 0``, known here, win as one group: that is the tile on
-    the diagonal of every call without offsets or with offsets a multiple
-    of the tile; any other crossed tile takes its spans one by one."""
+    the dq kernel, whose key tiles stream), or with ``over_queries`` each
+    block of ``sub_k`` keys the queries from its first live ``sub_q`` block
+    on (the backward, whose query tiles do): a sub-tile wholly above the
+    diagonal is in no span. On the chip (PERF.md, PR 27) a loop over
+    sub-tiles with traced bounds ran 1.1 to 2.6 times SLOWER than the tile
+    computed whole, and each span under a condition of its own won a third
+    of what the spans of ``d == 0``, known here, win as one group: that is
+    the tile on the diagonal of every call without offsets or with offsets
+    a multiple of the tile; any other crossed tile takes its spans one by
+    one. ``no_offsets``: the kernel knows its call has none (the backward),
+    so with equal tiles ``d`` is a multiple of the tile, no other tile is
+    crossed, and the one-by-one groups are not built at all: 5 span bodies
+    in the kernel's program where 21 stood (the one backward call at
+    256-wide keys read 27.1 ms with them and 6.5 without: PERF.md, PR
+    34)."""
     causal, block_q, block_k, sub_q, sub_k, window = tiling
     whole = slice(0, block_q), slice(0, block_k)
     if not causal:
@@ -313,6 +322,8 @@ def _tile_spans(d, tiling: _Tiling, over_queries=False):
     groups.append((crossed & (d == 0), [(rows, keys, True)
                                         for when, rows, keys in spans(0)
                                         if when]))
+    if no_offsets and block_q == block_k:
+        return groups
     return groups + [(crossed & (d != 0) & when, [(rows, keys, True)])
                      for when, rows, keys in spans(d)]
 
@@ -326,7 +337,7 @@ def _last_live_key_block(kb, d, block_q, block_k, nk):
 
 
 def _first_live_query_block(qb, d, block_q, block_k, nq):
-    """The same for the dk/dv kernel's query-side inputs: its dead steps
+    """The same for the backward kernel's query-side inputs: its dead steps
     come first in the sweep and name the first live query block."""
     _, (live, _) = _causal_ranges(d, block_q, block_k, nq, over_queries=True)
     return jnp.maximum(qb, jnp.minimum(live, nq - 1))
@@ -400,16 +411,21 @@ def _pad_qk(q, k):
     return jnp.pad(q, pad), jnp.pad(k, pad)
 
 
-def _scores(q, kblk, q_start, k_start, *, masked, sm_scale, window=None):
+def _scores(q, kblk, q_start, k_start, *, masked, sm_scale, window=None,
+            by_key=False):
     """float32 scores of one span, NEG_INF where ``masked`` says so: the key
     lies ahead of the query (``_MASK_DIAGONAL``, which ``True`` is), or
-    ``window`` or more behind it (``_MASK_EDGE``)."""
+    ``window`` or more behind it (``_MASK_EDGE``). ``[queries, keys]``, or
+    with ``by_key`` the same tile transposed, ``k q^T``."""
+    a, b = (kblk, q) if by_key else (q, kblk)
     s = jax.lax.dot_general(
-        q, kblk, (((1,), (1,)), ((), ())),
+        a, b, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * sm_scale
     if masked:
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                  int(by_key))
+        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                  int(not by_key))
         keep = qpos >= kpos if masked & _MASK_DIAGONAL else None
         if masked & _MASK_EDGE:
             near = qpos - kpos < window
@@ -418,11 +434,11 @@ def _scores(q, kblk, q_start, k_start, *, masked, sm_scale, window=None):
     return s
 
 
-def _run_spans(update, d, tiling, over_queries=False):
+def _run_spans(update, d, tiling, over_queries=False, no_offsets=False):
     """``update(rows, keys, masked)`` for each span of the tile that is due."""
     import jax.experimental.pallas as pl
 
-    for when, spans in _tile_spans(d, tiling, over_queries):
+    for when, spans in _tile_spans(d, tiling, over_queries, no_offsets):
         @pl.when(when)
         def _group(spans=spans):
             for span in spans:
@@ -586,74 +602,124 @@ def _pallas_block_partial(q, k, v, q_offset, k_offset, causal, sm_scale,
 
 # ---------------------------------------------------------------------------
 # Fused backward (full attention, offsets 0): scores are rebuilt span by span
-# from the saved log-sum-exp, so neither pass holds a [Tq, Tk] tensor.
+# from the saved log-sum-exp, so neither pass holds a [Tq, Tk] tensor, and
+# each live span is rebuilt ONCE: dq, dk and dv all take their update from
+# the one (p, ds) built there.
 # ---------------------------------------------------------------------------
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
 
 def _bwd_span(q, kblk, vblk, do, lse, delta, q_start, k_start, *,
               masked, sm_scale, window=None):
-    """-> (p, ds) of one span, float32."""
+    """-> (p, ds) of one span, float32 and TRANSPOSED, ``[keys, queries]``
+    (``lse``, ``delta`` ``[1, queries]``): dk and dv take them as they are
+    (``ds @ q``, ``p @ do``), so no ``[queries, keys]`` tile is turned for
+    them, and only dq contracts over the leading dimension."""
     s = _scores(q, kblk, q_start, k_start, masked=masked, sm_scale=sm_scale,
-                window=window)
+                window=window, by_key=True)
     p = jnp.exp(s - lse)                       # masked: exp(-1e30) == 0
-    dp = jax.lax.dot_general(
-        do, vblk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    dp = _dot(vblk, do, ((1,), (1,)))
     return p, p * (dp - delta) * sm_scale
 
 
-def _dkv_step(step, sweep: int, group: int):
-    """Step ``step`` of the dk/dv kernel's sweep -> ``(head, i)``: which of
-    the key-value head's ``group`` query heads, and the step of that head's
-    own sweep over the query blocks (``sweep`` steps a head, one head after
-    another)."""
-    if group == 1:
-        return 0, step
-    return jax.lax.div(step, sweep), jax.lax.rem(step, sweep)
+def _lane_tiles(d: int):
+    """The columns of a ``d``-wide operand in 128-lane tiles. dk and dq
+    leave their products a tile of columns at a time: at keys of 256 (192
+    padded) the one backward call with 256-wide products took 27.2 ms where
+    6.6 does the same work (v5e, sub-tiles of 256; any two of its three
+    accumulations, or sub-tiles of 512, read 5.2 - 6.9: PERF.md, PR 34)."""
+    return [slice(i, min(i + 128, d)) for i in range(0, d, 128)]
 
 
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dk_s, dv_s, *, sm_scale, nq, sweep,
-                      group, tiling):
-    """Grid (batch*kv heads, k_block, sweep step), the sweep sequential: one
-    K/V tile stays put while the query tiles of each of its ``group`` query
-    heads stream past it, and their sum is its gradient."""
+#: VMEM the one-call backward may ask for (a v5e core has 128 MiB, the
+#: scoped default is 16), and what of it is kept for everything but ``dq``:
+#: the double-buffered tiles, the dk / dv accumulators and a span's
+#: temporaries, which fit the 16 MiB default in the two-kernel form.
+_BWD_VMEM_LIMIT, _BWD_VMEM_REST = 100 << 20, 32 << 20
+
+
+def _bwd_vmem(t: int, d: int, dv: int, group: int,
+              itemsize: int = 2) -> Optional[int]:
+    """The form the backward of ``group`` query heads a key-value head takes
+    at ``t`` queries of (padded) size ``d`` and values of ``dv``, from shapes
+    alone -> the VMEM limit of the ONE call that keeps a key-value head's
+    whole ``dq`` in VMEM (float32 scratch, plus the output block it is cast
+    into, which the pipeline holds twice), or None where that does not fit
+    and dk/dv and dq are two kernels, each rebuilding the scores. ``dv``
+    sizes nothing that grows with ``t``: dk and dv leave a key block at a
+    time. Two sequences of 4,096: 40 MiB at 32 equal heads of 256, 56 at 6
+    heads of 128 a group, 64 at 8; at 8 heads of 128 a group the two-kernel
+    form starts over 8,704 positions, at equal heads of 256 over 34,816."""
+    del dv
+    need = group * t * d * (4 + 2 * itemsize) + _BWD_VMEM_REST
+    return need if need <= _BWD_VMEM_LIMIT else None
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dk_ref, dv_ref, *rest, sm_scale, nq, nk, sweep, group,
+                      tiling):
+    """Grid (batch*kv heads, k_block, head of the group, sweep step), the
+    last two sequential: one K/V tile stays put while the query tiles of
+    each of its ``group`` query heads stream past it, and their sum is its
+    gradient. ``rest`` is ``(dq_ref, dk_s, dv_s, dq_s)``: the key blocks are
+    sequential too, and ``dq_s [group * nq, block_q, d]`` keeps the whole
+    ``dq`` of the key-value head's query heads in float32 from its first
+    key block to its last, when it leaves through ``dq_ref`` (whose block a
+    key-value head's steps all name). Without the two (``(dk_s, dv_s)``: a
+    sequence whose ``dq`` does not fit) this is the dk/dv kernel alone."""
     import jax.experimental.pallas as pl
 
-    kb = pl.program_id(1)
-    step = pl.program_id(2)
+    dq_ref, dk_s, dv_s, dq_s = rest if len(rest) == 4 else (None, *rest, None)
+    kb, head, step = (pl.program_id(i) for i in (1, 2, 3))
+    first = (head == 0) & (step == 0)
+    last = (head == group - 1) & (step == sweep - 1)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    _, qb = _dkv_step(step, sweep, group)
+    if dq_s is not None:
+        @pl.when(first & (kb == 0))
+        def _init_dq():
+            dq_s[...] = jnp.zeros_like(dq_s)
+
+    qb = at = step
     if tiling.window is not None:
-        qb, _ = _band_block(qb, -kb * tiling.block_k, tiling, nq,
-                            over_queries=True)
+        qb, at = _band_block(step, -kb * tiling.block_k, tiling, nq,
+                             over_queries=True)
     q_start, k_start = qb * tiling.block_q, kb * tiling.block_k
 
     def update(rows, keys, masked):
         q, do = q_ref[0, rows, :], do_ref[0, rows, :]
         p, ds = _bwd_span(q, k_ref[0, keys, :], v_ref[0, keys, :], do,
-                          lse_ref[0, rows, :][:, :1],
-                          delta_ref[0, rows, :][:, :1],
+                          lse_ref[0, :1, rows], delta_ref[0, :1, rows],
                           q_start + rows.start, k_start + keys.start,
                           masked=masked, sm_scale=sm_scale,
                           window=tiling.window)
-        dv_s[keys, :] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_s[keys, :] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        ds = ds.astype(q.dtype)
+        dv_s[keys, :] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
+        for cols in _lane_tiles(q.shape[1]):
+            dk_s[keys, cols] += _dot(ds, q_ref[0, rows, cols], ((1,), (0,)))
+            if dq_s is not None:
+                dq_s[head * nq + at, rows, cols] += _dot(
+                    ds, k_ref[0, keys, cols], ((0,), (0,)))
 
-    _run_spans(update, q_start - k_start, tiling, over_queries=True)
+    _run_spans(update, q_start - k_start, tiling, over_queries=True,
+               no_offsets=True)
 
-    @pl.when(step == group * sweep - 1)
+    @pl.when(last)
     def _emit():
         dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+    if dq_s is not None:
+        @pl.when(last & (kb == nk - 1))
+        def _emit_dq():
+            dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -674,18 +740,17 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_start, k_start = qb * tiling.block_q, kb * tiling.block_k
 
     def update(rows, keys, masked):
-        kblk = k_ref[0, keys, :]
-        _, ds = _bwd_span(q_ref[0, rows, :], kblk, v_ref[0, keys, :],
-                          do_ref[0, rows, :], lse_ref[0, rows, :][:, :1],
-                          delta_ref[0, rows, :][:, :1],
+        _, ds = _bwd_span(q_ref[0, rows, :], k_ref[0, keys, :],
+                          v_ref[0, keys, :], do_ref[0, rows, :],
+                          lse_ref[0, :1, rows], delta_ref[0, :1, rows],
                           q_start + rows.start, k_start + keys.start,
                           masked=masked, sm_scale=sm_scale,
                           window=tiling.window)
-        dq_s[rows, :] += jax.lax.dot_general(
-            ds.astype(kblk.dtype), kblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        ds = ds.astype(q_ref.dtype)
+        for cols in _lane_tiles(q_ref.shape[2]):
+            dq_s[rows, cols] += _dot(ds, k_ref[0, keys, cols], ((0,), (0,)))
 
-    _run_spans(update, q_start - k_start, tiling)
+    _run_spans(update, q_start - k_start, tiling, no_offsets=True)
 
     @pl.when(step == sweep - 1)
     def _emit():
@@ -693,57 +758,88 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_in_specs(tiling: _Tiling, d, dv, q_of, k_of):
+    """q, k, v, do in tiles; lse and delta ``block_q`` positions along the
+    lanes (8 equal rows), under the query tile's index."""
     bq, bk = tiling.block_q, tiling.block_k
+
+    def row_of(*grid):
+        head, qb, _ = q_of(*grid)
+        return head, 0, qb
+
     return [_vmem_spec((1, bq, d), q_of), _vmem_spec((1, bk, d), k_of),
             _vmem_spec((1, bk, dv), k_of), _vmem_spec((1, bq, dv), q_of),
-            _vmem_spec((1, bq, 128), q_of), _vmem_spec((1, bq, 128), q_of)]
+            _vmem_spec((1, 8, bq), row_of), _vmem_spec((1, 8, bq), row_of)]
 
 
-@functools.partial(jax.jit, static_argnames=("tiling", "sm_scale", "interpret"))
-def _flash_dkv(q, k, v, do, lse, delta, *, tiling: _Tiling, sm_scale: float,
-               interpret: bool):
+@functools.partial(jax.jit, static_argnames=("tiling", "sm_scale", "interpret",
+                                             "vmem_limit"))
+def _flash_bwd(q, k, v, do, lse, delta, *, tiling: _Tiling, sm_scale: float,
+               interpret: bool, vmem_limit: Optional[int]):
     """``q, do [BH, T, .]``, ``k, v [BG, Tk, .]``, ``lse`` / ``delta``
-    row-broadcast over 128 lanes -> ``(dk, dv)`` in the shapes and dtypes of
-    ``k``, ``v``: a key-value head's gradient is summed over its ``H / G``
-    query heads inside the kernel."""
+    ``[BH, 8, T]`` (:func:`_bwd_operands`) -> ``(dq, dk, dv)`` in the
+    shapes and dtypes of ``q``, ``k``, ``v``: a key-value head's gradient
+    is summed over its ``H / G`` query heads inside the kernel.
+    ``vmem_limit`` is :func:`_bwd_vmem`'s word on the form: ONE kernel call
+    under that limit, or with None the same kernel for dk / dv alone and
+    :func:`_flash_dq`."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     (bh, tq, d), (bg, tk, _), dv = q.shape, k.shape, v.shape[2]
     bq, bk = tiling.block_q, tiling.block_k
-    nq, group = tq // bq, bh // bg
+    nq, nk, group = tq // bq, tk // bk, bh // bg
     sweep = (nq if tiling.window is None
-             else _band_sweep(tiling, tk // bk, nq, over_queries=True))
+             else _band_sweep(tiling, nk, nq, over_queries=True))
+    fused = vmem_limit is not None
 
-    def q_of(bg, kb, step):
-        head, qb = _dkv_step(step, sweep, group)
+    def q_of(bg, kb, head, qb):
         if tiling.window is not None:
             _, qb = _band_block(qb, -kb * bk, tiling, nq, over_queries=True)
         elif tiling.causal:
             qb = _first_live_query_block(qb, -kb * bk, bq, bk, nq)
-        return (bg * group + head if group > 1 else bg), qb, 0
+        return bg * group + head, qb, 0
 
-    def k_of(bg, kb, step):
+    def k_of(bg, kb, head, qb):
         return bg, kb, 0
 
-    return pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, sm_scale=sm_scale, nq=nq,
+    out_specs = [_vmem_spec((1, bk, d), k_of), _vmem_spec((1, bk, dv), k_of)]
+    out_shape = [jax.ShapeDtypeStruct((bg, tk, d), k.dtype),
+                 jax.ShapeDtypeStruct((bg, tk, dv), v.dtype)]
+    scratch = [pltpu.VMEM((bk, d), jnp.float32),
+               pltpu.VMEM((bk, dv), jnp.float32)]
+    if fused:
+        # dq as the kernel writes it: a key-value head's query heads and
+        # their query blocks in one block, [BH, T, D] as it lies in memory
+        out_specs.append(_vmem_spec((1, group * nq, bq, d),
+                                    lambda bg, *_: (bg, 0, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((bg, group * nq, bq, d),
+                                              q.dtype))
+        scratch.append(pltpu.VMEM((group * nq, bq, d), jnp.float32))
+    dk, dvv, *dq = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, sm_scale=sm_scale, nq=nq, nk=nk,
                           sweep=sweep, group=group, tiling=tiling),
-        grid=(bg, tk // bk, group * sweep),
+        grid=(bg, nk, group, sweep),
         in_specs=_bwd_in_specs(tiling, d, dv, q_of, k_of),
-        out_specs=[_vmem_spec((1, bk, d), k_of), _vmem_spec((1, bk, dv), k_of)],
-        out_shape=[jax.ShapeDtypeStruct((bg, tk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bg, tk, dv), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, dv), jnp.float32)],
-        compiler_params=_sweep_last(), interpret=interpret,
+        out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+        # dq's scratch runs through a key-value head's key blocks; without
+        # it they are as free as the heads
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary" if fused else
+                                 "parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
     )(q, k, v, do, lse, delta)
+    if fused:
+        return dq[0].reshape(q.shape), dk, dvv
+    return (_flash_dq(q, k, v, do, lse, delta, tiling=tiling,
+                      sm_scale=sm_scale, interpret=interpret), dk, dvv)
 
 
 @functools.partial(jax.jit, static_argnames=("tiling", "sm_scale", "interpret"))
 def _flash_dq(q, k, v, do, lse, delta, *, tiling: _Tiling, sm_scale: float,
               interpret: bool):
-    """The operands of :func:`_flash_dkv` -> ``dq`` in the dtype of ``q``."""
+    """The operands of :func:`_flash_bwd` -> ``dq`` in the dtype of ``q``:
+    the second kernel of a sequence whose ``dq`` does not fit VMEM."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -775,27 +871,37 @@ def _flash_dq(q, k, v, do, lse, delta, *, tiling: _Tiling, sm_scale: float,
     )(q, k, v, do, lse, delta)[0]
 
 
+def _bwd_operands(q, k, v, out, lse, do):
+    """-> the backward kernels' operands: heads folded into the batch,
+    ``do`` in the dtype of ``q``, ``lse`` and ``delta = sum(do * out)`` as
+    ``[BH, 8, T]``: the positions along the lanes, a head's row held 8
+    times (one float32 tile of sublanes; handed ``[BH, 1, T]`` XLA turned
+    the forward's whole ``[BH, T, 128]`` statistics to reach it, two
+    copies of 268 MB at 64 heads)."""
+    b, h, tq, d = q.shape
+    g, tk, dv = k.shape[1], k.shape[2], v.shape[3]
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+
+    def rows(a):            # [B,H,Tq] -> [BH, 8, Tq]
+        return jnp.broadcast_to(a.reshape(b * h, 1, tq), (b * h, 8, tq))
+
+    return (q.reshape(b * h, tq, d), k.reshape(b * g, tk, d),
+            v.reshape(b * g, tk, dv), do.astype(q.dtype).reshape(b * h, tq, dv),
+            rows(lse), rows(delta))
+
+
 def _pallas_flash_bwd(q, k, v, out, lse, do, causal, sm_scale,
                       block_q: int, block_k: int, interpret: bool,
                       window=None):
     """q, k already padded. -> (dq, dk, dv) in the inputs' shapes and
     dtypes."""
-    b, h, tq, d = q.shape
-    g, tk, dv = k.shape[1], k.shape[2], v.shape[3]
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-
-    def lanes(a):           # [B,H,Tq] -> row-broadcast over the 128 lanes
-        return jnp.broadcast_to(a.reshape(b * h, tq, 1), (b * h, tq, 128))
-
-    args = (q.reshape(b * h, tq, d), k.reshape(b * g, tk, d),
-            v.reshape(b * g, tk, dv), do.astype(q.dtype).reshape(b * h, tq, dv),
-            lanes(lse), lanes(delta))
-    common = dict(tiling=_tiling(causal, tq, tk, block_q, block_k, window),
-                  sm_scale=sm_scale, interpret=interpret)
-    dk, dvv = _flash_dkv(*args, **common)
-    dq = _flash_dq(*args, **common)
-    return (dq.reshape(b, h, tq, d), dk.reshape(b, g, tk, d),
-            dvv.reshape(b, g, tk, dv))
+    (_, h, tq, d), (_, g, tk, _) = q.shape, k.shape
+    dq, dk, dvv = _flash_bwd(
+        *_bwd_operands(q, k, v, out, lse, do),
+        tiling=_tiling(causal, tq, tk, block_q, block_k, window),
+        sm_scale=sm_scale, interpret=interpret,
+        vmem_limit=_bwd_vmem(tq, d, v.shape[3], h // g, q.dtype.itemsize))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dvv.reshape(v.shape)
 
 
 @functools.lru_cache(maxsize=None)

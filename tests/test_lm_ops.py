@@ -97,11 +97,16 @@ def _plain_attention(q, k, v):
 @pytest.mark.parametrize("impl,d,dv,t,block", [
     ("xla", 24, 16, 64, 32), ("pallas", 24, 16, 64, 32),
     ("xla", 192, 128, 128, 64), ("pallas", 192, 128, 128, 64),
-    ("pallas", 192, 128, 128, 128), ("pallas", 64, 64, 96, 32)])
+    ("pallas", 192, 128, 128, 128), ("pallas", 64, 64, 96, 32),
+    # the one backward call: dq summed over 3 and 4 key tiles, dk / dv over
+    # as many query tiles; a tile that is the whole sequence
+    ("pallas", 192, 128, 384, 128), ("pallas", 24, 16, 128, 32),
+    ("pallas", 24, 16, 64, 64)])
 def test_attention_with_value_size_of_its_own(impl, d, dv, t, block):
     """192-wide queries and keys (padded to 256 for the kernels), 128-wide
     values: forward and all three gradients against the plain softmax; the
-    Pallas path (interpreted here) runs its own backward kernels."""
+    Pallas path (interpreted here) runs its own backward kernel, from
+    which dq, dk and dv all leave."""
     ks = jax.random.split(jax.random.key(d + dv + t), 4)
     q, k = (jax.random.normal(ks[i], (2, 2, t, d)) for i in (0, 1))
     v, c = (jax.random.normal(ks[i], (2, 2, t, dv)) for i in (2, 3))
@@ -140,11 +145,19 @@ def _plain_band_attention(q, k, v, window=None):
     ("pallas", 2, 2, 64, 16, 5),        # under the sub-tile, equal heads
     ("pallas", 2, 1, 96, 32, 100),      # wider than the sequence: causal
     ("pallas", 6, 2, 64, 32, None),     # grouped heads without a window
-    ("pallas", 3, 1, 96, 32, 40), ("pallas", 2, 2, 128, 64, 16)])
+    ("pallas", 3, 1, 96, 32, 40), ("pallas", 2, 2, 128, 64, 16),
+    # the one backward call over three and four tiles: every head of a group
+    # keeps its own dq while the key tiles pass, dk / dv sum over the group
+    ("pallas", 6, 1, 96, 32, None), ("pallas", 8, 1, 128, 32, None),
+    ("pallas", 8, 1, 128, 32, 16),      # a window under the tile, 8 to one
+    ("pallas", 6, 1, 96, 32, 32),       # equal to the tile, 6 to one
+    ("pallas", 12, 2, 128, 32, 48),     # over the tile, 6 to one of two
+    ("pallas", 8, 2, 64, 64, None),     # a tile that is the whole sequence
+    ("pallas", 6, 1, 64, 64, 24)])      # and under a window
 def test_windowed_and_grouped_attention_against_the_plain_softmax(
         monkeypatch, impl, heads, kv, t, block, window):
     """Forward and all three gradients (a key-value head's summed over the
-    query heads that read it, inside the dk/dv kernel); the kernels
+    query heads that read it, inside the backward kernel); the kernels
     interpreted, in sub-tiles of 8 so that a tile has plain, crossed and
     dead sub-tiles on both edges of the band."""
     import importlib
@@ -169,6 +182,65 @@ def test_windowed_and_grouped_attention_against_the_plain_softmax(
     assert got[1][1].shape == k.shape and got[1][2].shape == v.shape
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("t,d,dv,group,limit", [
+    # the cells' three: kanana's and ling3's latent layers (32 equal heads,
+    # keys 192 padded to 256), laguna's full (6 a group) and window (8)
+    (4096, 256, 128, 1, 40 << 20), (4096, 128, 128, 6, 56 << 20),
+    (4096, 128, 128, 8, 64 << 20),
+    (8192, 128, 128, 8, 96 << 20),      # the last a group of 8 keeps whole
+    (16384, 128, 128, 8, None), (8192, 256, 128, 8, None),
+    (32768, 256, 128, 1, 96 << 20), (65536, 256, 128, 1, None),
+    (64, 16, 8, 6, (32 << 20) + 64 * 16 * 6 * 8)])
+def test_the_backwards_form_follows_from_shapes_alone(t, d, dv, group, limit):
+    """One call while a key-value head's dq (float32 scratch and the bf16
+    block it leaves through, held twice: 8 bytes an element) fits 100 MiB
+    less 32 for the rest; two kernels beyond. By hand."""
+    from fedml_tpu.ops.attention import _bwd_vmem
+
+    assert _bwd_vmem(t, d, dv, group) == limit
+    if limit is not None:
+        assert limit == group * t * d * 8 + (32 << 20) <= 100 << 20
+    # float32 operands leave through a float32 block: 12 bytes an element
+    assert _bwd_vmem(t, d, dv, group, itemsize=4) == (
+        None if group * t * d * 12 > 68 << 20
+        else group * t * d * 12 + (32 << 20))
+
+
+def test_a_dq_that_does_not_fit_takes_the_two_kernel_form(monkeypatch):
+    """The fallback of the shape function alone: with no room for dq the
+    same kernel gives dk / dv and the dq kernel runs beside it, to the same
+    gradients (6 query heads to one key-value head, a window over the tile,
+    four tiles)."""
+    import importlib
+
+    att = importlib.import_module("fedml_tpu.ops.attention")
+    ks = jax.random.split(jax.random.key(34), 4)
+    q = jax.random.normal(ks[0], (1, 6, 128, 16))
+    k = jax.random.normal(ks[1], (1, 1, 128, 16))
+    v, c = jax.random.normal(ks[2], (1, 1, 128, 8)), jax.random.normal(
+        ks[3], (1, 6, 128, 8))
+
+    def grads():
+        return jax.grad(lambda q, k, v: jnp.sum(attention(
+            q, k, v, impl="pallas", block_q=32, block_k=32, window=40,
+            interpret=True) * c), argnums=(0, 1, 2))(q, k, v)
+
+    def kernels():
+        # a function of its own each time: make_jaxpr keeps a trace too
+        return str(jax.make_jaxpr(lambda: grads())()).count("pallas_call")
+
+    one, n_one = grads(), kernels()
+    monkeypatch.setattr(att, "_BWD_VMEM_LIMIT", att._BWD_VMEM_REST)
+    att._flash_with_vjp.cache_clear()       # JAX keeps a traced backward
+    try:
+        two, n_two = grads(), kernels()
+    finally:
+        att._flash_with_vjp.cache_clear()
+    assert (n_one, n_two) == (2, 3)
+    for a, b in zip(one, two):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("tq,bq,bk,sq,sk,window", [

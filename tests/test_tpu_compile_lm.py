@@ -41,7 +41,8 @@ def no_cache():
 def test_latent_attention_kernels_compile_at_published_widths(one_chip,
                                                               no_cache, block):
     """32 heads, 4,096 positions, 192-wide queries and keys (padded to 256),
-    128-wide values, bf16: forward, dq and dk/dv kernels."""
+    128-wide values, bf16: the forward kernel and the one backward kernel
+    that dq, dk and dv all leave."""
     from fedml_tpu.ops.attention import attention
 
     def step(q, k, v, c):
@@ -55,7 +56,7 @@ def test_latent_attention_kernels_compile_at_published_widths(one_chip,
 
     compiled = jax.jit(step).lower(sd(192), sd(192), sd(128), sd(128)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") == 2
     # no [T, T] score tensor: the program's temporaries stay under 1 GB
     # (one head's float32 scores alone would be 67 MB, all 64 4.3 GB)
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
@@ -67,7 +68,8 @@ def test_grouped_and_windowed_kernels_compile_at_published_widths(
         one_chip, no_cache, heads, window, block):
     """64 query heads over 8 key-value heads under a window of 512, and 48
     over 8 without one, 4,096 positions, heads of 128, bf16, two sequences:
-    forward, dq and the dk/dv kernel that sums over its group. Under the
+    forward and the one backward kernel, which sums dk / dv over its group
+    and keeps the group's dq in VMEM (16 MiB at 8 heads). Under the
     window the temporaries hold no score tensor either, and the grid sweeps
     2 key tiles a query tile where the causal kernels sweep 4."""
     import importlib
@@ -85,7 +87,7 @@ def test_grouped_and_windowed_kernels_compile_at_published_widths(
                                     sharding=one_chip)
 
     compiled = jax.jit(step).lower(sd(heads), sd(8), sd(8), sd(heads)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.as_text().count("tpu_custom_call") == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
     _dq, dk, dv = jax.eval_shape(step, sd(heads), sd(8), sd(8), sd(heads))
     assert dk.shape == dv.shape == (2, 8, 4096, 128)
@@ -94,6 +96,31 @@ def test_grouped_and_windowed_kernels_compile_at_published_widths(
         n = 4096 // block
         assert att._band_sweep(tiling, n, n) == 2
         assert att._band_sweep(tiling, n, n, over_queries=True) == 2
+
+
+@pytest.mark.parametrize("t,kernels", [(8192, 2), (16384, 3)])
+def test_backward_form_compiles_on_both_sides_of_the_vmem_budget(
+        one_chip, no_cache, t, kernels):
+    """8 query heads of 128 over one key-value head under a window of 512,
+    one sequence: at 8,192 positions the one backward call keeps 32 MiB of
+    dq and asks for 96 MiB of VMEM, the most the shape function grants; at
+    16,384 dq does not fit and dk/dv and dq are two kernels."""
+    import importlib
+
+    att = importlib.import_module("fedml_tpu.ops.attention")
+    assert (att._bwd_vmem(t, 128, 128, 8) is None) == (kernels == 3)
+
+    def step(q, k, v, c):
+        return jax.grad(lambda q, k, v: jnp.sum(att.attention(
+            q, k, v, impl="pallas", block_q=1024, block_k=1024,
+            window=512).astype(jnp.float32) * c), argnums=(0, 1, 2))(q, k, v)
+
+    def sd(h):
+        return jax.ShapeDtypeStruct((1, h, t, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(step).lower(sd(8), sd(1), sd(1), sd(8)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == kernels
 
 
 @pytest.mark.parametrize("rows", [8192 * 6, 6144])

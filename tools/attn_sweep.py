@@ -1,19 +1,23 @@
 #!/usr/bin/env python3
-"""attn_sweep: ``ops/attention.py``'s three kernels alone on the chip, at the
-LM cell's shapes, over DMA tiles and compute sub-tiles.
+"""attn_sweep: ``ops/attention.py``'s kernels alone on the chip, at the LM
+cell's shapes, over DMA tiles and compute sub-tiles.
 
 The benchmark reads the kernels through a whole round (``attn_ms``, by
 scope, with XLA's passes around them). This tool times each kernel by
-itself: forward (``_pallas_block_partial``), dk/dv and dq (the two calls of
-``_pallas_flash_bwd``, each jitted alone so that XLA drops the other), at
-``[2, 32, 4096]`` with 192-wide keys and 128-wide values in bf16, causal.
-One row per ``tile:sub_q:sub_k``: wall-clock ms a call over ``--iters``
-calls, the device time of the heaviest operation in a traced call (the
-kernel without XLA's passes around it), the share of the score area the row
-executes, its MXU share on that executed work, the largest difference from
-the first row's results, and ``attention`` forward and gradients against
-the XLA path at float32 ``highest`` on two heads. The sub-tile is the
-module's constant, set here for a row; nothing else selects it.
+itself: forward (``_pallas_block_partial``), the backward as the shape takes
+it (``bwd``: ``_pallas_flash_bwd``, ONE call from which dq, dk and dv all
+leave while a key-value head's dq fits VMEM; ``bwd_form`` says which form
+``_bwd_vmem`` gave the shape) and, beside it, the two-kernel form that a
+longer sequence falls back to (``dkv`` and ``dq``: ``_flash_bwd`` without a
+VMEM limit, each jitted alone so that XLA drops the other), at ``[2, 32,
+4096]`` with 192-wide keys and 128-wide values in bf16, causal. One row per
+``tile:sub_q:sub_k``: wall-clock ms a call over ``--iters`` calls, the
+device time of the heaviest operation in a traced call (the kernel without
+XLA's passes around it), the share of the score area the row executes, its
+MXU share on that executed work, the largest difference from the first
+row's results, and ``attention`` forward and gradients against the XLA path
+at float32 ``highest`` on two heads. The sub-tile is the module's constant,
+set here for a row; nothing else selects it.
 
     python tools/attn_sweep.py 1024:1024:1024 1024:256:256 1024:128:128
 
@@ -44,8 +48,9 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
 
 #: matmul passes over the executed score area, (at the key width, at the
-#: value width): forward s | pv; dk/dv s, dk | dp, dv; dq s, dq | dp
-PASSES = {"fwd": (1, 1), "dkv": (2, 2), "dq": (2, 1)}
+#: value width): forward s | pv; the one backward call s, dk, dq | dp, dv;
+#: of the two-kernel form dk/dv s, dk | dp, dv and dq s, dq | dp
+PASSES = {"fwd": (1, 1), "bwd": (3, 2), "dkv": (2, 2), "dq": (2, 1)}
 
 
 @functools.cache
@@ -83,7 +88,7 @@ def _device_ms(fn, args, calls: int = 3) -> dict:
 
 def measure(shape, d, dv, tile, sub_q, sub_k, iters, interpret=False,
             trace=True, kv_heads=None, window=None, repeat_kv=False):
-    """-> (row, results): one configuration's three kernels."""
+    """-> (row, results): one configuration's kernels."""
     import jax
     import jax.numpy as jnp
 
@@ -116,21 +121,35 @@ def measure(shape, d, dv, tile, sub_q, sub_k, iters, interpret=False,
 
     out, lse = jax.jit(fwd)(q, k, v)
 
-    def bwd(q, k, v, out, lse, do):
-        dq, dk, dv_ = att._pallas_flash_bwd(
-            q, spread(k), spread(v), out, lse, do, True, scale, tile, tile,
-            interpret, window)
+    def bwd(q, k, v, out, lse, do, two_kernels=False):
+        k, v = spread(k), spread(v)
+        if two_kernels:
+            dq, dk, dv_ = att._flash_bwd(
+                *att._bwd_operands(q, k, v, out, lse, do),
+                tiling=att._tiling(True, t, t, tile, tile, window),
+                sm_scale=scale, interpret=interpret, vmem_limit=None)
+            dq, dk, dv_ = (x.reshape(like.shape)
+                           for x, like in ((dq, q), (dk, k), (dv_, v)))
+        else:
+            dq, dk, dv_ = att._pallas_flash_bwd(
+                q, k, v, out, lse, do, True, scale, tile, tile, interpret,
+                window)
         return dq, gathered(dk), gathered(dv_)
 
+    res = (q, k, v, out, lse, do)
     fns = {
         "fwd": (jax.jit(partial), (q, k, v)),
-        "dkv": (jax.jit(lambda *a: bwd(*a)[1:]), (q, k, v, out, lse, do)),
-        "dq": (jax.jit(lambda *a: bwd(*a)[0]), (q, k, v, out, lse, do)),
+        "bwd": (jax.jit(bwd), res),
+        "dkv": (jax.jit(lambda *a: bwd(*a, two_kernels=True)[1:]), res),
+        "dq": (jax.jit(lambda *a: bwd(*a, two_kernels=True)[0]), res),
     }
+    vmem = att._bwd_vmem(t, q.shape[-1], dv, 1 if repeat_kv else h // g)
     share = att.executed_score_share(t, t, tile, tile, sub_q, sub_k,
                                      window=window)
     row = {"tile": tile, "sub_q": sub_q, "sub_k": sub_k, "heads": h,
            "kv_heads": g, "window": window, "repeat_kv": repeat_kv,
+           "bwd_form": ("two kernels" if vmem is None else
+                        f"one call, {vmem >> 20} MiB of VMEM"),
            "executed_score_share": share}
     results = {}
     for name, (fn, args) in fns.items():
