@@ -20,7 +20,7 @@ import logging
 import time
 import warnings
 from collections import deque
-from functools import partial
+from functools import partial, wraps
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -36,7 +36,9 @@ from fedml_tpu.models import COUNTERS, ModelBundle, create_model
 from fedml_tpu.obs.tracer import (SCOPE_AGGREGATE, SCOPE_PROLOGUE,
                                   SCOPE_SERVER, SPAN_ENQUEUE, SPAN_H2D,
                                   SPAN_MATERIALIZE, SPAN_PLAN, SPAN_ROUND,
-                                  SPAN_WAIT_INPUTS, span)
+                                  SPAN_SETUP_API, SPAN_SETUP_LOCAL_TRAIN,
+                                  SPAN_SETUP_PLACE, SPAN_WAIT_INPUTS,
+                                  setup_span, span)
 from fedml_tpu.parallel.local import (
     LocalResult,
     finalize_metrics,
@@ -83,6 +85,26 @@ PATH_MESH_SHARDED = "mesh_sharded"    # sharded over the mesh, vmap a device
 _PACKED_PATHS = (PATH_PACKED, PATH_STREAM_PACKED, PATH_MESH_PACKED)
 
 
+def _setup_api_span(init):
+    """A round driver's ``__init__`` under a ``fedml/setup/api`` set-up span
+    (``api=<class>``). A subclass constructor that wraps itself nests the
+    base's span inside its own: readers take the outermost."""
+
+    @wraps(init)
+    def wrapped(self, *args, **kw):
+        with setup_span(SPAN_SETUP_API, api=type(self).__name__):
+            init(self, *args, **kw)
+
+    return wrapped
+
+
+def _placed(span_, arrays):
+    """``arrays`` (a placement's result, or None) with its bytes put on the
+    ``fedml/setup/place_data`` span that holds the call."""
+    span_.set("bytes", sum(int(a.nbytes) for a in jax.tree.leaves(arrays)))
+    return arrays
+
+
 class RoundPlan(NamedTuple):
     """What one round trains on and which program runs it: ``run_round``
     executes exactly this record and ``round_counts`` reports it."""
@@ -103,6 +125,7 @@ class FedAvgAPI:
     #: subclasses that shard round inputs themselves (cross-silo) opt out
     supports_device_data: bool = True
 
+    @_setup_api_span
     def __init__(self, dataset: FedDataset, config: FedConfig, bundle: Optional[ModelBundle] = None):
         self.dataset = dataset
         self.config = config
@@ -116,9 +139,10 @@ class FedAvgAPI:
         self._client_active_version = 0
         self.root_key = seed_everything(config.seed)
         self.variables = self.bundle.init(self.root_key)
-        self._local_train = self.build_local_train()
-        self._eval = make_eval_fn(self.bundle, self.task)
-        self.server_state = self.init_server_state()
+        with setup_span(SPAN_SETUP_LOCAL_TRAIN):
+            self._local_train = self.build_local_train()
+            self._eval = make_eval_fn(self.bundle, self.task)
+            self.server_state = self.init_server_state()
         # the default (host-cohort) round program rides the same fedscope
         # compile telemetry + fedcost attribution hook as the packed and
         # gather programs — a vanilla run is not a blind spot.
@@ -131,7 +155,8 @@ class FedAvgAPI:
         self._round_step = timed_build(
             self._program_name("round_step"), ("default",),
             self.build_round_step)
-        self._dev_train = self._maybe_place_train_data()
+        with setup_span(SPAN_SETUP_PLACE) as placing:
+            self._dev_train = _placed(placing, self._maybe_place_train_data())
         self._gather_steps: dict[Optional[int], Callable] = {}
         self._packed_steps: dict[tuple, Callable] = {}
         # recently computed round plans (round_idx -> RoundPlan) —
@@ -433,8 +458,8 @@ class FedAvgAPI:
         Dict order is recency: hits re-insert, eviction pops the oldest.
 
         Builds route through fedscope compile telemetry (obs/compile): the
-        "compile" registry group counts hits/misses and the traced runs get
-        build + first-call spans keyed by the program's shape key."""
+        "compile" registry group counts hits/misses, and each build is two
+        fedml/round/build set-up spans keyed by the program's shape key."""
         from fedml_tpu.obs import record_cache_hit, timed_build
 
         # class-qualified like the __init__-built programs: a subclass's
@@ -1674,6 +1699,7 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
     handles_own_device_data = True  # _maybe_place_sharded honors the flag
     elastic_rounds_ok = True      # the psum path guards zero total weight
 
+    @_setup_api_span
     def __init__(self, dataset, config, bundle=None, mesh=None, **kw):
         from fedml_tpu.parallel.mesh import client_mesh
 
@@ -1713,7 +1739,9 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
                 PATH_MESH_PACKED, everyone, None, None, lanes,
                 self._lane_slots(lanes))
         else:
-            self._dev_sharded = self._maybe_place_sharded(cohort)
+            with setup_span(SPAN_SETUP_PLACE) as placing:
+                self._dev_sharded = _placed(
+                    placing, self._maybe_place_sharded(cohort))
             if self._dev_sharded is not None:
                 self._static_plan = RoundPlan(
                     PATH_MESH_SHARDED, everyone, None, None, None,
@@ -1777,10 +1805,13 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
         n_pad = int(ds.train_x.shape[1])
         from fedml_tpu.parallel.packed import plan_arrays_tuple
 
-        data = shard_client_batch(self.mesh, (
-            x[perm], np.asarray(ds.train_y)[perm],
-            np.asarray(ds.train_mask)[perm]))
-        plan_arrays = shard_client_batch(self.mesh, plan_arrays_tuple(plan))
+        with setup_span(SPAN_SETUP_PLACE) as placing:
+            data = shard_client_batch(self.mesh, (
+                x[perm], np.asarray(ds.train_y)[perm],
+                np.asarray(ds.train_mask)[perm]))
+            plan_arrays = shard_client_batch(self.mesh,
+                                             plan_arrays_tuple(plan))
+            _placed(placing, (data, plan_arrays))
         from fedml_tpu.obs import timed_build
 
         # fedscope compile telemetry: the packed mesh program is the most

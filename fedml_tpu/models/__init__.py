@@ -15,6 +15,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from fedml_tpu.obs.tracer import SPAN_SETUP_INIT, setup_span
+
 _REGISTRY: dict[str, Callable[..., "ModelBundle"]] = {}
 
 
@@ -83,7 +85,9 @@ class ModelBundle:
         def init(r):
             return self.module.init({"params": r}, x, train=False)
 
-        return (jax.jit(init) if self.init_shape is not None else init)(rng)
+        jitted = self.init_shape is not None
+        with setup_span(SPAN_SETUP_INIT, model=self.name, jitted=jitted):
+            return (jax.jit(init) if jitted else init)(rng)
 
     def apply_train(self, variables: dict, x: jax.Array, rng: jax.Array):
         rngs, kwargs = {}, {}

@@ -20,11 +20,23 @@ assumes (arXiv:2303.01778):
   (``jax.named_scope``) names. Trace
   context piggybacks on ``comm/message.py`` envelopes so send spans stitch
   to recv spans across ranks and transports by message id.
+  ``setup_span(name, **ids)`` is the same span for SET-UP (a round driver's
+  constructor and its parts, both intervals of every program build): it
+  also appends one record (name, start and end on ``time.perf_counter``,
+  the set-up span open on the same thread as parent, ``ids``) to the
+  always-on, bounded in-memory set-up log, ``setup_log()``, whether or not
+  any tracer is on. ``time.perf_counter`` is the clock of
+  ``benchmarks/run.py``'s ``Clock`` and of its window, so the log's readers
+  (``benchmarks/trace/setup_spans.py`` and the eight set-up metrics over
+  it; ``timed_build``'s counters) lay it beside the benchmark's own marks.
 - :mod:`fedml_tpu.obs.export` — Perfetto/Chrome ``trace_event`` JSON and
   JSONL exporters; ``tools/trace_report.py`` is the analyzer.
 - :mod:`fedml_tpu.obs.compile` (fedscope) — per-program compile telemetry:
-  LRU hit/miss counters plus build / first-call spans, so compile-vs-execute
-  time is a first-class, regression-testable metric.
+  LRU hit/miss counters plus one set-up span around each program's
+  construction and one around its first call, and the listener that files
+  the compiler's own lower / load events under the set-up span that caused
+  them, so compile-vs-execute time is a first-class, regression-testable
+  metric and set-up can be told by named part from inside the program.
 - :mod:`fedml_tpu.obs.device` (fedscope) — device-memory sampler at round
   boundaries; a "devices" counter lane in the Perfetto export without a
   separate ``--profile_dir`` profiler run.
@@ -108,6 +120,8 @@ from fedml_tpu.obs.tracer import (
     get_tracer,
     reset,
     set_process_index,
+    setup_log,
+    setup_span,
     span,
     span_sampled,
     trace_filename,
@@ -152,6 +166,8 @@ __all__ = [
     "reset",
     "sample_device_memory",
     "set_process_index",
+    "setup_log",
+    "setup_span",
     "span",
     "span_sampled",
     "timed_build",

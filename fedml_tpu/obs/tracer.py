@@ -56,6 +56,7 @@ Overhead contract (pinned by tests/test_trace.py):
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -123,6 +124,25 @@ SPAN_ENQUEUE = "fedml/round/enqueue"
 SPAN_WAIT_INPUTS = "fedml/round/wait_inputs"
 SPAN_MATERIALIZE = "fedml/prefetch/materialize"
 SPAN_H2D = "fedml/prefetch/h2d"
+
+# Set-up spans (:func:`setup_span`): the same host spans, and one record
+# each in the always-on set-up log (:class:`SetupLog`), on
+# ``time.perf_counter``. ``fedml/round/build`` above is one of them.
+#: a round driver's whole constructor (``api=<class>``)
+SPAN_SETUP_API = "fedml/setup/api"
+#: ``ModelBundle.init``: the model's variables from the root key
+SPAN_SETUP_INIT = "fedml/setup/init_variables"
+#: ``build_local_train``, ``make_eval_fn``, ``init_server_state``
+SPAN_SETUP_LOCAL_TRAIN = "fedml/setup/local_train"
+#: the client stack put on the device(s), host seconds inside the calls
+#: (``device_put`` returns before the copy ends); ``bytes`` put
+SPAN_SETUP_PLACE = "fedml/setup/place_data"
+#: the compiler's own events (``obs/compile.py``'s listener), children of the
+#: set-up span open on their thread: jaxpr -> MLIR of one program ...
+SPAN_BUILD_LOWER = "fedml/build/lower"
+#: ... and its backend compile, or the read of its executable from the
+#: persistent cache (``cache=hit|miss|none``); both carry JAX's ``fun_name``
+SPAN_BUILD_LOAD = "fedml/build/load"
 
 
 def _now_us() -> int:
@@ -639,6 +659,14 @@ def tracer_if_sampled(rank: int = 0, round_idx: int = 0) -> Optional[Tracer]:
     return get_tracer(rank)
 
 
+def _ring_span(tr: Tracer, name: str, ids: dict, ann) -> _Span:
+    """``name`` as the ring keeps it (``fedml/round/plan`` is cat ``round``,
+    name ``plan``), opened together with the profiler annotation ``ann``."""
+    parts = name.split("/")
+    parts = parts[1:] or parts
+    return _Span(tr, parts[-1], parts[0], ids or None, None, ann)
+
+
 def span(name: str, **ids):
     """THE round-path span: a context manager around one layer boundary of
     the round driver or the host data path (names: the ``SPAN_*`` table).
@@ -657,9 +685,138 @@ def span(name: str, **ids):
     tr = tracer_if_sampled(0, ids.get("round", 0))
     if tr is None:
         return ann
-    parts = name.split("/")
-    parts = parts[1:] or parts
-    return _Span(tr, parts[-1], parts[0], ids or None, None, ann)
+    return _ring_span(tr, name, ids, ann)
+
+
+# -- the set-up log ----------------------------------------------------------
+
+class SetupRecord:
+    """One interval of set-up on ``time.perf_counter``: a :func:`setup_span`
+    or one of the compiler's events. ``parent`` is the ``id`` of the record
+    that was open on the same thread when this one started, or None."""
+
+    __slots__ = ("id", "name", "t0", "t1", "parent", "thread", "ids")
+
+    def __init__(self, rec_id: int, name: str, ids: dict):
+        self.id = rec_id
+        self.name = name
+        self.t0 = self.t1 = 0.0
+        self.parent: Optional[int] = None
+        self.thread = threading.get_ident()
+        self.ids = ids
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def __repr__(self) -> str:
+        return (f"SetupRecord({self.id}, {self.name!r}, {self.seconds:.6f} s, "
+                f"parent={self.parent}, {self.ids!r})")
+
+
+class SetupLog:
+    """Bounded in-memory log of closed :class:`SetupRecord` s, kept whether
+    or not any tracer is on. Set-up's records number tens to a few hundred a
+    process and never one a round; past ``cap`` the oldest fall off and are
+    counted in ``dropped``."""
+
+    def __init__(self, cap: int = 4096):
+        self._records: deque = deque(maxlen=int(cap))
+        self._ids = itertools.count(1)
+        self._lock = threading.Lock()
+        self._tls = threading.local()
+        self.dropped = 0
+
+    def __len__(self) -> int:
+        return len(self._records)
+
+    def records(self) -> list:
+        """The closed records, oldest first (a copy)."""
+        with self._lock:
+            return list(self._records)
+
+    def open_stack(self) -> list:
+        """This thread's open set-up spans, outermost first (the list
+        itself: :class:`_SetupSpan` pushes and pops it)."""
+        s = getattr(self._tls, "stack", None)
+        if s is None:
+            s = self._tls.stack = []
+        return s
+
+    def new(self, name: str, ids: dict) -> SetupRecord:
+        """A record whose parent is the span open on this thread now; not
+        in the log until :meth:`close`."""
+        rec = SetupRecord(next(self._ids), name, ids)
+        stack = self.open_stack()
+        if stack:
+            rec.parent = stack[-1].id
+        return rec
+
+    def close(self, rec: SetupRecord) -> None:
+        with self._lock:
+            if len(self._records) == self._records.maxlen:
+                self.dropped += 1
+            self._records.append(rec)
+
+
+_SETUP_LOG = SetupLog()
+
+
+def setup_log() -> SetupLog:
+    """The process-wide set-up log (``obs.setup_span`` and the compile
+    listener of ``obs/compile.py`` write it; ``timed_build``'s counters and
+    ``benchmarks/trace/setup_spans.py`` read it)."""
+    return _SETUP_LOG
+
+
+class _SetupSpan:
+    __slots__ = ("_inner", "_name", "_ids", "rec")
+
+    def __init__(self, inner, name: str, ids: dict):
+        self._inner = inner
+        self._name = name
+        self._ids = ids
+        #: the span's record, from ``__enter__`` on
+        self.rec: Optional[SetupRecord] = None
+
+    def set(self, key, value) -> None:
+        """An id learnt inside the span (the bytes a placement put)."""
+        self._ids[key] = value
+        inner_set = getattr(self._inner, "set", None)
+        if inner_set is not None:
+            inner_set(key, value)
+
+    def __enter__(self):
+        rec = self.rec = _SETUP_LOG.new(self._name, self._ids)
+        _SETUP_LOG.open_stack().append(rec)
+        self._inner.__enter__()
+        rec.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        rec = self.rec
+        rec.t1 = time.perf_counter()
+        self._inner.__exit__(*exc)
+        stack = _SETUP_LOG.open_stack()
+        if stack and stack[-1] is rec:
+            stack.pop()
+        _SETUP_LOG.close(rec)
+        return False
+
+
+def setup_span(name: str, **ids) -> _SetupSpan:
+    """A span of SET-UP (names: the ``SPAN_SETUP_*`` table and
+    ``SPAN_BUILD``): what :func:`span` is (the profiler annotation, and under
+    ``--trace_dir`` ONE ring record, whatever the round sampling says: set-up
+    belongs to no round) plus one :class:`SetupRecord` in the always-on
+    :func:`setup_log`, with start and end on ``time.perf_counter`` and the
+    set-up span open on the same thread as its parent. ``.rec`` is the
+    record, ``.set(key, value)`` adds an id learnt inside. Never on a
+    round's steady path: a record a round would push set-up's off the log."""
+    ann = TraceAnnotation(name, **ids)
+    tr = tracer_if_enabled(0)
+    inner = ann if tr is None else _ring_span(tr, name, ids, ann)
+    return _SetupSpan(inner, name, dict(ids))
 
 
 def trace_filename(rank: int, process: int = 0) -> str:

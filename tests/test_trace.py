@@ -533,9 +533,16 @@ def test_traced_mesh_packed_run_bit_identical(tmp_path):
     assert all(e["args"]["path"] == "packed_mesh" and e.get("psid")
                for e in ms)
     # compile spans attribute the program builds (shape-keyed)
-    comp = [e for e in events if e.get("cat") == "compile"]
-    assert any(e["name"].endswith(":first_call") for e in comp)
-    assert all("shape_key" in e["args"] for e in comp)
+    # compile spans attribute the program builds (shape-keyed): ONE ring
+    # record a build interval (fedml/round/build), none of cat "compile"
+    comp = [e for e in events
+            if e.get("cat") == "round" and e.get("name") == "build"]
+    assert any(e["args"]["phase"] == "first_call" for e in comp)
+    assert all("shape_key" in e["args"] and "program" in e["args"]
+               for e in comp)
+    assert not [e for e in events if e.get("cat") == "compile"]
+    builds = [(e["args"]["program"], e["args"]["phase"]) for e in comp]
+    assert len(builds) == len(set(builds))
 
 
 def test_mesh_report_critical_path_compile_and_device_lane(tmp_path):
@@ -731,6 +738,249 @@ def test_timed_build_raising_first_call_retimed_not_recorded():
     after = g.get("first_call_ms", 0.0)
     step(5)
     assert g.get("first_call_ms", 0.0) == after
+
+
+# -- the set-up log: setup_span, the compile listener (ISSUE 35) -------------
+
+def _new_records(last_id):
+    return [r for r in obs.setup_log().records() if r.id > last_id]
+
+
+def _last_id():
+    return max((r.id for r in obs.setup_log().records()), default=0)
+
+
+def test_setup_spans_nest_on_their_own_thread_and_record_with_tracing_off():
+    """A set-up span's parent is the set-up span open on the SAME thread
+    when it started; another thread's spans start a tree of their own. The
+    records exist with every tracer off, on time.perf_counter."""
+    import threading
+
+    assert not obs.tracing_enabled()
+    last, t_before = _last_id(), time.perf_counter()
+    inside, done = threading.Event(), threading.Event()
+
+    def other_thread():
+        inside.wait(10)
+        with obs.setup_span("t35/other", who="thread"):
+            with obs.setup_span("t35/other/child"):
+                pass
+        done.set()
+
+    th = threading.Thread(target=other_thread)
+    th.start()
+    with obs.setup_span("t35/outer", api="X") as outer:
+        with obs.setup_span("t35/inner") as inner:
+            inside.set()
+            assert done.wait(10)
+            inner.set("bytes", 7)
+    th.join(10)
+    assert not th.is_alive()
+    recs = {r.name: r for r in _new_records(last)}
+    assert set(recs) == {"t35/outer", "t35/inner", "t35/other",
+                         "t35/other/child"}
+    assert recs["t35/outer"].parent is None and outer.rec is recs["t35/outer"]
+    assert recs["t35/inner"].parent == recs["t35/outer"].id
+    assert recs["t35/inner"].ids == {"bytes": 7}
+    assert recs["t35/outer"].ids == {"api": "X"}
+    # opened while t35/inner was open on the main thread: not its child
+    assert recs["t35/other"].parent is None
+    assert recs["t35/other/child"].parent == recs["t35/other"].id
+    assert recs["t35/other"].thread != recs["t35/outer"].thread
+    o, i = recs["t35/outer"], recs["t35/inner"]
+    assert t_before <= o.t0 <= i.t0 <= i.t1 <= o.t1 <= time.perf_counter()
+    assert o.seconds == o.t1 - o.t0 and not obs.setup_log().open_stack()
+
+
+def test_setup_log_is_capped_and_counts_what_falls_off():
+    log = tracer.SetupLog(cap=3)
+    for k in range(5):
+        log.close(log.new(f"r{k}", {}))
+    assert len(log) == 3 and log.dropped == 2
+    assert [r.name for r in log.records()] == ["r2", "r3", "r4"]
+    assert len({r.id for r in log.records()}) == 3
+    # the process's own log is bounded the same way
+    assert obs.setup_log()._records.maxlen == 4096
+
+
+def test_setup_span_is_one_ring_record_under_trace_dir(tmp_path):
+    """Under --trace_dir a set-up span lands in rank 0's ring ONCE, whatever
+    the round sampling says (set-up belongs to no round)."""
+    obs.configure(str(tmp_path), sample_rate=0.0)
+    with obs.setup_span(tracer.SPAN_SETUP_PLACE) as placing:
+        placing.set("bytes", 12)
+    events = [e for e in obs.get_tracer(0).drain() if e["ph"] == "X"]
+    assert [(e["cat"], e["name"], e["args"]) for e in events] == [
+        ("setup", "place_data", {"bytes": 12})]
+
+
+def test_a_compile_event_lands_under_the_open_span_on_the_logs_clock():
+    """The compiler's lower / load events become records of the set-up log:
+    child of the set-up span open on the thread, JAX's time.time() interval
+    moved onto perf_counter, fun_name kept, the persistent cache's verdict
+    since the thread's last load on the load. jaxpr_trace_duration is not
+    read (it fires for every inner jit inside the outer one's interval)."""
+    from jax import monitoring
+
+    lower = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    load = "/jax/core/compile/backend_compile_duration"
+    last = _last_id()
+    with obs.setup_span(tracer.SPAN_BUILD, program="t35", phase="first_call"
+                        ) as call:
+        now_wall, now_perf = time.time(), time.perf_counter()
+        monitoring.record_event_time_span(
+            "/jax/core/compile/jaxpr_trace_duration", now_wall - 0.9,
+            now_wall - 0.5, fun_name="jit(fed)")
+        monitoring.record_event_time_span(lower, now_wall - 0.45,
+                                          now_wall - 0.30, fun_name="jit(fed)")
+        monitoring.record_event("/jax/compilation_cache/cache_hits")
+        monitoring.record_event_time_span(load, now_wall - 0.25,
+                                          now_wall - 0.05, fun_name="jit(fed)")
+        monitoring.record_event_time_span(load, now_wall - 0.04,
+                                          now_wall - 0.01, fun_name="jit(two)")
+    monitoring.record_event("/jax/compilation_cache/cache_misses")
+    monitoring.record_event_time_span(load, time.time() - 0.5, time.time(),
+                                      fun_name="jit(callers)")
+    recs = {r.ids.get("fun_name", "span") + "/" + r.ids.get("cache", "")
+            : r for r in _new_records(last)}
+    assert set(recs) == {"span/", "jit(fed)/", "jit(fed)/hit", "jit(two)/none",
+                         "jit(callers)/miss"}
+    lo, hit = recs["jit(fed)/"], recs["jit(fed)/hit"]
+    assert lo.name == tracer.SPAN_BUILD_LOWER
+    assert hit.name == recs["jit(two)/none"].name == tracer.SPAN_BUILD_LOAD
+    for r in (lo, hit, recs["jit(two)/none"]):
+        assert r.parent == call.rec.id and "by" not in r.ids
+    assert recs["jit(callers)/miss"].parent is None
+    # the interval, moved from time.time() onto perf_counter
+    assert abs(hit.t0 - (now_perf - 0.25)) < 0.02
+    assert abs(hit.seconds - 0.20) < 1e-6 and abs(lo.seconds - 0.15) < 1e-6
+    assert call.rec.t0 - 0.5 < lo.t0 < hit.t0 < call.rec.t1
+
+
+def test_a_compile_the_programs_code_asked_for_names_its_asker():
+    """A real compile: an eager op of the package's own code is a program,
+    and its records say which function asked (`by`); the same op asked for
+    by the test says nothing."""
+    import jax.numpy as jnp
+
+    from fedml_tpu.core.pytree import tree_weighted_mean
+
+    last = _last_id()
+    stacked = {"w": jnp.ones((3, 35, 7), jnp.float32)}
+    jax.block_until_ready(tree_weighted_mean(stacked, jnp.ones((3,))))
+    mine = [r for r in _new_records(last) if r.name == tracer.SPAN_BUILD_LOAD]
+    asked = [r for r in mine if "by" in r.ids]
+    # the arrays the test itself made (jnp.ones) name no asker
+    assert asked and len(asked) < len(mine)
+    assert all(r.ids["by"].startswith("fedml_tpu.core.pytree:")
+               and r.ids["fun_name"].startswith("jit(") and r.parent is None
+               and r.ids["cache"] in ("hit", "miss", "none") for r in asked)
+
+
+def test_timed_build_spans_record_attempts_and_counters_record_successes():
+    """timed_build's ONE timing mechanism: each interval is a
+    fedml/round/build set-up record (phase, program, shape_key), the
+    counters are read off the records of the intervals that succeeded, in
+    aggregate and per program."""
+    from fedml_tpu.obs import compile_counters, timed_build
+
+    g = compile_counters()
+    before, last = g.as_dict(), _last_id()
+    with pytest.raises(ZeroDivisionError):
+        timed_build("t35_build", ("k", 1), lambda: 1 / 0)
+    calls = {"n": 0}
+
+    def fn(x):
+        calls["n"] += 1
+        if calls["n"] == 1:
+            raise ValueError("first call dies")
+        return x + 1
+
+    with pytest.raises(ZeroDivisionError):
+        timed_build("t35_build", ("k", 1), lambda: 1 / 0)
+    assert g.as_dict() == before
+    step = timed_build("t35_build", ("k", 1), lambda: fn)
+    with pytest.raises(ValueError):
+        step(1)
+    assert step(1) == 2 and step(2) == 3
+    recs = [r for r in _new_records(last) if r.name == tracer.SPAN_BUILD]
+    assert all(r.ids["program"] == "t35_build"
+               and r.ids["shape_key"] == "('k', 1)" for r in recs)
+    # attempts: three constructions (two raised), two first calls (one did)
+    assert [r.ids["phase"] for r in recs] == [
+        "construct", "construct", "construct", "first_call", "first_call"]
+    assert g["misses.t35_build"] == 1
+    assert g["build_ms.t35_build"] == pytest.approx(recs[2].seconds * 1e3)
+    assert g["first_call_ms.t35_build"] == pytest.approx(recs[4].seconds * 1e3)
+    assert g["first_call_ms"] == pytest.approx(
+        before.get("first_call_ms", 0.0) + recs[4].seconds * 1e3)
+    assert g["build_ms"] == pytest.approx(
+        before.get("build_ms", 0.0) + recs[2].seconds * 1e3)
+
+
+def test_the_constructors_name_their_parts_in_the_setup_log():
+    """fedml/setup/api around the whole constructor (a subclass's wraps its
+    base's), init_variables / local_train / place_data inside it with the
+    bytes put, every program's construction a fedml/round/build record."""
+    from fedml_tpu.algorithms.fedavg import CrossSiloFedAvgAPI
+    from fedml_tpu.models import create_model
+    from fedml_tpu.parallel.mesh import client_mesh
+
+    last = _last_id()
+    api = _packed_api()
+    recs = _new_records(last)
+    by_id = {r.id: r for r in recs}
+    (top,) = [r for r in recs if r.name == tracer.SPAN_SETUP_API]
+    assert top.ids == {"api": "FedAvgAPI"} and top.parent is None
+    parts = {r.name: r for r in recs if r.parent == top.id}
+    assert {tracer.SPAN_SETUP_INIT, tracer.SPAN_SETUP_LOCAL_TRAIN,
+            tracer.SPAN_SETUP_PLACE, tracer.SPAN_BUILD} <= set(parts)
+    assert parts[tracer.SPAN_SETUP_INIT].ids == {"model": "lr",
+                                                 "jitted": False}
+    placed = sum(int(a.nbytes) for a in api._dev_train)
+    assert parts[tracer.SPAN_SETUP_PLACE].ids == {"bytes": placed} and placed
+    assert parts[tracer.SPAN_BUILD].ids["phase"] == "construct"
+    assert sum(r.seconds for r in parts.values()) <= top.seconds
+    # every compile of the constructor is a descendant of its span
+    for r in recs:
+        if r.name in (tracer.SPAN_BUILD_LOWER, tracer.SPAN_BUILD_LOAD):
+            while r.parent in by_id:
+                r = by_id[r.parent]
+            assert r is top
+
+    last = _last_id()
+    ds = make_synthetic_classification(
+        "mesh-tr", (6,), 3, 4, records_per_client=8,
+        partition_method="homo", batch_size=4, seed=0)
+    CrossSiloFedAvgAPI(
+        ds, _mesh_cfg(None),
+        create_model("lr", ds.class_num, input_shape=ds.train_x.shape[2:]),
+        mesh=client_mesh(2))
+    recs = _new_records(last)
+    outer, inner = sorted((r for r in recs if r.name == tracer.SPAN_SETUP_API),
+                          key=lambda r: r.t0)
+    assert outer.parent is None and inner.parent == outer.id
+    assert outer.ids == inner.ids == {"api": "CrossSiloFedAvgAPI"}
+    mesh_parts = [r for r in recs if r.parent == outer.id]
+    assert [r.ids["bytes"] > 0 for r in mesh_parts
+            if r.name == tracer.SPAN_SETUP_PLACE] == [True]
+    assert [r.ids["program"] for r in mesh_parts
+            if r.name == tracer.SPAN_BUILD] == ["mesh_packed_round"]
+
+
+def test_steady_rounds_write_nothing_to_the_setup_log():
+    """Set-up's records never come one a round: after the rounds that build
+    the programs, 50 more leave the log as it was."""
+    api = _packed_api()
+    for r in range(2):
+        jax.block_until_ready(api.run_round(r))
+    last, size = _last_id(), len(obs.setup_log())
+    assert [r for r in obs.setup_log().records() if r.name == tracer.SPAN_BUILD
+            and r.ids["phase"] == "first_call"]
+    for r in range(2, 52):
+        loss = api.run_round(r)
+    jax.block_until_ready(loss)
+    assert _new_records(last) == [] and len(obs.setup_log()) == size
 
 
 # -- fedsketch: deterministic head-based span sampling (ISSUE 10) -----------
@@ -1061,8 +1311,11 @@ def test_profiler_trace_holds_the_round_spans_nested_and_changes_no_bit(tmp_path
     # the new program's build is a span of its own, inside round 0's enqueue
     # (construction, then the first call, which says how the lanes run)
     builds = [st for _s, _e, n, st in sorted(spans) if n == tracer.SPAN_BUILD]
-    assert builds == [{"program": "packed_step"},
-                      {"program": "packed_step", "lanes": 2, "lane_width": 2}]
+    key = builds[0]["shape_key"]
+    assert builds == [{"program": "packed_step", "phase": "construct",
+                       "shape_key": key},
+                      {"program": "packed_step", "phase": "first_call",
+                       "shape_key": key, "lanes": 2, "lane_width": 2}]
     assert len([1 for *_x, n, _st in spans if n != tracer.SPAN_BUILD]) <= 2 * 6
 
 
@@ -1123,8 +1376,11 @@ def test_build_span_and_counters_carry_the_lane_width(tmp_path):
     builds = [dict(ev.stats) for pl in ProfileData.from_file(path).planes
               for ln in pl.lines for ev in ln.events
               if ev.name == tracer.SPAN_BUILD]
-    ids = {"program": "packed_step", "lanes": 4, "lane_width": 2}
-    assert ids in builds and {"program": "packed_step"} in builds
+    key = builds[0]["shape_key"]
+    ids = {"program": "packed_step", "phase": "first_call", "shape_key": key,
+           "lanes": 4, "lane_width": 2}
+    assert ids in builds and {"program": "packed_step", "phase": "construct",
+                              "shape_key": key} in builds
     g = obs.compile_counters()
     assert g["lanes.packed_step"] == 4 and g["lane_width.packed_step"] == 2
 

@@ -17,7 +17,8 @@ local/grpc/mqtt transports AND the reliable/chaos middleware, retransmits
 collapsed onto their logical message — is one (send span, recv span) pair.
 Mesh (in-mesh cross-silo / gossip) rounds have no wire legs; their
 decomposition comes from the fedscope device spans instead: ``mesh_step``
-per-round device dispatch and ``compile``-category build/first-call spans.
+per-round device dispatch and the ``fedml/round/build`` set-up spans (cat
+``round``, name ``build``; ``program`` and ``phase=construct|first_call``).
 
 Report sections:
 - round timeline: wall-clock per round with per-rank presence,
@@ -186,8 +187,14 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
                     row["spans"] += 1
                     if _args(ev).get("path"):
                         row["path"] = _args(ev)["path"]
-            elif ev.get("cat") == "compile":
-                row = compile_spans.setdefault(name, {"count": 0, "ms": 0.0})
+            elif (name == "build" and ev.get("cat") == "round"
+                  and "program" in _args(ev)):
+                # obs/compile.timed_build's ONE ring record a build interval
+                a = _args(ev)
+                stage = {"construct": "build"}.get(a.get("phase"),
+                                                   a.get("phase"))
+                row = compile_spans.setdefault(
+                    f"{a['program']}:{stage}", {"count": 0, "ms": 0.0})
                 row["count"] += 1
                 row["ms"] += ev.get("dur", 0) / 1e3
         elif ph == "i":
