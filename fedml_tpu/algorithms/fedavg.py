@@ -526,16 +526,13 @@ class FedAvgAPI:
         block-diag dots stream n_lanes x the useful FLOPs and the per-lane
         form's grouped convs fold the same n_lanes clients (H4) — either
         way the program folds ``n_lanes`` clients per op."""
-        from fedml_tpu.parallel.packed import (impl_label, lane_vmap_width,
-                                               packed_conv_active)
+        from fedml_tpu.parallel.packed import impl_label, packed_conv_active
 
         n_lanes = int(n_lanes)
         joint = packed_conv_active(self.bundle, pconv,
                                    self.config.client_optimizer)
-        step.lane_ids = {
-            "lanes": n_lanes,
-            "lane_width": n_lanes if joint
-            else lane_vmap_width(self.variables, n_lanes)}
+        step.lane_ids = {"lanes": n_lanes,
+                         "lane_width": self._lane_width(n_lanes)}
         if cost_hints:
             step.cost_hints = {
                 "packed_conv": impl_label(pconv) if joint else "off",
@@ -588,13 +585,32 @@ class FedAvgAPI:
         return plan_packing(counts, c.batch_size, c.epochs, c.pack_lanes,
                             t_quantum=max(1, c.bucket_quantum_batches // 4))
 
-    def _lane_slots(self, lanes) -> int:
-        """Record slots one EPOCH of a lane plan executes: packed lanes run
-        T batch-steps each over the whole round; report one epoch's share,
-        rounded to nearest (exact at epochs=1, the bench recipe; off by <1
-        batch otherwise)."""
+    def _lane_width(self, n_lanes: int) -> int:
+        """How many of a device's ``n_lanes`` lanes its packed program
+        advances together: all of them in the joint form,
+        parallel/packed.lane_vmap_width's choice in the per-lane form. The
+        configured ``packed_conv`` says which as well as the resolved one
+        does: 'auto' resolves to 'off' only where the joint form cannot
+        apply or at one lane, whose width is 1 either way."""
+        from fedml_tpu.parallel.packed import (lane_vmap_width,
+                                               packed_conv_active)
+
         c = self.config
-        return round(lanes.executed_slots / max(c.epochs, 1)) * c.batch_size
+        if packed_conv_active(self.bundle, c.packed_conv, c.client_optimizer):
+            return n_lanes
+        return lane_vmap_width(self.variables, n_lanes)
+
+    def _lane_slots(self, lanes, devices: int = 1) -> int:
+        """Record slots one EPOCH of a lane plan executes: the lane-steps
+        its loops walk over the whole round (PackPlan.executed_slots at the
+        width the program runs the lanes of one of ``devices`` at: a chunk
+        of lanes stops at its last live step, not at T); report one epoch's
+        share, rounded to nearest (exact at epochs=1, the bench recipe; off
+        by <1 batch otherwise)."""
+        c = self.config
+        width = self._lane_width(lanes.n_lanes // devices)
+        return round(lanes.executed_slots(width, c.scan_unroll)
+                     / max(c.epochs, 1)) * c.batch_size
 
     def build_round_step_packed(self, shape_key: tuple):
         from fedml_tpu.parallel.crosssilo import apply_server_and_rollback
@@ -664,27 +680,25 @@ class FedAvgAPI:
 
     def _run_packed_round(self, round_idx: int, plan: RoundPlan):
         """Execute the round under the packed schedule. ``plan.live``
-        already folds the Silo client-active mask (_round_plan); exited
-        clients additionally get the STRUCTURAL lane freeze — their plan
-        steps masked dead (mask_plan_arrays) in the same compiled program,
-        never a vmap fallback."""
+        already folds the Silo client-active mask, and ``plan.lanes`` the
+        STRUCTURAL lane freeze of exited clients (_round_plan: their plan
+        steps masked dead in the same compiled program, never a vmap
+        fallback). The ``fedml/round/plan`` span says how many of the
+        plan's steps the program walks (``steps_run`` of
+        ``steps_planned``: chunks of lanes x steps)."""
+        from fedml_tpu.parallel.packed import plan_arrays_tuple
+
         sampled, live, lanes = plan.sampled, plan.live, plan.lanes
         rk = round_key(self.root_key, round_idx)
-        with span(SPAN_PLAN, round=round_idx):
+        width = self._lane_width(lanes.n_lanes)
+        with span(SPAN_PLAN, round=round_idx,
+                  steps_planned=lanes.n_lanes // width * lanes.T,
+                  steps_run=lanes.executed_slots(
+                      width, self.config.scan_unroll) // width):
             counts = np.asarray(self.dataset.train_counts, np.float32)[sampled]
             weights = (counts if live is None
                        else counts * np.asarray(live, np.float32))
-            active = self._client_active
-            if active is None:
-                from fedml_tpu.parallel.packed import plan_arrays_tuple
-
-                plan_arrays = plan_arrays_tuple(lanes)
-            else:
-                from fedml_tpu.parallel.packed import mask_plan_arrays
-
-                plan_arrays = mask_plan_arrays(
-                    lanes,
-                    np.asarray(active, np.float32)[sampled][lanes.member_pos])
+            plan_arrays = plan_arrays_tuple(lanes)
         key = lanes.shape_key
         step = self._lru_step(self._packed_steps, key,
                               lambda: self.build_round_step_packed(key),
@@ -762,7 +776,7 @@ class FedAvgAPI:
         early EXIT, algorithms/silo.py): a client whose entry is 0 stops
         contributing — its aggregation weight zeroes on every schedule,
         and the packed paths additionally freeze its lane span structurally
-        (parallel/packed.mask_plan_arrays) inside the SAME compiled
+        (parallel/packed.masked_plan) inside the SAME compiled
         program. ``active``: [num_clients] {0,1}-ish, or None to clear.
         Takes effect from the next round."""
         if active is None:
@@ -802,6 +816,12 @@ class FedAvgAPI:
             if lanes is None:
                 path = PATH_GATHER      # a cohort with no record to pack
             else:
+                if self._client_active is not None:
+                    # the plan the program is handed, and so the one counted
+                    from fedml_tpu.parallel.packed import masked_plan
+
+                    lanes = masked_plan(
+                        lanes, self._client_active[sampled][lanes.member_pos])
                 padded = self._lane_slots(lanes)
         elif path == PATH_STREAM_PACKED:
             lanes = tuple(self._packed_plan(sampled[start:start + size])
@@ -1737,7 +1757,7 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
             lanes = self._packed_mesh["plan"]
             self._static_plan = RoundPlan(
                 PATH_MESH_PACKED, everyone, None, None, lanes,
-                self._lane_slots(lanes))
+                self._lane_slots(lanes, self.mesh.shape["clients"]))
         else:
             with setup_span(SPAN_SETUP_PLACE) as placing:
                 self._dev_sharded = _placed(
@@ -1759,9 +1779,14 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
     def _round_plan(self, round_idx: int, record: bool = False) -> RoundPlan:
         if self._static_plan is None:
             return super()._round_plan(round_idx, record)
-        plan = self._static_plan
-        return plan._replace(
-            live=self._round_live(round_idx, plan.sampled, record))
+        plan = self._static_plan._replace(live=self._round_live(
+            round_idx, self._static_plan.sampled, record))
+        if plan.path == PATH_MESH_PACKED and self._client_active is not None:
+            # the plan the program is handed, and so the one counted
+            lanes = self._mesh_lanes()[0]
+            plan = plan._replace(lanes=lanes, padded_slots=self._lane_slots(
+                lanes, self.mesh.shape["clients"]))
+        return plan
 
     def _mesh_packed_setup(self, cohort: int):
         """Resident placement + program for the packed mesh schedule
@@ -1868,28 +1893,29 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
              np.asarray(ds.train_counts, np.float32)),
         )
 
-    def _mesh_plan_arrays(self):
-        """The packed-mesh plan arrays, with the Silo client-active mask
-        applied as a STRUCTURAL lane freeze (mask_plan_arrays) when set —
-        re-placed over the mesh once per mask version, so exits cost one
-        host->device plan upload, never a recompile (shapes unchanged)."""
+    def _mesh_lanes(self) -> tuple:
+        """(plan, placed arrays) of the packed mesh, with the Silo
+        client-active mask applied as a STRUCTURAL lane freeze
+        (parallel/packed.masked_plan) when set — re-placed over the mesh
+        once per mask version, so exits cost one host->device plan upload,
+        never a recompile (shapes unchanged)."""
         pm = self._packed_mesh
         if self._client_active is None:
-            return pm["plan_arrays"]
+            return pm["plan"], pm["plan_arrays"]
         cached = getattr(self, "_masked_mesh_plan", None)
         if cached is not None and cached[0] == self._client_active_version:
-            return cached[1]
+            return cached[1:]
         from fedml_tpu.parallel.mesh import shard_client_batch
-        from fedml_tpu.parallel.packed import (mask_plan_arrays,
-                                               mesh_member_active)
+        from fedml_tpu.parallel.packed import (masked_plan,
+                                               mesh_member_active,
+                                               plan_arrays_tuple)
 
-        ma = mesh_member_active(
+        lanes = masked_plan(pm["plan"], mesh_member_active(
             pm["plan"], self.mesh.shape["clients"],
-            np.asarray(self._client_active, np.float32)[pm["perm"]])
-        placed = shard_client_batch(self.mesh,
-                                    mask_plan_arrays(pm["plan"], ma))
-        self._masked_mesh_plan = (self._client_active_version, placed)
-        return placed
+            np.asarray(self._client_active, np.float32)[pm["perm"]]))
+        placed = shard_client_batch(self.mesh, plan_arrays_tuple(lanes))
+        self._masked_mesh_plan = (self._client_active_version, lanes, placed)
+        return lanes, placed
 
     def _run_mesh_packed_round(self, round_idx: int, plan: RoundPlan):
         from fedml_tpu.parallel.mesh import shard_client_batch
@@ -1898,7 +1924,7 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
         w = pm["counts_perm"]
         if plan.live is not None:
             # weight-zero failures and exits; an exit also gets the
-            # structural lane freeze via _mesh_plan_arrays
+            # structural lane freeze via _mesh_lanes
             w = w * np.asarray(plan.live, np.float32)[pm["perm"]]
         rk = round_key(self.root_key, round_idx)
         (w_dev,) = shard_client_batch(self.mesh, (w,))
@@ -1907,7 +1933,7 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
                 "packed_mesh", round_idx, pm["round_fn"],
                 self.variables, self.server_state, *pm["data"], w_dev,
                 jnp.asarray(pm["perm"], jnp.int32), rk,
-                self._mesh_plan_arrays())
+                self._mesh_lanes()[1])
         return train_loss
 
     def _run_mesh_sharded_round(self, round_idx: int, plan: RoundPlan):

@@ -41,7 +41,7 @@ class SiloRunner:
       metric stalls EXITS the federation — its aggregation weight zeroes
       on every schedule, and under the packed schedule its lane span
       becomes a structural no-op in the SAME compiled program
-      (FedAvgAPI.set_client_active -> parallel/packed.mask_plan_arrays):
+      (FedAvgAPI.set_client_active -> parallel/packed.masked_plan):
       masked lane freeze/exit, never a vmap fallback or a recompile.
       Exits take effect from the next round.
     """

@@ -182,7 +182,8 @@ class FedConfig:
     # Client-packing schedule (parallel/packed.py): pack the sampled cohort
     # into this many fixed-length scan lanes, clients back-to-back with
     # optimizer reset at boundaries — padding shrinks from cohort-max
-    # granularity to one batch per client plus the lane tail. 0 = off.
+    # granularity to one batch per client plus what a lane lacks to the
+    # longest lane vmapped with it (parallel/packed.chunk_bounds). 0 = off.
     # Each client's trajectory replays the canonical unbucketed program
     # exactly; the aggregate matches up to float summation order. Serves
     # every algorithm with a plain weighted mean OR a crosssilo_hooks contract

@@ -26,7 +26,11 @@ CPU in tier-1 and a run with attribution enabled stays bit-identical to
 one without. Loop bodies are multiplied by their statically-derived trip
 counts (the ``lax.scan`` counter pattern in the HLO ``while`` condition);
 a loop whose trip count cannot be derived counts its body once and flags
-``unknown_trip_counts`` in the summary.
+``unknown_trip_counts`` in the summary. A loop whose condition is a
+data-dependent bound AND a constant one (the packed lanes' step loop ends at
+its chunk's last live step, parallel/packed.chunk_bounds) counts at the
+constant: the tables of a packed program are its CEILING, the plan's whole
+length, of which a round walks what ``round_counts`` reports.
 
 This module is also the single source for FLOPs-and-peak numbers:
 :data:`PEAK_BF16` / :func:`peak_flops` and :func:`fwd_flops_per_image`
@@ -262,9 +266,22 @@ def _while_trip_count(instr: dict, comp: dict, comps: dict) -> Optional[int]:
     body = comps.get(body_name.group(1))
     if not cond or not body:
         return None
-    root = next((i for i in cond.values()
-                 if i["root"] and i["op"] == "compare"), None)
-    if root is None:
+    root = next((i for i in cond.values() if i["root"]), None)
+    if root is not None and root["op"] == "and":
+        # a loop that ends at a data-dependent bound AND at a constant one
+        # (parallel/packed._walk_steps) counts at the constant: its ceiling
+        trips = [t for t in (_compare_trip_count(cond.get(o), cond, comp,
+                                                 instr, body)
+                             for o in root["operands"]) if t is not None]
+        return min(trips) if trips else None
+    return _compare_trip_count(root, cond, comp, instr, body)
+
+
+def _compare_trip_count(root: Optional[dict], cond: dict, comp: dict,
+                        instr: dict, body: dict) -> Optional[int]:
+    """The trip count one ``compare`` of a while's condition implies
+    (:func:`_while_trip_count`'s counter pattern), or None."""
+    if root is None or root["op"] != "compare":
         return None
     mdir = _COMPARE_DIR_RE.search(root["line"])
     if not mdir or mdir.group(1) not in ("LT", "LE"):

@@ -6,9 +6,13 @@ still pads to that max (a grouped variant with one scan length per
 count-sorted group, deleted in PR 28, still left 15% (sim) / 21% (mesh) of
 executed slots dead). This module removes the max: the cohort is packed into a few
 fixed-length lanes (LPT balancing), each lane running its clients
-BACK-TO-BACK in one `lax.scan` with optimizer-state reset at client
+BACK-TO-BACK in one loop with optimizer-state reset at client
 boundaries. Padding shrinks to the final partial batch of each client plus
-the lane tail — one-batch granularity instead of group-max granularity.
+what a lane lacks to the longest lane vmapped WITH it — one-batch
+granularity instead of group-max granularity. The plan's ``T`` is a shape
+(one XLA program for every round that shares it), not a step count: the
+lanes that advance together walk the plan only as far as their last live
+step (:func:`chunk_bounds`), which arrives as data.
 
 Exactness: each client's trajectory REPLAYS the canonical unbucketed
 program (`make_local_train_fn` at full n_pad) bit-for-bit — the same
@@ -70,11 +74,12 @@ class PackPlan(NamedTuple):
     def shape_key(self) -> tuple:
         return (self.n_lanes, self.k_max, self.T, self.epochs)
 
-    @property
-    def executed_slots(self) -> int:
-        """Batch slots the schedule executes (for padded-throughput
-        accounting): lanes x steps x batch — without the batch factor."""
-        return self.n_lanes * self.T
+    def executed_slots(self, width: int, unroll: int = 1) -> int:
+        """Batch slots the schedule executes when its lanes advance
+        ``width`` at a time (for padded-throughput accounting): each chunk's
+        lanes x the steps that chunk walks (:func:`chunk_bounds`, the bound
+        the lane program itself takes) — without the batch factor."""
+        return int(width * chunk_bounds(self.live, width, unroll).sum())
 
 
 def plan_packing(counts: np.ndarray, batch_size: int, epochs: int,
@@ -129,6 +134,58 @@ def plan_packing(counts: np.ndarray, batch_size: int, epochs: int,
 
     return PackPlan(n_lanes, k_max, T, epochs, slot, epoch, sie, reset, emit,
                     live, member_pos, member_valid, steps_real)
+
+
+def chunk_bounds(live, width: int, unroll: int = 1):
+    """How many of a plan's ``T`` steps each chunk of ``width`` neighbouring
+    lanes walks: one past the last step that is live in ANY of the chunk's
+    lanes (0 for a chunk with none), rounded up to whole blocks of
+    ``unroll`` steps. Not ``sum(live)``: a masked plan
+    (:func:`masked_plan`) has dead spans in the middle of a lane, which
+    are walked to reach the next live member. ``live``: ``[n_lanes, T]``,
+    NumPy or JAX; -> ``[n_lanes // width]`` ints of the same kind.
+
+    THE one definition: the lane programs take their loop's bound from it
+    (:func:`make_lanes_train`, :func:`make_packed_lanes_train`) and the round
+    driver its count of executed slots (:meth:`PackPlan.executed_slots`), so
+    the count cannot drift from the program."""
+    n, T = live.shape
+    unroll = max(int(unroll), 1)
+    last = ((live > 0) * np.arange(1, T + 1, dtype=np.int32)).max(axis=1)
+    bound = last.reshape(n // width, width).max(axis=1)
+    return -(-bound // unroll) * unroll
+
+
+def _walk_steps(step_fn: Callable, carry0, steps: tuple, bound, unroll: int):
+    """``carry = step_fn(carry, step)`` over the first ``bound`` entries of
+    the ``steps`` arrays' leading axis, in blocks of ``unroll``; ``bound``
+    (a traced scalar, from :func:`chunk_bounds`) is the loop's trip count,
+    so one program serves every bound. Every step from ``bound`` on must be
+    one whose effects the step itself discards (``live == 0``, no emit):
+    the result is then the whole scan's. Nothing differentiates through
+    this loop (the gradients are inside the step), so it may be a while."""
+    unroll = max(int(unroll), 1)
+    pad = -steps[0].shape[0] % unroll
+    if pad:
+        # whole blocks: the steps added are dead ones (live 0, emit 0)
+        steps = tuple(jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+                      for a in steps)
+    blocks, n = steps[0].shape[0] // unroll, bound // unroll
+
+    def block(loop):
+        i, carry = loop
+        for j in range(unroll):
+            carry = step_fn(carry, tuple(
+                jax.lax.dynamic_index_in_dim(a, i * unroll + j, keepdims=False)
+                for a in steps))
+        return i + 1, carry
+
+    # the plan's length stays in the condition as a constant: the loop's
+    # static ceiling, which the static cost model counts the body at
+    # (obs/cost.py) and past which an index would re-read the last step
+    return jax.lax.while_loop(
+        lambda loop: (loop[0] < n) & (loop[0] < blocks),
+        block, (jnp.int32(0), carry0))[1]
 
 
 def _member_replay_tables(mask_rows, epochs: int, n_pad: int,
@@ -198,10 +255,12 @@ def make_lane_train(
 
     def lane_train(variables0, x_flat, y_flat, m_flat, mask_rows,
                    member_row, member_keys, member_w, steps_real,
-                   slot, epoch_a, sie, reset, emit, live):
+                   slot, epoch_a, sie, reset, emit, live, bound):
         """One lane. x_flat/y_flat/m_flat: [C*n_pad, ...] flattened stacks
         (shared, unbatched); mask_rows [C, n_pad]; member_* are this lane's
-        [k_max] arrays; per-step metadata [T]."""
+        [k_max] arrays; per-step metadata [T]; ``bound``: how many of the T
+        steps to walk (:func:`chunk_bounds` of the lanes vmapped together:
+        a scalar, unbatched, so the loop's predicate stays one)."""
         with jax.named_scope(SCOPE_PROLOGUE):
             params0 = variables0["params"]
             opt_state0 = tx_opt.init(params0)
@@ -304,11 +363,11 @@ def make_lane_train(
                        acc_tau, acc_extras)
                 if lens:
                     out = out + ((upd_stack, l_first, l_last, floss_acc),)
-            return out, None
+            return out
 
         # zeros DERIVED from inputs, not constants: under shard_map the
         # inputs are device-varying, and a constant-zero carry init would
-        # type-clash with the varying carry the scan body produces
+        # type-clash with the varying carry the loop body produces
         with jax.named_scope(SCOPE_PROLOGUE):
             z = jnp.sum(member_w) * 0.0
             acc0 = jax.tree.map(lambda v: v.astype(jnp.float32) * 0.0, variables0)
@@ -333,10 +392,9 @@ def make_lane_train(
                     * p.astype(jnp.float32)[None], params0)
                 carry0 = carry0 + ((upd0, zk, zk, z),)
         with jax.named_scope(SCOPE_STEP):
-            final, _ = jax.lax.scan(
+            final = _walk_steps(
                 step_fn, carry0, (slot, epoch_a, sie, reset, emit, live),
-                unroll=max(int(scan_unroll), 1),
-            )
+                bound, scan_unroll)
         (_, _, _, acc_vars, acc_w, acc_loss, acc_tau, acc_extras) = final[:8]
         if lens:
             return (acc_vars, acc_w, acc_loss, acc_tau, acc_extras,
@@ -502,7 +560,8 @@ def make_lanes_train(
     ``vmap`` of :func:`make_lane_train` over the lane axis (XLA lowers the
     batched-kernel convs to a grouped conv, docs/mfu_experiments.md H4),
     :func:`lane_vmap_width` lanes at a time, the chunks one after another
-    in one ``lax.map``; with ``packed_conv`` on and a capable model, the
+    in one ``lax.map``, each as far as its own last live step
+    (:func:`chunk_bounds`); with ``packed_conv`` on and a capable model, the
     fedpack JOINT form (:func:`make_packed_lanes_train`) whose convs are
     ONE block-diagonal/grouped contraction across lanes
     (ops/packed_conv.py). Same signature and stacked-accumulator return
@@ -512,28 +571,36 @@ def make_lanes_train(
     if pb is not None:
         return make_packed_lanes_train(bundle, pb, task, n_pad, **lane_kwargs)
     lane_train = make_lane_train(bundle, task, n_pad, **lane_kwargs)
-    vmapped = jax.vmap(lane_train, in_axes=(None,) * 5 + (0,) * 10)
+    vmapped = jax.vmap(lane_train, in_axes=(None,) * 5 + (0,) * 10 + (None,))
+    unroll = lane_kwargs.get("scan_unroll", 1)
 
     def lanes_train(variables0, x_flat, y_flat, m_flat, mask_rows, *per_lane):
         L = per_lane[-1].shape[0]
+        shared = (variables0, x_flat, y_flat, m_flat, mask_rows)
+
+        def bound(lanes):
+            # of the lanes that advance together, from their ``live`` rows
+            # (the last per-lane array) and outside their vmap
+            return chunk_bounds(lanes[-1], lanes[-1].shape[0], unroll)[0]
+
         if L == 1:
             # one lane needs no lane axis inside its program; the results
             # get the axis back
             return jax.tree.map(
                 lambda a: a[None],
-                lane_train(variables0, x_flat, y_flat, m_flat, mask_rows,
-                           *(a[0] for a in per_lane)))
+                lane_train(*shared, *(a[0] for a in per_lane),
+                           bound(per_lane)))
         w = lane_vmap_width(variables0, L)
         if w == L:
-            return vmapped(variables0, x_flat, y_flat, m_flat, mask_rows,
-                           *per_lane)
+            return vmapped(*shared, *per_lane, bound(per_lane))
         # same lanes, same steps, same order within a lane: only how many
         # lanes one convolution groups changes. The shared arguments are
-        # closed over (loop constants), each chunk runs its own T-step scan
+        # closed over (loop constants), each chunk runs its own loop and
+        # stops at its own last live step
         with jax.named_scope(SCOPE_STEP):
             return map_chunks(
-                lambda *chunk: vmapped(variables0, x_flat, y_flat, m_flat,
-                                       mask_rows, *chunk), per_lane, w)
+                lambda *chunk: vmapped(*shared, *chunk, bound(chunk)),
+                per_lane, w)
 
     return lanes_train
 
@@ -762,7 +829,7 @@ def make_packed_lanes_train(
                        acc_tau, acc_extras)
                 if lens:
                     out = out + ((upd_stack, l_first, l_last, floss_acc),)
-            return out, None
+            return out
 
         # zeros DERIVED from inputs (shard_map type consistency, as in the
         # vmap form)
@@ -785,11 +852,11 @@ def make_packed_lanes_train(
                     * p.astype(jnp.float32)[:, None], sparams0)
                 carry0 = carry0 + ((upd0, zk2, zk2, zl),)
         with jax.named_scope(SCOPE_STEP):
-            final, _ = jax.lax.scan(
+            # all lanes advance in one loop: its bound is the whole plan's
+            final = _walk_steps(
                 step_fn, carry0,
                 (slot.T, epoch_a.T, sie.T, reset.T, emit.T, live.T),
-                unroll=max(int(scan_unroll), 1),
-            )
+                chunk_bounds(live, L, scan_unroll)[0], scan_unroll)
         (_, _, _, acc_vars, acc_w, acc_loss, acc_tau, acc_extras) = final[:8]
         # singleton lane axis on the extras: the hook summed lanes already,
         # and the caller's sum(axis=0) must reduce THIS axis, not a real one
@@ -898,17 +965,19 @@ def plan_arrays_tuple(plan: PackPlan) -> tuple:
             plan.live, plan.member_pos, plan.member_valid, plan.steps_real)
 
 
-def mask_plan_arrays(plan: PackPlan, member_active: np.ndarray) -> tuple:
-    """Masked plan arrays for per-client lane EXIT (Silo early stopping):
+def masked_plan(plan: PackPlan, member_active: np.ndarray) -> PackPlan:
+    """The plan masked for per-client lane EXIT (Silo early stopping):
     a member whose ``member_active[lane, k]`` is 0 becomes a STRUCTURAL
     no-op — its steps run with ``live = 0`` (params/opt/stats frozen by
     the existing dead-step masks), its ``emit``/``member_valid`` zero out
     so it contributes nothing to the weighted aggregate, and ``reset`` is
     suppressed so the lane carries frozen state through the dead span to
     the next active member's reset. Shapes are UNCHANGED — the same
-    compiled program executes, no recompile, no vmap fallback; the dead
-    steps are the price of keeping the XLA program static (a re-pack
-    would reclaim them at one recompile per exit wave).
+    compiled program executes, no recompile, no vmap fallback. A dead span
+    in the MIDDLE of a lane is still walked (the next live member lies
+    behind it; a re-pack would reclaim it at one recompile per exit wave);
+    one at a lane's tail is not, once every lane vmapped with it has ended
+    too (:func:`chunk_bounds` reads the masked ``live``).
 
     ``member_active``: [n_lanes, k_max] {0,1} per plan member."""
     act_m = np.asarray(member_active, np.float32)
@@ -916,13 +985,12 @@ def mask_plan_arrays(plan: PackPlan, member_active: np.ndarray) -> tuple:
     # steps index slot 0 but already carry live == 0, so the product below
     # cannot resurrect or kill them incorrectly)
     step_act = np.take_along_axis(act_m, plan.slot.astype(np.int64), axis=1)
-    return (plan.slot, plan.epoch, plan.sie,
-            (plan.reset * step_act).astype(plan.reset.dtype),
-            (plan.emit * step_act).astype(plan.emit.dtype),
-            (plan.live * step_act).astype(plan.live.dtype),
-            plan.member_pos,
-            (plan.member_valid * act_m).astype(plan.member_valid.dtype),
-            plan.steps_real)
+    return plan._replace(
+        reset=(plan.reset * step_act).astype(plan.reset.dtype),
+        emit=(plan.emit * step_act).astype(plan.emit.dtype),
+        live=(plan.live * step_act).astype(plan.live.dtype),
+        member_valid=(plan.member_valid * act_m).astype(
+            plan.member_valid.dtype))
 
 
 def mesh_member_active(plan: PackPlan, n_devices: int,

@@ -98,6 +98,30 @@ def test_parser_unknown_trip_count_flagged():
     assert ops[0]["count"] == 1                  # body counted once
 
 
+def test_parser_data_bounded_loop_counts_at_its_constant_ceiling():
+    """A loop that ends at a data-dependent bound AND at a constant one (the
+    packed lanes' step loop, parallel/packed._walk_steps) counts its body at
+    the constant; with no constant in the condition it is unknown."""
+
+    def walk(ceiling):
+        def f(n, x, w):
+            def cond(c):
+                ok = c[0] < n
+                return ok & (c[0] < ceiling) if ceiling else ok
+
+            return jax.lax.while_loop(
+                cond, lambda c: (c[0] + 1, c[1] @ w), (jnp.int32(0), x))[1]
+
+        return (jax.jit(f).lower(jnp.int32(3), jnp.zeros((4, 8)),
+                                 jnp.zeros((8, 8)))
+                .compiler_ir(dialect="hlo").as_hlo_text())
+
+    ops, unknown = cost.op_table(walk(7))
+    assert not unknown and [o["count"] for o in ops] == [7]
+    ops, unknown = cost.op_table(walk(0))
+    assert unknown and [o["count"] for o in ops] == [1]
+
+
 def test_parser_grouped_conv_per_group_lanes():
     """A cohort-vmapped conv lowers to feature_group_count=G; the MXU sees
     the PER-GROUP output width, so lane fill must divide by G."""

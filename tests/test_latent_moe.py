@@ -451,7 +451,7 @@ def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
              * sizes["top_k"])
     rungs = moe.row_rungs(pairs)
     assert len(rungs) == 4
-    conds = re.findall(r'"stablehlo\.case"\(.*?\n    \}\) :', text, re.S)
+    conds = re.findall(r'"stablehlo\.case"\(.*?\n +\}\) :', text, re.S)
     assert len(conds) == 2 * (sizes["layers"] - sizes["first_dense"])
     for cond in conds:
         assert cond.count("func.call @rung") == len(rungs) == cond.count(

@@ -235,20 +235,28 @@ def test_crosssilo_packed_elastic_failures():
 
 # -- the lane vmap width (parallel/packed.lane_vmap_width) --------------------
 
-def _lanes_case(model, n_lanes, hooks, lens):
-    """A jitted packed cohort program at ``n_lanes`` with its arguments: 10
-    LDA clients of 8x8 images (or 6 features for the dense model), batch 4."""
+def _lanes_ds(model):
     shape = (6,) if model == "lr" else (8, 8, 3)
-    ds = make_synthetic_classification(
+    return make_synthetic_classification(
         "pack-w", shape, 4, 10, records_per_client=12,
         partition_method="hetero", partition_alpha=0.5, batch_size=4, seed=3)
-    bundle = create_model(model, ds.class_num, input_shape=shape)
+
+
+def _lanes_case(model, plan, hooks, lens):
+    """A jitted packed cohort program with its arguments: 10 LDA clients of
+    8x8 images (or 6 features for the dense model), batch 4, under ``plan``
+    (a lane count: the cohort packed into that many lanes)."""
+    ds = _lanes_ds(model)
+    bundle = create_model(model, ds.class_num,
+                          input_shape=ds.train_x.shape[2:])
     task = get_task(ds.task, ds.class_num)
     counts = np.asarray(ds.train_counts, np.float64)
-    plan = plan_packing(counts, 4, 1, n_lanes=n_lanes)
-    assert plan.n_lanes == n_lanes and plan.k_max > 1
-    kw = dict(optimizer="sgd", lr=0.05, momentum=0.9, epochs=1, batch_size=4,
-              lens=lens)
+    if isinstance(plan, int):
+        n_lanes = plan
+        plan = plan_packing(counts, 4, 1, n_lanes=n_lanes)
+        assert plan.n_lanes == n_lanes and plan.k_max > 1
+    kw = dict(optimizer="sgd", lr=0.05, momentum=0.9, epochs=plan.epochs,
+              batch_size=4, lens=lens)
     if hooks:
         def client_transform(gvars, stacked):
             out = dict(stacked)
@@ -317,3 +325,266 @@ def test_lanes_run_lane_vmap_width_at_a_time(monkeypatch, model, n_lanes,
         assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_allclose(a, b, rtol=0,
                                    atol=2e-6 * max(np.abs(b).max(), 1e-6))
+
+
+# -- a chunk of lanes stops at its own last live step (ISSUE 36) ---------------
+
+def _whole_plan(live, width, unroll=1):
+    """A test double of ``chunk_bounds``: every chunk walks all T steps, as
+    a concrete count, so the loop lowers to the static scan it was."""
+    return np.full(live.shape[0] // width, live.shape[1])
+
+
+def _bound_case(name):
+    """-> (model, plan, width): a plan whose chunks end at different steps."""
+    from fedml_tpu.parallel.packed import masked_plan
+
+    ragged = np.array([12, 11, 9, 8, 7, 5, 4, 2, 0, 0], np.float64)
+    counts = np.asarray(_lanes_ds("resnet20").train_counts, np.float64)
+    if name == "8-lanes-of-one":
+        return "resnet20", plan_packing(ragged, 4, 1, n_lanes=8), 2
+    if name == "lanes-of-several":
+        return "resnet20", plan_packing(counts, 4, 1, n_lanes=4), 2
+    if name == "lanes-of-several-2-epochs":
+        return "resnet20", plan_packing(counts, 4, 2, n_lanes=4), 2
+    if name == "one-lane":
+        return "resnet20", plan_packing(counts, 4, 1, n_lanes=1,
+                                        t_quantum=5), 1
+    if name == "full-vmap":                        # dense: all lanes together
+        return "lr", plan_packing(counts, 4, 1, n_lanes=5, t_quantum=4), 5
+    plan = plan_packing(counts, 4, 1, n_lanes=4)
+    assert (plan.member_valid.sum(axis=1) >= 2).all()
+    active = np.ones((4, plan.k_max), np.float32)
+    if name == "exits-mid-and-tail":
+        last = int(plan.member_valid[2].sum()) - 1
+        active[0, 0] = active[2, last] = 0.0       # lane 0's first, lane 2's last
+    elif name == "all-dead-lane":
+        active[1] = 0.0
+    elif name == "all-dead-chunk":
+        active[2:] = 0.0
+    else:
+        raise KeyError(name)
+    return "resnet20", masked_plan(plan, active), 2
+
+
+BOUND_CASES = ["8-lanes-of-one", "lanes-of-several",
+               "lanes-of-several-2-epochs", "one-lane", "full-vmap",
+               "exits-mid-and-tail", "all-dead-lane", "all-dead-chunk"]
+
+
+@pytest.mark.parametrize("case", BOUND_CASES)
+def test_bounded_lane_loop_is_the_whole_scan_bit_for_bit(monkeypatch, case):
+    """The lane loop ends at a bound that arrives as data (``chunk_bounds``
+    of the lanes vmapped together): every accumulator, weight, loss, tau,
+    extra and lens stack equals, bit for bit, what the same program computes
+    when it walks all T steps (a static scan: the parent's program), and the
+    steps it walks are the plan's count, not chunks x T."""
+    from fedml_tpu.parallel import packed
+
+    model, plan, width = _bound_case(case)
+    walked = []
+    batch_step = packed.make_batch_sgd_step
+
+    def counting(*a, **kw):
+        step = batch_step(*a, **kw)
+
+        def counted(*args):
+            jax.debug.callback(lambda: walked.append(1))
+            return step(*args)
+
+        return counted
+
+    monkeypatch.setattr(packed, "make_batch_sgd_step", counting)
+    build, args = _lanes_case(model, plan, hooks=True, lens=True)
+    assert packed.lane_vmap_width(args[0], plan.n_lanes) == width
+    bounds = packed.chunk_bounds(plan.live, width)
+    assert 0 < bounds.sum() < len(bounds) * plan.T, "no chunk ends early"
+    assert plan.executed_slots(width) == width * bounds.sum()
+
+    got = jax.block_until_ready(build()(*args))
+    jax.effects_barrier()
+    assert len(walked) == bounds.sum()
+    del walked[:]
+    monkeypatch.setattr(packed, "chunk_bounds", _whole_plan)
+    whole = build()
+    assert "stablehlo.while" in whole.lower(*args).as_text()
+    want = jax.block_until_ready(whole(*args))
+    jax.effects_barrier()
+    assert len(walked) == len(bounds) * plan.T
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert float(got[1]) > 0 and np.isfinite(float(got[2]))
+
+
+def test_chunk_bounds_reads_the_last_live_step_not_the_live_count():
+    """One definition for the plan's count (NumPy) and the program (JAX):
+    a dead span in the middle of a lane is walked, a chunk's bound is its
+    longest lane's, a lane with no live step has bound 0, and ``unroll``
+    rounds a bound up to whole blocks."""
+    from fedml_tpu.parallel.packed import chunk_bounds
+
+    live = np.zeros((6, 8), np.float32)
+    live[0, :5] = 1
+    live[1, :2] = 1
+    live[2, [0, 1, 5]] = 1                      # dead in the middle
+    live[3, :3] = 1
+    for xp in (np, jnp):
+        assert chunk_bounds(xp.asarray(live), 1).tolist() == [5, 2, 6, 3, 0, 0]
+        assert chunk_bounds(xp.asarray(live), 2).tolist() == [5, 6, 0]
+        assert chunk_bounds(xp.asarray(live), 6).tolist() == [6]
+        assert chunk_bounds(xp.asarray(live), 2, unroll=4).tolist() == [8, 8, 0]
+    assert jax.jit(lambda a: chunk_bounds(a, 3))(live).tolist() == [6, 3]
+
+
+@pytest.mark.parametrize("unroll", [1, 3])
+@pytest.mark.parametrize("pack_lanes,width", [(8, 2), (2, 2), (1, 1)],
+                         ids=["chunked", "one-vmap", "one-lane"])
+def test_round_counts_reports_the_steps_the_chunks_walk(pack_lanes, width,
+                                                        unroll):
+    """``round_counts``' padded slots are the sum over the chunks of lanes of
+    width x the chunk's bound (``scan_unroll`` rounds a bound up to whole
+    blocks), from the plan the program is handed: the masked one under
+    ``set_client_active``."""
+    ds = _lanes_ds("resnet20")
+    api = FedAvgAPI(ds, FedConfig(
+        model="resnet20", dataset="pack-w", client_num_in_total=10,
+        client_num_per_round=10, comm_round=1, batch_size=4, lr=0.05,
+        epochs=1, seed=1, device_data="on", bucket_quantum_batches=20,
+        pack_lanes=pack_lanes, scan_unroll=unroll,
+        frequency_of_the_test=10_000))
+
+    def by_hand(live):
+        last = [max((t + 1 for t in range(live.shape[1]) if lane[t] > 0),
+                    default=0) for lane in live]
+        return sum(width * -(-max(last[i:i + width]) // unroll) * unroll
+                   for i in range(0, len(last), width)) * 4
+
+    try:
+        plan = api._round_plan(0)
+        assert plan.lanes.n_lanes == pack_lanes and plan.lanes.T % 5 == 0
+        real, padded = api.round_counts(0)
+        assert padded == plan.padded_slots == by_hand(plan.lanes.live)
+        assert real == int(np.asarray(ds.train_counts).sum()) <= padded
+        if unroll == 1:
+            assert padded < pack_lanes * plan.lanes.T * 4
+        # every lane's last client exits: every chunk ends earlier
+        last = (plan.lanes.member_valid.sum(axis=1) - 1).astype(int)
+        gone = plan.lanes.member_pos[np.arange(pack_lanes), last]
+        active = np.ones(10, np.float32)
+        active[plan.sampled[gone]] = 0.0
+        api.set_client_active(active)
+        masked = api._round_plan(0)
+        assert masked.lanes.live.sum() < plan.lanes.live.sum()
+        assert api.round_counts(0)[1] == by_hand(masked.lanes.live)
+        if unroll == 1:
+            assert api.round_counts(0)[1] < padded
+    finally:
+        api.close()
+
+
+def test_plan_span_says_how_many_steps_the_round_walked(tmp_path):
+    """The ``fedml/round/plan`` span of a packed round carries
+    ``steps_planned`` (chunks x T) and ``steps_run`` (the chunks' bounds)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from fedml_tpu.obs import tracer
+    from fedml_tpu.parallel.packed import chunk_bounds
+
+    ds = _lanes_ds("resnet20")
+    api = FedAvgAPI(ds, FedConfig(
+        model="resnet20", dataset="pack-w", client_num_in_total=10,
+        client_num_per_round=10, comm_round=1, batch_size=4, lr=0.05,
+        epochs=1, seed=1, device_data="on", bucket_quantum_batches=16,
+        pack_lanes=4, frequency_of_the_test=10_000, async_rounds=True))
+    try:
+        with jax.profiler.trace(str(tmp_path / "prof")):
+            jax.block_until_ready(api.run_round(0))
+        lanes = api._round_plan(0).lanes
+    finally:
+        api.close()
+    (path,) = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                        recursive=True)
+    plans = [dict(ev.stats) for pl in ProfileData.from_file(path).planes
+             for ln in pl.lines for ev in ln.events
+             if ev.name == tracer.SPAN_PLAN]
+    bounds = chunk_bounds(lanes.live, 2)
+    assert bounds.sum() < 2 * lanes.T
+    assert {"round": 0, "steps_planned": 2 * lanes.T,
+            "steps_run": int(bounds.sum())} in plans
+
+
+# -- the benchmark cells' schedules, without a chip ----------------------------
+
+def _cell_plans(name):
+    """-> (cell's batch size, [(plan, live counts) of round indices 1 and 2],
+    lane width): the lane plans ``FedAvgAPI._packed_plan`` makes for the
+    cell's cohorts, from the cell's own files and generator."""
+    import json
+    import pathlib
+
+    from benchmarks.traffic import cifar_like_lda, token_clients
+    from fedml_tpu.core.rng import sample_clients
+    from fedml_tpu.parallel.packed import lane_vmap_width
+
+    root = pathlib.Path(__file__).resolve().parents[1] / "benchmarks"
+    cell = json.loads((root / "workloads" / f"{name}.json").read_text())
+    config = json.loads(
+        (root / "configs" / f"{cell['config']}.json").read_text())
+    if "train_records" in config["data"]:
+        counts = np.array([len(y) for y in
+                           cifar_like_lda.client_labels(config, cell)])
+    else:
+        counts = token_clients.client_counts(config, cell)
+    fed, recipe = cell["fed_config"], config["recipe"]
+    rounds = range(cell["rounds"]["first"],
+                   cell["rounds"]["first"] + cell["rounds"]["cycle"])
+    plans = []
+    for r in rounds:
+        sampled = sample_clients(r, cell["clients"],
+                                 fed["client_num_per_round"],
+                                 cell["sampling_seed"])
+        plans.append((plan_packing(
+            counts[sampled].astype(np.float64), recipe["batch_size"],
+            recipe["epochs"], fed["pack_lanes"],
+            t_quantum=max(1, FedConfig().bucket_quantum_batches // 4)),
+            int(counts[sampled].sum())))
+    bundle = create_model(
+        config["model"]["program_name"], 10,
+        input_shape=tuple(config["data"].get("input_shape", ())) or None)
+    shapes = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
+    return (recipe["batch_size"], plans,
+            lane_vmap_width(shapes, plans[0][0].n_lanes))
+
+
+def test_flagship_cells_chunks_stop_at_their_own_last_live_step():
+    """``resnet56_sim_c8``: 8 lanes of one client, two at a time; LPT emits
+    them in descending order of load, so neighbours share a chunk, and the
+    four chunks walk 104 and 107 steps of the 128 the plan's shape has."""
+    from fedml_tpu.parallel.packed import chunk_bounds
+
+    batch, plans, width = _cell_plans("resnet56_sim_c8")
+    assert width == 2 and [n for _, n in plans] == [12_680, 12_770]
+    want = [((31, 29, 26, 26, 26, 25, 21, 18), (31, 26, 26, 21), 13_312),
+            ((31, 29, 29, 28, 25, 25, 22, 15), (31, 29, 25, 22), 13_696)]
+    for (plan, _), (loads, bounds, padded) in zip(plans, want):
+        assert plan.shape_key == (8, 1, 32, 1)
+        assert tuple(plan.live.sum(axis=1).astype(int)) == loads
+        assert tuple(chunk_bounds(plan.live, width)) == bounds
+        assert plan.executed_slots(width) * batch == padded
+
+
+@pytest.mark.parametrize("cell", ["kanana2_sim_c2", "ling3_sim_c2",
+                                  "laguna_sim_c2"])
+def test_lm_cells_one_lane_walks_every_step(cell):
+    """The LM cells bypass the bound: one lane whose 8 steps are all live in
+    both round indices (their padding is half of a last batch, not a step)."""
+    from fedml_tpu.parallel.packed import chunk_bounds
+
+    _, plans, width = _cell_plans(cell)
+    assert width == 1
+    for plan, _ in plans:
+        assert plan.shape_key == (1, 2, 8, 1)
+        assert chunk_bounds(plan.live, width).tolist() == [plan.T] == [8]

@@ -370,7 +370,7 @@ def test_fallback_counted_and_rewarns_after_reset(caplog):
 
 # -- 5. Silo per-client early exit as a masked lane freeze --------------------
 
-def test_mask_plan_arrays_structural_noop():
+def test_masked_plan_structural_noop():
     counts = np.array([37, 5, 80, 16, 3, 64, 22, 9])
     plan = packed_mod.plan_packing(counts, batch_size=8, epochs=2, n_lanes=3)
     active = np.ones((plan.n_lanes, plan.k_max), np.float32)
@@ -379,7 +379,8 @@ def test_mask_plan_arrays_structural_noop():
                 for k in range(plan.k_max) if plan.member_valid[l, k])
     active[l, k] = 0.0
     (slot, epoch, sie, reset, emit, live, member_pos, member_valid,
-     steps_real) = packed_mod.mask_plan_arrays(plan, active)
+     steps_real) = packed_mod.plan_arrays_tuple(
+         packed_mod.masked_plan(plan, active))
     dead = (plan.slot[l] == k) & (plan.live[l] > 0)
     assert dead.any()
     assert not live[l][dead].any() and not emit[l][dead].any() \

@@ -137,9 +137,17 @@ def test_a_cohort_with_no_record_is_planned_as_gather(ds):
 
 # -- round_counts against what the device program was handed -----------------
 
-def _lane_slots(plan_arrays, epochs):
-    lanes, steps = plan_arrays[0].shape          # slot: [lanes, T]
-    return lanes * steps * BATCH // epochs
+def _lane_slots(plan_arrays, epochs, devices=1):
+    """The slots a packed program walks, from the ``live`` rows it was
+    handed: the dense model's lanes all advance together on their device,
+    as far as the last live step of the longest of them."""
+    live = np.asarray(plan_arrays[5])            # [lanes, T]
+    steps = 0
+    for dev in np.split(live, devices):
+        last = [max((t + 1 for t in range(len(lane)) if lane[t] > 0),
+                    default=0) for lane in dev]
+        steps += len(dev) * max(last)
+    return steps * BATCH // epochs
 
 
 def _wrap_builder(api, name, slots_of):
@@ -209,7 +217,7 @@ def test_round_counts_is_the_plan_run_round_executes(ds, case):
     elif case == "mesh_packed":
         seen = _wrap_step(
             api._packed_mesh, "round_fn",
-            lambda args: _lane_slots(args[8], api.config.epochs))
+            lambda args: _lane_slots(args[8], api.config.epochs, devices=2))
     else:                       # host, mesh_sharded: the default round step
         seen = _wrap_step(api, "_round_step", _cohort_by_scan)
     try:
