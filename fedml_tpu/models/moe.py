@@ -41,13 +41,14 @@ from typing import Optional
 
 from fedml_tpu.models import COUNTERS, ModelBundle, register_model
 from fedml_tpu.models.transformer import (DeltaAttention, GroupedAttention,
-                                          LatentAttention, Linear, RMSNorm,
-                                          SelfAttention, SwiGLU, _normal,
-                                          yarn_frequencies)
+                                          LatentAttention, Linear,
+                                          Mamba2Mixer, RMSNorm, SelfAttention,
+                                          SwiGLU, _normal, yarn_frequencies)
 from fedml_tpu.obs.tracer import (SCOPE_LM_DENSE, SCOPE_LM_EXPERTS,
                                   SCOPE_LM_ROUTE)
 from fedml_tpu.ops.grouped_matmul import (embed_rows, fan_out_rows,
                                           grouped_matmul, permute_rows)
+from fedml_tpu.ops.ssd import SSD_CHUNK
 
 
 def top_k_probs(router_logits: jax.Array, top_k: int) -> jax.Array:
@@ -444,9 +445,10 @@ class LatentMoeSizes:
     held_count: Optional[int] = None
     remat: bool = True
     dtype: Any = jnp.float32
-    #: the mixer of each layer: ``"latent"``, ``"delta"``, or grouped-query
-    #: attention over all the keys (``"full"``) or under a sliding window
-    #: (``"window"``); empty: all latent
+    #: the mixer of each layer: ``"latent"``, ``"delta"``, ``"ssd"`` (a
+    #: Mamba-2 state-space mixer), or grouped-query attention over all the
+    #: keys (``"full"``) or under a sliding window (``"window"``); empty: all
+    #: latent
     mixers: tuple = ()
     #: group-limited routing (1: none)
     n_group: int = 1
@@ -480,13 +482,32 @@ class LatentMoeSizes:
     yarn_attention_factor: float = 1.0
     #: the router's score function (:class:`SharedRoutedMoe`)
     score: str = "sigmoid"
+    #: the state-space mixers: ``ssd_heads`` heads of ``ssd_head_dim``
+    #: channels over a state of ``ssd_state``, a convolution of ``ssd_conv``
+    #: positions, the recurrence in chunks of ``ssd_chunk``
+    ssd_heads: int = 0
+    ssd_head_dim: int = 64
+    ssd_state: int = 128
+    ssd_conv: int = 4
+    ssd_chunk: int = SSD_CHUNK
+    #: the four multipliers: ``h_0 = embed_scale * E[id]``; a block adds
+    #: ``residual_scale`` times its mixer's and its MLP's output; a full
+    #: layer's scores are ``q . k * attn_scale`` (None: ``v_dim^-0.5``);
+    #: ``logits = head(h) / logit_scale``. ``tied_head``: the head is the
+    #: embedding's table
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: Optional[float] = None
+    logit_scale: float = 1.0
+    tied_head: bool = False
 
 
 class LatentMoeBlock(nn.Module):
-    """``h += Mixer(RMSNorm(h))``; ``h += Mlp(RMSNorm(h))``: the mixer
-    latent attention (``attn``) or the delta rule (``delta``), the MLP a
-    SwiGLU of ``dense_width`` in the leading dense layers, the sparse layer
-    after."""
+    """``h += r Mixer(RMSNorm(h))``; ``h += r Mlp(RMSNorm(h))`` with ``r``
+    the sizes' ``residual_scale``: the mixer attention of one of three kinds
+    (``attn``), the delta rule (``delta``) or the state-space recurrence
+    (``ssd``), the MLP a SwiGLU of ``dense_width`` in the leading dense
+    layers, the sparse layer after."""
 
     sizes: LatentMoeSizes
     sparse: bool
@@ -504,6 +525,10 @@ class LatentMoeBlock(nn.Module):
             a = DeltaAttention(c.heads, c.delta_head_dim, c.delta_conv,
                                c.delta_lower_bound, c.eps, c.dtype,
                                name="delta")(a)
+        elif self.mixer == "ssd":
+            a = Mamba2Mixer(c.ssd_heads, c.ssd_head_dim, c.ssd_state,
+                            c.ssd_conv, c.ssd_chunk, c.eps, c.dtype,
+                            name="ssd")(a, train)
         elif self.mixer == "window":
             a = GroupedAttention(c.window_heads, c.kv_heads, c.v_dim, c.v_dim,
                                  c.window_rope_theta, window=c.window,
@@ -518,8 +543,15 @@ class LatentMoeBlock(nn.Module):
                     c.yarn_beta_fast, c.yarn_beta_slow).tolist())
             a = GroupedAttention(c.heads, c.kv_heads, c.v_dim, c.rope,
                                  c.rope_theta, yarn, scale, gate=c.out_gate,
-                                 dtype=c.dtype, name="attn")(a)
-        h = h + a
+                                 dtype=c.dtype, scale=c.attn_scale,
+                                 name="attn")(a)
+
+        def add(h, branch):
+            if c.residual_scale != 1.0:
+                branch = branch * jnp.asarray(c.residual_scale, branch.dtype)
+            return h + branch
+
+        h = add(h, a)
         m = RMSNorm(c.eps, c.dtype, name="mlp_norm")(h)
         if self.sparse:
             m = SharedRoutedMoe(c.n_routed, c.top_k, c.expert_width,
@@ -529,14 +561,15 @@ class LatentMoeBlock(nn.Module):
         else:
             with jax.named_scope(SCOPE_LM_DENSE):
                 m = SwiGLU(c.dense_width, c.dtype, name="mlp")(m)
-        return h + m
+        return add(h, m)
 
 
 class LatentMoeLM(nn.Module):
     """Decoder-only LM of blocks with sparse experts: an embedding,
     ``layers`` blocks (the first ``first_dense`` with a dense MLP; layer
     ``i``'s mixer is ``mixers[i]``, latent attention where none is named),
-    a final RMSNorm and an untied head; no learned positions. Each block is
+    a final RMSNorm and a head of its own, or, ``tied_head``, the
+    embedding's table again; no learned positions. Each block is
     rematerialised in the backward pass (``remat``)."""
 
     vocab_size: int
@@ -547,33 +580,46 @@ class LatentMoeLM(nn.Module):
         c = self.sizes
         table = self.param("embed", _normal(), (self.vocab_size, c.dim),
                            jnp.float32)
-        h = embed_rows(table, x.astype(jnp.int32)).astype(c.dtype)
+        h = embed_rows(table, x.astype(jnp.int32))
+        if c.embed_scale != 1.0:
+            h = h * c.embed_scale
+        h = h.astype(c.dtype)
         block = (nn.remat(LatentMoeBlock, static_argnums=(2,)) if c.remat
                  else LatentMoeBlock)
         mixers = c.mixers or ("latent",) * c.layers
-        if len(mixers) != c.layers or set(mixers) - {"latent", "delta", "full",
-                                                     "window"}:
+        if len(mixers) != c.layers or set(mixers) - {"latent", "delta", "ssd",
+                                                     "full", "window"}:
             raise ValueError(f"mixers {mixers}: one of 'latent' / 'delta' / "
-                             f"'full' / 'window' for each of the {c.layers} "
-                             "layers")
+                             f"'ssd' / 'full' / 'window' for each of the "
+                             f"{c.layers} layers")
         for i in range(c.layers):
             h = block(c, i >= c.first_dense, mixers[i],
                       name=f"layer_{i}")(h, train)
         h = RMSNorm(c.eps, c.dtype, name="final_norm")(h)
         with jax.named_scope(SCOPE_LM_DENSE):
-            return Linear(self.vocab_size, c.dtype, jnp.float32,
-                          name="lm_head")(h)
+            if c.tied_head:
+                logits = jnp.einsum("btd,vd->btv", h, table.astype(c.dtype),
+                                    preferred_element_type=jnp.float32)
+            else:
+                logits = Linear(self.vocab_size, c.dtype, jnp.float32,
+                                name="lm_head")(h)
+        return logits / c.logit_scale if c.logit_scale != 1.0 else logits
 
 
-def expert_row_counters(variables: dict) -> dict:
-    """``{"rows.<layer>.<expert>": rows, "steps.<layer>": steps}`` (and
-    ``"group_tokens.<layer>"`` under a group-limited router) from the
-    ``counters`` the sparse layers keep (host numbers): sums over every
+def layer_counters(variables: dict) -> dict:
+    """Host numbers from the ``counters`` the layers keep, sums over every
     training step since the variables were seeded, where the packed
-    simulation round trained them."""
+    simulation round trained them: a sparse layer's ``rows.<layer>.<expert>``
+    and ``steps.<layer>`` (and ``group_tokens.<layer>`` under a
+    group-limited router), a state-space mixer's ``decay.<layer>`` (its mean
+    ``exp(dt A)`` a step, summed) and ``steps.<layer>``. A model whose layers
+    keep none gives ``{}``."""
     out = {}
     for layer, stats in sorted(variables.get(COUNTERS, {}).items()):
-        mlp = stats.get("mlp", {})
+        ssd, mlp = stats.get("ssd", {}), stats.get("mlp", {})
+        if "decay" in ssd:
+            out[f"decay.{layer}"] = float(jax.device_get(ssd["decay"]))
+            out[f"steps.{layer}"] = float(jax.device_get(ssd["steps"]))
         if "expert_rows" not in mlp:
             continue
         for e, rows in enumerate(jax.device_get(mlp["expert_rows"])):
@@ -625,6 +671,28 @@ LATENT_MOE_PRESETS = {
         window_rope_theta=1e4, yarn_factor=64.0, yarn_original=4096,
         yarn_beta_fast=64.0, yarn_beta_slow=1.0,
         yarn_attention_factor=1.4158883083359672, score="softmax"),
+    # the first pipeline stage (one whole period of ``layer_types``: the
+    # published layers 0 - 9, nine Mamba-2 mixers to one position-free
+    # grouped-query attention layer, every MLP dense) of
+    # ibm-granite/granite-4.0-h-micro, with an eighth of the tied table's rows
+    # (``benchmarks/configs/granite4_h_micro.json``, held equal by a test)
+    "granite4_h_micro": dict(
+        dim=2048, heads=32, nope=64, rope=0, v_dim=64, kv_rank=0, layers=10,
+        first_dense=10, dense_width=8192, n_routed=0, top_k=0,
+        expert_width=0, n_shared=0, routed_scaling=1.0, rope_theta=1e4,
+        eps=1e-5, seq_len=4096,
+        mixers=["ssd"] * 5 + ["full"] + ["ssd"] * 4, kv_heads=8,
+        ssd_heads=64, ssd_head_dim=64, ssd_state=128, ssd_conv=4,
+        ssd_chunk=256, embed_scale=12.0, residual_scale=0.22,
+        attn_scale=0.015625, logit_scale=8.0, tied_head=True),
+    "granite4h_tiny": dict(
+        dim=32, heads=4, nope=8, rope=0, v_dim=8, kv_rank=0, layers=4,
+        first_dense=4, dense_width=64, n_routed=0, top_k=0, expert_width=0,
+        n_shared=0, routed_scaling=1.0, rope_theta=1e4, eps=1e-5, seq_len=32,
+        mixers=["ssd", "ssd", "full", "ssd"], kv_heads=2, ssd_heads=8,
+        ssd_head_dim=8, ssd_state=16, ssd_conv=4, ssd_chunk=8,
+        embed_scale=12.0, residual_scale=0.22, attn_scale=0.125,
+        logit_scale=8.0, tied_head=True),
     "laguna_tiny": dict(
         dim=32, heads=6, nope=8, rope=8, v_dim=16, kv_rank=0, layers=3,
         first_dense=1, dense_width=96, n_routed=16, top_k=4, expert_width=24,
@@ -660,7 +728,7 @@ def _latent_moe_bundle(name: str, output_dim: int, **kw) -> ModelBundle:
         name=name, module=module, input_shape=(seq_len,),
         input_dtype=jnp.int32, task="nwp",
         # parameter shapes do not depend on the sequence length
-        init_shape=(8,), counters=expert_row_counters)
+        init_shape=(8,), counters=layer_counters)
 
 
 @register_model("kanana2_30b_a3b")
@@ -676,6 +744,16 @@ def _ling3(output_dim: int = 19648, **kw):
 @register_model("laguna_xs2")
 def _laguna(output_dim: int = 12544, **kw):
     return _latent_moe_bundle("laguna_xs2", output_dim or 12544, **kw)
+
+
+@register_model("granite4_h_micro")
+def _granite4h(output_dim: int = 12544, **kw):
+    return _latent_moe_bundle("granite4_h_micro", output_dim or 12544, **kw)
+
+
+@register_model("granite4h_tiny")
+def _granite4h_tiny(output_dim: int = 64, **kw):
+    return _latent_moe_bundle("granite4h_tiny", output_dim or 64, **kw)
 
 
 @register_model("laguna_tiny")

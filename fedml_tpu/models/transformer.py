@@ -23,12 +23,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from fedml_tpu.models import ModelBundle, register_model
+from fedml_tpu.models import COUNTERS, ModelBundle, register_model
 from fedml_tpu.obs.tracer import (SCOPE_LM_ATTN, SCOPE_LM_ATTN_WINDOW,
                                   SCOPE_LM_DENSE, SCOPE_LM_KDA,
-                                  SCOPE_LM_KDA_PREP)
+                                  SCOPE_LM_KDA_PREP, SCOPE_LM_SSD,
+                                  SCOPE_LM_SSD_PREP)
 from fedml_tpu.ops.attention import attention
 from fedml_tpu.ops.kda import kda_chunked
+from fedml_tpu.ops.ssd import SSD_CHUNK, ssd_chunked
 
 
 class SelfAttention(nn.Module):
@@ -362,10 +364,12 @@ class GroupedAttention(nn.Module):
     query head ``i`` reads key-value head ``i // (heads / kv_heads)``. The
     first ``rotary_dim`` channels of every head of ``q`` and ``k`` turn
     (:func:`rotary`: by ``rope_theta``, or by ``inv_freq`` and ``scale``
-    where YaRN gives them), the others pass. Causal softmax of ``q . k /
-    sqrt(head_dim)`` over the ``window`` keys up to the query's own
-    (``None``: all of them). ``gate``: each head's output times ``sigmoid(x
-    W_g)``, one gate a head and no norm, before ``W_o``. No biases."""
+    where YaRN gives them), the others pass; ``rotary_dim`` 0: no channel
+    turns (a position-free layer). Causal softmax of ``q . k * scale``
+    (``None``: ``head_dim^-0.5``) over the ``window`` keys up to the query's
+    own (``None``: all of them). ``gate``: each head's output times
+    ``sigmoid(x W_g)``, one gate a head and no norm, before ``W_o``. No
+    biases."""
 
     heads: int
     kv_heads: int
@@ -377,6 +381,7 @@ class GroupedAttention(nn.Module):
     window: Optional[int] = None
     gate: bool = False
     dtype: Any = jnp.float32
+    scale: Optional[float] = None
 
     @nn.compact
     def __call__(self, x):
@@ -391,6 +396,8 @@ class GroupedAttention(nn.Module):
             return a.reshape(b, t, n, d).transpose(0, 2, 1, 3)
 
         def turned(a):
+            if not r:
+                return a
             first = rotary(a[..., :r], self.rope_theta, self.inv_freq,
                            self.rope_scale)
             return first if r == d else jnp.concatenate(
@@ -400,7 +407,8 @@ class GroupedAttention(nn.Module):
         with jax.named_scope(SCOPE_LM_ATTN if self.window is None
                              else SCOPE_LM_ATTN_WINDOW):
             o = attention(q, k, v, causal=True, window=self.window,
-                          block_q=_ATTN_BLOCK, block_k=_ATTN_BLOCK)
+                          sm_scale=self.scale, block_q=_ATTN_BLOCK,
+                          block_k=_ATTN_BLOCK)
         o = o.transpose(0, 2, 1, 3)
         if self.gate:
             o = HeadGate(dtype=self.dtype, norm=False, name="out_gate")(o, x)
@@ -493,3 +501,87 @@ class DeltaAttention(nn.Module):
         with jax.named_scope(SCOPE_LM_DENSE):
             return Linear(dim, self.dtype, name="o_proj")(
                 o.reshape(b, t, h * d))
+
+
+def log_uniform_steps(key: jax.Array, shape, low: float = 0.001,
+                      high: float = 0.1) -> jax.Array:
+    """``dt_bias`` of Mamba-2's public init: a head's step ``dt`` at a zero
+    pre-activation is drawn log-uniform over ``[low, high]`` and the bias is
+    its inverse softplus, ``dt + log(1 - exp(-dt))``."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, jnp.log(low),
+                                    jnp.log(high)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 state-space mixer (arXiv:2405.21060; one group: ``B`` and
+    ``C`` are shared by all heads). ``[z | xBC | dt] = W_in u``, widths
+    ``H P``, ``H P + 2 N``, ``H``; ``xBC = SiLU(conv(xBC) + b_conv)``, a
+    causal depthwise convolution of ``conv`` positions over ``x``, ``B``,
+    ``C`` together; ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)``
+    a head; the recurrence (``ops/ssd.py``) with the skip ``D x``; ``y =
+    RMSNorm(y * SiLU(z)) * w``, the gate BEFORE the norm and one norm over
+    all ``H P`` channels; ``out = W_out y``. The recurrence's parameters
+    start as the public code's do: ``dt`` log-uniform over [0.001, 0.1]
+    (:func:`log_uniform_steps`), ``A`` uniform over [1, 16], ``D`` 1; the
+    convolution as ``torch.nn.Conv1d``'s default, weights and bias uniform
+    over ``+- conv^-0.5``.
+
+    The ``counters`` collection carries ``decay``, the mean over positions
+    and heads of ``exp(dt A)`` summed over the training steps, and
+    ``steps``: how long a state lives (``decay^Q`` is what is left of it a
+    chunk of ``Q`` positions later)."""
+
+    heads: int
+    head_dim: int
+    state: int
+    conv: int = 4
+    chunk: int = SSD_CHUNK
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u, train: bool = False):
+        b, t, dim = u.shape
+        h, p, n = self.heads, self.head_dim, self.state
+        inner, f32 = h * p, jnp.float32
+        with jax.named_scope(SCOPE_LM_DENSE):
+            zxbcdt = Linear(2 * inner + 2 * n + h, self.dtype,
+                            name="in_proj")(u)
+        with jax.named_scope(SCOPE_LM_SSD_PREP):
+            bound = self.conv ** -0.5
+
+            def uniform(key, shape, dtype):
+                return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+            w = self.param("conv_kernel", uniform, (self.conv, inner + 2 * n),
+                           f32)
+            w_b = self.param("conv_bias", uniform, (inner + 2 * n,), f32)
+            a_log = self.param(
+                "A_log", lambda key, shape, dtype: jnp.log(jax.random.uniform(
+                    key, shape, dtype, 1.0, 16.0)), (h,), f32)
+            dt_bias = self.param(
+                "dt_bias", lambda key, shape, dtype: log_uniform_steps(
+                    key, shape).astype(dtype), (h,), f32)
+            skip = self.param("D", nn.initializers.ones, (h,), f32)
+            z = zxbcdt[..., :inner]
+            xbc = nn.silu(causal_conv(zxbcdt[..., inner:2 * inner + 2 * n], w)
+                          + w_b).astype(self.dtype)
+            dt = jax.nn.softplus(zxbcdt[..., 2 * inner + 2 * n:].astype(f32)
+                                 + dt_bias)
+            x = xbc[..., :inner].reshape(b, t, h, p)
+            decay = self.variable(COUNTERS, "decay", lambda: jnp.zeros((), f32))
+            steps = self.variable(COUNTERS, "steps", lambda: jnp.zeros((), f32))
+            if train and not self.is_initializing():
+                decay.value = decay.value + jnp.mean(
+                    jnp.exp(-dt * jnp.exp(a_log)))
+                steps.value = steps.value + 1.0
+        with jax.named_scope(SCOPE_LM_SSD):
+            y = ssd_chunked(x, dt, a_log, xbc[..., inner:inner + n],
+                            xbc[..., inner + n:], skip, chunk=self.chunk,
+                            dtype=self.dtype)
+        with jax.named_scope(SCOPE_LM_SSD_PREP):
+            y = RMSNorm(self.eps, self.dtype, name="norm")(
+                y.reshape(b, t, inner) * nn.silu(z.astype(f32)))
+        with jax.named_scope(SCOPE_LM_DENSE):
+            return Linear(dim, self.dtype, name="out_proj")(y)

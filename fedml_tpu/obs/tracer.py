@@ -103,6 +103,13 @@ SCOPE_LM_KDA = "fedml.lm.kda"
 #: SiLU, the L2 norms of q and k, the decay and step gates, the output's
 #: norm and gate (its projections are ``fedml.lm.dense``)
 SCOPE_LM_KDA_PREP = "fedml.lm.kda_prep"
+#: the selective state-space recurrence in chunks (ops/ssd.py): the
+#: intra-chunk products, the scan over chunks, the read-out; its backward too
+SCOPE_LM_SSD = "fedml.lm.ssd"
+#: what a state-space mixer does around it: the convolution and SiLU, the
+#: splits and head reshapes, ``softplus``, the gated norm (its two
+#: projections are ``fedml.lm.dense``)
+SCOPE_LM_SSD_PREP = "fedml.lm.ssd_prep"
 #: router matmul, selection, sort, the rows' fan-out and weighted add-back
 SCOPE_LM_ROUTE = "fedml.lm.route"
 #: the grouped matmuls over the rows of the experts held here

@@ -405,13 +405,16 @@ def test_token_ids_reach_the_model_unrounded(dtype, kind):
 @pytest.mark.parametrize("fixture,cell_name", [
     ("BENCHMARK.tiny_lm.json", "tiny_kanana2_sim"),
     ("BENCHMARK.tiny_hybrid.json", "tiny_ling3_sim"),
-    ("BENCHMARK.tiny_laguna.json", "tiny_laguna_sim")])
+    ("BENCHMARK.tiny_laguna.json", "tiny_laguna_sim"),
+    ("BENCHMARK.tiny_granite4h.json", "tiny_granite4h_sim")])
 def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
     """An LM's round program carries the step's scopes and the
     ``fedml.lm.*`` names of what it is built of, as metadata only: all of
     the table but the window layers' name for the hybrid decoder, that
-    without the delta rule's two for the latent-attention one, and for the
-    window / full decoder all but the delta rule's."""
+    without the delta rule's two for the latent-attention one, for the
+    window / full decoder all but the delta rule's; the state-space
+    recurrence's two are the state-space decoder's alone, which is dense
+    and names no router and no expert."""
     import re
 
     from benchmarks.harness.cell import build_api
@@ -439,14 +442,22 @@ def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
         table -= {tracer.SCOPE_LM_KDA, tracer.SCOPE_LM_KDA_PREP}
     if "window" not in mixers:
         table -= {tracer.SCOPE_LM_ATTN_WINDOW}
+    if "ssd" not in mixers:
+        table -= {tracer.SCOPE_LM_SSD, tracer.SCOPE_LM_SSD_PREP}
+    sizes = config["model"]
+    if sizes["first_dense"] == sizes["layers"]:
+        table -= {tracer.SCOPE_LM_ROUTE, tracer.SCOPE_LM_EXPERTS}
     assert found == table
     text = lowered.as_text()
     assert "fedml." not in text
+    if sizes["first_dense"] == sizes["layers"]:
+        assert "stablehlo.case" not in text
+        api.close()
+        return
     # the sparse layers' row capacities: one conditional a layer and pass
     # (forward, and the backward that rebuilds its rung; the remat replay's
     # is dead code), a branch a rung, one shared function a rung; inside a
     # branch the LAST fedml.* name is still the route's or the experts'
-    sizes = config["model"]
     pairs = (int(config["recipe"]["batch_size"]) * sizes["seq_len"]
              * sizes["top_k"])
     rungs = moe.row_rungs(pairs)
@@ -900,3 +911,307 @@ def test_laguna_round_counts_its_rows_and_trains():
     counters = api.bundle.counters(api.variables)
     assert counters["steps.layer_1"] == 4.0 and "rows.layer_2.3" in counters
     api.close()
+
+
+# --- the state-space / attention hybrid: Mamba-2 mixers, the four ----------
+# --- multipliers, a tied head ------------------------------------------------
+
+from benchmarks.references import granite4_h_micro as gra  # noqa: E402
+
+
+def granite_config(**over):
+    sizes = {**LATENT_MOE_PRESETS["granite4h_tiny"], **over}
+    return {"name": "tiny_granite4h", "model": sizes, "data": {"vocab": VOCAB},
+            "recipe": {"lr": 0.1, "momentum": 0.0}}
+
+
+def _granite_program_and_reference(config, remat=True, **over):
+    v = jax.jit(lambda k: gra.init(k, config))(jax.random.key(7))
+    b = create_model("granite4h_tiny", VOCAB, input_shape=(32,),
+                     dtype=jnp.float32, remat=remat, **over)
+    assert (jax.tree.map(jnp.shape, b.init(jax.random.key(0)))
+            == jax.tree.map(jnp.shape, v))
+    return v, b, gra._forward(config, "reference")
+
+
+@pytest.mark.parametrize("over,remat", [
+    ({}, True),
+    ({"mixers": ["full", "ssd", "ssd", "full"], "ssd_chunk": 12}, False)])
+def test_granite_logits_loss_and_gradients_match_the_reference(monkeypatch,
+                                                               over, remat):
+    """8 state-space heads of 8 over a state of 16 in chunks of 8 (and of 12,
+    which does not divide the 32 positions), 4 query heads over 2 key-value
+    heads of 8 with no rotary and a given score scale, the four multipliers
+    and the tied head, against the reference's token-by-token recurrence;
+    the layers' pattern is data."""
+    config = granite_config(**over)
+    v, b, forward = _granite_program_and_reference(config, remat, **over)
+    x, y, m = batch(t=32)
+
+    def program(p):
+        logits, new = b.apply_train({**v, "params": p}, x, None)
+        return nwp.loss(logits, y, m), (logits, new["counters"])
+
+    def reference(p):
+        logits, stats = forward(p, v["counters"], x)
+        per = -jnp.take_along_axis(jax.nn.log_softmax(logits), y[..., None],
+                                   -1)[..., 0]
+        w = jnp.broadcast_to(m[:, None], per.shape)
+        return jnp.sum(per * w) / jnp.sum(w), (logits, stats)
+
+    with jax.default_matmul_precision("highest"):
+        (lp, (op, sp)), gp = jax.jit(jax.value_and_grad(
+            program, has_aux=True))(v["params"])
+        (lr, (orf, sr)), gr = jax.jit(jax.value_and_grad(
+            reference, has_aux=True))(v["params"])
+    np.testing.assert_allclose(op, orf, atol=5e-6)
+    np.testing.assert_allclose(lp, lr, rtol=1e-6)
+    for (path, a), c in zip(jax.tree_util.tree_leaves_with_path(gp),
+                            jax.tree.leaves(gr)):
+        np.testing.assert_allclose(a, c, atol=1e-5 * float(jnp.abs(c).max() + 1e-6)
+                                   + 1e-9, err_msg=str(path))
+    # a dense decoder with a tied head: no router, no expert, no lm_head;
+    # every leaf of the recurrence takes a gradient
+    assert "lm_head" not in gp and "router" not in str(jax.tree.map(jnp.shape, gp))
+    ssd_leaves = gp["layer_1"]["ssd"]
+    assert set(ssd_leaves) == {"in_proj", "conv_kernel", "conv_bias", "A_log",
+                               "dt_bias", "D", "norm", "out_proj"}
+    for name in ("A_log", "dt_bias", "D", "conv_bias"):
+        assert float(jnp.abs(ssd_leaves[name]).max()) > 0, name
+    assert sorted(sp) == sorted(sr) == [
+        f"layer_{i}" for i, k in enumerate(config["model"]["mixers"])
+        if k == "ssd"]
+    for name in sp:
+        np.testing.assert_allclose(sp[name]["ssd"]["decay"],
+                                   sr[name]["ssd"]["decay"], rtol=1e-6)
+        assert 0.3 < float(sp[name]["ssd"]["decay"]) < 1.0
+        assert float(sp[name]["ssd"]["steps"]) == 1.0
+    # what the two controls of its own leave out shows in the logits: the
+    # carry between blocks of the scan, and the multipliers
+    # (against the stated precision, whose rounding they share)
+    monkeypatch.setattr(gra, "_SCAN_BLOCK", 8)
+    stated = jax.jit(gra._forward(config, "stated"))(
+        v["params"], v["counters"], x)[0]
+    assert float(jnp.abs(stated - orf).max()) < 0.02 * float(jnp.abs(orf).max())
+    for variant, least in (("state_cut", 1e-4), ("scale_plain", 1e-2)):
+        other = jax.jit(gra._forward(config, variant))(
+            v["params"], v["counters"], x)[0]
+        assert float(jnp.abs(other - stated).max()) > least, variant
+
+
+@pytest.mark.parametrize("name,plain", [
+    ("embed_scale", 1.0), ("residual_scale", 1.0), ("attn_scale", None),
+    ("logit_scale", 1.0)])
+def test_each_multiplier_is_seen(name, plain):
+    """A model with one of the four multipliers left at its plain value
+    gives other logits than the configuration's, and the reference built
+    with the same one agrees with it again."""
+    config = granite_config()
+    v, b, forward = _granite_program_and_reference(config)
+    # at hidden 32 the seeded scores are near zero and every softmax near
+    # uniform, whatever its scale: widen the attention layer's q and k
+    attn = v["params"]["layer_2"]["attn"]
+    attn["q_proj"]["kernel"] = attn["q_proj"]["kernel"] * 40.0
+    attn["k_proj"]["kernel"] = attn["k_proj"]["kernel"] * 40.0
+    x, _y, _m = batch(t=32)
+    want = b.apply_eval(v, x)
+    np.testing.assert_allclose(want, forward(v["params"], v["counters"], x)[0],
+                               atol=5e-6)
+    other = create_model("granite4h_tiny", VOCAB, input_shape=(32,),
+                         dtype=jnp.float32, **{name: plain})
+    got = other.apply_eval(v, x)
+    assert float(jnp.abs(got - want).max()) > 1e-3 * float(jnp.abs(want).max())
+    plain_value = 8 ** -0.5 if plain is None else plain     # heads of 8
+    again = gra._forward(granite_config(**{name: plain_value}), "reference")
+    np.testing.assert_allclose(got, again(v["params"], v["counters"], x)[0],
+                               atol=5e-6)
+
+
+def test_mamba2_mixer_against_the_reference_and_its_gate_comes_before_the_norm():
+    """The mixer alone on the reference's seeded leaves: the joint
+    projection's split, the convolution with its bias over x, B, C together,
+    softplus steps, the skip, the gated norm over all channels. A mixer that
+    normalised BEFORE the gate would read another output."""
+    from fedml_tpu.models.transformer import Mamba2Mixer
+
+    config = granite_config()
+    m = config["model"]
+    p = jax.jit(lambda k: gra.init(k, config))(jax.random.key(5))[
+        "params"]["layer_0"]["ssd"]
+    mixer = Mamba2Mixer(m["ssd_heads"], m["ssd_head_dim"], m["ssd_state"],
+                        m["ssd_conv"], m["ssd_chunk"], m["eps"])
+    u = jax.random.normal(jax.random.key(6), (2, 32, m["dim"]))
+    with jax.default_matmul_precision("highest"):
+        zero = jnp.zeros((), jnp.float32)
+        got = mixer.apply({"params": p, "counters": {"decay": zero,
+                                                     "steps": zero}}, u)
+        want, decay = gra._forward(config, "reference").ssd(u, p)
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    assert 0.3 < float(decay) < 1.0
+    # the same recurrence, the norm first and the gate after
+    inner = m["ssd_heads"] * m["ssd_head_dim"]
+    n = m["ssd_state"]
+    zx = u @ p["in_proj"]["kernel"]
+    z, xbc, dt = (zx[..., :inner], zx[..., inner:2 * inner + 2 * n],
+                  zx[..., 2 * inner + 2 * n:])
+    pad = jnp.pad(xbc, ((0, 0), (3, 0), (0, 0)))
+    xbc = jax.nn.silu(sum(pad[:, i:i + 32] * p["conv_kernel"][i]
+                          for i in range(4)) + p["conv_bias"])
+    y = gra.recurrence(xbc[..., :inner].reshape(2, 32, m["ssd_heads"], -1),
+                       jax.nn.softplus(dt + p["dt_bias"]), p["A_log"],
+                       xbc[..., inner:inner + n], xbc[..., inner + n:], p["D"])
+    y = y.reshape(2, 32, inner)
+
+    def rms(a):
+        return a * jax.lax.rsqrt(jnp.mean(a * a, -1, keepdims=True) + m["eps"])
+
+    with jax.default_matmul_precision("highest"):
+        gate_first = (rms(y * jax.nn.silu(z)) * p["norm"]["scale"]
+                      ) @ p["out_proj"]["kernel"]
+        norm_first = (rms(y) * p["norm"]["scale"] * jax.nn.silu(z)
+                      ) @ p["out_proj"]["kernel"]
+    np.testing.assert_allclose(got, gate_first, atol=2e-5)
+    assert float(jnp.abs(got - norm_first).max()) > 0.01 * float(jnp.abs(got).max())
+
+
+def test_the_tied_tables_gradient_has_both_parts():
+    """Untie the head (a model whose ``lm_head`` is the table transposed
+    gives the same logits): the tied table's gradient is the sum of what the
+    gather and the head take there."""
+    config = granite_config()
+    v, tied, _ = _granite_program_and_reference(config)
+    untied = create_model("granite4h_tiny", VOCAB, input_shape=(32,),
+                          dtype=jnp.float32, tied_head=False)
+    x, y, m = batch(t=32)
+    p = v["params"]
+    p2 = {**p, "lm_head": {"kernel": p["embed"].T}}
+
+    def loss(bundle, variables):
+        def f(params):
+            logits, _ = bundle.apply_train({**variables, "params": params}, x, None)
+            return nwp.loss(logits, y, m)
+        return f
+
+    with jax.default_matmul_precision("highest"):
+        g = jax.grad(loss(tied, v))(p)
+        g2 = jax.grad(loss(untied, {**v, "params": p2}))(p2)
+    gather, head = g2["embed"], g2["lm_head"]["kernel"].T
+    assert float(jnp.abs(gather).max()) > 0 and float(jnp.abs(head).max()) > 0
+    np.testing.assert_allclose(g["embed"], gather + head,
+                               atol=1e-6 * float(jnp.abs(head).max()))
+    # an id no input holds takes the head's part alone
+    unused = sorted(set(range(VOCAB)) - set(np.asarray(x).ravel().tolist()))
+    assert unused and not np.any(np.asarray(gather[unused[0]]))
+    assert np.any(np.asarray(g["embed"][unused[0]]))
+
+
+@pytest.mark.parametrize("scale", [None, 0.3])
+def test_grouped_attention_without_rotary_at_a_given_scale(scale):
+    """``rotary_dim`` 0 and a given score scale against the plain formula:
+    no channel turns, ``softmax(q . k * scale)`` over the causal prefix."""
+    from fedml_tpu.models.transformer import GroupedAttention
+
+    h, g, d, t = 4, 2, 8, 12
+    mod = GroupedAttention(h, g, d, 0, scale=scale)
+    # wide inputs: at the seeded 0.02 the scores are near zero and a softmax
+    # near uniform at any scale
+    x = 40.0 * jax.random.normal(jax.random.key(1), (2, t, 16))
+    with jax.default_matmul_precision("highest"):
+        v = mod.init(jax.random.key(2), x)
+        got = mod.apply(v, x)
+        p = v["params"]
+        q = (x @ p["q_proj"]["kernel"]).reshape(2, t, h, d)
+        k = jnp.repeat((x @ p["k_proj"]["kernel"]).reshape(2, t, g, d), h // g, 2)
+        val = jnp.repeat((x @ p["v_proj"]["kernel"]).reshape(2, t, g, d), h // g, 2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (d ** -0.5 if scale is None
+                                                   else scale)
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), val)
+        want = o.reshape(2, t, h * d) @ p["o_proj"]["kernel"]
+    np.testing.assert_allclose(got, want, atol=2e-6 * float(jnp.abs(want).max()))
+    if scale is not None:
+        plain = GroupedAttention(h, g, d, 0).apply(v, x)
+        assert float(jnp.abs(plain - got).max()) > 1e-3 * float(jnp.abs(got).max())
+
+
+def test_granite_registered_defaults_are_the_published_widths():
+    k = LATENT_MOE_PRESETS["granite4_h_micro"]
+    assert (k["dim"], k["heads"], k["kv_heads"], k["v_dim"], k["rope"],
+            k["dense_width"]) == (2048, 32, 8, 64, 0, 8192)
+    assert (k["ssd_heads"], k["ssd_head_dim"], k["ssd_state"], k["ssd_conv"],
+            k["ssd_chunk"]) == (64, 64, 128, 4, 256)
+    assert (k["embed_scale"], k["residual_scale"], k["attn_scale"],
+            k["logit_scale"], k["tied_head"]) == (12.0, 0.22, 1 / 64, 8.0, True)
+    assert k["mixers"] == ["ssd"] * 5 + ["full"] + ["ssd"] * 4
+    assert k["first_dense"] == k["layers"] == 10 and k["n_routed"] == 0
+    b = create_model("granite4_h_micro", 12544)
+    shapes = jax.eval_shape(b.init, jax.random.key(0))
+
+    def count(tree):
+        return sum(int(np.prod(s.shape)) for s in jax.tree.leaves(tree))
+
+    p = shapes["params"]
+    assert count(p) == 772_160_448        # the issue's 772.2 M parameters
+    assert count(p["layer_0"]) == 76_182_976
+    assert count(p["layer_5"]) == 60_821_504
+    assert p["layer_0"]["ssd"]["in_proj"]["kernel"].shape == (2048, 8512)
+    assert p["layer_0"]["ssd"]["conv_kernel"].shape == (4, 4352)
+    assert p["layer_0"]["ssd"]["norm"]["scale"].shape == (4096,)
+    assert p["layer_5"]["attn"]["k_proj"]["kernel"].shape == (2048, 512)
+    assert p["embed"].shape == (12544, 2048) and "lm_head" not in p
+    # only the state-space layers keep counters
+    assert sorted(shapes["counters"]) == [f"layer_{i}" for i in range(10)
+                                          if i != 5]
+
+
+def test_granite_round_sums_its_decays_and_trains():
+    """One packed round of the tiny state-space model, a dense tree with no
+    ``rows.*``: the counters are sums over the clients' steps, the loss is
+    finite and every kind of leaf moves."""
+    from fedml_tpu.algorithms.fedavg import FedAvgAPI
+    from fedml_tpu.core.config import FedConfig
+    from fedml_tpu.data import FedDataset
+
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, VOCAB, (4, 3, 33)).astype(np.int32)
+    ds = FedDataset(train_x=ids[..., :-1], train_y=ids[..., 1:],
+                    train_mask=np.ones((4, 3), np.float32),
+                    train_counts=np.full((4,), 3, np.int64),
+                    test_x=ids[0, :, :-1], test_y=ids[0, :, 1:],
+                    test_mask=np.ones(3, np.float32), class_num=VOCAB,
+                    task="nwp", name="tiny")
+    cfg = FedConfig(model="granite4h_tiny", dataset="tiny", batch_size=1,
+                    epochs=1, client_optimizer="sgd", lr=0.1, momentum=0.0,
+                    client_num_in_total=4, client_num_per_round=2,
+                    pack_lanes=1, device_data="on", comm_round=1)
+    api = FedAvgAPI(ds, cfg, create_model("granite4h_tiny", VOCAB,
+                                          input_shape=(32,)))
+    before = jax.device_get(api.variables)
+    loss = float(api.run_round(1))
+    after = jax.device_get(api.variables)
+    assert np.isfinite(loss)
+    c = after["counters"]["layer_0"]["ssd"]
+    assert float(c["steps"]) == 6.0                  # 2 clients x 3 sequences
+    assert 0.3 < float(c["decay"]) / 6.0 < 1.0
+    moved = jax.tree.map(lambda a, b: float(np.abs(a - b).max()),
+                         after["params"], before["params"])
+    for leaf in ("dt_bias", "D", "conv_bias", "conv_kernel", "in_proj"):
+        leaf_moved = moved["layer_0"]["ssd"][leaf]
+        assert (leaf_moved["kernel"] if leaf == "in_proj" else leaf_moved) > 0, leaf
+    assert moved["layer_2"]["attn"]["k_proj"]["kernel"] > 0
+    assert moved["embed"] > 0
+    counters = api.bundle.counters(api.variables)
+    assert counters["steps.layer_0"] == 6.0
+    assert counters["decay.layer_3"] == float(
+        after["counters"]["layer_3"]["ssd"]["decay"])
+    assert not [k for k in counters if k.startswith("rows.")]
+    api.close()
+
+
+def test_a_model_without_counters_publishes_none():
+    """``bundle.counters`` on a tree with no ``counters`` collection (all
+    attention, all dense): an empty dict, not a failure."""
+    b = create_model("granite4h_tiny", VOCAB, input_shape=(32,),
+                     mixers=("full",) * 4)
+    v = b.init(jax.random.key(0))
+    assert "counters" not in v and b.counters(v) == {}

@@ -235,7 +235,15 @@ def test_delta_rule_scan_compiles_at_published_widths(one_chip, no_cache,
                           yarn_original=0, yarn_attention_factor=1.0,
                           score="sigmoid")),
     ("ling3_tiny", dict(kv_heads=0, window_heads=0, window=0, yarn_factor=0.0,
-                        score="sigmoid"))])
+                        score="sigmoid")),
+    ("kanana2_tiny", dict(ssd_heads=0, ssd_head_dim=64, ssd_state=128,
+                          ssd_conv=4, ssd_chunk=256, embed_scale=1.0,
+                          residual_scale=1.0, attn_scale=None, logit_scale=1.0,
+                          tied_head=False)),
+    ("ling3_tiny", dict(ssd_heads=0, embed_scale=1.0, residual_scale=1.0,
+                        attn_scale=None, logit_scale=1.0, tied_head=False)),
+    ("laguna_tiny", dict(ssd_heads=0, embed_scale=1.0, residual_scale=1.0,
+                         attn_scale=None, logit_scale=1.0, tied_head=False))])
 def test_older_lm_lowers_the_same_with_the_new_options_at_their_defaults(
         name, named):
     """The options the hybrid decoder added (the mixers' pattern, the
@@ -245,13 +253,14 @@ def test_older_lm_lowers_the_same_with_the_new_options_at_their_defaults(
     programs as they were: built with every one of them named at its default
     a model lowers to the same text as built without, and its variable tree
     has no new leaf (CPU fixtures; against the parent commit's text the
-    three fixtures' round programs were checked by sha256, ``PERF.md`` PR 30
-    and PR 32)."""
+    fixtures' round programs were checked by sha256, ``PERF.md`` PR 30,
+    PR 32 and PR 37)."""
     from fedml_tpu.core.tasks import nwp
     from fedml_tpu.models import create_model
 
     def lowered(**kw):
-        b = create_model(name, 64, input_shape=(16,), **kw)
+        b = create_model(name, 64, input_shape=(32 if name == "laguna_tiny"
+                                                else 16,), **kw)
         v = b.init(jax.random.key(0))
 
         def step(v, x, y, m):
@@ -260,7 +269,7 @@ def test_older_lm_lowers_the_same_with_the_new_options_at_their_defaults(
                 return nwp.loss(logits, y, m), new
             return jax.value_and_grad(loss, has_aux=True)(v["params"])
 
-        x = jnp.zeros((2, 16), jnp.int32)
+        x = jnp.zeros((2,) + tuple(b.input_shape), jnp.int32)
         return (jax.jit(step).lower(v, x, x, jnp.ones((2,))).as_text(),
                 jax.tree.map(jnp.shape, v))
 
@@ -269,3 +278,58 @@ def test_older_lm_lowers_the_same_with_the_new_options_at_their_defaults(
     assert plain == text and tree == tree2
     if name == "kanana2_tiny":
         assert "group_tokens" not in str(tree) and "out_gate" not in str(tree)
+
+
+@pytest.mark.parametrize("chunk", [128, 256])
+def test_state_space_recurrence_compiles_at_published_widths(one_chip, no_cache,
+                                                             chunk):
+    """64 heads of 64 over a state of 128, 4,096 positions, one sequence,
+    bf16 operands: forward and backward of the chunked recurrence
+    (``ops/ssd.py``, plain ``jax.numpy``). One loop, the scan over the
+    chunks' states; no per-position state (8.6 GB) is ever formed, and the
+    masked decays of every head and chunk (``T x Q x H`` float32, 268 MB at
+    256) with their cotangents stay under 1.5 GB of temporaries."""
+    from fedml_tpu.ops import ssd
+
+    def step(x, dt, a_log, b, c, d, ct):
+        return jax.grad(lambda *a: jnp.sum(ssd.ssd_chunked(
+            *a, chunk=chunk) * ct), argnums=(0, 1, 2, 3, 4, 5))(
+                x, dt, a_log, b, c, d)
+
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(step).lower(
+        sd(jnp.bfloat16, 1, 4096, 64, 64), sd(jnp.float32, 1, 4096, 64),
+        sd(jnp.float32, 64), sd(jnp.bfloat16, 1, 4096, 128),
+        sd(jnp.bfloat16, 1, 4096, 128), sd(jnp.float32, 64),
+        sd(jnp.float32, 1, 4096, 64, 64)).compile()
+    text = compiled.as_text()
+    assert " while(" in text and "tpu_custom_call" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_position_free_attention_compiles_at_heads_of_64(one_chip, no_cache):
+    """32 query heads over 8 key-value heads of 64 channels (half a lane
+    tile; ``_pad_qk`` leaves them as they are), 4,096 positions, one
+    sequence, scores times 1/64: the forward kernel and the one backward
+    kernel."""
+    import importlib
+
+    att = importlib.import_module("fedml_tpu.ops.attention")
+
+    def step(q, k, v, c):
+        return jax.grad(lambda q, k, v: jnp.sum(att.attention(
+            q, k, v, impl="pallas", block_q=1024, block_k=1024,
+            sm_scale=0.015625).astype(jnp.float32) * c), argnums=(0, 1, 2))(
+                q, k, v)
+
+    def sd(h):
+        return jax.ShapeDtypeStruct((1, h, 4096, 64), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(step).lower(sd(32), sd(8), sd(8), sd(32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    _dq, dk, dv = jax.eval_shape(step, sd(32), sd(8), sd(8), sd(32))
+    assert dk.shape == dv.shape == (1, 8, 4096, 64)

@@ -27,7 +27,11 @@ key-value heads of 128 under a window of 512: ``--heads 64 --kv-heads 8
 --head-dim 128 --window 512``). ``--repeat-kv`` times the other way to serve
 grouped heads: ``k`` and ``v`` repeated ``heads / kv_heads`` times BEFORE
 kernels that then see equal heads, and their gradients summed over the group
-after, both inside the timed call.
+after, both inside the timed call. ``--pad-to 128`` times a NARROW head the
+other way (a state-space hybrid's attention layer has heads of 64, half a
+lane tile): ``q``, ``k``, ``v`` and the output's cotangent zero-padded to that
+width before the kernels (scores unchanged; the output's extra channels are
+zeros that a slice drops), against the same command without it.
 
 Fails at once without a TPU. Writes ``chiprun_out/attn_sweep.json``.
 """
@@ -87,7 +91,8 @@ def _device_ms(fn, args, calls: int = 3) -> dict:
 
 
 def measure(shape, d, dv, tile, sub_q, sub_k, iters, interpret=False,
-            trace=True, kv_heads=None, window=None, repeat_kv=False):
+            trace=True, kv_heads=None, window=None, repeat_kv=False,
+            pad_to=None):
     """-> (row, results): one configuration's kernels."""
     import jax
     import jax.numpy as jnp
@@ -102,6 +107,10 @@ def measure(shape, d, dv, tile, sub_q, sub_k, iters, interpret=False,
     v, do = (jax.random.normal(keys[i], (b, n, t, dv), jnp.bfloat16)
              for i, n in ((2, g), (3, h)))
     scale = d ** -0.5
+    if pad_to:
+        q, k, v, do = (jnp.pad(a, [(0, 0)] * 3 + [(0, pad_to - a.shape[-1])])
+                       for a in (q, k, v, do))
+        dv = pad_to
     q, k = att._pad_qk(q, k)
 
     def spread(a):      # a key-value head for each of its query heads
@@ -148,6 +157,7 @@ def measure(shape, d, dv, tile, sub_q, sub_k, iters, interpret=False,
                                      window=window)
     row = {"tile": tile, "sub_q": sub_q, "sub_k": sub_k, "heads": h,
            "kv_heads": g, "window": window, "repeat_kv": repeat_kv,
+           "head_dim": d, "padded_to": pad_to,
            "bwd_form": ("two kernels" if vmem is None else
                         f"one call, {vmem >> 20} MiB of VMEM"),
            "executed_score_share": share}
@@ -229,6 +239,8 @@ def main(argv=None) -> int:
     ap.add_argument("--value-dim", type=int, default=128)
     ap.add_argument("--window", type=int)
     ap.add_argument("--repeat-kv", action="store_true")
+    ap.add_argument("--pad-to", type=int,
+                    help="zero-pad q, k, v and the cotangent to this width")
     ap.add_argument("--out", default="chiprun_out/attn_sweep.json")
     args = ap.parse_args(argv)
     if jax.default_backend() != "tpu":
@@ -242,7 +254,7 @@ def main(argv=None) -> int:
                 (args.batch, args.heads, args.seq_len), args.head_dim,
                 args.value_dim, tile, sub_q, sub_k, args.iters,
                 kv_heads=args.kv_heads, window=args.window,
-                repeat_kv=args.repeat_kv)
+                repeat_kv=args.repeat_kv, pad_to=args.pad_to)
         except Exception as e:  # noqa: BLE001  the compiler refused the row
             print(json.dumps({"row": spec, "failed": str(e)[-600:]}), flush=True)
             continue
