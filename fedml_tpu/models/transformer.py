@@ -186,6 +186,33 @@ class Linear(nn.Module):
         return y.astype(self.out_dtype or self.dtype)
 
 
+def column_products(x, kernel, widths, dtype):
+    """``x @ kernel[:, a:b]`` for each of the neighbouring column ranges of
+    ``widths``, each a product of its own with ``Linear``'s precision."""
+    edges = np.cumsum((0,) + tuple(widths))
+    x = x.astype(dtype)
+    return tuple(
+        jnp.dot(x, kernel[:, a:b].astype(dtype),
+                preferred_element_type=jnp.float32).astype(dtype)
+        for a, b in zip(edges[:-1], edges[1:]))
+
+
+class ColumnLinear(nn.Module):
+    """``Linear``'s one ``kernel`` of ``sum(widths)`` columns (the same leaf,
+    initialiser and column order) issued as one product a column range:
+    each consumer of a joint projection takes the output of its own product
+    and nothing is cut from a shared output after the fact."""
+
+    widths: tuple
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", _normal(),
+                            (x.shape[-1], sum(self.widths)), jnp.float32)
+        return column_products(x, kernel, self.widths, self.dtype)
+
+
 class RMSNorm(nn.Module):
     """``x / sqrt(mean(x^2) + eps) * scale``, statistics in float32."""
 
@@ -517,15 +544,25 @@ class Mamba2Mixer(nn.Module):
     """A Mamba-2 state-space mixer (arXiv:2405.21060; one group: ``B`` and
     ``C`` are shared by all heads). ``[z | xBC | dt] = W_in u``, widths
     ``H P``, ``H P + 2 N``, ``H``; ``xBC = SiLU(conv(xBC) + b_conv)``, a
-    causal depthwise convolution of ``conv`` positions over ``x``, ``B``,
-    ``C`` together; ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)``
-    a head; the recurrence (``ops/ssd.py``) with the skip ``D x``; ``y =
-    RMSNorm(y * SiLU(z)) * w``, the gate BEFORE the norm and one norm over
-    all ``H P`` channels; ``out = W_out y``. The recurrence's parameters
-    start as the public code's do: ``dt`` log-uniform over [0.001, 0.1]
-    (:func:`log_uniform_steps`), ``A`` uniform over [1, 16], ``D`` 1; the
-    convolution as ``torch.nn.Conv1d``'s default, weights and bias uniform
-    over ``+- conv^-0.5``.
+    causal depthwise convolution of ``conv`` positions over the channels of
+    ``x``, ``B``, ``C``; ``dt = softplus(dt + dt_bias)`` and ``A =
+    -exp(A_log)`` a head; the recurrence (``ops/ssd.py``) with the skip ``D
+    x``; ``y = RMSNorm(y * SiLU(z)) * w``, the gate BEFORE the norm and one
+    norm over all ``H P`` channels; ``out = W_out y``. The recurrence's
+    parameters start as the public code's do: ``dt`` log-uniform over
+    [0.001, 0.1] (:func:`log_uniform_steps`), ``A`` uniform over [1, 16],
+    ``D`` 1; the convolution as ``torch.nn.Conv1d``'s default, weights and
+    bias uniform over ``+- conv^-0.5``.
+
+    The joint projection is issued as one product a consumer (``z``, ``x``,
+    ``B | C``, ``dt``) over column slices of the one ``in_proj/kernel``
+    (:class:`ColumnLinear`; the depthwise convolution then runs over ``x``
+    and over ``B | C`` with their own columns of ``conv_kernel``, the same
+    function channel by channel), because XLA, which keeps or computes
+    again an op's WHOLE result, computed a single ``[T, 2 H P + 2 N + H]``
+    product 9.3 times a layer-step for its consumers' different lifetimes
+    where four passes are needed: 36% of the bf16 peak on the required
+    work, 73% as four products (``PERF.md``, PR 38).
 
     The ``counters`` collection carries ``decay``, the mean over positions
     and heads of ``exp(dt A)`` summed over the training steps, and
@@ -546,8 +583,8 @@ class Mamba2Mixer(nn.Module):
         h, p, n = self.heads, self.head_dim, self.state
         inner, f32 = h * p, jnp.float32
         with jax.named_scope(SCOPE_LM_DENSE):
-            zxbcdt = Linear(2 * inner + 2 * n + h, self.dtype,
-                            name="in_proj")(u)
+            z, x, bc, dt = ColumnLinear((inner, inner, 2 * n, h), self.dtype,
+                                        name="in_proj")(u)
         with jax.named_scope(SCOPE_LM_SSD_PREP):
             bound = self.conv ** -0.5
 
@@ -564,12 +601,11 @@ class Mamba2Mixer(nn.Module):
                 "dt_bias", lambda key, shape, dtype: log_uniform_steps(
                     key, shape).astype(dtype), (h,), f32)
             skip = self.param("D", nn.initializers.ones, (h,), f32)
-            z = zxbcdt[..., :inner]
-            xbc = nn.silu(causal_conv(zxbcdt[..., inner:2 * inner + 2 * n], w)
-                          + w_b).astype(self.dtype)
-            dt = jax.nn.softplus(zxbcdt[..., 2 * inner + 2 * n:].astype(f32)
-                                 + dt_bias)
-            x = xbc[..., :inner].reshape(b, t, h, p)
+            x = nn.silu(causal_conv(x, w[:, :inner]) + w_b[:inner])
+            x = x.astype(self.dtype).reshape(b, t, h, p)
+            bc = nn.silu(causal_conv(bc, w[:, inner:]) + w_b[inner:])
+            bc = bc.astype(self.dtype)
+            dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
             decay = self.variable(COUNTERS, "decay", lambda: jnp.zeros((), f32))
             steps = self.variable(COUNTERS, "steps", lambda: jnp.zeros((), f32))
             if train and not self.is_initializing():
@@ -577,9 +613,8 @@ class Mamba2Mixer(nn.Module):
                     jnp.exp(-dt * jnp.exp(a_log)))
                 steps.value = steps.value + 1.0
         with jax.named_scope(SCOPE_LM_SSD):
-            y = ssd_chunked(x, dt, a_log, xbc[..., inner:inner + n],
-                            xbc[..., inner + n:], skip, chunk=self.chunk,
-                            dtype=self.dtype)
+            y = ssd_chunked(x, dt, a_log, bc[..., :n], bc[..., n:], skip,
+                            chunk=self.chunk, dtype=self.dtype)
         with jax.named_scope(SCOPE_LM_SSD_PREP):
             y = RMSNorm(self.eps, self.dtype, name="norm")(
                 y.reshape(b, t, inner) * nn.silu(z.astype(f32)))

@@ -4,6 +4,7 @@ kanana2_30b_a3b.py``), the share against the uncut layer, one federated
 round, and token ids through the resident stack. The ops it is built on:
 ``tests/test_lm_ops.py``."""
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1072,6 +1073,125 @@ def test_mamba2_mixer_against_the_reference_and_its_gate_comes_before_the_norm()
                       ) @ p["out_proj"]["kernel"]
     np.testing.assert_allclose(got, gate_first, atol=2e-5)
     assert float(jnp.abs(got - norm_first).max()) > 0.01 * float(jnp.abs(got).max())
+
+
+# the joint projection's widths: 2 H P + 2 N + H = 168 (the tiny preset: no
+# multiple of a lane tile's 128, as the published 8,512 is none) and 384
+MIXER_WIDTHS = {168: {}, 384: {"ssd_heads": 16, "ssd_head_dim": 8,
+                               "ssd_state": 56}}
+
+
+def _mixer_and_reference(width, dtype):
+    from fedml_tpu.models.transformer import Mamba2Mixer
+
+    config = granite_config(**MIXER_WIDTHS[width])
+    m = config["model"]
+    assert (2 * m["ssd_heads"] * m["ssd_head_dim"] + 2 * m["ssd_state"]
+            + m["ssd_heads"]) == width
+    p = jax.jit(lambda k: gra.init(k, config))(jax.random.key(5))[
+        "params"]["layer_0"]["ssd"]
+    mixer = Mamba2Mixer(m["ssd_heads"], m["ssd_head_dim"], m["ssd_state"],
+                        m["ssd_conv"], m["ssd_chunk"], m["eps"], dtype)
+    zero = jnp.zeros((), jnp.float32)
+
+    def program(p, u):
+        return mixer.apply({"params": p, "counters": {"decay": zero,
+                                                      "steps": zero}}, u)
+
+    variant = "reference" if dtype == jnp.float32 else "stated"
+    return m, p, program, lambda p, u: gra._forward(config, variant).ssd(u, p)[0]
+
+
+@pytest.mark.parametrize("width", sorted(MIXER_WIDTHS))
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 8e-3)])
+def test_mamba2_mixer_and_every_gradient_against_the_single_lin_reference(
+        width, dtype, tol):
+    """The joint projection as one product a consumer over column slices of
+    the ONE kernel against the reference's single ``lin`` cut after the
+    fact: the output, and the gradient of every leaf (``in_proj/kernel``
+    whole, one ``[d, 2 H P + 2 N + H]`` array) and of the input."""
+    dtype = jnp.dtype(dtype)
+    m, p, program, reference = _mixer_and_reference(width, dtype)
+    u = jax.random.normal(jax.random.key(6), (2, 32, m["dim"])).astype(dtype)
+    c = jax.random.normal(jax.random.key(8), (2, 32, m["dim"]))
+
+    def both(fn):
+        def loss(p, u):
+            out = fn(p, u)
+            return jnp.sum(out.astype(jnp.float32) * c), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))
+
+    with jax.default_matmul_precision("highest"):
+        (_, got), (gp, gu) = both(program)(p, u)
+        (_, want), (rp, ru) = both(reference)(p, u)
+    assert got.dtype == dtype and gu.dtype == dtype
+    assert gp["in_proj"]["kernel"].shape == (m["dim"], width)
+    assert set(gp) == {"in_proj", "conv_kernel", "conv_bias", "A_log",
+                       "dt_bias", "D", "norm", "out_proj"}
+    for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path((got, gp, gu)),
+            jax.tree.leaves((want, rp, ru))):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(b).max() > 0, path
+        np.testing.assert_allclose(a, b, atol=tol * np.abs(b).max(), rtol=0,
+                                   err_msg=str(path))
+
+
+def test_mamba2_mixer_tree_is_the_parents_leaf_for_leaf():
+    """Paths, shapes and dtypes as the parent commit's mixer made them (one
+    ``in_proj/kernel`` of all the joint projection's columns, no padded,
+    re-ordered or second leaf), and the same seeded numbers in it:
+    ``Linear``'s initialiser under ``Linear``'s path."""
+    from fedml_tpu.models.transformer import Linear, Mamba2Mixer
+
+    u = jnp.zeros((1, 16, 32))
+    v = Mamba2Mixer(8, 8, 16, 4, 8).init(jax.random.key(3), u)
+    assert {jax.tree_util.keystr(path): (leaf.shape, str(leaf.dtype))
+            for path, leaf in jax.tree_util.tree_leaves_with_path(v)} == {
+        "['counters']['decay']": ((), "float32"),
+        "['counters']['steps']": ((), "float32"),
+        "['params']['A_log']": ((8,), "float32"),
+        "['params']['D']": ((8,), "float32"),
+        "['params']['conv_bias']": ((96,), "float32"),
+        "['params']['conv_kernel']": ((4, 96), "float32"),
+        "['params']['dt_bias']": ((8,), "float32"),
+        "['params']['in_proj']['kernel']": ((32, 168), "float32"),
+        "['params']['norm']['scale']": ((64,), "float32"),
+        "['params']['out_proj']['kernel']": ((64, 32), "float32")}
+    kernel = v["params"]["in_proj"]["kernel"]
+    # the parent's numbers under the same key (read from its checkout)
+    np.testing.assert_allclose(
+        [kernel[0, 0], kernel[31, 167], jnp.abs(kernel).sum()],
+        [-0.016635634005069733, 0.0031889884267002344, 84.93415832519531],
+        rtol=1e-6)
+
+    class Joint(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return Linear(168, name="in_proj")(x)
+
+    np.testing.assert_array_equal(
+        kernel, Joint().init(jax.random.key(3), u)["params"]["in_proj"]["kernel"])
+
+
+@pytest.mark.parametrize("name,column", [
+    ("z", 5), ("x", 64 + 5), ("B", 128 + 5), ("C", 128 + 16 + 5),
+    ("dt", 128 + 32 + 5)])
+def test_each_slice_of_in_proj_reaches_its_consumer(name, column):
+    """One column of ``in_proj/kernel`` moved, in each consumer's range: the
+    output moves, and it is again the reference's on the moved kernel (a
+    slice wired to another consumer would read another output)."""
+    m, p, program, reference = _mixer_and_reference(168, jnp.float32)
+    u = jax.random.normal(jax.random.key(6), (2, 32, m["dim"]))
+    moved = {**p, "in_proj": {"kernel": p["in_proj"]["kernel"].at[
+        :, column].add(0.5)}}
+    with jax.default_matmul_precision("highest"):
+        before, after = program(p, u), program(moved, u)
+        want = reference(moved, u)
+    assert float(jnp.abs(after - before).max()) > 1e-3 * float(
+        jnp.abs(before).max()), name
+    np.testing.assert_allclose(after, want, atol=1e-5 * float(
+        jnp.abs(want).max()))
 
 
 def test_the_tied_tables_gradient_has_both_parts():

@@ -3,6 +3,8 @@ widths: no chip, no result, only what the chip's compiler would refuse
 (a tiling, the fast memory a kernel may use, a batched grouped matmul).
 All topology work happens inside the fixtures, in this one file."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -307,6 +309,34 @@ def test_state_space_recurrence_compiles_at_published_widths(one_chip, no_cache,
     text = compiled.as_text()
     assert " while(" in text and "tpu_custom_call" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_state_space_mixer_issues_a_product_a_consumer(one_chip, no_cache):
+    """The whole mixer at the published widths (hidden 2,048; 64 heads of 64
+    over a state of 128: a joint projection of 8,512 columns, 66.5 lane
+    tiles), forward and backward in bf16 on one sequence of 4,096: no
+    array of all 8,512 columns but the ONE kernel and its gradient, so no
+    consumer's slice is cut from a joint output that the compiler would
+    have to keep, or compute again, whole (``PERF.md``, PR 38)."""
+    from fedml_tpu.models.transformer import Mamba2Mixer
+
+    mixer = Mamba2Mixer(64, 64, 128, dtype=jnp.bfloat16)
+    u = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+    v = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.key(0), u))
+
+    def step(v, u, c):
+        return jax.grad(lambda p, u: jnp.sum(mixer.apply(
+            {**v, "params": p}, u).astype(jnp.float32) * c), argnums=(0, 1))(
+                v["params"], u)
+
+    text = jax.jit(step).lower(v, u, u).compile().as_text()
+    wide = set(re.findall(r" = \(?(\w+\[[\d,]*8512\])", text))
+    assert wide and all(shape.endswith("[2048,8512]") for shape in wide), wide
+    gp, gu = jax.eval_shape(step, v, u, u)
+    assert gp["in_proj"]["kernel"].shape == (2048, 8512)
+    assert gu.shape == (1, 4096, 2048)
 
 
 def test_position_free_attention_compiles_at_heads_of_64(one_chip, no_cache):

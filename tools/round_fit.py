@@ -11,13 +11,20 @@ program of the cell's first round for ``topologies.get_topology_desc("tpu",
 results, temporaries and program text, and their sum against the chip's
 16 GiB. Nothing runs: no time, no result (``PERF.md``, PR 30). The model's
 parameters are initialised on the host (3.3 GB for 822 M), so this takes a
-few minutes. ``--seq-len`` / ``--batch`` override the configuration's.
+few minutes. ``--seq-len`` / ``--batch`` override the configuration's;
+``--hlo FILE`` also writes the compiled program's text there and prints how
+many times a step the compiler issues each module's matmuls, by pass (the
+first forward, the forward again under ``nn.remat``, the backward): more
+than the layers ask for is XLA's OWN rematerialisation (``PERF.md``, PR 38:
+the state-space mixer's joint ``in_proj`` 84 times where 36 were asked for).
 """
 
 from __future__ import annotations
 
 import argparse
+import collections
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -26,11 +33,27 @@ if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
 
 
+def products(text: str) -> dict:
+    """{(module path after its ``fedml.*`` name, pass): matmuls} of a compiled
+    program's text, from the ``op_name`` of every ``convolution``."""
+    out = collections.Counter()
+    for op in re.findall(r" convolution\(.*op_name=\"([^\"]*)\"", text):
+        if "fedml.lm." not in op:
+            continue
+        owner = re.search(r"layer_\d+/(\w+)/", op)
+        tail = re.split(r"fedml\.[\w.]+/", op)[-1].replace("/dot_general", "")
+        which = ("remat-fwd" if "rematted_computation" in op
+                 else "bwd" if "transpose(" in op else "fwd")
+        out[(f"{owner.group(1)}/" if owner else "") + tail, which] += 1
+    return out
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--workload", required=True)
     p.add_argument("--seq-len", type=int)
     p.add_argument("--batch", type=int)
+    p.add_argument("--hlo")
     args = p.parse_args(argv)
 
     import jax
@@ -84,6 +107,15 @@ def main(argv=None) -> int:
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
         concrete)
     compiled = step.lower(*shapes).compile()
+    if args.hlo:
+        text = compiled.as_text()
+        with open(args.hlo, "w") as f:
+            f.write(text)
+        count = products(text)
+        for name in sorted({k[0] for k in count}):
+            print(f"  matmuls of {name:24s}" + "".join(
+                f"  {which} {count[name, which]:3d}"
+                for which in ("fwd", "remat-fwd", "bwd")))
     m = compiled.memory_analysis()
     parts = {"arguments": m.argument_size_in_bytes,
              "results": m.output_size_in_bytes,
