@@ -40,10 +40,12 @@ import dataclasses
 from typing import Optional
 
 from fedml_tpu.models import COUNTERS, ModelBundle, register_model
-from fedml_tpu.models.transformer import (DeltaAttention, GroupedAttention,
+from fedml_tpu.models.transformer import (CompressedConvAttention,
+                                          DeltaAttention, GroupedAttention,
                                           LatentAttention, Linear,
                                           Mamba2Mixer, RMSNorm, SelfAttention,
-                                          SwiGLU, _normal, yarn_frequencies)
+                                          SwiGLU, _normal, fan_in_uniform,
+                                          yarn_frequencies)
 from fedml_tpu.obs.tracer import (SCOPE_LM_DENSE, SCOPE_LM_EXPERTS,
                                   SCOPE_LM_ROUTE)
 from fedml_tpu.ops.grouped_matmul import (embed_rows, fan_out_rows,
@@ -161,11 +163,12 @@ def chosen_groups(biased: jax.Array, n_group: int, topk_group: int):
 
 
 def route(scores: jax.Array, bias: jax.Array, top_k: int, scaling: float,
-          groups: Optional[jax.Array] = None):
+          groups: Optional[jax.Array] = None, normalise: bool = True):
     """``scores [N, E]`` (sigmoid or softmax, float32) -> ``(idx [N, k],
     weights [N, k])``: the ``k`` experts with the largest ``score + bias``
     (``bias`` None: the largest scores), weighted by
-    their own scores over the chosen scores' sum, times ``scaling``. With
+    their own scores over the chosen scores' sum (not ``normalise``: by
+    their own scores as they are), times ``scaling``. With
     ``groups [N, n_group]`` (:func:`chosen_groups`) the choice is
     group-limited: only the experts of a token's chosen groups stand for it.
     Gradients flow through the scores, not through the selection or the
@@ -179,7 +182,47 @@ def route(scores: jax.Array, bias: jax.Array, top_k: int, scaling: float,
     _, idx = jax.lax.top_k(biased, top_k)
     hot = jax.nn.one_hot(idx, scores.shape[-1], dtype=scores.dtype)
     chosen = jnp.einsum("nke,ne->nk", hot, scores)
-    return idx, chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
+    if normalise:
+        chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return idx, chosen * scaling
+
+
+@jax.custom_vjp
+def pulled(weights: jax.Array, bias: jax.Array, pull: jax.Array):
+    """``weights`` as they are. On the way back ``bias`` takes ``pull`` as
+    its cotangent, whatever reaches ``weights`` (which passes): a balancing
+    bias has no gradient of the loss's (:func:`route`), and the client's
+    optimizer moves it by what the LOAD asks instead, ``bias <- bias - lr *
+    pull``, in the step that moves every other leaf."""
+    return weights
+
+
+def _pulled_fwd(weights, bias, pull):
+    return weights, pull
+
+
+def _pulled_bwd(pull, ct):
+    return ct, pull, jnp.zeros_like(pull)
+
+
+pulled.defvjp(_pulled_fwd, _pulled_bwd)
+
+
+def load_pull(scores: jax.Array, idx: jax.Array, rate: float) -> jax.Array:
+    """What a balancing bias is moved by, ``[E]``: each choice's excess load
+    over an even share, ``E * load - 1`` (``load`` the share of the batch's
+    (token, choice) pairs that chose it) held to ``+- 1``, times the scores'
+    own spread (their standard deviation over the batch: a bias is worth
+    what the scores it stands beside are apart) times ``rate``. A choice
+    that took too much falls, one that took too little rises. The bound: a
+    batch's padded sequence is one id 4,096 times, all of it one choice's,
+    and moves that choice's bias no further than a choice with twice its
+    share would."""
+    e = scores.shape[-1]
+    load = jnp.mean(jax.nn.one_hot(idx, e, dtype=jnp.float32), axis=(0, 1))
+    return jax.lax.stop_gradient(
+        rate * jnp.std(scores.astype(jnp.float32))
+        * jnp.clip(load * e - 1.0, -1.0, 1.0))
 
 
 #: The row capacities a sparse layer chooses from, as shares of its ``n * k``
@@ -309,6 +352,55 @@ def _routed_bwd(rungs, operands, ct):
 routed_rows.defvjp(_routed_fwd, _routed_bwd)
 
 
+class MlpRouter(nn.Module):
+    """A router that is an MLP and reads the router of the layer before it
+    (the ZAYA1 router, arXiv:2511.17127). ``s = x W_d + b_d`` in ``hidden``
+    channels; with a ``carry`` (what the router of the layer before ended
+    this step with, its own carry in it) ``s += gamma * carry``, ``gamma``
+    one learned number seeded at 0.5; ``r = RMSNorm(s)``, two layers ``r <-
+    GELU(r W + b)`` (the erf form), ``logits = r W_3`` over ``n_out``
+    choices; -> ``(softmax(logits) [N, n_out], bias [n_out], s [N,
+    hidden])``. ``bias`` is the balancing bias the choice adds to the
+    scores (:func:`route`: no gradient of the loss reaches it; the layer's
+    ``balance_rate`` moves it against the load, :func:`load_pull`; zeros
+    here, and a seeded router's choices then take uneven shares: the
+    benchmark's seeded weights carry a bias balanced on a batch drawn from
+    the seed, ``benchmarks/references/zaya1_8b.py``). The four
+    matrices and their biases start as ``torch.nn.Linear``'s default does
+    (uniform over ``+- fan_in^-0.5``), so that the logits' spread does not
+    depend on the widths. All of it in float32 at the highest matmul
+    precision, as the linear router: a token's ONE choice decides its whole
+    MLP."""
+
+    n_out: int
+    hidden: int
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x, carry=None):
+        f32 = jnp.float32
+
+        def dense(name, a, features, bias=True):
+            init = fan_in_uniform(a.shape[-1])
+            w = self.param(f"{name}_kernel", init, (a.shape[-1], features),
+                           f32)
+            y = jnp.dot(a, w, precision=jax.lax.Precision.HIGHEST)
+            if bias:
+                y = y + self.param(f"{name}_bias", init, (features,), f32)
+            return y
+
+        s = dense("down", x.astype(f32), self.hidden)
+        if carry is not None:
+            s = s + carry * self.param(
+                "gamma", nn.initializers.constant(0.5), (), f32)
+        r = RMSNorm(self.eps, f32, name="norm")(s)
+        for name in ("fc1", "fc2"):
+            r = jax.nn.gelu(dense(name, r, self.hidden), approximate=False)
+        logits = dense("out", r, self.n_out, bias=False)
+        bias = self.param("bias", nn.initializers.zeros, (self.n_out,), f32)
+        return jax.nn.softmax(logits, axis=-1), bias, s
+
+
 class SharedRoutedMoe(nn.Module):
     """Shared experts on every token + the weighted sum of each token's
     chosen routed experts, for the experts HELD here.
@@ -329,7 +421,22 @@ class SharedRoutedMoe(nn.Module):
     ``n_group > 1``: the router's choice is group-limited (:func:`route`).
     ``score``: ``"sigmoid"`` scores each expert by itself and chooses by
     ``score + bias`` (DeepSeek-V3's router); ``"softmax"`` scores over all
-    ``n_routed`` logits and has no bias (the Qwen-MoE lineage's).
+    ``n_routed`` logits and has no bias (the Qwen-MoE lineage's). Both are
+    one matrix inside the layer. ``router_hidden``: the router is an
+    :class:`MlpRouter` of that width instead, a module of its own
+    (``router/...``) that takes the ``carry`` of the layer before and hands
+    on its own: the layer returns ``(output, carry)``, ``carry`` None where
+    the router keeps none; it has one output more than there are experts, a
+    choice that is NO expert (index ``n_routed``: nobody's pair on any
+    share, no row, nothing added). With ONE choice a
+    token its weight is the score itself (over the chosen scores' sum it
+    would be the constant 1 and the router would learn nothing).
+    ``balance_rate``: the balancing bias follows the load. No gradient of
+    the loss reaches it; each training step hands the client's optimizer
+    :func:`load_pull` in its place (:func:`pulled`), so plain SGD moves it
+    by ``- lr * balance_rate * std(scores) * (E * load - 1)`` between steps
+    and the round's aggregate is the clients' weighted mean of it, as of any
+    parameter (0: the bias stays as it was loaded).
 
     The ``counters`` collection (``models.COUNTERS``) carries
     ``expert_rows`` (rows each held expert has computed, summed over the
@@ -338,7 +445,8 @@ class SharedRoutedMoe(nn.Module):
     the tokens among whose chosen groups is one with an expert held here.
     The packed simulation round sums them over the round's clients
     (float32: exact up to 2**24 rows an expert), and
-    ``ModelBundle.counters`` reads them on the host.
+    ``ModelBundle.counters`` reads them on the host. Under an MLP router
+    also ``skipped``, the tokens whose choice was no expert.
     """
 
     n_routed: int
@@ -352,9 +460,12 @@ class SharedRoutedMoe(nn.Module):
     n_group: int = 1
     topk_group: int = 1
     score: str = "sigmoid"
+    router_hidden: int = 0
+    eps: float = 1e-6
+    balance_rate: float = 0.0
 
     @nn.compact
-    def __call__(self, x, train: bool = False):
+    def __call__(self, x, train: bool = False, carry=None):
         b, t, d = x.shape
         n, k, dt = b * t, self.top_k, self.dtype
         held = self.n_routed if self.held_count is None else self.held_count
@@ -371,23 +482,21 @@ class SharedRoutedMoe(nn.Module):
         w_up = experts("up", d, self.width)
         w_down = experts("down", self.width, d)
         with jax.named_scope(SCOPE_LM_ROUTE):
-            w_r = self.param("router", _normal(), (d, self.n_routed),
-                             jnp.float32)
-            logits = jnp.dot(xf.astype(jnp.float32), w_r,
-                             precision=jax.lax.Precision.HIGHEST)
-            if self.score == "softmax":
-                if self.n_group > 1:
-                    raise ValueError("a softmax router has no groups")
-                bias, scores = None, jax.nn.softmax(logits, axis=-1)
+            if self.router_hidden:
+                scores, bias, carry = MlpRouter(
+                    self.n_routed + 1, self.router_hidden, self.eps,
+                    name="router")(xf, carry)
             else:
-                bias = self.param("e_score_correction_bias", _normal(0.01),
-                                  (self.n_routed,), jnp.float32)
-                scores = jax.nn.sigmoid(logits)
+                scores, bias = self._linear_scores(xf)
             groups = None
             if self.n_group > 1:
                 groups = chosen_groups(jax.lax.stop_gradient(scores + bias),
                                        self.n_group, self.topk_group)
-            idx, weights = route(scores, bias, k, self.scaling, groups)
+            idx, weights = route(scores, bias, k, self.scaling, groups,
+                                 normalise=k > 1)
+            if self.balance_rate:
+                weights = pulled(weights, bias, load_pull(
+                    scores, idx, self.balance_rate))
             self.sow("intermediates", "choices", idx)
             # pairs are laid out choice-major, [k, N]: a [k, N, D] view pads
             # no axis to the TPU's tiles, which [N, k, D] would (k = 6)
@@ -417,7 +526,28 @@ class SharedRoutedMoe(nn.Module):
                                 (self.held_first + held - 1) // size + 1]
                 reached.value = reached.value + jnp.sum(
                     jnp.any(mine_g, axis=1).astype(jnp.float32))
-        return (out + routed.astype(dt)).reshape(b, t, d)
+        if self.router_hidden:
+            skipped = self.variable(COUNTERS, "skipped",
+                                    lambda: jnp.zeros((), jnp.float32))
+            if train and not self.is_initializing():
+                skipped.value = skipped.value + jnp.sum(
+                    (idx == self.n_routed).astype(jnp.float32))
+        return (out + routed.astype(dt)).reshape(b, t, d), carry
+
+    def _linear_scores(self, xf):
+        """The router as one ``[d, n_routed]`` matrix of the layer's own:
+        -> ``(scores [N, n_routed], bias or None)``."""
+        w_r = self.param("router", _normal(), (xf.shape[-1], self.n_routed),
+                         jnp.float32)
+        logits = jnp.dot(xf.astype(jnp.float32), w_r,
+                         precision=jax.lax.Precision.HIGHEST)
+        if self.score == "softmax":
+            if self.n_group > 1:
+                raise ValueError("a softmax router has no groups")
+            return jax.nn.softmax(logits, axis=-1), None
+        bias = self.param("e_score_correction_bias", _normal(0.01),
+                          (self.n_routed,), jnp.float32)
+        return jax.nn.sigmoid(logits), bias
 
 
 @dataclasses.dataclass(frozen=True)
@@ -500,21 +630,59 @@ class LatentMoeSizes:
     attn_scale: Optional[float] = None
     logit_scale: float = 1.0
     tied_head: bool = False
+    #: the ``"cca"`` mixers (compressed convolutional attention: ``heads``
+    #: query heads over ``kv_heads`` key-value heads of ``v_dim``, rotary
+    #: over the first ``rope`` channels at ``rope_theta``): the positions of
+    #: the depthwise and of the head-wise convolution
+    cca_conv: tuple = (2, 2)
+    #: the sparse layers' router as an MLP of this width that reads the
+    #: router of the layer before (0: one matrix, :class:`SharedRoutedMoe`),
+    #: with one output more that is no expert
+    router_hidden: int = 0
+    #: the sparse layers' balancing bias moves against the load between
+    #: steps, by this rate (:func:`load_pull`; 0: it stays as loaded)
+    balance_rate: float = 0.0
+    #: ``h <- (a_r h + b_r) + (a_b branch + b_b)`` after each sub-layer, four
+    #: learned vectors (:class:`ScaledMerge`), in place of ``residual_scale``
+    scaled_residual: bool = False
+
+
+class ScaledMerge(nn.Module):
+    """``(res_scale * h + res_bias) + (branch_scale * branch +
+    branch_bias)``: a residual add with a learned scale and bias a channel
+    on the stream and on the branch, scales seeded at 1 and biases at 0; in
+    float32, the sum in ``dtype``."""
+
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h, branch):
+        def vec(name, init):
+            return self.param(name, init, (h.shape[-1],), jnp.float32)
+
+        ones, zeros = nn.initializers.ones, nn.initializers.zeros
+        h = h.astype(jnp.float32) * vec("res_scale", ones) + vec(
+            "res_bias", zeros)
+        branch = branch.astype(jnp.float32) * vec(
+            "branch_scale", ones) + vec("branch_bias", zeros)
+        return (h + branch).astype(self.dtype)
 
 
 class LatentMoeBlock(nn.Module):
     """``h += r Mixer(RMSNorm(h))``; ``h += r Mlp(RMSNorm(h))`` with ``r``
-    the sizes' ``residual_scale``: the mixer attention of one of three kinds
-    (``attn``), the delta rule (``delta``) or the state-space recurrence
-    (``ssd``), the MLP a SwiGLU of ``dense_width`` in the leading dense
-    layers, the sparse layer after."""
+    the sizes' ``residual_scale`` (or each add a :class:`ScaledMerge`): the
+    mixer attention of one of four kinds (``attn``), the delta rule
+    (``delta``) or the state-space recurrence (``ssd``), the MLP a SwiGLU of
+    ``dense_width`` in the leading dense layers, the sparse layer after.
+    ``(h, carry) -> (h, carry)``: ``carry`` is what a sparse layer's router
+    hands the router of the next layer, None where it keeps none."""
 
     sizes: LatentMoeSizes
     sparse: bool
     mixer: str = "latent"
 
     @nn.compact
-    def __call__(self, h, train: bool = False):
+    def __call__(self, h, train: bool = False, carry=None):
         c = self.sizes
         a = RMSNorm(c.eps, c.dtype, name="attn_norm")(h)
         if self.mixer == "latent":
@@ -529,6 +697,10 @@ class LatentMoeBlock(nn.Module):
             a = Mamba2Mixer(c.ssd_heads, c.ssd_head_dim, c.ssd_state,
                             c.ssd_conv, c.ssd_chunk, c.eps, c.dtype,
                             name="ssd")(a, train)
+        elif self.mixer == "cca":
+            a = CompressedConvAttention(c.heads, c.kv_heads, c.v_dim, c.rope,
+                                        c.rope_theta, c.cca_conv, c.dtype,
+                                        name="attn")(a)
         elif self.mixer == "window":
             a = GroupedAttention(c.window_heads, c.kv_heads, c.v_dim, c.v_dim,
                                  c.window_rope_theta, window=c.window,
@@ -546,22 +718,25 @@ class LatentMoeBlock(nn.Module):
                                  dtype=c.dtype, scale=c.attn_scale,
                                  name="attn")(a)
 
-        def add(h, branch):
+        def add(h, branch, name):
+            if c.scaled_residual:
+                return ScaledMerge(c.dtype, name=name)(h, branch)
             if c.residual_scale != 1.0:
                 branch = branch * jnp.asarray(c.residual_scale, branch.dtype)
             return h + branch
 
-        h = add(h, a)
+        h = add(h, a, "attn_merge")
         m = RMSNorm(c.eps, c.dtype, name="mlp_norm")(h)
         if self.sparse:
-            m = SharedRoutedMoe(c.n_routed, c.top_k, c.expert_width,
-                                c.n_shared, c.routed_scaling, c.held_first,
-                                c.held_count, c.dtype, c.n_group,
-                                c.topk_group, c.score, name="mlp")(m, train)
+            m, carry = SharedRoutedMoe(
+                c.n_routed, c.top_k, c.expert_width, c.n_shared,
+                c.routed_scaling, c.held_first, c.held_count, c.dtype,
+                c.n_group, c.topk_group, c.score, c.router_hidden, c.eps,
+                c.balance_rate, name="mlp")(m, train, carry)
         else:
             with jax.named_scope(SCOPE_LM_DENSE):
                 m = SwiGLU(c.dense_width, c.dtype, name="mlp")(m)
-        return add(h, m)
+        return add(h, m, "mlp_merge"), carry
 
 
 class LatentMoeLM(nn.Module):
@@ -569,8 +744,10 @@ class LatentMoeLM(nn.Module):
     ``layers`` blocks (the first ``first_dense`` with a dense MLP; layer
     ``i``'s mixer is ``mixers[i]``, latent attention where none is named),
     a final RMSNorm and a head of its own, or, ``tied_head``, the
-    embedding's table again; no learned positions. Each block is
-    rematerialised in the backward pass (``remat``)."""
+    embedding's table again; no learned positions. Beside ``h`` the blocks
+    hand on what a sparse layer's router gives the next one (None where no
+    router keeps such a state; the first layer receives none). Each block
+    is rematerialised in the backward pass (``remat``)."""
 
     vocab_size: int
     sizes: LatentMoeSizes
@@ -588,13 +765,14 @@ class LatentMoeLM(nn.Module):
                  else LatentMoeBlock)
         mixers = c.mixers or ("latent",) * c.layers
         if len(mixers) != c.layers or set(mixers) - {"latent", "delta", "ssd",
-                                                     "full", "window"}:
+                                                     "full", "window", "cca"}:
             raise ValueError(f"mixers {mixers}: one of 'latent' / 'delta' / "
-                             f"'ssd' / 'full' / 'window' for each of the "
-                             f"{c.layers} layers")
+                             f"'ssd' / 'full' / 'window' / 'cca' for each of "
+                             f"the {c.layers} layers")
+        carry = None
         for i in range(c.layers):
-            h = block(c, i >= c.first_dense, mixers[i],
-                      name=f"layer_{i}")(h, train)
+            h, carry = block(c, i >= c.first_dense, mixers[i],
+                             name=f"layer_{i}")(h, train, carry)
         h = RMSNorm(c.eps, c.dtype, name="final_norm")(h)
         with jax.named_scope(SCOPE_LM_DENSE):
             if c.tied_head:
@@ -611,7 +789,8 @@ def layer_counters(variables: dict) -> dict:
     training step since the variables were seeded, where the packed
     simulation round trained them: a sparse layer's ``rows.<layer>.<expert>``
     and ``steps.<layer>`` (and ``group_tokens.<layer>`` under a
-    group-limited router), a state-space mixer's ``decay.<layer>`` (its mean
+    group-limited router, ``skipped.<layer>`` where a choice is no expert),
+    a state-space mixer's ``decay.<layer>`` (its mean
     ``exp(dt A)`` a step, summed) and ``steps.<layer>``. A model whose layers
     keep none gives ``{}``."""
     out = {}
@@ -625,9 +804,9 @@ def layer_counters(variables: dict) -> dict:
         for e, rows in enumerate(jax.device_get(mlp["expert_rows"])):
             out[f"rows.{layer}.{e}"] = float(rows)
         out[f"steps.{layer}"] = float(jax.device_get(mlp["steps"]))
-        if "group_tokens" in mlp:
-            out[f"group_tokens.{layer}"] = float(
-                jax.device_get(mlp["group_tokens"]))
+        for extra in ("group_tokens", "skipped"):
+            if extra in mlp:
+                out[f"{extra}.{layer}"] = float(jax.device_get(mlp[extra]))
     return out
 
 
@@ -685,6 +864,26 @@ LATENT_MOE_PRESETS = {
         ssd_heads=64, ssd_head_dim=64, ssd_state=128, ssd_conv=4,
         ssd_chunk=256, embed_scale=12.0, residual_scale=0.22,
         attn_scale=0.015625, logit_scale=8.0, tied_head=True),
+    # one of two expert-parallel chips' share of the first six layers of
+    # Zyphra/ZAYA1-8B: compressed convolutional attention in a latent of half
+    # the model's width, an MLP router that reads the router of the layer
+    # before it, ONE choice a token of 16 experts (8 held) or none, scaled
+    # residuals, an eighth of the tied table's rows
+    # (``benchmarks/configs/zaya1_8b.json``, held equal by a test)
+    "zaya1_8b": dict(
+        dim=2048, heads=8, nope=64, rope=64, v_dim=128, kv_rank=0, layers=6,
+        first_dense=0, dense_width=0, n_routed=16, top_k=1,
+        expert_width=2048, n_shared=0, routed_scaling=1.0, rope_theta=5e6,
+        eps=1e-5, held_first=0, held_count=8, seq_len=4096,
+        mixers=["cca"] * 6, kv_heads=2, cca_conv=[2, 2], router_hidden=256,
+        balance_rate=130.0, scaled_residual=True, tied_head=True),
+    "zaya1_tiny": dict(
+        dim=32, heads=4, nope=4, rope=4, v_dim=8, kv_rank=0, layers=3,
+        first_dense=0, dense_width=0, n_routed=16, top_k=1, expert_width=32,
+        n_shared=0, routed_scaling=1.0, rope_theta=5e6, eps=1e-5,
+        held_first=0, held_count=8, seq_len=32, mixers=["cca"] * 3,
+        kv_heads=2, cca_conv=[2, 2], router_hidden=16, balance_rate=4.0,
+        scaled_residual=True, tied_head=True),
     "granite4h_tiny": dict(
         dim=32, heads=4, nope=8, rope=0, v_dim=8, kv_rank=0, layers=4,
         first_dense=4, dense_width=64, n_routed=0, top_k=0, expert_width=0,
@@ -722,7 +921,9 @@ LATENT_MOE_PRESETS = {
 def _latent_moe_bundle(name: str, output_dim: int, **kw) -> ModelBundle:
     sizes = {**LATENT_MOE_PRESETS[name], **kw}
     seq_len = sizes.pop("seq_len")
-    sizes["mixers"] = tuple(sizes.get("mixers", ()))
+    for key in ("mixers", "cca_conv"):
+        if key in sizes:
+            sizes[key] = tuple(sizes[key])
     module = LatentMoeLM(vocab_size=output_dim, sizes=LatentMoeSizes(**sizes))
     return ModelBundle(
         name=name, module=module, input_shape=(seq_len,),
@@ -749,6 +950,16 @@ def _laguna(output_dim: int = 12544, **kw):
 @register_model("granite4_h_micro")
 def _granite4h(output_dim: int = 12544, **kw):
     return _latent_moe_bundle("granite4_h_micro", output_dim or 12544, **kw)
+
+
+@register_model("zaya1_8b")
+def _zaya1(output_dim: int = 32784, **kw):
+    return _latent_moe_bundle("zaya1_8b", output_dim or 32784, **kw)
+
+
+@register_model("zaya1_tiny")
+def _zaya1_tiny(output_dim: int = 64, **kw):
+    return _latent_moe_bundle("zaya1_tiny", output_dim or 64, **kw)
 
 
 @register_model("granite4h_tiny")

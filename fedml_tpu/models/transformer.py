@@ -25,9 +25,9 @@ import numpy as np
 
 from fedml_tpu.models import COUNTERS, ModelBundle, register_model
 from fedml_tpu.obs.tracer import (SCOPE_LM_ATTN, SCOPE_LM_ATTN_WINDOW,
-                                  SCOPE_LM_DENSE, SCOPE_LM_KDA,
-                                  SCOPE_LM_KDA_PREP, SCOPE_LM_SSD,
-                                  SCOPE_LM_SSD_PREP)
+                                  SCOPE_LM_CCA_MIX, SCOPE_LM_DENSE,
+                                  SCOPE_LM_KDA, SCOPE_LM_KDA_PREP,
+                                  SCOPE_LM_SSD, SCOPE_LM_SSD_PREP)
 from fedml_tpu.ops.attention import attention
 from fedml_tpu.ops.kda import kda_chunked
 from fedml_tpu.ops.ssd import SSD_CHUNK, ssd_chunked
@@ -385,6 +385,40 @@ class HeadGate(nn.Module):
         return (o * jax.nn.sigmoid(gate)[..., None]).astype(self.dtype)
 
 
+def heads_attention(q, k, v, head_dim: int, rotary_dim: int,
+                    rope_theta: float = 10000.0, inv_freq=None,
+                    rope_scale: float = 1.0, window: Optional[int] = None,
+                    scale: Optional[float] = None, dtype: Any = None):
+    """Grouped-query attention from projected ``q [B, T, H * head_dim]`` and
+    ``k``, ``v [B, T, G * head_dim]`` (heads side by side along the last
+    axis) -> ``[B, T, H, head_dim]``: the one way into ``ops.attention`` of
+    every module whose query heads share key-value heads. The first
+    ``rotary_dim`` channels of every head of ``q`` and ``k`` turn
+    (:func:`rotary`), causal softmax of ``q . k * scale`` over ``window``
+    keys, under ``fedml.lm.attn`` (``fedml.lm.attn_window`` with a window);
+    the head transposes and rotary stay the step's. ``dtype``: what ``q``
+    and ``k`` are cast to after they have turned (a caller that hands them
+    over in float32)."""
+    b, t, d, r = q.shape[0], q.shape[1], head_dim, rotary_dim
+
+    def heads(a):
+        return a.reshape(b, t, a.shape[-1] // d, d).transpose(0, 2, 1, 3)
+
+    def turned(a):
+        if r:
+            first = rotary(a[..., :r], rope_theta, inv_freq, rope_scale)
+            a = first if r == d else jnp.concatenate(
+                [first, a[..., r:]], axis=-1)
+        return a if dtype is None else a.astype(dtype)
+
+    q, k, v = turned(heads(q)), turned(heads(k)), heads(v)
+    with jax.named_scope(SCOPE_LM_ATTN if window is None
+                         else SCOPE_LM_ATTN_WINDOW):
+        o = attention(q, k, v, causal=True, window=window, sm_scale=scale,
+                      block_q=_ATTN_BLOCK, block_k=_ATTN_BLOCK)
+    return o.transpose(0, 2, 1, 3)
+
+
 class GroupedAttention(nn.Module):
     """Grouped-query attention, full or under a sliding window. ``q = W_q
     x`` as ``heads`` heads of ``head_dim``, ``k`` and ``v`` as ``kv_heads``;
@@ -413,30 +447,14 @@ class GroupedAttention(nn.Module):
     @nn.compact
     def __call__(self, x):
         b, t, dim = x.shape
-        h, g, d, r = self.heads, self.kv_heads, self.head_dim, self.rotary_dim
+        h, g, d = self.heads, self.kv_heads, self.head_dim
         with jax.named_scope(SCOPE_LM_DENSE):
             q = Linear(h * d, self.dtype, name="q_proj")(x)
             k = Linear(g * d, self.dtype, name="k_proj")(x)
             v = Linear(g * d, self.dtype, name="v_proj")(x)
-
-        def heads(a, n):
-            return a.reshape(b, t, n, d).transpose(0, 2, 1, 3)
-
-        def turned(a):
-            if not r:
-                return a
-            first = rotary(a[..., :r], self.rope_theta, self.inv_freq,
-                           self.rope_scale)
-            return first if r == d else jnp.concatenate(
-                [first, a[..., r:]], axis=-1)
-
-        q, k, v = turned(heads(q, h)), turned(heads(k, g)), heads(v, g)
-        with jax.named_scope(SCOPE_LM_ATTN if self.window is None
-                             else SCOPE_LM_ATTN_WINDOW):
-            o = attention(q, k, v, causal=True, window=self.window,
-                          sm_scale=self.scale, block_q=_ATTN_BLOCK,
-                          block_k=_ATTN_BLOCK)
-        o = o.transpose(0, 2, 1, 3)
+        o = heads_attention(q, k, v, d, self.rotary_dim, self.rope_theta,
+                            self.inv_freq, self.rope_scale, self.window,
+                            self.scale)
         if self.gate:
             o = HeadGate(dtype=self.dtype, norm=False, name="out_gate")(o, x)
         with jax.named_scope(SCOPE_LM_DENSE):
@@ -451,6 +469,128 @@ def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
     k, t = w.shape[0], x.shape[1]
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
     return sum(xp[:, i:i + t] * w[i] for i in range(k))
+
+
+def shift_back(x: jax.Array, n: int = 1) -> jax.Array:
+    """``x [B, T, ...]`` read ``n`` positions earlier: ``y_t = x_{t-n}``,
+    zeros before position 0."""
+    if not n:
+        return x
+    pad = ((0, 0), (n, 0)) + ((0, 0),) * (x.ndim - 2)
+    return jnp.pad(x[:, :x.shape[1] - n], pad)
+
+
+def headwise_conv(u: jax.Array, w: jax.Array, dtype: Any) -> jax.Array:
+    """Causal convolution along ``T`` that mixes the channels INSIDE each
+    head: ``u [B, T, J, C]``, ``w [K, J, C, C]`` -> ``y_t[j] = sum_i
+    u_{t-K+1+i}[j] w[i, j]`` (zeros before position 0), one batched product
+    a tap with operands in ``dtype`` and float32 accumulation."""
+    k = w.shape[0]
+    u, w = u.astype(dtype), w.astype(dtype)
+    return sum(jnp.einsum("btjc,jcd->btjd", shift_back(u, k - 1 - i), w[i],
+                          preferred_element_type=jnp.float32)
+               for i in range(k))
+
+
+def cca_mix(q, k, v, w0, b0, w1, b1, temp, heads: int, kv_heads: int,
+            dtype: Any):
+    """What compressed convolutional attention does between its projections
+    and its scores (:class:`CompressedConvAttention` has the equations):
+    projected ``q [B, T, H e]``, ``k`` and ``v [B, T, G e]`` -> the mixed,
+    normalised ``q [B, T, H, e]`` and ``k [B, T, G, e]`` in float32 and
+    ``v`` with its later heads read one position earlier."""
+    b, t = q.shape[:2]
+    h, g, f32 = heads, kv_heads, jnp.float32
+    e = q.shape[-1] // h
+    m_q = (q.astype(f32).reshape(b, t, g, h // g, e)
+           + k.astype(f32).reshape(b, t, g, 1, e)) / 2
+    m_k = jnp.mean(m_q, axis=3)
+    u = causal_conv(jnp.concatenate([q, k], axis=-1), w0) + b0
+    y = headwise_conv(u.reshape(b, t, h + g, e), w1, dtype) + b1
+
+    def unit(a):
+        return a * (jax.lax.rsqrt(
+            jnp.sum(a * a, axis=-1, keepdims=True) + 1e-12) * e ** 0.5)
+
+    late = (g // 2) * e
+    return (unit(y[:, :, :h] + m_q.reshape(b, t, h, e)),
+            unit(y[:, :, h:] + m_k) * temp[:, None],
+            jnp.concatenate([v[..., :late], shift_back(v[..., late:])],
+                            axis=-1))
+
+
+def fan_in_uniform(fan_in: int):
+    """``torch.nn.Conv1d``'s and ``torch.nn.Linear``'s default, for weights
+    and biases alike: uniform over ``+- fan_in^-0.5``."""
+    bound = fan_in ** -0.5
+
+    def init(key, shape, dtype):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+    return init
+
+
+class CompressedConvAttention(nn.Module):
+    """Compressed convolutional attention (arXiv:2510.04476), grouped-query
+    form: attention in a latent of ``heads * head_dim`` channels, narrower
+    than the model, whose queries and keys are mixed along the sequence and
+    inside each head before the scores. ``q~ = W_q x`` as ``heads`` heads of
+    ``head_dim``, ``k~ = W_k x`` as ``kv_heads``; the means, taken BEFORE the
+    mixing, ``m_q[i] = (q~[i] + k~[i // r]) / 2`` (``r`` query heads a
+    key-value head) and ``m_k[g]`` the mean of its group's ``m_q``; ``z =
+    [q~ ; k~]`` goes through a depthwise causal convolution of ``conv[0]``
+    positions with bias (:func:`causal_conv`) and a head-wise one of
+    ``conv[1]`` positions with bias (:func:`headwise_conv`: the positions
+    before a sequence's first are zeros for both); ``q = y_q + m_q``, ``k =
+    y_k + m_k``; each head of both is set to length ``sqrt(head_dim)``, a
+    key head times its learned temperature ``k_temp``, in float32 (seeded
+    at 2: at 1 the seeded scores are N(0, 1), attention over a prefix is a
+    running mean and every token of a sequence hands the layers after it
+    the same vector); rotary over the first ``rotary_dim`` channels; the
+    later half of the value heads reads the position BEFORE its own
+    (``v_proj``'s
+    columns of the first ``kv_heads // 2`` heads are ``W_v1``, the others
+    ``W_v2``; a linear map commutes with the shift, so the shifted heads
+    are the projection's output read one position earlier); causal softmax
+    of ``q . k / sqrt(head_dim)``; ``W_o`` from the latent back to the
+    model's width. No bias in a projection. The convolutions start as
+    ``torch.nn.Conv1d``'s default does, so that every tap is seen."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rotary_dim: int
+    rope_theta: float = 10000.0
+    conv: tuple = (2, 2)
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, dim = x.shape
+        h, g, e, f32 = self.heads, self.kv_heads, self.head_dim, jnp.float32
+        k0, k1 = self.conv
+        with jax.named_scope(SCOPE_LM_DENSE):
+            q = Linear(h * e, self.dtype, name="q_proj")(x)
+            k = Linear(g * e, self.dtype, name="k_proj")(x)
+            v = Linear(g * e, self.dtype, name="v_proj")(x)
+        with jax.named_scope(SCOPE_LM_CCA_MIX):
+            w0 = self.param("conv0_kernel", fan_in_uniform(k0),
+                            (k0, (h + g) * e), f32)
+            b0 = self.param("conv0_bias", fan_in_uniform(k0), ((h + g) * e,),
+                            f32)
+            w1 = self.param("conv1_kernel", fan_in_uniform(k1 * e),
+                            (k1, h + g, e, e), f32)
+            b1 = self.param("conv1_bias", fan_in_uniform(k1 * e), (h + g, e),
+                            f32)
+            temp = self.param("k_temp", nn.initializers.constant(2.0), (g,),
+                              f32)
+            q, k, v = cca_mix(q, k, v, w0, b0, w1, b1, temp, h, g, self.dtype)
+        o = heads_attention(q.reshape(b, t, h * e), k.reshape(b, t, g * e), v,
+                            e, self.rotary_dim, self.rope_theta,
+                            dtype=self.dtype)
+        with jax.named_scope(SCOPE_LM_DENSE):
+            return Linear(dim, self.dtype, name="o_proj")(
+                o.reshape(b, t, h * e))
 
 
 def slow_decay_bias(key: jax.Array, shape, lower_bound: float) -> jax.Array:

@@ -110,6 +110,11 @@ SCOPE_LM_SSD = "fedml.lm.ssd"
 #: splits and head reshapes, ``softplus``, the gated norm (its two
 #: projections are ``fedml.lm.dense``)
 SCOPE_LM_SSD_PREP = "fedml.lm.ssd_prep"
+#: what compressed convolutional attention adds between its projections and
+#: the kernels: the means of ``q`` and ``k``, both convolutions, the L2
+#: normalisation with the key temperature, the value shift; its backward
+#: too (the projections are ``fedml.lm.dense``, the scores ``fedml.lm.attn``)
+SCOPE_LM_CCA_MIX = "fedml.lm.cca_mix"
 #: router matmul, selection, sort, the rows' fan-out and weighted add-back
 SCOPE_LM_ROUTE = "fedml.lm.route"
 #: the grouped matmuls over the rows of the experts held here

@@ -105,7 +105,7 @@ def test_all_shares_add_up_to_the_uncut_layer():
         params = {k: (a[first:first + count] if k in ("gate", "up", "down")
                       else a) for k, a in p.items() if n_shared or k != "shared"}
         stats = {"expert_rows": jnp.zeros((count,)), "steps": jnp.zeros(())}
-        return mod.apply({"params": params, "counters": stats}, x)
+        return mod.apply({"params": params, "counters": stats}, x)[0]
 
     with jax.default_matmul_precision("highest"):
         whole = layer(0, n_routed, 2)
@@ -131,7 +131,7 @@ def test_no_token_is_dropped_when_all_choose_one_expert(target):
     mod = SharedRoutedMoe(8, 2, 24, 2, 2.448, 0, 4)
     stats = {"expert_rows": jnp.zeros((4,)), "steps": jnp.zeros(())}
     with jax.default_matmul_precision("highest"):
-        out, new = mod.apply({"params": p, "counters": stats}, x, True,
+        (out, _), new = mod.apply({"params": p, "counters": stats}, x, True,
                              mutable=["counters"])
         # expert 7 is absent: only the held target's part is in the sum
         want, _, _ = ref._forward(config, "reference").moe(x, p)
@@ -212,7 +212,7 @@ def _steered_layer(totals, held_count=4, dtype=jnp.float32):
 
 def _layer_value_and_grads(mod, variables, x, c):
     def loss(params, x):
-        out, new = mod.apply({**variables, "params": params}, x, True,
+        (out, _), new = mod.apply({**variables, "params": params}, x, True,
                              mutable=["counters"])
         return (jnp.sum(out.astype(jnp.float32) * c),
                 (out, new["counters"]["expert_rows"]))
@@ -407,7 +407,8 @@ def test_token_ids_reach_the_model_unrounded(dtype, kind):
     ("BENCHMARK.tiny_lm.json", "tiny_kanana2_sim"),
     ("BENCHMARK.tiny_hybrid.json", "tiny_ling3_sim"),
     ("BENCHMARK.tiny_laguna.json", "tiny_laguna_sim"),
-    ("BENCHMARK.tiny_granite4h.json", "tiny_granite4h_sim")])
+    ("BENCHMARK.tiny_granite4h.json", "tiny_granite4h_sim"),
+    ("BENCHMARK.tiny_zaya1.json", "tiny_zaya1_sim")])
 def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
     """An LM's round program carries the step's scopes and the
     ``fedml.lm.*`` names of what it is built of, as metadata only: all of
@@ -415,7 +416,8 @@ def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
     without the delta rule's two for the latent-attention one, for the
     window / full decoder all but the delta rule's; the state-space
     recurrence's two are the state-space decoder's alone, which is dense
-    and names no router and no expert."""
+    and names no router and no expert; the mixing's name is the
+    compressed-attention decoder's alone."""
     import re
 
     from benchmarks.harness.cell import build_api
@@ -445,6 +447,8 @@ def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
         table -= {tracer.SCOPE_LM_ATTN_WINDOW}
     if "ssd" not in mixers:
         table -= {tracer.SCOPE_LM_SSD, tracer.SCOPE_LM_SSD_PREP}
+    if "cca" not in mixers:
+        table -= {tracer.SCOPE_LM_CCA_MIX}
     sizes = config["model"]
     if sizes["first_dense"] == sizes["layers"]:
         table -= {tracer.SCOPE_LM_ROUTE, tracer.SCOPE_LM_EXPERTS}
@@ -464,11 +468,14 @@ def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
     rungs = moe.row_rungs(pairs)
     assert len(rungs) == 4
     conds = re.findall(r'"stablehlo\.case"\(.*?\n +\}\) :', text, re.S)
-    assert len(conds) == 2 * (sizes["layers"] - sizes["first_dense"])
+    # (a scaled residual's gradient reads the branch it scales, so there
+    # the replay's conditional is live too)
+    passes = 3 if sizes.get("scaled_residual") else 2
+    assert len(conds) == passes * (sizes["layers"] - sizes["first_dense"])
     for cond in conds:
         assert cond.count("func.call @rung") == len(rungs) == cond.count(
             "stablehlo.return")
-    assert len(set(re.findall(r"func\.call @(rung[a-z_0-9]*)", text))) == 2 * len(rungs)
+    assert len(set(re.findall(r"func\.call @(rung[a-z_0-9]*)", text))) == passes * len(rungs)
     inside = set(re.findall(r'loc\("([^"]*moe_rows_[^"]*)"', named))
     assert ({int(c) for path in inside
              for c in re.findall(r"moe_rows_(\d+)", path)} == set(rungs))
@@ -595,7 +602,7 @@ def test_group_routed_shares_add_up_to_the_uncut_layer():
                        else a) for kk, a in p.items() if n_shared or kk != "shared"}
         stats = {"expert_rows": jnp.zeros((count,)), "steps": jnp.zeros(()),
                  "group_tokens": jnp.zeros(())}
-        out, new = mod.apply({"params": params, "counters": stats}, x, True,
+        (out, _), new = mod.apply({"params": params, "counters": stats}, x, True,
                              mutable=["counters"])
         return out, new["counters"]
 
@@ -829,7 +836,7 @@ def test_softmax_routed_shares_add_up_to_the_uncut_layer():
         params = {kk: (a[first:first + count] if kk in ("gate", "up", "down")
                        else a) for kk, a in p.items() if n_shared or kk != "shared"}
         stats = {"expert_rows": jnp.zeros((count,)), "steps": jnp.zeros(())}
-        out, new = mod.apply({"params": params, "counters": stats}, x, True,
+        (out, _), new = mod.apply({"params": params, "counters": stats}, x, True,
                              mutable=["counters"])
         return out, new["counters"]
 

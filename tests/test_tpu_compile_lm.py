@@ -245,14 +245,25 @@ def test_delta_rule_scan_compiles_at_published_widths(one_chip, no_cache,
     ("ling3_tiny", dict(ssd_heads=0, embed_scale=1.0, residual_scale=1.0,
                         attn_scale=None, logit_scale=1.0, tied_head=False)),
     ("laguna_tiny", dict(ssd_heads=0, embed_scale=1.0, residual_scale=1.0,
-                         attn_scale=None, logit_scale=1.0, tied_head=False))])
+                         attn_scale=None, logit_scale=1.0, tied_head=False)),
+    ("kanana2_tiny", dict(cca_conv=(2, 2), router_hidden=0, balance_rate=0.0,
+                          scaled_residual=False)),
+    ("ling3_tiny", dict(cca_conv=(2, 2), router_hidden=0, balance_rate=0.0,
+                        scaled_residual=False)),
+    ("laguna_tiny", dict(cca_conv=(2, 2), router_hidden=0, balance_rate=0.0,
+                         scaled_residual=False)),
+    ("granite4h_tiny", dict(cca_conv=(2, 2), router_hidden=0,
+                            balance_rate=0.0, scaled_residual=False))])
 def test_older_lm_lowers_the_same_with_the_new_options_at_their_defaults(
         name, named):
     """The options the hybrid decoder added (the mixers' pattern, the
     group-limited router, the query / key norms, the output gate) and those
     the window / full decoder added (the grouped-query mixers' sizes, the
-    window, YaRN, the router's score function) leave the older LMs'
-    programs as they were: built with every one of them named at its default
+    window, YaRN, the router's score function), the state-space decoder
+    (its mixers' sizes, the four multipliers, the tied head) and the
+    compressed-attention decoder (the convolutions' positions, the MLP
+    router, the choice that is no expert, the scaled residuals) leave the
+    older LMs' programs as they were: built with every one of them named at its default
     a model lowers to the same text as built without, and its variable tree
     has no new leaf (CPU fixtures; against the parent commit's text the
     fixtures' round programs were checked by sha256, ``PERF.md`` PR 30,
@@ -261,8 +272,8 @@ def test_older_lm_lowers_the_same_with_the_new_options_at_their_defaults(
     from fedml_tpu.models import create_model
 
     def lowered(**kw):
-        b = create_model(name, 64, input_shape=(32 if name == "laguna_tiny"
-                                                else 16,), **kw)
+        b = create_model(name, 64, input_shape=(
+            32 if name in ("laguna_tiny", "granite4h_tiny") else 16,), **kw)
         v = b.init(jax.random.key(0))
 
         def step(v, x, y, m):
@@ -363,3 +374,85 @@ def test_position_free_attention_compiles_at_heads_of_64(one_chip, no_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
     _dq, dk, dv = jax.eval_shape(step, sd(32), sd(8), sd(8), sd(32))
     assert dk.shape == dv.shape == (1, 8, 4096, 64)
+
+
+def test_compressed_conv_attention_compiles_at_published_widths(one_chip,
+                                                                no_cache):
+    """The whole CCA mixer at the published widths (hidden 2,048; 8 query
+    heads over 2 key-value heads of 128: a latent of 1,024, ten heads
+    through both convolutions), forward and backward in bf16 on two
+    sequences of 4,096: the two attention kernel calls (the forward and the
+    ONE backward) and nothing else of Mosaic's; the head-wise convolution's
+    kernel gradient comes out as the ``[2, 10, 128, 128]`` leaf; the mixing's
+    float32 passes over ``[2, 4096, 1280]`` stay under 1.5 GB of
+    temporaries."""
+    import importlib
+
+    from fedml_tpu.models.transformer import CompressedConvAttention
+
+    att = importlib.import_module("fedml_tpu.ops.attention")
+    mixer = CompressedConvAttention(8, 2, 128, 64, 5e6, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+    v = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.key(0), x))
+
+    def step(v, x, c):
+        return jax.grad(lambda p, x: jnp.sum(mixer.apply(
+            {"params": p}, x).astype(jnp.float32) * c), argnums=(0, 1))(
+                v["params"], x)
+
+    pick, att._pick_impl = att._pick_impl, lambda impl: "pallas"
+    try:
+        compiled = jax.jit(step).lower(v, x, x).compile()
+    finally:
+        att._pick_impl = pick
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    gp, gx = jax.eval_shape(step, v, x, x)
+    assert gp["conv1_kernel"].shape == (2, 10, 128, 128)
+    assert gp["q_proj"]["kernel"].shape == (2048, 1024)
+    assert gp["k_temp"].shape == (2,) and gx.shape == (2, 4096, 2048)
+
+
+def test_mlp_routed_layer_compiles_at_published_widths(one_chip, no_cache):
+    """The sparse sub-layer at the published widths on 8,192 tokens: a
+    router of 256 hidden channels with its carry, 17 outputs and ONE choice
+    a token, 8 held experts of ``[2048, 2048]`` in bf16: one conditional a
+    pass over the four row capacities of 8,192 pairs, the compiler's grouped
+    kernels unbatched inside its branches, the carry's cotangent back to the
+    layer before, and the load's pull where the balancing bias's gradient
+    would be."""
+    from fedml_tpu.models import moe
+
+    layer = moe.SharedRoutedMoe(16, 1, 2048, 0, 1.0, 0, 8, jnp.bfloat16,
+                                router_hidden=256, eps=1e-5,
+                                balance_rate=130.0)
+
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x, carry = sd(jnp.bfloat16, 2, 4096, 2048), sd(jnp.float32, 8192, 256)
+    v = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k, x, c: layer.init(k, x, False, c),
+                       jax.random.key(0), x, carry))
+    assert v["params"]["router"]["out_kernel"].shape == (256, 17)
+    assert v["params"]["gate"].shape == (8, 2048, 2048)
+
+    def step(v, x, carry, c):
+        def loss(p, x, carry):
+            out, s = layer.apply({**v, "params": p}, x, False, carry)
+            return jnp.sum((out.astype(jnp.float32) * c) ** 2) + jnp.sum(s)
+        return jax.grad(loss, argnums=(0, 1, 2))(v["params"], x, carry)
+
+    compiled = jax.jit(step).lower(v, x, carry, x).compile()
+    text = compiled.as_text()
+    assert moe.row_rungs(8192) == (1024, 2048, 4096, 8192)
+    assert text.count(" conditional(") == 2
+    for c in moe.row_rungs(8192):
+        assert f"moe_rows_{c}/" in text
+    gp, gx, gc = jax.eval_shape(step, v, x, carry, x)
+    assert gp["router"]["gamma"].shape == () and gc.shape == (8192, 256)
+    assert gp["router"]["bias"].shape == (17,)
+    assert gx.shape == (2, 4096, 2048)

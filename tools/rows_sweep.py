@@ -21,6 +21,8 @@ same rows (a smaller one is skipped). Wall-clock ms a call over ``--iters``
 calls, and the device's own time for a traced call.
 
     python tools/rows_sweep.py [capacity ...]       # the parts, on the chip
+    python tools/rows_sweep.py --width 2048 --choices 1 --routed 16 --held 8 \
+        4096 5120 8192       # another layer's sizes (these: zaya1_sim_c2's)
     python tools/rows_sweep.py --trace-dir .bench_out/trace/kanana2_sim_c2
 
 With ``--trace-dir`` (a profiler trace of the cell, as ``benchmarks/run.py
@@ -223,10 +225,15 @@ def main(argv=None) -> int:
                     help="row capacities to time (default: the layer's rungs)")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--seed", type=int, default=29)
+    for name in ("tokens", "dim", "width", "choices", "routed", "held"):
+        ap.add_argument(f"--{name}", type=int, default=globals()[name.upper()],
+                        help="the layer's size (default: kanana2_sim_c2's)")
     ap.add_argument("--trace-dir", help="a profiler trace of the cell")
     ap.add_argument("--trace-only", action="store_true",
                     help="read --trace-dir and time nothing (needs no chip)")
     args = ap.parse_args(argv)
+    globals().update(TOKENS=args.tokens, DIM=args.dim, WIDTH=args.width,
+                     CHOICES=args.choices, ROUTED=args.routed, HELD=args.held)
     out = {}
     if args.trace_dir:
         out["rungs_in_trace"] = rung_shares(args.trace_dir)
@@ -243,6 +250,7 @@ def main(argv=None) -> int:
         operands = _routing(args.seed)
         filled = int(operands[3].sum())
         out["device"] = jax.devices()[0].device_kind
+        out["sizes"] = [TOKENS, DIM, WIDTH, CHOICES, ROUTED, HELD]
         out["rows"] = []
         for capacity in rungs:
             if capacity < filled:
