@@ -685,7 +685,8 @@ class FedAvgAPI:
         steps masked dead in the same compiled program, never a vmap
         fallback). The ``fedml/round/plan`` span says how many of the
         plan's steps the program walks (``steps_run`` of
-        ``steps_planned``: chunks of lanes x steps)."""
+        ``steps_planned``: chunks of lanes x steps) and on how many of a
+        lane's steps a client starts or ends (``tree_pass_steps``)."""
         from fedml_tpu.parallel.packed import plan_arrays_tuple
 
         sampled, live, lanes = plan.sampled, plan.live, plan.lanes
@@ -694,7 +695,9 @@ class FedAvgAPI:
         with span(SPAN_PLAN, round=round_idx,
                   steps_planned=lanes.n_lanes // width * lanes.T,
                   steps_run=lanes.executed_slots(
-                      width, self.config.scan_unroll) // width):
+                      width, self.config.scan_unroll) // width,
+                  tree_pass_steps=lanes.tree_pass_steps(
+                      width, self.config.scan_unroll)):
             counts = np.asarray(self.dataset.train_counts, np.float32)[sampled]
             weights = (counts if live is None
                        else counts * np.asarray(live, np.float32))

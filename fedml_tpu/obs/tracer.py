@@ -130,6 +130,13 @@ SCOPE_SERVER = "fedml.server"
 
 # Host spans (:func:`span`), on the profiler's clock.
 SPAN_ROUND = "fedml/round"
+#: the host's part of a round before its program: cohort, weights, the lane
+#: plan. A packed round's says what the plan's loop does (``round=<index>``,
+#: ``steps_planned``: chunks of lanes x the plan's T; ``steps_run``: the
+#: steps the chunks walk, parallel/packed.chunk_bounds; ``tree_pass_steps``:
+#: the client boundaries on them, PackPlan.tree_pass_steps: reset and emit
+#: flags under the chunks' bounds, which in a ONE-lane round are the passes
+#: over the parameter tree its program still makes, of two a step)
 SPAN_PLAN = "fedml/round/plan"
 SPAN_BUILD = "fedml/round/build"
 SPAN_ENQUEUE = "fedml/round/enqueue"

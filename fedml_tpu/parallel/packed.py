@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from fedml_tpu.core.pytree import map_chunks
 from fedml_tpu.core.tasks import Task
@@ -80,6 +81,19 @@ class PackPlan(NamedTuple):
         lanes x the steps that chunk walks (:func:`chunk_bounds`, the bound
         the lane program itself takes) — without the batch factor."""
         return int(width * chunk_bounds(self.live, width, unroll).sum())
+
+    def tree_pass_steps(self, width: int, unroll: int = 1) -> int:
+        """Client boundaries on the steps the chunks walk: the ``reset`` and
+        ``emit`` flags set under each chunk's bound (:func:`chunk_bounds`,
+        as :meth:`executed_slots` takes it), counted together. In a round of
+        ONE lane these are the passes over the parameter tree its program
+        still makes (:func:`make_lane_train`'s ``branch``), of two a walked
+        step; lanes under ``vmap`` make both on every step whatever it
+        reads."""
+        walked = (np.arange(self.T) < np.repeat(
+            chunk_bounds(self.live, width, unroll), width)[:, None])
+        return int(((self.reset > 0) & walked).sum()
+                   + ((self.emit > 0) & walked).sum())
 
 
 def plan_packing(counts: np.ndarray, batch_size: int, epochs: int,
@@ -214,6 +228,53 @@ def _member_replay_tables(mask_rows, epochs: int, n_pad: int,
     return member_tables
 
 
+def _kept_transposed(shape) -> bool:
+    """Whether the TPU keeps a float32 array of ``shape`` with its two minor
+    axes swapped. It tiles them (8 sublanes x 128 lanes) and keeps the
+    array the way round that pads less, as it comes the way round on a tie:
+    a ``[2048, 8512]`` kernel lies column-major (2048 x 8,576 against
+    8,512 x 2,048 padded), its transpose row-major. Held to the compiler's
+    own answer shape by shape in tests/test_tpu_compile_lm.py."""
+    if len(shape) < 2:
+        return False
+
+    def padded(sublanes, lanes):
+        return -(-sublanes // 8) * 8 * (-(-lanes // 128) * 128)
+
+    rows, cols = shape[-2:]
+    return padded(cols, rows) < padded(rows, cols)
+
+
+def _on_flag(flag, update: Callable, kept, *more):
+    """``update(kept, *more)`` where ``flag`` (a scalar) is set and ``kept``
+    as it came where not, under ``lax.cond``: the step that does not need
+    the pass over the trees does not make it.
+
+    Both branches say of every leaf they take and hand back that it lies
+    the way round the device keeps it (:func:`_kept_transposed`). The
+    compiler lays a branch out by itself, before the loop around it, and
+    with nothing said it takes and returns a matrix row-major; a leaf that
+    lies column-major is then copied at that door, the loop's carry turns
+    row-major with it and a row-major copy of the round's variables is
+    kept beside the loop: granite4h_sim_c2, whose nine ``[2048, 8512]``
+    kernels lie so, held 589 MB more on the chip and lost two thirds of
+    the gain (PERF.md, PR 42). tests/test_tpu_compile_lm.py compiles a loop
+    for the chip with this and with a plain ``lax.cond`` and fails when the
+    copy is back here, or gone there."""
+    def as_kept(tree):
+        def leaf(x):
+            if not _kept_transposed(x.shape):
+                return x
+            axes = tuple(range(x.ndim))
+            return with_layout_constraint(x, Layout(
+                major_to_minor=axes[:-2] + (axes[-1], axes[-2])))
+        return jax.tree.map(leaf, tree)
+
+    return jax.lax.cond(
+        flag > 0, lambda *trees: as_kept(update(*as_kept(trees))),
+        lambda *trees: as_kept(trees[0]), kept, *more)
+
+
 def make_lane_train(
     bundle: ModelBundle,
     task: Task,
@@ -255,12 +316,27 @@ def make_lane_train(
 
     def lane_train(variables0, x_flat, y_flat, m_flat, mask_rows,
                    member_row, member_keys, member_w, steps_real,
-                   slot, epoch_a, sie, reset, emit, live, bound):
+                   slot, epoch_a, sie, reset, emit, live, bound, *,
+                   branch: bool = False):
         """One lane. x_flat/y_flat/m_flat: [C*n_pad, ...] flattened stacks
         (shared, unbatched); mask_rows [C, n_pad]; member_* are this lane's
         [k_max] arrays; per-step metadata [T]; ``bound``: how many of the T
         steps to walk (:func:`chunk_bounds` of the lanes vmapped together:
-        a scalar, unbatched, so the loop's predicate stays one)."""
+        a scalar, unbatched, so the loop's predicate stays one).
+
+        ``branch``: the two passes over the parameter tree that only a
+        client's first and last step need (the reset, the emit into the
+        lane's sums) run under ``lax.cond`` on the step's own flag, so the
+        steps between make neither. For a lane with NO lane axis only
+        (:func:`make_lanes_train` says which): under ``vmap`` the flags are
+        batched, a branch lowers to both sides and a select, and the lane
+        would pay the trace of a branch for the selects it has. The two
+        forms share the blocks' bodies, and the arithmetic as written (no
+        ``a * b + c`` contracted) agrees bit for bit, but for the sign of a
+        zero (``a + 0 * v`` turns ``-0.0`` into ``+0.0``); two COMPILED
+        programs may differ by 1 ulp where the backend fuses ``a * b + c``
+        in one and not in the other (BatchNorm's running means, on the
+        CPU at its default level)."""
         with jax.named_scope(SCOPE_PROLOGUE):
             params0 = variables0["params"]
             opt_state0 = tx_opt.init(params0)
@@ -273,18 +349,24 @@ def make_lane_train(
             orders, bkeys = jax.vmap(member_tables)(member_keys, member_row)
 
         def step_fn(carry, xs):
-            (variables, opt_state, loss_acc, acc_vars, acc_w, acc_loss,
-             acc_tau, acc_extras) = carry[:8]
+            variables, opt_state, loss_acc = carry[:3]
+            # what a client's last step adds to: the lens stacks ride along
+            accs = carry[3:8] + (carry[8][:3] if lens else ())
             k, e, s, rs, em, lv = xs
             with jax.named_scope(SCOPE_STEP_RESET):
-                variables = jax.tree.map(
-                    lambda v, z: jnp.where(rs > 0, z, v), variables, variables0)
-                opt_state = jax.tree.map(
-                    lambda v, z: jnp.where(rs > 0, z, v), opt_state, opt_state0)
-                loss_acc = jnp.where(rs > 0, 0.0, loss_acc)
-                if lens:
-                    upd_stack, l_first, l_last, floss_acc = carry[8]
-                    floss_acc = jnp.where(rs > 0, 0.0, floss_acc)
+                # what a client's first step starts from: the round's
+                # variables, a new optimizer, no loss (nor the lens's)
+                state = (variables, opt_state, loss_acc) + (
+                    (carry[8][3],) if lens else ())
+                fresh = (variables0, opt_state0) + jax.tree.map(
+                    jnp.zeros_like, state[2:])
+                if branch:
+                    state = _on_flag(rs, lambda state, fresh: fresh,
+                                     state, fresh)
+                else:
+                    state = jax.tree.map(
+                        lambda v, z: jnp.where(rs > 0, z, v), state, fresh)
+                variables, opt_state, loss_acc = state[:3]
 
             with jax.named_scope(SCOPE_STEP_GATHER):
                 row = member_row[k]
@@ -315,54 +397,71 @@ def make_lane_train(
 
                 lastep = (e == epochs - 1).astype(jnp.float32)
                 loss_acc = loss_acc + l * lv * lastep
+                if lens:
+                    floss_acc = state[3] + l * lv * (e == 0).astype(jnp.float32)
 
-                w = member_w[k] * em
-                sr = jnp.maximum(steps_real[k].astype(jnp.float32), 1.0)
+                def emit_block(accs, out_vars):
+                    """The client's result into the lane's sums. Linear in
+                    ``em``: a step that ends no client (``em`` 0) adds
+                    exactly nothing, so the select form calls it on every
+                    step and the branch form only where ``em`` is 1."""
+                    acc_vars, acc_w, acc_loss, acc_tau, acc_extras = accs[:5]
+                    w = member_w[k] * em
+                    sr = jnp.maximum(steps_real[k].astype(jnp.float32), 1.0)
+                    if lens:
+                        # fedlens member scatter (obs/lens.py): each member
+                        # emits exactly once, so .add at its slot is a masked
+                        # set. RAW update (pre-client_transform): a robust
+                        # clip must not hide the attacker from the lens.
+                        upd_stack, l_first, l_last = accs[5:]
+                        upd_stack = jax.tree.map(
+                            lambda b, v, p: b.at[k].add(
+                                em * (v.astype(jnp.float32)
+                                      - p.astype(jnp.float32))),
+                            upd_stack, out_vars["params"], params0)
+                        l_first = l_first.at[k].add(em * floss_acc / sr)
+                        l_last = l_last.at[k].add(em * loss_acc / sr)
+                    acc_out = out_vars
+                    if client_transform is not None:
+                        # hook contract is stacked-clients; singleton axis
+                        acc_out = jax.tree.map(
+                            lambda v: v[0],
+                            client_transform(
+                                variables0,
+                                jax.tree.map(lambda v: v[None], out_vars)))
+                    acc_vars = jax.tree.map(
+                        lambda a, v: a + w * v, acc_vars, acc_out)
+                    acc_w = acc_w + w
+                    acc_loss = acc_loss + w * loss_acc / sr
+                    acc_tau = acc_tau + w * epochs * sr
+                    if reduce_extras is not None:
+                        res1 = LocalResult(
+                            jax.tree.map(lambda v: v[None], out_vars),
+                            (loss_acc / sr)[None], (epochs * sr)[None])
+                        # the hook returns WEIGHTED partial sums
+                        ex = reduce_extras(variables0, res1, w[None])
+                        acc_extras = jax.tree.map(
+                            lambda a, b: a + b, acc_extras, ex)
+                    out = (acc_vars, acc_w, acc_loss, acc_tau, acc_extras)
+                    if lens:
+                        out += (upd_stack, l_first, l_last)
+                    return out
+
+                if branch:
+                    # the barrier (an identity) keeps the compiler from
+                    # moving the optimizer's update of the tree into both
+                    # branches: laguna_sim_c2's round program, compiled for
+                    # the chip, holds 13,892.4 MB without it and 13,513.4
+                    # with it (13,879.3 in the select form; PERF.md, PR 42:
+                    # section 6 has the chip's readings)
+                    accs, out_vars = jax.lax.optimization_barrier(
+                        (accs, out_vars))
+                    accs = _on_flag(em, emit_block, accs, out_vars)
+                else:
+                    accs = emit_block(accs, out_vars)
+                out = (out_vars, new_opt, loss_acc) + accs[:5]
                 if lens:
-                    # fedlens member scatter (obs/lens.py): each member emits
-                    # exactly once, so .add at its slot is a masked set, and
-                    # off-emit steps (em = 0) contribute exactly nothing — the
-                    # same linear-in-w contract the accumulators above rely on.
-                    # RAW update (pre-client_transform): a robust clip must not
-                    # hide the attacker from the lens.
-                    floss_acc = floss_acc + l * lv * (e == 0).astype(jnp.float32)
-                    upd_stack = jax.tree.map(
-                        lambda b, v, p: b.at[k].add(
-                            em * (v.astype(jnp.float32) - p.astype(jnp.float32))),
-                        upd_stack, out_vars["params"], params0)
-                    l_first = l_first.at[k].add(em * floss_acc / sr)
-                    l_last = l_last.at[k].add(em * loss_acc / sr)
-                acc_out = out_vars
-                if client_transform is not None:
-                    # hook contract is stacked-clients; singleton axis at emit
-                    acc_out = jax.tree.map(
-                        lambda v: v[0],
-                        client_transform(
-                            variables0,
-                            jax.tree.map(lambda v: v[None], out_vars)))
-                acc_vars = jax.tree.map(lambda a, v: a + w * v, acc_vars, acc_out)
-                acc_w = acc_w + w
-                acc_loss = acc_loss + w * loss_acc / sr
-                acc_tau = acc_tau + w * epochs * sr
-                if reduce_extras is not None:
-                    res1 = LocalResult(
-                        jax.tree.map(lambda v: v[None], out_vars),
-                        (loss_acc / sr)[None], (epochs * sr)[None])
-                    # the hook returns WEIGHTED partial sums; w = 0 off-emit,
-                    # so non-emit steps contribute exactly nothing. The hook
-                    # (like client_transform above) COMPUTES every step even
-                    # though only emit steps land — that is O(params) of
-                    # elementwise work per step against the step's O(batch x
-                    # model) training FLOPs, <0.1% for conv models; buffering
-                    # emitted trees and hooking once per member would trade it
-                    # for a k_max-sized model buffer per lane and more HBM
-                    # traffic than it saves.
-                    ex = reduce_extras(variables0, res1, w[None])
-                    acc_extras = jax.tree.map(lambda a, b: a + b, acc_extras, ex)
-                out = (out_vars, new_opt, loss_acc, acc_vars, acc_w, acc_loss,
-                       acc_tau, acc_extras)
-                if lens:
-                    out = out + ((upd_stack, l_first, l_last, floss_acc),)
+                    out += (accs[5:] + (floss_acc,),)
             return out
 
         # zeros DERIVED from inputs, not constants: under shard_map the
@@ -561,7 +660,10 @@ def make_lanes_train(
     batched-kernel convs to a grouped conv, docs/mfu_experiments.md H4),
     :func:`lane_vmap_width` lanes at a time, the chunks one after another
     in one ``lax.map``, each as far as its own last live step
-    (:func:`chunk_bounds`); with ``packed_conv`` on and a capable model, the
+    (:func:`chunk_bounds`), and ONE lane with no lane axis at all, which
+    alone can branch at its client boundaries (``lane_train``'s ``branch``:
+    chosen here, from the lanes' count); with ``packed_conv`` on and a
+    capable model, the
     fedpack JOINT form (:func:`make_packed_lanes_train`) whose convs are
     ONE block-diagonal/grouped contraction across lanes
     (ops/packed_conv.py). Same signature and stacked-accumulator return
@@ -584,12 +686,13 @@ def make_lanes_train(
             return chunk_bounds(lanes[-1], lanes[-1].shape[0], unroll)[0]
 
         if L == 1:
-            # one lane needs no lane axis inside its program; the results
-            # get the axis back
+            # one lane needs no lane axis inside its program, so its step
+            # flags are scalars and it can branch on them; the results get
+            # the axis back
             return jax.tree.map(
                 lambda a: a[None],
                 lane_train(*shared, *(a[0] for a in per_lane),
-                           bound(per_lane)))
+                           bound(per_lane), branch=True))
         w = lane_vmap_width(variables0, L)
         if w == L:
             return vmapped(*shared, *per_lane, bound(per_lane))

@@ -235,8 +235,8 @@ def test_crosssilo_packed_elastic_failures():
 
 # -- the lane vmap width (parallel/packed.lane_vmap_width) --------------------
 
-def _lanes_ds(model):
-    shape = (6,) if model == "lr" else (8, 8, 3)
+def _lanes_ds(model, features=6):
+    shape = (features,) if model == "lr" else (8, 8, 3)
     return make_synthetic_classification(
         "pack-w", shape, 4, 10, records_per_client=12,
         partition_method="hetero", partition_alpha=0.5, batch_size=4, seed=3)
@@ -485,7 +485,8 @@ def test_round_counts_reports_the_steps_the_chunks_walk(pack_lanes, width,
 
 def test_plan_span_says_how_many_steps_the_round_walked(tmp_path):
     """The ``fedml/round/plan`` span of a packed round carries
-    ``steps_planned`` (chunks x T) and ``steps_run`` (the chunks' bounds)."""
+    ``steps_planned`` (chunks x T), ``steps_run`` (the chunks' bounds) and
+    ``tree_pass_steps`` (the reset and emit flags on the walked steps)."""
     import glob
 
     from jax.profiler import ProfileData
@@ -512,8 +513,260 @@ def test_plan_span_says_how_many_steps_the_round_walked(tmp_path):
              if ev.name == tracer.SPAN_PLAN]
     bounds = chunk_bounds(lanes.live, 2)
     assert bounds.sum() < 2 * lanes.T
+    # 10 clients: each resets once and emits once, and every flag lies under
+    # its chunk's bound (lanes 2 at a time), on a step the program walks
+    walked = np.arange(lanes.T) < np.repeat(bounds, 2)[:, None]
+    assert ((lanes.reset > 0) <= walked).all()
+    assert ((lanes.emit > 0) <= walked).all()
+    assert lanes.tree_pass_steps(2) == 20
+    # a bound cut short leaves the flags past it out of the count
+    assert lanes._replace(live=lanes.live * (np.arange(lanes.T) < 2)
+                          ).tree_pass_steps(2) == int(
+        (lanes.reset[:, :2] > 0).sum() + (lanes.emit[:, :2] > 0).sum())
     assert {"round": 0, "steps_planned": 2 * lanes.T,
-            "steps_run": int(bounds.sum())} in plans
+            "steps_run": int(bounds.sum()),
+            "tree_pass_steps": 20} in plans
+
+
+# -- a one-lane round resets and emits under a branch (ISSUE 42) ---------------
+
+def _zoo_hooks():
+    """FedNova's ``reduce_extras`` and the robust clip's ``client_transform``,
+    from the algorithms themselves (tests/test_packed_zoo.py rides them on
+    the mesh)."""
+    from fedml_tpu.algorithms.fednova import FedNovaAPI
+    from fedml_tpu.algorithms.robust import FedAvgRobustAPI
+
+    ds = _lanes_ds("lr")
+    kw = dict(model="lr", dataset="pack-w", client_num_in_total=10,
+              client_num_per_round=10, comm_round=1, batch_size=4, lr=0.05,
+              momentum=0.9, seed=1, frequency_of_the_test=10_000)
+    hooks = {}
+    for cls, extra, name in ((FedNovaAPI, {}, "reduce_extras"),
+                             (FedAvgRobustAPI, {"norm_bound": 0.05},
+                              "client_transform")):
+        api = cls(ds, FedConfig(**kw, **extra))
+        try:
+            hooks[name] = api.crosssilo_hooks()[name]
+        finally:
+            api.close()
+    return hooks
+
+
+#: name -> (model, the lane's clients (rows of ``_lanes_ds``: counts 15 8 12
+#: 12 16 15 13 9 12 8, batch 4), make_lane_train's keywords, which of the
+#: lane's members stay active)
+ONE_LANE_CASES = {
+    "3-unequal-clients": ("lr", (1, 3, 4), {}, None),
+    "2-epochs": ("lr", (1, 3, 4), {"epochs": 2}, None),
+    "last-partial-batch": ("lr", (7, 6), {}, None),
+    "unroll-2-padded-tail": ("lr", (1, 3, 4), {"scan_unroll": 2}, None),
+    "dead-client-mid-lane": ("lr", (1, 3, 4), {}, (1.0, 0.0, 1.0)),
+    "momentum": ("lr", (1, 3, 4), {"momentum": 0.9}, None),
+    "batch-stats": ("resnet20", (1, 7), {"momentum": 0.9}, None),
+    "zoo-hooks": ("lr", (1, 3, 4), {"momentum": 0.9, "hooks": True}, None),
+    "lens": ("lr", (1, 3, 4), {"lens": True}, None),
+    # 128 features: a [128, 4] kernel, which the TPU keeps column-major and
+    # the branches are told so (parallel/packed._on_flag)
+    "column-major-leaf": ("lr", (1, 3, 4), {"momentum": 0.9, "features": 128}, None),
+}
+
+
+def _one_lane_case(name):
+    """-> (lane_train, its arguments but ``branch``, plan): ONE lane of
+    :func:`make_lane_train`, called as ``make_lanes_train`` calls it at
+    ``L == 1`` (no lane axis), with the arguments
+    ``make_packed_cohort_train``'s prologue would hand it."""
+    from fedml_tpu.parallel.packed import (chunk_bounds, make_lane_train,
+                                           masked_plan)
+
+    model, rows, kw, active = ONE_LANE_CASES[name]
+    kw, rows = dict(kw), np.asarray(rows)
+    ds = _lanes_ds(model, kw.pop("features", 6))
+    counts = np.asarray(ds.train_counts, np.int64)
+    assert len(set(counts[rows])) == len(rows) and counts[rows].min() > 0
+    if kw.pop("hooks", False):
+        kw.update(_zoo_hooks())
+    epochs, unroll = kw.get("epochs", 1), kw.get("scan_unroll", 1)
+    plan = plan_packing(counts[rows].astype(np.float64), 4, epochs, n_lanes=1)
+    if active is not None:
+        plan = masked_plan(plan, np.asarray([active], np.float32))
+    bundle = create_model(model, ds.class_num,
+                          input_shape=ds.train_x.shape[2:])
+    n_pad = int(ds.train_x.shape[1])
+    lane_train = make_lane_train(
+        bundle, get_task(ds.task, ds.class_num), n_pad, optimizer="sgd",
+        lr=0.05, batch_size=4, **kw)
+    tx, ty, tm = (jnp.asarray(a) for a in
+                  (ds.train_x, ds.train_y, ds.train_mask))
+    C = tx.shape[0]
+    pos = plan.member_pos[0]
+    args = (bundle.init(jax.random.PRNGKey(0)),
+            tx.reshape((C * n_pad,) + tx.shape[2:]),
+            ty.reshape((C * n_pad,) + ty.shape[2:]), tm.reshape(C * n_pad),
+            tm, jnp.asarray(rows[pos], jnp.int32),
+            jax.random.split(jax.random.PRNGKey(5), len(rows))[pos],
+            jnp.asarray(counts[rows][pos] * plan.member_valid[0], jnp.float32),
+            *(jnp.asarray(a[0]) for a in (
+                plan.steps_real, plan.slot, plan.epoch, plan.sie, plan.reset,
+                plan.emit, plan.live)),
+            jnp.asarray(chunk_bounds(plan.live, 1, unroll)[0]))
+    return lane_train, args, plan
+
+
+@pytest.mark.parametrize("case", list(ONE_LANE_CASES))
+def test_one_lane_branch_is_the_select_form_bit_for_bit(case):
+    """A lane with no lane axis resets and emits under ``lax.cond`` on the
+    step's own flag (``branch``); the select form of the SAME lane program
+    makes both passes on every step. Every output leaf is equal, bit for
+    bit: sums, weight, loss, tau, the hooks' extras, the lens stacks.
+    (``np.array_equal`` takes ``-0.0`` for ``+0.0``: the one difference the
+    forms may have, since ``a + 0 * v`` turns a ``-0.0`` sum into ``+0.0``.)
+    The cases: the shapes of plan and the carries a wrong reset or a
+    forgotten accumulator would show in."""
+    import functools
+
+    lane_train, args, plan = _one_lane_case(case)
+    _, _, kw, active = ONE_LANE_CASES[case]
+    if case == "batch-stats":
+        assert set(args[0]) == {"params", "batch_stats"}
+    if case == "column-major-leaf":
+        from fedml_tpu.parallel.packed import _kept_transposed
+
+        assert sorted((v.shape, _kept_transposed(v.shape))
+                      for v in jax.tree.leaves(args[0])) == [
+            ((4,), False), ((128, 4), True)]
+    if case == "unroll-2-padded-tail":
+        assert plan.T % 2 == 1
+    if case == "last-partial-batch":
+        assert (np.asarray(args[7]) % 4 > 0).all()
+    if active is not None:
+        assert plan.live[0, 0] and plan.live[0, -1] and not plan.live[0].all()
+        assert plan.tree_pass_steps(1) == 4
+    else:
+        assert plan.tree_pass_steps(1) == 2 * plan.k_max < 2 * plan.T
+    # LLVM at -O0: the CPU backend otherwise contracts ``a * b + c`` into a
+    # fused multiply-add where a fusion's shape invites it, not alike in two
+    # programs (BatchNorm's running mean read 1 ulp apart); with no
+    # contraction the arithmetic that is written is the arithmetic that runs
+    got, want = (jax.block_until_ready(
+        jax.jit(functools.partial(lane_train, branch=b)).lower(*args).compile(
+            compiler_options={"xla_backend_optimization_level": 0})(*args))
+        for b in (True, False))
+    assert len(got) == (6 if kw.get("lens") else 5)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and np.isfinite(a).all()
+        assert np.array_equal(a, b)
+    assert float(got[1]) > 0 and float(got[2]) > 0
+    if kw.get("hooks"):
+        assert float(got[4]["na"]) > 0
+        assert any(np.abs(np.asarray(v)).max() > 0
+                   for v in jax.tree.leaves(got[4]["pd"]))
+
+
+def _step_loop(jaxpr):
+    """-> (equations, ``cond`` equations) inside the body of the program's
+    largest ``while``: the lane loop (``_walk_steps``), counted through
+    every nested jaxpr."""
+    from jax.extend import core as jex_core
+
+    def subjaxprs(eqn):
+        for v in eqn.params.values():
+            for x in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(x, jex_core.ClosedJaxpr):
+                    yield x.jaxpr
+                elif isinstance(x, jex_core.Jaxpr):
+                    yield x
+
+    def count(j):
+        n = c = 0
+        for eqn in j.eqns:
+            n, c = n + 1, c + (eqn.primitive.name == "cond")
+            for sub in subjaxprs(eqn):
+                dn, dc = count(sub)
+                n, c = n + dn, c + dc
+        return n, c
+
+    def whiles(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "while":
+                yield count(eqn.params["body_jaxpr"].jaxpr)
+            for sub in subjaxprs(eqn):
+                yield from whiles(sub)
+
+    return max(whiles(jaxpr))
+
+
+@pytest.mark.parametrize("n_lanes", [1, 2, 8])
+def test_lanes_under_vmap_trace_no_branch(monkeypatch, n_lanes):
+    """``make_lanes_train`` branches only where the lanes have no lane axis
+    (``L == 1``: two ``cond`` in the loop's body, the reset's and the
+    emit's). Lanes under ``vmap`` (2 together; 8, two at a time) trace the
+    select form and nothing else: no ``cond`` in the body and the select
+    form's count of equations. The count is a witness: a branch under
+    ``vmap`` has a batched predicate, is lowered to both sides and a select
+    (no ``cond`` left to see), and counts more equations."""
+    from fedml_tpu.parallel import packed
+
+    make_lane_train = packed.make_lane_train
+
+    def forced(value):
+        def make(*a, **kw):
+            lane = make_lane_train(*a, **kw)
+            return lambda *args, branch=False: lane(*args, branch=value)
+        return make
+
+    def body():
+        build, args = _lanes_case("resnet20", n_lanes, hooks=True, lens=True)
+        return _step_loop(jax.make_jaxpr(build())(*args).jaxpr)
+
+    as_built = body()
+    monkeypatch.setattr(packed, "make_lane_train", forced(False))
+    select = body()
+    monkeypatch.setattr(packed, "make_lane_train", forced(True))
+    branch = body()
+    assert select[1] == 0
+    if n_lanes == 1:
+        assert as_built == branch and branch[1] == 2
+        assert branch[0] != select[0]
+    else:
+        assert as_built == select
+        assert branch[1] == 0 and branch[0] > select[0]
+
+
+def test_crosssilo_one_lane_a_device_branches_and_matches_sim(monkeypatch):
+    """The mesh reaches the same ``make_lanes_train``: a device that holds
+    one lane takes the branch under ``shard_map`` (each device on its own
+    flags; no collective is inside the loop), and the rounds agree with the
+    canonical unbucketed simulation run."""
+    from fedml_tpu.algorithms.fedavg import CrossSiloFedAvgAPI
+    from fedml_tpu.parallel import packed
+    from fedml_tpu.parallel.mesh import client_mesh
+
+    traced = []
+    make_lane_train = packed.make_lane_train
+
+    def spy(*a, **kw):
+        lane = make_lane_train(*a, **kw)
+
+        def lane_train(*args, branch=False):
+            traced.append(branch)
+            return lane(*args, branch=branch)
+        return lane_train
+
+    monkeypatch.setattr(packed, "make_lane_train", spy)
+    ds = _ds(C=8, records=200, bs=8)
+    kw = dict(client_num_in_total=8, client_num_per_round=8)
+    mesh_api = CrossSiloFedAvgAPI(ds, _cfg(pack_lanes=2, **kw),
+                                  mesh=client_mesh(2))
+    assert mesh_api._packed_mesh["plan"].shape_key[:2] == (2, 4)
+    hm = mesh_api.train()
+    assert traced and all(traced)
+    ref = FedAvgAPI(ds, _cfg(bucket_quantum_batches=0, **kw)).train()
+    np.testing.assert_allclose(hm["Test/Loss"], ref["Test/Loss"], rtol=3e-5)
+    np.testing.assert_allclose(hm["Test/Acc"], ref["Test/Acc"], atol=1e-6)
 
 
 # -- the benchmark cells' schedules, without a chip ----------------------------

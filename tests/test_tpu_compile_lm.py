@@ -456,3 +456,88 @@ def test_mlp_routed_layer_compiles_at_published_widths(one_chip, no_cache):
     assert gp["router"]["gamma"].shape == () and gc.shape == (8192, 256)
     assert gp["router"]["bias"].shape == (17,)
     assert gx.shape == (2, 4096, 2048)
+
+
+# -- the one-lane round's branches (parallel/packed._on_flag, PR 42) -----------
+
+#: float32 shapes the LM cells' variable trees hold (kernels, gates, routers,
+#: stacked experts, the state-space convolution) and shapes on both sides of
+#: each edge of the padding rule
+KEPT_SHAPES = [
+    (2048, 8512), (8512, 2048), (2560, 32), (2048, 576), (2048, 16032),
+    (2560, 19648), (2048, 48), (2048, 64), (256, 17), (2048, 17),
+    (12544, 2048), (32784, 2048), (2048, 2048), (4, 4352), (2, 1280),
+    (10, 128), (8, 2048, 2048), (16, 2048, 768), (32, 512, 2048),
+    (2, 10, 128, 128), (2048,), (), (128, 4), (120, 4), (1024, 1),
+    (100, 200), (136, 200), (256, 200), (128, 129), (512, 127), (8, 200),
+    (64, 200), (3, 3, 16, 32), (7, 2048, 64), (2, 2048, 8512)]
+
+
+def test_kept_transposed_is_the_compilers_own_layout(one_chip, no_cache):
+    """``_kept_transposed`` says which float32 shapes the TPU keeps with
+    their two minor axes swapped; the compiler's own layouts for a program's
+    arguments say the same of every shape here. If a compiler changes its
+    mind, this fails, and ``_on_flag`` tells its branches the wrong way."""
+    from fedml_tpu.parallel.packed import _kept_transposed
+
+    compiled = jax.jit(lambda *xs: [x + 1 for x in xs]).lower(*(
+        jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+        for s in KEPT_SHAPES)).compile()
+    for shape, fmt in zip(KEPT_SHAPES, compiled.input_formats[0]):
+        order = tuple(fmt.layout.major_to_minor)
+        plain = tuple(range(len(shape)))
+        swapped = plain[:-2] + plain[-2:][::-1]
+        assert order == (swapped if _kept_transposed(shape) else plain), shape
+    assert sum(map(_kept_transposed, KEPT_SHAPES)) == 17
+
+
+@pytest.mark.parametrize("form", ["select", "on_flag", "plain_cond"])
+def test_flagged_passes_leave_the_loop_carry_as_the_device_keeps_it(
+        one_chip, no_cache, form):
+    """A loop that carries a ``[2048, 8512]`` float32 kernel (granite's
+    ``in_proj``: column-major on the device), trains it and sums it, with
+    the reset and the sum on flags, as the lane loop has them. With selects
+    the kernel is column-major throughout. Under ``_on_flag`` too: no
+    row-major value of that shape anywhere in the compiled program, and the
+    temporaries hold no copy of it. Under a plain ``lax.cond`` the branches
+    take it row-major, the carry turns, and two copies (2 x 70 MB) appear:
+    why ``_on_flag`` says the layout. When THAT case fails the compiler has
+    learnt it, and ``_on_flag`` can go back to ``lax.cond``."""
+    from fedml_tpu.parallel.packed import _on_flag
+
+    shape = (2048, 8512)
+
+    def flagged(flag, update, kept, *more):
+        if form == "select":
+            return jnp.where(flag > 0, update(kept, *more), kept)
+        if form == "on_flag":
+            return _on_flag(flag, update, kept, *more)
+        return jax.lax.cond(flag > 0, update, lambda kept, *more: kept,
+                            kept, *more)
+
+    def loop(w0, x, reset, emit):
+        def step(i, carry):
+            w, acc = carry
+            w = flagged(reset[i], lambda w, w0: w0, w, w0)
+            w = w - 0.1 * jax.grad(lambda w: jnp.sum(jnp.tanh((
+                x @ w.astype(jnp.bfloat16)).astype(jnp.float32))))(w)
+            return w, flagged(emit[i], lambda acc, w: acc + emit[i] * w,
+                              acc, w)
+        return jax.lax.fori_loop(0, 8, step, (w0, jnp.zeros_like(w0)))
+
+    def sd(dtype, *s):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    compiled = jax.jit(loop).lower(
+        sd(jnp.float32, *shape), sd(jnp.bfloat16, 512, shape[0]),
+        sd(jnp.float32, 8), sd(jnp.float32, 8)).compile()
+    text = compiled.as_text()
+    row_major = len(re.findall(r"f32\[2048,8512\]\{1,0", text))
+    assert len(re.findall(r"f32\[2048,8512\]\{0,1", text)) > 20
+    assert text.count(" conditional(") == (0 if form == "select" else 2)
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    one_copy = 4 * shape[0] * shape[1]
+    if form == "plain_cond":
+        assert row_major > 0 and temporaries >= 2 * one_copy
+    else:
+        assert row_major == 0 and temporaries < one_copy
