@@ -157,122 +157,6 @@ def _bench_crosssilo(tiny: bool, model: str, rounds: int, batch: int,
     }
 
 
-def _bench_packed_conv_ab(ds, base_cfg, model: str, rounds: int, peak):
-    """fedpack flagship A/B (ops/packed_conv.py): the SAME packed-schedule
-    round measured under the per-lane vmap lowering ('off') and the
-    client-packed lowering (BENCH_PACKED_CONV_MODE, default 'blockdiag') —
-    per-lowering real img/s, the packed program's static output-lane
-    ceiling (the lift the packing buys) and, when a TPU peak is known,
-    measured USEFUL-basis MFU vs that ceiling. On the CPU container this
-    block is a structural/no-regression check (the >=1.5x img/s claim is
-    asserted only on the TPU bench host, docs/perf.md 'Client packing')."""
-    import jax
-    import jax.numpy as jnp
-
-    from fedml_tpu.algorithms.fedavg import FedAvgAPI
-    from fedml_tpu.models import create_model
-    from fedml_tpu.obs import cost as fedcost
-
-    mode = os.environ.get("BENCH_PACKED_CONV_MODE", "blockdiag")
-
-    def measure_arms(api_cls, pick_table, cfg_extra=None):
-        """One A/B (off vs ``mode``) through the shared measurement
-        discipline — two warm passes, one timed pass, real-img/s +
-        static-ceiling + roofline per arm — so the sgd flagship and the
-        adaptive arm below stay comparable in the same JSON tail."""
-        res = {"img_per_sec": {}, "mfu_vs_lane_ceiling": {},
-               "mfu_mac_useful": {}}
-        ceilings = {}
-        for arm in dict.fromkeys(("off", mode)):
-            # force residency so the CPU smoke exercises the same packed
-            # (device-resident) schedule branch the TPU run measures
-            cfg = base_cfg.replace(packed_conv=arm, device_data="on",
-                                   **(cfg_extra or {}))
-            bundle = create_model(
-                model, 10, dtype=jnp.bfloat16,
-                input_shape=ds.train_x.shape[2:],
-                bn_impl=os.environ.get("BENCH_BN", "xla"),
-                conv_impl=os.environ.get("BENCH_CONV", "xla"))
-            fedcost.reset_cost_tables()
-            api = api_cls(ds, cfg, bundle)
-            for _pass in range(2):    # same two-pass warm as the headline
-                for r in range(1, rounds + 1):
-                    last = api.run_round(r)
-                jax.block_until_ready(last)
-            t0 = time.perf_counter()
-            for r in range(1, rounds + 1):
-                last = api.run_round(r)
-            jax.block_until_ready(last)
-            dt = time.perf_counter() - t0
-            real = sum(api.round_counts(r)[0] for r in range(1, rounds + 1))
-            res["img_per_sec"][arm] = round(real * EPOCHS / dt, 1)
-            rec = pick_table()
-            if rec is not None:
-                ceilings[arm] = rec["summary"]["out_lane_ceiling"]
-                rf = fedcost.roofline(rec["summary"], dt, invocations=rounds,
-                                      peak=peak)
-                res["mfu_vs_lane_ceiling"][arm] = rf.get("mfu_vs_ceiling")
-                res["mfu_mac_useful"][arm] = rf.get("mfu_mac_useful",
-                                                    rf.get("mfu_mac"))
-        off, on = res["img_per_sec"].get("off"), res["img_per_sec"].get(mode)
-        res["speedup"] = round(on / off, 3) if (off and on) else None
-        # the packed program's static ceiling — the lane lift the packing
-        # buys (bench_report tracks this across the artifact series)
-        res["out_lane_ceiling"] = ceilings.get(mode)
-        res["off_lane_ceiling"] = ceilings.get("off")
-        return res
-
-    def biggest_table():
-        return max(fedcost.cost_tables().values(),
-                   key=lambda r: r["summary"]["gemm_flops_per_invocation"],
-                   default=None)
-
-    out = dict({"mode": mode}, **measure_arms(FedAvgAPI, biggest_table))
-
-    # fedplan (ISSUE 18): when the measured arm is `auto`, embed the plan
-    # the run resolved — per-stage picks, predicted vs uniform ceilings —
-    # so the artifact records WHY the arm lowered the way it did
-    # (bench_report's `plan` column reads the summary string back)
-    if mode == "auto":
-        from fedml_tpu.parallel.packed import (packed_fallback_reason,
-                                               resolve_packed_conv)
-
-        bundle = create_model(model, 10, dtype=jnp.bfloat16,
-                              input_shape=ds.train_x.shape[2:],
-                              bn_impl=os.environ.get("BENCH_BN", "xla"),
-                              conv_impl=os.environ.get("BENCH_CONV", "xla"))
-        resolved = resolve_packed_conv(
-            "auto", bundle, int(base_cfg.pack_lanes),
-            optimizer=base_cfg.client_optimizer)
-        out["plan"] = (
-            {"resolved": resolved,
-             "reason": packed_fallback_reason(bundle, "auto",
-                                              base_cfg.client_optimizer)}
-            if isinstance(resolved, str) else resolved.to_dict())
-
-    # packed-everywhere (ISSUE 12): one ADAPTIVE arm through the identical
-    # harness — FedOpt with a stateful server optimizer rides the same
-    # packed round program (hooks + threaded server state), so its
-    # per-lowering img/s and static ceiling land in the tail next to the
-    # sgd flagship's. BENCH_PACKED_CONV_OPT names the server optimizer
-    # ('off' disables the arm); bench_report's `fedopt ceiling` column is
-    # missing-key tolerant for pre-ISSUE-12 artifacts.
-    server_opt = os.environ.get("BENCH_PACKED_CONV_OPT", "adam")
-    if server_opt not in ("", "off", "0"):
-        from fedml_tpu.algorithms.fedopt import FedOptAPI
-
-        def fedopt_table():
-            # the class-qualified record for exactly the program measured
-            return (fedcost.table_for("packed_step.FedOptAPI")
-                    or biggest_table())
-
-        out["fedopt"] = dict(
-            {"server_optimizer": server_opt},
-            **measure_arms(FedOptAPI, fedopt_table,
-                           {"server_optimizer": server_opt}))
-    return out
-
-
 def _bench_crossdevice_r05_basis(tiny: bool):
     """Cross-device paradigm at the reference's own scale: 342,477 logical
     clients, 50 sampled per round (stackoverflow row,
@@ -934,15 +818,6 @@ def main():
                         "drift": sk.get("drift"),
                         "folds": st["folds"], "suspects": st["suspects"]}
 
-    # fedpack flagship A/B (ISSUE 9): both packed-conv lowerings measured
-    # through the same harness, embedded as the `packed_conv` block. Runs
-    # AFTER the flagship snapshot (it resets the cost tables per arm) and
-    # before the paradigm benches re-enable their own attribution records.
-    packed_conv_ab = None
-    if not os.environ.get("BENCH_NO_PACKED_AB"):
-        packed_conv_ab = _bench_packed_conv_ab(ds, cfg, model, rounds, peak)
-        fedcost.reset_cost_tables()   # paradigm benches attribute fresh
-
     # Cross-silo paradigm on the same hardware (VERDICT r2 #3): the north
     # star names DISTRIBUTED FedAvg, so measure the shard_map mesh path too —
     # full participation (the standard silo deployment), dataset resident and
@@ -1086,9 +961,6 @@ def main():
         # model's GEMM shapes allow (1.0 = lanes are the only limit) —
         # both sides of the division count GEMM multiply-accumulates only
         "mfu_vs_lane_ceiling": mfu_vs_lane_ceiling,
-        # fedpack A/B (ops/packed_conv.py): per-lowering real img/s, the
-        # packed program's lifted static lane ceiling, useful-basis MFU
-        "packed_conv": packed_conv_ab,
         # fedpulse end-of-run profiler aggregates for the flagship pass
         # (the cross-device block embeds its own at 342k-client scale);
         # carries the fedsketch `sketches` summaries (count + p50/p90/p99

@@ -514,62 +514,35 @@ class FedAvgAPI:
             hooks = {}
         return hooks
 
-    def _tag_packed_program(self, step, n_lanes: int, pconv,
-                            cost_hints: bool = True):
+    def _tag_packed_program(self, step, n_lanes: int):
         """What a built packed program says of itself, set in this one
-        place for the sim, streamed-chunk and mesh builders alike.
+        place for the sim, streamed-chunk and mesh builders alike:
         ``.lane_ids`` (obs/compile.timed_build reads it into the build span
-        and the compile counters): how many lanes one device runs, and how
-        many of them advance together — all of them in the joint form,
-        parallel/packed.lane_vmap_width's choice in the per-lane form.
-        ``.cost_hints`` (obs/cost.attribute_program): the joint form's
-        block-diag dots stream n_lanes x the useful FLOPs and the per-lane
-        form's grouped convs fold the same n_lanes clients (H4) — either
-        way the program folds ``n_lanes`` clients per op."""
-        from fedml_tpu.parallel.packed import impl_label, packed_conv_active
-
+        and the compile counters), how many lanes one device runs and how
+        many of them advance together
+        (parallel/packed.lane_vmap_width's choice)."""
         n_lanes = int(n_lanes)
-        joint = packed_conv_active(self.bundle, pconv,
-                                   self.config.client_optimizer)
         step.lane_ids = {"lanes": n_lanes,
                          "lane_width": self._lane_width(n_lanes)}
-        if cost_hints:
-            step.cost_hints = {
-                "packed_conv": impl_label(pconv) if joint else "off",
-                "packing_factor": n_lanes}
-            if joint and not isinstance(pconv, str):
-                # the LoweringPlan itself: attribute_program self-checks
-                # the realized static ceiling against it and emits
-                # program_plan
-                step.cost_hints["plan"] = pconv
         return step
 
     def packed_status(self) -> dict:
         """Introspection for the packed-coverage contract (the tier-1
         matrix test pins it): ``{"scheduled": <the round path runs packed
-        lanes>, "packed_conv_active": <joint MXU form engages>, "reason":
-        <None or the documented fallback reason>}``. After packed-everywhere
-        the only honest reasons left are the DESIGN.md §15 exception table —
-        models without a packed twin, flax-rng dropout without an
-        explicit-key twin, flag off, an algorithm the lane builder cannot
-        mirror, or a round path that runs no lanes."""
-        from fedml_tpu.parallel.packed import packed_fallback_reason
-
+        lanes>, "reason": <None or why it does not>}``: ``pack_lanes`` 0,
+        an algorithm the lane builder cannot mirror, or a round path that
+        runs no lanes."""
         c = self.config
-        if self._path not in _PACKED_PATHS:
-            if c.pack_lanes <= 0:
-                reason = "pack_lanes=0"
-            elif self._packing_hooks() is None:
-                reason = (f"{type(self).__name__} has no packed-lane "
-                          "algorithm mirror")
-            else:
-                reason = f"the {self._path} round path runs no packed lanes"
-            return {"scheduled": False, "packed_conv_active": False,
-                    "reason": reason}
-        reason = packed_fallback_reason(self.bundle, c.packed_conv,
-                                        c.client_optimizer)
-        return {"scheduled": True, "packed_conv_active": reason is None,
-                "reason": reason}
+        if self._path in _PACKED_PATHS:
+            return {"scheduled": True, "reason": None}
+        if c.pack_lanes <= 0:
+            reason = "pack_lanes=0"
+        elif self._packing_hooks() is None:
+            reason = (f"{type(self).__name__} has no packed-lane "
+                      "algorithm mirror")
+        else:
+            reason = f"the {self._path} round path runs no packed lanes"
+        return {"scheduled": False, "reason": reason}
 
     def _packed_plan(self, ids: np.ndarray):
         """The lane plan of a cohort (or streamed chunk) of these clients;
@@ -587,17 +560,9 @@ class FedAvgAPI:
 
     def _lane_width(self, n_lanes: int) -> int:
         """How many of a device's ``n_lanes`` lanes its packed program
-        advances together: all of them in the joint form,
-        parallel/packed.lane_vmap_width's choice in the per-lane form. The
-        configured ``packed_conv`` says which as well as the resolved one
-        does: 'auto' resolves to 'off' only where the joint form cannot
-        apply or at one lane, whose width is 1 either way."""
-        from fedml_tpu.parallel.packed import (lane_vmap_width,
-                                               packed_conv_active)
+        advances together: parallel/packed.lane_vmap_width's choice."""
+        from fedml_tpu.parallel.packed import lane_vmap_width
 
-        c = self.config
-        if packed_conv_active(self.bundle, c.packed_conv, c.client_optimizer):
-            return n_lanes
         return lane_vmap_width(self.variables, n_lanes)
 
     def _lane_slots(self, lanes, devices: int = 1) -> int:
@@ -614,20 +579,12 @@ class FedAvgAPI:
 
     def build_round_step_packed(self, shape_key: tuple):
         from fedml_tpu.parallel.crosssilo import apply_server_and_rollback
-        from fedml_tpu.parallel.packed import (make_packed_cohort_train,
-                                               resolve_packed_conv)
+        from fedml_tpu.parallel.packed import make_packed_cohort_train
 
-        c = self.config
         n_pad = int(self.dataset.train_x.shape[1])
         hooks = self._packing_hooks() or {}
         server_update = hooks.get("server_update")
         has_extras = hooks.get("reduce_extras") is not None
-        # fedplan: 'auto' resolves HERE, at program-build time, against the
-        # schedule's actual lane count — the plan (or a concrete flag)
-        # flows to the builder and rides the cost hints below
-        pconv = resolve_packed_conv(c.packed_conv, self.bundle,
-                                    int(shape_key[0]),
-                                    optimizer=c.client_optimizer)
         lens_on = self._lens_armed
         reduce_extras = hooks.get("reduce_extras")
         # a model's own counts (models.COUNTERS) are SUMS over the clients'
@@ -642,7 +599,6 @@ class FedAvgAPI:
                     res.variables[COUNTERS], variables0[COUNTERS])
         packed = make_packed_cohort_train(
             self.bundle, self.task, n_pad, shape_key,
-            packed_conv=pconv,
             client_transform=hooks.get("client_transform"),
             reduce_extras=reduce_extras,
             lens=lens_on,
@@ -676,7 +632,7 @@ class FedAvgAPI:
                             packed_lens(upd, lf, ll, mw))
                 return new_vars, new_state, acc_loss / denom
 
-        return self._tag_packed_program(round_step, shape_key[0], pconv)
+        return self._tag_packed_program(round_step, shape_key[0])
 
     def _run_packed_round(self, round_idx: int, plan: RoundPlan):
         """Execute the round under the packed schedule. ``plan.live``
@@ -1127,17 +1083,12 @@ class FedAvgAPI:
         and the lane program's native weighted sums fold into the
         accumulator — the MXU fast path bounded by the accumulator, not by
         one program's cohort buffers."""
-        from fedml_tpu.parallel.packed import (make_packed_cohort_train,
-                                               resolve_packed_conv)
+        from fedml_tpu.parallel.packed import make_packed_cohort_train
 
-        c = self.config
         n_pad = int(self.dataset.train_x.shape[1])
-        pconv = resolve_packed_conv(c.packed_conv, self.bundle,
-                                    int(shape_key[0]),
-                                    optimizer=c.client_optimizer)
         packed = make_packed_cohort_train(
             self.bundle, self.task, n_pad, shape_key,
-            packed_conv=pconv, key_slice=(cohort, start),
+            key_slice=(cohort, start),
             **self._local_train_kwargs())
         rows = jnp.arange(size, dtype=jnp.int32)
 
@@ -1154,8 +1105,7 @@ class FedAvgAPI:
         step = (_donation_quiet(jax.jit(chunk_step,
                                         donate_argnums=(1, 4, 5, 6)))
                 if self.config.donate else jax.jit(chunk_step))
-        return self._tag_packed_program(step, shape_key[0], pconv,
-                                        cost_hints=False)
+        return self._tag_packed_program(step, shape_key[0])
 
     def _stream_finish(self, packed: bool):
         """Round-close for the streaming fold: elastic all-failed rollback
@@ -1798,7 +1748,6 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
         from fedml_tpu.parallel.packed import (
             make_crosssilo_packed_round,
             plan_packing_mesh,
-            resolve_packed_conv,
         )
 
         c, ds = self.config, self.dataset
@@ -1845,23 +1794,16 @@ class CrossSiloFedAvgAPI(FedAvgAPI):
         # fedscope compile telemetry: the packed mesh program is the most
         # expensive build in the tree (shard_map over vmapped lanes); its
         # shape key is the lane geometry that determines the XLA program
-        # fedplan: resolve 'auto' against the PER-DEVICE lane count — the
-        # contraction each device runs folds plan.n_lanes // D clients
-        pconv = resolve_packed_conv(c.packed_conv, self.bundle,
-                                    int(plan.n_lanes // D),
-                                    optimizer=c.client_optimizer)
-
         def _build():
             rf = make_crosssilo_packed_round(
-                self.bundle, self.task, n_pad, self.mesh,
-                packed_conv=pconv, **hooks,
+                self.bundle, self.task, n_pad, self.mesh, **hooks,
                 **self._local_train_kwargs())
-            # the per-DEVICE contraction folds lanes_dev clients
-            return self._tag_packed_program(rf, plan.n_lanes // D, pconv)
+            # one DEVICE runs lanes_dev of the plan's lanes
+            return self._tag_packed_program(rf, plan.n_lanes // D)
 
         round_fn = timed_build(
             "mesh_packed_round",
-            (n_pad, D, lanes_dev, plan.shape_key, c.packed_conv), _build)
+            (n_pad, D, lanes_dev, plan.shape_key), _build)
         return dict(perm=perm, plan=plan, data=data, plan_arrays=plan_arrays,
                     counts_perm=np.asarray(ds.train_counts, np.float32)[perm],
                     round_fn=round_fn)

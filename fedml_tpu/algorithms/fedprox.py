@@ -20,9 +20,8 @@ from fedml_tpu.algorithms.fedavg import CrossSiloFedAvgAPI, FedAvgAPI
 class FedProxAPI(FedAvgAPI):
     def _local_train_kwargs(self) -> dict:
         # inject via the shared kwargs mapping (not build_local_train) so
-        # EVERY trainer form — vmapped, grouped, the packed lanes AND the
-        # fedpack joint MXU form (which folds the per-lane prox term into
-        # its summed loss, parallel/packed.py) — carries the proximal term
+        # EVERY trainer form — the vmapped cohort and the packed lanes —
+        # carries the proximal term
         return dict(super()._local_train_kwargs(),
                     prox_mu=self.config.fedprox_mu)
 

@@ -191,29 +191,6 @@ class FedConfig:
     # packed round); only rewired build_local_train / hookless custom
     # aggregate() fall back, with a warning.
     pack_lanes: int = 0
-    # fedpack conv lowering for the packed schedule's lane axis
-    # (ops/packed_conv.py): how the K co-scheduled lanes' same-shape convs
-    # reach the MXU. "off" (default) keeps the per-lane vmap (XLA lowers it
-    # to a grouped conv, docs/mfu_experiments.md H4); "blockdiag" runs ONE
-    # im2col block-diagonal GEMM per conv across all lanes (output lanes
-    # K*Cout, reduction lanes K*kh*kw*Cin — full MXU dims at K*C >= 128, at
-    # the price of K x streamed FLOPs, reported honestly by fedcost's
-    # packing_factor column); "grouped" runs one feature_group_count=K
-    # convolution (useful FLOPs only; XLA picks the MXU mapping); "auto"
-    # asks the fedplan cost model (obs/plan.py) to pick PER CONV STAGE from
-    # the static fedcost table at program-build time — the chosen plan
-    # rides cost_hints, a program_plan trace instant and the "plan" pulse
-    # lane, and a post-first-call self-check warns when the realized
-    # static ceiling diverges from the prediction. Applies
-    # wherever pack_lanes schedules lanes (sim + cross-silo mesh). The
-    # joint form is the DEFAULT abstraction (packed-everywhere, DESIGN.md
-    # §15): every client optimizer (stacked per-lane optax state),
-    # explicit-key dropout models and the Silo variants ride it; only the
-    # documented exception table (no packed twin / flax-rng dropout) falls
-    # back, warned once + counted in the "packed" registry lane. Numerics
-    # match the vmap lowering up to GEMM summation order
-    # (tests/test_packed_conv.py, tests/test_packed_everywhere.py).
-    packed_conv: str = "off"
     # lax.scan unroll factor for the local-SGD minibatch loop: XLA fuses
     # across adjacent steps (amortizing per-step loop/weight-traffic
     # overheads) without changing the math — same updates in the same
@@ -442,10 +419,6 @@ class FedConfig:
             raise ValueError(f"device_data must be auto|on|off, got {self.device_data!r}")
         if self.pack_lanes < 0:
             raise ValueError(f"pack_lanes must be >= 0, got {self.pack_lanes}")
-        if self.packed_conv not in ("off", "blockdiag", "grouped", "auto"):
-            raise ValueError(
-                f"packed_conv must be off|blockdiag|grouped|auto, got "
-                f"{self.packed_conv!r}")
         if self.cohort_policy not in ("uniform", "speed", "fair"):
             raise ValueError(
                 f"cohort_policy must be uniform|speed|fair, got "
@@ -700,13 +673,6 @@ def add_args(parser: Optional[argparse.ArgumentParser] = None) -> argparse.Argum
                    default=defaults.bucket_quantum_batches)
     p.add_argument("--pack_lanes", type=int, default=defaults.pack_lanes,
                    help="pack the cohort into N scan lanes (0 = off)")
-    p.add_argument("--packed_conv", type=str, default=defaults.packed_conv,
-                   choices=("off", "blockdiag", "grouped", "auto"),
-                   help="fedpack conv lowering for the packed lanes: one "
-                        "block-diagonal GEMM / grouped conv across the K "
-                        "lanes instead of the per-lane vmap (off = vmap); "
-                        "auto = fedplan picks per conv stage from the "
-                        "static roofline table (obs/plan.py)")
     p.add_argument("--host_pipeline_depth", type=int,
                    default=defaults.host_pipeline_depth,
                    help="prefetch this many future rounds' cohorts on "

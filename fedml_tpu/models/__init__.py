@@ -53,20 +53,10 @@ class ModelBundle:
     task: str = "classification"
     has_batch_stats: bool = False
     uses_dropout: bool = False
-    #: explicit-key dropout (ops/packed_conv.seed_dropout): apply_train
-    #: hands ``rng`` to the module as a ``dropout_rng`` kwarg instead of a
-    #: flax rng stream, so the derivation is replayable per lane by the
-    #: packed twin (which receives the [K] vector of lane keys). Models
-    #: opt in per-module; a dropout model WITHOUT it keeps the vmap
-    #: fallback under --packed_conv (parallel/packed.packed_fallback_reason).
+    #: explicit-key dropout (models/cnn.seed_dropout): apply_train hands
+    #: ``rng`` to the module as a ``dropout_rng`` kwarg instead of a flax
+    #: rng stream, so a step's masks derive from its batch key alone
     explicit_dropout: bool = False
-    #: fedpack hook (ops/packed_conv.py): ``packed_variant(impl)`` returns a
-    #: TRAIN-ONLY bundle whose module consumes lane-major [K, N, ...] input
-    #: and whose parameter tree is the standard tree with a leading K axis
-    #: on every leaf (stack_variables/unstack_variables are the bridges).
-    #: None = this model family has no packed conv lowering; the packed
-    #: schedule keeps its per-lane vmap.
-    packed_variant: Optional[Callable[[str], "ModelBundle"]] = None
     #: the single-example shape ``init`` traces with, where parameter shapes
     #: do not depend on it (a sequence model's length) and a forward pass at
     #: ``input_shape`` would be minutes of op-by-op work; such an init is

@@ -12,9 +12,8 @@ NHWC layout (TPU-native; torch reference is NCHW).
 
 from __future__ import annotations
 
-from typing import Any
-
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from fedml_tpu.models import ModelBundle, register_model
@@ -23,14 +22,9 @@ from fedml_tpu.models import ModelBundle, register_model
 class CNNOriginalFedAvg(nn.Module):
     output_dim: int = 62
     only_digits: bool = False
-    conv_impl: str = "xla"   # "packed": fedpack client-packed convs over a
-    #                          leading lane axis (ops/packed_conv.py)
-    packed_impl: Any = "blockdiag"  # name or per-stage LoweringPlan
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        if self.conv_impl == "packed":
-            return self._call_packed(x)
         if x.ndim == 2:  # flat 784 -> 28x28x1
             x = x.reshape((x.shape[0], 28, 28, 1))
         x = nn.Conv(32, (5, 5), padding="SAME")(x)
@@ -41,49 +35,43 @@ class CNNOriginalFedAvg(nn.Module):
         x = nn.relu(nn.Dense(512)(x))
         return nn.Dense(self.output_dim)(x)
 
-    def _call_packed(self, x):
-        """fedpack body (x [K, N, 28, 28, 1] or [K, N, 784] lane-major):
-        same submodule call order as the per-client body, so the parameter
-        tree is the standard tree with a leading K axis (ops/packed_conv
-        contract). Pooling folds the lane axis into the batch axis — it is
-        per-image work with no cross-lane terms."""
-        from fedml_tpu.ops.packed_conv import Conv as PConv
-        from fedml_tpu.ops.packed_conv import Dense as PDense
 
-        if x.ndim == 3:  # [K, N, 784] -> [K, N, 28, 28, 1]
-            x = x.reshape(x.shape[:2] + (28, 28, 1))
-        k = x.shape[0]
+#: salt folded (plus the per-model layer index) into the explicit dropout
+#: key so distinct dropout layers in one step draw independent masks —
+#: the same fold-a-constant derivation the packed replay tables use
+#: (parallel/local.EPOCH_KEY_SALT)
+DROPOUT_KEY_SALT = 0xD120
 
-        def pool(y):
-            flat = y.reshape((-1,) + y.shape[2:])
-            flat = nn.max_pool(nn.relu(flat), (2, 2), strides=(2, 2))
-            return flat.reshape((k, -1) + flat.shape[1:])
 
-        x = PConv(32, 5, impl=self.packed_impl)(x)
-        x = pool(x)
-        x = PConv(64, 5, impl=self.packed_impl)(x)
-        x = pool(x)
-        x = x.reshape(x.shape[:2] + (-1,))
-        x = nn.relu(PDense(512)(x))
-        return PDense(self.output_dim)(x)
+def seed_dropout(x, key, rate: float, layer: int, deterministic: bool):
+    """Explicit-key dropout: the masks of a step derive from the step's
+    batch key alone, so a packed lane replays its client's masks
+    bit-for-bit from that key (flax's ``nn.Dropout`` derives its key from
+    internal module-path folding). ``layer`` is the call site's static
+    index within the model; ``key`` is the step's batch key (models
+    receive it as ``dropout_rng``; see ModelBundle.explicit_dropout)."""
+    if deterministic or rate <= 0.0:
+        return x
+    if key is None:
+        # same contract as flax's missing-rng error: a train-mode apply
+        # without a key must fail loudly, not silently skip regularization
+        raise ValueError(
+            "seed_dropout: train-mode apply without a dropout key — pass "
+            "dropout_rng (ModelBundle.explicit_dropout threads it)")
+    k = jax.random.fold_in(key, DROPOUT_KEY_SALT + layer)
+    keep = jax.random.bernoulli(k, 1.0 - rate, x.shape)
+    return jnp.where(keep, x / (1.0 - rate), jnp.zeros_like(x))
 
 
 class CNNDropOut(nn.Module):
     """Dropout masks derive from an EXPLICIT key (`dropout_rng`, the step's
-    batch key) via ops/packed_conv.seed_dropout instead of a flax rng
-    stream, so the packed lane-major twin replays each lane's masks
-    bit-for-bit from that lane's own key (ModelBundle.explicit_dropout)."""
+    batch key) via :func:`seed_dropout` instead of a flax rng stream
+    (ModelBundle.explicit_dropout)."""
 
     output_dim: int = 62
-    conv_impl: str = "xla"   # "packed": fedpack lane-major body
-    packed_impl: Any = "blockdiag"  # name or per-stage LoweringPlan
 
     @nn.compact
     def __call__(self, x, train: bool = False, dropout_rng=None):
-        from fedml_tpu.ops.packed_conv import seed_dropout
-
-        if self.conv_impl == "packed":
-            return self._call_packed(x, train, dropout_rng)
         if x.ndim == 2:
             x = x.reshape((x.shape[0], 28, 28, 1))
         x = nn.relu(nn.Conv(32, (3, 3), padding="VALID")(x))
@@ -95,69 +83,22 @@ class CNNDropOut(nn.Module):
         x = seed_dropout(x, dropout_rng, 0.5, 1, not train)
         return nn.Dense(self.output_dim)(x)
 
-    def _call_packed(self, x, train: bool, dropout_rng):
-        """fedpack body (x [K, N, 28, 28, 1] or [K, N, 784] lane-major;
-        dropout_rng the [K] vector of per-lane batch keys): same submodule
-        call order as the per-client body, so the parameter tree is the
-        standard tree with a leading K axis; lane l's dropout masks are
-        bit-identical to the per-client body's under dropout_rng[l]."""
-        from fedml_tpu.ops.packed_conv import Conv as PConv
-        from fedml_tpu.ops.packed_conv import Dense as PDense
-        from fedml_tpu.ops.packed_conv import lane_dropout
-
-        if x.ndim == 3:  # [K, N, 784] -> [K, N, 28, 28, 1]
-            x = x.reshape(x.shape[:2] + (28, 28, 1))
-        k = x.shape[0]
-
-        def pool(y):
-            flat = y.reshape((-1,) + y.shape[2:])
-            flat = nn.max_pool(flat, (2, 2), strides=(2, 2))
-            return flat.reshape((k, -1) + flat.shape[1:])
-
-        x = nn.relu(PConv(32, 3, padding="VALID", impl=self.packed_impl)(x))
-        x = nn.relu(PConv(64, 3, padding="VALID", impl=self.packed_impl)(x))
-        x = pool(x)
-        x = lane_dropout(x, dropout_rng, 0.25, 0, not train)
-        x = x.reshape(x.shape[:2] + (-1,))
-        x = nn.relu(PDense(128)(x))
-        x = lane_dropout(x, dropout_rng, 0.5, 1, not train)
-        return PDense(self.output_dim)(x)
-
 
 @register_model("cnn")
 def _cnn(output_dim: int, **_):
-    bundle = ModelBundle(
+    return ModelBundle(
         name="cnn",
         module=CNNOriginalFedAvg(output_dim),
         input_shape=(28, 28, 1),
     )
-    # fedpack hook (ops/packed_conv.py): train-only lane-major twin for the
-    # packed schedule's joint-lane program (--packed_conv)
-    bundle.packed_variant = lambda impl: ModelBundle(
-        name="cnn_packed",
-        module=CNNOriginalFedAvg(output_dim, conv_impl="packed",
-                                 packed_impl=impl),
-        input_shape=(28, 28, 1),
-    )
-    return bundle
 
 
 @register_model("cnn_dropout")
 def _cnn_dropout(output_dim: int, **_):
-    bundle = ModelBundle(
+    return ModelBundle(
         name="cnn_dropout",
         module=CNNDropOut(output_dim),
         input_shape=(28, 28, 1),
         uses_dropout=True,
         explicit_dropout=True,
     )
-    # fedpack hook: explicit_dropout marks the twin's per-lane key stream,
-    # which is what clears packed_fallback_reason's dropout gate
-    bundle.packed_variant = lambda impl: ModelBundle(
-        name="cnn_dropout_packed",
-        module=CNNDropOut(output_dim, conv_impl="packed", packed_impl=impl),
-        input_shape=(28, 28, 1),
-        uses_dropout=True,
-        explicit_dropout=True,
-    )
-    return bundle

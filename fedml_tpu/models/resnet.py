@@ -28,12 +28,7 @@ class BasicBlock(nn.Module):
     use_norm: bool = True  # False: perf-experiment variant without BN
     bn_impl: str = "xla"   # "pallas": fused stats+normalize(+relu) kernel
     conv_impl: str = "xla"  # "lanes": spatial-in-lanes Pallas conv
-    #                         (ops/conv_lanes.py); "packed": fedpack client-
-    #                         packed convs on lane-major [K,N,H,W,C] input
-    #                         (ops/packed_conv.py)
-    packed_impl: Any = "blockdiag"  # packed lowering name (blockdiag |
-    #                                 grouped) or a per-stage fedplan
-    #                                 LoweringPlan (obs/plan.py)
+    #                         (ops/conv_lanes.py)
     hw: tuple = (0, 0)      # static input (H, W) — lanes layout only
 
     def _norms(self, train: bool, axis: int = -1):
@@ -61,8 +56,6 @@ class BasicBlock(nn.Module):
     def __call__(self, x, train: bool = False):
         if self.conv_impl == "lanes":
             return self._call_lanes(x, train)
-        if self.conv_impl == "packed":
-            return self._call_packed(x, train)
         conv = partial(nn.Conv, use_bias=False, dtype=self.dtype)
         norm = self._norms(train)
         residual = x
@@ -95,31 +88,6 @@ class BasicBlock(nn.Module):
             residual = norm()(residual)
         return nn.relu(y + residual)
 
-    def _call_packed(self, x, train: bool):
-        """fedpack body (x [K, N, H, W, C], lane-major): same submodule
-        call order as the NHWC body — the packed classes are named 'Conv'/
-        'BatchNorm' — so the parameter pytree is the standard tree with a
-        leading K (lane) axis on every leaf (ops/packed_conv contract)."""
-        from fedml_tpu.ops.packed_conv import BatchNorm as PBatchNorm
-        from fedml_tpu.ops.packed_conv import Conv as PConv
-
-        conv = partial(PConv, use_bias=False, impl=self.packed_impl,
-                       dtype=self.dtype)
-        if self.use_norm:
-            norm = lambda: PBatchNorm(use_running_average=not train,
-                                      momentum=0.9, dtype=self.dtype)
-        else:
-            norm = lambda: (lambda y: y)
-        residual = x
-        y = conv(self.filters, 3, self.strides)(x)
-        y = nn.relu(norm()(y))
-        y = conv(self.filters, 3)(y)
-        y = norm()(y)
-        if residual.shape != y.shape:
-            residual = conv(self.filters, 1, self.strides)(x)
-            residual = norm()(residual)
-        return nn.relu(y + residual)
-
 
 class CifarResNet(nn.Module):
     """depth = 6n+2; blocks_per_stage = n.
@@ -136,15 +104,10 @@ class CifarResNet(nn.Module):
     use_norm: bool = True
     bn_impl: str = "xla"
     conv_impl: str = "xla"  # "lanes": Pallas spatial-in-lanes convs for the
-    #                         C<=32 stages (docs/mfu_experiments.md H6);
-    #                         "packed": fedpack client-packed convs over a
-    #                         leading lane axis (ops/packed_conv.py)
-    packed_impl: Any = "blockdiag"  # name or per-stage LoweringPlan
+    #                         C<=32 stages (docs/mfu_experiments.md H6)
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        if self.conv_impl == "packed":
-            return self._call_packed(x, train)
         x = x.astype(self.dtype)
         x = nn.Conv(self.widths[0], (3, 3), padding="SAME", use_bias=False, dtype=self.dtype)(x)
         if self.use_norm:
@@ -191,70 +154,28 @@ class CifarResNet(nn.Module):
         x = jnp.mean(x, axis=(1, 2))
         return nn.Dense(self.output_dim, dtype=jnp.float32)(x.astype(jnp.float32))
 
-    def _call_packed(self, x, train: bool):
-        """fedpack body: x [K, N, 32, 32, 3] lane-major; every stage runs
-        client-packed convs (at any K*C >= 128 the contraction keeps at
-        least one full MXU dimension). Submodule call order matches the
-        NHWC body, so the parameter tree is the standard tree + leading K."""
-        from fedml_tpu.ops.packed_conv import BatchNorm as PBatchNorm
-        from fedml_tpu.ops.packed_conv import Conv as PConv
-        from fedml_tpu.ops.packed_conv import Dense as PDense
-
-        x = x.astype(self.dtype)
-        x = PConv(self.widths[0], 3, use_bias=False, impl=self.packed_impl,
-                  dtype=self.dtype)(x)
-        if self.use_norm:
-            x = PBatchNorm(use_running_average=not train, momentum=0.9,
-                           dtype=self.dtype)(x)
-        x = nn.relu(x)
-        for stage, filters in enumerate(self.widths):
-            for block in range(self.blocks_per_stage):
-                strides = 2 if stage > 0 and block == 0 else 1
-                x = BasicBlock(filters, strides, dtype=self.dtype,
-                               use_norm=self.use_norm,
-                               conv_impl="packed",
-                               packed_impl=self.packed_impl)(x, train=train)
-        x = jnp.mean(x, axis=(2, 3))
-        return PDense(self.output_dim, dtype=jnp.float32)(
-            x.astype(jnp.float32))
-
 
 def _make(depth: int, output_dim: int, dtype=jnp.float32, bn_axis=None,
-          bn_impl="xla", conv_impl="xla",
-          packed_impl="blockdiag") -> CifarResNet:
+          bn_impl="xla", conv_impl="xla") -> CifarResNet:
     assert (depth - 2) % 6 == 0, "CIFAR ResNet depth must be 6n+2"
-    if conv_impl in ("lanes", "packed") and bn_impl == "pallas":
-        raise ValueError(f"conv_impl={conv_impl!r} uses XLA-lowered "
-                         "BatchNorm on its own layout; combine with "
-                         "bn_impl='xla'")
+    if conv_impl == "lanes" and bn_impl == "pallas":
+        raise ValueError("conv_impl='lanes' uses XLA-lowered BatchNorm on "
+                         "its own layout; combine with bn_impl='xla'")
     return CifarResNet((depth - 2) // 6, output_dim, dtype=dtype,
-                       bn_axis=bn_axis, bn_impl=bn_impl, conv_impl=conv_impl,
-                       packed_impl=packed_impl)
+                       bn_axis=bn_axis, bn_impl=bn_impl, conv_impl=conv_impl)
 
 
 def _register_resnet(name: str, depth: int):
     @register_model(name)
     def _factory(output_dim: int, dtype=jnp.float32, bn_axis=None,
-                 bn_impl="xla", conv_impl="xla", packed_impl="blockdiag", **_):
-        bundle = ModelBundle(
+                 bn_impl="xla", conv_impl="xla", **_):
+        return ModelBundle(
             name=name,
             module=_make(depth, output_dim, dtype, bn_axis, bn_impl,
-                         conv_impl, packed_impl),
+                         conv_impl),
             input_shape=(32, 32, 3),
             has_batch_stats=True,
         )
-        if conv_impl == "xla" and bn_impl == "xla" and bn_axis is None:
-            # fedpack hook: the packed schedule's joint-lane program swaps
-            # in this train-only twin (lane-major input, stacked params —
-            # ops/packed_conv.py) when --packed_conv is on
-            bundle.packed_variant = lambda impl: ModelBundle(
-                name=f"{name}_packed",
-                module=_make(depth, output_dim, dtype, None, "xla",
-                             "packed", impl),
-                input_shape=(32, 32, 3),
-                has_batch_stats=True,
-            )
-        return bundle
     return _factory
 
 

@@ -157,13 +157,10 @@ def timed_build(name: str, shape_key, builder: Callable) -> Callable:
             _cost.attribute_program(name, shape_key, fn, args)
         return out
 
-    # packed programs carry fedcost packing hints as `.cost_hints` and
-    # their lane geometry as `.lane_ids`; keep such sidecar attributes
+    # packed programs carry their lane geometry as `.lane_ids`; keep it
     # reachable
-    for attr in ("cost_hints", "lane_ids"):
-        val = getattr(fn, attr, None)
-        if val is not None:
-            setattr(step, attr, val)
+    if ids:
+        step.lane_ids = ids
     return step
 
 

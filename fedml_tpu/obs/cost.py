@@ -48,12 +48,6 @@ from typing import Optional
 #: the output-channel dim and the reduction dim fill this many lanes.
 MXU_LANES = 128
 
-#: stage-FLOPs fraction below which a stage is "dominated": too small to
-#: matter for lowering/lane-count decisions (obs/plan.py flags rather than
-#: lets a tiny 1x1 shortcut conv flip a plan). Shared with summarize()'s
-#: per-stage ``dominated`` flag and ``dominated_frac`` total.
-DOMINATED_FRAC = 0.01
-
 #: bf16 peak FLOP/s keyed by the EXACT ``device_kind`` string jax reports,
 #: with the source of each figure. Only kinds this repo has run on are
 #: listed: a TPU that is not here is an error (:func:`peak_flops`), never a
@@ -487,52 +481,10 @@ def op_table(hlo_text: str) -> tuple[list[dict], bool]:
                 "out_lane_fill": _lane_fill(row["n"]),
                 "red_lane_fill": _lane_fill(row["k"]),
             })
-            # fedpack columns: packing_factor = co-scheduled clients folded
-            # into this op; useful_flops = FLOPs doing real per-client
-            # work. Defaults (1, = flops) — whether an op folds clients is
-            # program-level knowledge, filled in by apply_packing() from
-            # the builder's out-of-band hint (the pre-optimization HLO text
-            # prints no name-stack metadata — checked under jax 0.9.0 — so
-            # ops carry no marker to parse).
-            row["packing_factor"] = 1
-            row["useful_flops"] = row["flops"]
             row["intensity"] = (row["flops"] / row["bytes"]
                                 if row["bytes"] else 0.0)
             ops.append(row)
     return ops, unknown
-
-
-def apply_packing(ops: list[dict], factor: int,
-                  impl: str = "blockdiag") -> list[dict]:
-    """Fill a client-packed program's packing columns (in place), given the
-    builder's hint that ``factor`` clients are folded per op.
-
-    - Grouped convs with ``groups == factor`` are the K-client folding
-      (the per-lane vmap's H4 lowering, or ops/packed_conv.conv_grouped);
-      their analytic FLOPs are already useful-only, so only the factor is
-      recorded. Patch-extraction/depthwise shapes (per-group N of 1, or
-      N == K — identity-kernel im2col machinery) are excluded.
-    - With ``impl == 'blockdiag'``, unbatched dots whose output AND
-      reduction dims are both multiples of ``factor`` are the block GEMMs
-      (ops/packed_conv.conv_blockdiag) — fwd (N = K*Co), dgrad (N = K*R)
-      and wgrad (N = K*Co) all qualify — streaming ``factor`` x the useful
-      FLOPs as structural zeros: ``useful_flops`` divides accordingly.
-
-    Hint-scoped by design: it only runs on programs whose builder attached
-    ``cost_hints``, never on arbitrary HLO.
-    """
-    if not factor or factor <= 1:
-        return ops
-    for o in ops:
-        if (o["kind"] == "conv" and o["groups"] == factor
-                and o["n"] > 1 and o["n"] != o["k"]):
-            o["packing_factor"] = int(factor)
-        elif (impl == "blockdiag" and o["kind"] == "dot"
-                and o.get("b", 1) == 1
-                and o["n"] % factor == 0 and o["k"] % factor == 0):
-            o["packing_factor"] = int(factor)
-            o["useful_flops"] = o["flops"] / factor
-    return ops
 
 
 def summarize(ops: list[dict], unknown_trip_counts: bool = False,
@@ -544,11 +496,8 @@ def summarize(ops: list[dict], unknown_trip_counts: bool = False,
     total = sum(o["flops"] * o["count"] for o in ops)
     if total <= 0:
         return {"gemm_ops": 0, "gemm_flops_per_invocation": 0.0,
-                "useful_flops_per_invocation": 0.0,
                 "out_lane_ceiling": None, "red_lane_ceiling": None,
-                "packing": None,
-                "by_output_channels": {}, "dominated_frac": 0.0,
-                "top_ops": [],
+                "by_output_channels": {}, "top_ops": [],
                 "unknown_trip_counts": unknown_trip_counts}
     out_ceiling = sum(o["flops"] * o["count"] * o["out_lane_fill"]
                       for o in ops) / total
@@ -557,34 +506,18 @@ def summarize(ops: list[dict], unknown_trip_counts: bool = False,
     by_n: dict[int, float] = {}
     for o in ops:
         by_n[o["n"]] = by_n.get(o["n"], 0.0) + o["flops"] * o["count"]
-    # a stage whose FLOPs are < DOMINATED_FRAC of the program is flagged
-    # dominated: the planner/report must not let it steer a decision
     stage = {
         str(n): {"out_lane_fill": _lane_fill(n),
-                 "flops_frac": round(f / total, 4),
-                 "dominated": f / total < DOMINATED_FRAC}
+                 "flops_frac": round(f / total, 4)}
         for n, f in sorted(by_n.items())
     }
-    dominated_frac = sum(f for f in by_n.values()
-                         if f / total < DOMINATED_FRAC) / total
     top = sorted(ops, key=lambda o: -o["flops"] * o["count"])[:top_k]
-    # fedpack accounting: streamed vs useful FLOPs. `.get` defaults keep
-    # hand-built op rows (tests, older callers) working unchanged.
-    useful = sum(o.get("useful_flops", o["flops"]) * o["count"] for o in ops)
-    max_factor = max((o.get("packing_factor", 1) for o in ops), default=1)
-    packing = None
-    if max_factor > 1:
-        packing = {"max_factor": int(max_factor),
-                   "useful_flops_frac": round(useful / total, 4)}
     return {
         "gemm_ops": len(ops),
         "gemm_flops_per_invocation": total,
-        "useful_flops_per_invocation": useful,
         "out_lane_ceiling": round(out_ceiling, 4),
         "red_lane_ceiling": round(red_ceiling, 4),
-        "packing": packing,
         "by_output_channels": stage,
-        "dominated_frac": round(dominated_frac, 4),
         "top_ops": [
             {k: (round(v, 4) if isinstance(v, float) else v)
              for k, v in o.items() if k != "intensity"}
@@ -643,17 +576,6 @@ def roofline(summary: dict, measured_s: float, invocations: float = 1.0,
     ceiling = summary.get("out_lane_ceiling")
     if peak and ceiling:
         out["mfu_vs_ceiling"] = round((achieved / peak) / ceiling, 4)
-    # fedpack honesty: when the program streams structural zeros (block-
-    # diagonal packing), also report the USEFUL-work rates — the number
-    # comparable across lowerings (streamed MFU flatters a packed program
-    # by exactly its packing factor)
-    useful = summary.get("useful_flops_per_invocation")
-    if useful is not None and useful < flops / max(invocations, 1e-12):
-        u = useful * invocations
-        ach_u = u / measured_s if measured_s > 0 else 0.0
-        out["useful_gflops_per_sec"] = round(ach_u / 1e9, 2)
-        if peak:
-            out["mfu_mac_useful"] = round(ach_u / peak, 4)
     return out
 
 
@@ -682,44 +604,6 @@ def cost_attribution_enabled() -> bool:
 
 
 _NO_ATTR = object()
-
-
-def _plan_self_check(name: str, plan, summary: dict) -> Optional[dict]:
-    """Post-first-call fedplan self-check: compare the realized program's
-    streamed-basis lane ceiling against the plan's parsed-basis prediction
-    and WARN (log + 'plan' registry counter) on divergence above the
-    plan's tolerance — a planner bug should be loud, not silent. The
-    realized program carries ops the per-stage micro-programs don't (dense
-    head, loss, optimizer), so the tolerance is deliberately loose."""
-    predicted = getattr(plan, "predicted_static_ceiling", None)
-    realized = summary.get("out_lane_ceiling")
-    if predicted is None or realized is None:
-        return None
-    tol = float(getattr(plan, "self_check_tol", 0.15))
-    delta = float(realized) - float(predicted)
-    ok = abs(delta) <= tol
-    if not ok:
-        import logging
-
-        logging.getLogger("fedml_tpu.cost").warning(
-            "fedplan self-check: program %r realized static lane ceiling "
-            "%.3f diverges from the plan's prediction %.3f by %+.3f "
-            "(tolerance %.3f) — the planner scored stages the program "
-            "does not run, or the lowering changed under it",
-            name, realized, predicted, delta, tol)
-        try:
-            # the plan module owns the long-lived 'plan' registry group
-            # (registry groups are weakref'd — a fresh group here would
-            # die, and its counter with it, before any snapshot)
-            from fedml_tpu.obs.plan import _plan_group
-
-            g = _plan_group()
-            g["self_check_warn"] = g.get("self_check_warn", 0) + 1
-        except Exception:
-            pass
-    return {"predicted_static_ceiling": float(predicted),
-            "realized_static_ceiling": float(realized),
-            "delta": round(delta, 4), "tolerance": tol, "ok": ok}
 
 
 def configure_from(config) -> bool:
@@ -765,35 +649,14 @@ def attribute_program(name: str, shape_key, fn, args) -> Optional[dict]:
         rep = analyze_jitted(fn, args)
         if rep is None:
             return None
-        # fedpack hint (ops/packed_conv.py): programs whose builder marked
-        # them as client-packed get their block-diag dots' packing_factor /
-        # useful-FLOP columns filled in and the summary recomputed. A
-        # plan-steered ("auto") program carries its LoweringPlan in the
-        # hints; its blockdiag stages' dots need the useful-FLOP division
-        # whenever ANY stage uses the block GEMM (plan.hint_impl).
-        hints = getattr(fn, "cost_hints", None)
-        plan = (hints or {}).get("plan")
-        if hints and hints.get("packing_factor", 1) > 1:
-            impl = hints.get("packed_conv", "blockdiag")
-            if plan is not None:
-                impl = getattr(plan, "hint_impl", impl)
-            apply_packing(rep["ops"], int(hints["packing_factor"]), impl)
-            rep["summary"] = summarize(
-                rep["ops"], rep["summary"]["unknown_trip_counts"])
         record = {
             "program": name,
             "shape_key": repr(shape_key),
             "path": PROGRAM_PATHS.get(name),
-            "packed_conv": (hints or {}).get("packed_conv"),
             "summary": rep["summary"],
             "xla_cost": rep["xla_cost"],
             "ops": rep["ops"],
         }
-        if plan is not None:
-            record["plan"] = plan.to_dict() if hasattr(plan, "to_dict") \
-                else plan
-            record["plan_self_check"] = _plan_self_check(
-                name, plan, rep["summary"])
         with _lock:
             _TABLES[name] = record
         from fedml_tpu.obs.tracer import tracer_if_enabled
@@ -812,12 +675,6 @@ def attribute_program(name: str, shape_key, fn, args) -> Optional[dict]:
                 "peak_bf16_flops": peak,
                 "peak_table_entry": entry,
             })
-            if plan is not None:
-                tr.instant("program_plan", cat="cost", args={
-                    "program": name,
-                    "plan": record.get("plan"),
-                    "self_check": record.get("plan_self_check"),
-                })
         return record
     except UnknownDeviceKind:
         raise

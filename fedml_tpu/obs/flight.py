@@ -65,7 +65,7 @@ Bundle layout (``incident-<id>/``)::
                         all — when ``--lens on`` armed the run)
     pulse-tail.jsonl    the raw recent pulse snapshots (fedtop shape)
     watchdog.json       the structured watchdog.incident() view
-    cost.json/plan.json fedcost tables / fedplan decisions, when present
+    cost.json           fedcost tables, when present
 
 Contracts (the tracer's discipline, restated):
 
@@ -299,7 +299,7 @@ class FlightRecorder:
                          wd or {"rule": rule, "round": round_idx,
                                 "detail": reason})
 
-        # fedcost / fedplan context, when those planes ran this process
+        # fedcost context, when that plane ran this process
         try:
             from fedml_tpu.obs import cost as _cost
 
@@ -308,14 +308,6 @@ class FlightRecorder:
                 safe = {k: v for k, v in tables.items() if _jsonable(v)}
                 if safe:
                     self._write_json(os.path.join(ddir, "cost.json"), safe)
-        except Exception:
-            pass
-        try:
-            from fedml_tpu.obs import plan as _plan
-
-            st = _plan.cache_stats()
-            if st.get("hits") or st.get("misses"):
-                self._write_json(os.path.join(ddir, "plan.json"), st)
         except Exception:
             pass
 

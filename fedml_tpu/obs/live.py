@@ -67,10 +67,8 @@ __all__ = [
     "reset", "session_stats",
 ]
 
-#: registry namespaces exported as pulse "lanes" every snapshot ("packed"
-#: carries the fedpack fallback counters, parallel/packed.py; "plan" the
-#: fedplan cache/self-check counters, obs/plan.py)
-_LANES = ("time", "wire", "chaos", "compile", "packed", "plan")
+#: registry namespaces exported as pulse "lanes" every snapshot
+_LANES = ("time", "wire", "chaos", "compile")
 
 #: process-lifetime stats for the conftest session summary (NEVER reset by
 #: configure()/reset() — they describe the session, not one run).

@@ -871,10 +871,7 @@ def flush_all(trace_dir: Optional[str] = None) -> list[str]:
 def reset() -> None:
     """Drop all tracers and disable tracing (tests; never mid-run). Also
     tears down the fedpulse plane — a plane leaked across tests would feed
-    every later run_round in the process — and the packed-schedule
-    fallback accounting (warn-once set + "packed" registry counter lane),
-    so a second federation in one process warns and counts afresh instead
-    of inheriting the first's suppression."""
+    every later run_round in the process."""
     global _ENABLED, _TRACE_DIR, _TRACE_ID, _PROCESS
     global _SAMPLE_RATE, _SAMPLE_SEED
     with _lock:
@@ -892,8 +889,3 @@ def reset() -> None:
     _live.reset()
     _flight.reset()
     _lens.reset()
-    import sys
-
-    packed = sys.modules.get("fedml_tpu.parallel.packed")
-    if packed is not None:   # only if already imported — never import here
-        packed.reset_fallback_warnings()

@@ -28,13 +28,11 @@ TPU-ism (SURVEY.md §7 hard part (a)) and packing is the TPU-native answer.
 
 from __future__ import annotations
 
-import logging
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from fedml_tpu.core.pytree import map_chunks
@@ -42,13 +40,9 @@ from fedml_tpu.core.tasks import Task
 from fedml_tpu.models import ModelBundle
 from fedml_tpu.obs.tracer import (SCOPE_AGGREGATE, SCOPE_PROLOGUE, SCOPE_STEP,
                                   SCOPE_STEP_EMIT, SCOPE_STEP_GATHER,
-                                  SCOPE_STEP_OPT, SCOPE_STEP_RESET,
-                                  SCOPE_STEP_TRAIN)
+                                  SCOPE_STEP_RESET)
 from fedml_tpu.parallel.local import (EPOCH_KEY_SALT as _EPOCH_KEY_SALT,
                                       make_batch_sgd_step, make_optimizer)
-
-log = logging.getLogger(__name__)
-
 
 class PackPlan(NamedTuple):
     """Static lane schedule for one cohort. Shapes (n_lanes, k_max, T) are
@@ -207,9 +201,7 @@ def _member_replay_tables(mask_rows, epochs: int, n_pad: int,
     """The canonical per-member replay tables — EXACTLY
     make_local_train_fn's per-epoch ``permutation`` over the global n_pad,
     real-first stable sort, and ``fold_in(ekey, EPOCH_KEY_SALT)`` batch
-    keys. ONE definition shared by the vmapped lane form and the fedpack
-    joint form, so the bit-exact replay contract cannot drift between the
-    two lowerings. Returns ``member_tables(key, row) -> (orders [E,n_pad],
+    keys. Returns ``member_tables(key, row) -> (orders [E,n_pad],
     bkeys [E,steps_full])``; vmap it over members (and lanes)."""
 
     def member_tables(key, row):
@@ -503,124 +495,6 @@ def make_lane_train(
     return lane_train
 
 
-# --- fedpack: the joint (stacked-lane) execution form -----------------------
-
-# Fallback bookkeeping: warn-once keys plus a registry counter lane
-# ("packed" namespace) so pulse snapshots and trace_report surface WHICH
-# programs fell back, not just a one-shot process log line. State is
-# process-scoped but resettable: obs.reset() (the per-federation teardown
-# tests already call between runs) clears both, so a second federation in
-# one process re-warns and counts from zero instead of inheriting the
-# first federation's suppression.
-_FALLBACK_STATE: dict = {"seen": set(), "group": None}
-
-
-def _fallback_group():
-    g = _FALLBACK_STATE["group"]
-    if g is None:
-        from fedml_tpu.obs import default_registry
-
-        g = _FALLBACK_STATE["group"] = default_registry().group("packed")
-    return g
-
-
-def reset_fallback_warnings() -> None:
-    """Clear the warn-once set and drop the registry counter group (called
-    by obs.reset so fallback accounting is per-federation in tests/tools
-    that reset the plane between runs)."""
-    _FALLBACK_STATE["seen"].clear()
-    _FALLBACK_STATE["group"] = None
-
-
-def impl_label(packed_conv) -> str:
-    """Short string form of a lowering selector for counter keys, log
-    lines and cost hints: the flag string itself, or 'auto' for a fedplan
-    :class:`~fedml_tpu.obs.plan.LoweringPlan` (its per-stage detail rides
-    ``cost_hints['plan']``, not the label)."""
-    return packed_conv if isinstance(packed_conv, str) else "auto"
-
-
-def resolve_packed_conv(packed_conv, bundle: ModelBundle, n_lanes: int,
-                        dtype=None, optimizer: str = "sgd"):
-    """Resolve the ``--packed_conv`` flag to what the builders consume at
-    program-build time: concrete flags pass through; ``'auto'`` becomes
-    the fedplan :class:`~fedml_tpu.obs.plan.LoweringPlan` for this bundle
-    at the schedule's ACTUAL lane count — or ``'off'`` (with the
-    documented :func:`packed_fallback_reason` warning downstream) when the
-    joint form cannot apply (no packed twin, flax-rng dropout, or a
-    single-lane schedule with nothing to co-schedule)."""
-    if packed_conv != "auto":
-        return packed_conv
-    if n_lanes < 2 or packed_fallback_reason(
-            bundle, "auto", optimizer) is not None:
-        return "off"
-    from fedml_tpu.obs.plan import plan_lowering
-
-    return plan_lowering(bundle, int(n_lanes), dtype=dtype)
-
-
-def packed_fallback_reason(bundle: ModelBundle, packed_conv,
-                           optimizer: str = "sgd") -> Optional[str]:
-    """Why the joint form does NOT apply (None = it does). After the
-    packed-everywhere refactor the only remaining reasons are genuinely
-    unpackable shapes — the DESIGN.md §15 exception table:
-
-    - ``packed_conv=off`` (the flag, not a capability gap);
-    - the model family ships no lane-major packed twin
-      (``packed_variant is None`` — mixed per-lane architectures, rnn/
-      transformer/moe);
-    - the model uses flax-rng dropout and its packed twin does not opt in
-      to the explicit per-lane key stream (``explicit_dropout``).
-
-    Client optimizer choice no longer disqualifies: optimizer state is
-    held per-lane (``[L]``-leading leaves via a vmapped optax init/update),
-    so adam's scalar step count and friends reset and freeze per lane like
-    any other leaf. ``optimizer`` stays in the signature for call-site
-    symmetry and future optimizers with genuinely unliftable state."""
-    del optimizer
-    if packed_conv in (None, "", "off"):
-        return "packed_conv=off"
-    if bundle.packed_variant is None:
-        return f"model {bundle.name!r} has no packed conv variant"
-    if bundle.uses_dropout:
-        pb = bundle.packed_variant(packed_conv)
-        if not getattr(pb, "explicit_dropout", False):
-            return (f"model {bundle.name!r} uses flax-rng dropout and its "
-                    "packed twin has no explicit per-lane key stream")
-    return None
-
-
-def _packed_model_bundle(bundle: ModelBundle, packed_conv: str,
-                         optimizer: str) -> Optional[ModelBundle]:
-    """Resolve the fedpack joint-lane lowering: the packed twin bundle, or
-    None when the per-lane vmap must stay (:func:`packed_fallback_reason`).
-    A real fallback (flag ON but joint form inapplicable) is warned once
-    per (model, lowering) and counted in the "packed" registry lane."""
-    reason = packed_fallback_reason(bundle, packed_conv, optimizer)
-    if reason is not None:
-        if packed_conv not in (None, "", "off"):
-            label = impl_label(packed_conv)
-            g = _fallback_group()
-            ck = f"fallback:{bundle.name}:{label}"
-            g[ck] = g.get(ck, 0) + 1
-            key = (bundle.name, label, reason)
-            if key not in _FALLBACK_STATE["seen"]:
-                _FALLBACK_STATE["seen"].add(key)
-                log.warning(
-                    "packed_conv=%r falls back to the per-lane vmap: %s",
-                    label, reason)
-        return None
-    return bundle.packed_variant(packed_conv)
-
-
-def packed_conv_active(bundle: ModelBundle, packed_conv: str,
-                       optimizer: str = "sgd") -> bool:
-    """Whether :func:`make_lanes_train` will use the fedpack joint form for
-    this (bundle, flag, optimizer) — callers use it to attach fedcost
-    packing hints only to programs that really carry the packed GEMMs."""
-    return packed_fallback_reason(bundle, packed_conv, optimizer) is None
-
-
 #: Lanes vmapped together. The plan's lane count says how many lanes a
 #: round HAS; how many advance in one grouped convolution is the chip's
 #: choice: on the v5e the per-lane form executes 31,215 img/s at a vmap
@@ -651,27 +525,16 @@ def make_lanes_train(
     bundle: ModelBundle,
     task: Task,
     n_pad: int,
-    *,
-    packed_conv: str = "off",
     **lane_kwargs,
 ) -> Callable:
-    """The all-lanes program both packed round builders share: by default
-    ``vmap`` of :func:`make_lane_train` over the lane axis (XLA lowers the
+    """The all-lanes program both packed round builders share: ``vmap``
+    of :func:`make_lane_train` over the lane axis (XLA lowers the
     batched-kernel convs to a grouped conv, docs/mfu_experiments.md H4),
     :func:`lane_vmap_width` lanes at a time, the chunks one after another
     in one ``lax.map``, each as far as its own last live step
     (:func:`chunk_bounds`), and ONE lane with no lane axis at all, which
     alone can branch at its client boundaries (``lane_train``'s ``branch``:
-    chosen here, from the lanes' count); with ``packed_conv`` on and a
-    capable model, the
-    fedpack JOINT form (:func:`make_packed_lanes_train`) whose convs are
-    ONE block-diagonal/grouped contraction across lanes
-    (ops/packed_conv.py). Same signature and stacked-accumulator return
-    either way."""
-    pb = _packed_model_bundle(bundle, packed_conv,
-                              lane_kwargs.get("optimizer", "sgd"))
-    if pb is not None:
-        return make_packed_lanes_train(bundle, pb, task, n_pad, **lane_kwargs)
+    chosen here, from the lanes' count)."""
     lane_train = make_lane_train(bundle, task, n_pad, **lane_kwargs)
     vmapped = jax.vmap(lane_train, in_axes=(None,) * 5 + (0,) * 10 + (None,))
     unroll = lane_kwargs.get("scan_unroll", 1)
@@ -708,271 +571,6 @@ def make_lanes_train(
     return lanes_train
 
 
-def make_packed_lanes_train(
-    bundle: ModelBundle,
-    packed_bundle: ModelBundle,
-    task: Task,
-    n_pad: int,
-    *,
-    optimizer: str = "sgd",
-    lr: float = 0.01,
-    momentum: float = 0.0,
-    wd: float = 0.0,
-    epochs: int = 1,
-    batch_size: int = 32,
-    grad_clip: Optional[float] = None,
-    prox_mu: float = 0.0,
-    compute_dtype=None,
-    scan_unroll: int = 1,
-    client_transform: Optional[Callable] = None,
-    reduce_extras: Optional[Callable] = None,
-    lens: bool = False,
-) -> Callable:
-    """The fedpack JOINT form of ``vmap(lane_train)``: all lanes advance
-    through ONE scan whose per-step model apply sees the stacked lane axis
-    explicitly, so every conv lowers as one client-packed contraction
-    (``packed_bundle``, ops/packed_conv.py) instead of K per-lane
-    partial-lane GEMMs. Everything per-lane — replay tables, reset/freeze
-    masks, weighted accumulation, grad clipping, OPTIMIZER STATE — is
-    computed with an explicit [L] lane vector exactly as the vmap form
-    computes it per lane, so the two forms agree up to GEMM summation
-    order (pinned by tests/test_packed_conv.py and the per-paradigm pins
-    in tests/test_packed_everywhere.py).
-
-    Optimizer state is stacked per lane: ``vmap(tx.init)`` over the
-    stacked params gives every optax leaf — including adam/amsgrad's
-    scalar step count and adagrad/yogi accumulators — a leading ``[L]``
-    axis, and ``vmap(tx.update)`` keeps the update per-lane, so the
-    reset-at-client-boundary and dead-step-freeze masks address ALL state
-    uniformly. This is what lets every client optimizer the reference
-    library ships ride the packed convs instead of forcing the vmap
-    fallback.
-
-    Dropout models ride via the explicit per-lane key stream: the packed
-    twin opts in with ``explicit_dropout`` (ops/packed_conv.seed_dropout /
-    lane_dropout) and the joint form hands the model apply the whole
-    ``[L]`` vector of this step's member batch keys — lane ``l``'s mask
-    derives from exactly the key the vmap form's lane ``l`` consumes, so
-    the two lowerings draw bit-identical masks per lane.
-
-    Same call signature as the vmapped lane program (variables unstacked;
-    member/plan arrays carrying the leading lane axis) and the same stacked
-    returns, except ``acc_extras`` comes back with a singleton leading axis:
-    the hooks' stacked-clients contract already sums over the lane axis
-    inside one call, and the callers' ``sum(axis=0)`` tail must stay a
-    no-op rather than a reduction over a parameter axis.
-    """
-    del compute_dtype  # callers pre-cast the stacked arrays once
-    from fedml_tpu.ops.packed_conv import stack_variables
-    from fedml_tpu.parallel.local import LocalResult
-
-    tx_opt = make_optimizer(optimizer, lr, momentum, wd)
-    steps_full = n_pad // batch_size
-    bs = batch_size
-    pb = packed_bundle
-
-    def bcast(vec, leaf):
-        """[L] lane vector -> broadcastable against a stacked leaf."""
-        return vec.reshape(vec.shape + (1,) * (leaf.ndim - 1))
-
-    def lanes_train(variables0, x_flat, y_flat, m_flat, mask_rows,
-                    member_row, member_keys, member_w, steps_real,
-                    slot, epoch_a, sie, reset, emit, live):
-        with jax.named_scope(SCOPE_PROLOGUE):
-            L = slot.shape[0]
-            stack0 = stack_variables(variables0, L)
-            sparams0 = stack0["params"]
-            # per-LANE optimizer state: vmap(init) gives every optax leaf a
-            # leading [L] axis (adam's scalar count becomes [L]), so the
-            # reset/freeze masks below address adaptive state per lane
-            opt_state0 = jax.vmap(tx_opt.init)(sparams0)
-
-            # Exact replay of make_local_train_fn's per-epoch order and batch
-            # keys, per (lane, member) — the SAME shared definition the vmap
-            # form uses (_member_replay_tables), so the two lowerings cannot
-            # drift on the replay contract
-            member_tables = _member_replay_tables(mask_rows, epochs, n_pad,
-                                                  steps_full)
-            orders, bkeys = jax.vmap(jax.vmap(member_tables))(
-                member_keys, member_row)     # [L,k_max,E,n_pad], [L,k_max,E,S]
-
-        def batch_step_packed(svars, sopt, bx, by, bm, bkey_l):
-            """One joint minibatch step: per-lane losses summed so the grad
-            of the stacked params IS the per-lane grads (the block weight's
-            off-diagonal zeros are structural — ops/packed_conv)."""
-
-            with jax.named_scope(SCOPE_STEP_TRAIN):
-                def loss_fn(sp):
-                    vars_in = dict(svars)
-                    vars_in["params"] = sp
-                    # the FULL [L] key vector: explicit-dropout packed twins
-                    # draw lane l's mask from bkey_l[l] — the very key the
-                    # vmap form's lane l consumes (non-dropout twins ignore it)
-                    logits, new_vars = pb.apply_train(vars_in, bx, bkey_l)
-                    per_lane = jax.vmap(task.loss)(logits, by, bm)      # [L]
-                    if prox_mu:
-                        # per-LANE prox term, folded into per_lane so the
-                        # REPORTED loss matches the vmap form (whose batch_step
-                        # returns loss WITH prox); summing per-lane terms gives
-                        # the same total the grads need (== tree_dot(d, d))
-                        from fedml_tpu.core.pytree import tree_sub
-                        d = tree_sub(sp, sparams0)
-                        prox_l = sum(
-                            jnp.sum(jnp.square(g), axis=tuple(range(1, g.ndim)))
-                            for g in jax.tree.leaves(d))                # [L]
-                        per_lane = per_lane + 0.5 * prox_mu * prox_l
-                    return jnp.sum(per_lane), (new_vars, per_lane)
-
-                (_, (new_vars, per_lane)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(svars["params"])
-                if grad_clip:
-                    # per-LANE clip (lane == one client's step), the joint form
-                    # of the vmap path's per-lane optax.global_norm
-                    sq = [jnp.sum(jnp.square(g), axis=tuple(range(1, g.ndim)))
-                          for g in jax.tree.leaves(grads)]
-                    gnorm = jnp.sqrt(sum(sq))                            # [L]
-                    scale = jnp.minimum(
-                        1.0, grad_clip / jnp.maximum(gnorm, 1e-12))
-                    grads = jax.tree.map(
-                        lambda g: g * bcast(scale, g).astype(g.dtype), grads)
-            # per-lane update mirrors the per-lane init: adaptive moments,
-            # step counts and accumulators advance lane-by-lane exactly as
-            # the vmap form's per-lane tx.update does
-            with jax.named_scope(SCOPE_STEP_OPT):
-                updates, new_opt = jax.vmap(tx_opt.update)(
-                    grads, sopt, svars["params"])
-                out_vars = dict(new_vars)
-                out_vars["params"] = optax.apply_updates(
-                    svars["params"], updates)
-            return out_vars, new_opt, per_lane
-
-        def step_fn(carry, xs):
-            (svars, sopt, loss_acc, acc_vars, acc_w, acc_loss, acc_tau,
-             acc_extras) = carry[:8]
-            k, e, s, rs, em, lv = xs                    # each [L]
-            with jax.named_scope(SCOPE_STEP_RESET):
-                svars = jax.tree.map(
-                    lambda v, z: jnp.where(bcast(rs, v) > 0, z, v), svars, stack0)
-                sopt = jax.tree.map(
-                    lambda v, z: jnp.where(bcast(rs, v) > 0, z, v),
-                    sopt, opt_state0)
-                loss_acc = jnp.where(rs > 0, 0.0, loss_acc)
-                if lens:
-                    upd_stack, l_first, l_last, floss_acc = carry[8]
-                    floss_acc = jnp.where(rs > 0, 0.0, floss_acc)
-
-            with jax.named_scope(SCOPE_STEP_GATHER):
-                rows = jnp.take_along_axis(member_row, k[:, None], axis=1)[:, 0]
-                oseg = jax.vmap(
-                    lambda o, kk, ee, ss: jax.lax.dynamic_slice(
-                        o, (kk, ee, ss * bs), (1, 1, bs)).reshape(bs)
-                )(orders, k, e, s)                          # [L, bs]
-                flat = rows[:, None] * n_pad + oseg
-                bx = jnp.take(x_flat, flat, axis=0)
-                by = jnp.take(y_flat, flat, axis=0)
-                bm = jnp.take(m_flat, flat, axis=0)
-                bkey_l = jax.vmap(
-                    lambda bk, kk, ee, ss: bk[kk, ee, ss])(bkeys, k, e, s)
-
-            new_vars, new_opt, per_lane = batch_step_packed(
-                svars, sopt, bx, by, bm, bkey_l)
-
-            with jax.named_scope(SCOPE_STEP_EMIT):
-                def freeze_if_dead(new, old):
-                    return jax.tree.map(
-                        lambda n, o: bcast(lv, n) * n + (1.0 - bcast(lv, n)) * o
-                        if jnp.issubdtype(n.dtype, jnp.floating)
-                        else jnp.where(bcast(lv, n) > 0, n, o),
-                        new, old,
-                    )
-
-                new_opt = freeze_if_dead(new_opt, sopt)
-                out_vars = dict(freeze_if_dead(new_vars, svars))
-
-                lastep = (e == epochs - 1).astype(jnp.float32)
-                loss_acc = loss_acc + per_lane * lv * lastep
-
-                w = jnp.take_along_axis(member_w, k[:, None], axis=1)[:, 0] * em
-                sr = jnp.maximum(jnp.take_along_axis(
-                    steps_real, k[:, None], axis=1)[:, 0].astype(jnp.float32),
-                    1.0)
-                if lens:
-                    # fedlens member scatter, joint form: lane l's member k[l]
-                    # slot takes the masked set (each member emits once); same
-                    # RAW-update/linear-in-emit contract as the vmap lane form
-                    floss_acc = (floss_acc
-                                 + per_lane * lv * (e == 0).astype(jnp.float32))
-                    lidx = jnp.arange(k.shape[0])
-                    upd_stack = jax.tree.map(
-                        lambda b, v, p: b.at[lidx, k].add(
-                            bcast(em, v)
-                            * (v.astype(jnp.float32) - p.astype(jnp.float32))),
-                        upd_stack, out_vars["params"], sparams0)
-                    l_first = l_first.at[lidx, k].add(em * floss_acc / sr)
-                    l_last = l_last.at[lidx, k].add(em * loss_acc / sr)
-                acc_out = out_vars
-                if client_transform is not None:
-                    # the hook contract is stacked-clients; the joint form IS
-                    # stacked — one call covers every lane
-                    acc_out = client_transform(variables0, out_vars)
-                acc_vars = jax.tree.map(
-                    lambda a, v: a + bcast(w, v) * v, acc_vars, acc_out)
-                acc_w = acc_w + w
-                acc_loss = acc_loss + w * loss_acc / sr
-                acc_tau = acc_tau + w * epochs * sr
-                if reduce_extras is not None:
-                    # w = 0 off-emit, so non-emit lanes contribute exactly
-                    # nothing (the same linear-in-w contract the vmap form
-                    # relies on); the hook's return is already the lane sum
-                    res = LocalResult(out_vars, loss_acc / sr, epochs * sr)
-                    ex = reduce_extras(variables0, res, w)
-                    acc_extras = jax.tree.map(
-                        lambda a, b: a + b, acc_extras, ex)
-                out = (out_vars, new_opt, loss_acc, acc_vars, acc_w, acc_loss,
-                       acc_tau, acc_extras)
-                if lens:
-                    out = out + ((upd_stack, l_first, l_last, floss_acc),)
-            return out
-
-        # zeros DERIVED from inputs (shard_map type consistency, as in the
-        # vmap form)
-        with jax.named_scope(SCOPE_PROLOGUE):
-            zl = jnp.sum(member_w, axis=1) * 0.0            # [L]
-            acc0 = jax.tree.map(lambda v: v.astype(jnp.float32) * 0.0, stack0)
-            if reduce_extras is not None:
-                ex0 = reduce_extras(
-                    variables0,
-                    LocalResult(jax.tree.map(lambda v: v * 0.0, stack0),
-                                zl, zl), zl)
-                acc_extras0 = jax.tree.map(lambda e: e * 0.0, ex0)
-            else:
-                acc_extras0 = {}
-            carry0 = (stack0, opt_state0, zl, acc0, zl, zl, zl, acc_extras0)
-            if lens:
-                zk2 = member_w * 0.0                        # [L, k_max]
-                upd0 = jax.tree.map(
-                    lambda p: zk2.reshape(zk2.shape + (1,) * (p.ndim - 1))
-                    * p.astype(jnp.float32)[:, None], sparams0)
-                carry0 = carry0 + ((upd0, zk2, zk2, zl),)
-        with jax.named_scope(SCOPE_STEP):
-            # all lanes advance in one loop: its bound is the whole plan's
-            final = _walk_steps(
-                step_fn, carry0,
-                (slot.T, epoch_a.T, sie.T, reset.T, emit.T, live.T),
-                chunk_bounds(live, L, scan_unroll)[0], scan_unroll)
-        (_, _, _, acc_vars, acc_w, acc_loss, acc_tau, acc_extras) = final[:8]
-        # singleton lane axis on the extras: the hook summed lanes already,
-        # and the caller's sum(axis=0) must reduce THIS axis, not a real one
-        acc_extras = jax.tree.map(lambda e: e[None], acc_extras)
-        if lens:
-            # [L, k_max, ...] member stacks — the exact shapes the vmapped
-            # lane form returns, so callers handle both forms identically
-            return acc_vars, acc_w, acc_loss, acc_tau, acc_extras, final[8][:3]
-        return acc_vars, acc_w, acc_loss, acc_tau, acc_extras
-
-    return lanes_train
-
-
 def make_packed_cohort_train(
     bundle: ModelBundle,
     task: Task,
@@ -980,7 +578,6 @@ def make_packed_cohort_train(
     shape_key: tuple,
     *,
     compute_dtype=None,
-    packed_conv: str = "off",
     key_slice: Optional[tuple] = None,
     **lane_kwargs,
 ) -> Callable:
@@ -1001,11 +598,9 @@ def make_packed_cohort_train(
     ``reduce_extras`` partial tree ({} when the hook is absent) — the sim
     paradigm's counterpart of the mesh psum tail, so the full cross-silo
     hook contract (FedOpt/FedNova/AGC/robust) rides the packed schedule in
-    BOTH paradigms. ``packed_conv`` selects the fedpack conv lowering for
-    the lane axis (ops/packed_conv.py): 'off' keeps the per-lane vmap."""
+    BOTH paradigms."""
     del shape_key  # lane count and shapes come in via the arrays
-    lanes_fn = make_lanes_train(bundle, task, n_pad,
-                                packed_conv=packed_conv, **lane_kwargs)
+    lanes_fn = make_lanes_train(bundle, task, n_pad, **lane_kwargs)
 
     def packed_train(variables, tx, ty, tm, sampled_rows, weights_pos, rng,
                      plan_arrays):
@@ -1042,9 +637,8 @@ def make_packed_cohort_train(
             lens_out = lanes[5]
             lanes = lanes[:5]
         acc_vars, acc_w, acc_loss, acc_tau, extras = lanes
-        # extras: [L] stacked (vmap form) or singleton-axis (joint form) —
-        # sum(axis=0) reduces either to the cohort partial sums the
-        # server_update hook consumes
+        # extras: [L] stacked; sum(axis=0) reduces them to the cohort
+        # partial sums the server_update hook consumes
         with jax.named_scope(SCOPE_AGGREGATE):
             out = (jax.tree.map(lambda a: jnp.sum(a, axis=0), acc_vars),
                    jnp.sum(acc_w), jnp.sum(acc_loss), jnp.sum(acc_tau),
@@ -1200,7 +794,6 @@ def make_crosssilo_packed_round(
     axis: str = "clients",
     *,
     compute_dtype=None,
-    packed_conv: str = "off",
     client_transform: Optional[Callable] = None,
     reduce_extras: Optional[Callable] = None,
     server_update: Optional[Callable] = None,
@@ -1229,11 +822,7 @@ def make_crosssilo_packed_round(
 
     from fedml_tpu.parallel.crosssilo import apply_server_and_rollback
 
-    # fedpack: the per-device lane block runs the joint stacked-lane form
-    # when packed_conv is on (same psum tail either way — the joint form
-    # returns the same stacked accumulators)
     lanes_fn = make_lanes_train(bundle, task, n_pad,
-                                packed_conv=packed_conv,
                                 client_transform=client_transform,
                                 reduce_extras=reduce_extras, **lane_kwargs)
 

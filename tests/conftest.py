@@ -90,19 +90,6 @@ def pytest_sessionfinish(session, exitstatus):
              f"unsuppressed finding(s), {len(res.suppressed)} suppressed")
     except Exception:
         pass
-    # fedplan cache accounting: a miss is one real jit(...).lower() of a
-    # per-stage candidate micro-program — a hit/miss swing between runs
-    # means the plan key (stage shapes, K, dtype, jax version) churned and
-    # the suite re-lowered candidates it should have reused
-    try:
-        from fedml_tpu.obs.plan import cache_stats
-
-        st = cache_stats()
-        if st["hits"] or st["misses"]:
-            emit(f"[t1] plan-cache: {st['hits']} hit(s) / "
-                 f"{st['misses']} miss(es) this session")
-    except Exception:
-        pass
     # fedpulse session digest: one line when any test streamed a pulse —
     # a silent drop of pulse coverage (or an unexpected critical health
     # event inside the suite) shows up in the tier-1 log itself

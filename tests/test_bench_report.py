@@ -103,18 +103,8 @@ def test_trajectory_values(tmp_path):
     assert traj[6]["xdev_policy"] == "speed"
     assert traj[6]["_basis"] is not None and traj[5]["_basis"] is None
     assert traj[5]["xdev_cohort"] == pytest.approx(50)  # key predates r06
-    # r07 (fedplan, ISSUE 18): the tiny-scale auto arm — the resolved
-    # MIXED plan rides the artifact (its summary is the `plan` column; the
-    # starved 16-channel stages pick the block GEMM, the saturated ones
-    # keep grouped) and the lifted packed ceiling beats r06's uniform arm.
-    # Tiny-scale resnet56 is a new host basis vs r06's full-scale lr run,
-    # so throughput re-bases rather than gating.
-    assert traj[7]["packed_plan"].startswith("K=4 ")
-    assert "bd@16" in traj[7]["packed_plan"]
-    assert "grp@" in traj[7]["packed_plan"]
-    assert "pred=0.919" in traj[7]["packed_plan"]
-    assert traj[7]["packed_lane_ceiling"] > traj[6]["packed_lane_ceiling"]
-    assert traj[6]["packed_plan"] is None   # key predates r07
+    # r07: tiny-scale resnet56 is a new host basis vs r06's full-scale lr
+    # run, so throughput re-bases rather than gating.
     assert traj[7]["_basis"] is not None
 
 
@@ -202,7 +192,7 @@ def test_sketch_columns_render_dash_on_presketch_artifacts(tmp_path, capsys):
     header, *rows = [l for l in out.out.splitlines() if l.strip()]
     for row in rows:
         if row.lstrip().startswith(("r06", "r07")):
-            assert row.rstrip().endswith("speed")  # fedsched/fedplan arms
+            assert row.rstrip().endswith("speed")  # fedsched arms
         elif row.lstrip().startswith("r0"):
             assert row.rstrip().endswith("-")      # policy column empty
 

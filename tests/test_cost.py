@@ -277,11 +277,15 @@ def test_golden_flagship_round_program_table():
 
 # -- attribution through the timed_build hook --------------------------------
 
-def _tiny_run(**cfg_kw):
-    ds = make_synthetic_classification(
+def _tiny_ds():
+    return make_synthetic_classification(
         "cost-attr", (8, 8, 3), 4, 8, records_per_client=12,
         partition_method="hetero", partition_alpha=0.5, batch_size=4,
         seed=0)
+
+
+def _tiny_run(**cfg_kw):
+    ds = _tiny_ds()
     cfg = FedConfig(model="cnn", dataset="x", client_num_in_total=8,
                     client_num_per_round=4, comm_round=2, batch_size=4,
                     epochs=1, lr=0.1, seed=0, frequency_of_the_test=1000,
@@ -311,6 +315,27 @@ def test_attribution_records_tables_and_is_bit_identical():
     for a, b in zip(jax.tree_util.tree_leaves(v_off),
                     jax.tree_util.tree_leaves(v_on)):
         np.testing.assert_array_equal(a, b)
+
+
+def test_a_packed_program_says_its_lanes_and_has_no_packing_column():
+    """The lane program has one form (ISSUE 43): a built packed program
+    carries ``lane_ids`` and no packing hint, and its table has no packing
+    column: every FLOP it counts is a client's own."""
+    _tiny_run(cost_attribution=True)
+    rec = cost.cost_tables()["packed_step"]
+    assert "packed_conv" not in rec and "plan" not in rec
+    assert "packing" not in rec["summary"]
+    assert "useful_flops_per_invocation" not in rec["summary"]
+    assert rec["ops"] and not any(
+        k in o for o in rec["ops"] for k in ("packing_factor", "useful_flops"))
+    api = FedAvgAPI(_tiny_ds(), FedConfig(
+        model="cnn", dataset="x", client_num_in_total=8,
+        client_num_per_round=8, batch_size=4, pack_lanes=4,
+        device_data="on"), create_model("cnn", 4, input_shape=(8, 8, 3)))
+    step = api.build_round_step_packed(api._round_plan(1).lanes.shape_key)
+    assert step.lane_ids == {"lanes": 4, "lane_width": 2}
+    assert not hasattr(step, "cost_hints")
+    assert not hasattr(cost, "apply_packing")
 
 
 def test_attribution_emits_program_cost_event_under_tracing(tmp_path):

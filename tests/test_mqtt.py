@@ -192,7 +192,11 @@ class TestRealTCPBroker:
 
     def test_federation_over_tcp_broker(self):
         """A full FedAvg edge federation (init/sync/upload/finish, binary
-        model payloads) where every message rides the TCP broker."""
+        model payloads) where every message rides the TCP broker, under the
+        at-least-once layer (comm/reliable.py): QoS 0 loses what is in
+        flight when a loaded worker's socket client reconnects, and a lost
+        message is what that layer exists for. The federation's own limit
+        is 60 s here, so a stall costs a worker that and not 300."""
         import fedml_tpu.comm.mqtt_broker as mb
         from fedml_tpu.core.config import FedConfig
         from fedml_tpu.data.synthetic import make_synthetic_classification
@@ -204,14 +208,16 @@ class TestRealTCPBroker:
         cfg = FedConfig(model="lr", dataset="synthetic",
                         client_num_in_total=2, client_num_per_round=2,
                         comm_round=2, epochs=1, batch_size=4, lr=0.1,
-                        seed=0, frequency_of_the_test=1, device_data="off")
+                        seed=0, frequency_of_the_test=1, device_data="off",
+                        wire_reliable=True)
         with mb.MqttBroker(0) as broker:
             agg = run_fedavg_edge(
-                ds, cfg, worker_num=2,
+                ds, cfg, worker_num=2, timeout=60.0,
                 comm_factory=lambda r: mqtt_backend.MqttCommManager(
                     "127.0.0.1", broker.port, client_id=r, client_num=2))
         accs = [h["acc"] for h in agg.test_history]
         assert len(accs) == 2 and all(np.isfinite(a) for a in accs)
+        assert agg.uploads_accepted == 2 * 2
 
     def test_reconnect_after_broker_restart(self):
         """Broker dies and comes back on the same port: the socket client

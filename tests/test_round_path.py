@@ -121,6 +121,26 @@ def test_round_path_is_chosen_once(ds, case, caplog, monkeypatch):
                                           "mesh-partial") else 0), ignored
 
 
+def test_the_lane_program_has_no_flag():
+    """One form of the lane program (ISSUE 43): the parser knows no
+    ``--packed_conv``, ``FedConfig`` no such field, and the counts of both
+    are what that deletion left."""
+    import dataclasses
+
+    from fedml_tpu.core.config import add_args
+
+    parser = add_args()
+    with pytest.raises(SystemExit):
+        parser.parse_args(["--packed_conv", "blockdiag"])
+    flags = [a for a in parser._actions if a.option_strings
+             and a.option_strings != ["-h", "--help"]]
+    names = {f.name for f in dataclasses.fields(FedConfig)}
+    assert "packed_conv" not in names
+    assert (len(names), len(flags)) == (104, 97)
+    with pytest.raises(TypeError):
+        FedConfig(packed_conv="off")
+
+
 def test_a_cohort_with_no_record_is_planned_as_gather(ds):
     """The one per-round case: ``plan_packing`` has nothing to pack, and
     ``_round_plan`` itself plans the round the gather program then runs."""

@@ -1363,10 +1363,10 @@ def test_build_span_and_counters_carry_the_lane_width(tmp_path):
     from fedml_tpu.algorithms.fedavg import FedAvgAPI
 
     ds = make_synthetic_classification(
-        "tr-conv", (8, 8, 3), 3, 4, records_per_client=8,
+        "tr-conv", (8, 8, 1), 3, 4, records_per_client=8,
         partition_method="homo", batch_size=4, seed=0)
     api = FedAvgAPI(ds, FedConfig(
-        model="resnet20", client_num_in_total=4, client_num_per_round=4,
+        model="cnn", client_num_in_total=4, client_num_per_round=4,
         comm_round=2, batch_size=4, lr=0.1, frequency_of_the_test=1,
         device_data="on", pack_lanes=4, async_rounds=True))
     with jax.profiler.trace(str(tmp_path / "prof")):

@@ -77,21 +77,6 @@ METRICS = {
     # the round program stopped filling the lanes the model shapes allow
     "mfu_vs_lane_ceiling": (
         lambda j: j.get("mfu_vs_lane_ceiling"), "mfu/ceiling", True),
-    # fedpack (PR-9 packed_conv A/B block): the packed lowering's static
-    # output-lane ceiling — the lane-ceiling LIFT the client packing buys.
-    # Absent on r01-r08 artifacts (extractor returns None, never a gate
-    # flake on missing keys).
-    "packed_lane_ceiling": (
-        lambda j: (j.get("packed_conv") or {}).get("out_lane_ceiling"),
-        "packed ceiling", True),
-    # packed-everywhere (ISSUE 12): the ADAPTIVE (FedOpt) packed round
-    # program's static ceiling — must track the sgd flagship's (the
-    # acceptance bar is >= 0.8). Absent on pre-ISSUE-12 artifacts (the
-    # chained .get()s return None; missing keys never flake the gate).
-    "packed_fedopt_ceiling": (
-        lambda j: ((j.get("packed_conv") or {}).get("fedopt") or {})
-        .get("out_lane_ceiling"),
-        "fedopt packed ceiling", True),
     # fedsketch distribution tails from the profiler block (ISSUE 10):
     # per-client p99 train-ms and the p99 rounds-behind staleness spread
     "p99_train_ms": (
@@ -132,17 +117,6 @@ METRICS = {
                    if g else None)(
             (j.get("crossdevice") or {}).get("gateway")),
         "gw busy+shed", False),
-    # fedplan (ISSUE 18): the auto arm's chosen plan, as its summary
-    # string ("K=4 grp@32 ... pred=0.919") — a STRING column like
-    # `policy`, trajectory-only (strings never reach the drop gate).
-    # Absent on r01-r06 artifacts and on non-auto bench runs (chained
-    # .get()s return None -> "-"); an auto run that RESOLVED to a
-    # fallback embeds {"resolved","reason"} with no summary key, which
-    # renders "-" the same way.
-    "packed_plan": (
-        lambda j: ((j.get("packed_conv") or {}).get("plan") or {})
-        .get("summary"),
-        "plan", False),
     # fedlens (ISSUE 20): the learning-signal distribution tails at the
     # flagship operating point — p99 raw-update norm, p99 drift (1 -
     # cosine vs the round aggregate; higher = clients pulling against

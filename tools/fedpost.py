@@ -106,7 +106,6 @@ def load_bundle(path: str) -> dict:
         "path": os.path.abspath(path),
         "manifest": man,
         "watchdog": _opt_json("watchdog.json"),
-        "plan": _opt_json("plan.json"),
         "rounds": rounds,
         "events": load_incident_bundle(path),
     }

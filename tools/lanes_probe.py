@@ -17,30 +17,6 @@ Variants isolate where time goes:
 
 Run on the TPU: python tools/lanes_probe.py
 Env: PROBE_ITERS (default 200), PROBE_BATCH (64), PROBE_IMGS_PER_STEP (1).
-
-Packed mode (fedpack, docs/mfu_experiments.md H8): ``--mode packed`` (or
-PROBE_MODE=packed) sweeps the client-packing factor K at the flagship's
-three channel widths and times the three lane-axis conv lowerings of
-ops/packed_conv.py against each other — per-lane ``vmap`` (the packed
-schedule's default), ``blockdiag`` (one im2col block-diagonal GEMM,
-streams K x the useful FLOPs) and ``grouped`` (one feature_group_count=K
-conv). Each row prints the block GEMM's (M, K_red, N), its 128x128 MXU
-tile count, us/iteration and achieved USEFUL GFLOP/s (plus streamed for
-blockdiag — the number the MXU actually executes), for forward and
-forward+grad programs. Same whole-jitted-scan two-point protocol as the
-default mode, so the fixed per-call cost cancels.
-
-Auto mode (fedplan, docs/mfu_experiments.md H10): ``--mode auto`` is the
-silicon adjudicator for the STATIC planner (obs/plan.py). It discovers
-``--model``'s real conv stages, times each stage's fwd+grad program under
-all three lowerings at K=``--lanes`` (same two-point protocol), and
-compares the planner's per-stage pick against the measured-best lowering.
-A non-dominated stage whose pick is more than ``--tolerance`` (fractional
-time, default 0.10 / PROBE_TOL) slower than the measured best is a
-DISAGREEMENT and the probe exits 1 — the H4 expansion credit the planner
-bets on (explicit fgc=K convs get lane-full mappings) is exactly what
-this mode confirms or refutes on the chip. Dominated stages (<1% of
-model conv FLOPs) are probed and reported but never gate.
 """
 
 from __future__ import annotations
@@ -145,166 +121,6 @@ def _conv_variant(mode, xf, w2, h, w):
     )(xp, w2)
 
 
-def _scan_opt(fn, tx, xs):
-    """Adaptive-optimizer packed-program probe body: each scan iteration
-    is one TRAIN step — conv loss grad wrt the stacked kernels, then a
-    per-LANE optax update (``vmap(tx.update)``, the same stacked-state
-    form parallel/packed.py's joint program uses) — so the timed program
-    carries the optimizer's [K]-stacked state exactly like the packed
-    round does. The kernel renormalizes each iteration so the carry stays
-    bounded across the scan (a timing probe, not a training recipe)."""
-    import optax
-
-    def make(n):
-        def step(carry, _):
-            w, opt = carry
-            g = jax.grad(lambda ww: jnp.sum(
-                (fn(xs, ww) ** 2).astype(jnp.float32)))(w)
-            upd, opt = jax.vmap(tx.update)(g, opt, w)
-            w = optax.apply_updates(w, upd)
-            w = (w / (jnp.max(jnp.abs(w)) + 1e-3)).astype(w.dtype)
-            return (w, opt), ()
-
-        def run(ws, opt0):
-            (w, _), _ = jax.lax.scan(step, (ws, opt0), None, length=n)
-            return w
-
-        return run
-
-    return make
-
-
-def packed_main(optimizer: str = "none"):
-    """The H8 sweep: K x {vmap, blockdiag, grouped} at C = 16/32/64.
-    With ``--optimizer`` (sgd/adam/adamw/adagrad/yogi) each row also times
-    the full TRAIN step — fwd + dgrad/wgrad + a per-lane stacked optax
-    update — the packed-everywhere (H9) probe for the adaptive-optimizer
-    packed programs, same two-point protocol."""
-    from fedml_tpu.ops import packed_conv as pc
-
-    tx = None
-    if optimizer not in ("", "none", "off"):
-        from fedml_tpu.parallel.local import make_optimizer
-
-        tx = make_optimizer(optimizer, 0.01,
-                            momentum=0.9 if optimizer == "sgd" else 0.0)
-
-    rng = np.random.RandomState(0)
-    results = {}
-    variants = (("vmap", pc.conv_vmap), ("blockdiag", pc.conv_blockdiag),
-                ("grouped", pc.conv_grouped))
-    for (ci, co, h, w) in [(16, 16, 32, 32), (32, 32, 16, 16),
-                           (64, 64, 8, 8)]:
-        for K in (1, 2, 4, 8):
-            tag = f"c{ci}@{h}x{w}-K{K}"
-            xs = jnp.asarray(rng.randn(K, BATCH, h, w, ci), jnp.bfloat16)
-            ws = jnp.asarray(rng.randn(K, 3, 3, ci, co) * 0.1, jnp.bfloat16)
-            m, kr, n = BATCH * h * w, K * 9 * ci, K * co
-            tiles = -(-kr // 128) * (-(-n // 128))
-            useful = 2.0 * K * BATCH * h * w * 9 * ci * co
-            row = {"MKN": [m, kr, n], "mxu_tiles": tiles,
-                   "us": {}, "useful_gflops": {}}
-            for name, fn in variants:
-                us = _time(_scan(lambda a, b, f=fn: f(a, b), xs, ws), xs, ws)
-                row["us"][name] = round(us, 2)
-                row["useful_gflops"][name] = round(useful / us * 1e-3, 1)
-
-                def train(a, b, f=fn):
-                    g = jax.grad(lambda xx: jnp.sum(
-                        (f(xx, b) ** 2).astype(jnp.float32)))(a)
-                    return (g / (jnp.max(jnp.abs(g)) + 1e-3)).astype(a.dtype)
-
-                us_t = _time(_scan(train, xs, ws), xs, ws)
-                row["us"][f"{name}_f+dgrad"] = round(us_t, 2)
-                if tx is not None:
-                    opt0 = jax.vmap(tx.init)(ws)
-                    us_o = _time(_scan_opt(fn, tx, xs), ws, opt0)
-                    row["us"][f"{name}_train+{optimizer}"] = round(us_o, 2)
-            # streamed rate: what the MXU executes for blockdiag (K x useful)
-            row["streamed_gflops_blockdiag"] = round(
-                useful * K / row["us"]["blockdiag"] * 1e-3, 1)
-            results[tag] = row
-            print(tag, json.dumps(row), flush=True)
-    print(json.dumps({"mode": "packed", "iters": ITERS, "batch": BATCH,
-                      "optimizer": optimizer,
-                      "device": str(jax.devices()[0]), "rows": results}))
-
-
-def auto_main(model: str, lanes: int, tolerance: float) -> int:
-    """The H10 probe: planner pick vs measured best, per real conv stage.
-
-    Times the SAME program shape the planner scored — fwd + grad wrt
-    (activations, kernels) of one packed conv stage — so the comparison
-    is pick-vs-best on the planner's own ground. Returns a process exit
-    code: 0 agreement (within tolerance on every gating stage), 1
-    disagreement, 2 unplannable model."""
-    import jax.numpy as jnp  # noqa: F811 (module-level alias is fine)
-
-    from fedml_tpu.models import create_model
-    from fedml_tpu.obs import plan as fedplan
-    from fedml_tpu.ops import packed_conv as pc
-
-    bundle = create_model(model, 10, dtype=jnp.bfloat16,
-                          input_shape=(32, 32, 3))
-    try:
-        plan = fedplan.plan_lowering(bundle, lanes)
-    except ValueError as e:
-        print(f"fedplan cannot plan {model}: {e}", file=sys.stderr)
-        return 2
-
-    rng = np.random.RandomState(0)
-    impls = {"blockdiag": pc.conv_blockdiag, "grouped": pc.conv_grouped,
-             "off": pc.conv_vmap}
-    rows, disagreements = {}, []
-    for st in plan.stages:
-        tag = (f"{st.kh}x{st.kw}-{st.ci}-{st.co}-s{st.strides}"
-               f"@{st.h}x{st.w}")
-        xs = jnp.asarray(
-            rng.randn(lanes, BATCH, st.h, st.w, st.ci), jnp.bfloat16)
-        ws = jnp.asarray(
-            rng.randn(lanes, st.kh, st.kw, st.ci, st.co) * 0.1,
-            jnp.bfloat16)
-        us = {}
-        for name, fn in impls.items():
-            def train(a, b, f=fn, s=st.strides, p=st.padding):
-                gx, gw = jax.grad(
-                    lambda xx, ww: jnp.sum(jnp.square(
-                        f(xx, ww, s, p).astype(jnp.float32))),
-                    argnums=(0, 1))(a, b)
-                # fold the weight grad back nonlinearly so XLA cannot
-                # DCE the wgrad dot out of the timed scan
-                g = gx + (jnp.tanh(jnp.sum(gw)) * 1e-4).astype(a.dtype)
-                return (g / (jnp.max(jnp.abs(g)) + 1e-3)).astype(a.dtype)
-
-            us[name] = round(_time(_scan(train, xs, ws), xs, ws), 2)
-        best = min(us, key=us.get)
-        slower = (us[st.impl] - us[best]) / us[best] if us[best] > 0 else 0.0
-        gates = not st.dominated
-        agree = st.impl == best or slower <= tolerance
-        row = {"pick": st.impl, "measured_best": best, "us": us,
-               "pick_slower_frac": round(slower, 4),
-               "flops_frac": st.flops_frac, "dominated": st.dominated,
-               "count": st.count, "gates": gates, "agree": agree}
-        rows[tag] = row
-        print(tag, json.dumps(row), flush=True)
-        if gates and not agree:
-            disagreements.append(tag)
-
-    out = {"mode": "auto", "model": model, "lanes": lanes,
-           "tolerance": tolerance, "iters": ITERS, "batch": BATCH,
-           "device": str(jax.devices()[0]),
-           "plan": plan.summary_str(),
-           "predicted_ceiling": plan.predicted_ceiling,
-           "disagreements": disagreements, "rows": rows}
-    print(json.dumps(out))
-    if disagreements:
-        print(f"fedplan disagreement on {len(disagreements)} stage(s): "
-              f"{disagreements} — the static pick leaves "
-              f">{tolerance:.0%} on the table", file=sys.stderr)
-        return 1
-    return 0
-
-
 def main():
     rng = np.random.RandomState(0)
     results = {}
@@ -356,35 +172,7 @@ def main():
 
 
 if __name__ == "__main__":
-    import argparse
-
-    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--mode", choices=("lanes", "packed", "auto"),
-                    default=os.environ.get("PROBE_MODE", "lanes"))
-    ap.add_argument("--optimizer",
-                    choices=("none", "sgd", "adam", "adamw", "adagrad",
-                             "yogi"),
-                    default=os.environ.get("PROBE_OPT", "none"),
-                    help="packed mode: also time the full train step with "
-                         "a per-lane stacked optax update (packed-"
-                         "everywhere / H9 probe)")
-    ap.add_argument("--model",
-                    default=os.environ.get("BENCH_MODEL", "resnet56"),
-                    help="auto mode: whose conv stages to adjudicate")
-    ap.add_argument("--lanes", type=int,
-                    default=int(os.environ.get("PROBE_LANES", "4")),
-                    help="auto mode: pack-lane count K")
-    ap.add_argument("--tolerance", type=float,
-                    default=float(os.environ.get("PROBE_TOL", "0.10")),
-                    help="auto mode: fractional pick-vs-best slowdown "
-                         "above which a non-dominated stage fails")
-    args = ap.parse_args()
     from fedml_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    if args.mode == "auto":
-        sys.exit(auto_main(args.model, args.lanes, args.tolerance))
-    elif args.mode == "packed":
-        packed_main(args.optimizer)
-    else:
-        main()
+    main()

@@ -11,7 +11,6 @@ instead of hand arithmetic:
     python tools/roofline_report.py --what train         # fwd + bwd step
     python tools/roofline_report.py --what round         # whole round program
     python tools/roofline_report.py --markdown           # perf.md table
-    python tools/roofline_report.py --what round --plan  # fedplan candidates
 
 ``--what fwd`` lowers the eval forward (the same program bench.py costs
 with XLA's cost model); ``--what train`` lowers one grad step (adds the
@@ -22,13 +21,6 @@ records) and reports what a traced run would record. Output: the per-op
 GEMM table (top-k by executed FLOPs), the per-output-channel stage table,
 flop-weighted output/reduction lane ceilings, and — when a TPU peak is
 known for the current device — the MFU ceiling those lanes impose.
-
-``--plan`` renders the fedplan (obs/plan.py) per-stage candidate table
-instead: every forward conv stage x {blockdiag, grouped, off} with each
-candidate's effective/parsed lane ceilings and useful fraction, the
-winner flagged, and the plan-vs-uniform-flag ceilings. With ``--markdown``
-it emits the docs/perf.md "Client packing" plan table (regenerated, not
-hand-edited). ``--lanes`` (or BENCH_PACK_LANES) sets K.
 
 Exit codes: 0 ok; 2 when the model cannot be lowered/analyzed.
 """
@@ -105,15 +97,11 @@ def _analyze_round(model: str, batch: int, dtype_name: str, top_k: int):
         "roofline", (32, 32, 3), 10, 4, records_per_client=2 * batch,
         partition_method="homo", partition_alpha=0.5, batch_size=batch,
         seed=0)
-    # BENCH_PACKED_CONV: fedpack conv lowering for the packed lanes
-    # (off|blockdiag|grouped) — `--what round` with blockdiag regenerates
-    # the docs/perf.md "Client packing" lane table (lifted ceiling)
     cfg = FedConfig(model=model, dataset="cifar10", client_num_in_total=4,
                     client_num_per_round=4, comm_round=1, batch_size=batch,
                     epochs=1, lr=0.1, dtype=dtype_name,
                     frequency_of_the_test=10_000, seed=0,
                     pack_lanes=int(os.environ.get("BENCH_PACK_LANES", "2")),
-                    packed_conv=os.environ.get("BENCH_PACKED_CONV", "off"),
                     device_data="on", cost_attribution=True)
     bundle = create_model(model, 10, dtype=dtype,
                           input_shape=ds.train_x.shape[2:],
@@ -142,94 +130,6 @@ def _analyze_round(model: str, batch: int, dtype_name: str, top_k: int):
             "shape_key": rec["shape_key"]}
 
 
-def _analyze_plan(model: str, batch: int, dtype_name: str, lanes: int):
-    """The fedplan candidate grid: plan + full per-stage candidate records
-    (score_stage replays the cached micro-lowerings, so this costs the
-    same jit(...).lower() calls the plan itself did)."""
-    import jax.numpy as jnp
-
-    from fedml_tpu.models import create_model
-    from fedml_tpu.obs import plan as fedplan
-
-    dtype = jnp.bfloat16 if dtype_name == "bfloat16" else jnp.float32
-    bundle = create_model(model, 10, dtype=dtype, input_shape=(32, 32, 3),
-                          bn_impl=os.environ.get("BENCH_BN", "xla"),
-                          conv_impl=os.environ.get("BENCH_CONV", "xla"))
-    plan = fedplan.plan_lowering(bundle, lanes, batch=batch)
-    scored = [fedplan.score_stage(s, lanes, plan.dtype, batch)
-              for s in fedplan.model_conv_stages(bundle, batch=batch)]
-    return {"plan": plan, "scored": scored}
-
-
-def _stage_tag(st: dict) -> str:
-    tag = (f"{st['kh']}x{st['kw']} {st['ci']:>3}->{st['co']:<3}"
-           f" s{st['strides']} @{st['h']}x{st['w']}")
-    return tag + (f" x{st['count']}" if st["count"] > 1 else "")
-
-
-def format_plan(rep: dict, model: str) -> str:
-    plan, scored = rep["plan"], rep["scored"]
-    lines = [f"fedplan: {model} K={plan.lanes} {plan.dtype} "
-             f"batch={plan.batch} — {len(plan.stages)} conv stage(s)",
-             f"predicted flop-weighted lane ceiling "
-             f"{plan.predicted_ceiling * 100:.1f}% (useful-weighted; "
-             f"streamed-basis static {plan.predicted_static_ceiling * 100:.1f}%, "
-             f"useful FLOPs {plan.useful_flops_frac * 100:.1f}% of streamed)",
-             "uniform flags: " + "  ".join(
-                 f"{impl} {ceil * 100:.1f}%" for impl, ceil in plan.uniform),
-             "",
-             "  stage                        FLOP%  candidate   eff-ceil"
-             "  parsed  useful%  verdict"]
-    for ps, s in zip(plan.stages, scored):
-        tag = _stage_tag(s["stage"])
-        frac = f"{ps.flops_frac * 100:>5.1f}"
-        if ps.dominated:
-            frac += "*"
-        first = True
-        for impl in ("blockdiag", "grouped", "off"):
-            c = s["candidates"][impl]
-            won = impl == ps.impl
-            verdict = ("winner" if won
-                       else s["reasons"].get(impl, ""))
-            lines.append(
-                f"  {tag if first else '':<28} {frac if first else '':>6} "
-                f"{impl:<10} {'>' if won else ' '}{c['eff_ceiling'] * 100:>6.1f}%"
-                f" {c['ceiling'] * 100:>6.1f}% {c['useful_frac'] * 100:>7.1f}%"
-                f"  {verdict}")
-            first = False
-    lines.append("")
-    lines.append("  (* = dominated stage, <1% of model conv FLOPs — flagged "
-                 "so it cannot steer lane-count selection)")
-    lines.append(f"  plan: {plan.summary_str()}")
-    return "\n".join(lines)
-
-
-def format_plan_markdown(rep: dict) -> str:
-    """The docs/perf.md 'Client packing' plan table, regenerated."""
-    plan, scored = rep["plan"], rep["scored"]
-    lines = ["| stage | FLOP share | pick | blockdiag | grouped | off |",
-             "|---|---|---|---|---|---|"]
-    for ps, s in zip(plan.stages, scored):
-        cells = []
-        for impl in ("blockdiag", "grouped", "off"):
-            c = s["candidates"][impl]
-            cell = f"{c['eff_ceiling'] * 100:.1f}%"
-            if impl == ps.impl:
-                cell = f"**{cell}**"
-            cells.append(cell)
-        dom = "\\*" if ps.dominated else ""
-        lines.append(f"| {_stage_tag(s['stage'])} | "
-                     f"{ps.flops_frac * 100:.1f}%{dom} | {ps.impl} | "
-                     + " | ".join(cells) + " |")
-    uni = "  ".join(f"{impl} {ceil * 100:.1f}%"
-                    for impl, ceil in plan.uniform)
-    lines.append("")
-    lines.append(f"Predicted flop-weighted lane ceiling: "
-                 f"**{plan.predicted_ceiling * 100:.1f}%** "
-                 f"(uniform flags: {uni})")
-    return "\n".join(lines)
-
-
 def format_report(rep: dict, model: str, what: str, peak, peak_entry) -> str:
     s = rep["summary"]
     lines = [f"roofline: {model} ({what} program), "
@@ -253,13 +153,6 @@ def format_report(rep: dict, model: str, what: str, peak, peak_entry) -> str:
         f"flop-weighted output-lane ceiling: "
         f"{s['out_lane_ceiling'] * 100:.1f}% "
         f"(reduction-lane: {s['red_lane_ceiling'] * 100:.1f}%)")
-    packing = s.get("packing")
-    if packing:
-        lines.append(
-            f"client packing (fedpack): factor {packing['max_factor']}, "
-            f"useful FLOPs {packing['useful_flops_frac'] * 100:.1f}% of "
-            f"streamed — MFU against this program must be read on the "
-            f"USEFUL basis (docs/perf.md 'Client packing')")
     if peak:
         lines.append(
             f"=> MFU ceiling on {peak_entry} ({peak / 1e12:.0f} TFLOP/s "
@@ -267,12 +160,12 @@ def format_report(rep: dict, model: str, what: str, peak, peak_entry) -> str:
             "can exceed it while these GEMM shapes leave lanes empty")
     lines.append("")
     lines.append(f"top ops by executed FLOPs:")
-    lines.append("  kind  count        M      K      N  groups  pack   fill"
+    lines.append("  kind  count        M      K      N  groups   fill"
                  "  GFLOPs  FLOP/byte")
     for o in s["top_ops"]:
         lines.append(
             f"  {o['kind']:<5} {o['count']:>5} {o['m']:>8} {o['k']:>6} "
-            f"{o['n']:>6} {o['groups']:>7} {o.get('packing_factor', 1):>5} "
+            f"{o['n']:>6} {o['groups']:>7} "
             f"{o['out_lane_fill'] * 100:>5.1f}% "
             f"{o['flops'] * o['count'] / 1e9:>7.3f}  {o['intensity']:>9.1f}")
     return "\n".join(lines)
@@ -309,33 +202,11 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true")
     ap.add_argument("--markdown", action="store_true",
                     help="emit the docs/perf.md stage table and exit")
-    ap.add_argument("--plan", action="store_true",
-                    help="render the fedplan per-stage candidate table "
-                         "(obs/plan.py) instead of the op roofline")
-    ap.add_argument("--lanes", type=int,
-                    default=int(os.environ.get("BENCH_PACK_LANES", "4")),
-                    help="pack-lane count K for --plan")
     args = ap.parse_args(argv)
 
     import jax
 
     from fedml_tpu.obs import cost
-
-    if args.plan:
-        try:
-            rep = _analyze_plan(args.model, args.batch, args.dtype,
-                                args.lanes)
-        except ValueError as e:
-            print(f"fedplan cannot plan {args.model}: {e}", file=sys.stderr)
-            return 2
-        if args.markdown:
-            print(format_plan_markdown(rep))
-        elif args.json:
-            print(json.dumps({"model": args.model, "lanes": args.lanes,
-                              "plan": rep["plan"].to_dict()}, indent=2))
-        else:
-            print(format_plan(rep, args.model))
-        return 0
 
     fn = {"fwd": _analyze_fwd, "train": _analyze_train,
           "round": _analyze_round}[args.what]

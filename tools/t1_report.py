@@ -13,8 +13,7 @@ out next to the dot count, so each PR can see its budget profile:
 
 Report: DOTS (passed-in-window, the gate's own regex), outcome summary
 line, failure/error names, the slowest-10 test files, the compile-cache
-line, the plan-cache line (fedplan candidate micro-lowering hits/misses),
-the obs-overhead line (the pinned full-plane-on vs off wall
+line, the obs-overhead line (the pinned full-plane-on vs off wall
 delta from the fedsketch budget test), the fedlint line (rule count
 plus unsuppressed/suppressed finding counts over the real tree), the
 lens line (fedlens learning folds / client observations / suspects
@@ -49,7 +48,6 @@ SUMMARY_RE = re.compile(
 FAIL_RE = re.compile(r"^(FAILED|ERROR) (\S+)")
 FILE_SECONDS_RE = re.compile(r"^\[t1\] file-seconds: (\[.*\])\s*$")
 CACHE_RE = re.compile(r"^\[t1\] compile-cache: (.*)$")
-PLAN_CACHE_RE = re.compile(r"^\[t1\] plan-cache: (.*)$")
 OBS_OVERHEAD_RE = re.compile(r"^\[t1\] obs-overhead: (.*)$")
 FEDLINT_RE = re.compile(r"^\[t1\] fedlint: (.*)$")
 LENS_RE = re.compile(r"^\[t1\] lens: (.*)$")
@@ -63,7 +61,6 @@ def parse_log(text: str) -> dict:
     summary = None
     file_seconds: list = []
     cache_line = None
-    plan_cache = None
     obs_overhead = None
     fedlint = None
     lens = None
@@ -92,10 +89,6 @@ def parse_log(text: str) -> dict:
         if m:
             cache_line = m.group(1)
             continue
-        m = PLAN_CACHE_RE.match(line)
-        if m:
-            plan_cache = m.group(1)
-            continue
         m = OBS_OVERHEAD_RE.match(line)
         if m:
             obs_overhead = m.group(1)
@@ -121,7 +114,6 @@ def parse_log(text: str) -> dict:
         "failures": failures,
         "slowest_files": file_seconds[:10],
         "compile_cache": cache_line,
-        "plan_cache": plan_cache,
         "obs_overhead": obs_overhead,
         "fedlint": fedlint,
         "lens": lens,
@@ -143,8 +135,6 @@ def format_report(rep: dict) -> str:
         lines.append(f"summary: {rep['summary']}")
     if rep["compile_cache"]:
         lines.append(f"compile-cache: {rep['compile_cache']}")
-    if rep.get("plan_cache"):
-        lines.append(f"plan-cache: {rep['plan_cache']}")
     if rep.get("obs_overhead"):
         lines.append(f"obs-overhead: {rep['obs_overhead']}")
     if rep.get("fedlint"):

@@ -151,7 +151,6 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
     device_mem: dict[object, dict] = {}   # rank -> series -> high-water
     device_mem_samples = 0
     cost_programs: dict[str, dict] = {}   # fedcost program_cost instants
-    plan_programs: dict[str, dict] = {}   # fedplan program_plan instants
 
     for ev in events:
         ph, name = ev.get("ph"), ev.get("name")
@@ -207,10 +206,6 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
                 if a.get("program"):
                     # re-attributions (new shape key) keep the LAST record
                     cost_programs[a["program"]] = a
-            elif name == "program_plan" and ev.get("cat") == "cost":
-                a = _args(ev)
-                if a.get("program"):
-                    plan_programs[a["program"]] = a
         elif ph == "C":
             if name == "registry":
                 # each flush writes a full CUMULATIVE registry snapshot, so
@@ -419,13 +414,6 @@ def analyze(events: list[dict], expect_ranks: int = 0) -> dict:
                 for p, a in sorted(cost_programs.items())},
             "achieved": achieved,
         }
-    if plan_programs:
-        # fedplan (--packed_conv auto): the per-stage lowering plan each
-        # program was built from, plus the post-first-call self-check
-        # (predicted vs realized static lane ceiling)
-        rep["plan"] = {
-            p: {"plan": a.get("plan"), "self_check": a.get("self_check")}
-            for p, a in sorted(plan_programs.items())}
     if device_mem:
         rep["device_mem"] = {
             "samples": device_mem_samples,
@@ -648,28 +636,6 @@ def format_report(rep: dict) -> str:
                             f"{ach.get('mfu_vs_ceiling', 0) * 100:.0f}% of "
                             f"the lane ceiling")
                 lines.append(row)
-    plansec = rep.get("plan")
-    if plansec:
-        lines.append("")
-        lines.append("lowering plans (fedplan, --packed_conv auto):")
-        for pname, p in plansec.items():
-            pl = p.get("plan") or {}
-            lines.append(f"  {pname}: {pl.get('summary', '(no summary)')}")
-            uni = pl.get("uniform") or {}
-            if uni:
-                lines.append("      vs uniform: " + "  ".join(
-                    f"{impl} {ceil * 100:.1f}%"
-                    for impl, ceil in sorted(uni.items())))
-            sc = p.get("self_check")
-            if sc:
-                verdict = ("ok" if sc.get("ok")
-                           else "DIVERGED — plan vs realized program")
-                lines.append(
-                    f"      self-check: predicted static ceiling "
-                    f"{sc.get('predicted_static_ceiling', 0) * 100:.1f}% vs "
-                    f"realized {sc.get('realized_static_ceiling', 0) * 100:.1f}%"
-                    f" (delta {sc.get('delta', 0) * 100:+.1f}%, "
-                    f"tol {sc.get('tolerance', 0) * 100:.0f}%) {verdict}")
     comp = rep.get("compile")
     if comp and (comp["counters"] or comp["spans"]):
         c = comp["counters"]
