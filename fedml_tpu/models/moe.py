@@ -42,12 +42,12 @@ from typing import Optional
 from fedml_tpu.models import COUNTERS, ModelBundle, register_model
 from fedml_tpu.models.transformer import (CompressedConvAttention,
                                           DeltaAttention, GroupedAttention,
-                                          LatentAttention, Linear,
+                                          LatentAttention, Linear, MLP_FORMS,
                                           Mamba2Mixer, RMSNorm, SelfAttention,
-                                          SwiGLU, _normal, fan_in_uniform,
+                                          _normal, fan_in_uniform,
                                           yarn_frequencies)
 from fedml_tpu.obs.tracer import (SCOPE_LM_DENSE, SCOPE_LM_EXPERTS,
-                                  SCOPE_LM_ROUTE)
+                                  SCOPE_LM_LATENT, SCOPE_LM_ROUTE)
 from fedml_tpu.ops.grouped_matmul import (embed_rows, fan_out_rows,
                                           grouped_matmul, permute_rows)
 from fedml_tpu.ops.ssd import SSD_CHUNK
@@ -256,22 +256,46 @@ def _rung_index(rungs: tuple, sizes: jax.Array) -> jax.Array:
     return sum((total > c).astype(jnp.int32) for c in rungs[:-1])
 
 
+def _swiglu_rows(rows, sizes, live, w_gate, w_up, w_down):
+    g = grouped_matmul(rows, w_gate, sizes)
+    u = grouped_matmul(rows, w_up, sizes)
+    return grouped_matmul(nn.silu(g) * u, w_down, sizes), ()
+
+
+def _relu2_rows(rows, sizes, live, w_up, w_down):
+    u = grouped_matmul(rows, w_up, sizes)
+    y = grouped_matmul(jnp.square(nn.relu(u)), w_down, sizes)
+    return y, (jnp.sum((u > 0) & live, dtype=jnp.float32),)
+
+
+#: a held expert's form by name: its matrices' names, in the order the rows'
+#: function takes them, and ``(rows [C, d], sizes, live [C, 1], *weights) ->
+#: (rows out [C, d], stats)``, the grouped matmuls and what lies between
+#: them. ``swiglu``: ``down(silu(gate x) * up x)``, no statistic; ``relu2``:
+#: ``down(relu(up x)^2)`` and the live rows' hidden units that are positive
+#: before the square (float32)
+EXPERT_FORMS = {"swiglu": (("gate", "up", "down"), _swiglu_rows),
+                "relu2": (("up", "down"), _relu2_rows)}
+
+
 @functools.cache
-def _rung(capacity: int):
+def _rung(capacity: int, form: str = "swiglu"):
     """The held experts' part of a sparse layer over the first ``capacity``
     sorted row slots: ``(xf [n, d], order [k*n], inv [k*n], sizes [held],
-    mine [k, n], weights [n, k], w_gate, w_up, w_down) -> [n, d]`` float32
-    (the three experts' weights in ``xf``'s dtype). Exact whenever
+    mine [k, n], weights [n, k], *w) -> ([n, d] float32, stats)`` (``w``:
+    the matrices of the experts' ``form`` in ``xf``'s dtype, ``stats`` its
+    statistics: :data:`EXPERT_FORMS`). Exact whenever
     ``sum(sizes) <= capacity``: a slot past the last group goes in and comes
     out as zeros (so that nothing a kernel leaves there, and no cotangent of
     it, reaches a token), and a pair whose slot was not kept reads a zero
-    row. One jitted function a capacity, so that every sparse layer of a
-    model traces it once."""
+    row. One jitted function a capacity and form, so that every sparse layer
+    of a model traces it once."""
 
     # the trace counts a capacity's layer-steps by this name
     rows_name = f"moe_rows_{capacity}"
+    expert_rows = EXPERT_FORMS[form][1]
 
-    def rung(xf, order, inv, sizes, mine, weights, w_gate, w_up, w_down):
+    def rung(xf, order, inv, sizes, mine, weights, *w):
         (k, n), d = mine.shape, xf.shape[-1]
         with jax.named_scope(rows_name):
             with jax.named_scope(SCOPE_LM_ROUTE):
@@ -279,28 +303,27 @@ def _rung(capacity: int):
                 rows = jnp.where(
                     live, fan_out_rows(xf, order[:capacity], inv), 0)
             with jax.named_scope(SCOPE_LM_EXPERTS):
-                g = grouped_matmul(rows, w_gate, sizes)
-                u = grouped_matmul(rows, w_up, sizes)
-                y = grouped_matmul(nn.silu(g) * u, w_down, sizes)
+                y, stats = expert_rows(rows, sizes, live, *w)
             with jax.named_scope(SCOPE_LM_ROUTE):
                 y = jnp.where(live, y, 0)
                 back = permute_rows(y, inv, order[:capacity]).reshape(k, n, d)
                 wt = jnp.where(mine, weights.T, 0.0)
-                return jnp.einsum("knd,kn->nd", back.astype(jnp.float32), wt)
+                return jnp.einsum("knd,kn->nd", back.astype(jnp.float32),
+                                  wt), stats
 
     return jax.jit(rung)
 
 
 @functools.cache
-def _rung_vjp(capacity: int):
-    """``(operands, ct) ->`` the cotangents of ``_rung(capacity)``'s five
-    floating operands, from a forward rebuilt at that capacity; the three
+def _rung_vjp(capacity: int, form: str = "swiglu"):
+    """``(operands, ct) ->`` the cotangents of ``_rung(capacity, form)``'s
+    floating operands, from a forward rebuilt at that capacity; the experts'
     weights' in float32, what the parameters they were cast from take."""
 
     def rung_vjp(operands, ct):
         xf, order, inv, sizes, mine, weights, *w = operands
         _, vjp = jax.vjp(
-            lambda xf, weights, *w: _rung(capacity)(
+            lambda xf, weights, *w: _rung(capacity, form)(
                 xf, order, inv, sizes, mine, weights, *w), xf, weights, *w)
         dx, dweights, *dw = vjp(ct)
         with jax.named_scope(SCOPE_LM_EXPERTS):
@@ -317,33 +340,33 @@ def _cast_experts(w, dtype):
         return [a.astype(dtype) for a in w]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def routed_rows(rungs: tuple, xf, order, inv, sizes, mine, weights,
-                *w) -> jax.Array:
-    """``_rung(C)`` at the first ``C`` of ``rungs`` that holds the rows
-    ``sizes`` counts, on a rung's operands (``w``: the three experts'
-    weights, still float32 parameters): a ``lax.switch`` on the router's
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def routed_rows(rungs: tuple, form: str, xf, order, inv, sizes, mine, weights,
+                *w) -> tuple:
+    """``_rung(C, form)`` at the first ``C`` of ``rungs`` that holds the rows
+    ``sizes`` counts, on a rung's operands (``w``: the experts' matrices,
+    still float32 parameters): a ``lax.switch`` on the router's
     own count, no row dropped (the last rung is every pair). The backward
     pass saves the operands alone, switches on the same index and rebuilds
     that rung's forward: a plain ``lax.switch`` would save the union of
     every rung's residuals, 1.9 times the full capacity's. Under a ``vmap``
     (packed lanes) the index is batched and JAX runs every rung and
     selects: still exact, only slow (and the TPU refuses a batched grouped
-    matmul there anyway)."""
+    matmul there anyway). -> ``([n, d] float32, the form's statistics)``."""
     return jax.lax.switch(
-        _rung_index(rungs, sizes), [_rung(c) for c in rungs],
+        _rung_index(rungs, sizes), [_rung(c, form) for c in rungs],
         xf, order, inv, sizes, mine, weights, *_cast_experts(w, xf.dtype))
 
 
-def _routed_fwd(rungs, *operands):
-    return routed_rows(rungs, *operands), operands
+def _routed_fwd(rungs, form, *operands):
+    return routed_rows(rungs, form, *operands), operands
 
 
-def _routed_bwd(rungs, operands, ct):
+def _routed_bwd(rungs, form, operands, ct):
     xf, order, inv, sizes, mine, weights, *w = operands
     with jax.named_scope(SCOPE_LM_ROUTE):
         dx, dweights, *dw = jax.lax.switch(
-            _rung_index(rungs, sizes), [_rung_vjp(c) for c in rungs],
+            _rung_index(rungs, sizes), [_rung_vjp(c, form) for c in rungs],
             (xf, order, inv, sizes, mine, weights,
              *_cast_experts(w, xf.dtype)), ct)
     return (dx, None, None, None, None, dweights, *dw)
@@ -438,6 +461,18 @@ class SharedRoutedMoe(nn.Module):
     and the round's aggregate is the clients' weighted mean of it, as of any
     parameter (0: the bias stays as it was loaded).
 
+    ``form``: what an expert and the shared MLP are (:data:`EXPERT_FORMS`,
+    ``transformer.MLP_FORMS``): a SwiGLU of three matrices, or ``relu2``,
+    ``down(relu(up x)^2)`` of two. ``latent``: the routed experts work in a
+    latent of that width, between two projections of the layer's own,
+    ``out = latent_out(sum_i w_i expert_i(latent_in x)) + shared(x)``: the
+    rows that move are ``latent`` wide, the experts' matrices ``[latent,
+    width]`` and ``[width, latent]``; the router and the shared MLP read the
+    layer's input (0: the experts read and write the model's width).
+    ``shared_width``: the shared MLP's width where it is published by
+    itself (``moe_shared_expert_intermediate_size``; 0: ``n_shared *
+    width``).
+
     The ``counters`` collection (``models.COUNTERS``) carries
     ``expert_rows`` (rows each held expert has computed, summed over the
     training steps) and ``steps``: the load statistic that the published
@@ -446,7 +481,10 @@ class SharedRoutedMoe(nn.Module):
     The packed simulation round sums them over the round's clients
     (float32: exact up to 2**24 rows an expert), and
     ``ModelBundle.counters`` reads them on the host. Under an MLP router
-    also ``skipped``, the tokens whose choice was no expert.
+    also ``skipped``, the tokens whose choice was no expert; where the form
+    counts them (``relu2``) also ``live_units``, the share of the hidden
+    units of the held experts' rows and of the shared MLP that are positive
+    before the square, summed over the training steps.
     """
 
     n_routed: int
@@ -463,6 +501,9 @@ class SharedRoutedMoe(nn.Module):
     router_hidden: int = 0
     eps: float = 1e-6
     balance_rate: float = 0.0
+    form: str = "swiglu"
+    latent: int = 0
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, x, train: bool = False, carry=None):
@@ -470,17 +511,23 @@ class SharedRoutedMoe(nn.Module):
         n, k, dt = b * t, self.top_k, self.dtype
         held = self.n_routed if self.held_count is None else self.held_count
         xf = x.reshape(n, d)
-        out = 0.0
+        names, _ = EXPERT_FORMS[self.form]
+        out, shared_stats, shared_width = 0.0, (), 0
         if self.n_shared:
+            shared_width = self.shared_width or self.n_shared * self.width
             with jax.named_scope(SCOPE_LM_DENSE):
-                out = SwiGLU(self.n_shared * self.width, dt, name="shared")(xf)
-
-        def experts(name, a, c):
-            return self.param(name, _normal(), (held, a, c), jnp.float32)
-
-        w_gate = experts("gate", d, self.width)
-        w_up = experts("up", d, self.width)
-        w_down = experts("down", self.width, d)
+                out, shared_stats = MLP_FORMS[self.form](
+                    shared_width, dt, name="shared")(xf, stats=True)
+        xl = None
+        if self.latent:
+            with jax.named_scope(SCOPE_LM_LATENT):
+                xl = Linear(self.latent, dt, name="latent_in")(xf)
+        dl = self.latent or d
+        # an expert's last matrix leads back to the rows' width
+        w = [self.param(name, _normal(),
+                        (held, self.width, dl) if name == "down"
+                        else (held, dl, self.width), jnp.float32)
+             for name in names]
         with jax.named_scope(SCOPE_LM_ROUTE):
             if self.router_hidden:
                 scores, bias, carry = MlpRouter(
@@ -508,8 +555,13 @@ class SharedRoutedMoe(nn.Module):
             inv = jnp.argsort(order)
             sizes = jnp.sum(jax.nn.one_hot(key, held + 1, dtype=jnp.int32),
                             axis=0)[:held]
-            routed = routed_rows(row_rungs(n * k), xf.astype(dt), order, inv,
-                                 sizes, mine, weights, w_gate, w_up, w_down)
+            routed, stats = routed_rows(
+                row_rungs(n * k), self.form,
+                xf.astype(dt) if xl is None else xl, order, inv, sizes, mine,
+                weights, *w)
+        if self.latent:
+            with jax.named_scope(SCOPE_LM_LATENT):
+                routed = Linear(d, dt, name="latent_out")(routed.astype(dt))
         seen = self.variable(COUNTERS, "expert_rows",
                              lambda: jnp.zeros((held,), jnp.float32))
         steps = self.variable(COUNTERS, "steps",
@@ -532,6 +584,14 @@ class SharedRoutedMoe(nn.Module):
             if train and not self.is_initializing():
                 skipped.value = skipped.value + jnp.sum(
                     (idx == self.n_routed).astype(jnp.float32))
+        if stats:          # the form counts its live hidden units
+            live = self.variable(COUNTERS, "live_units",
+                                 lambda: jnp.zeros((), jnp.float32))
+            if train and not self.is_initializing():
+                units = (jnp.sum(sizes).astype(jnp.float32) * self.width
+                         + n * shared_width)
+                live.value = live.value + jax.lax.stop_gradient(
+                    (stats[0] + sum(shared_stats)) / jnp.maximum(units, 1.0))
         return (out + routed.astype(dt)).reshape(b, t, d), carry
 
     def _linear_scores(self, xf):
@@ -576,10 +636,28 @@ class LatentMoeSizes:
     remat: bool = True
     dtype: Any = jnp.float32
     #: the mixer of each layer: ``"latent"``, ``"delta"``, ``"ssd"`` (a
-    #: Mamba-2 state-space mixer), or grouped-query attention over all the
-    #: keys (``"full"``) or under a sliding window (``"window"``); empty: all
-    #: latent
+    #: Mamba-2 state-space mixer), ``"cca"``, grouped-query attention over
+    #: all the keys (``"full"``) or under a sliding window (``"window"``), or
+    #: ``"none"`` (the layer is its MLP alone); empty: all latent
     mixers: tuple = ()
+    #: the MLP of each layer: ``"dense"``, ``"sparse"`` or ``"none"`` (the
+    #: layer is its mixer alone: with ``mixers`` a model whose layers are ONE
+    #: sub-layer each); empty: the first ``first_dense`` dense, the others
+    #: sparse
+    mlps: tuple = ()
+    #: what every MLP is, the dense one, an expert and the shared MLP
+    #: (``transformer.MLP_FORMS``, :data:`EXPERT_FORMS`): ``"swiglu"`` or
+    #: ``"relu2"``
+    mlp_form: str = "swiglu"
+    #: the routed experts' latent width, between two projections of the
+    #: sparse layer's own (0: they work at ``dim``)
+    moe_latent: int = 0
+    #: the shared MLP's width where a configuration publishes it as a key of
+    #: its own (``moe_shared_expert_intermediate_size``, beside
+    #: ``n_shared_experts`` 1 and another ``moe_intermediate_size``: the
+    #: file's ``n_shared`` then stays the published count); 0: ``n_shared *
+    #: expert_width``
+    shared_width: int = 0
     #: group-limited routing (1: none)
     n_group: int = 1
     topk_group: int = 1
@@ -672,51 +750,20 @@ class LatentMoeBlock(nn.Module):
     """``h += r Mixer(RMSNorm(h))``; ``h += r Mlp(RMSNorm(h))`` with ``r``
     the sizes' ``residual_scale`` (or each add a :class:`ScaledMerge`): the
     mixer attention of one of four kinds (``attn``), the delta rule
-    (``delta``) or the state-space recurrence (``ssd``), the MLP a SwiGLU of
-    ``dense_width`` in the leading dense layers, the sparse layer after.
-    ``(h, carry) -> (h, carry)``: ``carry`` is what a sparse layer's router
-    hands the router of the next layer, None where it keeps none."""
+    (``delta``) or the state-space recurrence (``ssd``), the MLP (``mlp``)
+    ``"dense"``, of ``dense_width``, or ``"sparse"``, both in the sizes'
+    ``mlp_form``. Either sub-layer may be ``"none"``: the layer is then the
+    other alone, with its one norm. ``(h, carry) -> (h, carry)``: ``carry``
+    is what a sparse layer's router hands the router of the next layer, None
+    where it keeps none."""
 
     sizes: LatentMoeSizes
-    sparse: bool
+    mlp: str
     mixer: str = "latent"
 
     @nn.compact
     def __call__(self, h, train: bool = False, carry=None):
         c = self.sizes
-        a = RMSNorm(c.eps, c.dtype, name="attn_norm")(h)
-        if self.mixer == "latent":
-            a = LatentAttention(c.heads, c.nope, c.rope, c.v_dim, c.kv_rank,
-                                c.rope_theta, c.eps, c.dtype, c.qk_norm,
-                                c.out_gate, name="attn")(a)
-        elif self.mixer == "delta":
-            a = DeltaAttention(c.heads, c.delta_head_dim, c.delta_conv,
-                               c.delta_lower_bound, c.eps, c.dtype,
-                               name="delta")(a)
-        elif self.mixer == "ssd":
-            a = Mamba2Mixer(c.ssd_heads, c.ssd_head_dim, c.ssd_state,
-                            c.ssd_conv, c.ssd_chunk, c.eps, c.dtype,
-                            name="ssd")(a, train)
-        elif self.mixer == "cca":
-            a = CompressedConvAttention(c.heads, c.kv_heads, c.v_dim, c.rope,
-                                        c.rope_theta, c.cca_conv, c.dtype,
-                                        name="attn")(a)
-        elif self.mixer == "window":
-            a = GroupedAttention(c.window_heads, c.kv_heads, c.v_dim, c.v_dim,
-                                 c.window_rope_theta, window=c.window,
-                                 gate=c.out_gate, dtype=c.dtype,
-                                 name="attn")(a)
-        else:
-            yarn, scale = None, 1.0
-            if c.yarn_factor:
-                scale = c.yarn_attention_factor
-                yarn = tuple(yarn_frequencies(
-                    c.rope, c.rope_theta, c.yarn_factor, c.yarn_original,
-                    c.yarn_beta_fast, c.yarn_beta_slow).tolist())
-            a = GroupedAttention(c.heads, c.kv_heads, c.v_dim, c.rope,
-                                 c.rope_theta, yarn, scale, gate=c.out_gate,
-                                 dtype=c.dtype, scale=c.attn_scale,
-                                 name="attn")(a)
 
         def add(h, branch, name):
             if c.scaled_residual:
@@ -725,24 +772,68 @@ class LatentMoeBlock(nn.Module):
                 branch = branch * jnp.asarray(c.residual_scale, branch.dtype)
             return h + branch
 
-        h = add(h, a, "attn_merge")
+        def mix(a):
+            """The layer's mixer on its normed input."""
+            if self.mixer == "latent":
+                a = LatentAttention(c.heads, c.nope, c.rope, c.v_dim,
+                                    c.kv_rank, c.rope_theta, c.eps, c.dtype,
+                                    c.qk_norm, c.out_gate, name="attn")(a)
+            elif self.mixer == "delta":
+                a = DeltaAttention(c.heads, c.delta_head_dim, c.delta_conv,
+                                   c.delta_lower_bound, c.eps, c.dtype,
+                                   name="delta")(a)
+            elif self.mixer == "ssd":
+                a = Mamba2Mixer(c.ssd_heads, c.ssd_head_dim, c.ssd_state,
+                                c.ssd_conv, c.ssd_chunk, c.eps, c.dtype,
+                                name="ssd")(a, train)
+            elif self.mixer == "cca":
+                a = CompressedConvAttention(c.heads, c.kv_heads, c.v_dim,
+                                            c.rope, c.rope_theta, c.cca_conv,
+                                            c.dtype, name="attn")(a)
+            elif self.mixer == "window":
+                a = GroupedAttention(c.window_heads, c.kv_heads, c.v_dim,
+                                     c.v_dim, c.window_rope_theta,
+                                     window=c.window, gate=c.out_gate,
+                                     dtype=c.dtype, name="attn")(a)
+            else:
+                yarn, scale = None, 1.0
+                if c.yarn_factor:
+                    scale = c.yarn_attention_factor
+                    yarn = tuple(yarn_frequencies(
+                        c.rope, c.rope_theta, c.yarn_factor, c.yarn_original,
+                        c.yarn_beta_fast, c.yarn_beta_slow).tolist())
+                a = GroupedAttention(c.heads, c.kv_heads, c.v_dim, c.rope,
+                                     c.rope_theta, yarn, scale,
+                                     gate=c.out_gate, dtype=c.dtype,
+                                     scale=c.attn_scale, name="attn")(a)
+            return a
+
+        if self.mixer != "none":
+            a = RMSNorm(c.eps, c.dtype, name="attn_norm")(h)
+            h = add(h, mix(a), "attn_merge")
+        if self.mlp == "none":
+            return h, carry
         m = RMSNorm(c.eps, c.dtype, name="mlp_norm")(h)
-        if self.sparse:
+        if self.mlp == "sparse":
             m, carry = SharedRoutedMoe(
                 c.n_routed, c.top_k, c.expert_width, c.n_shared,
                 c.routed_scaling, c.held_first, c.held_count, c.dtype,
                 c.n_group, c.topk_group, c.score, c.router_hidden, c.eps,
-                c.balance_rate, name="mlp")(m, train, carry)
+                c.balance_rate, c.mlp_form, c.moe_latent, c.shared_width,
+                name="mlp")(m, train, carry)
         else:
             with jax.named_scope(SCOPE_LM_DENSE):
-                m = SwiGLU(c.dense_width, c.dtype, name="mlp")(m)
+                m = MLP_FORMS[c.mlp_form](c.dense_width, c.dtype,
+                                          name="mlp")(m)
         return add(h, m, "mlp_merge"), carry
 
 
 class LatentMoeLM(nn.Module):
     """Decoder-only LM of blocks with sparse experts: an embedding,
-    ``layers`` blocks (the first ``first_dense`` with a dense MLP; layer
-    ``i``'s mixer is ``mixers[i]``, latent attention where none is named),
+    ``layers`` blocks (layer ``i``'s mixer is ``mixers[i]``, latent
+    attention where none is named, its MLP ``mlps[i]``, where none is named
+    dense in the first ``first_dense`` layers and sparse after; a layer may
+    be one of the two alone),
     a final RMSNorm and a head of its own, or, ``tied_head``, the
     embedding's table again; no learned positions. Beside ``h`` the blocks
     hand on what a sparse layer's router gives the next one (None where no
@@ -764,14 +855,21 @@ class LatentMoeLM(nn.Module):
         block = (nn.remat(LatentMoeBlock, static_argnums=(2,)) if c.remat
                  else LatentMoeBlock)
         mixers = c.mixers or ("latent",) * c.layers
-        if len(mixers) != c.layers or set(mixers) - {"latent", "delta", "ssd",
-                                                     "full", "window", "cca"}:
+        if len(mixers) != c.layers or set(mixers) - {
+                "latent", "delta", "ssd", "full", "window", "cca", "none"}:
             raise ValueError(f"mixers {mixers}: one of 'latent' / 'delta' / "
-                             f"'ssd' / 'full' / 'window' / 'cca' for each of "
-                             f"the {c.layers} layers")
+                             f"'ssd' / 'full' / 'window' / 'cca' / 'none' for "
+                             f"each of the {c.layers} layers")
+        mlps = c.mlps or tuple("sparse" if i >= c.first_dense else "dense"
+                               for i in range(c.layers))
+        if (len(mlps) != c.layers or set(mlps) - {"dense", "sparse", "none"}
+                or ("none", "none") in zip(mixers, mlps)):
+            raise ValueError(f"mlps {mlps}: one of 'dense' / 'sparse' / "
+                             f"'none' for each of the {c.layers} layers, and "
+                             f"no layer without a mixer and without an MLP")
         carry = None
         for i in range(c.layers):
-            h, carry = block(c, i >= c.first_dense, mixers[i],
+            h, carry = block(c, mlps[i], mixers[i],
                              name=f"layer_{i}")(h, train, carry)
         h = RMSNorm(c.eps, c.dtype, name="final_norm")(h)
         with jax.named_scope(SCOPE_LM_DENSE):
@@ -789,8 +887,9 @@ def layer_counters(variables: dict) -> dict:
     training step since the variables were seeded, where the packed
     simulation round trained them: a sparse layer's ``rows.<layer>.<expert>``
     and ``steps.<layer>`` (and ``group_tokens.<layer>`` under a
-    group-limited router, ``skipped.<layer>`` where a choice is no expert),
-    a state-space mixer's ``decay.<layer>`` (its mean
+    group-limited router, ``skipped.<layer>`` where a choice is no expert,
+    ``live_units.<layer>`` where the experts' form counts its live hidden
+    units), a state-space mixer's ``decay.<layer>`` (its mean
     ``exp(dt A)`` a step, summed) and ``steps.<layer>``. A model whose layers
     keep none gives ``{}``."""
     out = {}
@@ -804,7 +903,7 @@ def layer_counters(variables: dict) -> dict:
         for e, rows in enumerate(jax.device_get(mlp["expert_rows"])):
             out[f"rows.{layer}.{e}"] = float(rows)
         out[f"steps.{layer}"] = float(jax.device_get(mlp["steps"]))
-        for extra in ("group_tokens", "skipped"):
+        for extra in ("group_tokens", "skipped", "live_units"):
             if extra in mlp:
                 out[f"{extra}.{layer}"] = float(jax.device_get(mlp[extra]))
     return out
@@ -877,6 +976,35 @@ LATENT_MOE_PRESETS = {
         eps=1e-5, held_first=0, held_count=8, seq_len=4096,
         mixers=["cca"] * 6, kv_heads=2, cca_conv=[2, 2], router_hidden=256,
         balance_rate=130.0, scaled_residual=True, tied_head=True),
+    # one of 64 chips' share of the first eleven layers (one pipeline stage of
+    # eight, a whole period ``MEMEMEM*EME``) of
+    # nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16, each layer ONE sub-layer:
+    # a Mamba-2 mixer (one of its 8 groups, 16 heads: the mixers are
+    # tensor-parallel over 8 chips), position-free attention (4 query heads
+    # over 1 key-value head) or a sparse MLP of squared-ReLU experts in a
+    # latent of 1,024 (8 of 512 held, 22 a token) beside a shared MLP of
+    # 5,376, an eighth of the untied table's rows
+    # (``benchmarks/configs/nemotron3_super_120b.json``, held equal by a test)
+    "nemotron3_super": dict(
+        dim=4096, heads=4, nope=128, rope=0, v_dim=128, kv_rank=0, layers=11,
+        first_dense=0, dense_width=0, n_routed=512, top_k=22,
+        expert_width=2688, n_shared=1, routed_scaling=5.0, rope_theta=1e4,
+        eps=1e-5, held_first=0, held_count=8, seq_len=4096,
+        mixers=["ssd", "none", "ssd", "none", "ssd", "none", "ssd", "full",
+                "none", "ssd", "none"],
+        mlps=["none", "sparse", "none", "sparse", "none", "sparse", "none",
+              "none", "sparse", "none", "sparse"],
+        kv_heads=1, ssd_heads=16, ssd_head_dim=64, ssd_state=128, ssd_conv=4,
+        ssd_chunk=256, mlp_form="relu2", moe_latent=1024, shared_width=5376),
+    "nemotron3_super_tiny": dict(
+        dim=32, heads=2, nope=16, rope=0, v_dim=16, kv_rank=0, layers=4,
+        first_dense=0, dense_width=0, n_routed=16, top_k=4, expert_width=24,
+        n_shared=1, routed_scaling=5.0, rope_theta=1e4, eps=1e-5,
+        held_first=0, held_count=4, seq_len=32,
+        mixers=["ssd", "none", "full", "none"],
+        mlps=["none", "sparse", "none", "sparse"], kv_heads=1, ssd_heads=8,
+        ssd_head_dim=8, ssd_state=16, ssd_conv=4, ssd_chunk=8,
+        mlp_form="relu2", moe_latent=16, shared_width=48),
     "zaya1_tiny": dict(
         dim=32, heads=4, nope=4, rope=4, v_dim=8, kv_rank=0, layers=3,
         first_dense=0, dense_width=0, n_routed=16, top_k=1, expert_width=32,
@@ -921,7 +1049,7 @@ LATENT_MOE_PRESETS = {
 def _latent_moe_bundle(name: str, output_dim: int, **kw) -> ModelBundle:
     sizes = {**LATENT_MOE_PRESETS[name], **kw}
     seq_len = sizes.pop("seq_len")
-    for key in ("mixers", "cca_conv"):
+    for key in ("mixers", "mlps", "cca_conv"):
         if key in sizes:
             sizes[key] = tuple(sizes[key])
     module = LatentMoeLM(vocab_size=output_dim, sizes=LatentMoeSizes(**sizes))
@@ -955,6 +1083,16 @@ def _granite4h(output_dim: int = 12544, **kw):
 @register_model("zaya1_8b")
 def _zaya1(output_dim: int = 32784, **kw):
     return _latent_moe_bundle("zaya1_8b", output_dim or 32784, **kw)
+
+
+@register_model("nemotron3_super")
+def _nemotron3s(output_dim: int = 16384, **kw):
+    return _latent_moe_bundle("nemotron3_super", output_dim or 16384, **kw)
+
+
+@register_model("nemotron3_super_tiny")
+def _nemotron3s_tiny(output_dim: int = 64, **kw):
+    return _latent_moe_bundle("nemotron3_super_tiny", output_dim or 64, **kw)
 
 
 @register_model("zaya1_tiny")

@@ -230,16 +230,43 @@ class RMSNorm(nn.Module):
 
 
 class SwiGLU(nn.Module):
-    """``down(silu(gate(x)) * up(x))``."""
+    """``down(silu(gate(x)) * up(x))``. ``stats``: -> ``(y, ())``, the
+    form's statistics beside the result, as every entry of
+    :data:`MLP_FORMS` gives them (this form keeps none)."""
 
     width: int
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, stats: bool = False):
         g = Linear(self.width, self.dtype, name="gate")(x)
         u = Linear(self.width, self.dtype, name="up")(x)
-        return Linear(x.shape[-1], self.dtype, name="down")(nn.silu(g) * u)
+        y = Linear(x.shape[-1], self.dtype, name="down")(nn.silu(g) * u)
+        return (y, ()) if stats else y
+
+
+class Relu2Mlp(nn.Module):
+    """``down(relu(up(x))^2)``: an MLP of two matrices, no gate (the
+    NemotronH family's ``relu2``). ``stats``: -> ``(y, (live,))``, the
+    number of hidden units that are positive before the square (float32, no
+    gradient): what a kernel that skips dead units would have to compute."""
+
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, stats: bool = False):
+        u = Linear(self.width, self.dtype, name="up")(x)
+        y = Linear(x.shape[-1], self.dtype, name="down")(
+            jnp.square(nn.relu(u)))
+        return (y, (jnp.sum(u > 0, dtype=jnp.float32),)) if stats else y
+
+
+#: an MLP's form by name: gated with SiLU (three matrices) or a squared
+#: ReLU between two. Called with ``stats=True`` each gives ``(y, stats)``,
+#: the same tuple of statistics as the form's entry in
+#: ``moe.EXPERT_FORMS`` gives for an expert's rows
+MLP_FORMS = {"swiglu": SwiGLU, "relu2": Relu2Mlp}
 
 
 def yarn_frequencies(r: int, theta: float, factor: float, original: int,

@@ -119,6 +119,9 @@ SCOPE_LM_CCA_MIX = "fedml.lm.cca_mix"
 SCOPE_LM_ROUTE = "fedml.lm.route"
 #: the grouped matmuls over the rows of the experts held here
 SCOPE_LM_EXPERTS = "fedml.lm.experts"
+#: a sparse layer's two projections around its routed experts, into their
+#: latent and back (``SharedRoutedMoe.latent``); the backward keeps it
+SCOPE_LM_LATENT = "fedml.lm.latent_proj"
 #: every other matmul: attention projections, shared experts, dense MLP, head
 SCOPE_LM_DENSE = "fedml.lm.dense"
 #: next-token cross-entropy over the logits

@@ -274,13 +274,13 @@ def test_a_rung_too_small_would_lose_rows(monkeypatch):
     mod, variables, x = _steered_layer([33])
     seen = []
 
-    def spy(rungs, *operands):
+    def spy(rungs, form, *operands):
         seen.append(operands)
-        return moe._rung(rungs[-1])(*operands)
+        return moe._rung(rungs[-1], form)(*operands)
 
     monkeypatch.setattr(moe, "routed_rows", spy)
     mod.apply(variables, x[0])
-    full, small, fits = (moe._rung(c)(*seen[0]) for c in (64, 32, 48))
+    full, small, fits = (moe._rung(c)(*seen[0])[0] for c in (64, 32, 48))
     np.testing.assert_allclose(fits, full, atol=1e-6)
     assert float(jnp.abs(small - full).max()) > 1e-3
 
@@ -408,7 +408,8 @@ def test_token_ids_reach_the_model_unrounded(dtype, kind):
     ("BENCHMARK.tiny_hybrid.json", "tiny_ling3_sim"),
     ("BENCHMARK.tiny_laguna.json", "tiny_laguna_sim"),
     ("BENCHMARK.tiny_granite4h.json", "tiny_granite4h_sim"),
-    ("BENCHMARK.tiny_zaya1.json", "tiny_zaya1_sim")])
+    ("BENCHMARK.tiny_zaya1.json", "tiny_zaya1_sim"),
+    ("BENCHMARK.tiny_nemotron3s.json", "tiny_nemotron3s_sim")])
 def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
     """An LM's round program carries the step's scopes and the
     ``fedml.lm.*`` names of what it is built of, as metadata only: all of
@@ -450,6 +451,8 @@ def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
     if "cca" not in mixers:
         table -= {tracer.SCOPE_LM_CCA_MIX}
     sizes = config["model"]
+    if not sizes.get("moe_latent"):
+        table -= {tracer.SCOPE_LM_LATENT}
     if sizes["first_dense"] == sizes["layers"]:
         table -= {tracer.SCOPE_LM_ROUTE, tracer.SCOPE_LM_EXPERTS}
     assert found == table
@@ -473,10 +476,14 @@ def test_lowered_lm_round_program_names_every_scope(fixture, cell_name):
     conds = [c for c in re.findall(
         r'"stablehlo\.case"\(.*?\n +\}\) :', text, re.S) if "@rung" in c]
     assert len(conds) == cases - 2
-    # (a scaled residual's gradient reads the branch it scales, so there
-    # the replay's conditional is live too)
-    passes = 3 if sizes.get("scaled_residual") else 2
-    assert len(conds) == passes * (sizes["layers"] - sizes["first_dense"])
+    # (a scaled residual's gradient reads the branch it scales, and the
+    # gradient of the projection out of a latent reads the routed sum it
+    # projects, so there the replay's conditional is live too)
+    passes = (3 if sizes.get("scaled_residual") or sizes.get("moe_latent")
+              else 2)
+    sparse = (list(sizes["mlps"]).count("sparse") if sizes.get("mlps")
+              else sizes["layers"] - sizes["first_dense"])
+    assert len(conds) == passes * sparse
     for cond in conds:
         assert cond.count("func.call @rung") == len(rungs) == cond.count(
             "stablehlo.return")

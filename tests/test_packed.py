@@ -249,7 +249,9 @@ LANE_SIDE = {
     "kanana2_30b_a3b": "wide", "kanana2_tiny": "wide",
     "laguna_tiny": "wide", "laguna_xs2": "wide",
     "ling3_flash_vl": "wide", "ling3_tiny": "wide", "lr": "wide",
-    "mobilenet": "narrow", "mobilenet_v3": "narrow", "resnet110": "narrow",
+    "mobilenet": "narrow", "mobilenet_v3": "narrow",
+    "nemotron3_super": "wide", "nemotron3_super_tiny": "wide",
+    "resnet110": "narrow",
     "resnet18_gn": "narrow", "resnet20": "narrow", "resnet56": "narrow",
     "resnet56_nonorm": "narrow", "resnet56_w128": "wide",
     "resnet56_w64": "narrow", "rnn": "wide", "rnn_stackoverflow": "wide",

@@ -171,7 +171,7 @@ def test_row_capacities_compile_as_one_conditional_a_pass(one_chip, no_cache):
 
     def step(*ops):
         return jax.grad(lambda xf, weights, *w: jnp.sum(moe.routed_rows(
-            rungs, xf, *ops[1:5], weights, *w) ** 2), argnums=(0, 1, 2, 3, 4))(
+            rungs, "swiglu", xf, *ops[1:5], weights, *w)[0] ** 2), argnums=(0, 1, 2, 3, 4))(
                 ops[0], *ops[5:])
 
     compiled = jax.jit(step).lower(*operands).compile()
@@ -541,3 +541,132 @@ def test_flagged_passes_leave_the_loop_carry_as_the_device_keeps_it(
         assert row_major > 0 and temporaries >= 2 * one_copy
     else:
         assert row_major == 0 and temporaries < one_copy
+
+
+# -- one of 64 chips' share of the hybrid state-space / latent-MoE decoder
+#    (nemotron3_super, PR 44): shapes no cell had run ---------------------------
+
+def test_position_free_attention_compiles_at_four_heads_over_one(one_chip,
+                                                                 no_cache):
+    """4 query heads over ONE key-value head of 128 channels (a chip's
+    eighth of 32 over 2), 4,096 positions, one sequence, no rotary: the
+    forward kernel and the one backward kernel, ``H`` 4 and ``G`` 1."""
+    import importlib
+
+    att = importlib.import_module("fedml_tpu.ops.attention")
+
+    def step(q, k, v, c):
+        return jax.grad(lambda q, k, v: jnp.sum(att.attention(
+            q, k, v, impl="pallas", block_q=1024, block_k=1024).astype(
+                jnp.float32) * c), argnums=(0, 1, 2))(q, k, v)
+
+    def sd(h):
+        return jax.ShapeDtypeStruct((1, h, 4096, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(step).lower(sd(4), sd(1), sd(1), sd(4)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+    _dq, dk, dv = jax.eval_shape(step, sd(4), sd(1), sd(1), sd(4))
+    assert dk.shape == dv.shape == (1, 1, 4096, 128)
+
+
+def test_state_space_mixer_compiles_at_a_groups_share(one_chip, no_cache):
+    """One group's 16 heads of 64 over a state of 128 at hidden 4,096: a
+    joint projection of 2,320 columns (1,024 + 1,024 + 256 + 16), issued as
+    a product a consumer as at 8,512: no array of all 2,320 columns but the
+    ONE kernel and its gradient."""
+    from fedml_tpu.models.transformer import Mamba2Mixer
+
+    mixer = Mamba2Mixer(16, 64, 128, eps=1e-5, dtype=jnp.bfloat16)
+    u = jax.ShapeDtypeStruct((1, 4096, 4096), jnp.bfloat16, sharding=one_chip)
+    v = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.key(0), u))
+
+    def step(v, u, c):
+        return jax.grad(lambda p, u: jnp.sum(mixer.apply(
+            {**v, "params": p}, u).astype(jnp.float32) * c), argnums=(0, 1))(
+                v["params"], u)
+
+    compiled = jax.jit(step).lower(v, u, u).compile()
+    text = compiled.as_text()
+    wide = set(re.findall(r" = \(?(\w+\[[\d,]*2320\])", text))
+    assert wide and all(shape.endswith("[4096,2320]") for shape in wide), wide
+    assert " while(" in text and "tpu_custom_call" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    gp, gu = jax.eval_shape(step, v, u, u)
+    assert gp["in_proj"]["kernel"].shape == (4096, 2320)
+    assert gp["out_proj"]["kernel"].shape == (1024, 4096)
+    assert gu.shape == (1, 4096, 4096)
+
+
+def test_latent_sparse_layer_compiles_at_published_widths(one_chip, no_cache):
+    """The sparse sub-layer at the published widths on 4,096 tokens: a
+    router of 512 outputs and 22 choices (90,112 pairs), 8 held experts of
+    TWO matrices ``[1024, 2688]`` / ``[2688, 1024]`` in a latent between two
+    projections, a squared-ReLU shared MLP of 5,376 on the full width: one
+    conditional a pass over the four row capacities, two grouped matmuls a
+    branch and pass direction (no third matrix), the rows that move 1,024
+    wide."""
+    from fedml_tpu.models import moe
+
+    layer = moe.SharedRoutedMoe(512, 22, 2688, 1, 5.0, 0, 8, jnp.bfloat16,
+                                eps=1e-5, form="relu2", latent=1024,
+                                shared_width=5376)
+    x = jax.ShapeDtypeStruct((1, 4096, 4096), jnp.bfloat16, sharding=one_chip)
+    v = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.key(0), x))
+    p = v["params"]
+    assert set(p) == {"shared", "latent_in", "latent_out", "router",
+                      "e_score_correction_bias", "up", "down"}
+    assert p["up"].shape == (8, 1024, 2688) and p["down"].shape == (8, 2688, 1024)
+    assert p["shared"]["up"]["kernel"].shape == (4096, 5376)
+    assert set(v["counters"]) == {"expert_rows", "steps", "live_units"}
+
+    def step(v, x, c):
+        def loss(p, x):
+            out, _ = layer.apply({**v, "params": p}, x)
+            return jnp.sum((out.astype(jnp.float32) * c) ** 2)
+        return jax.grad(loss, argnums=(0, 1))(v["params"], x)
+
+    compiled = jax.jit(step).lower(v, x, x).compile()
+    text = compiled.as_text()
+    rungs = moe.row_rungs(4096 * 22)
+    assert rungs == (11264, 22528, 45056, 90112)
+    assert text.count(" conditional(") == 2
+    for c in rungs:
+        assert f"moe_rows_{c}/" in text
+    # the first capacity's rows are [11264, 1024]: a quarter of the bytes a
+    # row of the model's width would move
+    assert "bf16[11264,1024]" in text and "bf16[11264,4096]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
+    gp, gx = jax.eval_shape(step, v, x, x)
+    assert gp["latent_in"]["kernel"].shape == (4096, 1024)
+    assert gx.shape == (1, 4096, 4096)
+
+
+#: float32 shapes of the nemotron3_super tree beside ``KEPT_SHAPES``
+KEPT_SHAPES_LATENT = [
+    (4096, 2320), (1024, 4096), (4, 1280), (1280,), (16,), (4096, 512),
+    (512,), (4096, 128), (512, 4096), (4096, 1024), (4096, 5376),
+    (5376, 4096), (8, 1024, 2688), (8, 2688, 1024), (16384, 4096),
+    (4096, 16384), (4096,)]
+
+
+def test_kept_transposed_is_the_compilers_layout_for_the_latent_tree(
+        one_chip, no_cache):
+    """``_kept_transposed`` against the compiler's own argument layouts for
+    every leaf shape of the one-sub-layer decoder's share (the one-lane
+    round's branches are told the layout by it: ``packed._on_flag``)."""
+    from fedml_tpu.parallel.packed import _kept_transposed
+
+    compiled = jax.jit(lambda *xs: [x + 1 for x in xs]).lower(*(
+        jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+        for s in KEPT_SHAPES_LATENT)).compile()
+    for shape, fmt in zip(KEPT_SHAPES_LATENT, compiled.input_formats[0]):
+        order = tuple(fmt.layout.major_to_minor)
+        plain = tuple(range(len(shape)))
+        swapped = plain[:-2] + plain[-2:][::-1]
+        assert order == (swapped if _kept_transposed(shape) else plain), shape

@@ -1342,7 +1342,7 @@ def test_lowered_round_program_names_every_scope(kw, missing):
     # the parts of a decoder LM's step are named by LM programs only
     # (tests/test_latent_moe.py holds those)
     lm = {v for k, v in vars(tracer).items() if k.startswith("SCOPE_LM_")}
-    assert len(table) == 20 and len(lm) == 11
+    assert len(table) == 21 and len(lm) == 12
     import re
     found = set(re.findall(r"fedml\.[a-z_.]+", text))
     assert found == table - lm - missing

@@ -149,7 +149,8 @@ def measure(operands, capacity: int, iters: int, trace: bool = True) -> dict:
         "add_back_gather": (jax.jit(add_back_gather), (y, inv, head, wt)),
         "add_back_scatter": (jax.jit(add_back_scatter), (y, head, wt)),
         "rung_fwd": (moe._rung(capacity), (*operands[:6], *wb)),
-        "rung_bwd": (moe._rung_vjp(capacity), ((*operands[:6], *wb), ct)),
+        "rung_bwd": (moe._rung_vjp(capacity), ((*operands[:6], *wb),
+                                               (ct, ()))),
     }
     row = {"capacity": capacity, "rows_filled": int(jnp.sum(sizes))}
     for name, (fn, args) in parts.items():

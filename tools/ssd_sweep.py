@@ -4,7 +4,10 @@ the state-space LM cell's shapes (``x [1, 4096, 64, 64]``, ``B``, ``C [1,
 4096, 128]``: one sequence, 64 heads of 64 over a state of 128), at each
 chunk size named.
 
-    python tools/ssd_sweep.py [chunk ...]
+    python tools/ssd_sweep.py [--heads H] [chunk ...]
+
+``--heads 16`` is a group's share of heads (one of 64 chips' share of
+Nemotron-3-Super's mixer: ``PERF.md``, PR 44).
 
 For each chunk: wall-clock ms of the forward and of forward + backward
 (``ops/ssd.ssd_chunked``, jitted alone, the module's bfloat16 operands), the
@@ -24,6 +27,7 @@ Fails at once without a TPU. Writes ``chiprun_out/ssd_sweep.json``;
 
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import sys
@@ -32,7 +36,7 @@ import time
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
 
-B, T, H, P, N = 1, 4096, 64, 64, 128
+B, T, P, N = 1, 4096, 64, 128
 GRAD_HEADS = 8
 NAMES = ("x", "dt", "A_log", "B", "C", "D")
 
@@ -55,8 +59,11 @@ def main(argv=None) -> int:
     from fedml_tpu.models.transformer import log_uniform_steps
     from fedml_tpu.ops import ssd
 
-    chunks = [int(a) for a in (argv if argv is not None else sys.argv[1:])
-              ] or [64, 128, 256]
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--heads", type=int, default=64)
+    ap.add_argument("chunks", type=int, nargs="*", default=[64, 128, 256])
+    opts = ap.parse_args(argv)
+    H, chunks = opts.heads, opts.chunks
     if jax.devices()[0].platform != "tpu":
         print("ssd_sweep: needs a TPU", file=sys.stderr)
         return 3
