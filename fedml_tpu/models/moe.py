@@ -283,8 +283,8 @@ def _rung(capacity: int, form: str = "swiglu"):
     """The held experts' part of a sparse layer over the first ``capacity``
     sorted row slots: ``(xf [n, d], order [k*n], inv [k*n], sizes [held],
     mine [k, n], weights [n, k], *w) -> ([n, d] float32, stats)`` (``w``:
-    the matrices of the experts' ``form`` in ``xf``'s dtype, ``stats`` its
-    statistics: :data:`EXPERT_FORMS`). Exact whenever
+    the matrices of the experts' ``form``, the parameters as they are,
+    ``stats`` its statistics: :data:`EXPERT_FORMS`). Exact whenever
     ``sum(sizes) <= capacity``: a slot past the last group goes in and comes
     out as zeros (so that nothing a kernel leaves there, and no cotangent of
     it, reaches a token), and a pair whose slot was not kept reads a zero
@@ -318,26 +318,17 @@ def _rung(capacity: int, form: str = "swiglu"):
 def _rung_vjp(capacity: int, form: str = "swiglu"):
     """``(operands, ct) ->`` the cotangents of ``_rung(capacity, form)``'s
     floating operands, from a forward rebuilt at that capacity; the experts'
-    weights' in float32, what the parameters they were cast from take."""
+    weights' in the parameters' own dtype, as the grouped matmul leaves
+    them."""
 
     def rung_vjp(operands, ct):
         xf, order, inv, sizes, mine, weights, *w = operands
         _, vjp = jax.vjp(
             lambda xf, weights, *w: _rung(capacity, form)(
                 xf, order, inv, sizes, mine, weights, *w), xf, weights, *w)
-        dx, dweights, *dw = vjp(ct)
-        with jax.named_scope(SCOPE_LM_EXPERTS):
-            return (dx, dweights, *(g.astype(jnp.float32) for g in dw))
+        return vjp(ct)
 
     return jax.jit(rung_vjp)
-
-
-def _cast_experts(w, dtype):
-    """The experts' weights (float32 parameters) in the rows' dtype, OUTSIDE
-    the switch: XLA fuses the cast into whatever made the parameters, where
-    a branch's operand would have to be written out."""
-    with jax.named_scope(SCOPE_LM_EXPERTS):
-        return [a.astype(dtype) for a in w]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -345,9 +336,10 @@ def routed_rows(rungs: tuple, form: str, xf, order, inv, sizes, mine, weights,
                 *w) -> tuple:
     """``_rung(C, form)`` at the first ``C`` of ``rungs`` that holds the rows
     ``sizes`` counts, on a rung's operands (``w``: the experts' matrices,
-    still float32 parameters): a ``lax.switch`` on the router's
-    own count, no row dropped (the last rung is every pair). The backward
-    pass saves the operands alone, switches on the same index and rebuilds
+    the float32 parameters as they are: the grouped matmul rounds the tile
+    it multiplies, ``ops/grouped_matmul.py``): a ``lax.switch`` on the
+    router's own count, no row dropped (the last rung is every pair). The
+    backward pass saves the operands alone, switches on the same index and rebuilds
     that rung's forward: a plain ``lax.switch`` would save the union of
     every rung's residuals, 1.9 times the full capacity's. Under a ``vmap``
     (packed lanes) the index is batched and JAX runs every rung and
@@ -355,7 +347,7 @@ def routed_rows(rungs: tuple, form: str, xf, order, inv, sizes, mine, weights,
     matmul there anyway). -> ``([n, d] float32, the form's statistics)``."""
     return jax.lax.switch(
         _rung_index(rungs, sizes), [_rung(c, form) for c in rungs],
-        xf, order, inv, sizes, mine, weights, *_cast_experts(w, xf.dtype))
+        xf, order, inv, sizes, mine, weights, *w)
 
 
 def _routed_fwd(rungs, form, *operands):
@@ -363,12 +355,10 @@ def _routed_fwd(rungs, form, *operands):
 
 
 def _routed_bwd(rungs, form, operands, ct):
-    xf, order, inv, sizes, mine, weights, *w = operands
     with jax.named_scope(SCOPE_LM_ROUTE):
         dx, dweights, *dw = jax.lax.switch(
-            _rung_index(rungs, sizes), [_rung_vjp(c, form) for c in rungs],
-            (xf, order, inv, sizes, mine, weights,
-             *_cast_experts(w, xf.dtype)), ct)
+            _rung_index(rungs, operands[3]),
+            [_rung_vjp(c, form) for c in rungs], operands, ct)
     return (dx, None, None, None, None, dweights, *dw)
 
 

@@ -8,8 +8,10 @@ implementations:
   softmax over K/V blocks, MXU-shaped matmuls, partial (o, m, l) outputs so
   sequence-parallel ring attention can merge chunks across devices.
 - :mod:`fedml_tpu.ops.grouped_matmul` — the sparse-expert layer's grouped
-  matmul over rows sorted by expert (``lax.ragged_dot``) and the row moves
-  around it, gathers in both directions.
+  matmul over rows sorted by expert (three kernels that read the experts'
+  float32 matrices themselves and cast the tile they hold;
+  ``lax.ragged_dot`` off the TPU) and the row moves around it, gathers in
+  both directions.
 - :mod:`fedml_tpu.ops.kda` — the delta rule with a per-channel decay (a
   linear-attention layer's recurrence) in chunks: a kernel pair, forward
   and backward, that makes a chunk's operands itself and keeps a head's

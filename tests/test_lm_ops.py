@@ -15,40 +15,118 @@ from fedml_tpu.ops.grouped_matmul import (embed_rows, fan_out_rows,
 
 # --- the grouped matmul -----------------------------------------------------
 
-@pytest.mark.parametrize("sizes", [
-    (4, 4, 4, 4), (0, 16, 0, 0), (16, 0, 0, 0), (0, 0, 0, 16), (3, 0, 9, 1),
-    (0, 0, 0, 0), (1, 2, 3, 4)])
-def test_grouped_matmul_matches_the_per_expert_loop(sizes):
+def _per_expert_loop(x, w, gs):
+    ends = jnp.cumsum(gs)
+    row = jnp.arange(x.shape[0])[:, None]
+    return sum(jnp.where((row >= ends[g] - gs[g]) & (row < ends[g]),
+                         jnp.dot(x, w[g], precision="highest"), 0)
+               for g in range(w.shape[0]))
+
+
+#: (rows, K, N) of the interpreted kernels' cases, None for the plain path's
+#: (16 float32 rows of 8 against 4 experts of 8 x 6), and the groups' sizes
+GROUPED_CASES = [
+    (None, (4, 4, 4, 4)), (None, (0, 16, 0, 0)), (None, (16, 0, 0, 0)),
+    (None, (0, 0, 0, 16)), (None, (3, 0, 9, 1)), (None, (0, 0, 0, 0)),
+    (None, (1, 2, 3, 4)),
+    # bf16 rows, float32 weights, the kernels interpreted (row tile 128):
+    ((512, 256, 256), (100, 37, 200, 150)),     # edges inside the tiles
+    ((512, 256, 256), (0, 130, 120, 250)),      # an empty group first
+    ((512, 256, 256), (130, 0, 120, 250)),      # ... in the middle
+    ((512, 256, 256), (130, 120, 250, 0)),      # ... last
+    ((512, 256, 256), (0, 0, 512, 0)),          # every row in ONE group
+    ((512, 256, 256), (0, 481, 0, 0)),          # an edge off the row tile
+    ((512, 256, 256), (128, 128, 128, 128)),    # edges ON the row tile
+    ((512, 256, 256), (0, 0, 0, 0)),            # no row at all
+    ((512, 256, 256), (60, 3, 1, 70)),          # most rows past the last
+    ((1024, 128, 256), (700, 20, 300, 4)),      # the tile follows the shape
+    ((256, 128, 384), (90, 100, 0, 50)),        # the relu2 pair: up,
+    ((256, 384, 128), (90, 100, 0, 50)),        # and down (panels of 384)
+]
+
+
+@pytest.mark.parametrize("shape,sizes", GROUPED_CASES)
+def test_grouped_matmul_matches_the_per_expert_loop(monkeypatch, shape, sizes):
     """Empty experts, one expert with every row, rows that belong to none:
-    forward and both gradients."""
+    forward and both gradients. The plain path on float32 operands, and the
+    kernels (interpreted) on bf16 rows and float32 weights against the loop
+    on the weights rounded to bf16: the output and ``d_rows`` in the rows'
+    dtype, ``d_w`` the float32 accumulator unrounded, rows past the last
+    group zeros going out and without effect on ``d_w`` whatever they
+    hold."""
+    import fedml_tpu.ops.grouped_matmul as gm
+    kernels = shape is not None
+    (m, k, n), dtype = shape or (16, 8, 6), jnp.bfloat16 if kernels else jnp.float32
     k1, k2, k3 = jax.random.split(jax.random.key(sum(sizes) + len(sizes)), 3)
-    x = jax.random.normal(k1, (16, 8), jnp.float32)
-    w = jax.random.normal(k2, (4, 8, 6), jnp.float32)
-    c = jax.random.normal(k3, (16, 6), jnp.float32)
+    x = jax.random.normal(k1, (m, k), jnp.float32).astype(dtype)
+    w = jax.random.normal(k2, (4, k, n), jnp.float32)
+    c = jax.random.normal(k3, (m, n), jnp.float32).astype(dtype)
     gs = jnp.asarray(sizes, jnp.int32)
+    live = (jnp.arange(m) < sum(sizes))[:, None]
+    if kernels:
+        monkeypatch.setattr(gm, "_pick_impl", lambda impl: "pallas")
+        assert gm._tiles(m, k, n, 4, 2, 4) == (256 if m == 1024 else 128)
+        # what a row of no group holds must not matter
+        x, c = jnp.where(live, x, jnp.nan), jnp.where(live, c, jnp.nan)
 
-    def loop(x, w, gs):
-        ends = jnp.cumsum(gs)
-        row = jnp.arange(x.shape[0])[:, None]
-        return sum(jnp.where((row >= ends[g] - gs[g]) & (row < ends[g]),
-                             x @ w[g], 0) for g in range(w.shape[0]))
+    def run(fn, x, w, c):
+        return jax.value_and_grad(lambda x, w: jnp.sum(
+            fn(x, w, gs).astype(jnp.float32) * c), argnums=(0, 1))(x, w)
 
-    def run(fn):
-        return jax.value_and_grad(
-            lambda x, w: jnp.sum(fn(x, w, gs) * c), argnums=(0, 1))(x, w)
-
-    (a, (dxa, dwa)), (b, (dxb, dwb)) = run(grouped_matmul), run(loop)
-    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(dxa, dxb, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(dwa, dwb, rtol=1e-5, atol=1e-5)
-    out = np.asarray(grouped_matmul(x, w, gs))
+    a, (dxa, dwa) = run(grouped_matmul, x, w, jnp.where(live, c, 0))
+    assert dxa.dtype == dtype and dwa.dtype == jnp.float32
+    # the loop in float32 on the values the product sees, d_w unrounded
+    seen = (jnp.where(live, x, 0).astype(jnp.float32),
+            w.astype(dtype).astype(jnp.float32),
+            jnp.where(live, c, 0).astype(jnp.float32))
+    b, (dxb, dwb) = run(lambda *o: _per_expert_loop(*o).astype(dtype), *seen)
+    tol = dict(rtol=2e-2, atol=2e-2) if kernels else dict(rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(a, b, rtol=tol["rtol"])
+    np.testing.assert_allclose(dxa.astype(jnp.float32), dxb, **tol)
+    np.testing.assert_allclose(dwa, dwb, rtol=1e-5, atol=1e-4)
+    out = np.asarray(grouped_matmul(x, w, gs).astype(jnp.float32))
     assert not out[sum(sizes):].any()           # rows of no group are zero
+    assert not np.asarray(dxa.astype(jnp.float32))[sum(sizes):].any()
     start = 0
-    for g, n in enumerate(sizes):
-        np.testing.assert_allclose(out[start:start + n],
-                                   np.asarray(x[start:start + n] @ w[g]),
-                                   rtol=1e-5, atol=1e-5)
-        start += n
+    for g, rows in enumerate(sizes):
+        np.testing.assert_allclose(
+            out[start:start + rows],
+            np.asarray(jnp.dot(seen[0][start:start + rows], seen[1][g],
+                               precision="highest")), **tol)
+        start += rows
+
+
+def test_grouped_matmul_keeps_the_plain_path_where_the_kernels_do_not_tile(
+        monkeypatch):
+    """A width off the 128-lane tile, a capacity off the row tile and two
+    matrices beyond the VMEM a call may ask for take ``lax.ragged_dot``; a
+    batching ``vmap`` takes it at any shape, forward and backward."""
+    import fedml_tpu.ops.grouped_matmul as gm
+    assert gm._tiles(512, 256, 200, 4, 2, 4) is None
+    assert gm._tiles(500, 256, 256, 4, 2, 4) is None
+    assert gm._tiles(8192, 4096, 4096, 8, 2, 4) is None
+    assert gm._tiles(8192, 2048, 2048, 8, 2, 4) == 256
+    assert gm._tiles(6144, 2048, 768, 16, 2, 4) == 256
+    assert gm._tiles(1024, 2048, 2048, 8, 2, 4) == 128
+    assert [gm._panel(n) for n in (512, 768, 2048, 2688, 200)] == [
+        512, 384, 512, 384, 0]
+    monkeypatch.setattr(gm, "_pick_impl", lambda impl: "pallas")
+    ks = jax.random.split(jax.random.key(5), 3)
+    x = jax.random.normal(ks[0], (2, 128, 128), jnp.float32)
+    w = jax.random.normal(ks[1], (3, 128, 128), jnp.float32)
+    gs = jnp.asarray([[50, 0, 60], [1, 100, 27]], jnp.int32)
+
+    def loss(x, w, gs):
+        return jnp.sum(grouped_matmul(x, w, gs) ** 2)
+
+    got = jax.vmap(jax.value_and_grad(loss, argnums=(0, 1)),
+                   in_axes=(0, None, 0))(x, w, gs)
+    want = [jax.value_and_grad(lambda x, w: jnp.sum(_per_expert_loop(
+        x, w, s) ** 2), argnums=(0, 1))(a, w) for a, s in zip(x, gs)]
+    for i, (v, (dx, dw)) in enumerate(want):
+        np.testing.assert_allclose(got[0][i], v, rtol=1e-4)
+        np.testing.assert_allclose(got[1][0][i], dx, rtol=1e-4, atol=1e-3)
+        np.testing.assert_allclose(got[1][1][i], dw, rtol=1e-4, atol=1e-3)
 
 
 @pytest.mark.parametrize("op,kept", [

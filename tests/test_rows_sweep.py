@@ -20,23 +20,28 @@ def sweep():
     return mod
 
 
-@pytest.mark.parametrize("capacity", [16, 64, 192])
-def test_parts_run_and_the_add_back_forms_agree(sweep, monkeypatch, capacity):
+@pytest.mark.parametrize("capacity,form", [
+    (16, "swiglu"), (64, "swiglu"), (192, "swiglu"), (64, "relu2")])
+def test_parts_run_and_the_add_back_forms_agree(sweep, monkeypatch, capacity,
+                                                form):
     from fedml_tpu.models.moe import row_rungs
 
     for name, value in dict(TOKENS=32, DIM=16, WIDTH=8, CHOICES=6, ROUTED=16,
-                            HELD=2, VOCAB=50).items():
+                            HELD=2, VOCAB=50, FORM=form).items():
         monkeypatch.setattr(sweep, name, value)
     assert row_rungs(32 * 6) == (24, 48, 96, 192)
     operands = sweep._routing(3)
+    assert len(operands) == 6 + (3 if form == "swiglu" else 2)
     filled = int(operands[3].sum())
     assert 0 < filled <= 16
     row = sweep.measure(operands, capacity, iters=1, trace=False)
     assert row["capacity"] == capacity and row["rows_filled"] == filled
     assert {"fan_out", "mask", "experts_fwd", "experts_fwd_bwd",
-            "add_back_gather", "add_back_scatter", "rung_fwd",
-            "rung_bwd"} <= set(row)
+            "incumbent_fwd", "incumbent_fwd_bwd", "add_back_gather",
+            "add_back_scatter", "rung_fwd", "rung_bwd"} <= set(row)
     assert row["scatter_gap_to_gather"] < 1e-5
+    # off the chip both rows are ``lax.ragged_dot`` on the cast matrices
+    assert row["experts_gap_to_incumbent"] == 0
 
 
 @pytest.mark.parametrize("shown", [True, False])
@@ -61,6 +66,8 @@ def test_rung_shares_count_the_conditionals_of_a_trace(sweep, monkeypatch,
             op(t, t + 0.9, f"%conditional.{j}", "jit(step)/fedml.lm.route/cond")
         op(t + 0.1, t + 0.3, f"%fusion.{j}", path + "route/gather")
         op(t + 0.3, t + 0.4, f"%ragged-dot.{j}", "ragged-dot-none:")
+        op(t + 0.8, t + 0.85, f"%_gmm_dw.{j}", path + "experts/jit(_gmm_dw)/"
+           "pallas_call")
         op(t + 0.4, t + 0.8, f"%while.{j}", path + "experts/while")
         op(t + 0.5, t + 0.6, f"%ragged.{j}", path + "experts/while/body/dot")
     op(3.0, 3.5, "%fusion.9", "jit(step)/fedml.lm.dense/dot_general")
@@ -74,4 +81,10 @@ def test_rung_shares_count_the_conditionals_of_a_trace(sweep, monkeypatch,
     assert got["counted_by"] == ("conditional" if shown else "runs")
     assert got["shares"] == {8: 2 / 3, 16: 1 / 3}
     np.testing.assert_allclose([got["device_ms"][8], got["device_ms"][16]],
-                               [2 * 600.0, 600.0])
+                               [2 * 650.0, 650.0])
+    # the grouped matmuls' calls by kernel: the compiler's, and the repo's
+    # own by the jitted function that makes the call (``%ragged.{j}`` and the
+    # fusions under the kernels' paths are no calls)
+    assert got["kernel_calls"] == {"_gmm_dw": 3, "ragged-dot": 3}
+    np.testing.assert_allclose([got["kernel_ms"]["_gmm_dw"],
+                                got["kernel_ms"]["ragged-dot"]], [150., 300.])

@@ -125,30 +125,93 @@ def test_backward_form_compiles_on_both_sides_of_the_vmem_budget(
     assert compiled.as_text().count("tpu_custom_call") == kernels
 
 
-@pytest.mark.parametrize("rows", [8192 * 6, 6144])
-def test_grouped_matmul_compiles_unbatched_at_published_widths(one_chip,
-                                                               no_cache, rows):
-    """49,152 row slots (8,192 tokens x 6 choices: the sparse layer's last
-    row capacity) and 6,144 (its first), 16 held experts of 2048 x 768:
-    forward and both gradients; XLA's own operation count is the row
-    slots' (the static capacity), three passes."""
-    from fedml_tpu.ops.grouped_matmul import grouped_matmul
+@pytest.fixture()
+def grouped_kernels(monkeypatch):
+    """``ops/grouped_matmul.py`` asks ``jax.default_backend()`` whether to
+    take its kernels, and that is the CPU's here: the chip's choice, with
+    the kernels compiled and not interpreted."""
+    import fedml_tpu.ops.grouped_matmul as gm
+    monkeypatch.setattr(gm, "_pick_impl", lambda impl: "pallas")
+    monkeypatch.setattr(gm, "interpret", lambda: False)
+    return gm
 
-    d, f, held = 2048, 768, 16
+
+#: the five sparse cells' ``K x N``, held experts, first and last capacity
+GROUPED_SHAPES = {
+    "kanana2": (2048, 768, 16, 6144, 49152),
+    "ling3": (2560, 768, 8, 4096, 32768),
+    "laguna": (2048, 512, 32, 8192, 65536),
+    "zaya1": (2048, 2048, 8, 1024, 8192),
+    "nemotron3s_up": (1024, 2688, 8, 11264, 90112),
+    "nemotron3s_down": (2688, 1024, 8, 11264, 90112),
+}
+
+
+@pytest.mark.parametrize("last", [False, True])
+@pytest.mark.parametrize("cell", sorted(GROUPED_SHAPES))
+def test_grouped_matmul_compiles_unbatched_at_published_widths(
+        one_chip, no_cache, grouped_kernels, cell, last):
+    """bf16 rows against the held experts' float32 matrices at a cell's
+    first and last row capacity: forward and both gradients are the three
+    kernels of the repo's own (none of the compiler's grouped kernels, no
+    cast of a matrix outside them), ``d_w`` leaves in float32, and the cost
+    they tell XLA is the row slots' (the static capacity), three passes."""
+    k, n, held, *capacities = GROUPED_SHAPES[cell]
+    rows = capacities[last]
+    assert grouped_kernels._tiles(rows, k, n, held, 2, 4)
 
     def step(x, w, sizes):
-        return jax.grad(lambda x, w: jnp.sum(grouped_matmul(
+        return jax.grad(lambda x, w: jnp.sum(grouped_kernels.grouped_matmul(
             x, w, sizes).astype(jnp.float32) ** 2), argnums=(0, 1))(x, w)
 
-    compiled = jax.jit(step).lower(
-        jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip),
-        jax.ShapeDtypeStruct((held, d, f), jnp.bfloat16, sharding=one_chip),
-        jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)).compile()
+    operands = (
+        jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((held, k, n), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip))
+    compiled = jax.jit(step).lower(*operands).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 and "ragged-dot" not in text
+    assert not re.search(rf"(bf16|f32)\[{held},{k},{n}\][^ ]* convert\(", text)
+    dx, dw = jax.eval_shape(step, *operands)
+    assert (dx.dtype, dw.dtype) == (jnp.bfloat16, jnp.float32)
     flops = compiled.cost_analysis()["flops"]
-    assert flops == pytest.approx(3 * 2 * rows * d * f, rel=0.02)
+    assert flops == pytest.approx(3 * 2 * rows * k * n, rel=0.02)
 
 
-def test_row_capacities_compile_as_one_conditional_a_pass(one_chip, no_cache):
+@pytest.mark.parametrize("capacity", [4096, 8192])
+def test_wide_experts_rung_holds_no_copy_of_a_matrix(one_chip, no_cache,
+                                                     grouped_kernels,
+                                                     capacity):
+    """``zaya1_8b``'s held experts (8 of 2048 x 2048, one choice of 8,192
+    tokens): the compiled ``_rung`` and ``_rung_vjp`` of a capacity hold no
+    float32 -> bf16 ``convert`` of an ``[8, 2048, 2048]`` operand and no
+    bf16 -> float32 one (a weight gradient on its way back), and the
+    gradients of the three matrices leave as float32."""
+    from fedml_tpu.models import moe
+
+    n, d, held = 8192, 2048, 8
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    operands = (sd((n, d), jnp.bfloat16), sd((n,), jnp.int32),
+                sd((n,), jnp.int32), sd((held,), jnp.int32),
+                sd((1, n), jnp.bool_), sd((n, 1), jnp.float32),
+                *[sd((held, d, d), jnp.float32)] * 3)
+    ct = (sd((n, d), jnp.float32), ())
+    for fn, args in ((moe._rung(capacity), operands),
+                     (moe._rung_vjp(capacity), (operands, ct))):
+        text = fn.lower(*args).compile().as_text()
+        assert "ragged-dot" not in text
+        assert not re.search(r"(bf16|f32)\[8,2048,2048\][^ ]* convert\(", text)
+        assert text.count("tpu_custom_call") == (3 if fn is moe._rung(
+            capacity) else 9)
+    grads = jax.eval_shape(moe._rung_vjp(capacity), operands, ct)
+    assert [g.dtype for g in grads[2:]] == [jnp.float32] * 3
+
+
+def test_row_capacities_compile_as_one_conditional_a_pass(one_chip, no_cache,
+                                                          grouped_kernels):
     """The held experts' part of a sparse layer at the cell's shapes
     (``models/moe.routed_rows``): forward and backward each compile to one
     conditional with a branch a row capacity, and the backward's

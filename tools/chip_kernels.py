@@ -17,6 +17,9 @@ compiler's message. Rows:
   the whole padded row, so VMEM grows with V);
 - ``ops/kda.py``'s kernel pair, forward and backward, at heads of 128 and
   chunks of 32 and 64 against the ``jax.numpy`` scan, float32 operands;
+- ``ops/grouped_matmul.py``'s three kernels (bf16 rows, float32 matrices,
+  an empty group and rows of no group) against ``lax.ragged_dot`` on the
+  matrices cast outside, at a narrow and a wide expert's shape;
 - ``ops/batchnorm.py`` and ``ops/conv_lanes.py`` once each — they sit
   behind ``bn_impl``/``conv_impl`` (default ``xla``) and are queued for
   deletion, so a failure there is reported but does not fail the run — and
@@ -168,6 +171,33 @@ def _kda_case(t: int, h: int, chunk: int) -> dict:
             "bwd_err": _rel_err(got[1], ref[1])}
 
 
+def _grouped_case(m: int, k: int, n: int, groups: int) -> dict:
+    """The grouped matmul's kernels, forward and both gradients, against the
+    compiler's grouped kernels on the matrices cast to bf16 outside."""
+    import jax
+    import jax.numpy as jnp
+
+    import fedml_tpu.ops.grouped_matmul as gm
+
+    ks = jax.random.split(jax.random.key(m + n), 4)
+    share = jax.random.dirichlet(ks[0], jnp.ones((groups,))).at[1].set(0)
+    sizes = jnp.floor(share * 0.8 * m).astype(jnp.int32)
+    live = (jnp.arange(m) < jnp.sum(sizes))[:, None]
+    x = jnp.where(live, jax.random.normal(ks[1], (m, k), jnp.bfloat16), 0)
+    c = jnp.where(live, jax.random.normal(ks[2], (m, n), jnp.float32), 0)
+    w = k ** -0.5 * jax.random.normal(ks[3], (groups, k, n), jnp.float32)
+
+    def run(fn):
+        return jax.value_and_grad(lambda x, w: jnp.sum(
+            fn(x, w, sizes).astype(jnp.float32) * c), (0, 1))(x, w)
+
+    assert gm._tiles(m, k, n, groups, 2, 4)
+    ref = jax.jit(lambda: run(gm._plain))()
+    got = jax.jit(lambda: run(gm.grouped_matmul))()
+    return {"fwd_err": _rel_err(got[0], ref[0]),
+            "bwd_err": _rel_err(got[1], ref[1])}
+
+
 def _batchnorm_case() -> dict:
     import jax
     import jax.numpy as jnp
@@ -278,6 +308,10 @@ def main() -> int:
     ] + [
         (f"kda T={t} H={h} chunk={c}", True, partial(_kda_case, t, h, c))
         for t, h, c in ((512, 4, 32), (1024, 2, 64))
+    ] + [
+        (f"grouped matmul M={m} K={k} N={n} G={g}", True,
+         partial(_grouped_case, m, k, n, g))
+        for m, k, n, g in ((6144, 2048, 768, 16), (4096, 2048, 2048, 8))
     ] + [
         ("batchnorm (bn_impl=pallas) 64x32x32x16 bf16", False,
          _batchnorm_case),

@@ -11,12 +11,17 @@ program of the cell's first round for ``topologies.get_topology_desc("tpu",
 results, temporaries and program text, and their sum against the chip's
 16 GiB. Nothing runs: no time, no result (``PERF.md``, PR 30). The model's
 parameters are initialised on the host (3.3 GB for 822 M), so this takes a
-few minutes. ``--seq-len`` / ``--batch`` override the configuration's;
+few minutes; it also prints what the program's Python trace and lowering
+took here. ``--seq-len`` / ``--batch`` override the configuration's;
 ``--hlo FILE`` also writes the compiled program's text there and prints how
 many times a step the compiler issues each module's matmuls, by pass (the
 first forward, the forward again under ``nn.remat``, the backward): more
 than the layers ask for is XLA's OWN rematerialisation (``PERF.md``, PR 38:
 the state-space mixer's joint ``in_proj`` 84 times where 36 were asked for).
+``--lowered FILE`` writes the LOWERED program's text and stops: two
+checkouts' files, with the Pallas kernels' serialized payloads masked (they
+embed the callers' file names and line numbers), say whether a change left
+a cell's round program alone (``PERF.md``, PR 44, PR 45).
 """
 
 from __future__ import annotations
@@ -26,6 +31,7 @@ import collections
 import os
 import re
 import sys
+import time
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -54,6 +60,8 @@ def main(argv=None) -> int:
     p.add_argument("--seq-len", type=int)
     p.add_argument("--batch", type=int)
     p.add_argument("--hlo")
+    p.add_argument("--lowered", help="write the lowered program's text there "
+                   "and stop before the compile")
     args = p.parse_args(argv)
 
     import jax
@@ -67,18 +75,20 @@ def main(argv=None) -> int:
     from fedml_tpu.core.rng import round_key
     from fedml_tpu.parallel.packed import plan_arrays_tuple
 
-    # the program asks jax.default_backend() which attention and which
-    # delta-rule scan to take; here that is the CPU's, and the chip's
-    # kernels, compiled and not interpreted, are what has to fit (the
-    # package exports the function under the attention module's name)
+    # the program asks jax.default_backend() which attention, which
+    # delta-rule scan and which grouped matmul to take; here that is the
+    # CPU's, and the chip's kernels, compiled and not interpreted, are what
+    # has to fit (the package exports the function under the attention
+    # module's name)
     import importlib
 
     def on_the_chip(impl):
         return "pallas" if impl == "auto" else impl
 
     importlib.import_module("fedml_tpu.ops.attention")._pick_impl = on_the_chip
-    kda = importlib.import_module("fedml_tpu.ops.kda")
-    kda._pick_impl, kda.interpret = on_the_chip, lambda: False
+    for name in ("fedml_tpu.ops.kda", "fedml_tpu.ops.grouped_matmul"):
+        kernels = importlib.import_module(name)
+        kernels._pick_impl, kernels.interpret = on_the_chip, lambda: False
     jax.config.update("jax_enable_compilation_cache", False)
 
     spec = Spec()
@@ -106,7 +116,23 @@ def main(argv=None) -> int:
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
         concrete)
-    compiled = step.lower(*shapes).compile()
+    t0 = time.perf_counter()
+    traced = step.trace(*shapes)
+    t1 = time.perf_counter()
+    lowered = traced.lower()
+    t2 = time.perf_counter()
+    if args.lowered:
+        with open(args.lowered, "w") as f:
+            f.write(lowered.as_text())
+        print(f"  trace {t1 - t0:.1f} s, lower {t2 - t1:.1f} s; lowered "
+              f"text in {args.lowered}")
+        return 0
+    compiled = lowered.compile()
+    # the Python part of a first call, which no compile cache skips
+    # (``round_trace_s``, ``round_lower_s`` on the chip's host)
+    print(f"  trace {t1 - t0:.1f} s, lower {t2 - t1:.1f} s, compile "
+          f"{time.perf_counter() - t2:.1f} s (this host, the kernels' "
+          f"lowering in both)")
     if args.hlo:
         text = compiled.as_text()
         with open(args.hlo, "w") as f:

@@ -9,7 +9,11 @@ sorted row slots that it chooses each step from the router's own count
 (``moe_route_ms``, ``expert_mm_ms``, by scope). This tool times the parts by
 themselves, each jitted alone, at ``[8192, 2048]`` bf16 tokens, 6 choices of
 128 experts, 16 held experts of 768: the fan-out gather, the row mask, the
-three grouped matmuls forward and forward + backward, the two forms of the
+experts' grouped matmuls forward and forward + backward on the float32
+matrices as the program runs them (``ops/grouped_matmul.py``: the kernels of
+the repo's own) and as it ran them before PR 45 (``incumbent_*``:
+``lax.ragged_dot`` on the matrices cast to bf16 and the weight gradients
+cast back, the casts counted), the two forms of the
 weighted add-back (today's gather of one row a PAIR and float32 sum, and a
 scatter-add of the ``C`` weighted rows into ``[n, d]`` float32), and a whole
 rung forward and backward (``moe._rung``, ``moe._rung_vjp``). One row a
@@ -23,12 +27,16 @@ calls, and the device's own time for a traced call.
     python tools/rows_sweep.py [capacity ...]       # the parts, on the chip
     python tools/rows_sweep.py --width 2048 --choices 1 --routed 16 --held 8 \
         4096 5120 8192       # another layer's sizes (these: zaya1_sim_c2's)
+    python tools/rows_sweep.py --form relu2 --tokens 4096 --dim 1024 \
+        --width 2688 --choices 22 --routed 512 --held 8 11264
+                             # two matrices an expert (nemotron3s_sim_c2's)
     python tools/rows_sweep.py --trace-dir .bench_out/trace/kanana2_sim_c2
 
 With ``--trace-dir`` (a profiler trace of the cell, as ``benchmarks/run.py
 --trace 1`` leaves it) it also prints the share of the sparse layers'
-conditionals that ran under each ``moe_rows_<C>`` name; that part needs no
-chip. Fails at once without a TPU unless ``--trace-only``. Writes
+conditionals that ran under each ``moe_rows_<C>`` name, and the grouped
+matmuls' calls by kernel (``_gmm_fwd``, ``_gmm_dx``, ``_gmm_dw``, and the
+compiler's ``%ragged-dot`` where any is left); that part needs no chip. Fails at once without a TPU unless ``--trace-only``. Writes
 ``chiprun_out/rows_sweep.json``.
 """
 
@@ -47,7 +55,12 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
 
 TOKENS, DIM, WIDTH, CHOICES, ROUTED, HELD, VOCAB = 8192, 2048, 768, 6, 128, 16, 16032
+FORM = "swiglu"
 RUNG_NAME = re.compile(r"moe_rows_(\d+)")
+#: a grouped matmul's call in a trace: the kernels of the repo's own carry
+#: the name of the jitted function that makes the call (``%_gmm_fwd.7``),
+#: the compiler's its bare name (``%ragged-dot-none``)
+KERNEL_NAME = re.compile(r"^%(_gmm_fwd|_gmm_dx|_gmm_dw|ragged-dot)")
 
 
 def _device_ms(fn, args, calls: int = 3) -> float:
@@ -75,6 +88,7 @@ def _routing(seed: int):
     import jax
     import jax.numpy as jnp
 
+    from fedml_tpu.models import moe
     from fedml_tpu.models.moe import route
 
     ks = jax.random.split(jax.random.key(seed), 8)
@@ -92,8 +106,11 @@ def _routing(seed: int):
     key = jnp.where(mine, local, HELD).reshape(-1)
     order = jnp.argsort(key, stable=True)
     sizes = jnp.sum(jax.nn.one_hot(key, HELD + 1, dtype=jnp.int32), axis=0)[:HELD]
+    # the form's matrices: all but the last into the width, the last out
+    into = len(moe.EXPERT_FORMS[FORM][0]) - 1
     w = [0.02 * jax.random.normal(ks[4 + i], shape, jnp.float32)
-         for i, shape in enumerate([(HELD, DIM, WIDTH)] * 2 + [(HELD, WIDTH, DIM)])]
+         for i, shape in enumerate([(HELD, DIM, WIDTH)] * into
+                                   + [(HELD, WIDTH, DIM)])]
     return (xf.astype(jnp.bfloat16), order, jnp.argsort(order), sizes, mine,
             weights, *w)
 
@@ -101,33 +118,39 @@ def _routing(seed: int):
 def measure(operands, capacity: int, iters: int, trace: bool = True) -> dict:
     """One capacity's parts -> {part: {"ms", "device_ms"}} and the scatter
     form's largest difference from the gather form."""
-    import flax.linen as nn
     import jax
     import jax.numpy as jnp
 
+    import fedml_tpu.ops.grouped_matmul as gm
     from fedml_tpu.models import moe
-    from fedml_tpu.ops.grouped_matmul import (fan_out_rows, grouped_matmul,
-                                              permute_rows)
+    from fedml_tpu.ops.grouped_matmul import fan_out_rows, permute_rows
 
     xf, order, inv, sizes, mine, weights, *w = operands
     n, d = xf.shape
     k = mine.shape[0]
     head = order[:capacity]
-    wb = [a.astype(xf.dtype) for a in w]
     wt = jnp.where(mine, weights.T, 0.0)
+    live = (jnp.arange(capacity) < jnp.sum(sizes))[:, None]
 
     def mask(rows, sizes):
         return jnp.where((jnp.arange(capacity) < jnp.sum(sizes))[:, None], rows, 0)
 
-    def experts(rows, w_gate, w_up, w_down, sizes):
-        g = grouped_matmul(rows, w_gate, sizes)
-        u = grouped_matmul(rows, w_up, sizes)
-        return grouped_matmul(nn.silu(g) * u, w_down, sizes)
+    def experts(rows, sizes, *ws):
+        return moe.EXPERT_FORMS[FORM][1](rows, sizes, live, *ws)[0]
 
-    def experts_both(rows, w_gate, w_up, w_down, sizes, ct):
-        y, vjp = jax.vjp(lambda r, *ws: experts(r, *ws, sizes), rows, w_gate,
-                         w_up, w_down)
+    def experts_both(rows, sizes, ct, *ws):
+        y, vjp = jax.vjp(lambda r, *ws: experts(r, sizes, *ws), rows, *ws)
         return y, vjp(ct)
+
+    def incumbent(fn):
+        """``fn`` traced with the grouped matmul as it was before PR 45."""
+        def traced(*args):
+            ours, moe.grouped_matmul = moe.grouped_matmul, gm._plain
+            try:
+                return fn(*args)
+            finally:
+                moe.grouped_matmul = ours
+        return traced
 
     def add_back_gather(y, inv, head, wt):
         back = permute_rows(y, inv, head).reshape(k, n, d)
@@ -139,18 +162,22 @@ def measure(operands, capacity: int, iters: int, trace: bool = True) -> dict:
             y.astype(jnp.float32) * scale)
 
     rows = jax.jit(mask)(jax.jit(fan_out_rows)(xf, head, inv), sizes)
-    y = jax.jit(mask)(jax.jit(experts)(rows, *wb, sizes), sizes)
+    y = jax.jit(mask)(jax.jit(experts)(rows, sizes, *w), sizes)
     ct = jax.random.normal(jax.random.key(1), (n, d), jnp.float32)
+    stats = jax.eval_shape(moe._rung(capacity, FORM), *operands)[1]
     parts = {
         "fan_out": (jax.jit(fan_out_rows), (xf, head, inv)),
         "mask": (jax.jit(mask), (rows, sizes)),
-        "experts_fwd": (jax.jit(experts), (rows, *wb, sizes)),
-        "experts_fwd_bwd": (jax.jit(experts_both), (rows, *wb, sizes, y)),
+        "experts_fwd": (jax.jit(experts), (rows, sizes, *w)),
+        "experts_fwd_bwd": (jax.jit(experts_both), (rows, sizes, y, *w)),
+        "incumbent_fwd": (jax.jit(incumbent(experts)), (rows, sizes, *w)),
+        "incumbent_fwd_bwd": (jax.jit(incumbent(experts_both)),
+                              (rows, sizes, y, *w)),
         "add_back_gather": (jax.jit(add_back_gather), (y, inv, head, wt)),
         "add_back_scatter": (jax.jit(add_back_scatter), (y, head, wt)),
-        "rung_fwd": (moe._rung(capacity), (*operands[:6], *wb)),
-        "rung_bwd": (moe._rung_vjp(capacity), ((*operands[:6], *wb),
-                                               (ct, ()))),
+        "rung_fwd": (moe._rung(capacity, FORM), operands),
+        "rung_bwd": (moe._rung_vjp(capacity, FORM), (operands, (
+            ct, jax.tree_util.tree_map(jnp.zeros_like, stats)))),
     }
     row = {"capacity": capacity, "rows_filled": int(jnp.sum(sizes))}
     for name, (fn, args) in parts.items():
@@ -166,6 +193,10 @@ def measure(operands, capacity: int, iters: int, trace: bool = True) -> dict:
     a, b = a[0](*a[1]), b[0](*b[1])
     row["scatter_gap_to_gather"] = float(jnp.max(jnp.abs(a - b))
                                          / jnp.max(jnp.abs(a)))
+    a, b = parts["experts_fwd"], parts["incumbent_fwd"]
+    a, b = (fn(*args).astype(jnp.float32) for fn, args in (a, b))
+    row["experts_gap_to_incumbent"] = float(jnp.max(jnp.abs(a - b))
+                                            / jnp.max(jnp.abs(b)))
     return row
 
 
@@ -178,8 +209,10 @@ def rung_shares(trace_dir: str) -> dict:
     nothing of another path interrupts (an operation without a path, as
     the compiler's grouped kernels are, interrupts nothing). Forward and
     backward conditionals of one layer-step take the same branch, so the
-    shares are the layer-steps'. -> {"conditionals", "counted_by", "shares":
-    {C: share}, "device_ms": {C: ms inside those branches}}."""
+    shares are the layer-steps'. The grouped matmuls' calls are counted by
+    kernel, wherever they ran (:data:`KERNEL_NAME`). -> {"conditionals", "counted_by", "shares": {C: share},
+    "device_ms": {C: ms inside those branches}, "kernel_calls": {kernel:
+    calls}, "kernel_ms": {kernel: ms}}."""
     from benchmarks.trace import opmeta, scopes
 
     path = scopes.find_xplane(trace_dir)
@@ -196,6 +229,12 @@ def rung_shares(trace_dir: str) -> dict:
         return int(found[-1]) if found else None
 
     rungs = [rung_of(i) for i in range(len(ops))]
+    calls, call_ms = Counter(), Counter()
+    for start, end, name in ops:
+        found = KERNEL_NAME.match(name)
+        if found:
+            calls[found.group(1)] += 1
+            call_ms[found.group(1)] += (end - start) * 1e3
     enclosing, runs, ms, last = {}, Counter(), Counter(), None
     for i, c in enumerate(rungs):
         if c is None:
@@ -217,7 +256,9 @@ def rung_shares(trace_dir: str) -> dict:
     return {"conditionals": total,
             "counted_by": "conditional" if enclosing else "runs",
             "shares": {c: counts[c] / total for c in sorted(counts)},
-            "device_ms": {c: ms[c] for c in sorted(ms)}}
+            "device_ms": {c: ms[c] for c in sorted(ms)},
+            "kernel_calls": dict(sorted(calls.items())),
+            "kernel_ms": dict(sorted(call_ms.items()))}
 
 
 def main(argv=None) -> int:
@@ -229,12 +270,15 @@ def main(argv=None) -> int:
     for name in ("tokens", "dim", "width", "choices", "routed", "held"):
         ap.add_argument(f"--{name}", type=int, default=globals()[name.upper()],
                         help="the layer's size (default: kanana2_sim_c2's)")
+    ap.add_argument("--form", default=FORM, choices=("swiglu", "relu2"),
+                    help="what an expert is (models/moe.py: EXPERT_FORMS)")
     ap.add_argument("--trace-dir", help="a profiler trace of the cell")
     ap.add_argument("--trace-only", action="store_true",
                     help="read --trace-dir and time nothing (needs no chip)")
     args = ap.parse_args(argv)
     globals().update(TOKENS=args.tokens, DIM=args.dim, WIDTH=args.width,
-                     CHOICES=args.choices, ROUTED=args.routed, HELD=args.held)
+                     CHOICES=args.choices, ROUTED=args.routed, HELD=args.held,
+                     FORM=args.form)
     out = {}
     if args.trace_dir:
         out["rungs_in_trace"] = rung_shares(args.trace_dir)
@@ -251,7 +295,7 @@ def main(argv=None) -> int:
         operands = _routing(args.seed)
         filled = int(operands[3].sum())
         out["device"] = jax.devices()[0].device_kind
-        out["sizes"] = [TOKENS, DIM, WIDTH, CHOICES, ROUTED, HELD]
+        out["sizes"] = [TOKENS, DIM, WIDTH, CHOICES, ROUTED, HELD, FORM]
         out["rows"] = []
         for capacity in rungs:
             if capacity < filled:
